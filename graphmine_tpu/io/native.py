@@ -2,40 +2,65 @@
 
 The native library provides the hot host-side path the reference delegated to
 the JVM (parquet/RDD machinery, ``Graphframes.py:53-74``): streaming
-edge-list parsing + open-addressing string interning. Build it with
-``make -C native``. When the shared library is absent these bindings return
-``None`` and callers fall back to the NumPy implementation.
+edge-list parsing + open-addressing string interning. The shared library is
+not tracked in git: on first use it is built from ``native/graph_builder.cpp``
+(``make -C native``), and a failed build raises. ``_lib()`` returns ``None``
+— callers then take the NumPy implementation — only when the checkout has
+no ``native/`` sources at all. ``GRAPHMINE_NATIVE_LIB`` names a prebuilt
+library to load instead (it must load).
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
+import subprocess
 
 import numpy as np
 
+_NATIVE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    "native",
+)
 _LIB = None
 _LIB_TRIED = False
+
+
+def _build(so: str, source: str) -> None:
+    """``make -C native`` unless ``so`` is already newer than ``source``.
+    Checked and built under a lock on the Makefile, so concurrent first
+    users (pytest-xdist workers) never load a half-written library."""
+    with open(os.path.join(_NATIVE_DIR, "Makefile")) as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if os.path.exists(so) and os.path.getmtime(so) >= os.path.getmtime(source):
+            return
+        proc = subprocess.run(
+            ["make", "-C", _NATIVE_DIR], capture_output=True, text=True
+        )
+        if proc.returncode != 0 or not os.path.exists(so):
+            raise RuntimeError(
+                f"building {so} failed (rc {proc.returncode}):\n"
+                f"{proc.stdout}{proc.stderr}"
+            )
 
 
 def _lib():
     global _LIB, _LIB_TRIED
     if _LIB_TRIED:
         return _LIB
-    _LIB_TRIED = True
-    here = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    for cand in (
-        os.environ.get("GRAPHMINE_NATIVE_LIB", ""),
-        os.path.join(here, "native", "libgraphbuild.so"),
-    ):
-        if cand and os.path.exists(cand):
-            try:
-                lib = ctypes.CDLL(cand)
-                _bind(lib)
-                _LIB = lib
-                break
-            except OSError:
-                continue
+    so = os.environ.get("GRAPHMINE_NATIVE_LIB")
+    if not so:
+        so = os.path.join(_NATIVE_DIR, "libgraphbuild.so")
+        source = os.path.join(_NATIVE_DIR, "graph_builder.cpp")
+        if os.path.exists(source):
+            _build(so, source)
+        elif not os.path.exists(so):
+            _LIB_TRIED = True
+            return None
+    lib = ctypes.CDLL(so)
+    _bind(lib)
+    _LIB, _LIB_TRIED = lib, True
     return _LIB
 
 

@@ -18,7 +18,7 @@ IS the performance argument:
    already hold the exact layout.
 
 2. **Measured rooflines** — per-family achieved-rate anchors seeded from
-   the committed silicon captures (BENCH_r04/r05; see
+   the r4/r5 silicon captures on one device kind (see
    :data:`ROOFLINE_SEEDS` for per-anchor provenance), overridable by a
    JSON file (``GRAPHMINE_ROOFLINE_FILE``) or per-anchor env vars
    (``GRAPHMINE_ROOFLINE_<NAME>``) so a fresh capture re-seeds the model
@@ -49,6 +49,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import time
 from dataclasses import dataclass
 
@@ -56,38 +57,48 @@ _I32 = 4  # bytes per int32/float32 slot — the compute plane's one word size
 
 # ---- measured roofline anchors (single owner) ------------------------------
 #
-# Values are work-units per second PER CHIP. Provenance discipline: each
-# anchor names the capture that seeded it; anchors nobody has measured on
-# silicon yet say so ("model seed") and are exactly the ones a future
-# capture should replace (tools/bench_diff.py --manifest names the
-# pending tiers).
+# Values are work-units per second PER CHIP, keyed by the device kind
+# (jax's ``device_kind``) they were measured on. Provenance discipline:
+# each anchor names the capture that seeded it; anchors nobody has
+# measured on silicon yet say so ("model seed") and are exactly the ones
+# a future capture should replace (tools/bench_diff.py --manifest names
+# the pending tiers). Cost estimates are always computed from
+# ``MODEL_DEVICE_KIND``'s seeds (plus overrides); what a measurement on
+# ANOTHER kind of device achieved against them is not a fraction of
+# anything — see :func:`anchored`.
+MODEL_DEVICE_KIND = "TPU v5 lite"
 ROOFLINE_SEEDS: dict = {
-    # Random-gather slots/s: BENCH_r04/r05 `roofline` tier, TPU v5 lite
-    # (131.8M / 132.6M slots/s measured; ops/bucketed_mode.py header).
-    # Governs the sort gather and every bucketed/blocked row reduce.
-    "gather_slots_per_sec": 1.32e8,
-    # Full binned-pass (stream + scatter) slots/s: SEEDED EQUAL to the
-    # random gather pending the silicon `blocking` capture — the capture
-    # whose `detail.binned_vs_random_gather` ratio is exactly the number
-    # that should replace this seed AND move the BLOCKED_MIN_* crossover
-    # constants (ROADMAP; tools/bench_diff.py prints the suggestion when
-    # it lands).
-    "binned_slots_per_sec": 1.32e8,
-    # ICI exchange bytes/s per chip: NO bench tier measures this yet —
-    # 4.5e10 B/s is a conservative v5e-interconnect model seed (order of
-    # magnitude below the advertised peak; the sharded tier's silicon
-    # capture is the natural place to measure it).
-    "exchange_bytes_per_sec": 4.5e10,
-    # Exact-kNN distance pairs/s: the r6 LOF crossover provenance table
-    # (ops/lof.py): 65,536 points (=> 65,536^2 pairs) in 2.3 s on v5e.
-    "lof_exact_pairs_per_sec": 1.87e9,
-    # IVF-flat end-to-end points/s at crossover scale: same table,
-    # 262,144 points in 9.0 s (candidate reduction included).
-    "lof_ivf_points_per_sec": 2.9e4,
+    MODEL_DEVICE_KIND: {
+        # Random-gather slots/s: the r4/r5 `roofline` bench tier
+        # (131.8M / 132.6M slots/s measured; ops/bucketed_mode.py
+        # header). Governs the sort gather and every bucketed/blocked
+        # row reduce.
+        "gather_slots_per_sec": 1.32e8,
+        # Full binned-pass (stream + scatter) slots/s: SEEDED EQUAL to
+        # the random gather pending the silicon `blocking` capture — the
+        # capture whose `detail.binned_vs_random_gather` ratio is exactly
+        # the number that should replace this seed AND move the
+        # BLOCKED_MIN_* crossover constants (ROADMAP; tools/bench_diff.py
+        # prints the suggestion when it lands).
+        "binned_slots_per_sec": 1.32e8,
+        # ICI exchange bytes/s per chip: NO bench tier measures this yet
+        # — 4.5e10 B/s is a conservative v5e-interconnect model seed
+        # (order of magnitude below the advertised peak; the sharded
+        # tier's silicon capture is the natural place to measure it).
+        "exchange_bytes_per_sec": 4.5e10,
+        # Exact-kNN distance pairs/s: the r6 LOF crossover provenance
+        # table (ops/lof.py): 65,536 points (=> 65,536^2 pairs) in 2.3 s.
+        "lof_exact_pairs_per_sec": 1.87e9,
+        # IVF-flat end-to-end points/s at crossover scale: same table,
+        # 262,144 points in 9.0 s (candidate reduction included).
+        "lof_ivf_points_per_sec": 2.9e4,
+    },
 }
 
 _SEED_PROVENANCE = {
-    "gather_slots_per_sec": "BENCH_r04/r05 roofline tier (TPU v5e)",
+    "gather_slots_per_sec": (
+        "r4/r5 roofline capture on TPU v5 lite (record deleted in PR 22)"
+    ),
     "binned_slots_per_sec": (
         "seeded = gather pending the silicon `blocking` capture"
     ),
@@ -116,7 +127,7 @@ def rooflines(overrides: dict | None = None) -> dict:
     """
     out = {
         k: {"v": float(v), "src": _SEED_PROVENANCE[k]}
-        for k, v in ROOFLINE_SEEDS.items()
+        for k, v in ROOFLINE_SEEDS[MODEL_DEVICE_KIND].items()
     }
     path = os.environ.get("GRAPHMINE_ROOFLINE_FILE")
     if path:
@@ -139,6 +150,23 @@ def rooflines(overrides: dict | None = None) -> dict:
             if k in out:
                 out[k] = {"v": float(v), "src": "caller"}
     return out
+
+
+def _running_device_kind() -> str | None:
+    """``device_kind`` of the device this process computes on — read off
+    jax only when the caller already imported it (this module stays
+    importable on a machine with no accelerator stack)."""
+    jax = sys.modules.get("jax")
+    return jax.devices()[0].device_kind if jax is not None else None
+
+
+def anchored(device_kind: str | None) -> bool:
+    """Whether an achieved-vs-model fraction means anything on
+    ``device_kind``: the kind has measured seeds, or the operator
+    supplied anchors for this machine (``GRAPHMINE_ROOFLINE_FILE``)."""
+    return device_kind in ROOFLINE_SEEDS or bool(
+        os.environ.get("GRAPHMINE_ROOFLINE_FILE")
+    )
 
 
 @dataclass(frozen=True)
@@ -541,7 +569,10 @@ def emit_superstep_timing(
     that could not build an estimate must not emit a record claiming
     one). The achieved fraction is predicted-time / achieved-time for
     the window — >1 means the model is conservative, far below 1 is the
-    triage signal (docs/RUNBOOKS.md §12). Timing comes from the caller's
+    triage signal (docs/RUNBOOKS.md §12). It is ``None`` on a device kind
+    the model has no anchors for (:func:`anchored`): the seeds describe
+    one kind of chip, and a CPU or an unmeasured part judged against
+    them yields a number that means nothing. Timing comes from the caller's
     EXISTING superstep sync (the driver already blocks per superstep for
     the labels-changed counter) — this adds zero device syncs.
 
@@ -564,9 +595,13 @@ def emit_superstep_timing(
         num_edges * window / seconds / max(cost.devices, 1)
         if seconds > 0 else 0.0
     )
-    fraction = (
-        cost.predicted_seconds / per_step if per_step > 0 else 0.0
-    )
+    fraction = None
+    if anchored(_running_device_kind()):
+        # significant digits, not decimal places: a 1e-6 fraction must
+        # not round to a report-breaking 0.0
+        fraction = _sig(
+            cost.predicted_seconds / per_step if per_step > 0 else 0.0
+        )
     return sink.emit(
         "superstep_timing",
         op=op,
@@ -577,10 +612,7 @@ def emit_superstep_timing(
         seconds=round(seconds, 6),
         edges_per_sec_per_chip=round(achieved),
         predicted_edges_per_sec_per_chip=round(cost.predicted_per_chip),
-        # significant digits, not decimal places: a 1e-6 fraction (tiny
-        # CPU smoke runs are dispatch-dominated) must not round to a
-        # report-breaking 0.0
-        achieved_fraction=_sig(fraction),
+        achieved_fraction=fraction,
         devices=cost.devices,
         cold_compile=bool(cold_compile),
         cost=cost.record(),

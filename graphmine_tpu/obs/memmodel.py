@@ -585,8 +585,8 @@ def predegrade_superstep(
 
 def rss_sample() -> dict | None:
     """Host-RSS fallback measurement for backends whose allocator does
-    not report ``memory_stats()`` (CPU smoke runs, some tunneled
-    runtimes) — the watermark then says so (``source: "rss"``) instead
+    not report ``memory_stats()`` (the CPU backend) — the watermark
+    then says so (``source: "rss"``) instead
     of silently comparing device model against nothing."""
     from graphmine_tpu.obs.heartbeat import rss_mb
 

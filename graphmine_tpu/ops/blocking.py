@@ -2,7 +2,7 @@
 
 Every superstep family (LPA / CC / PageRank) is **random-gather bound**:
 the r4 width-ladder work drove the fused bucketed kernel to the measured
-~130M gathered-slots/s roofline (BENCH_r05 ``roofline`` tier;
+~130M gathered-slots/s roofline (the r5 ``roofline`` bench tier;
 ``ops/bucketed_mode.py`` header), so further chip-rate gains require
 changing the *memory-access pattern*, not the arithmetic. This module
 implements propagation blocking (PAPERS.md: arXiv 2011.08451 "Optimizing

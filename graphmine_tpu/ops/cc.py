@@ -95,7 +95,7 @@ def connected_components(
     ``plan``: a fused :class:`BucketedModePlan` (r5) — supersteps run
     :func:`cc_superstep_bucketed` instead of the segment_min path
     (identical labels every step, tested; measured 2.57x on the
-    100M-edge cc bench tier, `bench_r5_final_tpu.log`) — or a
+    100M-edge cc bench tier in the r5 capture) — or a
     :class:`~graphmine_tpu.ops.blocking.BlockedPlan` (r7): supersteps run
     :func:`~graphmine_tpu.ops.blocking.cc_superstep_blocked`, the
     destination-binned bin-then-reduce layout past the gather roofline.

@@ -197,7 +197,7 @@ def betweenness_centrality(
         # psum over ICI — embarrassingly parallel Brandes.
         from jax.sharding import PartitionSpec as P
 
-        from graphmine_tpu._jax_compat import shard_map
+        from jax import shard_map
 
         axes = tuple(mesh.axis_names)
 

@@ -56,10 +56,7 @@ def knn(points: jax.Array, k: int, row_tile: int = 1024, impl: str = "auto"):
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _tiled_knn(queries, refs, k, row_tile, *, exclude_self=False, ref_mask=None,

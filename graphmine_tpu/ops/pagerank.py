@@ -20,9 +20,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from graphmine_tpu._jax_compat import pcast
 import numpy as np
 from jax import lax
+from jax.lax import pcast
 
 from graphmine_tpu.graph.container import Graph
 

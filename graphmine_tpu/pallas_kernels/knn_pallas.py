@@ -37,17 +37,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _compiler_params_cls():
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cls is None:
-        raise RuntimeError(
-            "unsupported pallas version: pltpu exposes neither "
-            "CompilerParams nor TPUCompilerParams"
-        )
-    return cls
-
 _BIG = float("inf")
 
 
@@ -163,8 +152,7 @@ def knn_pallas(points: jax.Array, k: int, row_tile: int = 128,
             pltpu.VMEM((row_tile, k), jnp.float32),
             pltpu.VMEM((row_tile, k), jnp.int32),
         ],
-        # renamed TPUCompilerParams -> CompilerParams across pallas releases
-        compiler_params=_compiler_params_cls()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

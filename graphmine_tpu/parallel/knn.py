@@ -26,9 +26,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from graphmine_tpu._jax_compat import shard_map
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 

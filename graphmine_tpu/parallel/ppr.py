@@ -18,9 +18,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-
-from graphmine_tpu._jax_compat import shard_map
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from graphmine_tpu.graph.container import Graph

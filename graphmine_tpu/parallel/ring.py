@@ -22,9 +22,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-
-from graphmine_tpu._jax_compat import pcast, shard_map
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import pcast
 from jax.sharding import PartitionSpec as P
 
 from graphmine_tpu.ops.segment import segment_mode

@@ -32,9 +32,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from graphmine_tpu._jax_compat import shard_map
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from graphmine_tpu.graph.container import Graph, build_graph

@@ -36,35 +36,41 @@ def _visible_devices() -> int:
 
 
 def device_hbm_bytes(devices=None) -> int | None:
-    """Best-effort real per-device HBM via ``memory_stats()``: the MIN
-    of ``bytes_limit`` across all local devices (ISSUE 14 satellite) — a
+    """Real per-device HBM via ``memory_stats()``: the MIN of
+    ``bytes_limit`` across all local devices (ISSUE 14 satellite) — a
     heterogeneous or partially-occupied mesh must plan against its
     smallest chip, and trusting ``jax.devices()[0]`` alone budgeted
     against whichever part happened to enumerate first.
 
-    Returns None when no backend reports it (CPU returns None, some
-    tunneled runtimes raise) — the planner then falls back to its
-    16 GiB default. Queried here, not in the planner, so host-side
-    planning paths never import jax (planner.hbm_bytes_per_device).
-    ``devices`` overrides the enumeration (tests)."""
+    A CPU device reports none and is skipped; with no report at all the
+    answer is None and the planner budgets against its 16 GiB default
+    (the CPU test meshes). A TPU must report: one without a
+    ``bytes_limit``, or whose ``memory_stats()`` raises, is an error —
+    never a silent 16 GiB assumption about an unknown part. Queried
+    here, not in the planner, so host-side planning paths never import
+    jax (planner.hbm_bytes_per_device). ``devices`` overrides the
+    enumeration (tests)."""
     if devices is None:
         import jax
 
-        try:
-            devices = jax.local_devices()
-        except Exception:
-            return None
+        devices = jax.local_devices()
     limits = []
     for dev in devices:
+        on_tpu = getattr(dev, "platform", None) == "tpu"
         try:
             stats = dev.memory_stats()
         except Exception:
+            if on_tpu:
+                raise
             continue
-        if not stats:
-            continue
-        limit = stats.get("bytes_limit")
+        limit = (stats or {}).get("bytes_limit")
         if limit and limit > 0:
             limits.append(int(limit))
+        elif on_tpu:
+            raise RuntimeError(
+                f"{dev} reports no bytes_limit: cannot size the memory "
+                "plan for it (set GRAPHMINE_HBM_BYTES to pin a budget)"
+            )
     return min(limits) if limits else None
 
 
@@ -134,7 +140,10 @@ class PipelineResult:
 
 def run_pipeline(config: PipelineConfig) -> PipelineResult:
     config.validate()
+    from graphmine_tpu.compile_cache import enable_compile_cache
     from graphmine_tpu.obs.spans import Tracer
+
+    enable_compile_cache()
 
     # Records stream to --metrics-out AS EMITTED (MetricsSink.emit), not
     # only at exit: a preemption or OOM-kill skips every finally block,
@@ -1508,9 +1517,6 @@ def main(argv=None) -> None:
     from graphmine_tpu.pipeline.config import parse_args
 
     config = parse_args(argv)  # --help / bad flags exit before jax loads
-    from graphmine_tpu.compile_cache import enable_compile_cache
-
-    enable_compile_cache()
     result = run_pipeline(config)
     _show(result, config.show)
 

@@ -57,8 +57,9 @@ _SINGLE_BYTES_PER_VERTEX = memmodel.SINGLE_BYTES_PER_VERTEX
 _REPLICATED_BYTES_PER_VERTEX = memmodel.REPLICATED_BYTES_PER_VERTEX
 _RING_BYTES_PER_VERTEX = memmodel.RING_BYTES_PER_VERTEX
 
-# Default HBM per device: 16 GiB (TPU v5e, the measured chip of
-# DESIGN.md). Overridable per-process for other parts/CPU testing.
+# HBM assumed for a device that reports no limit — the CPU backend of the
+# tests (16 GiB, a TPU v5e's). A TPU is budgeted by what it reports
+# (driver.device_hbm_bytes) or by GRAPHMINE_HBM_BYTES, never by this.
 _DEFAULT_HBM = 16 * (1 << 30)
 # Plan against 90% of physical HBM: XLA's own workspace + fragmentation.
 _HBM_HEADROOM = 0.9
@@ -110,7 +111,9 @@ def hbm_bytes_per_device(device_bytes=None) -> int:
     explicit budget overrides) → ``device_bytes`` (the caller's measured
     ``memory_stats()["bytes_limit"]`` as an int, or a zero-arg callable
     producing it lazily — the driver passes ``device_hbm_bytes`` itself,
-    queried only when the env var did not win) → the 16 GiB v5e default.
+    queried only when the env var did not win; it raises for a TPU that
+    reports nothing) → the 16 GiB default, which is left only to a
+    backend that reports no limit at all: the CPU test meshes.
     This function never imports jax itself — callers planning host-side
     stay device-free; a v4 (32 GiB) or v5p (95 GiB) part is budgeted
     correctly exactly when the caller passes what the runtime reports."""

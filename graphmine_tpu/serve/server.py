@@ -340,6 +340,9 @@ class SnapshotServer:
         profilez_dir: str | None = None,
         writer_shards: int | None = None,
     ):
+        from graphmine_tpu.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
         self.store = store
         self.sink = sink
         self.prom_out = prom_out

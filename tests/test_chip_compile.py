@@ -1,0 +1,160 @@
+"""Compiles of the main path's programs for a TPU v5e that is described,
+not attached (the chip's own compiler, no chip): what it refuses here it
+would refuse on the chip — tile misalignment, VMEM or HBM overflow, a
+program it cannot partition — which the CPU backend and the Pallas
+interpreter never show. Nothing runs, so nothing here is a result or a
+time. The full-size (2^18 vertices / 25 M edges) compiles take minutes
+and belong to the no-chip rehearsal before a chip call, not to tier-1.
+
+Everything built from the topology is built inside fixtures or tests of
+THIS file: only one process may hold libtpu, and under pytest-xdist every
+worker imports every test module.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        described = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip can be written to the
+    # persistent cache but not read back without one: keep it off here
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield described
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def planted():
+    from graphmine_tpu.datasets import planted_anomaly_graph
+
+    v = 1 << 16
+    src, dst, _, _ = planted_anomaly_graph(v, 1_000_000, seed=0)
+    return src, dst, v
+
+
+@pytest.fixture(scope="module")
+def fused_plan(planted):
+    from graphmine_tpu.ops.bucketed_mode import build_graph_and_plan
+
+    src, dst, v = planted
+    return build_graph_and_plan(src, dst, num_vertices=v)
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _compile(fn, *args, **kwargs):
+    compiled = fn.lower(*args, **kwargs).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 15 << 30
+    return compiled
+
+
+@pytest.mark.parametrize("impl, k", [("pallas", 8), ("xla", 128)])
+def test_knn_compiles_for_v5e(one_chip, impl, k):
+    from graphmine_tpu.ops.knn import _knn_xla
+    from graphmine_tpu.pallas_kernels.knn_pallas import knn_pallas
+
+    points = jax.ShapeDtypeStruct((65536, 8), jnp.float32, sharding=one_chip)
+    compiled = _compile(knn_pallas if impl == "pallas" else _knn_xla, points, k=k)
+    assert ("tpu_custom_call" in compiled.as_text()) == (impl == "pallas")
+
+
+def test_lpa_superstep_bucketed_compiles_for_v5e(one_chip, fused_plan, planted):
+    from graphmine_tpu.ops.bucketed_mode import lpa_superstep_bucketed
+
+    graph, plan = fused_plan
+    labels = jax.ShapeDtypeStruct((planted[2],), jnp.int32, sharding=one_chip)
+    _compile(
+        jax.jit(lpa_superstep_bucketed), labels,
+        _shapes(graph, one_chip), _shapes(plan, one_chip),
+    )
+
+
+def test_cc_superstep_bucketed_compiles_for_v5e(one_chip, fused_plan, planted):
+    from graphmine_tpu.ops.cc import cc_superstep_bucketed
+
+    _, plan = fused_plan
+    labels = jax.ShapeDtypeStruct((planted[2],), jnp.int32, sharding=one_chip)
+    _compile(jax.jit(cc_superstep_bucketed), labels, _shapes(plan, one_chip))
+
+
+def test_query_engine_gather_compiles_for_v5e(one_chip):
+    """The served batched read: the engine's own jitted gather, at the
+    smoke's table width and its largest batch bucket."""
+    from graphmine_tpu.serve.query import QueryEngine
+    from graphmine_tpu.serve.snapshot import Snapshot
+
+    tiny = Snapshot(
+        arrays={
+            "src": np.array([0, 1], np.int32), "dst": np.array([1, 2], np.int32),
+            "labels": np.zeros(3, np.int32),
+        },
+        meta={"version": 1},
+    )
+    v = 1 << 18
+    _compile(
+        QueryEngine(tiny)._gather,
+        jax.ShapeDtypeStruct((3, v), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((v,), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1024,), jnp.int32, sharding=one_chip),
+    )
+
+
+def test_sharded_lpa_superstep_compiles_for_four_chips(topo, planted):
+    """One replicated-schedule LPA superstep over a 4-device mesh of the
+    described chips. ``shard_graph_arrays`` would place arrays, and a
+    described device can hold none: the jitted inner program gets the
+    host partition's shapes with the shardings it would have placed."""
+    from graphmine_tpu.graph.container import build_graph
+    from graphmine_tpu.parallel.mesh import make_mesh
+    from graphmine_tpu.parallel.sharded import (
+        _sharded_lpa_jit,
+        _vertex_axes,
+        partition_graph,
+    )
+
+    src, dst, v = planted
+    mesh = make_mesh(4, devices=topo.devices)
+    axes = _vertex_axes(mesh)
+    sg = partition_graph(
+        build_graph(src, dst, num_vertices=v, to_device=False),
+        mesh=mesh, build_bucket_plan=True,
+    )
+    sg = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            np.shape(a), a.dtype,
+            sharding=NamedSharding(mesh, P(axes, *[None] * (np.ndim(a) - 1))),
+        ),
+        sg,
+    )
+    compiled = _compile(_sharded_lpa_jit, sg, mesh, 1, None, 0, False)
+    assert "all-gather" in compiled.as_text()

@@ -1,7 +1,6 @@
 """Native C++ graph builder vs the NumPy fallback (parity + robustness)."""
 
 import os
-import subprocess
 
 import numpy as np
 import pytest
@@ -14,11 +13,34 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture(scope="module", autouse=True)
 def built_lib():
-    if not native.available():
-        subprocess.run(["make", "-C", os.path.join(REPO, "native")], check=True)
-        native._LIB_TRIED = False  # re-probe after build
-    if not native.available():
-        pytest.skip("native lib unavailable")
+    assert native.available()  # builds native/libgraphbuild.so on first use
+
+
+@pytest.mark.parametrize("source_ok", [True, False])
+def test_missing_library_is_rebuilt_or_raises(tmp_path, monkeypatch, source_ok):
+    """A checkout holds the sources, not the .so: first use builds it
+    (``make -C native``); a build that fails raises — the NumPy path is
+    never taken in silence."""
+    native_dir = tmp_path / "native"
+    native_dir.mkdir()
+    for name in ("Makefile", "graph_builder.cpp"):
+        text = open(os.path.join(REPO, "native", name)).read()
+        if name.endswith(".cpp") and not source_ok:
+            text = "this is not C++\n"
+        (native_dir / name).write_text(text)
+    monkeypatch.setattr(native, "_NATIVE_DIR", str(native_dir))
+    monkeypatch.setattr(native, "_LIB", None)
+    monkeypatch.setattr(native, "_LIB_TRIED", False)
+    monkeypatch.delenv("GRAPHMINE_NATIVE_LIB", raising=False)
+    if source_ok:
+        lib = native._lib()
+        assert lib is not None
+        assert lib._name == str(native_dir / "libgraphbuild.so")
+        assert hasattr(lib, "gb_build_message_csr")
+    else:
+        with pytest.raises(RuntimeError, match="building .* failed"):
+            native._lib()
+        assert native._LIB is None and not native._LIB_TRIED
 
 
 def _write(tmp_path, text):

@@ -180,7 +180,7 @@ def test_hbm_precedence_env_device_default(monkeypatch):
 def test_device_hbm_bytes_memory_stats_chain(monkeypatch):
     """The driver's device query: bytes_limit when reported — the MIN
     across all local devices since ISSUE 14 — None on CPU
-    (memory_stats() -> None), None when the runtime raises."""
+    (memory_stats() -> None), None when a non-TPU runtime raises."""
     import jax
 
     from graphmine_tpu.pipeline import driver
@@ -191,7 +191,7 @@ def test_device_hbm_bytes_memory_stats_chain(monkeypatch):
 
         def memory_stats(self):
             if self._raise:
-                raise RuntimeError("tunneled runtime")
+                raise RuntimeError("memory_stats unavailable")
             return self._stats
 
     def fake_devices(*devs):
@@ -221,6 +221,25 @@ def test_device_hbm_bytes_memory_stats_chain(monkeypatch):
         jax, "local_devices", fake_devices(_Dev(raise_=True))
     )
     assert driver.device_hbm_bytes() is None
+
+
+@pytest.mark.parametrize("stats", [None, {"other": 1}, RuntimeError("boom")])
+def test_device_hbm_bytes_tpu_must_report(stats):
+    """A TPU that reports no bytes_limit (or whose memory_stats raises)
+    is an error — never a silent 16 GiB assumption about an unknown
+    part; the default is left to the CPU backend of the tests."""
+    from graphmine_tpu.pipeline.driver import device_hbm_bytes
+
+    class _Tpu:
+        platform = "tpu"
+
+        def memory_stats(self):
+            if isinstance(stats, Exception):
+                raise stats
+            return stats
+
+    with pytest.raises(RuntimeError):
+        device_hbm_bytes([_Tpu()])
 
 
 def test_pipeline_plan_uses_device_reported_hbm(monkeypatch, tmp_path):

@@ -292,12 +292,15 @@ def _roofline_section(records, min_frac: float):
     flagged = 0
     exchange_windows = 0
     for r in timings:
-        frac = float(r.get("achieved_fraction", 0.0) or 0.0)
+        # None: the run's device kind has no roofline anchors (costmodel
+        # .anchored) — nothing to judge the window against, never flagged
+        unanchored = r.get("achieved_fraction") is None
+        frac = float(r.get("achieved_fraction") or 0.0)
         # a window that paid an XLA trace+compile (the ops seams mark
         # it) reads far below model on healthy hardware — report the
         # honest number, but never raise the triage flag on it
         cold = bool(r.get("cold_compile"))
-        below = frac < min_frac and not cold
+        below = frac < min_frac and not cold and not unanchored
         flagged += below
         fam = f"{r.get('family', '?')}/{r.get('variant', '?')}"
         if int(r.get("devices", 1) or 1) > 1:
@@ -305,6 +308,8 @@ def _roofline_section(records, min_frac: float):
         note = ""
         if below:
             note = f"  << below {min_frac:g}x model"
+        elif unanchored:
+            note = "  (no roofline anchors for this device kind)"
         elif cold and frac < min_frac:
             note = "  (window includes XLA compile — not flagged)"
         # exchange column (ISSUE 15): the model's exchange share of the
@@ -328,7 +333,8 @@ def _roofline_section(records, min_frac: float):
             f"  {r.get('window', '?'):>3}  {fam:<17}"
             f"  {int(r.get('edges_per_sec_per_chip', 0) or 0):>13,}"
             f"  {int(r.get('predicted_edges_per_sec_per_chip', 0) or 0):>14,}"
-            f"  {frac:>5.2f}  {exch_col}{note}"
+            f"  {'  n/a' if unanchored else format(frac, '>5.2f')}"
+            f"  {exch_col}{note}"
         )
         if split:
             out.append(split)

@@ -47,9 +47,8 @@
 #                  observability suite (tests/test_costmodel.py: the
 #                  analytical cost model exact against hand-computed
 #                  plans, superstep_timing achieved-vs-model e2e,
-#                  bench_diff gate + the trajectory self-check over the
-#                  committed BENCH_r01–r05 files, bench.py
-#                  --list-missing) — the fast slice when iterating on
+#                  bench_diff gate on synthetic BENCH files) — the
+#                  fast slice when iterating on
 #                  obs/costmodel.py or tools/bench_diff.py
 #   --faults-only  run just the `faults`-marked recovery suite — the fast
 #                  pre-commit loop when iterating on resilience paths

@@ -22,8 +22,9 @@ small enough to generate in-tool. The reference has no recovery story at
 all (``persist()`` at ``Graphframes.py:82`` is in-memory caching);
 SURVEY §5 names checkpoint/resume as the failure-recovery subsystem.
 
-Prints ONE JSON line; exit 0 iff labels match bit-exactly. Run on a live
-TPU window (scrubbed-CPU runs prove only the CPU path again):
+Prints ONE JSON line; exit 0 iff labels match bit-exactly. Run on the
+machine with the chip (a CPU run proves only the CPU path again); the
+children take the chip one after another, the parent never does:
 
     python tools/tpu_resume_check.py
 """
@@ -66,9 +67,18 @@ def _make_dataset(tmp: str) -> str:
     return path
 
 
+# The chip belongs to one process at a time, so this parent never touches
+# jax: each child names its device on its first stdout line, then runs the
+# pipeline CLI's own main() on its arguments.
+_REPORT_DEVICE_THEN_RUN = (
+    "import sys, jax; print(jax.devices()[0], flush=True); "
+    "from graphmine_tpu.pipeline.driver import main; main(sys.argv[1:])"
+)
+
+
 def _cli(data: str, ckpt_dir: str, resume: bool = False) -> list[str]:
     argv = [
-        sys.executable, "-m", "graphmine_tpu.pipeline",
+        sys.executable, "-c", _REPORT_DEVICE_THEN_RUN,
         "--data-path", data,
         "--batch-rows", "4000000",
         "--max-iter", str(MAX_ITER),
@@ -105,9 +115,6 @@ def _load_ckpt(ckpt_dir: str):
 
 
 def main() -> int:
-    import jax
-
-    device = str(jax.devices()[0])
     tmp = tempfile.mkdtemp(prefix="graphmine_resume_")
     try:
         data = _make_dataset(tmp)
@@ -115,10 +122,11 @@ def main() -> int:
 
         # 1. fresh straight-through run
         t0 = time.perf_counter()
-        subprocess.run(
+        fresh = subprocess.run(
             _cli(data, dirs["fresh"]), check=True, cwd=_REPO,
-            stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
+            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
         )
+        device = fresh.stdout.splitlines()[0]
         fresh_s = time.perf_counter() - t0
         want, it = _load_ckpt(dirs["fresh"])
         assert it == MAX_ITER, it
