@@ -20,6 +20,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from graphmine_tpu.io.factorize import factorize
+from graphmine_tpu.obs.spans import stage_span
 
 
 @dataclass
@@ -156,7 +157,9 @@ def _column_codes(col, interner):
     ).astype(np.int32, copy=False)
 
 
-def load_parquet_edges(path: str, batch_rows: int | None = None) -> EdgeTable:
+def load_parquet_edges(
+    path: str, batch_rows: int | None = None, sink=None
+) -> EdgeTable:
     """Read a parquet file/dir/glob of outlinks and build the edge table.
 
     Parity with ``Graphframes.py:16-30``: glob support, null-domain filter
@@ -174,9 +177,14 @@ def load_parquet_edges(path: str, batch_rows: int | None = None) -> EdgeTable:
     so raw id values differ from the bulk path. Names and name-keyed edges
     (with multiplicity) are identical; LPA partitions can differ on mode
     *ties*, whose smallest-label rule reads the id assignment.
+
+    ``sink``: optional MetricsSink; each batch's decode (parquet to
+    Arrow, null filter) and intern (ids through the interner), and the
+    final concatenation, are then the stage spans ``ingest_decode``,
+    ``ingest_intern`` and ``ingest_concat``.
     """
     if batch_rows is not None:
-        return _load_parquet_edges_streaming(path, batch_rows)
+        return _load_parquet_edges_streaming(path, batch_rows, sink)
     import pyarrow as pa
     import pyarrow.compute as pc
     import pyarrow.parquet as pq
@@ -184,26 +192,34 @@ def load_parquet_edges(path: str, batch_rows: int | None = None) -> EdgeTable:
     from graphmine_tpu.io.factorize import IncrementalFactorizer
 
     paths = _resolve_paths(path)
-    tables = [
-        pq.read_table(p, columns=["_c1", "_c2"],
-                      read_dictionary=["_c1", "_c2"])
-        for p in paths
-    ]
-    try:
-        table = pa.concat_tables(tables, promote_options="permissive")
-    except TypeError:
-        # pyarrow < 14 has no promote_options; promote=True is the same
-        # permissive schema unification there (ADVICE r5: don't fail a
-        # previously-working path on older environments)
-        table = pa.concat_tables(tables, promote=True)
-    num_rows_raw = table.num_rows
-    valid = pc.and_(pc.is_valid(table.column("_c1")), pc.is_valid(table.column("_c2")))
-    table = table.filter(valid)  # Graphframes.py:30 null-domain filter
+    with stage_span(sink, "ingest_decode", batch=0) as stage:
+        tables = [
+            pq.read_table(p, columns=["_c1", "_c2"],
+                          read_dictionary=["_c1", "_c2"])
+            for p in paths
+        ]
+        try:
+            table = pa.concat_tables(tables, promote_options="permissive")
+        except TypeError:
+            # pyarrow < 14 has no promote_options; promote=True is the
+            # same permissive schema unification there (ADVICE r5: don't
+            # fail a previously-working path on older environments)
+            table = pa.concat_tables(tables, promote=True)
+        num_rows_raw = table.num_rows
+        valid = pc.and_(
+            pc.is_valid(table.column("_c1")), pc.is_valid(table.column("_c2"))
+        )
+        table = table.filter(valid)  # Graphframes.py:30 null-domain filter
+        stage.note(rows=table.num_rows)
     # The interner applied parent-column-first reproduces factorize()'s
     # first-appearance order over concat(parent, child) exactly.
     interner = IncrementalFactorizer()
-    src = _column_codes(table.column("_c1"), interner)
-    dst = _column_codes(table.column("_c2"), interner)
+    with stage_span(
+        sink, "ingest_intern", batch=0, rows=table.num_rows
+    ) as stage:
+        src = _column_codes(table.column("_c1"), interner)
+        dst = _column_codes(table.column("_c2"), interner)
+        stage.note(names_so_far=len(interner))
     et = EdgeTable(
         src=src, dst=dst, names=interner.names(), num_rows_raw=num_rows_raw
     )
@@ -211,12 +227,11 @@ def load_parquet_edges(path: str, batch_rows: int | None = None) -> EdgeTable:
     return _add_quarantine(et, "null_rows", num_rows_raw - table.num_rows)
 
 
-def _load_parquet_edges_streaming(path: str, batch_rows: int) -> EdgeTable:
+def _load_parquet_edges_streaming(
+    path: str, batch_rows: int, sink=None
+) -> EdgeTable:
     """Batched parquet scan + incremental intern; peak host memory is
     O(batch + vocabulary + edges) instead of O(total rows x string size)."""
-    import pyarrow.compute as pc
-    import pyarrow.parquet as pq
-
     from graphmine_tpu.io.factorize import IncrementalFactorizer
 
     if batch_rows <= 0:
@@ -225,21 +240,53 @@ def _load_parquet_edges_streaming(path: str, batch_rows: int) -> EdgeTable:
     src_parts, dst_parts = [], []
     num_rows_raw = 0
     for p in _resolve_paths(path):
-        pf = pq.ParquetFile(p, read_dictionary=["_c1", "_c2"])
-        for batch in pf.iter_batches(batch_size=batch_rows, columns=["_c1", "_c2"]):
-            num_rows_raw += batch.num_rows
+        with stage_span(sink, "ingest_file", file=os.path.basename(p)):
+            num_rows_raw += _intern_parquet_file(
+                p, batch_rows, interner, src_parts, dst_parts, sink
+            )
+    with stage_span(
+        sink, "ingest_concat", batch=len(src_parts), names_so_far=len(interner)
+    ) as stage:
+        et = edge_table_from_parts(
+            src_parts, dst_parts, interner.names(), num_rows_raw
+        )
+        stage.note(rows=et.num_edges)
+    return _add_quarantine(et, "null_rows", num_rows_raw - et.num_edges)
+
+
+def _intern_parquet_file(
+    path: str, batch_rows: int, interner, src_parts, dst_parts, sink
+) -> int:
+    """One file of the streaming scan: every batch decoded, null-filtered
+    and interned onto ``src_parts`` / ``dst_parts``; returns the file's
+    raw row count."""
+    import pyarrow.compute as pc
+    import pyarrow.parquet as pq
+
+    pf = pq.ParquetFile(path, read_dictionary=["_c1", "_c2"])
+    batches = pf.iter_batches(batch_size=batch_rows, columns=["_c1", "_c2"])
+    rows_raw = 0
+    while True:
+        # the iterator decodes a batch when asked for it: the span goes
+        # around the asking (and the last one finds the file at its end)
+        with stage_span(sink, "ingest_decode", batch=len(src_parts)) as stage:
+            batch = next(batches, None)
+            if batch is None:
+                return rows_raw
+            rows_raw += batch.num_rows
             valid = pc.and_(
                 pc.is_valid(batch.column(0)), pc.is_valid(batch.column(1))
             )
             batch = batch.filter(valid)  # Graphframes.py:30 null filter
-            # dictionary-index interning per column (the r5 fast path;
-            # falls back to per-row strings for non-dict storage)
+            stage.note(rows=batch.num_rows)
+        # dictionary-index interning per column (the r5 fast path;
+        # falls back to per-row strings for non-dict storage)
+        with stage_span(
+            sink, "ingest_intern", batch=len(src_parts), rows=batch.num_rows
+        ) as stage:
             src_parts.append(_column_codes(batch.column(0), interner))
             dst_parts.append(_column_codes(batch.column(1), interner))
-    et = edge_table_from_parts(
-        src_parts, dst_parts, interner.names(), num_rows_raw
-    )
-    return _add_quarantine(et, "null_rows", num_rows_raw - et.num_edges)
+            stage.note(names_so_far=len(interner))
 
 
 def _resolve_paths(path: str) -> list[str]:

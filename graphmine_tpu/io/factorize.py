@@ -101,5 +101,8 @@ class IncrementalFactorizer:
             lut[i] = code
         return lut[codes_batch].astype(np.int32)
 
+    def __len__(self) -> int:
+        return len(self._names)
+
     def names(self) -> np.ndarray:
         return np.asarray(self._names, dtype=object)

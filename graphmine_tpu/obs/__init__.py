@@ -5,7 +5,7 @@ Stdlib-only leaf package — safe to import from anywhere in the pipeline
 builds on it, not the other way around):
 
 - :mod:`graphmine_tpu.obs.spans`      hierarchical span context
-  (run_id -> phase -> rung -> superstep) with monotonic timings;
+  (run_id -> phase -> rung -> stage / superstep) with monotonic timings;
 - :mod:`graphmine_tpu.obs.registry`   counter/gauge/histogram registry
   with a Prometheus exporter (textfile or the serve layer's live
   ``GET /metrics``);
@@ -25,6 +25,10 @@ builds on it, not the other way around):
   (ISSUE 14): per-plan HBM footprint inventories, the byte seeds the
   pipeline planner derives its schedule model from, the ``mem``
   sub-record builder and the ``memory_watermark`` emission;
+- :mod:`graphmine_tpu.obs.devtrace`   the reduction of one
+  ``profile_dir`` capture: device seconds by named scope, program and
+  program span, busy/idle per chapter (a wire reader of its own for the
+  profiler's file; no jax);
 - :mod:`graphmine_tpu.obs.sketch`     mergeable quantile sketches over
   fixed log ladders (the ``Histogram.merge`` contract applied to LOF
   scores and community sizes) + the PSI drift distance;

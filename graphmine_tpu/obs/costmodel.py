@@ -577,10 +577,10 @@ def emit_superstep_timing(
     the labels-changed counter) — this adds zero device syncs.
 
     ``cold_compile=True`` marks a window whose wall time includes an XLA
-    trace+compile (the ops fixpoint seams detect it via the jit cache —
-    :func:`timed_fixpoint`): the record still ships the honest numbers,
-    but obs_report's roofline section excludes such windows from the
-    below-model flag — a compile-bearing window reading 0.05x model on
+    trace+compile (the ops fixpoint seams detect it from the process's
+    compile counter — :func:`timed_fixpoint`): the record still ships
+    the honest numbers, but obs_report's roofline section excludes such
+    windows from the below-model flag — a compile-bearing window reading 0.05x model on
     healthy hardware is exactly the false positive the flag must not
     raise. (The driver-side windows need no marker: like its watchdog,
     the driver excludes each operating point's compile-bearing first
@@ -696,7 +696,23 @@ class WindowTimer:
         return rec
 
 
-def timed_fixpoint(fn, jit_fn=None):
+# Backend compiles this process has seen: incremented by the one
+# jax.monitoring listener (pipeline/metrics.py installs it when a sink is
+# made), read by timed_fixpoint. A plain integer here so that obs/ stays
+# jax-free; without the listener it never moves and no window is marked.
+_backend_compiles = 0
+
+
+def note_backend_compile() -> None:
+    global _backend_compiles
+    _backend_compiles += 1
+
+
+def backend_compiles() -> int:
+    return _backend_compiles
+
+
+def timed_fixpoint(fn):
     """``(result, seconds, cold_compile)`` with the result's device work
     completed — shared by the ops-layer fixpoint wrappers (cc/pagerank/
     LPA auto seams) so a jitted while_loop's wall time covers the actual
@@ -704,14 +720,11 @@ def timed_fixpoint(fn, jit_fn=None):
     whose first element is one; duck-typed so this module stays
     jax-free.
 
-    ``jit_fn``: the underlying jitted callable — when its executable
-    cache grew across the call, this window paid an XLA trace+compile
-    and ``cold_compile`` comes back True (the caller stamps it on the
-    timing record so the roofline flag skips the window). Detection is
-    best-effort via the private ``_cache_size`` probe: absent the probe,
-    windows are reported un-marked rather than guessed at."""
-    probe = getattr(jit_fn, "_cache_size", None)
-    before = probe() if callable(probe) else None
+    ``cold_compile`` is True when the process compiled (or loaded from
+    the persistent cache) any program during the call: the window paid
+    for more than its supersteps, and the caller stamps that on the
+    timing record so the roofline flag skips it."""
+    before = backend_compiles()
     t0 = time.perf_counter()
     out = fn()
     head = out[0] if isinstance(out, tuple) else out
@@ -719,5 +732,4 @@ def timed_fixpoint(fn, jit_fn=None):
     if block is not None:
         block()
     seconds = time.perf_counter() - t0
-    cold = before is not None and probe() > before
-    return out, seconds, cold
+    return out, seconds, backend_compiles() > before

@@ -42,6 +42,22 @@ register("run_end", "ok")
 register("span", "name", "seconds", "status")
 register("heartbeat", "uptime_s")
 register("profile_capture", "dir", "ok")
+# compile: three per compiled program (its outermost trace, its
+# lowering, its backend stage) from the process-wide jax.monitoring
+# listener of pipeline/metrics.py, stamped with the span open on the
+# compiling thread; `cache_hit` is
+# the persistent-cache verdict of a backend stage (None for the other
+# two, which the cache never serves).
+register("compile", "stage", "fun_name", "seconds", "cache_hit")
+# device_scope / device_idle: the reduction of one profile_dir capture
+# (obs/devtrace.py), emitted by maybe_profile before profile_capture.
+# device_scope: device seconds of the leaf operations grouped by the
+# first two DEVICE_SCOPES levels of their op_name, by compiled program
+# (`module`) and by the innermost program span that was open when the
+# operation started. device_idle: busy/idle device seconds inside one
+# chapter span (a direct child of the run's root span).
+register("device_scope", "module", "scope", "device_seconds", "events")
+register("device_idle", "busy_seconds", "idle_seconds")
 
 # ---- pipeline phases (timed records carry `seconds`) ----------------------
 register("load", "seconds")
@@ -262,6 +278,37 @@ RECOVERY_PHASES = frozenset((
     "repair_fallback", "delta_shed", "breaker_transition",
     "fleet_degraded", "wal_replay", "writer_promote", "publish_fenced",
     "shard_degraded",
+))
+
+
+# Every ``jax.named_scope`` literal of the package, once: the names the
+# device timeline carries in each operation's ``op_name``. Two levels are
+# stable across graphs and refactors — an outer scope per algorithm x
+# plan family, an inner scope per pass over memory — and the degree
+# class ``w<width>`` (the one computed name) may follow as a third.
+# ``obs/devtrace.py`` groups device seconds by the first two registered
+# names of an op_name; what carries none is booked as ``unscoped``.
+# ``tests/test_trace.py`` holds the package to this list both ways.
+DEVICE_SCOPES = frozenset((
+    # outer: algorithm x family
+    "lpa_blocked", "cc_blocked", "lpa_bucketed", "cc_bucketed",
+    "lpa_sort", "cc_sort", "masked_lpa", "superstep", "census",
+    "modularity", "features", "triangles", "ivf", "knn_exact",
+    "knn_cross", "lof",
+    # inner: superstep passes
+    "bin_gather", "bin_scatter", "row_gather", "row_mode", "row_min",
+    "hist", "write_back", "pointer_jump", "msg_gather", "segment_mode",
+    "segment_min", "sort", "run_reduce", "mask",
+    "changed_count", "converged",
+    # inner: census / modularity
+    "sizes", "edge_counts", "q",
+    # inner: features / triangles
+    "degrees", "neighbor_stats", "distinct_communities", "stack",
+    "bsearch", "count",
+    # inner: kNN / IVF / LOF
+    "distance", "topk", "assign", "lloyd_update", "search_gather",
+    "search_distance", "search_topk", "merge_gather", "merge_topk",
+    "reach", "lrd", "score",
 ))
 
 

@@ -269,3 +269,46 @@ def xla_annotation(name: str):
         return jax.profiler.TraceAnnotation(name)
     except Exception:
         return contextlib.nullcontext()
+
+
+class _Stage:
+    """What :func:`stage_span` yields: the two things a stage does to
+    its own span. Without a sink both do nothing, so an ops function
+    called with ``sink=None`` records nothing and syncs nothing."""
+
+    def __init__(self, sink=None):
+        self._sink = sink
+
+    def note(self, **attrs) -> None:
+        """Counts that explain the seconds, set on the open span."""
+        if self._sink is not None:
+            self._sink.span_attrs(**attrs)
+
+    def sync(self, out):
+        """Wait for the stage's device outputs (duck-typed
+        ``block_until_ready`` on ``out`` or on each element of a tuple
+        or list of them) and hand ``out`` back: a stage span closes
+        only when its device work is done, or its seconds would be the
+        next stage's."""
+        if self._sink is not None:
+            for leaf in out if isinstance(out, (tuple, list)) else (out,):
+                block = getattr(leaf, "block_until_ready", None)
+                if block is not None:
+                    block()
+        return out
+
+
+_NO_STAGE = _Stage()
+
+
+@contextlib.contextmanager
+def stage_span(sink, name: str, **attrs):
+    """A stage inside a chapter, where host and device alternate:
+    ``sink.span(name, **attrs)`` (a ``span`` record at close and a
+    ``TraceAnnotation`` on the profiler's clock) yielding a
+    :class:`_Stage`. ``sink=None`` yields the stage that does nothing."""
+    if sink is None:
+        yield _NO_STAGE
+        return
+    with sink.span(name, **attrs):
+        yield _Stage(sink)

@@ -41,12 +41,14 @@ The reference has no kNN at all; this extends the north-star scorer
 from __future__ import annotations
 
 from functools import partial
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from graphmine_tpu.obs.spans import stage_span
 from graphmine_tpu.ops.knn import cross_knn
 
 
@@ -69,34 +71,36 @@ def _assign_tiled(points: jax.Array, centers: jax.Array) -> jax.Array:
     (one matmul + argmin per tile — no top_k machinery; C is small)."""
     n = points.shape[0]
     n_pad = -(-n // _ASSIGN_TILE) * _ASSIGN_TILE
-    tiles = jnp.pad(points, ((0, n_pad - n), (0, 0))).reshape(
-        n_pad // _ASSIGN_TILE, _ASSIGN_TILE, -1
-    )
-    c_sq = jnp.sum(centers * centers, axis=1)
-
-    def tile(p):
-        cross = lax.dot_general(
-            p, centers, dimension_numbers=(((1,), (1,)), ((), ())),
-            precision=lax.Precision.HIGHEST,
+    with jax.named_scope("ivf"), jax.named_scope("assign"):
+        tiles = jnp.pad(points, ((0, n_pad - n), (0, 0))).reshape(
+            n_pad // _ASSIGN_TILE, _ASSIGN_TILE, -1
         )
-        # |p|^2 is constant per row — argmin doesn't need it
-        return jnp.argmin(c_sq[None, :] - 2.0 * cross, axis=1)
+        c_sq = jnp.sum(centers * centers, axis=1)
 
-    return lax.map(tile, tiles).reshape(n_pad)[:n].astype(jnp.int32)
+        def tile(p):
+            cross = lax.dot_general(
+                p, centers, dimension_numbers=(((1,), (1,)), ((), ())),
+                precision=lax.Precision.HIGHEST,
+            )
+            # |p|^2 is constant per row — argmin doesn't need it
+            return jnp.argmin(c_sq[None, :] - 2.0 * cross, axis=1)
+
+        return lax.map(tile, tiles).reshape(n_pad)[:n].astype(jnp.int32)
 
 
 @jax.jit
 def _lloyd_step(points: jax.Array, centers: jax.Array) -> jax.Array:
     a = _assign_tiled(points, centers)
     c = centers.shape[0]
-    sums = jax.ops.segment_sum(points, a, num_segments=c)
-    counts = jax.ops.segment_sum(
-        jnp.ones((points.shape[0],), jnp.float32), a, num_segments=c
-    )
-    return jnp.where(
-        counts[:, None] > 0, sums / jnp.maximum(counts, 1.0)[:, None],
-        centers,
-    )
+    with jax.named_scope("ivf"), jax.named_scope("lloyd_update"):
+        sums = jax.ops.segment_sum(points, a, num_segments=c)
+        counts = jax.ops.segment_sum(
+            jnp.ones((points.shape[0],), jnp.float32), a, num_segments=c
+        )
+        return jnp.where(
+            counts[:, None] > 0, sums / jnp.maximum(counts, 1.0)[:, None],
+            centers,
+        )
 
 
 def kmeans(points, n_clusters: int, iters: int = 5, seed: int = 0):
@@ -124,20 +128,23 @@ def _search_clusters(q_vec, q_gid, m_vec, m_gid, m_valid, k: int):
     """One cluster's block: exact distances from its padded query batch
     to its padded member list, masked top-k. Shapes: q_vec [Qmax, F],
     m_vec [Lmax, F]; returns ([Qmax, k] d2 asc, [Qmax, k] global ids)."""
-    cross = lax.dot_general(
-        q_vec, m_vec, dimension_numbers=(((1,), (1,)), ((), ())),
-        precision=lax.Precision.HIGHEST,  # the r4 MXU bf16 lesson
-    )
-    d2 = (
-        jnp.sum(q_vec * q_vec, axis=1)[:, None]
-        - 2.0 * cross
-        + jnp.sum(m_vec * m_vec, axis=1)[None, :]
-    )
-    d2 = jnp.maximum(d2, 0.0)
-    d2 = jnp.where(~m_valid[None, :], jnp.inf, d2)
-    d2 = jnp.where(q_gid[:, None] == m_gid[None, :], jnp.inf, d2)  # self
-    neg, j = lax.top_k(-d2, k)
-    return -neg, m_gid[j]
+    with jax.named_scope("ivf"):
+        with jax.named_scope("search_distance"):
+            cross = lax.dot_general(
+                q_vec, m_vec, dimension_numbers=(((1,), (1,)), ((), ())),
+                precision=lax.Precision.HIGHEST,  # the r4 MXU bf16 lesson
+            )
+            d2 = (
+                jnp.sum(q_vec * q_vec, axis=1)[:, None]
+                - 2.0 * cross
+                + jnp.sum(m_vec * m_vec, axis=1)[None, :]
+            )
+            d2 = jnp.maximum(d2, 0.0)
+            d2 = jnp.where(~m_valid[None, :], jnp.inf, d2)
+            d2 = jnp.where(q_gid[:, None] == m_gid[None, :], jnp.inf, d2)  # self
+        with jax.named_scope("search_topk"):
+            neg, j = lax.top_k(-d2, k)
+            return -neg, m_gid[j]
 
 
 def _search_chunks(pts, m_gid, m_valid, q_gid, row_sub, k: int):
@@ -153,10 +160,10 @@ def _search_chunks(pts, m_gid, m_valid, q_gid, row_sub, k: int):
 
     def one_chunk(args):
         qg, s = args
-        mg = m_gid_dev[s]
-        return _search_clusters(
-            pts_dev[qg], qg, pts_dev[mg], mg, m_valid_dev[s], k
-        )
+        with jax.named_scope("ivf"), jax.named_scope("search_gather"):
+            mg = m_gid_dev[s]
+            q_vec, m_vec, valid = pts_dev[qg], pts_dev[mg], m_valid_dev[s]
+        return _search_clusters(q_vec, qg, m_vec, mg, valid, k)
 
     return lax.map(one_chunk, (jnp.asarray(q_gid), jnp.asarray(row_sub)))
 
@@ -178,7 +185,17 @@ def _exact_fallback(pts, k, guard: str, detail: str, sink):
     )
     if sink is not None:
         sink.emit("ivf_fallback", guard=guard, detail=detail)
-    return exact_knn(pts, k, impl="auto")
+    with stage_span(sink, "knn_exact", n=len(pts), k=k) as stage:
+        return stage.sync(exact_knn(pts, k, impl="auto"))
+
+
+class _GuardTripped(Exception):
+    """An IVF pathology guard fired while the inverted lists were being
+    built: carries what :func:`_exact_fallback` reports."""
+
+    def __init__(self, guard: str, detail: str):
+        super().__init__(guard, detail)
+        self.guard, self.detail = guard, detail
 
 
 def ivf_knn(
@@ -242,17 +259,103 @@ def ivf_knn(
     if n < 4 * n_clusters:
         # documented sizing fallback, not a pathology guard: tiny clouds
         # route to the exact path by design, no warning
-        return exact_knn(pts, k, impl="auto")
+        with stage_span(sink, "knn_exact", n=n, k=k) as stage:
+            return stage.sync(exact_knn(pts, k, impl="auto"))
 
     if centers is None:
-        centers = kmeans(pts, n_clusters, iters=kmeans_iters, seed=seed)
+        with stage_span(
+            sink, "ivf_train", n=n, k=k, n_clusters=n_clusters
+        ) as stage:
+            centers = stage.sync(
+                kmeans(pts, n_clusters, iters=kmeans_iters, seed=seed)
+            )
     # probe assignment: each query's n_probe nearest centers; column 0
     # is the owning cluster (a point is always a member of its own
     # nearest cluster's list).
-    _, probe = cross_knn(jnp.asarray(pts), centers, n_probe)
-    probe = np.asarray(probe)
-    assign = probe[:, 0]
+    with stage_span(sink, "ivf_probe", n=n, n_clusters=n_clusters):
+        _, probe = cross_knn(jnp.asarray(pts), centers, n_probe)
+        probe = np.asarray(probe)
+    try:
+        with stage_span(sink, "ivf_lists") as stage:
+            lists = _inverted_lists(pts, k, probe, n_clusters, n_probe)
+            stage.note(**lists.counts)
+    except _GuardTripped as tripped:
+        return _exact_fallback(pts, k, tripped.guard, tripped.detail, sink)
 
+    r_rows, chunk_b, p_max = lists.r_rows, lists.chunk_b, lists.p_max
+    exec_fn = search_exec if search_exec is not None else _search_chunks
+    with stage_span(
+        sink, "ivf_search", n_pairs=lists.n_pairs, chunk_rows=r_rows, k=k
+    ) as stage:
+        d2_all, gid_all = stage.sync(exec_fn(
+            pts, lists.m_gid, lists.m_valid, lists.q_gid, lists.row_sub, k
+        ))
+    # the host half of the merge: ivf_lists again. The two concatenates
+    # it dispatches run on the device while the host builds the take
+    # table, so this span does not wait for them (ivf_merge does).
+    with stage_span(sink, "ivf_lists", p_max=p_max):
+        if d2_all.shape[0] < r_rows or d2_all.shape != (
+            d2_all.shape[0], chunk_b, k
+        ) or gid_all.shape != d2_all.shape:
+            # a short/misshapen executor result would otherwise clamp real
+            # pair indices onto the junk row in the merge gather — degraded
+            # results with no error. Fail loudly instead.
+            raise ValueError(
+                f"search_exec returned shapes {tuple(d2_all.shape)}/"
+                f"{tuple(gid_all.shape)}; expected [R'>= {r_rows}, "
+                f"{chunk_b}, {k}] with extra rows appended at the end"
+            )
+        # [R', B, k] -> per-pair rows -> tiled [T, p_max * k] merges (one
+        # monolithic [N, p_max * k] gather + top_k would hold ~4 GB of
+        # merge operands at 262K x 16 x 128). Queries with fewer than
+        # p_max pairs pad with the appended all-inf junk row: never
+        # selected. The slice to r_rows * chunk_b drops any
+        # executor-padded chunk rows (a mesh executor pads R to a
+        # device-count multiple) AND pins the junk-row sentinel id below
+        # at the same flat index either way.
+        d2_flat = jnp.concatenate(
+            [d2_all.reshape(-1, k)[: r_rows * chunk_b],
+             jnp.full((1, k), jnp.inf, d2_all.dtype)]
+        )
+        gid_flat = jnp.concatenate(
+            [gid_all.reshape(-1, k)[: r_rows * chunk_b],
+             jnp.full((1, k), -1, jnp.int32)]
+        )
+        junk = r_rows * chunk_b
+        merge_t = 16384
+        n_pad = -(-n // merge_t) * merge_t
+        take = np.full((n_pad, p_max), junk, np.int64)
+        pairs_per_q = lists.pairs_per_q
+        pair_col = (
+            np.arange(lists.n_pairs)
+            - np.repeat(np.cumsum(pairs_per_q) - pairs_per_q, pairs_per_q)
+        )
+        take[lists.pair_q, pair_col] = lists.slot_of_pair
+        # Explicit int32, not an implicit jnp downcast: the bound above
+        # guarantees every row id (junk sentinel included) fits, and the
+        # cast states the invariant instead of relying on x64-mode
+        # defaults.
+        take = take.astype(np.int32).reshape(n_pad // merge_t, merge_t, p_max)
+
+    # NB: the flat result arrays are jit ARGUMENTS, not closure captures
+    # — a closed-over concrete array is baked into the HLO as a constant,
+    # and serializing the ~GB-scale [R * B, k] buffers hung XLA:TPU
+    # compilation for minutes (found the hard way, r5).
+    with stage_span(sink, "ivf_merge", n=n, k=k, p_max=p_max) as stage:
+        d2_out, gid_out = _merge_tiles(d2_flat, gid_flat, jnp.asarray(take), k)
+        return stage.sync((
+            d2_out.reshape(n_pad, k)[:n],
+            gid_out.reshape(n_pad, k)[:n],
+        ))
+
+
+def _inverted_lists(pts, k: int, probe, n_clusters: int, n_probe: int):
+    """Host side of the index, all NumPy: from the fetched probe table
+    to the member and query tables the search executor takes and the
+    pair bookkeeping the merge needs. Raises :class:`_GuardTripped` when
+    a pathology guard fires."""
+    n = len(pts)
+    assign = probe[:, 0]
     # ---- host: SIZE-CAPPED inverted sublists ---------------------------
     # k-means on clustered data skews hard (one blob -> one giant
     # cluster); an uncapped member matrix sets Lmax = that cluster's
@@ -279,9 +382,9 @@ def ivf_knn(
     if k >= sizes.max():
         # no cluster can fill its own top-k; recall craters — the honest
         # move is the exact path.
-        return _exact_fallback(
-            pts, k, "k_unfillable",
-            f"k={k} >= largest cluster size {int(sizes.max())}", sink,
+        raise _GuardTripped(
+            "k_unfillable",
+            f"k={k} >= largest cluster size {int(sizes.max())}",
         )
     # member id matrix [n_sub, Lmax] (clamps keep empty sublists
     # in-bounds; their rows are fully masked)
@@ -316,17 +419,17 @@ def ivf_knn(
     #    side. IVF has nothing to exploit on such a cloud anyway.
     probed_sizes = sizes[probe].sum(axis=1)       # members across probes
     if int(probed_sizes.min()) < k + 1:
-        return _exact_fallback(
-            pts, k, "capacity",
+        raise _GuardTripped(
+            "capacity",
             f"a query's probed clusters hold {int(probed_sizes.min())} "
-            f"members < k+1={k + 1} (its top-k cannot fill)", sink,
+            f"members < k+1={k + 1} (its top-k cannot fill)",
         )
     if p_max > 4 * n_probe:
-        return _exact_fallback(
-            pts, k, "skew",
+        raise _GuardTripped(
+            "skew",
             f"probe expansion {p_max} sublists/query > 4*n_probe="
             f"{4 * n_probe} (one dominant cluster; IVF has no structure "
-            "to exploit)", sink,
+            "to exploit)",
         )
     pair_q = np.repeat(
         np.arange(n, dtype=np.int64), pairs_per_q
@@ -356,10 +459,10 @@ def ivf_knn(
     # past 2^31-1 would wrap to a junk gather instead of failing. The
     # junk-row sentinel id r_rows * chunk_b is the largest value stored.
     if r_rows * chunk_b >= (1 << 31):
-        return _exact_fallback(
-            pts, k, "index_bound",
+        raise _GuardTripped(
+            "index_bound",
             f"merge-gather row ids reach {r_rows * chunk_b:,} >= 2^31 "
-            "(int32 device gather would wrap)", sink,
+            "(int32 device gather would wrap)",
         )
     row_sub = np.repeat(np.arange(n_sub), chunks_per_s)
     chunk_rank = (
@@ -386,61 +489,13 @@ def ivf_knn(
     slot_of_pair[pair_order] = np.arange(
         r_rows * chunk_b
     ).reshape(r_rows, chunk_b)[q_valid]
-
-    exec_fn = search_exec if search_exec is not None else _search_chunks
-    d2_all, gid_all = exec_fn(
-        pts, m_gid, m_valid, q_gid, row_sub.astype(np.int32), k
-    )
-    if d2_all.shape[0] < r_rows or d2_all.shape != (
-        d2_all.shape[0], chunk_b, k
-    ) or gid_all.shape != d2_all.shape:
-        # a short/misshapen executor result would otherwise clamp real
-        # pair indices onto the junk row in the merge gather — degraded
-        # results with no error. Fail loudly instead.
-        raise ValueError(
-            f"search_exec returned shapes {tuple(d2_all.shape)}/"
-            f"{tuple(gid_all.shape)}; expected [R'>= {r_rows}, "
-            f"{chunk_b}, {k}] with extra rows appended at the end"
-        )
-    # [R', B, k] -> per-pair rows -> tiled [T, p_max * k] merges (one
-    # monolithic [N, p_max * k] gather + top_k would hold ~4 GB of merge
-    # operands at 262K x 16 x 128). Queries with fewer than p_max pairs
-    # pad with the appended all-inf junk row: never selected. The slice
-    # to r_rows * chunk_b drops any executor-padded chunk rows (a mesh
-    # executor pads R to a device-count multiple) AND pins the junk-row
-    # sentinel id below at the same flat index either way.
-    d2_flat = jnp.concatenate(
-        [d2_all.reshape(-1, k)[: r_rows * chunk_b],
-         jnp.full((1, k), jnp.inf, d2_all.dtype)]
-    )
-    gid_flat = jnp.concatenate(
-        [gid_all.reshape(-1, k)[: r_rows * chunk_b],
-         jnp.full((1, k), -1, jnp.int32)]
-    )
-    junk = r_rows * chunk_b
-    merge_t = 16384
-    n_pad = -(-n // merge_t) * merge_t
-    take = np.full((n_pad, p_max), junk, np.int64)
-    pair_col = (
-        np.arange(n_pairs)
-        - np.repeat(np.cumsum(pairs_per_q) - pairs_per_q, pairs_per_q)
-    )
-    take[pair_q, pair_col] = slot_of_pair
-    # Explicit int32, not an implicit jnp downcast: the bound above
-    # guarantees every row id (junk sentinel included) fits, and the cast
-    # states the invariant instead of relying on x64-mode defaults.
-    take_dev = jnp.asarray(
-        take.astype(np.int32).reshape(n_pad // merge_t, merge_t, p_max)
-    )
-
-    # NB: the flat result arrays are jit ARGUMENTS, not closure captures
-    # — a closed-over concrete array is baked into the HLO as a constant,
-    # and serializing the ~GB-scale [R * B, k] buffers hung XLA:TPU
-    # compilation for minutes (found the hard way, r5).
-    d2_out, gid_out = _merge_tiles(d2_flat, gid_flat, take_dev, k)
-    return (
-        d2_out.reshape(n_pad, k)[:n],
-        gid_out.reshape(n_pad, k)[:n],
+    return SimpleNamespace(
+        m_gid=m_gid, m_valid=m_valid, q_gid=q_gid,
+        row_sub=row_sub.astype(np.int32), r_rows=r_rows, chunk_b=chunk_b,
+        p_max=p_max, n_pairs=n_pairs, pair_q=pair_q, pairs_per_q=pairs_per_q,
+        slot_of_pair=slot_of_pair,
+        counts=dict(n_sub=n_sub, l_max=l_max, n_pairs=n_pairs, p_max=p_max,
+                    chunk_rows=r_rows),
     )
 
 
@@ -452,9 +507,12 @@ def _merge_tiles(d2_flat, gid_flat, take_tiles, k: int):
     merge_t, p_max = take_tiles.shape[1], take_tiles.shape[2]
 
     def tile(tk):
-        d2_t = d2_flat[tk].reshape(merge_t, p_max * k)
-        gid_t = gid_flat[tk].reshape(merge_t, p_max * k)
-        neg, sel = lax.top_k(-d2_t, k)
-        return -neg, jnp.take_along_axis(gid_t, sel, axis=1)
+        with jax.named_scope("ivf"):
+            with jax.named_scope("merge_gather"):
+                d2_t = d2_flat[tk].reshape(merge_t, p_max * k)
+                gid_t = gid_flat[tk].reshape(merge_t, p_max * k)
+            with jax.named_scope("merge_topk"):
+                neg, sel = lax.top_k(-d2_t, k)
+                return -neg, jnp.take_along_axis(gid_t, sel, axis=1)
 
     return lax.map(tile, take_tiles)

@@ -65,9 +65,8 @@ import numpy as np
 from graphmine_tpu.graph.container import Graph
 from graphmine_tpu.ops.bucketed_mode import (
     _SENTINEL,
-    _bucket_mode,
-    _bucket_wmode,
     _extend_widths,
+    _row_modes,
 )
 
 # ---- plan-family crossover policy (single owner) ---------------------------
@@ -478,9 +477,11 @@ def _blocked_tile(plan: BlockedPlan, values_pad: jax.Array, fill) -> jax.Array:
     message into its destination bin's tile slot. Unwritten slots (bin
     padding + the reserved sentinel slot) keep ``fill``, which the reduce
     rows rely on (mode/min sentinel, sum identity 0)."""
-    vals = values_pad[plan.src_sorted]
-    tile = jnp.full((plan.tile_alloc,), fill, values_pad.dtype)
-    return tile.at[plan.scatter_pos].set(vals, unique_indices=True)
+    with jax.named_scope("bin_gather"):
+        vals = values_pad[plan.src_sorted]
+    with jax.named_scope("bin_scatter"):
+        tile = jnp.full((plan.tile_alloc,), fill, values_pad.dtype)
+        return tile.at[plan.scatter_pos].set(vals, unique_indices=True)
 
 
 def _check_plan(plan: BlockedPlan, labels: jax.Array, graph: Graph | None):
@@ -518,17 +519,16 @@ def lpa_superstep_blocked(
             "silently dropped"
         )
     _check_plan(plan, labels, graph)
-    lbl_pad = jnp.concatenate(
-        [labels.astype(jnp.int32), jnp.full((1,), _SENTINEL, jnp.int32)]
-    )
-    tile = _blocked_tile(plan, lbl_pad, _SENTINEL)
-    out = labels.astype(jnp.int32)
-    wmats = plan.weight_mat or (None,) * len(plan.row_idx)
-    for ids, ridx, wmat in zip(plan.row_vertex, plan.row_idx, wmats):
-        mat = tile[ridx]
-        mode = _bucket_mode(mat) if wmat is None else _bucket_wmode(mat, wmat)
-        out = out.at[ids].set(mode, unique_indices=True, mode="drop")
-    return out
+    with jax.named_scope("lpa_blocked"):
+        lbl_pad = jnp.concatenate(
+            [labels.astype(jnp.int32), jnp.full((1,), _SENTINEL, jnp.int32)]
+        )
+        tile = _blocked_tile(plan, lbl_pad, _SENTINEL)
+        wmats = plan.weight_mat or (None,) * len(plan.row_idx)
+        return _row_modes(
+            tile, labels.astype(jnp.int32), plan.row_vertex, plan.row_idx,
+            wmats,
+        )
 
 
 def cc_superstep_blocked(labels: jax.Array, plan: BlockedPlan) -> jax.Array:
@@ -538,15 +538,24 @@ def cc_superstep_blocked(labels: jax.Array, plan: BlockedPlan) -> jax.Array:
     labels, then pointer jump); padding slots carry the int32-max
     sentinel, which never wins a min."""
     _check_plan(plan, labels, None)
-    lbl_pad = jnp.concatenate(
-        [labels.astype(jnp.int32), jnp.full((1,), _SENTINEL, jnp.int32)]
-    )
-    tile = _blocked_tile(plan, lbl_pad, _SENTINEL)
-    new = labels.astype(jnp.int32)
-    for ids, ridx in zip(plan.row_vertex, plan.row_idx):
-        row_min = jnp.min(tile[ridx], axis=1)
-        new = new.at[ids].min(row_min, unique_indices=True, mode="drop")
-    return jnp.minimum(new, new[new]).astype(jnp.int32)
+    with jax.named_scope("cc_blocked"):
+        lbl_pad = jnp.concatenate(
+            [labels.astype(jnp.int32), jnp.full((1,), _SENTINEL, jnp.int32)]
+        )
+        tile = _blocked_tile(plan, lbl_pad, _SENTINEL)
+        new = labels.astype(jnp.int32)
+        for ids, ridx in zip(plan.row_vertex, plan.row_idx):
+            width = f"w{ridx.shape[1]}"
+            with jax.named_scope("row_gather"), jax.named_scope(width):
+                mat = tile[ridx]
+            with jax.named_scope("row_min"), jax.named_scope(width):
+                row_min = jnp.min(mat, axis=1)
+            with jax.named_scope("write_back"):
+                new = new.at[ids].min(
+                    row_min, unique_indices=True, mode="drop"
+                )
+        with jax.named_scope("pointer_jump"):
+            return jnp.minimum(new, new[new]).astype(jnp.int32)
 
 
 def blocked_inflow(plan: BlockedPlan, contrib: jax.Array) -> jax.Array:

@@ -490,16 +490,31 @@ def bucketed_mode(plan: BucketedModePlan, messages: jax.Array, fallback: jax.Arr
     msgs_pad = jnp.concatenate(
         [messages.astype(jnp.int32), jnp.full((1,), _SENTINEL, jnp.int32)]
     )
-    out = fallback.astype(jnp.int32)
     wmats = (
         plan.weight_mat
         if weights == "plan" and plan.weight_mat is not None
         else (None,) * len(plan.vertex_ids)
     )
-    for ids, idx, wmat in zip(plan.vertex_ids, plan.msg_idx, wmats):
-        mat = msgs_pad[idx]
-        mode = _bucket_mode(mat) if wmat is None else _bucket_wmode(mat, wmat)
-        out = out.at[ids].set(mode, unique_indices=True, mode="drop")
+    return _row_modes(
+        msgs_pad, fallback.astype(jnp.int32), plan.vertex_ids, plan.msg_idx,
+        wmats,
+    )
+
+
+def _row_modes(values_pad, out, vertex_ids, row_idx, wmats):
+    """The reduce of every degree class: gather each class's dense rows
+    from ``values_pad``, take the row-wise mode, write it to the class's
+    vertices in ``out``."""
+    for ids, idx, wmat in zip(vertex_ids, row_idx, wmats):
+        width = f"w{idx.shape[1]}"
+        with jax.named_scope("row_gather"), jax.named_scope(width):
+            mat = values_pad[idx]
+        with jax.named_scope("row_mode"), jax.named_scope(width):
+            mode = (
+                _bucket_mode(mat) if wmat is None else _bucket_wmode(mat, wmat)
+            )
+        with jax.named_scope("write_back"):
+            out = out.at[ids].set(mode, unique_indices=True, mode="drop")
     return out
 
 
@@ -534,22 +549,31 @@ def lpa_superstep_bucketed(
                 f"but got V={labels.shape[0]}, M={graph.num_messages} — "
                 "plan/graph mismatch"
             )
-        lbl_pad = jnp.concatenate(
-            [labels.astype(jnp.int32), jnp.full((1,), _SENTINEL, jnp.int32)]
-        )
-        out = labels.astype(jnp.int32)
-        wmats = plan.weight_mat or (None,) * len(plan.vertex_ids)
-        for ids, sidx, wmat in zip(plan.vertex_ids, plan.send_idx, wmats):
-            mat = lbl_pad[sidx]
-            mode = (
-                _bucket_mode(mat) if wmat is None else _bucket_wmode(mat, wmat)
-            )
-            out = out.at[ids].set(mode, unique_indices=True, mode="drop")
-        if plan.hist_vertex_ids is not None:
-            # Mega-hub mode: per-hub label histogram + argmax. Exact slot
-            # count (no padding), no wide sort; argmax's first-max rule is
-            # the smallest-label tie-break. Weighted: the histogram
-            # accumulates weights instead of counts.
+        with jax.named_scope("lpa_bucketed"):
+            return _lpa_superstep_fused(labels, plan)
+    with jax.named_scope("lpa_bucketed"):
+        with jax.named_scope("msg_gather"):
+            msg = labels[graph.msg_send]
+        return bucketed_mode(plan, msg, labels)
+
+
+def _lpa_superstep_fused(labels: jax.Array, plan: BucketedModePlan):
+    """The fused superstep body: every degree class gathers its senders'
+    labels straight from the padded label vector."""
+    lbl_pad = jnp.concatenate(
+        [labels.astype(jnp.int32), jnp.full((1,), _SENTINEL, jnp.int32)]
+    )
+    wmats = plan.weight_mat or (None,) * len(plan.vertex_ids)
+    out = _row_modes(
+        lbl_pad, labels.astype(jnp.int32), plan.vertex_ids, plan.send_idx,
+        wmats,
+    )
+    if plan.hist_vertex_ids is not None:
+        # Mega-hub mode: per-hub label histogram + argmax. Exact slot
+        # count (no padding), no wide sort; argmax's first-max rule is
+        # the smallest-label tie-break. Weighted: the histogram
+        # accumulates weights instead of counts.
+        with jax.named_scope("hist"):
             n_hist = plan.hist_vertex_ids.shape[0]
             neigh = labels[plan.hist_send].astype(jnp.int32)
             flat = plan.hist_row_offset + neigh
@@ -571,9 +595,8 @@ def lpa_superstep_bucketed(
                 hist = hist.at[flat].add(plan.hist_weight, mode="drop")
             counts = hist.reshape(n_hist, plan.num_vertices)
             modes = jnp.argmax(counts, axis=1).astype(jnp.int32)
+        with jax.named_scope("write_back"):
             out = out.at[plan.hist_vertex_ids].set(
                 modes, unique_indices=True, mode="drop"
             )
-        return out
-    msg = labels[graph.msg_send]
-    return bucketed_mode(plan, msg, labels)
+    return out

@@ -25,13 +25,18 @@ from graphmine_tpu.graph.container import Graph
 
 
 def cc_superstep(labels: jax.Array, graph: Graph) -> jax.Array:
-    msg = labels[graph.msg_send]
-    neigh_min = jax.ops.segment_min(
-        msg, graph.msg_recv, num_segments=graph.num_vertices, indices_are_sorted=True
-    )
-    new = jnp.minimum(labels, neigh_min)
-    # Pointer jumping: follow the current representative one hop.
-    return jnp.minimum(new, new[new]).astype(jnp.int32)
+    with jax.named_scope("cc_sort"):
+        with jax.named_scope("msg_gather"):
+            msg = labels[graph.msg_send]
+        with jax.named_scope("segment_min"):
+            neigh_min = jax.ops.segment_min(
+                msg, graph.msg_recv, num_segments=graph.num_vertices,
+                indices_are_sorted=True,
+            )
+            new = jnp.minimum(labels, neigh_min)
+        # Pointer jumping: follow the current representative one hop.
+        with jax.named_scope("pointer_jump"):
+            return jnp.minimum(new, new[new]).astype(jnp.int32)
 
 
 def cc_superstep_bucketed(labels: jax.Array, plan) -> jax.Array:
@@ -58,24 +63,35 @@ def cc_superstep_bucketed(labels: jax.Array, plan) -> jax.Array:
             "it with build_graph_and_plan or BucketedModePlan.from_edges"
         )
     sentinel = jnp.iinfo(jnp.int32).max
-    lbl_pad = jnp.concatenate(
-        [labels.astype(jnp.int32), jnp.full((1,), sentinel, jnp.int32)]
-    )
-    new = labels.astype(jnp.int32)
-    for ids, sidx in zip(plan.vertex_ids, plan.send_idx):
-        row_min = jnp.min(lbl_pad[sidx], axis=1)
-        new = new.at[ids].min(row_min, unique_indices=True, mode="drop")
-    if plan.hist_vertex_ids is not None:
-        n_hist = plan.hist_vertex_ids.shape[0]
-        rows = plan.hist_row_offset // jnp.int32(plan.num_vertices)
-        hub_min = jax.ops.segment_min(
-            labels[plan.hist_send].astype(jnp.int32), rows,
-            num_segments=n_hist, indices_are_sorted=True,
+    with jax.named_scope("cc_bucketed"):
+        lbl_pad = jnp.concatenate(
+            [labels.astype(jnp.int32), jnp.full((1,), sentinel, jnp.int32)]
         )
-        new = new.at[plan.hist_vertex_ids].min(
-            hub_min, unique_indices=True, mode="drop"
-        )
-    return jnp.minimum(new, new[new]).astype(jnp.int32)
+        new = labels.astype(jnp.int32)
+        for ids, sidx in zip(plan.vertex_ids, plan.send_idx):
+            width = f"w{sidx.shape[1]}"
+            with jax.named_scope("row_gather"), jax.named_scope(width):
+                mat = lbl_pad[sidx]
+            with jax.named_scope("row_min"), jax.named_scope(width):
+                row_min = jnp.min(mat, axis=1)
+            with jax.named_scope("write_back"):
+                new = new.at[ids].min(
+                    row_min, unique_indices=True, mode="drop"
+                )
+        if plan.hist_vertex_ids is not None:
+            with jax.named_scope("hist"):
+                n_hist = plan.hist_vertex_ids.shape[0]
+                rows = plan.hist_row_offset // jnp.int32(plan.num_vertices)
+                hub_min = jax.ops.segment_min(
+                    labels[plan.hist_send].astype(jnp.int32), rows,
+                    num_segments=n_hist, indices_are_sorted=True,
+                )
+            with jax.named_scope("write_back"):
+                new = new.at[plan.hist_vertex_ids].min(
+                    hub_min, unique_indices=True, mode="drop"
+                )
+        with jax.named_scope("pointer_jump"):
+            return jnp.minimum(new, new[new]).astype(jnp.int32)
 
 
 def connected_components(
@@ -161,7 +177,6 @@ def connected_components(
 
         (labels, iters), secs, cold = timed_fixpoint(
             lambda: _connected_components(graph, max_iter, True, plan),
-            jit_fn=_connected_components,
         )
         iters = int(iters)
         # weighted=False explicitly: CC's min ignores the weight payload
@@ -190,7 +205,8 @@ def _connected_components(
 
     def cond(state):
         labels, prev_changed, it = state
-        return (prev_changed > 0) & (it < limit)
+        with jax.named_scope("superstep"), jax.named_scope("converged"):
+            return (prev_changed > 0) & (it < limit)
 
     from graphmine_tpu.ops.blocking import BlockedPlan, cc_superstep_blocked
 
@@ -202,7 +218,8 @@ def _connected_components(
             new = cc_superstep_blocked(labels, plan)
         else:
             new = cc_superstep_bucketed(labels, plan)
-        changed = jnp.sum(new != labels, dtype=jnp.int32)
+        with jax.named_scope("superstep"), jax.named_scope("changed_count"):
+            changed = jnp.sum(new != labels, dtype=jnp.int32)
         return new, changed, it + 1
 
     labels0 = jnp.arange(graph.num_vertices, dtype=jnp.int32)

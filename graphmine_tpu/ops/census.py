@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from graphmine_tpu.graph.container import Graph
 
 
+@jax.jit
 def community_sizes(labels: jax.Array) -> jax.Array:
     """Vertex count per label value, shape ``[V]`` (0 for unused labels).
 
@@ -23,8 +24,9 @@ def community_sizes(labels: jax.Array) -> jax.Array:
     per-community census the reference printed at ``Graphframes.py:120``.
     """
     v = labels.shape[0]
-    ones = jnp.ones_like(labels)
-    return jax.ops.segment_sum(ones, labels, num_segments=v)
+    with jax.named_scope("census"), jax.named_scope("sizes"):
+        ones = jnp.ones_like(labels)
+        return jax.ops.segment_sum(ones, labels, num_segments=v)
 
 
 def intra_community_edge_mask(labels: jax.Array, graph: Graph) -> jax.Array:
@@ -37,13 +39,15 @@ def intra_community_edge_mask(labels: jax.Array, graph: Graph) -> jax.Array:
     return labels[graph.src] == labels[graph.dst]
 
 
+@jax.jit
 def community_edge_counts(labels: jax.Array, graph: Graph) -> jax.Array:
     """Intra-community edge count per label value, shape ``[V]``."""
     v = labels.shape[0]
-    mask = intra_community_edge_mask(labels, graph)
-    return jax.ops.segment_sum(
-        mask.astype(jnp.int32), labels[graph.src], num_segments=v
-    )
+    with jax.named_scope("census"), jax.named_scope("edge_counts"):
+        mask = intra_community_edge_mask(labels, graph)
+        return jax.ops.segment_sum(
+            mask.astype(jnp.int32), labels[graph.src], num_segments=v
+        )
 
 
 def census_table(labels: jax.Array, graph: Graph):

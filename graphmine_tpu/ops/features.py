@@ -18,12 +18,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from graphmine_tpu.graph.container import Graph
+from graphmine_tpu.obs.spans import stage_span
 from graphmine_tpu.ops.census import community_sizes
 
 
 def vertex_features(
     graph: Graph, communities: jax.Array, triangles_cache=None,
-    include_clustering: bool | str = True, simple_edges=None,
+    include_clustering: bool | str = True, simple_edges=None, sink=None,
 ) -> jax.Array:
     """Feature matrix ``[V, 8]`` (float32):
 
@@ -58,6 +59,9 @@ def vertex_features(
     # OOM-killed a 25M-edge mega-hub run at 130 GB — the driver probes
     # ``oriented_wedge_count`` and passes "sampled" past its budget);
     # False zeros the column (the measured-weaker host-7 configuration).
+    # ``sink``: optional MetricsSink; the triangle pass and the compiled
+    # feature program are then stage spans (``triangles_host``,
+    # ``triangles_device``, ``features_device``).
     if isinstance(include_clustering, np.bool_):
         include_clustering = bool(include_clustering)
     if include_clustering == "sampled":
@@ -70,7 +74,8 @@ def vertex_features(
         from graphmine_tpu.ops.triangles import clustering_coefficient
 
         clust = clustering_coefficient(
-            graph, _cached=triangles_cache, simple_edges=simple_edges
+            graph, _cached=triangles_cache, simple_edges=simple_edges,
+            sink=sink,
         )
     elif include_clustering is False:
         clust = jnp.zeros((graph.num_vertices,), jnp.float32)
@@ -79,7 +84,8 @@ def vertex_features(
             f"include_clustering must be True, False or 'sampled' "
             f"(got {include_clustering!r})"
         )
-    return _vertex_features_jit(graph, communities, clust)
+    with stage_span(sink, "features_device") as stage:
+        return stage.sync(_vertex_features_jit(graph, communities, clust))
 
 
 @partial(jax.jit, static_argnames=())
@@ -87,34 +93,39 @@ def _vertex_features_jit(
     graph: Graph, communities: jax.Array, clust: jax.Array
 ) -> jax.Array:
     v = graph.num_vertices
-    ones_e = jnp.ones_like(graph.src)
-    out_deg = jax.ops.segment_sum(ones_e, graph.src, num_segments=v)
-    in_deg = jax.ops.segment_sum(ones_e, graph.dst, num_segments=v)
-    msg_deg = graph.degrees()
-    comm_size = community_sizes(communities)[communities]
-    neigh_deg_sum = jax.ops.segment_sum(
-        msg_deg[graph.msg_send], graph.msg_recv, num_segments=v,
-        indices_are_sorted=True,
-    )
-    mean_neigh_deg = neigh_deg_sum / jnp.maximum(msg_deg, 1)
-    same = (communities[graph.msg_send] == communities[graph.msg_recv]).astype(
-        jnp.int32
-    )
-    same_cnt = jax.ops.segment_sum(
-        same, graph.msg_recv, num_segments=v, indices_are_sorted=True
-    )
-    same_frac = same_cnt / jnp.maximum(msg_deg, 1)
-    distinct = _distinct_neighbor_communities(graph, communities, v)
-    feats = jnp.log1p(
-        jnp.stack(
-            [out_deg, in_deg, msg_deg, comm_size, mean_neigh_deg,
-             distinct.astype(jnp.float32)], axis=1
-        ).astype(jnp.float32)
-    )
-    return jnp.concatenate(
-        [feats, same_frac[:, None].astype(jnp.float32),
-         clust[:, None].astype(jnp.float32)], axis=1
-    )
+    with jax.named_scope("features"):
+        with jax.named_scope("degrees"):
+            ones_e = jnp.ones_like(graph.src)
+            out_deg = jax.ops.segment_sum(ones_e, graph.src, num_segments=v)
+            in_deg = jax.ops.segment_sum(ones_e, graph.dst, num_segments=v)
+            msg_deg = graph.degrees()
+            comm_size = community_sizes(communities)[communities]
+        with jax.named_scope("neighbor_stats"):
+            neigh_deg_sum = jax.ops.segment_sum(
+                msg_deg[graph.msg_send], graph.msg_recv, num_segments=v,
+                indices_are_sorted=True,
+            )
+            mean_neigh_deg = neigh_deg_sum / jnp.maximum(msg_deg, 1)
+            same = (
+                communities[graph.msg_send] == communities[graph.msg_recv]
+            ).astype(jnp.int32)
+            same_cnt = jax.ops.segment_sum(
+                same, graph.msg_recv, num_segments=v, indices_are_sorted=True
+            )
+            same_frac = same_cnt / jnp.maximum(msg_deg, 1)
+        with jax.named_scope("distinct_communities"):
+            distinct = _distinct_neighbor_communities(graph, communities, v)
+        with jax.named_scope("stack"):
+            feats = jnp.log1p(
+                jnp.stack(
+                    [out_deg, in_deg, msg_deg, comm_size, mean_neigh_deg,
+                     distinct.astype(jnp.float32)], axis=1
+                ).astype(jnp.float32)
+            )
+            return jnp.concatenate(
+                [feats, same_frac[:, None].astype(jnp.float32),
+                 clust[:, None].astype(jnp.float32)], axis=1
+            )
 
 
 def _distinct_neighbor_communities(

@@ -91,27 +91,34 @@ def _tiled_knn(queries, refs, k, row_tile, *, exclude_self=False, ref_mask=None,
 
     def tile_knn(args):
         tile, tile_sq, tile_ids = args
-        # precision=HIGHEST: the TPU MXU's default one-pass bf16 rounding
-        # of f32 operands puts ~1e-2-relative error on d2 — the r4
-        # cross-backend audit measured 0.084 abs TPU-vs-CPU divergence on
-        # these distances before this was forced to true f32 (the
-        # multi-pass cost is invisible at F ~ 8-64 feature dims).
-        cross = lax.dot_general(
-            tile, refs,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            precision=lax.Precision.HIGHEST,
-        )
-        d2 = tile_sq[:, None] - 2.0 * cross + ref_sq[None, :]
-        d2 = jnp.maximum(d2, 0.0)
-        if exclude_self:
-            self_mask = tile_ids[:, None] == jnp.arange(m, dtype=jnp.int32)[None, :]
-            d2 = jnp.where(self_mask, jnp.inf, d2)
-        if query_ids is not None:
-            d2 = jnp.where(tile_ids[:, None] == ref_ids[None, :], jnp.inf, d2)
-        if invalid is not None:
-            d2 = jnp.where(invalid[None, :], jnp.inf, d2)
-        neg_top, idx = lax.top_k(-d2, k)
-        return -neg_top, idx
+        with jax.named_scope("distance"):
+            # precision=HIGHEST: the TPU MXU's default one-pass bf16
+            # rounding of f32 operands puts ~1e-2-relative error on d2 —
+            # the r4 cross-backend audit measured 0.084 abs TPU-vs-CPU
+            # divergence on these distances before this was forced to
+            # true f32 (the multi-pass cost is invisible at F ~ 8-64
+            # feature dims).
+            cross = lax.dot_general(
+                tile, refs,
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                precision=lax.Precision.HIGHEST,
+            )
+            d2 = tile_sq[:, None] - 2.0 * cross + ref_sq[None, :]
+            d2 = jnp.maximum(d2, 0.0)
+            if exclude_self:
+                self_mask = (
+                    tile_ids[:, None] == jnp.arange(m, dtype=jnp.int32)[None, :]
+                )
+                d2 = jnp.where(self_mask, jnp.inf, d2)
+            if query_ids is not None:
+                d2 = jnp.where(
+                    tile_ids[:, None] == ref_ids[None, :], jnp.inf, d2
+                )
+            if invalid is not None:
+                d2 = jnp.where(invalid[None, :], jnp.inf, d2)
+        with jax.named_scope("topk"):
+            neg_top, idx = lax.top_k(-d2, k)
+            return -neg_top, idx
 
     dists, idx = lax.map(tile_knn, (rows, row_sq, row_idx))
     return dists.reshape(n_pad, k)[:n], idx.reshape(n_pad, k)[:n]
@@ -122,7 +129,8 @@ def _knn_xla(points: jax.Array, k: int, row_tile: int = 1024):
     n, _ = points.shape
     if k >= n:
         raise ValueError(f"k={k} must be < number of points {n}")
-    return _tiled_knn(points, points, k, row_tile, exclude_self=True)
+    with jax.named_scope("knn_exact"):
+        return _tiled_knn(points, points, k, row_tile, exclude_self=True)
 
 
 @partial(jax.jit, static_argnames=("k", "row_tile"))
@@ -144,4 +152,5 @@ def cross_knn(
     m = refs.shape[0]
     if k > m:
         raise ValueError(f"k={k} must be <= number of references {m}")
-    return _tiled_knn(queries, refs, k, row_tile, ref_mask=ref_mask)
+    with jax.named_scope("knn_cross"):
+        return _tiled_knn(queries, refs, k, row_tile, ref_mask=ref_mask)

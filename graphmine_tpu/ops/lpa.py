@@ -36,13 +36,16 @@ def lpa_superstep(labels: jax.Array, graph: Graph) -> jax.Array:
     the label with the largest incoming *weight sum* (ties toward the
     smallest label) — classic weighted LPA; unweighted is the all-ones
     special case."""
-    msg = labels[graph.msg_send]
-    mode, _ = segment_mode(
-        graph.msg_recv, msg, num_segments=graph.num_vertices,
-        indices_are_sorted=True, weights=graph.msg_weight,
-    )
-    deg = graph.degrees()
-    return jnp.where(deg > 0, mode, labels).astype(jnp.int32)
+    with jax.named_scope("lpa_sort"):
+        with jax.named_scope("msg_gather"):
+            msg = labels[graph.msg_send]
+        mode, _ = segment_mode(
+            graph.msg_recv, msg, num_segments=graph.num_vertices,
+            indices_are_sorted=True, weights=graph.msg_weight,
+        )
+        with jax.named_scope("write_back"):
+            deg = graph.degrees()
+            return jnp.where(deg > 0, mode, labels).astype(jnp.int32)
 
 
 def label_propagation(
@@ -145,7 +148,6 @@ def label_propagation(
             lambda: _label_propagation(
                 graph, max_iter, init_labels, return_history, plan
             ),
-            jit_fn=_label_propagation,
         )
         cost = superstep_cost(
             "lpa_superstep",
@@ -229,7 +231,8 @@ def _label_propagation(
 
     def step(labels, _):
         new = superstep(labels)
-        changed = jnp.sum(new != labels, dtype=jnp.int32)
+        with jax.named_scope("superstep"), jax.named_scope("changed_count"):
+            changed = jnp.sum(new != labels, dtype=jnp.int32)
         return new, changed
 
     labels, changed = lax.scan(step, labels, None, length=max_iter)

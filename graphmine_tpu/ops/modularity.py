@@ -38,16 +38,22 @@ def modularity_weighted(
     excluded, carried in ``self_weight``). Out-of-range ids (padding
     sentinels) are dropped by the segment ops.
     """
-    w = weight.astype(jnp.float32)
-    k = jax.ops.segment_sum(w, recv, num_segments=num_vertices) + 2.0 * self_weight
-    two_m = jnp.maximum(k.sum(), 1e-12)
-    valid = recv < num_vertices
-    intra_msgs = jnp.where(
-        valid & (labels[jnp.minimum(recv, num_vertices - 1)] == labels[send]), w, 0.0
-    ).sum()
-    sigma_in = intra_msgs + 2.0 * self_weight.sum()
-    sigma_tot = jax.ops.segment_sum(k, labels, num_segments=num_vertices)
-    return sigma_in / two_m - gamma * jnp.sum((sigma_tot / two_m) ** 2)
+    with jax.named_scope("modularity"), jax.named_scope("q"):
+        w = weight.astype(jnp.float32)
+        k = (
+            jax.ops.segment_sum(w, recv, num_segments=num_vertices)
+            + 2.0 * self_weight
+        )
+        two_m = jnp.maximum(k.sum(), 1e-12)
+        valid = recv < num_vertices
+        intra_msgs = jnp.where(
+            valid
+            & (labels[jnp.minimum(recv, num_vertices - 1)] == labels[send]),
+            w, 0.0,
+        ).sum()
+        sigma_in = intra_msgs + 2.0 * self_weight.sum()
+        sigma_tot = jax.ops.segment_sum(k, labels, num_segments=num_vertices)
+        return sigma_in / two_m - gamma * jnp.sum((sigma_tot / two_m) ** 2)
 
 
 def message_weights(graph: Graph) -> tuple[jax.Array, jax.Array]:

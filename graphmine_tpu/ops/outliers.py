@@ -30,6 +30,7 @@ import numpy as np
 from jax import lax
 
 from graphmine_tpu.graph.container import Graph
+from graphmine_tpu.obs.spans import stage_span
 from graphmine_tpu.ops.segment import segment_mode
 
 
@@ -45,15 +46,22 @@ def masked_label_propagation(
     messages whose endpoints share a community.
     """
     v = graph.num_vertices
-    keep = communities[graph.msg_send] == communities[graph.msg_recv]
-    recv = jnp.where(keep, graph.msg_recv, v)  # v = drop sentinel
-    deg = jax.ops.segment_sum(keep.astype(jnp.int32), graph.msg_recv, num_segments=v)
+    with jax.named_scope("masked_lpa"), jax.named_scope("mask"):
+        keep = communities[graph.msg_send] == communities[graph.msg_recv]
+        recv = jnp.where(keep, graph.msg_recv, v)  # v = drop sentinel
+        deg = jax.ops.segment_sum(
+            keep.astype(jnp.int32), graph.msg_recv, num_segments=v
+        )
     labels0 = jnp.arange(v, dtype=jnp.int32)
 
     def step(labels, _):
-        msg = labels[graph.msg_send]
-        mode, _ = segment_mode(recv, msg, num_segments=v)
-        return jnp.where(deg > 0, mode, labels).astype(jnp.int32), None
+        with jax.named_scope("masked_lpa"):
+            with jax.named_scope("msg_gather"):
+                msg = labels[graph.msg_send]
+            mode, _ = segment_mode(recv, msg, num_segments=v)
+            with jax.named_scope("write_back"):
+                new = jnp.where(deg > 0, mode, labels).astype(jnp.int32)
+        return new, None
 
     labels, _ = lax.scan(step, labels0, None, length=max_iter)
     return labels
@@ -71,15 +79,24 @@ class OutlierReport:
 
 
 def recursive_lpa_outliers(
-    graph: Graph, communities: jax.Array, max_iter: int = 5, decile: float = 0.1
+    graph: Graph, communities: jax.Array, max_iter: int = 5,
+    decile: float = 0.1, sink=None,
 ) -> OutlierReport:
     """Parity outlier detector (dead spec, ``Graphframes.py:121-137``).
 
     Device side: one masked LPA over the whole graph. Host side: the
     per-parent decile thresholds over the (tiny) sub-community size table.
+    ``sink``: optional MetricsSink; the two sides are then the stage
+    spans ``masked_lpa`` (to the fetched labels) and ``decile_report``.
     """
-    sub = np.asarray(masked_label_propagation(graph, communities, max_iter=max_iter))
-    return _decile_report(sub, np.asarray(communities), decile)
+    with stage_span(sink, "masked_lpa"):
+        sub = np.asarray(
+            masked_label_propagation(graph, communities, max_iter=max_iter)
+        )
+    with stage_span(sink, "decile_report") as stage:
+        report = _decile_report(sub, np.asarray(communities), decile)
+        stage.note(sub_communities=len(report.sub_sizes))
+    return report
 
 
 def recursive_lpa_outliers_sharded(

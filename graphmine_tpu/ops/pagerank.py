@@ -135,7 +135,6 @@ def pagerank(
             lambda: _pagerank(
                 graph, alpha, max_iter, tol, reset, weights, resolved
             ),
-            jit_fn=_pagerank,
         )
         iters = max(int(iters), 1)
         cost = superstep_cost(

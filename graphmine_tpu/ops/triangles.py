@@ -32,6 +32,7 @@ import numpy as np
 from jax import lax
 
 from graphmine_tpu.graph.container import Graph, simple_undirected_edges
+from graphmine_tpu.obs.spans import stage_span
 
 
 def _oriented_csr(graph: Graph, simple_edges=None):
@@ -88,47 +89,57 @@ def _oriented_csr(graph: Graph, simple_edges=None):
 def _count_device(ptr, col, wedge_v, wedge_w, wedge_u, num_vertices: int, search_iters: int):
     """Vectorized membership test: is (v, w) an oriented edge? Then credit
     triangles to u, v, w via segment sums."""
-    lo = ptr[wedge_v]
-    hi = ptr[wedge_v + 1]
+    with jax.named_scope("triangles"):
+        with jax.named_scope("bsearch"):
+            lo = ptr[wedge_v]
+            hi = ptr[wedge_v + 1]
 
-    def bsearch(_, state):
-        lo, hi = state
-        mid = (lo + hi) // 2
-        val = col[jnp.clip(mid, 0, col.shape[0] - 1)]
-        go_right = (val < wedge_w) & (mid < hi)
-        lo = jnp.where(go_right, mid + 1, lo)
-        hi = jnp.where(go_right, hi, jnp.maximum(mid, lo))
-        return lo, hi
+            def bsearch(_, state):
+                lo, hi = state
+                mid = (lo + hi) // 2
+                val = col[jnp.clip(mid, 0, col.shape[0] - 1)]
+                go_right = (val < wedge_w) & (mid < hi)
+                lo = jnp.where(go_right, mid + 1, lo)
+                hi = jnp.where(go_right, hi, jnp.maximum(mid, lo))
+                return lo, hi
 
-    lo_f, _ = lax.fori_loop(0, search_iters, bsearch, (lo, hi))
-    found = (lo_f < ptr[wedge_v + 1]) & (col[jnp.clip(lo_f, 0, col.shape[0] - 1)] == wedge_w)
-    # skip degenerate wedges where v == w (the edge itself)
-    found &= wedge_v != wedge_w
-    hit = found.astype(jnp.int32)
-    tri = (
-        jax.ops.segment_sum(hit, wedge_u, num_segments=num_vertices)
-        + jax.ops.segment_sum(hit, wedge_v, num_segments=num_vertices)
-        + jax.ops.segment_sum(hit, wedge_w, num_segments=num_vertices)
-    )
-    return tri, hit.sum()
+            lo_f, _ = lax.fori_loop(0, search_iters, bsearch, (lo, hi))
+            found = (lo_f < ptr[wedge_v + 1]) & (
+                col[jnp.clip(lo_f, 0, col.shape[0] - 1)] == wedge_w
+            )
+            # skip degenerate wedges where v == w (the edge itself)
+            found &= wedge_v != wedge_w
+        with jax.named_scope("count"):
+            hit = found.astype(jnp.int32)
+            tri = (
+                jax.ops.segment_sum(hit, wedge_u, num_segments=num_vertices)
+                + jax.ops.segment_sum(hit, wedge_v, num_segments=num_vertices)
+                + jax.ops.segment_sum(hit, wedge_w, num_segments=num_vertices)
+            )
+            return tri, hit.sum()
 
 
-def _triangles(graph: Graph, simple_edges=None):
+def _triangles(graph: Graph, simple_edges=None, sink=None):
     """Shared pipeline: host build + device count once.
 
-    Returns ``(tri [V], total, simple_degree [V])``.
+    Returns ``(tri [V], total, simple_degree [V])``. ``sink``: optional
+    MetricsSink; the host build and the device count are then the stage
+    spans ``triangles_host`` and ``triangles_device``.
     """
-    ptr, col, wu, wv, ww, deg, _, _ = _oriented_csr(graph, simple_edges)
+    with stage_span(sink, "triangles_host") as stage:
+        ptr, col, wu, wv, ww, deg, _, _ = _oriented_csr(graph, simple_edges)
+        stage.note(wedges=len(wu))
     if len(wu) == 0:
         z = jnp.zeros((graph.num_vertices,), jnp.int32)
         return z, jnp.int32(0), jnp.asarray(deg, jnp.int32)
     max_row = int(np.max(np.diff(ptr), initial=1))
     iters = max(int(np.ceil(np.log2(max(max_row, 2)))) + 1, 1)
-    tri, total = _count_device(
-        jnp.asarray(ptr, jnp.int32), jnp.asarray(col),
-        jnp.asarray(wv), jnp.asarray(ww), jnp.asarray(wu),
-        num_vertices=graph.num_vertices, search_iters=iters,
-    )
+    with stage_span(sink, "triangles_device", wedges=len(wu)) as stage:
+        tri, total = stage.sync(_count_device(
+            jnp.asarray(ptr, jnp.int32), jnp.asarray(col),
+            jnp.asarray(wv), jnp.asarray(ww), jnp.asarray(wu),
+            num_vertices=graph.num_vertices, search_iters=iters,
+        ))
     return tri, total, jnp.asarray(deg, jnp.int32)
 
 
@@ -169,7 +180,7 @@ def oriented_wedge_count(graph: Graph, simple_edges=None) -> int:
 
 
 def clustering_coefficient(
-    graph: Graph, _cached=None, simple_edges=None
+    graph: Graph, _cached=None, simple_edges=None, sink=None
 ) -> jax.Array:
     """Local clustering coefficient ``[V]`` (float32): triangles through a
     vertex over its wedge count on the simplified graph.
@@ -177,10 +188,11 @@ def clustering_coefficient(
     ``_cached`` optionally takes a prior :func:`_triangles` result so a
     caller needing both counts and coefficients pays the pipeline once;
     ``simple_edges`` forwards a precomputed dedup (see
-    :func:`_oriented_csr`).
+    :func:`_oriented_csr`); ``sink`` the stage spans of
+    :func:`_triangles`.
     """
     tri, _, deg = (
-        _triangles(graph, simple_edges) if _cached is None else _cached
+        _triangles(graph, simple_edges, sink) if _cached is None else _cached
     )
     deg = deg.astype(jnp.float32)
     wedges = deg * (deg - 1.0) / 2.0

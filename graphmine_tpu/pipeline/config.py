@@ -62,7 +62,9 @@ class PipelineConfig:
     lof_impl: str = "auto"  # auto | xla | pallas | ivf
     # observability (docs/OBSERVABILITY.md)
     show: int = 10  # .show(10) parity
-    profile_dir: str | None = None  # jax.profiler trace output
+    # one jax.profiler capture around the whole run, reduced at its end
+    # into device_scope / device_idle records (docs/OBSERVABILITY.md)
+    profile_dir: str | None = None
     # write every metrics record (incl. retry/degrade/quarantine/rollback
     # recovery events, docs/RESILIENCE.md) as JSON lines to this path at
     # the end of the run — the on-disk twin of the logging stream. Opened

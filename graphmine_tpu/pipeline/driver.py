@@ -23,6 +23,7 @@ import numpy as np
 
 from graphmine_tpu.graph.container import Graph, graph_from_edge_table
 from graphmine_tpu.io.edges import EdgeTable, load_edge_list, load_parquet_edges
+from graphmine_tpu.obs.spans import stage_span
 from graphmine_tpu.pipeline import checkpoint as ckpt
 from graphmine_tpu.pipeline import resilience
 from graphmine_tpu.pipeline.config import PipelineConfig
@@ -168,13 +169,16 @@ def run_pipeline(config: PipelineConfig) -> PipelineResult:
         ).start()
     run_err: BaseException | None = None
     try:
-        result = _run_pipeline(config, m)
-        if config.snapshot_out:
-            # Serving hand-off (r7, docs/SERVING.md): the run's final
-            # phase publishes labels/CC/LOF/census + edges as a versioned
-            # snapshot generation the serve/ subsystem queries and
-            # delta-repairs against.
-            _publish_snapshot(config, result, m)
+        # profile_dir: one capture around every chapter of the run,
+        # reduced into device_scope / device_idle records when it stops
+        with maybe_profile(config.profile_dir, sink=m):
+            result = _run_pipeline(config, m)
+            if config.snapshot_out:
+                # Serving hand-off (r7, docs/SERVING.md): the run's final
+                # phase publishes labels/CC/LOF/census + edges as a
+                # versioned snapshot generation the serve/ subsystem
+                # queries and delta-repairs against.
+                _publish_snapshot(config, result, m)
         return result
     except BaseException as e:
         run_err = e
@@ -223,7 +227,7 @@ def _run_pipeline(config: PipelineConfig, m: MetricsSink) -> PipelineResult:
         resilience.fault_point("load", path=config.data_path)
         if config.data_format == "parquet":
             return load_parquet_edges(
-                config.data_path, batch_rows=config.batch_rows
+                config.data_path, batch_rows=config.batch_rows, sink=m
             )
         return load_edge_list(
             config.data_path, weight_col=config.edge_weight_col,
@@ -509,7 +513,7 @@ def _run_pipeline(config: PipelineConfig, m: MetricsSink) -> PipelineResult:
 
             scorer = lambda: recursive_lpa_outliers(
                 graph, labels, max_iter=config.sub_max_iter,
-                decile=config.decile,
+                decile=config.decile, sink=m,
             )
             timing_kv = {}
 
@@ -626,25 +630,29 @@ def _run_pipeline(config: PipelineConfig, m: MetricsSink) -> PipelineResult:
                      "outliers_lof", k=config.lof_k,
                      devices=n_dev if use_sharded_lof else 1,
                      features="host-8-sampled" if scale_out else feature_mode):
-            if scale_out:
-                # Host feature twin (no O(E) device transfer). The exact
-                # wedge pipeline is infeasible exactly when the graph
-                # exceeds one device, so the clustering column comes from
-                # the wedge-SAMPLED estimator (r4): the full 8-feature
-                # set survives at scale with a bounded per-vertex error
-                # (ops/triangles.sampled_clustering_coefficient).
-                feats = standardize(vertex_features_host(
-                    graph, labels, include_clustering="sampled"
-                ))
-            else:
-                feats = standardize(vertex_features(
-                    graph, labels,
-                    include_clustering=(
-                        "sampled" if feature_mode == "device-8-sampled"
-                        else True
-                    ),
-                    simple_edges=simple_edges,
-                ))
+            with stage_span(
+                m, "lof_features", n=graph.num_vertices
+            ) as stage:
+                if scale_out:
+                    # Host feature twin (no O(E) device transfer). The
+                    # exact wedge pipeline is infeasible exactly when the
+                    # graph exceeds one device, so the clustering column
+                    # comes from the wedge-SAMPLED estimator (r4): the
+                    # full 8-feature set survives at scale with a bounded
+                    # per-vertex error
+                    # (ops/triangles.sampled_clustering_coefficient).
+                    feats = standardize(vertex_features_host(
+                        graph, labels, include_clustering="sampled"
+                    ))
+                else:
+                    feats = stage.sync(standardize(vertex_features(
+                        graph, labels,
+                        include_clustering=(
+                            "sampled" if feature_mode == "device-8-sampled"
+                            else True
+                        ),
+                        simple_edges=simple_edges, sink=m,
+                    )))
             if use_sharded_lof:
                 # Multi-device (parallel/knn.py): the planner-resolved
                 # family — IVF candidate reduction with the search stage
@@ -759,11 +767,8 @@ def _publish_snapshot(config: PipelineConfig, result: PipelineResult, m: Metrics
             # telemetry=True returns the real supersteps-to-fixpoint on
             # the existing while-loop carry (no extra device syncs) — the
             # CC phase's achieved-vs-model window (ISSUE 12).
-            from graphmine_tpu.parallel.sharded import _sharded_cc_jit
-
             (cc_labels, tele), secs, cold = timed_fixpoint(
-                lambda: sharded_connected_components(sg, mesh, telemetry=True),
-                jit_fn=_sharded_cc_jit,
+                lambda: sharded_connected_components(sg, mesh, telemetry=True)
             )
             emit_superstep_timing(
                 m, "cc_superstep",
@@ -1495,19 +1500,17 @@ def _run_lpa(
         )
         else run_plan.schedule
     )
-    with maybe_profile(config.profile_dir, sink=m):
-        labels = resilience.run_phase(
-            "lpa", make_runner(primary), policy, m,
-            ladder=tuple((v, make_runner(v)) for v in rungs),
-            device_ladder=tuple(device_rungs),
-            # supersteps advanced since the last failure => a NEW incident:
-            # the retry budget bounds attempts per incident, not per run
-            progress=lambda: state["it"],
-            # a reactive OOM's degrade record carries the failed point's
-            # modeled inventory + the last watermark (ISSUE 14)
-            degrade_context=_lpa_degrade_context,
-        )
-    return labels
+    return resilience.run_phase(
+        "lpa", make_runner(primary), policy, m,
+        ladder=tuple((v, make_runner(v)) for v in rungs),
+        device_ladder=tuple(device_rungs),
+        # supersteps advanced since the last failure => a NEW incident:
+        # the retry budget bounds attempts per incident, not per run
+        progress=lambda: state["it"],
+        # a reactive OOM's degrade record carries the failed point's
+        # modeled inventory + the last watermark (ISSUE 14)
+        degrade_context=_lpa_degrade_context,
+    )
 
 
 def main(argv=None) -> None:

@@ -21,14 +21,91 @@ import contextlib
 import json
 import logging
 import os
+import sys
 import threading
 import time
+import weakref
 from dataclasses import dataclass, field
 
+from graphmine_tpu.obs.costmodel import note_backend_compile
 from graphmine_tpu.obs.registry import Registry
 from graphmine_tpu.obs.spans import xla_annotation
 
 log = logging.getLogger("graphmine_tpu")
+
+# ---- a record per compile --------------------------------------------------
+# One process-wide pair of jax.monitoring listeners, installed when the
+# first sink is made in a process that has jax, forwards each compile
+# stage to every sink that is open (made and not yet finalized).
+
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_open_sinks: "weakref.WeakValueDictionary[int, MetricsSink]" = (
+    weakref.WeakValueDictionary()
+)
+# per compiling thread: .cache_hit, a hit that awaits its backend stage;
+# .trace, the newest trace event, which awaits its program's lowering
+_listener = threading.local()
+_listener_lock = threading.Lock()
+_listener_installed = False
+
+
+def _on_compile_event(event: str, **_) -> None:
+    # jax reports a persistent-cache hit inside the backend stage it
+    # serves, on the compiling thread, before that stage's duration
+    if event == _CACHE_HIT_EVENT:
+        _listener.cache_hit = True
+
+
+def _on_compile_duration(event: str, seconds: float, fun_name="", **_) -> None:
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    if stage == "trace":
+        # jax times every function it traces, the jnp helpers inside a
+        # program too (2,000 events in a cold pipeline job, 60 programs),
+        # and an outer trace's seconds hold the inner ones'. The outermost
+        # ends last, just before its program is lowered: keep the newest
+        # and write it with that lowering, three records a program.
+        _listener.trace = (str(fun_name), seconds)
+        return
+    notes = [(stage, str(fun_name), seconds, None)]
+    if stage == "lower":
+        trace = getattr(_listener, "trace", None)
+        if trace is not None:
+            _listener.trace = None
+            notes.insert(0, ("trace", *trace, None))
+    else:
+        notes[0] = (*notes[0][:3], getattr(_listener, "cache_hit", False))
+        _listener.cache_hit = False
+        note_backend_compile()
+    with _listener_lock:
+        sinks = list(_open_sinks.values())
+    for sink in sinks:
+        for note in notes:
+            sink._note_compile(*note)
+
+
+def _install_compile_listener() -> None:
+    """Idempotent, and a no-op until jax is imported: a sink made by
+    host-only tooling must not drag the runtime in. A sink made earlier
+    than jax tries again at each span it opens."""
+    global _listener_installed
+    jax = sys.modules.get("jax")
+    if _listener_installed or jax is None:
+        return
+    with _listener_lock:
+        if _listener_installed:
+            return
+        jax.monitoring.register_event_listener(_on_compile_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_duration
+        )
+        _listener_installed = True
 
 
 @dataclass
@@ -79,6 +156,27 @@ class MetricsSink:
     _lost: int = field(default=0, repr=False)
     _lost_warned: bool = field(default=False, repr=False)
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
+
+    def __post_init__(self):
+        with _listener_lock:
+            _open_sinks[id(self)] = self
+        _install_compile_listener()
+
+    def _note_compile(self, stage, fun_name, seconds, cache_hit) -> None:
+        """One compile stage, from the process-wide listener: a
+        ``compile`` record stamped with the span open on the compiling
+        thread, and the registry's running totals."""
+        self.emit("compile", stage=stage, fun_name=fun_name,
+                  seconds=round(seconds, 6), cache_hit=cache_hit)
+        if stage == "backend":
+            self.registry.counter(
+                "graphmine_compiles_total",
+                "programs compiled, or loaded from the persistent cache",
+            ).inc()
+        self.registry.counter(
+            "graphmine_compile_seconds_total",
+            "seconds in jax's trace, lowering and backend compile stages",
+        ).inc(seconds)
 
     def emit(self, phase: str, _span=None, **kv) -> dict:
         """Append one record (and stream it). ``_span`` pins the record
@@ -185,6 +283,7 @@ class MetricsSink:
         if self.tracer is None:
             yield None
             return
+        _install_compile_listener()
         sp = None
         try:
             with self.tracer.span(
@@ -202,6 +301,15 @@ class MetricsSink:
                     seconds=round(sp.seconds, 4), status=sp.status,
                     **sp.attrs,
                 )
+
+    def span_attrs(self, **attrs) -> None:
+        """Set attributes on the span open on this thread (they ride its
+        ``span`` record at close); nothing without a tracer, and nothing
+        on the root span, which writes no record."""
+        if self.tracer is not None:
+            sp = self.tracer.current()
+            if sp is not self.tracer.root:
+                sp.attrs.update(attrs)
 
     def of_phase(self, phase: str) -> list:
         """All records for one phase name — recovery events (``retry``,
@@ -226,6 +334,8 @@ class MetricsSink:
         mid-run, or a different target path) **append** the records the
         stream never persisted — never truncate, the file may hold prior
         runs' records (a resumed run reusing one ``--metrics-out``)."""
+        with _listener_lock:  # closed: no more compile records
+            _open_sinks.pop(id(self), None)
         if self._stream is not None:
             try:
                 self._stream.close()
@@ -309,25 +419,71 @@ def shard_sink(
     )
 
 
+def _reduce_capture(profile_dir: str, sink: MetricsSink) -> dict:
+    """Reduce the capture just written (obs/devtrace.py) into
+    ``device_scope`` and ``device_idle`` records; returns what the
+    ``profile_capture`` record says about it."""
+    from graphmine_tpu.obs import devtrace
+    from graphmine_tpu.obs.schema import DEVICE_SCOPES
+
+    t0 = time.perf_counter()
+    path = devtrace.newest_xplane(profile_dir)
+    root = sink.tracer.root.path if sink.tracer is not None else "run"
+    reduced = devtrace.reduce_capture(
+        *devtrace.read_xplane(path, root), DEVICE_SCOPES
+    )
+    for row in reduced["scopes"]:
+        sink.emit("device_scope", **row)
+    for row in reduced["idle"]:
+        sink.emit("device_idle", **row)
+    return {
+        "trace_bytes": os.path.getsize(path),
+        "devices": reduced["devices"],
+        "busy_seconds": reduced["busy_seconds"],
+        "reduce_seconds": round(time.perf_counter() - t0, 4),
+    }
+
+
 @contextlib.contextmanager
 def maybe_profile(profile_dir: str | None, sink: MetricsSink | None = None):
-    """jax.profiler trace around a pipeline phase (SURVEY §5 tracing).
+    """One jax.profiler capture around the block — ``run_pipeline`` wraps
+    the whole run in it, and it works as well around any single call
+    (one ``label_propagation(..., sink=sink)``).
+
+    The capture holds the host's annotations and no Python call stacks
+    (those slow the host and swell the file). While it is open, the
+    persistent compile cache keys on the programs' metadata too: jax
+    leaves op names and source lines out of the key by default, so an
+    executable that an older checkout put in the cache would be loaded
+    with that checkout's names, and the capture would report scopes the
+    code no longer has (or none). A program this process already holds
+    keeps the names it was compiled with: profile in a fresh process.
+    On a clean stop the newest ``.xplane.pb`` is reduced in the process:
+    ``device_scope`` records (device seconds by named scope, program and
+    program span) and one ``device_idle`` record per chapter, then
+    ``profile_capture`` with the file's size and the busy seconds the
+    scopes add up to. A capture with no device plane (the CPU backend)
+    reduces to no rows.
 
     Hardened (ISSUE 3 satellite): a failing ``start_trace`` runs the body
     unprofiled instead of aborting the run, and ``stop_trace`` failures
     are contained — a raise out of the ``finally`` would *mask the
-    body's own error*, which is the one the operator needs. Either
-    outcome is recorded as a ``profile_capture`` record carrying the
-    trace dir, so offline reports can link the XLA trace (or its
-    absence) to the run.
+    body's own error*, which is the one the operator needs; a failing
+    reduction is contained the same way. Every outcome is recorded as a
+    ``profile_capture`` record carrying the trace dir, so offline
+    reports can link the XLA trace (or its absence) to the run.
     """
     if not profile_dir:
         yield
         return
     import jax
 
+    names_in_key = "jax_compilation_cache_include_metadata_in_key"
+    was_in_key = getattr(jax.config, names_in_key)
     try:
-        jax.profiler.start_trace(profile_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(profile_dir, profiler_options=options)
     except Exception as e:
         log.warning("profiler start_trace(%s) failed: %r; running "
                     "unprofiled", profile_dir, e)
@@ -336,9 +492,12 @@ def maybe_profile(profile_dir: str | None, sink: MetricsSink | None = None):
                       error=repr(e))
         yield
         return
+    jax.config.update(names_in_key, True)
     try:
         yield
     finally:
+        jax.config.update(names_in_key, was_in_key)
+        t_stop = time.perf_counter()
         try:
             jax.profiler.stop_trace()
         except Exception as e:
@@ -348,5 +507,13 @@ def maybe_profile(profile_dir: str | None, sink: MetricsSink | None = None):
                 sink.emit("profile_capture", dir=profile_dir, ok=False,
                           error=repr(e))
         else:
+            stop_seconds = time.perf_counter() - t_stop
             if sink is not None:
-                sink.emit("profile_capture", dir=profile_dir, ok=True)
+                try:
+                    reduced = _reduce_capture(profile_dir, sink)
+                except Exception as e:
+                    log.warning("could not reduce the capture under %s: %r",
+                                profile_dir, e)
+                    reduced = {"reduce_error": repr(e)}
+                sink.emit("profile_capture", dir=profile_dir, ok=True,
+                          stop_seconds=round(stop_seconds, 4), **reduced)

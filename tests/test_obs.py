@@ -251,7 +251,7 @@ def test_finalize_without_streaming_appends(tmp_path):
 def test_maybe_profile_stop_failure_does_not_mask_body_error(tmp_path, monkeypatch):
     import jax
 
-    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d, **kw: None)
 
     def bad_stop():
         raise RuntimeError("No profiler session active")
@@ -268,7 +268,7 @@ def test_maybe_profile_stop_failure_does_not_mask_body_error(tmp_path, monkeypat
 def test_maybe_profile_start_failure_runs_unprofiled(tmp_path, monkeypatch):
     import jax
 
-    def bad_start(d):
+    def bad_start(d, **kw):
         raise RuntimeError("profiler already active")
 
     monkeypatch.setattr(jax.profiler, "start_trace", bad_start)
@@ -283,7 +283,7 @@ def test_maybe_profile_start_failure_runs_unprofiled(tmp_path, monkeypatch):
 def test_maybe_profile_success_records_trace_dir(tmp_path, monkeypatch):
     import jax
 
-    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d, **kw: None)
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     m = MetricsSink()
     with maybe_profile(str(tmp_path), sink=m):

@@ -22,6 +22,7 @@ import json
 import math
 import os
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -521,10 +522,19 @@ def test_slow_request_digest_and_error_accounting(tmp_path):
         with pytest.raises(urllib.error.HTTPError) as e:
             _get(host, port, "/nope")
         assert e.value.code == 404
-        statusz, _ = _get_json(host, port, "/statusz")
+        # a request is accounted after its reply is sent: a reader that
+        # arrives at once may be early, so read until the tail has run
+        deadline = time.monotonic() + 5.0
+        while True:
+            statusz, _ = _get_json(host, port, "/statusz")
+            eps = statusz["endpoints"]
+            settled = (eps.get("query", {}).get("count") == 2
+                       and "unknown" in eps)
+            if settled or time.monotonic() > deadline:
+                break
+            time.sleep(0.01)
     finally:
         server.stop()
-    eps = statusz["endpoints"]
     assert eps["query"]["count"] == 2 and eps["query"]["errors"] == 1
     assert eps["query"]["error_rate"] == 0.5
     # unknown paths share ONE bucket — no unbounded label cardinality
@@ -540,7 +550,11 @@ def test_slow_request_digest_and_error_accounting(tmp_path):
     want = hashlib.sha256(
         json.dumps({"vertices": [1, 2]}).encode()
     ).hexdigest()
-    assert post_logs[0]["body_sha256"] == want
+    # the 200 reply's record, picked by status: the access-log line is
+    # written after the reply is sent, so two handler threads may append
+    # theirs in either order
+    (ok_log,) = [r for r in post_logs if r["status"] == 200]
+    assert ok_log["body_sha256"] == want
     assert validate_records(sink.records) == []
 
 

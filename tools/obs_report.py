@@ -155,9 +155,12 @@ def _fmt_offset(rec, t0):
     return f"+{rec.get('t', t0) - t0:8.2f}s"
 
 
-def _short_path(rec):
-    path = rec.get("span_path", "")
+def _short(path: str) -> str:
     return path[4:] if path.startswith("run/") else path  # strip "run/"
+
+
+def _short_path(rec):
+    return _short(rec.get("span_path", ""))
 
 
 def _bar(frac: float, width: int = BAR_WIDTH) -> str:
@@ -172,7 +175,9 @@ def _phase_waterfall(records, t0):
         for r in spans:
             secs = float(r.get("seconds", 0.0))
             start = float(r.get("t", t0)) - secs - t0
-            rows.append((start, r.get("name", "?"), secs,
+            # stage spans sit under their chapter: indent by depth
+            depth = max(str(r.get("span_path", "")).count("/") - 1, 0)
+            rows.append((start, "  " * depth + r.get("name", "?"), secs,
                          r.get("status", "ok"), _short_path(r)))
     else:  # pre-span streams: fall back to timed phase records
         for r in records:
@@ -376,6 +381,63 @@ def _roofline_section(records, min_frac: float):
                 "(and re-seed via GRAPHMINE_ROOFLINE_FILE) before "
                 "trusting a below-model exchange verdict "
                 "(docs/RUNBOOKS.md §15)"
+            )
+    return out
+
+
+def _device_section(records):
+    """One ``profile_dir`` capture, reduced by the run itself
+    (``device_scope`` / ``device_idle`` records, obs/devtrace.py), and
+    the run's compiles by program. Empty without either."""
+    scopes = [r for r in records if r.get("phase") == "device_scope"]
+    idle = [r for r in records if r.get("phase") == "device_idle"]
+    compiles = [r for r in records if r.get("phase") == "compile"]
+    out = []
+    if scopes:
+        busy = sum(float(r["device_seconds"]) for r in scopes) or 1.0
+        out.append(
+            "  device seconds by named scope (a fusion carries the scope "
+            "of its root instruction):"
+        )
+        out.append(f"  {'scope':<34}{'program':<30}{'seconds':>10}"
+                   f"{'share':>8}{'ops':>9}  launched under")
+        for r in scopes[:40]:
+            secs = float(r["device_seconds"])
+            out.append(
+                f"  {r['scope']:<34}{str(r['module'])[:28]:<30}{secs:>10.4f}"
+                f"{100 * secs / busy:>7.1f}%{r['events']:>9}  "
+                f"{_short(r.get('stage_path', ''))}"
+            )
+        if len(scopes) > 40:
+            rest = sum(float(r["device_seconds"]) for r in scopes[40:])
+            out.append(f"  ... {len(scopes) - 40} more rows, {rest:.4f}s")
+    for r in idle:
+        busy_s, idle_s = float(r["busy_seconds"]), float(r["idle_seconds"])
+        total = (busy_s + idle_s) or 1.0
+        out.append(
+            f"  [device_idle] {_short(r.get('chapter_path', '')):<28}"
+            f" busy {busy_s:8.3f}s  idle {idle_s:8.3f}s "
+            f"({100 * idle_s / total:.0f}% idle)"
+        )
+    if compiles:
+        by_prog = {}
+        for r in compiles:
+            key = (r.get("fun_name", "?"), _short_path(r))
+            row = by_prog.setdefault(key, [0.0, 0, 0])
+            row[0] += float(r.get("seconds", 0.0))
+            if r.get("stage") == "backend":
+                row[1] += 1
+                row[2] += bool(r.get("cache_hit"))
+        total = sum(v[0] for v in by_prog.values())
+        out.append(
+            f"  compiles: {sum(v[1] for v in by_prog.values())} programs, "
+            f"{total:.3f}s in trace + lower + backend; the longest:"
+        )
+        ranked = sorted(by_prog.items(), key=lambda kv: -kv[1][0])
+        for (fun, path), (secs, n, hits) in ranked[:8]:
+            out.append(
+                f"  [compile] {fun:<36}{secs:>9.3f}s  x{n} "
+                f"({hits} from the cache)  under {path}"
             )
     return out
 
@@ -1247,6 +1309,11 @@ def build_report(
         lines.append("")
         lines.append("-- roofline (achieved vs cost model) --")
         lines.extend(roofline)
+    device = _device_section(records)
+    if device:  # a profile_dir capture and/or the run's compiles
+        lines.append("")
+        lines.append("-- device timeline (scopes / idle / compiles) --")
+        lines.extend(device)
     memory = _memory_section(records, t0)
     if memory:  # pre-ISSUE-14 streams carry no memory_watermark
         lines.append("")

@@ -87,25 +87,37 @@ _INLINE_SHARD_RE = re.compile(
 )
 _SHARD_OWNER = os.path.join("graphmine_tpu", "serve", "shardplane.py")
 
+# Named scopes (ISSUE 25): the names on the device timeline are string
+# literals registered in schema.DEVICE_SCOPES, so a report that groups
+# device seconds by scope can say which names exist. The one computed
+# name is the degree class: a variable called `width`, bound to
+# f"w{...}" and nothing else.
+_SCOPE_RE = re.compile(r"\bjax\.named_scope\(\s*([^)]*?)\s*\)")
+_SCOPE_LITERAL_RE = re.compile(r"[\"']([A-Za-z_][A-Za-z0-9_]*)[\"']$")
+_WIDTH_BINDING_RE = re.compile(r"\bwidth\s*=\s*(.*)")
+
 PACKAGE_DIR = os.path.join(_REPO, "graphmine_tpu")
+
+
+def _py_files(root: str):
+    """``(path relative to the repo, text)`` of every ``.py`` under
+    ``root``, in a fixed order."""
+    for dirpath, dirnames, filenames in os.walk(root):
+        dirnames[:] = [d for d in dirnames if d != "__pycache__"]
+        for name in sorted(filenames):
+            if name.endswith(".py"):
+                path = os.path.join(dirpath, name)
+                with open(path) as f:
+                    yield os.path.relpath(path, _REPO), f.read()
 
 
 def scan(root: str = PACKAGE_DIR) -> list:
     """All (phase, file, line) triples of string-literal phase emits."""
     found = []
-    for dirpath, dirnames, filenames in os.walk(root):
-        dirnames[:] = [d for d in dirnames if d != "__pycache__"]
-        for name in sorted(filenames):
-            if not name.endswith(".py"):
-                continue
-            path = os.path.join(dirpath, name)
-            with open(path) as f:
-                text = f.read()
-            for m in _EMIT_RE.finditer(text):
-                line = text.count("\n", 0, m.start()) + 1
-                found.append((
-                    m.group(1), os.path.relpath(path, _REPO), line,
-                ))
+    for rel, text in _py_files(root):
+        for m in _EMIT_RE.finditer(text):
+            line = text.count("\n", 0, m.start()) + 1
+            found.append((m.group(1), rel, line))
     return found
 
 
@@ -113,24 +125,16 @@ def _scan_inline(root, pattern, owners) -> list:
     """``(file, line)`` pairs of an inline sub-record kwarg literal
     outside its owning builder module(s)."""
     found = []
-    for dirpath, dirnames, filenames in os.walk(root):
-        dirnames[:] = [d for d in dirnames if d != "__pycache__"]
-        for name in sorted(filenames):
-            if not name.endswith(".py"):
-                continue
-            path = os.path.join(dirpath, name)
-            rel = os.path.relpath(path, _REPO)
-            if rel in owners:
-                continue
-            with open(path) as f:
-                lines = f.readlines()
-            for i, raw in enumerate(lines, 1):
-                # crude comment strip: good enough for a kwarg lint (a
-                # '#' inside a string arg would hide a same-line match,
-                # which no real emit call shape does)
-                code = raw.split("#", 1)[0]
-                if pattern.search(code):
-                    found.append((rel, i))
+    for rel, text in _py_files(root):
+        if rel in owners:
+            continue
+        for i, raw in enumerate(text.splitlines(), 1):
+            # crude comment strip: good enough for a kwarg lint (a
+            # '#' inside a string arg would hide a same-line match,
+            # which no real emit call shape does)
+            code = raw.split("#", 1)[0]
+            if pattern.search(code):
+                found.append((rel, i))
     return found
 
 
@@ -156,6 +160,69 @@ def scan_inline_shard_records(root: str = PACKAGE_DIR) -> list:
     """``(file, line)`` pairs of direct shard-plane record emits outside
     the single builder (serve/shardplane.emit_shard_record)."""
     return _scan_inline(root, _INLINE_SHARD_RE, (_SHARD_OWNER,))
+
+
+def _scan_scope_calls(root: str) -> list:
+    """``(argument text, file, line)`` of every ``jax.named_scope(...)``
+    call, plus ``("width=<rhs>", file, line)`` of every binding of the
+    one variable a scope may be computed through."""
+    found = []
+    for rel, text in _py_files(root):
+        for m in _SCOPE_RE.finditer(text):
+            line = text.count("\n", 0, m.start()) + 1
+            found.append((m.group(1), rel, line))
+        if "named_scope(width)" in text:
+            for m in _WIDTH_BINDING_RE.finditer(text):
+                line = text.count("\n", 0, m.start()) + 1
+                found.append(("width=" + m.group(1).strip(), rel, line))
+    return found
+
+
+def scan_scopes(root: str = PACKAGE_DIR) -> list:
+    """All (scope, file, line) triples of string-literal named scopes."""
+    out = []
+    for arg, path, line in _scan_scope_calls(root):
+        m = _SCOPE_LITERAL_RE.match(arg)
+        if m:
+            out.append((m.group(1), path, line))
+    return out
+
+
+def scope_violations(root: str = PACKAGE_DIR, check_unused: bool = True) -> list:
+    """Named scopes that are not registered in schema.DEVICE_SCOPES or
+    are computed (anything but a literal, or `width` bound to f"w{...}"),
+    and registered scopes that no code uses."""
+    from graphmine_tpu.obs.schema import DEVICE_SCOPES
+
+    out, used = [], set()
+    for arg, path, line in _scan_scope_calls(root):
+        m = _SCOPE_LITERAL_RE.match(arg)
+        if m:
+            used.add(m.group(1))
+            if m.group(1) not in DEVICE_SCOPES:
+                out.append(
+                    f"{path}:{line}: named scope {m.group(1)!r} is not "
+                    "registered in graphmine_tpu/obs/schema.py (DEVICE_SCOPES)"
+                )
+        elif arg.startswith("width="):
+            if not arg.startswith('width=f"w{'):
+                out.append(
+                    f"{path}:{line}: `width` names a scope and must be "
+                    f'bound to f"w{{...}}", not {arg[6:]!r}'
+                )
+        elif arg != "width":
+            out.append(
+                f"{path}:{line}: computed scope name {arg!r} — scope names "
+                'are string literals (the degree class f"w{...}" through '
+                "`width` is the one exception)"
+            )
+    if check_unused:
+        out.extend(
+            f"graphmine_tpu/obs/schema.py: DEVICE_SCOPES lists {name!r}, "
+            "which no jax.named_scope uses"
+            for name in sorted(DEVICE_SCOPES - used)
+        )
+    return out
 
 
 def violations(root: str = PACKAGE_DIR) -> list:
@@ -193,6 +260,9 @@ def violations(root: str = PACKAGE_DIR) -> list:
         "single builder"
         for path, line in scan_inline_shard_records(root)
     )
+    # unused registrations are a fact about the package, not about
+    # whatever tree a caller points the lint at
+    out.extend(scope_violations(root, check_unused=root == PACKAGE_DIR))
     return out
 
 
