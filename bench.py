@@ -1867,9 +1867,9 @@ def main_roofline() -> None:
 def main_blocking() -> None:
     """Propagation-blocking micro-tier (ISSUE 7): measure the sequential
     binned-pass slots/s against the random-gather slots/s on the SAME
-    message volume, so the blocked-family crossover constant
-    (``ops/blocking.py``: BLOCKED_MIN_VERTICES / BLOCKED_MIN_MESSAGES) is
-    anchored to a hardware measurement instead of a capacity model.
+    message volume. (The crossover constants this was written to anchor
+    are gone: whole supersteps on the chip took ``blocked`` out of
+    ``plan="auto"``, ``ops/blocking.py`` policy comment, PR 26.)
 
     Three chained-feedback loops (the roofline tier's measurement
     discipline — one fori_loop dispatch, best-of-3 windows, data

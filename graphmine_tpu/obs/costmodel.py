@@ -75,11 +75,14 @@ ROOFLINE_SEEDS: dict = {
         # row reduce.
         "gather_slots_per_sec": 1.32e8,
         # Full binned-pass (stream + scatter) slots/s: SEEDED EQUAL to
-        # the random gather pending the silicon `blocking` capture — the
-        # capture whose `detail.binned_vs_random_gather` ratio is exactly
-        # the number that should replace this seed AND move the
-        # BLOCKED_MIN_* crossover constants (ROADMAP; tools/bench_diff.py
-        # prints the suggestion when it lands).
+        # the random gather; no `blocking` capture ever replaced it, and
+        # the chip has since said the seed is ~3.6x too high (PERF.md §6,
+        # PR 26; v5e, 128.3 M messages a superstep): monotone gather
+        # 2.40 s + scatter-through-sort 1.13 s = 36 M slots/s for the
+        # binned pass, then the tile-local row gather at 54 M slots/s,
+        # against 136 M slots/s for the bucketed family's one gather
+        # (the anchor above held). Not re-seeded: auto no longer
+        # resolves the blocked family and ROADMAP D2 deletes it.
         "binned_slots_per_sec": 1.32e8,
         # ICI exchange bytes/s per chip: NO bench tier measures this yet
         # — 4.5e10 B/s is a conservative v5e-interconnect model seed

@@ -49,6 +49,11 @@ hub's messages stay contiguous in its (oversized) bin tile and ride a
 wide sort row on the 1.5x-extended ladder — the blocked layout is also
 the gate to bigger-than-HBM graphs, since bins stream tile-by-tile
 instead of materializing one global gather.
+
+Measured on a TPU v5e (PR 26; the policy comment below has the numbers):
+the bet did not pay — both blocked gathers ran at 0.4x the rate of the
+one bucketed gather they replace, a superstep took 5.4x as long, and
+``plan="auto"`` resolves no graph to this family. It runs on request.
 """
 
 from __future__ import annotations
@@ -70,28 +75,26 @@ from graphmine_tpu.ops.bucketed_mode import (
 )
 
 # ---- plan-family crossover policy (single owner) ---------------------------
-# Measured provenance (same treatment as the r5 bucketed flip and the r6
-# IVF flip):
+# Measured provenance:
 #   * bucketed beats sort from ~2^16 messages (r1 measurement, the
 #     threshold label_propagation has shipped since; plan build amortizes
 #     past there).
-#   * blocked targets the regime where the value table no longer behaves
-#     cache-resident: the random gather pays full HBM latency per slot
-#     once the [V] int32 table is far beyond on-chip memory (VMEM ~16 MB
-#     => ~2^22 int32 entries; BLOCKED_MIN_VERTICES = 2^21 keeps one
-#     doubling of headroom below that wall), and the two-pass layout's
-#     extra tile traffic amortizes only at ~2^22+ messages. The measured
-#     anchor is the `blocking` bench tier (binned-pass vs random-gather
-#     slots/s on the same message volume — `python bench.py --tier
-#     blocking`, record `blocking_binned_slots_per_sec`); the current
-#     container only holds its CPU-fallback record
-#     (`blocking_binned_slots_per_sec_cpu_fallback`), so these constants
-#     are set from the VMEM capacity model above pending the silicon
-#     capture (ROADMAP backlog). Env overrides let a measured part move
-#     the wall without a code change.
+#   * blocked has NO auto path on one device (PR 26). Its premise — a
+#     gather from a value table past on-chip capacity costs several times
+#     a tile-local one — was measured on a TPU v5e at V = 2^22,
+#     M = 128.3 M (benchmark cell `cdlp-g500-22`; PERF.md §6, PR 26) and
+#     did not hold. Device seconds per LPA superstep: blocked 6.134 =
+#     bin_gather 2.40 (monotone indices into the 16 MB label vector,
+#     53 M elements/s) + bin_scatter 1.13 (lowered through a sort) +
+#     row_gather 2.50 (tile-local indices into the ~0.5 GB tile, the
+#     same rate); bucketed 1.139 = row_gather 1.02 (139 M padded slots
+#     straight from the same label vector, 136 M/s) + row_mode, hist and
+#     write_back 0.10. One bucketed job is 0.186x a blocked one. The
+#     family stays reachable as `sort` is — requested="blocked", a
+#     BlockedPlan passed as plan=, GRAPHMINE_SUPERSTEP_FAMILY=blocked,
+#     the sharded paths' build_blocked_plan=True — until ROADMAP D2
+#     deletes it.
 BUCKETED_MIN_MESSAGES = 1 << 16
-BLOCKED_MIN_MESSAGES = 1 << 22
-BLOCKED_MIN_VERTICES = 1 << 21
 
 # 2D edge partition with neighbor-only frontier exchange (r16): on a
 # >= 2-device mesh the exchange term is the scaling ceiling ROADMAP
@@ -126,16 +129,6 @@ def crossover_thresholds() -> dict:
     flip is explainable from the JSONL alone — ISSUE 12 satellite)."""
     return {
         "bucketed_min_messages": BUCKETED_MIN_MESSAGES,
-        "blocked_min_messages": int(
-            os.environ.get(
-                "GRAPHMINE_BLOCKED_MIN_MESSAGES", BLOCKED_MIN_MESSAGES
-            )
-        ),
-        "blocked_min_vertices": int(
-            os.environ.get(
-                "GRAPHMINE_BLOCKED_MIN_VERTICES", BLOCKED_MIN_VERTICES
-            )
-        ),
         "sharded2d_min_messages": int(
             os.environ.get(
                 "GRAPHMINE_SHARDED2D_MIN_MESSAGES", SHARDED2D_MIN_MESSAGES
@@ -158,14 +151,14 @@ def select_superstep_family(
     and ``pipeline/planner.plan_superstep``.
 
     Returns ``(family, reason)`` with ``family`` in :data:`FAMILIES`.
-    ``requested`` forces a family (still validated); the
-    ``GRAPHMINE_SUPERSTEP_FAMILY`` env var forces it process-wide, and
-    ``GRAPHMINE_BLOCKED_MIN_MESSAGES`` / ``GRAPHMINE_BLOCKED_MIN_VERTICES``
-    move the blocked crossover (tests, parts with different on-chip
-    capacity). ``weighted`` is accepted for signature stability: every
-    family carries the slot-aligned weight payload, so weights never
-    change the selection (the weighted contract is enforced at superstep
-    time — see :func:`lpa_superstep_blocked`).
+    ``requested`` forces a family (still validated) and the
+    ``GRAPHMINE_SUPERSTEP_FAMILY`` env var forces it process-wide: the
+    only two ways to ``blocked`` here, which ``auto`` never resolves
+    (the policy comment above has the measurement). ``weighted`` is
+    accepted for signature stability: every family carries the
+    slot-aligned weight payload, so weights never change the selection
+    (the weighted contract is enforced at superstep time — see
+    :func:`lpa_superstep_blocked`).
 
     ``num_devices`` (r16) gates the ``sharded_2d`` family: on a >= 2
     device mesh past ``SHARDED2D_MIN_MESSAGES`` the 2D edge partition's
@@ -190,7 +183,7 @@ def select_superstep_family(
             raise ValueError(
                 "superstep family 'sharded_2d' needs a >= 2-device mesh "
                 f"(num_devices={d}); its neighbor-only exchange has no "
-                "single-device meaning — use 'blocked' there"
+                "single-device meaning — use 'auto' there"
             )
         return requested, f"requested {requested!r}"
     env = os.environ.get("GRAPHMINE_SUPERSTEP_FAMILY")
@@ -209,14 +202,6 @@ def select_superstep_family(
             f"M={num_messages} >= {thr['sharded2d_min_messages']}: 2D edge "
             "partition — neighbor-only boundary exchange beats the "
             "4·Vc·(D-1)-byte label all_gather (bench tier 'exchange')"
-        )
-    min_m = thr["blocked_min_messages"]
-    min_v = thr["blocked_min_vertices"]
-    if num_messages >= min_m and num_vertices >= min_v:
-        return "blocked", (
-            f"V={num_vertices} >= {min_v} and M={num_messages} >= {min_m}: "
-            "value table past on-chip capacity — destination-binned tiles "
-            "beat the random-gather roofline (bench tier 'blocking')"
         )
     if num_messages >= BUCKETED_MIN_MESSAGES:
         return "bucketed", (

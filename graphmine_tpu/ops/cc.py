@@ -114,8 +114,9 @@ def connected_components(
     100M-edge cc bench tier in the r5 capture) — or a
     :class:`~graphmine_tpu.ops.blocking.BlockedPlan` (r7): supersteps run
     :func:`~graphmine_tpu.ops.blocking.cc_superstep_blocked`, the
-    destination-binned bin-then-reduce layout past the gather roofline.
-    The default ``"auto"`` resolves the family through
+    destination-binned bin-then-reduce layout (taken only on request:
+    its three passes took 5.4x the one bucketed gather on a v5e, PERF.md
+    §6, PR 26). The default ``"auto"`` resolves the family through
     :func:`~graphmine_tpu.ops.blocking.select_superstep_family` (the
     single crossover-policy owner; same per-graph plan cache as
     :func:`~graphmine_tpu.ops.lpa.label_propagation`); ``None`` forces

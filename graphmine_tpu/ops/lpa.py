@@ -66,12 +66,15 @@ def label_propagation(
     :class:`~graphmine_tpu.ops.bucketed_mode.BucketedModePlan` (the
     degree-bucketed dense mode kernel, ~3x the sort superstep at 10^7
     messages) or a :class:`~graphmine_tpu.ops.blocking.BlockedPlan` (the
-    propagation-blocking bin-then-reduce engine past the gather roofline)
+    propagation-blocking bin-then-reduce engine: three passes over the
+    messages where the bucketed plan makes one, 5.4x slower on a v5e at
+    128 M messages — PERF.md §6, PR 26 — and taken only on request)
     — identical labels either way, tested. The default ``"auto"``
     resolves the family through
     :func:`~graphmine_tpu.ops.blocking.select_superstep_family` (the
-    single crossover-policy owner) and builds the plan from the graph
-    (cached per graph, per family). Auto stays on the sort path when
+    single crossover-policy owner: ``bucketed`` or ``sort`` on one
+    device) and builds the plan from the graph (cached per graph, per
+    family). Auto stays on the sort path when
     custom ``init_labels`` are given (the fused plan's
     histogram/sentinel machinery assumes labels in ``[0, V)`` — the
     default ``arange`` initialization guarantees that, arbitrary labels

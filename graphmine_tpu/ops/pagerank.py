@@ -57,38 +57,17 @@ def pagerank(
     per-edge ``weights`` argument is edge-order-aligned, not CSR-aligned;
     a weighted run refuses loudly rather than silently dropping or
     misaligning weights — pass ``plan=None``). The default ``"auto"``
-    consults :func:`~graphmine_tpu.ops.blocking.select_superstep_family`
-    and flips to blocked only past the measured crossover on an eligible
-    graph; everything else keeps the segment_sum path bit-for-bit.
-    ``sink``: optional MetricsSink for the ``impl_selected`` /
-    ``plan_build`` provenance records.
+    is the segment_sum path at every size: PageRank has no bucketed
+    inflow, and the superstep policy
+    (:func:`~graphmine_tpu.ops.blocking.select_superstep_family`) has
+    resolved no graph to ``blocked`` since PR 26, so only an explicit
+    ``BlockedPlan`` takes the binned layout. ``sink``: optional
+    MetricsSink for the ``superstep_timing`` record.
     """
     from graphmine_tpu.ops.blocking import BlockedPlan
 
     resolved = None
-    if isinstance(plan, str) and plan == "auto":
-        if (
-            weights is None
-            and not graph.symmetric
-            and not isinstance(graph.msg_ptr, jax.core.Tracer)
-        ):
-            from graphmine_tpu.ops.blocking import (
-                emit_plan_records,
-                select_superstep_family,
-            )
-            from graphmine_tpu.ops.lpa import _cached_auto_plan
-
-            family, reason = select_superstep_family(
-                graph.num_vertices, graph.num_messages
-            )
-            if family == "blocked":
-                resolved, seconds, cached = _cached_auto_plan(graph, "blocked")
-                emit_plan_records(
-                    sink, "pagerank_inflow", resolved, reason, seconds,
-                    cached, graph.num_edges, graph.num_messages,
-                    num_vertices=graph.num_vertices,
-                )
-    elif isinstance(plan, BlockedPlan):
+    if isinstance(plan, BlockedPlan):
         if (
             plan.num_vertices != graph.num_vertices
             or plan.num_messages != graph.num_messages
@@ -115,7 +94,7 @@ def pagerank(
                 "silently dropped"
             )
         resolved = plan
-    elif plan is not None:
+    elif plan is not None and not (isinstance(plan, str) and plan == "auto"):
         raise ValueError(
             f"plan must be 'auto', None, or a BlockedPlan; got {plan!r}"
         )

@@ -300,12 +300,12 @@ def _run_pipeline(config: PipelineConfig, m: MetricsSink) -> PipelineResult:
     # graphframes, and sharded runs.
     wants_plan = run_plan is not None and run_plan.schedule == "single"
     # Which plan FAMILY that single-device path runs (r7): the planner
-    # resolves blocked vs bucketed at plan time through the single
-    # crossover-policy owner (ops/blocking.select_superstep_family), with
-    # the blocked→bucketed degradation rung — same provenance treatment
-    # as the r6 IVF flip. "sort" at tiny scale still builds the bucketed
-    # plan here (the shared-CSR-pass build is the historical single-path
-    # behavior; the plan is cheap exactly where "sort" wins).
+    # resolves it at plan time through the single crossover-policy owner
+    # (ops/blocking.select_superstep_family: bucketed or sort, blocked
+    # only when forced) with its degradation rung — same provenance
+    # treatment as the r6 IVF flip. "sort" at tiny scale still builds the
+    # bucketed plan here (the shared-CSR-pass build is the historical
+    # single-path behavior; the plan is cheap exactly where "sort" wins).
     sstep_plan = None
     if wants_plan:
         import dataclasses as _dc
@@ -1162,7 +1162,8 @@ def _run_lpa(
         # "single": the planner-resolved fused plan family — the
         # degree-bucketed kernel (ops/bucketed_mode.py, ~3x the sort
         # superstep) or the propagation-blocking bin-then-reduce engine
-        # (ops/blocking.py, past the gather roofline); identical labels
+        # (ops/blocking.py; only when forced, auto never resolves it
+        # on one device); identical labels
         # either way. The plan was built alongside the Graph from one
         # shared message-CSR pass (wants_plan in run_pipeline is true
         # exactly for this branch).

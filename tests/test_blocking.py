@@ -20,13 +20,12 @@ import pytest
 
 from graphmine_tpu.graph.container import build_graph
 from graphmine_tpu.ops.blocking import (
-    BLOCKED_MIN_MESSAGES,
-    BLOCKED_MIN_VERTICES,
     BUCKETED_MIN_MESSAGES,
     BlockedPlan,
     blocked_inflow,
     build_graph_and_blocked_plan,
     cc_superstep_blocked,
+    crossover_thresholds,
     lpa_superstep_blocked,
     plan_build_stats,
     select_superstep_family,
@@ -376,27 +375,66 @@ def test_family_policy_thresholds():
     assert fam == "sort" and "65536" in reason
     fam, _ = select_superstep_family(1000, BUCKETED_MIN_MESSAGES)
     assert fam == "bucketed"
-    # message count alone is not enough: the value table must also be
-    # past on-chip capacity for blocked to win
-    fam, _ = select_superstep_family(1000, BLOCKED_MIN_MESSAGES)
+    # one device: past the bucketed crossover no size resolves anything
+    # else — the value table past on-chip capacity (V >= 2^21) and
+    # M >= 2^22 sent auto to blocked until the chip showed its three
+    # passes 5.4x slower than the one bucketed gather (PERF.md, PR 26)
+    fam, _ = select_superstep_family(1000, 1 << 22)
     assert fam == "bucketed"
-    fam, reason = select_superstep_family(
-        BLOCKED_MIN_VERTICES, BLOCKED_MIN_MESSAGES
-    )
-    assert fam == "blocked" and "blocking" in reason
+    fam, reason = select_superstep_family(1 << 21, 1 << 22)
+    assert fam == "bucketed" and "blocking" not in reason
+    assert set(crossover_thresholds()) == {
+        "bucketed_min_messages", "sharded2d_min_messages",
+        "sharded2d_min_devices",
+    }
 
 
 def test_family_policy_env_overrides(monkeypatch):
+    # the retired crossover knobs decide nothing any more
     monkeypatch.setenv("GRAPHMINE_BLOCKED_MIN_MESSAGES", "1")
     monkeypatch.setenv("GRAPHMINE_BLOCKED_MIN_VERTICES", "1")
     fam, _ = select_superstep_family(100, BUCKETED_MIN_MESSAGES)
-    assert fam == "blocked"
+    assert fam == "bucketed"
     monkeypatch.setenv("GRAPHMINE_SUPERSTEP_FAMILY", "sort")
     fam, reason = select_superstep_family(1 << 24, 1 << 24)
     assert fam == "sort" and "env override" in reason
     monkeypatch.setenv("GRAPHMINE_SUPERSTEP_FAMILY", "nope")
     with pytest.raises(ValueError, match="GRAPHMINE_SUPERSTEP_FAMILY"):
         select_superstep_family(1 << 24, 1 << 24)
+
+
+@pytest.mark.parametrize(
+    "v,m,graph",
+    [
+        (1 << 22, 1 << 27, "power_law"),   # the benchmark's cdlp-g500-22
+        (1 << 24, 1 << 28, "dup_edges"),
+        (1 << 21, 1 << 22, "isolated"),    # the old thresholds themselves
+    ],
+)
+def test_auto_never_resolves_blocked_on_one_device(v, m, graph):
+    """``auto`` resolves ``bucketed`` at every size that used to flip to
+    ``blocked``; an explicit request still resolves ``blocked``, builds
+    its plan through the auto plan cache and runs bit-identical."""
+    from graphmine_tpu.ops.lpa import _cached_auto_plan
+    from graphmine_tpu.pipeline.planner import plan_superstep
+
+    fam, reason = select_superstep_family(v, m)
+    assert fam == "bucketed" and "blocked" not in reason
+    assert plan_superstep(v, m).family == "bucketed"
+    fam, reason = select_superstep_family(v, m, requested="blocked")
+    assert fam == "blocked" and "requested" in reason
+
+    src, dst, nv = GRAPHS[graph](np.random.default_rng(3))
+    g = build_graph(src, dst, num_vertices=nv)
+    plan, _, cached = _cached_auto_plan(g, fam)
+    assert isinstance(plan, BlockedPlan) and not cached
+    ref = np.asarray(label_propagation(g, 5, plan=None))
+    np.testing.assert_array_equal(
+        ref, np.asarray(label_propagation(g, 5, plan=plan))
+    )
+    np.testing.assert_array_equal(
+        ref, np.asarray(label_propagation(g, 5, plan="auto"))
+    )
 
 
 def test_family_policy_requested_validation():
@@ -412,7 +450,10 @@ def test_planner_superstep_plan_and_ladder():
         plan_superstep,
     )
 
-    p = plan_superstep(BLOCKED_MIN_VERTICES, BLOCKED_MIN_MESSAGES)
+    # auto never plans the blocked family; requested explicitly it
+    # still carries its blocked→bucketed rung
+    assert plan_superstep(1 << 21, 1 << 22).family == "bucketed"
+    p = plan_superstep(1 << 21, 1 << 22, requested="blocked")
     assert p.family == "blocked" and p.degrade_to == "bucketed"
     p2 = plan_superstep(1000, BUCKETED_MIN_MESSAGES)
     assert p2.family == "bucketed" and p2.degrade_to == "sort"
@@ -428,8 +469,8 @@ def test_planner_superstep_plan_and_ladder():
 
 
 def test_auto_seam_resolves_blocked_with_parity(monkeypatch):
-    """With the crossover forced down, plan='auto' flips LPA and CC to
-    the blocked family end-to-end — identical labels, and the
+    """With the family forced process-wide, plan='auto' flips LPA and CC
+    to the blocked family end-to-end — identical labels, and the
     impl_selected + plan_build provenance pair lands in the sink,
     schema-valid."""
     from graphmine_tpu.obs.schema import validate_records

@@ -392,13 +392,16 @@ def test_lof_impl_selected_carries_threshold_and_cost():
 def test_superstep_auto_seam_impl_selected_carries_thresholds(monkeypatch):
     from graphmine_tpu.ops.lpa import label_propagation
 
-    monkeypatch.setenv("GRAPHMINE_BLOCKED_MIN_MESSAGES", "123")
+    monkeypatch.setenv("GRAPHMINE_SHARDED2D_MIN_MESSAGES", "123")
     m = _sink()
     label_propagation(ring4(), max_iter=1, sink=m)
     (sel,) = [r for r in m.records if r["phase"] == "impl_selected"]
     # the env-overridden constant is what the record ships — the value
     # that actually decided, not the compiled-in default
-    assert sel["thresholds"]["blocked_min_messages"] == 123
+    assert sel["thresholds"]["sharded2d_min_messages"] == 123
+    # only constants that decide something ship (PR 26: auto has no
+    # blocked crossover on one device)
+    assert not [k for k in sel["thresholds"] if k.startswith("blocked")]
     assert sel["cost"]["family"] == sel["impl"]
 
 
@@ -672,10 +675,10 @@ def test_bench_diff_crossover_suggestion_on_silicon_blocking(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "blocked-crossover suggestion" in out
     assert "1.90x" in out
-    assert "BLOCKED_MIN_VERTICES" in out
-    # the constants are parsed from ops/blocking.py source (stdlib-only)
-    consts = bench_diff._current_blocked_constants()
-    assert consts["BLOCKED_MIN_MESSAGES"] == 1 << 22
-    assert consts["BLOCKED_MIN_VERTICES"] == 1 << 21
+    assert "GRAPHMINE_SUPERSTEP_FAMILY=blocked" in out
+    # no crossover constant is left to parse or to suggest a value for
+    # (PR 26: auto resolves no graph to blocked)
+    assert "BLOCKED_MIN" not in out
+    assert not hasattr(bench_diff, "_current_blocked_constants")
     # a CPU-fallback ratio must NOT produce a suggestion
     capsys.readouterr()

@@ -23,11 +23,10 @@ holding the artifacts):
   trajectory — the machine-readable replacement for ROADMAP's
   hand-maintained "Silicon capture backlog" list.
 - **Crossover suggestion**: when a real (non-fallback) ``blocking``
-  capture lands, its ``detail.binned_vs_random_gather`` ratio is
-  compared against the VMEM-capacity-model constants in
-  ``ops/blocking.py`` (parsed from source — this tool must not import
-  jax) and a concrete ``BLOCKED_MIN_*`` update is suggested, closing
-  the loop ROADMAP names.
+  capture lands, its ``detail.binned_vs_random_gather`` ratio is set
+  beside the whole-superstep measurement that took the ``blocked``
+  family out of ``plan="auto"`` (PERF.md §6, PR 26), with what the
+  ratio would have to be followed by before an auto branch returns.
 
 Inputs: ``BENCH_*.json`` driver artifacts (``{n, cmd, rc, tail,
 parsed}`` — ``tail`` holds the stdout tail's JSON record lines,
@@ -478,29 +477,12 @@ def silicon_manifest(captures: list) -> dict:
 # ---- crossover suggestion --------------------------------------------------
 
 
-def _current_blocked_constants() -> dict:
-    """BLOCKED_MIN_* parsed from ops/blocking.py SOURCE (this tool is
-    stdlib-only and must not import the jax-loading ops layer)."""
-    path = os.path.join(_REPO, "graphmine_tpu", "ops", "blocking.py")
-    out = {}
-    try:
-        with open(path) as f:
-            src = f.read()
-        for name in ("BLOCKED_MIN_MESSAGES", "BLOCKED_MIN_VERTICES"):
-            m = re.search(rf"^{name}\s*=\s*(.+)$", src, re.M)
-            if m:
-                out[name] = int(eval(m.group(1), {"__builtins__": {}}))  # noqa: S307 — literal like `1 << 22` from our own source
-    except OSError:
-        pass
-    return out
-
-
 def crossover_suggestion(captures: list) -> list:
     """When a real (non-fallback) ``blocking`` capture carries
-    ``detail.binned_vs_random_gather``, suggest what the measured ratio
-    means for the ``BLOCKED_MIN_*`` crossover constants (which today
-    encode a VMEM capacity model, not a measurement — ROADMAP names this
-    exact loop). Empty list until that capture lands."""
+    ``detail.binned_vs_random_gather``, say what the measured ratio
+    means for the ``blocked`` family, which ``plan="auto"`` resolves on
+    no graph (``ops/blocking.py`` policy comment: whole supersteps on a
+    TPU v5e, PERF.md §6, PR 26). Empty list until that capture lands."""
     best = None
     for cap in reversed(captures):  # newest capture wins
         entry = cap["tiers"].get("blocking")
@@ -513,34 +495,32 @@ def crossover_suggestion(captures: list) -> list:
     if best is None:
         return []
     label, ratio = best
-    consts = _current_blocked_constants()
-    cur = ", ".join(f"{k}={v:,}" for k, v in consts.items()) or "(unparsed)"
     lines = [
         f"  silicon blocking capture in {label}: "
         f"binned_vs_random_gather = {ratio:.2f}x",
-        f"  current crossover constants (ops/blocking.py): {cur}",
+        "  auto resolves no graph to blocked (ops/blocking.py): a whole "
+        "blocked LPA superstep took 6.13 s against 1.14 s bucketed on a "
+        "v5e at 128 M messages (PERF.md, PR 26)",
     ]
     if ratio >= 1.05:
         lines.append(
-            "  suggestion: the binned pass BEATS the random gather on "
-            "silicon — lower BLOCKED_MIN_VERTICES/BLOCKED_MIN_MESSAGES "
-            "(or set GRAPHMINE_BLOCKED_MIN_* to deploy first) so the "
-            "blocked family engages below the VMEM-model wall; re-run "
-            "the blocking tier at the candidate sizes to place the new "
-            "crossover"
+            "  suggestion: the binned pass BEATS the random gather in "
+            "this capture, which whole supersteps did not show — before "
+            "an auto branch returns, A/B whole jobs of the benchmark's "
+            "cdlp-g500-22 cell with GRAPHMINE_SUPERSTEP_FAMILY=blocked "
+            "against the default"
         )
     elif ratio <= 0.95:
         lines.append(
-            "  suggestion: the binned pass LOSES to the random gather at "
-            "the measured size — raise BLOCKED_MIN_* (the VMEM model was "
-            "optimistic) and re-measure at larger V before deploying "
-            "blocked by default"
+            "  suggestion: the binned pass LOSES to the random gather, "
+            "as whole supersteps did — blocked stays on request only "
+            "(ROADMAP D2 deletes the family)"
         )
     else:
         lines.append(
-            "  suggestion: measured ratio is within noise of 1.0 — keep "
-            "the VMEM-model constants; the crossover decision needs a "
-            "larger-V capture"
+            "  suggestion: measured ratio is within noise of 1.0 — the "
+            "blocked family then pays its two further passes for "
+            "nothing; it stays on request only"
         )
     return lines
 
