@@ -2000,9 +2000,9 @@ def main_exchange() -> None:
     (``sharded_superstep_cost``: ``4·Vc·(D-1)`` vs
     ``4·Σ_peer |boundary|``). The headline is the neighbor/all_gather
     bytes fraction at the largest measured D; ``detail`` carries the
-    per-D seconds, bytes and boundary fractions the crossover policy
-    (``ops/blocking.SHARDED2D_MIN_*``) should eventually be re-seeded
-    from.
+    per-D seconds, bytes and boundary fractions (the 2D family has no
+    ``auto`` path since PR 27: four chips read it slower than the bucket
+    rows' one all_gather, PERF.md §6).
 
     Honest-capture note: multi-device meshes need actual devices, so
     the orchestrator runs this tier on an 8-virtual-CPU-device mesh

@@ -39,6 +39,7 @@ _EXPORTS = {
     "load_parquet_edges": ("graphmine_tpu.io.edges", "load_parquet_edges"),
     "load_edge_list": ("graphmine_tpu.io.edges", "load_edge_list"),
     "label_propagation": ("graphmine_tpu.ops.lpa", "label_propagation"),
+    "make_mesh": ("graphmine_tpu.parallel.mesh", "make_mesh"),
     "connected_components": ("graphmine_tpu.ops.cc", "connected_components"),
     "leiden": ("graphmine_tpu.ops.louvain", "leiden"),
     "louvain": ("graphmine_tpu.ops.louvain", "louvain"),

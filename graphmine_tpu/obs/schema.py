@@ -125,6 +125,15 @@ register("shard_exchange", "op", "family", "devices", "peers",
          "exchange_bytes", "frontier_bytes", "ladder_bytes",
          "frontier_frac")
 
+# exchange: one per `label_propagation(..., mesh=)` call (ops/lpa.py), exact
+# from the placed partition: the bytes one chip receives per superstep
+# (4·Vc·(D-1) for the all_gather families, 4·(D-1)·B for sharded_2d's
+# padded boundary buffers), how uneven the vertex-range shards are in
+# messages, and the padded gather slots a shard streams per superstep.
+register("exchange", "op", "family", "shards", "bytes_per_superstep",
+         "messages_per_shard_max", "messages_per_shard_mean",
+         "padded_slots_per_shard")
+
 # ---- serving records (docs/SERVING.md) ------------------------------------
 register("snapshot_publish", "version", "snapshot_id", "path", "bytes",
          "arrays", "seconds")
@@ -292,13 +301,13 @@ RECOVERY_PHASES = frozenset((
 DEVICE_SCOPES = frozenset((
     # outer: algorithm x family
     "lpa_blocked", "cc_blocked", "lpa_bucketed", "cc_bucketed",
-    "lpa_sort", "cc_sort", "masked_lpa", "superstep", "census",
+    "lpa_sort", "cc_sort", "lpa_sharded", "masked_lpa", "superstep", "census",
     "modularity", "features", "triangles", "ivf", "knn_exact",
     "knn_cross", "lof",
     # inner: superstep passes
     "bin_gather", "bin_scatter", "row_gather", "row_mode", "row_min",
     "hist", "write_back", "pointer_jump", "msg_gather", "segment_mode",
-    "segment_min", "sort", "run_reduce", "mask",
+    "segment_min", "sort", "run_reduce", "mask", "exchange",
     "changed_count", "converged",
     # inner: census / modularity
     "sizes", "edge_counts", "q",

@@ -96,21 +96,24 @@ from graphmine_tpu.ops.bucketed_mode import (
 #     deletes it.
 BUCKETED_MIN_MESSAGES = 1 << 16
 
-# 2D edge partition with neighbor-only frontier exchange (r16): on a
-# >= 2-device mesh the exchange term is the scaling ceiling ROADMAP
-# names — the one-all_gather families ship 4·Vc·(D-1) bytes per chip per
-# superstep regardless of how small the live frontier is, while the 2D
-# family ships 4·Σ_peer |boundary(peer)| (range-partitioned power-law
-# CSRs keep boundaries well under Vc; serve-path repair frontiers keep
-# them near empty). The message floor mirrors the bucketed crossover's
-# rationale: the per-peer boundary tables are one more O(M log M) host
-# pass (one sorted-unique per shard + a positional remap), which below
-# ~16K messages would dominate the run it plans. Unmeasured
-# on silicon yet (the `exchange` bench tier is the capture point — its
-# modeled-bytes record is honest on CPU); env overrides move the wall
-# without a code change.
-SHARDED2D_MIN_MESSAGES = 1 << 14
-SHARDED2D_MIN_DEVICES = 2
+# On a mesh of >= 2 devices `auto` is the same per-class bucket rows, built
+# per vertex-range shard, with the labels replicated and exchanged by one
+# tiled all_gather a superstep (`parallel/sharded.py:_lpa_shard_body_bucketed`).
+# Read off four TPU v5e chips on the graphalytics-g500-22 draw (V = 2^22,
+# M = 128.3 M, 10 supersteps, labels equal across the three and to the
+# reference; PERF.md §6, PR 27): bucket rows + all_gather 2.748 s a job,
+# sharded_2d 12.397 s (4.5x), the ring schedule 33.291 s (12.1x). Of the
+# 2.748 s, 2.483 s a chip is lpa_sharded/row_gather (36.5 M padded slots a
+# shard a superstep, 147 M slots/s) and 0.0014 s lpa_sharded/exchange: the
+# 12.6 MB a chip receives per superstep is 0.05 % of the job, so a schedule
+# that shrinks the exchange (2D ships 5.0 MB) has nothing to win, and the
+# 2D family pays for it with blocked's stream + tile + rows (69.4 M padded
+# slots a shard, a 20.9 s plan build against 0.9 s).
+# `sharded_2d` (labels sharded, per-peer boundary `ppermute`s, then blocked's
+# stream + tile + rows) keeps no auto path: reachable by
+# requested="sharded_2d" / GRAPHMINE_SUPERSTEP_FAMILY=sharded_2d until ROADMAP
+# D2, as `blocked` is. The ring schedule (`parallel/ring.py`) is the planner's
+# memory rung for label vectors that do not fit replicated, not a speed choice.
 
 #: One bin's message-tile budget (int32 slots). 2^18 slots = 1 MiB —
 #: small against the ~16 MB/core VMEM so the tile, its row matrices and
@@ -127,19 +130,7 @@ def crossover_thresholds() -> dict:
     both the selection itself (:func:`select_superstep_family`) and the
     provenance records (``impl_selected`` carries this dict, so a policy
     flip is explainable from the JSONL alone — ISSUE 12 satellite)."""
-    return {
-        "bucketed_min_messages": BUCKETED_MIN_MESSAGES,
-        "sharded2d_min_messages": int(
-            os.environ.get(
-                "GRAPHMINE_SHARDED2D_MIN_MESSAGES", SHARDED2D_MIN_MESSAGES
-            )
-        ),
-        "sharded2d_min_devices": int(
-            os.environ.get(
-                "GRAPHMINE_SHARDED2D_MIN_DEVICES", SHARDED2D_MIN_DEVICES
-            )
-        ),
-    }
+    return {"bucketed_min_messages": BUCKETED_MIN_MESSAGES}
 
 
 def select_superstep_family(
@@ -160,18 +151,17 @@ def select_superstep_family(
     (the weighted contract is enforced at superstep time — see
     :func:`lpa_superstep_blocked`).
 
-    ``num_devices`` (r16) gates the ``sharded_2d`` family: on a >= 2
-    device mesh past ``SHARDED2D_MIN_MESSAGES`` the 2D edge partition's
-    neighbor-only exchange replaces the per-superstep label all_gather
-    (``parallel/sharded.py``: labels sharded, per-peer boundary
-    ``ppermute``). Single-device resolutions (every fused caller) never
-    see it; an explicit ``requested="sharded_2d"`` on fewer than 2
-    devices is a loud error, while the process-wide env override simply
-    does not apply there (it targets the sharded paths; raising would
-    break the fused ops under a global override).
+    ``num_devices``: on a mesh of >= 2 devices ``auto`` is ``bucketed``
+    at every size — the per-shard bucket rows with replicated labels and
+    one ``all_gather`` a superstep (the policy comment above has the
+    four-chip reading). ``sharded_2d`` (labels sharded, per-peer boundary
+    ``ppermute``) is resolved only on request, and only there: an
+    explicit ``requested="sharded_2d"`` on fewer than 2 devices is a loud
+    error, while the process-wide env override simply does not apply
+    there (it targets the sharded paths; raising would break the fused
+    ops under a global override).
     """
     del weighted
-    thr = crossover_thresholds()
     d = int(num_devices)
     if requested != "auto":
         if requested not in FAMILIES:
@@ -193,15 +183,12 @@ def select_superstep_family(
                 f"GRAPHMINE_SUPERSTEP_FAMILY={env!r} is not one of {FAMILIES}"
             )
         return env, f"GRAPHMINE_SUPERSTEP_FAMILY={env} (env override)"
-    if (
-        d >= thr["sharded2d_min_devices"]
-        and num_messages >= thr["sharded2d_min_messages"]
-    ):
-        return "sharded_2d", (
-            f"D={d} >= {thr['sharded2d_min_devices']} and "
-            f"M={num_messages} >= {thr['sharded2d_min_messages']}: 2D edge "
-            "partition — neighbor-only boundary exchange beats the "
-            "4·Vc·(D-1)-byte label all_gather (bench tier 'exchange')"
+    if d >= 2:
+        return "bucketed", (
+            f"D={d}: per-shard degree-bucketed rows, labels replicated, one "
+            "all_gather a superstep (four v5e chips at M=128.3 M: 2.75 s a "
+            "job against 12.40 s sharded_2d and 33.29 s ring, the exchange "
+            "0.05 % of it; PERF.md PR 27)"
         )
     if num_messages >= BUCKETED_MIN_MESSAGES:
         return "bucketed", (

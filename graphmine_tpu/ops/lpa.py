@@ -55,6 +55,7 @@ def label_propagation(
     return_history: bool = False,
     plan="auto",
     sink=None,
+    mesh=None,
 ):
     """Run ``max_iter`` LPA supersteps; returns int32 labels ``[V]``.
 
@@ -86,7 +87,28 @@ def label_propagation(
     ``plan_build`` record (family, build seconds, bins/buckets, padded
     slots/edge), so host plan cost is visible in obs_report instead of
     hiding inside first-call latency.
+
+    ``mesh``: a ``jax.sharding.Mesh`` runs the job across its devices
+    (``None`` is the one-device path above, byte for byte). The graph
+    may — and past one chip's memory must — be host-resident
+    (``build_graph(..., to_device=False)``): it is partitioned into
+    vertex-range shards once per (graph, mesh, family), each shard placed
+    straight on its device, and all ``max_iter`` supersteps run as one
+    compiled program (:func:`~graphmine_tpu.parallel.sharded.
+    sharded_label_propagation`); no device ever holds the whole edge list
+    or message CSR. ``plan`` is then ``"auto"`` or a family name, and the
+    family comes from ``select_superstep_family(..., num_devices=D)``.
+    ``sink`` receives ``impl_selected``, ``partition``, ``plan_build`` and
+    one ``exchange`` record per call (bytes a chip receives per superstep,
+    messages and padded slots per shard). ``return_history`` is the
+    one-device path's.
     """
+    if mesh is not None:
+        if return_history:
+            raise ValueError("return_history is not available with mesh=")
+        return _mesh_label_propagation(
+            graph, mesh, max_iter, init_labels, plan, sink
+        )
     from graphmine_tpu.ops.blocking import BlockedPlan, emit_plan_records
     from graphmine_tpu.ops.bucketed_mode import BucketedModePlan
 
@@ -202,6 +224,126 @@ def _cached_auto_plan(graph: Graph, family: str = "bucketed"):
         raise ValueError(f"no plan to build for family {family!r}")
     plans[family] = plan
     return plan, seconds, False
+
+
+_mesh_partition_cache: dict = {}
+
+
+def _mesh_label_propagation(graph, mesh, max_iter, init_labels, plan, sink):
+    """The mesh half of :func:`label_propagation`: resolve the family,
+    partition and place the graph (cached), emit the provenance records,
+    run the one compiled program."""
+    from graphmine_tpu.ops.blocking import (
+        crossover_thresholds,
+        select_superstep_family,
+    )
+    from graphmine_tpu.parallel.sharded import sharded_label_propagation
+
+    if not isinstance(plan, str):
+        raise ValueError(
+            "with mesh=, plan is 'auto' or a superstep family name; got "
+            f"{plan!r}"
+        )
+    family, reason = select_superstep_family(
+        graph.num_vertices, graph.num_messages, requested=plan,
+        weighted=graph.msg_weight is not None, num_devices=mesh.size,
+    )
+    sg, stats, cached = _cached_mesh_partition(graph, mesh, family)
+    if sink is not None:
+        cost = stats["cost"]
+        sink.emit(
+            "impl_selected", op="lpa_superstep", impl=family,
+            n=graph.num_messages, reason=reason, devices=mesh.size,
+            thresholds=crossover_thresholds(), cost=cost,
+        )
+        sink.emit(
+            "partition", shards=mesh.size, family=family, cached=cached,
+            schedule="sharded_2d" if family == "sharded_2d" else "replicated",
+            seconds=0.0 if cached else round(stats["partition_seconds"], 6),
+        )
+        if family != "sort":
+            sink.emit(
+                "plan_build", op="lpa_superstep", family=family, cached=cached,
+                seconds=0.0 if cached else round(stats["plan_seconds"], 6),
+                width_classes=stats["width_classes"],
+                padded_slots_per_edge=round(
+                    stats["padded_slots_per_shard"] * mesh.size
+                    / max(graph.num_edges, 1), 3,
+                ),
+                cost=cost,
+            )
+        sink.emit("exchange", op="lpa_superstep", family=family, **stats["exchange"])
+    return sharded_label_propagation(sg, mesh, max_iter, init_labels)
+
+
+def _cached_mesh_partition(graph: Graph, mesh, family: str):
+    """``(sharded graph on the mesh, stats, cached)`` per (graph, mesh,
+    family): the host partition, its plan and the placement are paid once,
+    as :func:`_cached_auto_plan` pays a plan once. Keyed by the identity
+    of the graph's ``msg_ptr`` (host or device array); a weakref finalizer
+    evicts the entry with it. Only the placed shards are kept — the host
+    copies go as soon as they are on the devices."""
+    import time
+    import weakref
+
+    import numpy as np
+
+    from graphmine_tpu.obs.costmodel import sharded_superstep_cost
+    from graphmine_tpu.parallel.sharded import (
+        FAMILY_PARTITION_FLAGS,
+        _shard_message_offsets,
+        partition_graph,
+        shard_graph_arrays,
+    )
+
+    key = id(graph.msg_ptr)
+    hit = _mesh_partition_cache.get(key)
+    if hit is None or hit[0]() is not graph.msg_ptr:
+        ref = weakref.ref(
+            graph.msg_ptr, lambda _, k=key: _mesh_partition_cache.pop(k, None)
+        )
+        hit = (ref, {})
+        _mesh_partition_cache[key] = hit
+    placed = hit[1]
+    where = (tuple(d.id for d in mesh.devices.flat), mesh.axis_names, family)
+    if where in placed:
+        return (*placed[where], True)
+    lpa_only = family != "sort"
+    timings: dict = {}
+    t0 = time.perf_counter()
+    sg = shard_graph_arrays(
+        partition_graph(
+            graph, mesh=mesh, lpa_only=lpa_only, timings=timings,
+            **FAMILY_PARTITION_FLAGS[family],
+        ),
+        mesh, lpa_only=lpa_only,
+    )
+    jax.block_until_ready(sg)  # the transfer is set-up's, not the first job's
+    seconds = time.perf_counter() - t0
+    # shapes only: the padded slots a shard streams and the bytes a chip
+    # receives per superstep have one owner, the cost model
+    cost = sharded_superstep_cost(
+        "lpa_superstep", sg, graph.num_edges, num_messages=graph.num_messages
+    )
+    counts = np.diff(_shard_message_offsets(
+        np.asarray(graph.msg_ptr), sg.num_shards, sg.chunk_size
+    ))
+    stats = {
+        "partition_seconds": seconds - timings["plan_seconds"],
+        "plan_seconds": timings["plan_seconds"],
+        "width_classes": len(sg.bucket_send or sg.blk_row_idx),
+        "padded_slots_per_shard": cost.padded_slots,
+        "cost": cost.record(),
+        "exchange": {
+            "shards": sg.num_shards,
+            "bytes_per_superstep": cost.exchange_bytes,
+            "messages_per_shard_max": int(counts.max()),
+            "messages_per_shard_mean": float(counts.mean()),
+            "padded_slots_per_shard": cost.padded_slots,
+        },
+    }
+    placed[where] = (sg, stats)
+    return sg, stats, False
 
 
 @partial(jax.jit, static_argnames=("max_iter", "return_history"))

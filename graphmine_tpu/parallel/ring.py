@@ -93,9 +93,16 @@ def _lpa_ring_body(own, recv_local, send, deg, *, chunk_size, num_shards):
     """Per-device ring LPA superstep: ring-gather sender labels →
     shard-local segment-mode → select. Output stays sharded."""
     recv_local, send, deg = recv_local[0], send[0], deg[0]
-    msg = _ring_gather(own, send, num_shards=num_shards, chunk_size=chunk_size)
-    mode, _ = segment_mode(recv_local, msg, num_segments=chunk_size)
-    return jnp.where(deg > 0, mode, own).astype(jnp.int32)
+    with jax.named_scope("lpa_sharded"):
+        # the rotation and the gather from the chunk in hand are one loop:
+        # the whole of it is the superstep's exchange
+        with jax.named_scope("exchange"):
+            msg = _ring_gather(
+                own, send, num_shards=num_shards, chunk_size=chunk_size
+            )
+        mode, _ = segment_mode(recv_local, msg, num_segments=chunk_size)
+        with jax.named_scope("write_back"):
+            return jnp.where(deg > 0, mode, own).astype(jnp.int32)
 
 
 def _lpa_ring_body_weighted(own, recv_local, send, deg, w, *, chunk_size,

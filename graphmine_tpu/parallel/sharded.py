@@ -26,6 +26,7 @@ are fully sharded.
 from __future__ import annotations
 
 import dataclasses
+import time
 from dataclasses import dataclass
 from functools import partial
 
@@ -37,6 +38,12 @@ from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from graphmine_tpu.graph.container import Graph, build_graph
+from graphmine_tpu.ops.bucketed_mode import (
+    _SENTINEL,
+    _bucket_mode,
+    _bucket_wmode,
+    _extend_widths,
+)
 from graphmine_tpu.ops.segment import segment_mode
 from graphmine_tpu.pipeline.resilience import DivergenceError
 
@@ -364,6 +371,8 @@ def partition_graph(
     build_blocked_plan: bool = False,
     blocked_tile_slots: int | None = None,
     build_plan2d: bool = False,
+    lpa_only: bool = False,
+    timings: dict | None = None,
 ) -> ShardedGraph:
     """Partition a graph's message CSR into vertex-range shards (host-side).
 
@@ -384,12 +393,27 @@ def partition_graph(
     tables of the ``sharded_2d`` family (labels sharded, neighbor-only
     ``ppermute`` exchange instead of the full all_gather); the blocked
     stream ids are remapped onto the compact per-shard label table and
-    ``blk_src`` is dropped.
+    ``blk_src`` is dropped. ``lpa_only`` (needs one of the three plans)
+    never materializes the sort-body arrays ``shard_graph_arrays(...,
+    lpa_only=True)`` would drop anyway: at 10^9 messages they are 8 GB of
+    host copies nothing reads. ``timings``, when given, receives
+    ``plan_seconds``: the part of this call spent in the plan builders
+    (the ``plan_build`` record's seconds; the rest is slicing).
+
+    The per-shard work (slice copies and every plan builder's per-shard
+    pass) runs in threads, one per shard or per (shard, class): NumPy
+    releases the interpreter lock in its copies, sorts and gathers, so
+    set-up is not D x serial.
     """
     if build_bucket_plan and (build_blocked_plan or build_plan2d):
         raise ValueError(
             "build_bucket_plan and build_blocked_plan/build_plan2d are "
             "mutually exclusive — one plan family per partition"
+        )
+    if lpa_only and not (build_bucket_plan or build_blocked_plan or build_plan2d):
+        raise ValueError(
+            "lpa_only drops the sort-body arrays; pass build_bucket_plan, "
+            "build_blocked_plan or build_plan2d with it"
         )
     if mesh is not None and num_shards is None:
         num_shards = mesh.size
@@ -412,11 +436,8 @@ def partition_graph(
     d = num_shards
     vc = -(-num_vertices // d)  # ceil
     vc = -(-vc // pad_multiple) * pad_multiple
-    # recv is CSR-sorted ascending: shard boundaries come from d binary
-    # searches instead of an O(M) divide + bincount pass.
-    offsets = np.zeros(d + 1, dtype=np.int64)
-    offsets[1:-1] = np.searchsorted(recv, np.arange(1, d) * vc)
-    offsets[-1] = len(recv)
+    ptr = np.asarray(g.msg_ptr, dtype=np.int64)
+    offsets = _shard_message_offsets(ptr, d, vc)
     counts = np.diff(offsets)
     mp = max(int(counts.max(initial=0)), 1)
     mp = -(-mp // pad_multiple) * pad_multiple
@@ -438,27 +459,33 @@ def partition_graph(
 
     # Per-shard slice copies write straight into the padded rows (no temp
     # per shard, no full-array pre-fill — only the padded tails are filled).
-    recv_local = np.empty((d, mp), dtype=np.int32)
+    recv_local = None if lpa_only else np.empty((d, mp), dtype=np.int32)
     send_pad = np.empty((d, mp), dtype=np.int32)
     w_pad = None if w_msg is None else np.zeros((d, mp), dtype=np.float32)
-    for s in range(d):
+
+    def slice_shard(s):
         lo, hi = offsets[s], offsets[s + 1]
         n = hi - lo
-        np.subtract(recv[lo:hi], s * vc, out=recv_local[s, :n], casting="unsafe")
-        recv_local[s, n:] = vc  # Vc = drop sentinel
+        if recv_local is not None:
+            np.subtract(
+                recv[lo:hi], s * vc, out=recv_local[s, :n], casting="unsafe"
+            )
+            recv_local[s, n:] = vc  # Vc = drop sentinel
         send_pad[s, :n] = send[lo:hi]
         send_pad[s, n:] = 0
         if w_pad is not None:
             w_pad[s, :n] = w_msg[lo:hi]
 
+    _in_threads(slice_shard, range(d))
+
     # Degrees come free from the CSR pointer (O(V) diff, not an O(M)
     # bincount over the messages); padded vertices get degree 0.
-    ptr = np.asarray(g.msg_ptr, dtype=np.int64)
     deg = np.zeros(d * vc, dtype=np.int32)
     deg[:num_vertices] = np.diff(ptr).astype(np.int32)
     deg = deg.reshape(d, vc)
 
     bucket_send, bucket_target, bucket_weight = (), (), ()
+    t_plan = time.perf_counter()
     if build_bucket_plan:
         bucket_send, bucket_target, bucket_weight = _build_shard_bucket_plan(
             deg, send_pad, counts, vc, d, w_pad
@@ -470,23 +497,62 @@ def partition_graph(
         )
     if build_plan2d:
         blk.update(_build_shard_plan2d(blk.pop("blk_src"), vc, d, pad_multiple))
+    if timings is not None:
+        timings["plan_seconds"] = time.perf_counter() - t_plan
 
     # Fields stay host-side (NumPy): shard_graph_arrays does the one
     # device placement, directly to the mesh sharding — no staging copy
     # on the default device.
     return ShardedGraph(
         msg_recv_local=recv_local,
-        msg_send=send_pad,
-        degrees=deg,
+        msg_send=None if lpa_only else send_pad,
+        degrees=None if lpa_only else deg,
         num_vertices=num_vertices,
         chunk_size=vc,
         num_shards=d,
         bucket_send=bucket_send,
         bucket_target=bucket_target,
-        msg_weight=w_pad,
+        msg_weight=None if lpa_only else w_pad,
         bucket_weight=bucket_weight,
         **blk,
     )
+
+
+#: What ``partition_graph`` builds for each superstep family on a mesh
+#: (`ops/blocking.select_superstep_family(..., num_devices=D)` names the
+#: family; the mesh entry of ``ops/lpa.py`` and the pipeline's replicated
+#: schedule both read the flags here).
+FAMILY_PARTITION_FLAGS = {
+    "bucketed": {"build_bucket_plan": True},
+    "blocked": {"build_blocked_plan": True},
+    "sharded_2d": {"build_plan2d": True},
+    "sort": {},
+}
+
+
+def _in_threads(fn, tasks) -> list:
+    """``[fn(t) for t in tasks]`` in threads (NumPy releases the
+    interpreter lock in its copies, sorts and gathers)."""
+    import os
+    from concurrent.futures import ThreadPoolExecutor
+
+    tasks = list(tasks)
+    workers = min(len(tasks), os.cpu_count() or 1, 16)
+    if workers <= 1:
+        return [fn(t) for t in tasks]
+    with ThreadPoolExecutor(max_workers=workers) as pool:
+        return list(pool.map(fn, tasks))
+
+
+def _shard_message_offsets(ptr: np.ndarray, d: int, vc: int) -> np.ndarray:
+    """int64 ``[d + 1]``: where each vertex-range shard's messages start
+    in the receiver-sorted CSR, read off the int64 row pointers (O(D), no
+    pass over the messages). A host CSR may hold more than 2^31 messages;
+    only a single shard may not, which the caller checks on the
+    differences."""
+    ptr = np.asarray(ptr, dtype=np.int64)
+    starts = np.minimum(np.arange(d + 1, dtype=np.int64) * vc, len(ptr) - 1)
+    return ptr[starts]
 
 
 def _build_shard_bucket_plan(deg, send_pad, counts, chunk_size, d, w_pad=None):
@@ -498,63 +564,77 @@ def _build_shard_bucket_plan(deg, send_pad, counts, chunk_size, d, w_pad=None):
     devices. No histogram path here — a per-shard [n, V] count matrix
     would replicate per device; mega-hubs ride wide sort rows instead.
 
-    Vectorized across shards (one grouped argsort + per-class batched
-    gathers instead of classes x shards ``_class_rows`` calls — the
-    round-1 host-side scaling wall, VERDICT item 6). Semantics are pinned
-    against the direct ``_class_rows`` reference by
+    Two passes in threads: a stable argsort groups a shard's vertices by
+    class and counts them (the row counts have to be known across shards
+    before any matrix is allocated), then every (shard, class) gathers its
+    rows straight into its slice of the stacked ``[D, n_c, w_c]`` arrays.
+    All offsets are int64: a shard's message run may be 2^31-1 long.
+    Semantics are pinned against the direct ``_class_rows`` reference by
     ``tests/test_sharded.py::test_bucket_plan_matches_class_rows_reference``.
     """
-    from graphmine_tpu.ops.bucketed_mode import _extend_widths
-
     sentinel_send = chunk_size * d          # the label sentinel slot
     widths = _extend_widths(int(deg.max(initial=1)))
-    classes = np.searchsorted(widths, np.maximum(deg, 1))  # [d, vc]
-    # local CSR start of each owned vertex inside its shard's message run
-    ptr = np.zeros((d, chunk_size), dtype=np.int64)
-    np.cumsum(deg[:, :-1], axis=1, out=ptr[:, 1:])
-
-    eligible = deg > 0
     n_classes = len(widths)
-    # Group owned vertices by class in one stable argsort per shard;
-    # ineligible (deg == 0) vertices sort to a trailing pseudo-class.
-    # Stability keeps rows in ascending vertex order within each class,
-    # matching _class_rows' nonzero() order.
-    sort_key = np.where(eligible, classes, n_classes).astype(np.int64)
-    order = np.argsort(sort_key, axis=1, kind="stable")       # [d, vc]
-    flat = (np.arange(d, dtype=np.int64)[:, None] * (n_classes + 1) + sort_key)
-    cnt = np.bincount(flat.ravel(), minlength=d * (n_classes + 1))
-    cnt = cnt.reshape(d, n_classes + 1)                       # [d, classes+1]
+
+    def group(s):
+        # ineligible (deg == 0) vertices sort to a trailing pseudo-class;
+        # stability keeps rows in ascending vertex order within a class,
+        # matching _class_rows' nonzero() order
+        cls = np.searchsorted(widths, np.maximum(deg[s], 1))
+        key = np.where(deg[s] > 0, cls, n_classes)
+        # local CSR start of each owned vertex inside the shard's run
+        ptr = np.zeros(chunk_size, dtype=np.int64)
+        np.cumsum(deg[s, :-1], dtype=np.int64, out=ptr[1:])
+        return (
+            np.argsort(key, kind="stable"),
+            np.bincount(key, minlength=n_classes + 1),
+            ptr,
+        )
+
+    grouped = _in_threads(group, range(d))
+    cnt = np.stack([g[1] for g in grouped])                   # [d, classes+1]
     start = np.zeros_like(cnt)
     np.cumsum(cnt[:, :-1], axis=1, out=start[:, 1:])
-    # _class_rows clamps gather indices to the shard's true message count.
-    max_idx = np.maximum(counts.astype(np.int64) - 1, 0)[:, None, None]
+    used = [c for c in range(n_classes) if cnt[:, c].any()]
+    rows_max = [int(cnt[:, c].max()) for c in used]
+    bucket_send = [
+        np.empty((d, n_c, int(widths[c])), dtype=np.int32)
+        for c, n_c in zip(used, rows_max)
+    ]
+    bucket_target = [np.empty((d, n_c), dtype=np.int32) for n_c in rows_max]
+    bucket_weight = [] if w_pad is None else [
+        np.zeros(b.shape, dtype=np.float32) for b in bucket_send
+    ]
 
-    bucket_send, bucket_target, bucket_weight = [], [], []
-    for c in np.unique(classes[eligible]):
-        w = int(widths[c])
-        n_s = cnt[:, c]                                       # rows per shard
-        n_c = int(n_s.max())
-        j = np.arange(n_c, dtype=np.int64)[None, :]           # [1, n_c]
-        row_valid = j < n_s[:, None]                          # [d, n_c]
-        pos = np.minimum(start[:, c, None] + j, deg.shape[1] - 1)
-        rows = np.take_along_axis(order, pos, 1)              # [d, n_c]
-        ptr_r = np.take_along_axis(ptr, rows, 1)
-        deg_r = np.where(row_valid, np.take_along_axis(deg, rows, 1), 0)
-        offs = np.arange(w, dtype=np.int64)[None, None, :]
-        idx = ptr_r[..., None] + offs                         # [d, n_c, w]
-        valid = offs < deg_r[..., None]
-        flat_idx = np.minimum(idx, max_idx).reshape(d, -1)
-        gathered = np.take_along_axis(send_pad, flat_idx, 1).reshape(d, n_c, w)
-        send_c = np.where(valid, gathered, sentinel_send).astype(np.int32)
-        # Padding rows get DISTINCT targets chunk_size + j: the shard body
-        # scatters them into in-range scratch slots past the real chunk
-        # (sliced away), keeping unique_indices honest with no OOB index.
-        tgt_c = np.where(row_valid, rows, chunk_size + j).astype(np.int32)
-        bucket_send.append(send_c)
-        bucket_target.append(tgt_c)
+    def fill(task):
+        s, k = task
+        c, (order, _, ptr) = used[k], grouped[s]
+        # _class_rows clamps gather indices to the shard's true message count
+        max_idx = max(int(counts[s]) - 1, 0)
+        n = int(cnt[s, c])
+        rows = order[start[s, c]: start[s, c] + n]
+        offs = np.arange(int(widths[c]), dtype=np.int64)[None, :]
+        idx = np.minimum(ptr[rows][:, None] + offs, max_idx)
+        valid = offs < deg[s, rows][:, None]
+        send_c = bucket_send[k][s]
+        send_c[:n] = np.where(valid, send_pad[s][idx], sentinel_send)
+        send_c[n:] = sentinel_send
+        # Padding rows get DISTINCT targets chunk_size + j: the shard
+        # body scatters them into in-range scratch slots past the real
+        # chunk (sliced away), keeping unique_indices honest with no
+        # OOB index.
+        bucket_target[k][s, :n] = rows
+        bucket_target[k][s, n:] = chunk_size + np.arange(n, rows_max[k])
         if w_pad is not None:
-            wg = np.take_along_axis(w_pad, flat_idx, 1).reshape(d, n_c, w)
-            bucket_weight.append(np.where(valid, wg, 0.0).astype(np.float32))
+            bucket_weight[k][s, :n] = np.where(valid, w_pad[s][idx], 0.0)
+
+    # one task per (shard, class), the largest first: more tasks than
+    # shards, so a host with more cores than shards uses them
+    tasks = sorted(
+        ((s, k) for s in range(d) for k in range(len(used))),
+        key=lambda t: -int(cnt[t[0], used[t[1]]]) * int(widths[used[t[1]]]),
+    )
+    _in_threads(fill, tasks)
     return tuple(bucket_send), tuple(bucket_target), tuple(bucket_weight)
 
 
@@ -571,8 +651,8 @@ def _build_shard_blocked_plan(
     all devices. Padding messages (the CSR rows past ``counts[s]``)
     stream the label-sentinel sender and scatter into a per-shard scratch
     region past the bins; padding rows target ``chunk_size + j`` scratch
-    slots exactly like the bucketed plan. Built with a per-shard host
-    loop (D is small; the per-shard work is vectorized NumPy).
+    slots exactly like the bucketed plan. The per-shard layouts are built
+    one thread per shard (vectorized NumPy each).
     """
     import os as _os
 
@@ -581,7 +661,6 @@ def _build_shard_blocked_plan(
         _bin_bounds,
         _blocked_layout,
     )
-    from graphmine_tpu.ops.bucketed_mode import _extend_widths
 
     if tile_slots is None:
         tile_slots = int(
@@ -601,14 +680,14 @@ def _build_shard_blocked_plan(
         sizes = ptr_s[bounds[1:]] - ptr_s[bounds[:-1]]
         tb = max(tb, -(-int(sizes.max(initial=1)) // 8) * 8)
 
-    shard_layouts, n_bins_max = [], 1
-    for s in range(d):
-        layout = _blocked_layout(
+    shard_layouts = _in_threads(
+        lambda s: _blocked_layout(
             ptrs[s], send_pad[s], tile_slots, widths=widths, tile_width=tb,
             weights=None if w_pad is None else w_pad[s],
-        )
-        shard_layouts.append(layout)
-        n_bins_max = max(n_bins_max, len(layout[2]) - 1)
+        ),
+        range(d),
+    )
+    n_bins_max = max([1] + [len(layout[2]) - 1 for layout in shard_layouts])
 
     tile_total = n_bins_max * tb
     tile_alloc = tile_total + mp + 1
@@ -689,17 +768,20 @@ def _build_shard_plan2d(blk_src, chunk_size, d, pad_multiple=8):
     # contiguous slices found by searchsorted on the chunk boundaries —
     # O(M log M) total host work (the same order as the blocked plan
     # build this rides on), independent of D.
-    need: list[list] = [[] for _ in range(d)]
-    uniqs, bounds = [], []
-    for s in range(d):
+    def boundary_sets(s):
         uniq = np.unique(blk_src[s].astype(np.int64))     # incl. sentinel
-        uniqs.append(uniq)
         bound = np.searchsorted(uniq, np.arange(d + 1) * chunk_size)
-        bounds.append(bound)
+        need_s = []
         for r in range(1, d):
             peer = (s - r) % d
             ids = uniq[bound[peer]: bound[peer + 1]]
-            need[s].append(ids - peer * chunk_size)
+            need_s.append(ids - peer * chunk_size)
+        return uniq, bound, need_s
+
+    per = _in_threads(boundary_sets, range(d))
+    uniqs = [p[0] for p in per]
+    bounds = [p[1] for p in per]
+    need = [p[2] for p in per]
     b = max(
         (len(ids) for row in need for ids in row), default=1
     )
@@ -711,7 +793,8 @@ def _build_shard_plan2d(blk_src, chunk_size, d, pad_multiple=8):
             send_tab[s, r - 1, : len(ids)] = ids
     sentinel_slot = chunk_size + (d - 1) * b
     src_local = np.full((d, mp), sentinel_slot, dtype=np.int32)
-    for s in range(d):
+
+    def remap(s):
         g = blk_src[s].astype(np.int64)
         owner = g // chunk_size                           # pad -> d
         # one global position pass: index within need[s][r-1] is the
@@ -722,6 +805,8 @@ def _build_shard_plan2d(blk_src, chunk_size, d, pad_multiple=8):
         out = chunk_size + (r_of - 1) * b + in_need
         out = np.where(owner == s, g - s * chunk_size, out)
         src_local[s] = np.where(owner >= d, sentinel_slot, out)
+
+    _in_threads(remap, range(d))
     total = sum(len(ids) for row in need for ids in row)
     return dict(
         x2d_send_tab=send_tab,
@@ -808,15 +893,19 @@ def _lpa_shard_body(labels_full, recv_local, send, deg, weight, *, chunk_size, a
     recv_local = recv_local[0]
     send = send[0]
     deg = deg[0]
-    msg = labels_full[send]
-    mode, _ = segment_mode(
-        recv_local, msg, num_segments=chunk_size,
-        weights=None if weight is None else weight[0],
-    )
-    start = lax.axis_index(axes).astype(jnp.int32) * chunk_size
-    own = lax.dynamic_slice(labels_full, (start,), (chunk_size,))
-    new_own = jnp.where(deg > 0, mode, own).astype(jnp.int32)
-    return lax.all_gather(new_own, axes, tiled=True)
+    with jax.named_scope("lpa_sharded"):
+        with jax.named_scope("msg_gather"):
+            msg = labels_full[send]
+        mode, _ = segment_mode(
+            recv_local, msg, num_segments=chunk_size,
+            weights=None if weight is None else weight[0],
+        )
+        with jax.named_scope("write_back"):
+            start = lax.axis_index(axes).astype(jnp.int32) * chunk_size
+            own = lax.dynamic_slice(labels_full, (start,), (chunk_size,))
+            new_own = jnp.where(deg > 0, mode, own).astype(jnp.int32)
+        with jax.named_scope("exchange"):
+            return lax.all_gather(new_own, axes, tiled=True)
 
 
 def _lpa_shard_body_bucketed(
@@ -834,36 +923,51 @@ def _lpa_shard_body_bucketed(
     bucket and keep their label. ``bucket_weight`` (r2): slot-aligned
     weights switch the row modes to weighted argmax.
     """
-    from graphmine_tpu.ops.bucketed_mode import (
-        _SENTINEL,
-        _bucket_mode,
-        _bucket_wmode,
-    )
-
-    lbl_pad = jnp.concatenate(
-        [labels_full, jnp.full((1,), _SENTINEL, jnp.int32)]
-    )
-    start = lax.axis_index(axes).astype(jnp.int32) * chunk_size
-    own = lax.dynamic_slice(labels_full, (start,), (chunk_size,))
-    # Padding rows carry DISTINCT targets chunk_size + j (j < n_c): one
-    # scratch extension by the max class width keeps every scatter index
-    # in range and unique. Do NOT "optimize" this back to out-of-bounds
-    # indices with mode="drop" — under shard_map the XLA:CPU lowering of
-    # a unique_indices OOB scatter was observed corrupting the last
-    # in-range slot with a shifted read (caught by
-    # tools/consistency_sweep.py; see docs/DESIGN.md).
-    n_max = max((t.shape[-1] for t in bucket_target), default=0)
-    own = jnp.concatenate([own, jnp.zeros((n_max,), own.dtype)])
-    wmats = bucket_weight or (None,) * len(bucket_send)
-    for sidx, tgt, wmat in zip(bucket_send, bucket_target, wmats):
-        mat = lbl_pad[sidx[0]]
-        vals = (
-            _bucket_mode(mat) if wmat is None else _bucket_wmode(mat, wmat[0])
+    with jax.named_scope("lpa_sharded"):
+        lbl_pad = jnp.concatenate(
+            [labels_full, jnp.full((1,), _SENTINEL, jnp.int32)]
         )
-        own = own.at[tgt[0]].set(vals, unique_indices=True)
-    return lax.all_gather(
-        own[:chunk_size].astype(jnp.int32), axes, tiled=True
-    )
+        start = lax.axis_index(axes).astype(jnp.int32) * chunk_size
+        own = lax.dynamic_slice(labels_full, (start,), (chunk_size,))
+        own = _shard_row_modes(
+            lbl_pad, own, bucket_send, bucket_target, bucket_weight
+        )
+        with jax.named_scope("exchange"):
+            return lax.all_gather(
+                own[:chunk_size].astype(jnp.int32), axes, tiled=True
+            )
+
+
+def _shard_row_modes(table, own, row_idx, row_target, row_weight):
+    """The reduce of every degree class of one shard: gather the class's
+    dense rows from ``table``, take the row-wise mode, write it to the
+    class's LOCAL targets in ``own`` (the stacked-plan twin of
+    ``ops/bucketed_mode._row_modes``: indices arrive as ``[1, n, w]``
+    shard slices). Returns ``own`` extended by a scratch region the caller
+    slices away.
+
+    Padding rows carry DISTINCT targets chunk_size + j (j < n_c): one
+    scratch extension by the max class width keeps every scatter index
+    in range and unique. Do NOT "optimize" this back to out-of-bounds
+    indices with mode="drop" — under shard_map the XLA:CPU lowering of
+    a unique_indices OOB scatter was observed corrupting the last
+    in-range slot with a shifted read (caught by
+    tools/consistency_sweep.py; see docs/DESIGN.md)."""
+    n_max = max((t.shape[-1] for t in row_target), default=0)
+    own = jnp.concatenate([own, jnp.zeros((n_max,), own.dtype)])
+    wmats = row_weight or (None,) * len(row_idx)
+    for ridx, tgt, wmat in zip(row_idx, row_target, wmats):
+        width = f"w{ridx.shape[-1]}"
+        with jax.named_scope("row_gather"), jax.named_scope(width):
+            mat = table[ridx[0]]
+        with jax.named_scope("row_mode"), jax.named_scope(width):
+            vals = (
+                _bucket_mode(mat) if wmat is None
+                else _bucket_wmode(mat, wmat[0])
+            )
+        with jax.named_scope("write_back"):
+            own = own.at[tgt[0]].set(vals, unique_indices=True)
+    return own
 
 
 def _blocked_shard_tile(labels_full, blk_src, blk_pos, tile_alloc, fill):
@@ -872,10 +976,14 @@ def _blocked_shard_tile(labels_full, blk_src, blk_pos, tile_alloc, fill):
     scatter each message into its slot of this shard's destination-binned
     tile. Padding messages carry the sentinel value into scratch slots
     past the bins; unwritten slots keep ``fill``."""
-    lbl_pad = jnp.concatenate([labels_full, jnp.full((1,), fill, jnp.int32)])
-    vals = lbl_pad[blk_src[0]]
-    tile = jnp.full((tile_alloc,), fill, jnp.int32)
-    return tile.at[blk_pos[0]].set(vals, unique_indices=True)
+    with jax.named_scope("bin_gather"):
+        lbl_pad = jnp.concatenate(
+            [labels_full, jnp.full((1,), fill, jnp.int32)]
+        )
+        vals = lbl_pad[blk_src[0]]
+    with jax.named_scope("bin_scatter"):
+        tile = jnp.full((tile_alloc,), fill, jnp.int32)
+        return tile.at[blk_pos[0]].set(vals, unique_indices=True)
 
 
 def _lpa_shard_body_blocked(
@@ -888,25 +996,17 @@ def _lpa_shard_body_blocked(
     all_gather. Padding rows scatter to the ``chunk_size + j`` scratch
     extension (sliced away), exactly like the bucketed body; see the OOB
     warning there for why the scratch exists."""
-    from graphmine_tpu.ops.bucketed_mode import (
-        _SENTINEL,
-        _bucket_mode,
-        _bucket_wmode,
-    )
-
-    tile = _blocked_shard_tile(labels_full, blk_src, blk_pos, tile_alloc, _SENTINEL)
-    start = lax.axis_index(axes).astype(jnp.int32) * chunk_size
-    own = lax.dynamic_slice(labels_full, (start,), (chunk_size,))
-    n_max = max((t.shape[-1] for t in row_target), default=0)
-    own = jnp.concatenate([own, jnp.zeros((n_max,), own.dtype)])
-    wmats = row_weight or (None,) * len(row_idx)
-    for ridx, tgt, wmat in zip(row_idx, row_target, wmats):
-        mat = tile[ridx[0]]
-        vals = _bucket_mode(mat) if wmat is None else _bucket_wmode(mat, wmat[0])
-        own = own.at[tgt[0]].set(vals, unique_indices=True)
-    return lax.all_gather(
-        own[:chunk_size].astype(jnp.int32), axes, tiled=True
-    )
+    with jax.named_scope("lpa_sharded"):
+        tile = _blocked_shard_tile(
+            labels_full, blk_src, blk_pos, tile_alloc, _SENTINEL
+        )
+        start = lax.axis_index(axes).astype(jnp.int32) * chunk_size
+        own = lax.dynamic_slice(labels_full, (start,), (chunk_size,))
+        own = _shard_row_modes(tile, own, row_idx, row_target, row_weight)
+        with jax.named_scope("exchange"):
+            return lax.all_gather(
+                own[:chunk_size].astype(jnp.int32), axes, tiled=True
+            )
 
 
 def _cc_shard_body_blocked(
@@ -918,8 +1018,6 @@ def _cc_shard_body_blocked(
     (the int32-max sentinel never wins), pointer jump on the gathered
     full vector (no extra comms), matching :func:`_cc_shard_body`
     step-for-step."""
-    from graphmine_tpu.ops.bucketed_mode import _SENTINEL
-
     tile = _blocked_shard_tile(labels_full, blk_src, blk_pos, tile_alloc, _SENTINEL)
     start = lax.axis_index(axes).astype(jnp.int32) * chunk_size
     own = lax.dynamic_slice(labels_full, (start,), (chunk_size,))
@@ -958,9 +1056,10 @@ def _exchange_2d(own, send_tab, *, axes, num_shards):
     (padded to B). Returns the D-1 received buffers in peer-offset
     order, matching the compact-table layout the stream remap indexes."""
     bufs = []
-    for r in range(1, num_shards):
-        perm = [(i, (i + r) % num_shards) for i in range(num_shards)]
-        bufs.append(lax.ppermute(own[send_tab[r - 1]], axes, perm))
+    with jax.named_scope("exchange"):
+        for r in range(1, num_shards):
+            perm = [(i, (i + r) % num_shards) for i in range(num_shards)]
+            bufs.append(lax.ppermute(own[send_tab[r - 1]], axes, perm))
     return bufs
 
 
@@ -987,26 +1086,15 @@ def _lpa_shard_body_2d(
     the sort oracle (the r8 order-independence contract). Labels stay
     SHARDED: input and output are the shard's own ``[Vc]`` chunk; no
     replicated V-vector exists anywhere in the superstep."""
-    from graphmine_tpu.ops.bucketed_mode import (
-        _SENTINEL,
-        _bucket_mode,
-        _bucket_wmode,
-    )
-
-    bufs = _exchange_2d(own, send_tab[0], axes=axes, num_shards=num_shards)
-    table = _table_2d(own, bufs, _SENTINEL)
-    vals = table[src_local[0]]
-    tile = jnp.full((tile_alloc,), _SENTINEL, jnp.int32).at[blk_pos[0]].set(
-        vals, unique_indices=True
-    )
-    n_max = max((t.shape[-1] for t in row_target), default=0)
-    out = jnp.concatenate([own, jnp.zeros((n_max,), own.dtype)])
-    wmats = row_weight or (None,) * len(row_idx)
-    for ridx, tgt, wmat in zip(row_idx, row_target, wmats):
-        mat = tile[ridx[0]]
-        vals_r = _bucket_mode(mat) if wmat is None else _bucket_wmode(mat, wmat[0])
-        out = out.at[tgt[0]].set(vals_r, unique_indices=True)
-    return out[:chunk_size].astype(jnp.int32)
+    with jax.named_scope("lpa_sharded"):
+        bufs = _exchange_2d(own, send_tab[0], axes=axes, num_shards=num_shards)
+        with jax.named_scope("bin_gather"):
+            vals = _table_2d(own, bufs, _SENTINEL)[src_local[0]]
+        with jax.named_scope("bin_scatter"):
+            tile = jnp.full((tile_alloc,), _SENTINEL, jnp.int32)
+            tile = tile.at[blk_pos[0]].set(vals, unique_indices=True)
+        out = _shard_row_modes(tile, own, row_idx, row_target, row_weight)
+        return out[:chunk_size].astype(jnp.int32)
 
 
 def _cc_shard_body_2d(
@@ -1029,8 +1117,6 @@ def _cc_shard_body_2d(
     stay bit-identical to the oracle and a fixpoint stays a fixpoint
     under one more superstep (the serve-path sampled-exact-check
     predicate)."""
-    from graphmine_tpu.ops.bucketed_mode import _SENTINEL
-
     bufs = _exchange_2d(own, send_tab[0], axes=axes, num_shards=num_shards)
     table = _table_2d(own, bufs, _SENTINEL)
     vals = table[src_local[0]]

@@ -939,6 +939,7 @@ def _run_lpa(
     )
     from graphmine_tpu.parallel.mesh import make_mesh
     from graphmine_tpu.parallel.sharded import (
+        FAMILY_PARTITION_FLAGS,
         partition_graph,
         shard_graph_arrays,
         sharded_label_propagation,
@@ -1096,11 +1097,17 @@ def _run_lpa(
             )
         if variant == "replicated":
             mesh = _rung_mesh(ndev)
+            # the family is the mesh policy owner's (plan_run asked
+            # select_superstep_family with num_devices=D)
+            lpa_only = run_plan.lpa_only and run_plan.family != "sort"
             with m.timed("partition", shards=ndev, schedule="replicated"):
                 sg = shard_graph_arrays(
-                    partition_graph(graph, mesh=mesh, build_bucket_plan=True),
+                    partition_graph(
+                        graph, mesh=mesh, lpa_only=lpa_only,
+                        **FAMILY_PARTITION_FLAGS[run_plan.family or "bucketed"],
+                    ),
                     mesh,
-                    lpa_only=run_plan.lpa_only,
+                    lpa_only=lpa_only,
                 )
             current["chunk_size"] = sg.chunk_size
             current["cost"] = sharded_superstep_cost(

@@ -102,6 +102,11 @@ class RunPlan:
     hbm_bytes: int           # per-device budget the plan was made against
     reason: str              # one-line human-readable selection rationale
     estimates: dict = field(default_factory=dict)  # schedule -> bytes/device
+    # The superstep family a replicated mesh run partitions for, from the
+    # one policy owner (ops/blocking.select_superstep_family with
+    # num_devices=D); None where the schedule leaves no choice (single: the
+    # driver's plan_superstep call decides; ring: the sort CSR).
+    family: str | None = None
 
 
 def hbm_bytes_per_device(device_bytes=None) -> int:
@@ -345,6 +350,18 @@ def plan_run(
     def _gb(b):
         return f"{b / (1 << 30):.2f} GiB"
 
+    def _mesh_family(sched):
+        # the policy owner's answer for a mesh; imports the ops layer
+        # (hence jax) lazily and only for a distributed schedule
+        if sched != "replicated":
+            return None
+        from graphmine_tpu.ops.blocking import select_superstep_family
+
+        return select_superstep_family(
+            num_vertices, 2 * num_edges, weighted=weighted,
+            num_devices=num_devices,
+        )[0]
+
     def _idx_ok(s):
         return messages_per_device(s, num_edges, num_devices) <= _INT32_MAX
 
@@ -389,6 +406,7 @@ def plan_run(
             hbm_bytes=budget,
             reason=f"requested '{requested}' ({_gb(need)}/device fits)",
             estimates=est,
+            family=_mesh_family(sched),
         )
 
     idx_blocked = [s for s in candidates if not _idx_ok(s)]
@@ -410,6 +428,7 @@ def plan_run(
                 hbm_bytes=budget,
                 reason=why,
                 estimates=est,
+                family=_mesh_family(sched),
             )
 
     if idx_blocked and all(

@@ -383,10 +383,7 @@ def test_family_policy_thresholds():
     assert fam == "bucketed"
     fam, reason = select_superstep_family(1 << 21, 1 << 22)
     assert fam == "bucketed" and "blocking" not in reason
-    assert set(crossover_thresholds()) == {
-        "bucketed_min_messages", "sharded2d_min_messages",
-        "sharded2d_min_devices",
-    }
+    assert set(crossover_thresholds()) == {"bucketed_min_messages"}
 
 
 def test_family_policy_env_overrides(monkeypatch):

@@ -392,13 +392,13 @@ def test_lof_impl_selected_carries_threshold_and_cost():
 def test_superstep_auto_seam_impl_selected_carries_thresholds(monkeypatch):
     from graphmine_tpu.ops.lpa import label_propagation
 
-    monkeypatch.setenv("GRAPHMINE_SHARDED2D_MIN_MESSAGES", "123")
+    from graphmine_tpu.ops.blocking import BUCKETED_MIN_MESSAGES
+
     m = _sink()
     label_propagation(ring4(), max_iter=1, sink=m)
     (sel,) = [r for r in m.records if r["phase"] == "impl_selected"]
-    # the env-overridden constant is what the record ships — the value
-    # that actually decided, not the compiled-in default
-    assert sel["thresholds"]["sharded2d_min_messages"] == 123
+    # the constants that decided ship with the record
+    assert sel["thresholds"] == {"bucketed_min_messages": BUCKETED_MIN_MESSAGES}
     # only constants that decide something ship (PR 26: auto has no
     # blocked crossover on one device)
     assert not [k for k in sel["thresholds"] if k.startswith("blocked")]

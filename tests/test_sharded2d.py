@@ -260,21 +260,15 @@ def test_plan2d_mutually_exclusive_with_bucket_plan(rng):
 # ---- crossover policy + planner ladder -------------------------------------
 
 
-def test_policy_selects_2d_past_crossover():
-    from graphmine_tpu.ops.blocking import (
-        SHARDED2D_MIN_MESSAGES,
-        select_superstep_family,
-    )
+def test_policy_mesh_auto_is_bucket_rows_not_2d():
+    """PR 27: on four chips the 2D family's three passes lost to one pass
+    of per-shard bucket rows + one all_gather (PERF.md §6), so ``auto`` on
+    a mesh resolves ``bucketed`` at every size and 2D only on request."""
+    from graphmine_tpu.ops.blocking import select_superstep_family
 
-    fam, reason = select_superstep_family(
-        1 << 16, SHARDED2D_MIN_MESSAGES, num_devices=8
-    )
-    assert fam == "sharded_2d" and "neighbor-only" in reason
-    # below the message floor: not 2D
-    fam, _ = select_superstep_family(
-        1 << 16, SHARDED2D_MIN_MESSAGES - 1, num_devices=8
-    )
-    assert fam != "sharded_2d"
+    for m in (1 << 10, 1 << 14, 1 << 30):
+        fam, reason = select_superstep_family(1 << 16, m, num_devices=8)
+        assert fam == "bucketed" and "all_gather" in reason
     # single device: never 2D, whatever the size
     fam, _ = select_superstep_family(1 << 22, 1 << 23, num_devices=1)
     assert fam != "sharded_2d"
@@ -297,15 +291,13 @@ def test_policy_env_overrides(monkeypatch):
         select_superstep_family,
     )
 
+    # the model-seeded 2D thresholds and their env overrides went with
+    # the branch (PR 27)
     monkeypatch.setenv("GRAPHMINE_SHARDED2D_MIN_MESSAGES", "10")
     monkeypatch.setenv("GRAPHMINE_SHARDED2D_MIN_DEVICES", "3")
-    thr = crossover_thresholds()
-    assert thr["sharded2d_min_messages"] == 10
-    assert thr["sharded2d_min_devices"] == 3
+    assert not [k for k in crossover_thresholds() if "2d" in k]
     fam, _ = select_superstep_family(100, 10, num_devices=3)
-    assert fam == "sharded_2d"
-    fam, _ = select_superstep_family(100, 10, num_devices=2)
-    assert fam != "sharded_2d", "moved device floor must hold"
+    assert fam == "bucketed"
     # the process-wide family override applies to sharded resolutions
     # but silently does NOT apply on one device (fused ops keep working)
     monkeypatch.setenv("GRAPHMINE_SUPERSTEP_FAMILY", "sharded_2d")
@@ -324,8 +316,13 @@ def test_planner_ladder_degrades_2d_to_one_allgather():
 
     assert _SUPERSTEP_DEGRADE["sharded_2d"] == "blocked"
     assert FAMILY_DEGRADE["sharded_2d"] == "blocked"
-    plan = plan_superstep(1 << 16, 1 << 14, num_devices=8)
+    plan = plan_superstep(
+        1 << 16, 1 << 14, requested="sharded_2d", num_devices=8
+    )
     assert plan.family == "sharded_2d" and plan.degrade_to == "blocked"
+    # auto on a mesh: the bucket rows, one rung above sort (PR 27)
+    plan = plan_superstep(1 << 16, 1 << 14, num_devices=8)
+    assert plan.family == "bucketed" and plan.degrade_to == "sort"
     # single-device resolution is byte-identical to the pre-r16 policy
     plan1 = plan_superstep(1 << 16, 1 << 14)
     assert plan1.family != "sharded_2d"
@@ -496,7 +493,7 @@ def test_serve_warm_repair_selects_2d(tmp_path, monkeypatch, rng):
     )
     from graphmine_tpu.serve.snapshot import SnapshotStore
 
-    monkeypatch.setenv("GRAPHMINE_SHARDED2D_MIN_MESSAGES", "1")
+    monkeypatch.setenv("GRAPHMINE_SUPERSTEP_FAMILY", "sharded_2d")
     v = 60
     src, dst = _community_edges(rng, v)
     g = build_graph(src, dst, num_vertices=v)
@@ -548,7 +545,7 @@ def test_serve_predegrades_2d_on_tiny_budget(tmp_path, monkeypatch, rng):
     )
     from graphmine_tpu.serve.snapshot import SnapshotStore
 
-    monkeypatch.setenv("GRAPHMINE_SHARDED2D_MIN_MESSAGES", "1")
+    monkeypatch.setenv("GRAPHMINE_SUPERSTEP_FAMILY", "sharded_2d")
     monkeypatch.setenv("GRAPHMINE_HBM_BYTES", "512")  # nothing 2D fits
     v = 60
     src, dst = _community_edges(rng, v)
