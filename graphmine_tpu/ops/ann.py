@@ -1,14 +1,13 @@
 """Approximate kNN: TPU-native IVF-flat (k-means + cluster-probe search).
 
-Why this exists (r5, measured): the exact all-pairs scorer is AT the
-hardware's top_k/sort roofline — `lax.top_k` on a [1024, 262144] f32
-tile runs at 1.6G elem/s against the roofline tier's 1.85G row-sort
-rate, so no exact implementation gets meaningfully faster
-(docs/DESIGN.md "Exact kNN is at the sort roofline"). The remaining
-lever is FEWER CANDIDATE PAIRS. IVF-flat measured 0.95–0.98 recall@32
-touching 6–13% of N on Gaussian data — the WORST case for it (real LOF
-feature clouds are clustered, which is exactly what inverted lists
-exploit).
+Why this exists (r5, measured): the exact all-pairs scorer runs at XLA's
+row-sort rate — `lax.top_k` on a [1024, 262144] f32 tile reads 1.6G
+elem/s against 1.85G for a plain row sort — so no exact implementation
+on XLA's sort gets meaningfully faster (docs/DESIGN.md "Exact kNN is at
+the sort rate"). The remaining lever is FEWER CANDIDATE PAIRS. IVF-flat
+measured 0.95–0.98 recall@32 touching 6–13% of N on Gaussian data — the
+WORST case for it (real LOF feature clouds are clustered, which is
+exactly what inverted lists exploit).
 
 TPU-first shape discipline — everything the device sees is static:
 
@@ -21,11 +20,18 @@ TPU-first shape discipline — everything the device sees is static:
   centers; (query, cluster) pairs are grouped BY CLUSTER host-side and
   padded to one static ``Qmax``, so the device runs a single
   ``lax.map`` over clusters of ``[Qmax, F] x [F, Lmax]`` distance
-  blocks + ``top_k`` — no irregular [N, n_probe * Lmax] gather (which
-  would put the candidate fetch right back on the gather roofline the
-  exact path already saturates). A member belongs to exactly one
-  cluster, so per-query candidates are duplicate-free by construction
-  and the final merge is one ``top_k`` over ``n_probe * k``.
+  blocks + a selection of the ``k`` nearest — no irregular
+  [N, n_probe * Lmax] gather (XLA's gather runs at ~0.2 G elem/s on this
+  chip whatever the table; PERF.md §7.4). A member belongs to exactly
+  one cluster, so per-query candidates are duplicate-free by
+  construction and the final merge is one selection over
+  ``n_probe * k``.
+- **Selection** (:func:`_select_k`, PR 28): one stable sort of
+  (distance, id) pairs, first ``k`` columns kept. The ids ride the sort,
+  so nothing is looked up by position afterwards: ``lax.top_k`` followed
+  by ``m_gid[positions]`` spent 3.64 ms a ``[4096, 1024]`` chunk, 2.8 of
+  them in the lookup, against 1.26 ms for the sort that carries the ids
+  (v5e, PERF.md §6, PR 28), with the same neighbours bit for bit.
 
 The result contract matches :func:`graphmine_tpu.ops.knn.knn`:
 ``(d2, idx)`` ascending, self excluded — so
@@ -123,10 +129,39 @@ def kmeans(points, n_clusters: int, iters: int = 5, seed: int = 0):
     return centers
 
 
+def _select_k(d2, ids, k: int):
+    """The ``k`` smallest of each row of ``d2 [R, W]`` with the ids that
+    sit beside them in ``ids [R, W]``: ``([R, k] d2 ascending, [R, k]
+    ids)``. One stable sort of (distance, id) pairs on the distance
+    alone, first ``k`` columns kept: the sort carries the ids, so no id
+    is looked up by position afterwards (module docstring, "Selection").
+
+    The order is ``lax.top_k(-d2, k)``'s, ties included, on ANY input:
+    among equal distances the lower position comes first, which is what
+    ``is_stable=True`` says, so this leans on no invariant of its
+    callers. The two-key form (``num_keys=2``, unstable, one operand
+    fewer: 0.85 against 1.26 ms a chunk) was measured and dropped: it
+    needs position order and id order to agree on every tie, which holds
+    inside one search chunk (``_inverted_lists`` lays members out in id
+    order) and NOT in the merge, where equal distances from two probed
+    clusters sit in probe order; it changed 1,494 of 262,144 neighbour
+    lists on the pipeline cell's cloud (PERF.md §6, PR 28).
+
+    ``inf`` slots (padding, the self slot, the merge's junk row) sort
+    after every finite distance, in position order. A NaN distance sorts
+    after ``inf`` (``lax.sort``'s total order), so it is never taken
+    ahead of a real candidate; where ``lax.top_k`` put one was the
+    backend's choice, and nothing here checks features for NaN."""
+    d2_sorted, ids_sorted = lax.sort(
+        (d2, ids), dimension=1, num_keys=1, is_stable=True
+    )
+    return d2_sorted[:, :k], ids_sorted[:, :k]
+
+
 @partial(jax.jit, static_argnames=("k",))
 def _search_clusters(q_vec, q_gid, m_vec, m_gid, m_valid, k: int):
     """One cluster's block: exact distances from its padded query batch
-    to its padded member list, masked top-k. Shapes: q_vec [Qmax, F],
+    to its padded member list, the k nearest kept. Shapes: q_vec [Qmax, F],
     m_vec [Lmax, F]; returns ([Qmax, k] d2 asc, [Qmax, k] global ids)."""
     with jax.named_scope("ivf"):
         with jax.named_scope("search_distance"):
@@ -143,8 +178,7 @@ def _search_clusters(q_vec, q_gid, m_vec, m_gid, m_valid, k: int):
             d2 = jnp.where(~m_valid[None, :], jnp.inf, d2)
             d2 = jnp.where(q_gid[:, None] == m_gid[None, :], jnp.inf, d2)  # self
         with jax.named_scope("search_topk"):
-            neg, j = lax.top_k(-d2, k)
-            return -neg, m_gid[j]
+            return _select_k(d2, jnp.broadcast_to(m_gid, d2.shape), k)
 
 
 def _search_chunks(pts, m_gid, m_valid, q_gid, row_sub, k: int):
@@ -501,9 +535,9 @@ def _inverted_lists(pts, k: int, probe, n_clusters: int, n_probe: int):
 
 @partial(jax.jit, static_argnames=("k",))
 def _merge_tiles(d2_flat, gid_flat, take_tiles, k: int):
-    """Per-query merge: gather each tile's pair rows, one top-k over the
-    ``p_max * k`` candidates (duplicate-free: every member belongs to
-    exactly one sublist)."""
+    """Per-query merge: gather each tile's pair rows, one selection of
+    the k nearest among the ``p_max * k`` candidates (duplicate-free:
+    every member belongs to exactly one sublist)."""
     merge_t, p_max = take_tiles.shape[1], take_tiles.shape[2]
 
     def tile(tk):
@@ -512,7 +546,6 @@ def _merge_tiles(d2_flat, gid_flat, take_tiles, k: int):
                 d2_t = d2_flat[tk].reshape(merge_t, p_max * k)
                 gid_t = gid_flat[tk].reshape(merge_t, p_max * k)
             with jax.named_scope("merge_topk"):
-                neg, sel = lax.top_k(-d2_t, k)
-                return -neg, jnp.take_along_axis(gid_t, sel, axis=1)
+                return _select_k(d2_t, gid_t, k)
 
     return lax.map(tile, take_tiles)

@@ -31,6 +31,7 @@ from jax import lax, shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from graphmine_tpu.ops.ann import _select_k
 from graphmine_tpu.ops.knn import _tiled_knn
 # the one jitted wrapper of the shared LOF formula (ops/lof.py owns it)
 from graphmine_tpu.ops.lof import _lof_from_knn_jit as _lof_from_knn
@@ -42,8 +43,9 @@ def _knn_ring_body(pts, *, n: int, k: int, chunk: int, num_shards: int,
     """Per-device ring kNN (runs under shard_map; ``pts`` is this device's
     ``[chunk, F]`` row slice). Each hop folds the visiting chunk into the
     running top-k via the shared :func:`ops.knn._tiled_knn` core
-    (id-equality self-exclusion, padding slots masked) and one ``top_k``
-    over ``[chunk, 2k]``; D-1 ppermute hops total."""
+    (id-equality self-exclusion, padding slots masked) and one selection
+    over ``[chunk, 2k]`` (:func:`ops.ann._select_k`: the sort carries the
+    ids); D-1 ppermute hops total."""
     my = lax.axis_index(VERTEX_AXIS).astype(jnp.int32)
     local_gid = my * chunk + jnp.arange(chunk, dtype=jnp.int32)
     best_d = jnp.full((chunk, k), jnp.inf, jnp.float32)
@@ -60,9 +62,7 @@ def _knn_ring_body(pts, *, n: int, k: int, chunk: int, num_shards: int,
         )
         cat_d = jnp.concatenate([best_d, d2], axis=1)
         cat_g = jnp.concatenate([best_g, visit_gid[idx]], axis=1)
-        neg, pos = lax.top_k(-cat_d, k)
-        best_d = -neg
-        best_g = jnp.take_along_axis(cat_g, pos, axis=1)
+        best_d, best_g = _select_k(cat_d, cat_g, k)
         if r != num_shards - 1:
             visit = lax.ppermute(visit, VERTEX_AXIS, perm)
     return best_d, best_g

@@ -174,3 +174,125 @@ def test_ivf_guard_fallback_warns_and_records():
     with pytest.warns(UserWarning, match="ivf_knn guard"):
         lof_scores(pts, k=40, impl="ivf", sink=m2)
     assert m2.of_phase("ivf_fallback")
+
+
+# -- the selection: (distance, id) pairs sorted, first k kept (PR 28) ---------
+#
+# Every block below is built on an integer lattice (coordinates 0..3), so
+# each squared distance is exact in float32 whatever the order of the
+# arithmetic: the NumPy oracle and the device agree bit for bit, and equal
+# distances are everywhere.
+
+
+def _oracle_select(d2, ids, k):
+    """``lax.top_k``'s order: ascending distance, the lower POSITION on a
+    tie (``np.lexsort((position, d2))[:k]`` row by row)."""
+    pos = np.arange(d2.shape[1])
+    order = np.stack([np.lexsort((pos, row))[:k] for row in d2])
+    return (np.take_along_axis(d2, order, axis=1),
+            np.take_along_axis(ids, order, axis=1))
+
+
+def _search_block(valid_len, k, seed):
+    """One cluster's block as ``_inverted_lists`` lays it out: member ids
+    ascending, padded slots repeating the last id behind ``m_valid``
+    False; the first half of the queries are members (a self slot each),
+    the second half come from outside the sublist."""
+    from graphmine_tpu.ops.ann import _search_clusters
+
+    rng = np.random.default_rng(seed)
+    l_max, q_max, n = 64, 32, 500
+    pts = rng.integers(0, 4, size=(n, 8)).astype(np.float32)
+    members = np.sort(rng.choice(n, valid_len, replace=False)).astype(np.int32)
+    m_gid = np.concatenate(
+        [members, np.full(l_max - valid_len, members[-1], np.int32)]
+    )
+    m_valid = np.arange(l_max) < valid_len
+    outside = np.setdiff1d(np.arange(n), members)
+    q_gid = np.concatenate([
+        rng.choice(members, q_max // 2), rng.choice(outside, q_max // 2)
+    ]).astype(np.int32)
+    got = _search_clusters(
+        pts[q_gid], q_gid, pts[m_gid], m_gid, m_valid, k
+    )
+    d2 = ((pts[q_gid][:, None, :] - pts[m_gid][None, :, :]) ** 2).sum(-1)
+    d2 = np.where(~m_valid[None, :], np.inf, d2).astype(np.float32)
+    d2 = np.where(q_gid[:, None] == m_gid[None, :], np.float32(np.inf), d2)
+    ids = np.broadcast_to(m_gid, d2.shape)
+    return got, _oracle_select(d2, ids, k), d2
+
+
+def _merge_block(finite_per_row, k, seed):
+    """Search results as the merge sees them: ``[rows, k]`` ascending
+    distances from a handful of values (ties across a query's rows), ids
+    distinct and DESCENDING across rows so that position order and id
+    order disagree on every such tie, a junk row of ``inf`` / ``-1`` last,
+    and a take table whose short queries pad with the junk row."""
+    from graphmine_tpu.ops.ann import _merge_tiles
+
+    rng = np.random.default_rng(seed)
+    rows, merge_t, p_max, tiles = 30, 8, 3, 2
+    d2_flat = np.sort(
+        rng.integers(0, 6, size=(rows, k)).astype(np.float32), axis=1
+    )
+    d2_flat[:, finite_per_row:] = np.inf
+    gid_flat = np.arange(rows * k, dtype=np.int32)[::-1].reshape(rows, k).copy()
+    d2_flat = np.concatenate([d2_flat, np.full((1, k), np.inf, np.float32)])
+    gid_flat = np.concatenate([gid_flat, np.full((1, k), -1, np.int32)])
+    take = np.stack([
+        rng.choice(rows, p_max, replace=False)
+        for _ in range(tiles * merge_t)
+    ]).astype(np.int32)
+    take[::3, -1] = rows  # a query with fewer than p_max pairs
+    got = _merge_tiles(
+        d2_flat, gid_flat, take.reshape(tiles, merge_t, p_max), k
+    )
+    got = tuple(np.asarray(g).reshape(tiles * merge_t, k) for g in got)
+    d2 = d2_flat[take].reshape(tiles * merge_t, p_max * k)
+    ids = gid_flat[take].reshape(tiles * merge_t, p_max * k)
+    return got, _oracle_select(d2, ids, k), d2
+
+
+@pytest.mark.parametrize("site, finite", [
+    pytest.param("search", 50, id="search-ties-padding-self"),
+    pytest.param("search", 10, id="search-fewer-than-k-finite"),
+    pytest.param("merge", 16, id="merge-ties-across-rows"),
+    pytest.param("merge", 4, id="merge-fewer-than-k-finite"),
+    pytest.param("ivf_knn", None, id="ivf_knn-equals-parent"),
+])
+def test_selection_keeps_top_k_order(site, finite):
+    """The sort of (distance, id) pairs returns what ``lax.top_k`` +
+    gather-by-position returned, bit for bit, ties included: planted
+    ties, ``inf`` padding, a self slot, and rows with fewer than ``k``
+    finite candidates (the ``inf`` slots then come out in position
+    order too). ``finite`` is the valid members of a search block's 64
+    slots, or the finite columns of each of a merge query's <= 3 rows.
+    NaN is not covered: validated structural features are finite, and
+    ``_select_k``'s docstring says where one would land."""
+    if site == "ivf_knn":
+        # digests of the PARENT's output (commit 7b30f92: lax.top_k +
+        # m_gid[j] / take_along_axis) on this cloud; every row has ties
+        import hashlib
+
+        rng = np.random.default_rng(28)
+        pts = rng.integers(0, 4, size=(6000, 8)).astype(np.float32)
+        d2, idx = ivf_knn(pts, k=24, n_clusters=32, n_probe=6)
+        d2, idx = np.asarray(d2), np.asarray(idx)
+        assert (np.diff(d2, axis=1) == 0).any(axis=1).all()
+        assert hashlib.sha256(d2.tobytes()).hexdigest() == (
+            "75e1fa03687c9def2f3e94bc2ab3aa38c39a073727b2405707597d28837aaba2"
+        )
+        assert hashlib.sha256(idx.tobytes()).hexdigest() == (
+            "3c031a70621a519634f35462e59abb2ef1a7d026f2384cc6003d79a3088a4359"
+        )
+        return
+    k = 16
+    block = _search_block if site == "search" else _merge_block
+    (got_d2, got_ids), (want_d2, want_ids), d2 = block(finite, k, seed=3)
+    # the block holds what its name says
+    assert (np.isfinite(d2).sum(axis=1) < k).all() == (finite < k)
+    assert (want_d2[:, 1:] == want_d2[:, :-1]).any(axis=1).all()
+    np.testing.assert_array_equal(
+        np.asarray(got_d2).view(np.uint32), want_d2.view(np.uint32)
+    )
+    np.testing.assert_array_equal(np.asarray(got_ids), want_ids)

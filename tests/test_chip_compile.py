@@ -88,6 +88,46 @@ def test_knn_compiles_for_v5e(one_chip, impl, k):
     assert ("tpu_custom_call" in compiled.as_text()) == (impl == "pallas")
 
 
+@pytest.mark.parametrize("site", ["search", "merge"])
+def test_ivf_selection_compiles_without_a_gather(one_chip, site):
+    """The IVF search's selection at the pipeline cell's shapes (a
+    ``[4096, 8] x [1024, 8]`` chunk, a ``[16384, 18 * 128]`` merge tile,
+    k = 128): the sort carries the ids, so no gather turns positions into
+    ids afterwards. That gather was 3.6 s of a 16.3 s job (PR 28); this
+    keeps it from coming back. The merge's row gather of the pair tables
+    (``ivf/merge_gather``) is a different operation and stays."""
+    from graphmine_tpu.ops.ann import _merge_tiles, _search_clusters
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    k, chunk_b, l_max, p_max, merge_t = 128, 4096, 1024, 18, 16384
+    if site == "search":
+        compiled = _compile(
+            _search_clusters,
+            shape((chunk_b, 8), jnp.float32), shape((chunk_b,), jnp.int32),
+            shape((l_max, 8), jnp.float32), shape((l_max,), jnp.int32),
+            shape((l_max,), jnp.bool_), k=k,
+        )
+        scope = ""
+    else:
+        rows = 1295 * chunk_b + 1  # the cell's chunk rows + the junk row
+        compiled = _compile(
+            _merge_tiles,
+            shape((rows, k), jnp.float32), shape((rows, k), jnp.int32),
+            shape((16, merge_t, p_max), jnp.int32), k=k,
+        )
+        scope = "merge_topk"
+    hlo = compiled.as_text()
+    assert " sort(" in hlo
+    gathers = [
+        line for line in hlo.splitlines()
+        if (" gather(" in line or "AssumeGatherIndicesInBound" in line)
+        and scope in line
+    ]
+    assert not gathers, gathers[:2]
+
+
 def test_lpa_superstep_bucketed_compiles_for_v5e(one_chip, fused_plan, planted):
     from graphmine_tpu.ops.bucketed_mode import lpa_superstep_bucketed
 
