@@ -142,19 +142,8 @@ _EXPORTS = {
     "graphx_label_propagation": (
         "graphmine_tpu.oracle", "graphx_label_propagation"
     ),
-    "BlockedPlan": ("graphmine_tpu.ops.blocking", "BlockedPlan"),
-    "blocked_inflow": ("graphmine_tpu.ops.blocking", "blocked_inflow"),
-    "build_graph_and_blocked_plan": (
-        "graphmine_tpu.ops.blocking", "build_graph_and_blocked_plan"
-    ),
-    "cc_superstep_blocked": (
-        "graphmine_tpu.ops.blocking", "cc_superstep_blocked"
-    ),
-    "lpa_superstep_blocked": (
-        "graphmine_tpu.ops.blocking", "lpa_superstep_blocked"
-    ),
     "select_superstep_family": (
-        "graphmine_tpu.ops.blocking", "select_superstep_family"
+        "graphmine_tpu.ops.superstep_policy", "select_superstep_family"
     ),
     "obs": ("graphmine_tpu.obs", None),
     "CostEstimate": ("graphmine_tpu.obs.costmodel", "CostEstimate"),
@@ -177,7 +166,7 @@ _EXPORTS = {
         "graphmine_tpu.obs.memmodel", "schedule_footprint"
     ),
     "crossover_thresholds": (
-        "graphmine_tpu.ops.blocking", "crossover_thresholds"
+        "graphmine_tpu.ops.superstep_policy", "crossover_thresholds"
     ),
     "LofPlan": ("graphmine_tpu.pipeline.planner", "LofPlan"),
     "PlanError": ("graphmine_tpu.pipeline.planner", "PlanError"),

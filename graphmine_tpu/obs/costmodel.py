@@ -2,7 +2,7 @@
 
 Every compute-plane record so far says *what ran* (``impl_selected``,
 ``plan_build``, ``superstep_telemetry``) but not *how fast it should have
-run* — the crossover constants in ``ops/blocking.py`` and ``ops/lof.py``
+run* — the crossover constants in ``ops/superstep_policy.py`` and ``ops/lof.py``
 encode measured walls, yet nothing at runtime judges achieved throughput
 against them. This module closes that gap (ISSUE 12 tentpole), in the
 tradition of the GraphBLAST / propagation-blocking line (PAPERS arXiv
@@ -10,7 +10,7 @@ tradition of the GraphBLAST / propagation-blocking line (PAPERS arXiv
 IS the performance argument:
 
 1. **Per-plan cost derivation** — for every superstep family (sort /
-   bucketed / blocked, fused and sharded) and LOF impl, derive message
+   bucketed, fused and sharded) and LOF impl, derive message
    slots, padded gather slots, bytes gathered/scattered, padding overhead
    and exchanged ICI bytes **directly from the already-built plan/graph
    objects** (:func:`superstep_cost`, :func:`sharded_superstep_cost`,
@@ -71,19 +71,9 @@ ROOFLINE_SEEDS: dict = {
     MODEL_DEVICE_KIND: {
         # Random-gather slots/s: the r4/r5 `roofline` bench tier
         # (131.8M / 132.6M slots/s measured; ops/bucketed_mode.py
-        # header). Governs the sort gather and every bucketed/blocked
-        # row reduce.
+        # header). Governs the sort gather and every bucketed row
+        # reduce (136 M slots/s on a v5e at 128.3 M messages, PR 26).
         "gather_slots_per_sec": 1.32e8,
-        # Full binned-pass (stream + scatter) slots/s: SEEDED EQUAL to
-        # the random gather; no `blocking` capture ever replaced it, and
-        # the chip has since said the seed is ~3.6x too high (PERF.md §6,
-        # PR 26; v5e, 128.3 M messages a superstep): monotone gather
-        # 2.40 s + scatter-through-sort 1.13 s = 36 M slots/s for the
-        # binned pass, then the tile-local row gather at 54 M slots/s,
-        # against 136 M slots/s for the bucketed family's one gather
-        # (the anchor above held). Not re-seeded: auto no longer
-        # resolves the blocked family and ROADMAP D2 deletes it.
-        "binned_slots_per_sec": 1.32e8,
         # ICI exchange bytes/s per chip: NO bench tier measures this yet
         # — 4.5e10 B/s is a conservative v5e-interconnect model seed
         # (order of magnitude below the advertised peak; the sharded
@@ -101,9 +91,6 @@ ROOFLINE_SEEDS: dict = {
 _SEED_PROVENANCE = {
     "gather_slots_per_sec": (
         "r4/r5 roofline capture on TPU v5 lite (record deleted in PR 22)"
-    ),
-    "binned_slots_per_sec": (
-        "seeded = gather pending the silicon `blocking` capture"
     ),
     "exchange_bytes_per_sec": "model seed (unmeasured; no ICI bench tier yet)",
     "lof_exact_pairs_per_sec": "ops/lof.py r6 crossover table (65K in 2.3s)",
@@ -238,8 +225,6 @@ def _sig(x: float, digits: int = 4) -> float:
 def _plan_family(plan) -> str:
     if plan is None:
         return "sort"
-    if hasattr(plan, "padded_row_slots"):  # ops.blocking.BlockedPlan
-        return "blocked"
     if hasattr(plan, "vertex_ids"):        # ops.bucketed_mode.BucketedModePlan
         return "bucketed"
     raise TypeError(f"unknown plan type {type(plan).__name__}")
@@ -257,52 +242,11 @@ def _plan_weighted(plan) -> bool:
     return getattr(plan, "weight_mat", None) not in (None, ())
 
 
-def _sharded_family(sg) -> str:
-    """The plan family a built ``ShardedGraph`` runs — shapes only, no
-    jax import (``getattr`` because pre-r16 pickled/stub shard objects
-    lack the 2D fields). One owner for the cost, footprint and
-    shard_exchange consumers."""
-    if getattr(sg, "x2d_src_local", None) is not None:
-        return "sharded_2d"
-    if sg.blk_src is not None:
-        return "blocked"
-    if sg.bucket_send:
-        return "bucketed"
-    return "sort"
-
-
 def allgather_exchange_bytes(sg) -> int:
-    """The one-all_gather families' modeled per-chip exchange bytes per
-    superstep — every chip receives the other ``D-1`` chunks of the
-    padded label vector (``4·Vc·(D-1)``, the ROADMAP scaling ceiling).
-    This is the 2D family's comparison ladder, so it has one owner."""
+    """Modeled per-chip exchange bytes per superstep: every chip
+    receives the other ``D-1`` chunks of the padded label vector
+    (``4·Vc·(D-1)``, the ROADMAP scaling ceiling)."""
     return _I32 * int(sg.chunk_size) * max(int(sg.num_shards) - 1, 0)
-
-
-def neighbor_exchange_bytes(sg) -> int:
-    """The 2D family's modeled per-chip WIRE bytes per superstep: each
-    of the D-1 ppermute shifts ships one buffer of the shared padded
-    width B (SPMD needs one program, so every shard pays the max
-    boundary), i.e. ``4·(D-1)·B`` — what actually crosses the ICI with
-    the current shared-width implementation. On a skewed graph where
-    one (shard, peer) boundary approaches Vc this honestly approaches
-    the all_gather ladder; :func:`neighbor_frontier_bytes` is the
-    unpadded floor a per-pair-width (or frontier-masked) refinement
-    would approach."""
-    d = max(int(sg.num_shards), 1)
-    b = int(getattr(sg, "x2d_boundary", 0))
-    return _I32 * (d - 1) * b
-
-
-def neighbor_frontier_bytes(sg) -> int:
-    """The 2D family's exact UNPADDED per-chip boundary bytes per
-    superstep — ``4·Σ_peer |boundary(peer)|`` in the ISSUE's terms,
-    fleet total divided across chips (ceil): the information content of
-    the exchange, before the shared-SPMD-width padding
-    :func:`neighbor_exchange_bytes` charges for."""
-    d = max(int(sg.num_shards), 1)
-    total = int(getattr(sg, "x2d_boundary_total", 0))
-    return _I32 * -(-total // d)
 
 
 # ---- superstep families ----------------------------------------------------
@@ -320,7 +264,7 @@ def superstep_cost(
 ) -> CostEstimate:
     """Cost of ONE fused (single-device) superstep.
 
-    With ``plan`` (a built BucketedModePlan / BlockedPlan) the padded
+    With ``plan`` (a built BucketedModePlan) the padded
     slot counts are **exact** — read off the plan's own matrices; without
     one (the driver's plan-time ``impl_selected`` fires before the
     build, and the sort family never builds one) the r4-measured ~10%
@@ -337,9 +281,6 @@ def superstep_cost(
       sort rides inside the measured gather anchor), scatter V results.
     - **bucketed**: one random gather of the plan's padded slots
       (padding gathers the sentinel — same bandwidth), scatter V.
-    - **blocked**: bin phase streams M slots at the binned-pass rate
-      (monotone gather + tile scatter), reduce phase gathers the padded
-      row slots tile-locally at the gather rate, scatter V.
     """
     a = anchors if anchors is not None else rooflines()
     if plan is not None:
@@ -350,7 +291,6 @@ def superstep_cost(
     m = max(int(num_messages), 1)
     v = int(num_vertices)
     gather = a["gather_slots_per_sec"]["v"]
-    binned = a["binned_slots_per_sec"]["v"]
     wf = 2 if weighted else 1
     if family == "sort":
         padded = m
@@ -365,17 +305,6 @@ def superstep_cost(
         bytes_g = _I32 * padded * wf
         bytes_s = _I32 * v
         compute = (padded * wf) / gather
-    elif family == "blocked":
-        row_slots = (
-            int(plan.padded_row_slots) if plan is not None
-            else int(m * _EST_PAD)
-        )
-        padded = m + row_slots
-        # stream pass gathers M label slots + scatters them into the
-        # tile; reduce gathers the padded rows (and their weight mats).
-        bytes_g = _I32 * (m + row_slots * wf)
-        bytes_s = _I32 * m + _I32 * v
-        compute = m / binned + (row_slots * wf) / gather
     else:
         raise ValueError(f"unknown superstep family {family!r}")
     return CostEstimate(
@@ -388,9 +317,7 @@ def superstep_cost(
         predicted_seconds=compute,
         predicted_per_chip=num_edges / compute if compute > 0 else 0.0,
         unit="edges/s/chip",
-        roofline={
-            k: a[k] for k in ("gather_slots_per_sec", "binned_slots_per_sec")
-        },
+        roofline={"gather_slots_per_sec": a["gather_slots_per_sec"]},
     )
 
 
@@ -407,9 +334,9 @@ def sharded_superstep_cost(
     no device sync, no jax import; safe to call at operating-point build
     time on device-resident shards).
 
-    Per-chip compute follows the shard's plan family — blocked bin
-    groups (``blk_*``), the stacked bucket plan (``bucket_send``), or
-    the sort shard body over the padded ``[D, Mp]`` message arrays — and
+    Per-chip compute follows the shard's plan family — the stacked
+    bucket plan (``bucket_send``) or the sort shard body over the padded
+    ``[D, Mp]`` message arrays — and
     the exchange term models the per-superstep label collective: every
     chip receives the other ``D-1`` chunks of the padded label vector —
     the same bytes whether they arrive as one all_gather (``replicated``)
@@ -419,34 +346,14 @@ def sharded_superstep_cost(
     a = anchors if anchors is not None else rooflines()
     d = int(sg.num_shards)
     gather = a["gather_slots_per_sec"]["v"]
-    binned = a["binned_slots_per_sec"]["v"]
     exch_rate = a["exchange_bytes_per_sec"]["v"]
     if weighted is None:  # infer; explicit False models weight-blind ops (CC)
-        weighted = (
-            sg.msg_weight is not None
-            or bool(sg.bucket_weight) or bool(sg.blk_row_weight)
-        )
+        weighted = sg.msg_weight is not None or bool(sg.bucket_weight)
     wf = 2 if weighted else 1
     # NOTE: shard_graph_arrays(lpa_only=True) trims the sort-body arrays
-    # (msg_send may be None on a bucketed/blocked partition) — each
+    # (msg_send may be None on a bucketed partition) — each
     # family reads its padded slot count off its OWN arrays.
-    x2d = getattr(sg, "x2d_src_local", None)
-    if x2d is not None or sg.blk_src is not None:
-        # One compute model for both bin-group families — same bin
-        # tiles, same row reduce; the 2D family differs only in where
-        # the stream gathers from (the compact table) and in the
-        # exchange term set below.
-        family = "sharded_2d" if x2d is not None else "blocked"
-        stream = x2d if x2d is not None else sg.blk_src
-        mp = int(stream.shape[1])            # padded stream slots/shard
-        row_slots = sum(
-            int(r.shape[1]) * int(r.shape[2]) for r in sg.blk_row_idx
-        )
-        padded = mp + row_slots
-        bytes_g = _I32 * (mp + row_slots * wf)
-        bytes_s = _I32 * mp + _I32 * int(sg.chunk_size)
-        compute = mp / binned + (row_slots * wf) / gather
-    elif sg.bucket_send:
+    if sg.bucket_send:
         family = "bucketed"
         mp = None
         padded = sum(
@@ -467,15 +374,7 @@ def sharded_superstep_cost(
         else (mp if mp is not None else padded) * d
     )
     m_chip = max(m_total // max(d, 1), 1)    # real slots per chip (mean)
-    # Exchange term: the one-all_gather families ship the other D-1
-    # label chunks per chip; the 2D family ships one padded boundary
-    # buffer per peer — the honest WIRE bytes, padding included (r16 —
-    # the bytes drop the `exchange` bench tier and the acceptance pin
-    # assert; neighbor_frontier_bytes is the unpadded floor).
-    exchange_bytes = (
-        neighbor_exchange_bytes(sg) if family == "sharded_2d"
-        else allgather_exchange_bytes(sg)
-    )
+    exchange_bytes = allgather_exchange_bytes(sg)
     exchange = exchange_bytes / exch_rate
     predicted = compute + exchange
     return CostEstimate(
@@ -492,10 +391,7 @@ def sharded_superstep_cost(
         unit="edges/s/chip",
         roofline={
             k: a[k]
-            for k in (
-                "gather_slots_per_sec", "binned_slots_per_sec",
-                "exchange_bytes_per_sec",
-            )
+            for k in ("gather_slots_per_sec", "exchange_bytes_per_sec")
         },
     )
 
@@ -624,46 +520,26 @@ def emit_superstep_timing(
 
 def emit_shard_exchange(sink, op: str, sg, **kv) -> dict | None:
     """Emit one ``shard_exchange`` record: the modeled per-chip ICI bytes
-    of the shard family that actually ran next to the one-all_gather
-    ladder model (``4·Vc·(D-1)``), with the frontier fraction — what
-    share of a full label exchange the per-peer boundary tables actually
-    ship (1.0 for the one-all_gather families by construction). This is
-    the record's single emission point (the ``emit_memory_watermark``
-    contract); emitted at the existing telemetry cadence — once per
-    sharded repair apply on the serve path (the ``exchange`` bench tier
-    carries the same modeled numbers in its per-D ``detail`` rows
-    rather than a sink stream). No-op without a sink.
-
-    ``exchange_bytes`` is the WIRE model (padded shared-width buffers —
-    what actually ships); ``frontier_bytes`` the exact unpadded
-    boundary content, and ``frontier_frac`` its share of the ladder —
-    together with ``boundary_slots`` (fleet-total unpadded count) and
-    ``padded_boundary`` (the shared SPMD width B) they say how much of
-    the exchange is frontier vs padding (the 2D analog of
-    ``padding_overhead``)."""
+    of one sharded superstep. Every shard family exchanges by one tiled
+    ``all_gather`` of the label vector (``4·Vc·(D-1)`` a chip), so
+    ``exchange_bytes``, ``frontier_bytes`` and ``ladder_bytes`` are that
+    one number and ``frontier_frac`` is 1.0; the keys stay for the
+    record's readers. The record's single emission point, once per
+    sharded repair apply on the serve path. No-op without a sink."""
     if sink is None:
         return None
-    family = _sharded_family(sg)
     d = int(sg.num_shards)
     ladder = allgather_exchange_bytes(sg)
-    if family == "sharded_2d":
-        modeled = neighbor_exchange_bytes(sg)
-        frontier = neighbor_frontier_bytes(sg)
-    else:
-        modeled = frontier = ladder
-    frac = frontier / ladder if ladder else 1.0
     return sink.emit(
         "shard_exchange",
         op=op,
-        family=family,
+        family="bucketed" if sg.bucket_send else "sort",
         devices=d,
         peers=max(d - 1, 0),
-        exchange_bytes=int(modeled),
-        frontier_bytes=int(frontier),
-        ladder_bytes=int(ladder),
-        frontier_frac=round(frac, 4),
-        boundary_slots=int(getattr(sg, "x2d_boundary_total", 0)),
-        padded_boundary=int(getattr(sg, "x2d_boundary", 0)),
+        exchange_bytes=ladder,
+        frontier_bytes=ladder,
+        ladder_bytes=ladder,
+        frontier_frac=1.0,
         **kv,
     )
 

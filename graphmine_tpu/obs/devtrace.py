@@ -43,8 +43,8 @@ _PROGRAM_ID = re.compile(r"\(\d+\)$")
 
 def scope_of(op_name: str, registered) -> str:
     """The first two ``registered`` levels of an ``op_name``:
-    ``jit(f)/lpa_blocked/while/body/row_gather/w8/gather:`` gives
-    ``lpa_blocked/row_gather``. The last component is the primitive (and
+    ``jit(f)/lpa_bucketed/while/body/row_gather/w8/gather:`` gives
+    ``lpa_bucketed/row_gather``. The last component is the primitive (and
     after a colon its type), never a scope; what jit, scan and the other
     transforms put between the levels is not registered and drops out.
     No registered level gives ``unscoped``."""

@@ -11,10 +11,10 @@ tradition (PAPERS arXiv 1908.01407, 2011.08451) where explicit workspace
 budgets ARE the scaling argument:
 
 1. **Per-plan footprint inventory** — for every superstep family (sort /
-   bucketed / blocked, fused and sharded) and LOF impl, derive a named
+   bucketed, fused and sharded) and LOF impl, derive a named
    byte inventory **directly off the already-built plan/graph objects**:
-   CSR arrays, bucketed width-ladder mats, BlockedPlan stream+tile
-   slots, sharded twins plus the per-superstep all_gather exchange
+   CSR arrays, bucketed width-ladder mats, sharded twins plus the
+   per-superstep all_gather exchange
    buffer, LOF exact distance/top-k workspace vs IVF cluster-batched
    workspace, weighted payload doubling
    (:func:`superstep_footprint`, :func:`sharded_superstep_footprint`,
@@ -84,27 +84,18 @@ SINGLE_BYTES_PER_VERTEX = 8.0
 REPLICATED_BYTES_PER_VERTEX = 16.0
 RING_BYTES_PER_VERTEX = 24.0  # divided by D (labels are sharded)
 
-# Pre-plan tile estimate for the blocked family (the real plan knows its
-# ``tile_alloc`` exactly): one bin's message-tile budget, mirroring
-# ops/blocking.DEFAULT_TILE_SLOTS (2^18 slots = 1 MiB) without importing
-# the jax-loading ops layer.
-BLOCKED_TILE_SLOTS_EST = 1 << 18
-
 # IVF cluster-batch balance pad (model seed): real Qmax/Lmax are
 # data-dependent cluster sizes; the model assumes balanced clusters of
 # n/C padded by this factor (k-means imbalance at the measured scales —
 # ops/ann.py pads to the true max).
 IVF_BALANCE_PAD = 2.0
 
-# The family ladder the plan-time pre-degrade walks — the same
-# sharded_2d -> blocked -> bucketed -> sort order as
-# planner._SUPERSTEP_DEGRADE (sort is the floor: None, nothing leaner
-# exists; sharded_2d's rung drops the per-peer boundary tables back to
-# the one-all_gather exchange).
-FAMILY_DEGRADE = {
-    "sharded_2d": "blocked", "blocked": "bucketed", "bucketed": "sort",
-    "sort": None,
-}
+# THE superstep family degrade order (one statement; the planner's
+# ladder and SuperstepPlan.degrade_to and the plan-time pre-degrade below
+# all read it): bucketed drops its padded plan matrices for the sort
+# superstep; sort is the floor (None: nothing leaner exists). It lives
+# here because this module stays jax-free.
+FAMILY_DEGRADE = {"bucketed": "sort", "sort": None}
 
 
 @dataclass(frozen=True)
@@ -241,16 +232,13 @@ def superstep_footprint(
     num_edges: int | None = None,
     plan=None,
     weighted: bool | None = None,
-    num_devices: int = 1,
 ) -> MemEstimate:
     """Footprint of ONE fused (single-device) superstep operating point.
 
     With a built ``plan`` the counts are EXACT — the plan's own matrix
     shapes: edge endpoints + the message CSR + labels in/out + msg
-    weights, plus per family the width-ladder mats + vertex ids (+
-    slot-aligned weight mats) + the gathered transient (bucketed), or
-    the sender-major stream pair + the destination-binned tile + reduce
-    rows + owners (+ weight mats) + the row-gather transient (blocked).
+    weights, plus the width-ladder mats + vertex ids (+ slot-aligned
+    weight mats) + the gathered transient (bucketed).
 
     WITHOUT a plan (the driver's plan-time pre-degrade fires before any
     build) the estimate is anchored to the SAME seed constants the
@@ -258,23 +246,9 @@ def superstep_footprint(
     measured ``BYTES_PER_EDGE`` model, so ``bucketed`` reproduces
     :func:`schedule_inventory`'s single-device decomposition exactly
     (the two consumers can never disagree about the path the planner
-    just admitted, so an admitted run never spuriously pre-degrades),
-    ``sort`` drops the plan-mats term (the planner's documented
-    degradation saving), and ``blocked`` adds the stream pair + tile
-    the 36 B/edge seed predates.
-
-    ``num_devices`` (r16): pre-build estimates for a SHARDED operating
-    point — the ``sharded_2d`` family (only meaningful there) models the
-    per-chip sharded edge arrays + stream/tile + SHARDED labels + the
-    per-peer boundary tables at their worst case (boundary = the whole
-    peer chunk: the pre-build view cannot know the real boundary, and an
-    over-estimate pre-degrades where an under-estimate OOMs); the
-    one-all_gather families with ``num_devices > 1`` model the
-    replicated schedule's per-chip twin (sharded edge terms + the
-    replicated label pair + exchange buffer) so a sharded_2d → blocked
-    pre-degrade walk compares per-chip against per-chip. Existing
-    single-device callers (``num_devices=1``) are bit-identical to the
-    pre-r16 arithmetic.
+    just admitted, so an admitted run never spuriously pre-degrades)
+    and ``sort`` drops the plan-mats term (the planner's documented
+    degradation saving).
     """
     if plan is not None:
         family = _plan_family(plan)
@@ -284,44 +258,17 @@ def superstep_footprint(
     v = int(num_vertices)
     m = max(int(num_messages), 1)
     e = int(num_edges) if num_edges is not None else m // 2
-    d = max(int(num_devices), 1)
-    if family not in ("sort", "bucketed", "blocked", "sharded_2d"):
+    if family not in FAMILY_DEGRADE:
         raise ValueError(f"unknown superstep family {family!r}")
-    if family == "sharded_2d" and d < 2:
-        raise ValueError(
-            "family 'sharded_2d' needs num_devices >= 2 (its per-peer "
-            "exchange tables have no single-device meaning)"
-        )
-    if plan is None and family == "sharded_2d":
-        vc = -(-v // d)
-        mc = -(-m // d)
-        base = schedule_inventory("single", v, e, 1, weighted)
-        inv = {k: b // d for k, b in base.items() if k != "labels"}
-        inv["stream"] = 2 * _I32 * mc
-        inv["tile"] = _I32 * min(mc, BLOCKED_TILE_SLOTS_EST)
-        inv["labels_sharded"] = 2 * _I32 * vc
-        inv["exchange_send_tab"] = _I32 * vc * (d - 1)
-        inv["exchange_recv_bufs"] = _I32 * vc * (d - 1)
-        return MemEstimate(
-            op=op, family=family, devices=d, weighted=weighted,
-            inventory=inv, exact=False,
-        )
     if plan is None:
         # Seed-anchored estimates (see docstring): the bucketed path is
         # the measured schedule model verbatim, so an admitted run can
         # never pre-degrade off the family the planner just accepted.
-        if d > 1:
-            inv = schedule_inventory("replicated", v, e, d, weighted)
-        else:
-            inv = schedule_inventory("single", v, e, 1, weighted)
+        inv = schedule_inventory("single", v, e, 1, weighted)
         if family == "sort":
             del inv["plan_mats"]
-        elif family == "blocked":
-            mc = -(-m // d)
-            inv["stream"] = 2 * _I32 * mc
-            inv["tile"] = _I32 * min(mc, BLOCKED_TILE_SLOTS_EST)
         return MemEstimate(
-            op=op, family=family, devices=d, weighted=weighted,
+            op=op, family=family, devices=1, weighted=weighted,
             inventory=inv, exact=False,
         )
     inv = {
@@ -333,7 +280,7 @@ def superstep_footprint(
         inv["msg_weights"] = _I32 * m
     if family == "sort":
         inv["gather_transient"] = _I32 * m * (2 if weighted else 1)
-    elif family == "bucketed":
+    else:
         padded = _bucketed_padded_slots(plan)
         ids = sum(int(x.shape[0]) for x in (plan.vertex_ids or ()))
         if plan.hist_vertex_ids is not None:
@@ -343,16 +290,6 @@ def superstep_footprint(
         if weighted:
             inv["weight_mats"] = _I32 * padded
         inv["gather_transient"] = _I32 * padded
-    else:
-        rows = int(plan.padded_row_slots)
-        owners = sum(int(r.shape[0]) for r in plan.row_idx)
-        inv["stream"] = 2 * _I32 * m
-        inv["tile"] = _I32 * int(plan.tile_alloc)
-        inv["reduce_rows"] = _I32 * rows
-        inv["row_vertex"] = _I32 * owners
-        if weighted:
-            inv["weight_mats"] = _I32 * rows
-        inv["gather_transient"] = _I32 * rows
     return MemEstimate(
         op=op, family=family, devices=1, weighted=weighted,
         inventory=inv, exact=True,
@@ -391,15 +328,12 @@ def sharded_superstep_footprint(
     vc = int(sg.chunk_size)
     v = int(sg.num_vertices)
     if weighted is None:
-        weighted = (
-            sg.msg_weight is not None
-            or bool(sg.bucket_weight) or bool(sg.blk_row_weight)
-        )
+        weighted = sg.msg_weight is not None or bool(sg.bucket_weight)
     weighted = bool(weighted)
     # NOTE: shard_graph_arrays(lpa_only=True) trims the sort-body CSR
-    # (msg_recv_local/msg_send/degrees may all be None on a bucketed or
-    # blocked partition) — count only the arrays that exist, exactly
-    # like sharded_superstep_cost.
+    # (msg_recv_local/msg_send/degrees may all be None on a bucketed
+    # partition) — count only the arrays that exist, exactly like
+    # sharded_superstep_cost.
     inv: dict = {}
     if sg.degrees is not None:
         inv["degrees"] = _per_chip_bytes(sg.degrees)
@@ -412,50 +346,7 @@ def sharded_superstep_footprint(
         inv["shard_messages"] = msgs
     if sg.msg_weight is not None:
         inv["msg_weights"] = _per_chip_bytes(sg.msg_weight)
-    if getattr(sg, "x2d_src_local", None) is not None:
-        family = "sharded_2d"
-        inv["stream"] = (
-            _per_chip_bytes(sg.x2d_src_local) + _per_chip_bytes(sg.blk_pos)
-        )
-        inv["tile"] = _I32 * int(sg.blk_tile_alloc)
-        rows = sum(_per_chip_bytes(r) for r in sg.blk_row_idx)
-        inv["reduce_rows"] = rows
-        inv["row_vertex"] = sum(
-            _per_chip_bytes(t) for t in sg.blk_row_target
-        )
-        if sg.blk_row_weight:
-            inv["weight_mats"] = sum(
-                _per_chip_bytes(w) for w in sg.blk_row_weight
-            )
-        inv["gather_transient"] = rows
-        # the per-peer boundary plan: one send table + one received
-        # buffer set per peer offset, both at the padded [D-1, B] shape
-        inv["exchange_send_tab"] = _per_chip_bytes(sg.x2d_send_tab)
-        inv["exchange_recv_bufs"] = _per_chip_bytes(sg.x2d_send_tab)
-        # labels stay SHARDED (current + updated chunk) — the whole
-        # point: no replicated V-term regardless of `schedule`
-        inv["labels_sharded"] = 2 * _I32 * vc
-        return MemEstimate(
-            op=op, family=family, devices=d, weighted=weighted,
-            inventory=inv, exact=True,
-        )
-    if sg.blk_src is not None:
-        family = "blocked"
-        inv["stream"] = (
-            _per_chip_bytes(sg.blk_src) + _per_chip_bytes(sg.blk_pos)
-        )
-        inv["tile"] = _I32 * int(sg.blk_tile_alloc)
-        rows = sum(_per_chip_bytes(r) for r in sg.blk_row_idx)
-        inv["reduce_rows"] = rows
-        inv["row_vertex"] = sum(
-            _per_chip_bytes(t) for t in sg.blk_row_target
-        )
-        if sg.blk_row_weight:
-            inv["weight_mats"] = sum(
-                _per_chip_bytes(w) for w in sg.blk_row_weight
-            )
-        inv["gather_transient"] = rows
-    elif sg.bucket_send:
+    if sg.bucket_send:
         family = "bucketed"
         mats = sum(_per_chip_bytes(b) for b in sg.bucket_send)
         inv["plan_mats"] = mats
@@ -546,7 +437,6 @@ def predegrade_superstep(
     num_edges: int,
     weighted: bool,
     budget_bytes: int,
-    num_devices: int = 1,
 ):
     """Walk the family ladder at PLAN time until the modeled footprint
     fits ``budget_bytes`` — the proactive twin of the driver's reactive
@@ -559,21 +449,15 @@ def predegrade_superstep(
     (empty = the requested family fits). The sort floor is returned
     even when it does not fit: there is nothing leaner, and the
     planner's schedule model already accepted the run — the reactive
-    ladder (and the watermark trail) owns whatever happens next.
-
-    ``num_devices`` (r16): a ``sharded_2d`` starting rung — whose NEW
-    plan-time terms are the per-peer boundary tables, modeled at their
-    worst case — walks back to the one-all_gather ``blocked`` family and
-    onward; every rung is then modeled per-chip on the same mesh."""
+    ladder (and the watermark trail) owns whatever happens next."""
     budget = int(budget_bytes)
     steps = []
     while True:
         est = superstep_footprint(
             "lpa_superstep", family, num_vertices, num_messages,
             num_edges=num_edges, weighted=weighted,
-            num_devices=num_devices,
         )
-        nxt = FAMILY_DEGRADE.get(family)
+        nxt = FAMILY_DEGRADE[family]
         if est.total_bytes <= budget or nxt is None:
             return family, est, steps
         steps.append((family, nxt, est))

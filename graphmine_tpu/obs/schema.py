@@ -82,9 +82,9 @@ register("outliers_lof", "seconds", "k", "devices", "features")
 register("outlier_summary", "method")
 register("ivf_fallback", "guard", "detail")
 register("impl_selected", "op", "impl", "n", "reason")
-# plan_build: one per superstep-plan materialization (blocked/bucketed —
-# ops/blocking.emit_plan_records and the driver's single-device build):
-# host build seconds, family, bins/width classes, padded gather slots per
+# plan_build: one per superstep-plan materialization
+# (ops/superstep_policy.emit_plan_records and the driver's single-device
+# build): host build seconds, family, width classes, padded gather slots per
 # edge. Host plan cost grows with the tighter ladders; this record keeps
 # it visible in obs_report instead of hiding inside first-call latency.
 register("plan_build", "op", "family", "seconds", "padded_slots_per_edge")
@@ -114,22 +114,20 @@ register("superstep_timing", "op", "family", "variant", "iteration",
 register("memory_watermark", "op", "predicted_bytes", "achieved_bytes",
          "headroom_frac", "source", "mem")
 
-# shard_exchange (ISSUE 15): modeled per-chip ICI bytes of the shard
-# family that ran next to the one-all_gather ladder model (4·Vc·(D-1)),
-# with the frontier fraction — the share of a full label exchange the 2D
-# family's per-peer boundary tables actually ship. Single builder:
-# obs/costmodel.emit_shard_exchange, emitted once per sharded repair
-# apply (serve/delta.py); the `exchange` bench tier carries the same
-# modeled numbers in its per-D detail rows rather than a sink stream.
+# shard_exchange (ISSUE 15): modeled per-chip ICI bytes of one sharded
+# superstep: the one-all_gather model 4·Vc·(D-1) under all three byte
+# keys, frontier_frac 1.0 (a family that shipped less was deleted in
+# PR 29). Single builder: obs/costmodel.emit_shard_exchange, emitted once
+# per sharded repair apply (serve/delta.py).
 register("shard_exchange", "op", "family", "devices", "peers",
          "exchange_bytes", "frontier_bytes", "ladder_bytes",
          "frontier_frac")
 
 # exchange: one per `label_propagation(..., mesh=)` call (ops/lpa.py), exact
 # from the placed partition: the bytes one chip receives per superstep
-# (4·Vc·(D-1) for the all_gather families, 4·(D-1)·B for sharded_2d's
-# padded boundary buffers), how uneven the vertex-range shards are in
-# messages, and the padded gather slots a shard streams per superstep.
+# (4·Vc·(D-1): one tiled all_gather of the label vector), how uneven the
+# vertex-range shards are in messages, and the padded gather slots a shard
+# streams per superstep.
 register("exchange", "op", "family", "shards", "bytes_per_superstep",
          "messages_per_shard_max", "messages_per_shard_mean",
          "padded_slots_per_shard")
@@ -300,12 +298,12 @@ RECOVERY_PHASES = frozenset((
 # ``tests/test_trace.py`` holds the package to this list both ways.
 DEVICE_SCOPES = frozenset((
     # outer: algorithm x family
-    "lpa_blocked", "cc_blocked", "lpa_bucketed", "cc_bucketed",
+    "lpa_bucketed", "cc_bucketed",
     "lpa_sort", "cc_sort", "lpa_sharded", "masked_lpa", "superstep", "census",
     "modularity", "features", "triangles", "ivf", "knn_exact",
     "knn_cross", "lof",
     # inner: superstep passes
-    "bin_gather", "bin_scatter", "row_gather", "row_mode", "row_min",
+    "row_gather", "row_mode", "row_min",
     "hist", "write_back", "pointer_jump", "msg_gather", "segment_mode",
     "segment_min", "sort", "run_reduce", "mask", "exchange",
     "changed_count", "converged",

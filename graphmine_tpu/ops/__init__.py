@@ -9,14 +9,7 @@ from graphmine_tpu.ops.streaming_lof import StreamingLOF, fit_lof, score_lof
 from graphmine_tpu.ops.louvain import leiden, louvain
 from graphmine_tpu.ops.modularity import modularity
 from graphmine_tpu.ops.bucketed_mode import BucketedModePlan, bucketed_mode, lpa_superstep_bucketed
-from graphmine_tpu.ops.blocking import (
-    BlockedPlan,
-    blocked_inflow,
-    build_graph_and_blocked_plan,
-    cc_superstep_blocked,
-    lpa_superstep_blocked,
-    select_superstep_family,
-)
+from graphmine_tpu.ops.superstep_policy import select_superstep_family
 from graphmine_tpu.ops.pagerank import pagerank, parallel_personalized_pagerank
 from graphmine_tpu.ops.svdpp import SVDPlusPlusModel, svd_plus_plus, svdpp_predict
 from graphmine_tpu.ops.degrees import degrees, in_degrees, out_degrees
@@ -41,4 +34,4 @@ __all__ = ["degree_assortativity", "density", "diameter", "reciprocity", "spectr
            "eigenvector_centrality", "katz_centrality",
            "weighted_shortest_paths",
            "adjusted_rand_index", "normalized_mutual_info","segment_mode", "BucketedModePlan", "bucketed_mode", "lpa_superstep_bucketed",
-           "BlockedPlan", "blocked_inflow", "build_graph_and_blocked_plan", "cc_superstep_blocked", "lpa_superstep_blocked", "select_superstep_family", "aggregate_messages", "pregel", "find", "parse_pattern", "StreamingLOF", "fit_lof", "score_lof", "label_propagation", "lpa_superstep", "connected_components", "strongly_connected_components", "louvain", "leiden", "modularity", "pagerank", "parallel_personalized_pagerank", "svd_plus_plus", "svdpp_predict", "SVDPlusPlusModel", "degrees", "in_degrees", "out_degrees", "bfs", "bfs_parents", "bfs_distances", "shortest_paths", "triangle_count", "clustering_coefficient", "core_numbers"]
+           "select_superstep_family", "aggregate_messages", "pregel", "find", "parse_pattern", "StreamingLOF", "fit_lof", "score_lof", "label_propagation", "lpa_superstep", "connected_components", "strongly_connected_components", "louvain", "leiden", "modularity", "pagerank", "parallel_personalized_pagerank", "svd_plus_plus", "svdpp_predict", "SVDPlusPlusModel", "degrees", "in_degrees", "out_degrees", "bfs", "bfs_parents", "bfs_distances", "shortest_paths", "triangle_count", "clustering_coefficient", "core_numbers"]

@@ -111,59 +111,38 @@ def connected_components(
     ``plan``: a fused :class:`BucketedModePlan` (r5) — supersteps run
     :func:`cc_superstep_bucketed` instead of the segment_min path
     (identical labels every step, tested; measured 2.57x on the
-    100M-edge cc bench tier in the r5 capture) — or a
-    :class:`~graphmine_tpu.ops.blocking.BlockedPlan` (r7): supersteps run
-    :func:`~graphmine_tpu.ops.blocking.cc_superstep_blocked`, the
-    destination-binned bin-then-reduce layout (taken only on request:
-    its three passes took 5.4x the one bucketed gather on a v5e, PERF.md
-    §6, PR 26). The default ``"auto"`` resolves the family through
-    :func:`~graphmine_tpu.ops.blocking.select_superstep_family` (the
-    single crossover-policy owner; same per-graph plan cache as
+    100M-edge cc bench tier in the r5 capture). The default ``"auto"``
+    resolves the family through
+    :func:`~graphmine_tpu.ops.superstep_policy.select_superstep_family`
+    (the single crossover-policy owner; same per-graph plan cache as
     :func:`~graphmine_tpu.ops.lpa.label_propagation`); ``None`` forces
     the segment_min path. Callers that built the graph with
-    ``build_graph_and_plan`` / ``build_graph_and_blocked_plan`` can pass
-    their plan directly. ``sink``: optional MetricsSink — auto
+    ``build_graph_and_plan`` can pass their plan directly. ``sink``:
+    optional MetricsSink — auto
     resolutions emit ``impl_selected`` + ``plan_build`` provenance
     records (see ``label_propagation``).
     """
-    from graphmine_tpu.ops.blocking import BlockedPlan
-
     if isinstance(plan, str) and plan == "auto":
-        from graphmine_tpu.ops.blocking import (
+        from graphmine_tpu.ops.lpa import _cached_auto_plan
+        from graphmine_tpu.ops.superstep_policy import (
             emit_plan_records,
             select_superstep_family,
         )
-        from graphmine_tpu.ops.lpa import _cached_auto_plan
 
         plan = None
         if not isinstance(graph.msg_ptr, jax.core.Tracer):
             family, reason = select_superstep_family(
-                graph.num_vertices, graph.num_messages,
-                weighted=graph.msg_weight is not None,
+                graph.num_vertices, graph.num_messages
             )
             seconds, cached = 0.0, False
-            if family != "sort":
-                plan, seconds, cached = _cached_auto_plan(graph, family)
+            if family == "bucketed":
+                plan, seconds, cached = _cached_auto_plan(graph)
             emit_plan_records(
                 sink, "cc_superstep", plan, reason, seconds, cached,
                 graph.num_edges, graph.num_messages,
                 num_vertices=graph.num_vertices,
             )
-    if isinstance(plan, BlockedPlan):
-        # Full plan/graph identity check HERE, where the graph is in
-        # hand — cc_superstep_blocked alone can only check V, and a
-        # same-V plan from a different graph would silently mis-reduce.
-        if (
-            plan.num_vertices != graph.num_vertices
-            or plan.num_messages != graph.num_messages
-        ):
-            raise ValueError(
-                f"plan built for V={plan.num_vertices}, "
-                f"M={plan.num_messages} but graph has "
-                f"V={graph.num_vertices}, M={graph.num_messages} — "
-                "plan/graph mismatch"
-            )
-    elif plan is not None and plan.send_idx is None:
+    if plan is not None and plan.send_idx is None:
         plan = None  # non-fused plan: no label-gather indices to min over
     if sink is not None and not isinstance(graph.msg_ptr, jax.core.Tracer):
         # Achieved-vs-model attribution (ISSUE 12): run the fixpoint with
@@ -209,14 +188,10 @@ def _connected_components(
         with jax.named_scope("superstep"), jax.named_scope("converged"):
             return (prev_changed > 0) & (it < limit)
 
-    from graphmine_tpu.ops.blocking import BlockedPlan, cc_superstep_blocked
-
     def body(state):
         labels, _, it = state
         if plan is None:
             new = cc_superstep(labels, graph)
-        elif isinstance(plan, BlockedPlan):
-            new = cc_superstep_blocked(labels, plan)
         else:
             new = cc_superstep_bucketed(labels, plan)
         with jax.named_scope("superstep"), jax.named_scope("changed_count"):

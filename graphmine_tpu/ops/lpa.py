@@ -66,16 +66,12 @@ def label_propagation(
     ``plan``: a
     :class:`~graphmine_tpu.ops.bucketed_mode.BucketedModePlan` (the
     degree-bucketed dense mode kernel, ~3x the sort superstep at 10^7
-    messages) or a :class:`~graphmine_tpu.ops.blocking.BlockedPlan` (the
-    propagation-blocking bin-then-reduce engine: three passes over the
-    messages where the bucketed plan makes one, 5.4x slower on a v5e at
-    128 M messages — PERF.md §6, PR 26 — and taken only on request)
-    — identical labels either way, tested. The default ``"auto"``
-    resolves the family through
-    :func:`~graphmine_tpu.ops.blocking.select_superstep_family` (the
-    single crossover-policy owner: ``bucketed`` or ``sort`` on one
-    device) and builds the plan from the graph (cached per graph, per
-    family). Auto stays on the sort path when
+    messages) — identical labels to the sort superstep, tested. The
+    default ``"auto"`` resolves the family through
+    :func:`~graphmine_tpu.ops.superstep_policy.select_superstep_family`
+    (the single crossover-policy owner: ``bucketed`` or ``sort``) and
+    builds the plan from the graph (cached per graph). Auto stays on the
+    sort path when
     custom ``init_labels`` are given (the fused plan's
     histogram/sentinel machinery assumes labels in ``[0, V)`` — the
     default ``arange`` initialization guarantees that, arbitrary labels
@@ -84,7 +80,7 @@ def label_propagation(
 
     ``sink``: optional MetricsSink — each auto resolution emits an
     ``impl_selected`` record, and each plan materialization a
-    ``plan_build`` record (family, build seconds, bins/buckets, padded
+    ``plan_build`` record (family, build seconds, width classes, padded
     slots/edge), so host plan cost is visible in obs_report instead of
     hiding inside first-call latency.
 
@@ -109,34 +105,31 @@ def label_propagation(
         return _mesh_label_propagation(
             graph, mesh, max_iter, init_labels, plan, sink
         )
-    from graphmine_tpu.ops.blocking import BlockedPlan, emit_plan_records
     from graphmine_tpu.ops.bucketed_mode import BucketedModePlan
+    from graphmine_tpu.ops.superstep_policy import (
+        emit_plan_records,
+        select_superstep_family,
+    )
 
     if isinstance(plan, str) and plan == "auto":
         plan = None
         if init_labels is None and not isinstance(graph.msg_ptr, jax.core.Tracer):
-            from graphmine_tpu.ops.blocking import select_superstep_family
-
             family, reason = select_superstep_family(
-                graph.num_vertices, graph.num_messages,
-                weighted=graph.msg_weight is not None,
+                graph.num_vertices, graph.num_messages
             )
             seconds, cached = 0.0, False
-            if family != "sort":
-                # Weighted graphs ride the fast paths too (r2): both
-                # builders carry the slot-aligned weight payload.
-                plan, seconds, cached = _cached_auto_plan(graph, family)
+            if family == "bucketed":
+                # Weighted graphs ride the fast path too (r2): the plan
+                # carries the slot-aligned weight payload.
+                plan, seconds, cached = _cached_auto_plan(graph)
             emit_plan_records(
                 sink, "lpa_superstep", plan, reason, seconds, cached,
                 graph.num_edges, graph.num_messages,
                 num_vertices=graph.num_vertices,
             )
-    elif plan is not None and not isinstance(
-        plan, (BucketedModePlan, BlockedPlan)
-    ):
+    elif plan is not None and not isinstance(plan, BucketedModePlan):
         raise ValueError(
-            "plan must be 'auto', None, a BucketedModePlan or a "
-            f"BlockedPlan; got {plan!r}"
+            f"plan must be 'auto', None or a BucketedModePlan; got {plan!r}"
         )
     if (
         isinstance(plan, BucketedModePlan)
@@ -191,8 +184,8 @@ def label_propagation(
 _auto_plan_cache: dict = {}
 
 
-def _cached_auto_plan(graph: Graph, family: str = "bucketed"):
-    """Auto plan per (graph, family), cached so repeated calls pay the
+def _cached_auto_plan(graph: Graph):
+    """The graph's auto (bucketed) plan, cached so repeated calls pay the
     host build (device->host fetch of msg_ptr/msg_send + NumPy layout)
     once. Keyed by the identity of the graph's msg_ptr array; a weakref
     finalizer evicts the entry when that array is collected. Returns
@@ -200,29 +193,20 @@ def _cached_auto_plan(graph: Graph, family: str = "bucketed"):
     material (seconds is 0.0 on a cache hit)."""
     import weakref
 
-    from graphmine_tpu.ops.blocking import BlockedPlan, timed_plan_build
     from graphmine_tpu.ops.bucketed_mode import BucketedModePlan
+    from graphmine_tpu.ops.superstep_policy import timed_plan_build
 
     key = id(graph.msg_ptr)
     hit = _auto_plan_cache.get(key)
-    if hit is None or hit[0]() is not graph.msg_ptr:
-        ref = weakref.ref(
-            graph.msg_ptr, lambda _, k=key: _auto_plan_cache.pop(k, None)
-        )
-        hit = (ref, {})
-        _auto_plan_cache[key] = hit
-    plans = hit[1]
-    if family in plans:
-        return plans[family], 0.0, True
-    if family == "blocked":
-        plan, seconds = timed_plan_build(lambda: BlockedPlan.from_graph(graph))
-    elif family == "bucketed":
-        plan, seconds = timed_plan_build(
-            lambda: BucketedModePlan.from_graph(graph, with_send=True)
-        )
-    else:
-        raise ValueError(f"no plan to build for family {family!r}")
-    plans[family] = plan
+    if hit is not None and hit[0]() is graph.msg_ptr:
+        return hit[1], 0.0, True
+    plan, seconds = timed_plan_build(
+        lambda: BucketedModePlan.from_graph(graph, with_send=True)
+    )
+    ref = weakref.ref(
+        graph.msg_ptr, lambda _, k=key: _auto_plan_cache.pop(k, None)
+    )
+    _auto_plan_cache[key] = (ref, plan)
     return plan, seconds, False
 
 
@@ -233,7 +217,7 @@ def _mesh_label_propagation(graph, mesh, max_iter, init_labels, plan, sink):
     """The mesh half of :func:`label_propagation`: resolve the family,
     partition and place the graph (cached), emit the provenance records,
     run the one compiled program."""
-    from graphmine_tpu.ops.blocking import (
+    from graphmine_tpu.ops.superstep_policy import (
         crossover_thresholds,
         select_superstep_family,
     )
@@ -246,7 +230,7 @@ def _mesh_label_propagation(graph, mesh, max_iter, init_labels, plan, sink):
         )
     family, reason = select_superstep_family(
         graph.num_vertices, graph.num_messages, requested=plan,
-        weighted=graph.msg_weight is not None, num_devices=mesh.size,
+        num_devices=mesh.size,
     )
     sg, stats, cached = _cached_mesh_partition(graph, mesh, family)
     if sink is not None:
@@ -258,7 +242,7 @@ def _mesh_label_propagation(graph, mesh, max_iter, init_labels, plan, sink):
         )
         sink.emit(
             "partition", shards=mesh.size, family=family, cached=cached,
-            schedule="sharded_2d" if family == "sharded_2d" else "replicated",
+            schedule="replicated",
             seconds=0.0 if cached else round(stats["partition_seconds"], 6),
         )
         if family != "sort":
@@ -290,7 +274,6 @@ def _cached_mesh_partition(graph: Graph, mesh, family: str):
 
     from graphmine_tpu.obs.costmodel import sharded_superstep_cost
     from graphmine_tpu.parallel.sharded import (
-        FAMILY_PARTITION_FLAGS,
         _shard_message_offsets,
         partition_graph,
         shard_graph_arrays,
@@ -314,7 +297,7 @@ def _cached_mesh_partition(graph: Graph, mesh, family: str):
     sg = shard_graph_arrays(
         partition_graph(
             graph, mesh=mesh, lpa_only=lpa_only, timings=timings,
-            **FAMILY_PARTITION_FLAGS[family],
+            build_bucket_plan=family == "bucketed",
         ),
         mesh, lpa_only=lpa_only,
     )
@@ -331,7 +314,7 @@ def _cached_mesh_partition(graph: Graph, mesh, family: str):
     stats = {
         "partition_seconds": seconds - timings["plan_seconds"],
         "plan_seconds": timings["plan_seconds"],
-        "width_classes": len(sg.bucket_send or sg.blk_row_idx),
+        "width_classes": len(sg.bucket_send),
         "padded_slots_per_shard": cost.padded_slots,
         "cost": cost.record(),
         "exchange": {
@@ -363,16 +346,9 @@ def _label_propagation(
     if plan is None:
         superstep = lambda lbl: lpa_superstep(lbl, graph)
     else:
-        from graphmine_tpu.ops.blocking import (
-            BlockedPlan,
-            lpa_superstep_blocked,
-        )
         from graphmine_tpu.ops.bucketed_mode import lpa_superstep_bucketed
 
-        if isinstance(plan, BlockedPlan):
-            superstep = lambda lbl: lpa_superstep_blocked(lbl, graph, plan)
-        else:
-            superstep = lambda lbl: lpa_superstep_bucketed(lbl, graph, plan)
+        superstep = lambda lbl: lpa_superstep_bucketed(lbl, graph, plan)
 
     def step(labels, _):
         new = superstep(labels)

@@ -48,62 +48,18 @@ def pagerank(
     below ``tol`` (checked inside the while_loop — no host sync per
     iteration), bounded by ``max_iter``.
 
-    ``plan``: a :class:`~graphmine_tpu.ops.blocking.BlockedPlan` routes
-    the inflow through the destination-binned bin-then-reduce layout
-    (``blocked_inflow``; sums reassociate, so parity is to float
-    tolerance). Requires a **directed** message CSR
-    (``build_graph(..., symmetric=False)`` — a symmetric CSR carries both
-    directions and would double the inflow) and ``weights=None`` (the
-    per-edge ``weights`` argument is edge-order-aligned, not CSR-aligned;
-    a weighted run refuses loudly rather than silently dropping or
-    misaligning weights — pass ``plan=None``). The default ``"auto"``
-    is the segment_sum path at every size: PageRank has no bucketed
-    inflow, and the superstep policy
-    (:func:`~graphmine_tpu.ops.blocking.select_superstep_family`) has
-    resolved no graph to ``blocked`` since PR 26, so only an explicit
-    ``BlockedPlan`` takes the binned layout. ``sink``: optional
-    MetricsSink for the ``superstep_timing`` record.
+    ``plan``: ``"auto"`` or ``None``, both the ``segment_sum`` inflow at
+    every size (PageRank has no bucketed inflow; the argument is the
+    superstep ops' common one). ``sink``: optional MetricsSink for the
+    ``superstep_timing`` record.
     """
-    from graphmine_tpu.ops.blocking import BlockedPlan
-
-    resolved = None
-    if isinstance(plan, BlockedPlan):
-        if (
-            plan.num_vertices != graph.num_vertices
-            or plan.num_messages != graph.num_messages
-        ):
-            # blocked_inflow alone can only check V; a same-V plan from a
-            # different graph would silently route rank the wrong way
-            raise ValueError(
-                f"plan built for V={plan.num_vertices}, "
-                f"M={plan.num_messages} but graph has "
-                f"V={graph.num_vertices}, M={graph.num_messages} — "
-                "plan/graph mismatch"
-            )
-        if graph.symmetric:
-            raise ValueError(
-                "blocked PageRank needs a directed message CSR "
-                "(build_graph(..., symmetric=False)); this graph's CSR "
-                "carries both directions and would double the inflow"
-            )
-        if weights is not None:
-            raise ValueError(
-                "blocked PageRank does not carry the edge-aligned weights "
-                "argument (the plan's layout is CSR-aligned); pass "
-                "plan=None for weighted ranks — weights are never "
-                "silently dropped"
-            )
-        resolved = plan
-    elif plan is not None and not (isinstance(plan, str) and plan == "auto"):
-        raise ValueError(
-            f"plan must be 'auto', None, or a BlockedPlan; got {plan!r}"
-        )
+    if plan is not None and not (isinstance(plan, str) and plan == "auto"):
+        raise ValueError(f"plan must be 'auto' or None; got {plan!r}")
     if sink is not None and not isinstance(graph.msg_ptr, jax.core.Tracer):
         # Achieved-vs-model attribution (ISSUE 12): _pagerank returns its
         # while_loop iteration count, so the window is the REAL
         # supersteps-to-tolerance; judged against the analytical model
-        # (segment_sum inflow ≈ the sort gather; blocked_inflow ≈ the
-        # binned two-pass).
+        # (segment_sum inflow ≈ the sort gather).
         from graphmine_tpu.obs.costmodel import (
             emit_superstep_timing,
             superstep_cost,
@@ -111,22 +67,20 @@ def pagerank(
         )
 
         (pr, iters), secs, cold = timed_fixpoint(
-            lambda: _pagerank(
-                graph, alpha, max_iter, tol, reset, weights, resolved
-            ),
+            lambda: _pagerank(graph, alpha, max_iter, tol, reset, weights),
         )
         iters = max(int(iters), 1)
         cost = superstep_cost(
-            "pagerank_inflow", "sort" if resolved is None else "auto",
+            "pagerank_inflow", "sort",
             graph.num_vertices, graph.num_messages, graph.num_edges,
-            plan=resolved, weighted=weights is not None,
+            weighted=weights is not None,
         )
         emit_superstep_timing(
             sink, "pagerank_inflow", cost, iters, iters, secs,
             graph.num_edges, variant="fused", cold_compile=cold,
         )
         return pr
-    pr, _ = _pagerank(graph, alpha, max_iter, tol, reset, weights, resolved)
+    pr, _ = _pagerank(graph, alpha, max_iter, tol, reset, weights)
     return pr
 
 
@@ -138,7 +92,6 @@ def _pagerank(
     tol: float = 1e-6,
     reset: jax.Array | None = None,
     weights: jax.Array | None = None,
-    plan=None,
 ) -> jax.Array:
     v = graph.num_vertices
     src, dst = graph.src, graph.dst
@@ -163,11 +116,7 @@ def _pagerank(
 
     def step(state):
         pr, _, it = state
-        if plan is not None:
-            from graphmine_tpu.ops.blocking import blocked_inflow
-
-            inflow = blocked_inflow(plan, pr * inv_out)
-        elif edge_frac is None:
+        if edge_frac is None:
             inflow = jax.ops.segment_sum((pr * inv_out)[src], dst, num_segments=v)
         else:
             inflow = jax.ops.segment_sum(pr[src] * edge_frac, dst, num_segments=v)

@@ -1,0 +1,149 @@
+"""Superstep family policy: which plan a LPA / CC / PageRank superstep runs.
+
+Two families. ``bucketed`` (:class:`~graphmine_tpu.ops.bucketed_mode.
+BucketedModePlan`: per-degree-class dense rows gathered straight from the
+label vector) is the fast path, fused and per shard on a mesh. ``sort``
+(the ``segment_mode`` / ``segment_min`` / ``segment_sum`` superstep over
+the message CSR, no plan) is the reference every test holds ``bucketed``
+to, the path for graphs too small to repay a plan build, and the planner's
+degrade rung.
+
+This module owns the choice (:func:`select_superstep_family`) and the
+``impl_selected`` / ``plan_build`` records that explain it
+(:func:`emit_plan_records`); ``ops/lpa.py``, ``ops/cc.py``,
+``ops/pagerank.py``, ``pipeline/planner.py`` and ``pipeline/driver.py``
+read both from here.
+"""
+
+from __future__ import annotations
+
+import time
+
+from graphmine_tpu.obs.costmodel import _bucketed_padded_slots, superstep_cost
+from graphmine_tpu.obs.memmodel import FAMILY_DEGRADE
+
+# bucketed beats sort from ~2^16 messages (r1 measurement, the threshold
+# label_propagation has shipped since; the plan build amortizes past there).
+#
+# Two more families were built, measured on a TPU v5e and deleted (PR 29;
+# docs/DESIGN.md "Tried, measured, deleted"):
+#   * `blocked` (propagation blocking: sender-major stream -> destination-
+#     binned tile -> tile-local rows), PR 26, V = 2^22, M = 128.3 M: device
+#     seconds per LPA superstep 6.134 (bin_gather 2.40 + bin_scatter 1.13 +
+#     row_gather 2.50) against bucketed's 1.139 (row_gather 1.02 + the rest
+#     0.10). A gather from a value table past on-chip capacity did not cost
+#     several times a tile-local one, so two gathers and a scatter lost to one.
+#   * the 2D partition (labels sharded, per-peer boundary `ppermute`, then
+#     blocked's passes), PR 27, four chips, same graph: 12.397 s a job against
+#     2.748 s for per-shard bucket rows + one tiled `all_gather` a superstep,
+#     whose exchange is 0.05 % of the job: nothing for a thinner exchange to win.
+# The ring schedule (`parallel/ring.py`, 33.291 s there) is the planner's
+# memory rung for label vectors that do not fit replicated, not a speed choice.
+BUCKETED_MIN_MESSAGES = 1 << 16
+
+# the families, fast to lean: the keys of the one degrade order
+FAMILIES = tuple(FAMILY_DEGRADE)
+
+
+def crossover_thresholds() -> dict:
+    """The family-crossover constants that decide every ``plan="auto"``
+    resolution. One owner for the selection (:func:`select_superstep_family`)
+    and its provenance (``impl_selected`` carries this dict, so a policy
+    flip is explainable from the JSONL alone)."""
+    return {"bucketed_min_messages": BUCKETED_MIN_MESSAGES}
+
+
+def select_superstep_family(
+    num_vertices: int, num_messages: int, requested: str = "auto",
+    num_devices: int = 1,
+) -> tuple[str, str]:
+    """Resolve the superstep plan family: ``(family, reason)`` with
+    ``family`` in :data:`FAMILIES`.
+
+    ``requested`` forces a family (validated). ``auto`` is ``bucketed`` on
+    a mesh of >= 2 devices at every size (per-shard bucket rows, labels
+    replicated, one ``all_gather`` a superstep) and on one device from
+    :data:`BUCKETED_MIN_MESSAGES` messages; below that it is ``sort``.
+    The crossover is in messages alone; ``num_vertices`` decides nothing.
+    """
+    d = int(num_devices)
+    if requested != "auto":
+        if requested not in FAMILIES:
+            raise ValueError(
+                f"unknown superstep family {requested!r}; expected one of "
+                f"{FAMILIES} or 'auto'"
+            )
+        return requested, f"requested {requested!r}"
+    if d >= 2:
+        return "bucketed", (
+            f"D={d}: per-shard degree-bucketed rows, labels replicated, one "
+            "all_gather a superstep (four v5e chips at M=128.3 M: 2.75 s a "
+            "job, the exchange 0.05 % of it; PERF.md PR 27)"
+        )
+    if num_messages >= BUCKETED_MIN_MESSAGES:
+        return "bucketed", (
+            f"M={num_messages} >= {BUCKETED_MIN_MESSAGES}: degree-bucketed "
+            "dense rows amortize the host plan build (r1 crossover)"
+        )
+    return "sort", (
+        f"M={num_messages} < {BUCKETED_MIN_MESSAGES}: sort-based "
+        "segment_mode superstep (plan build would dominate)"
+    )
+
+
+# ---- plan-build observability ----------------------------------------------
+
+
+def plan_build_stats(plan, num_edges: int) -> dict:
+    """The ``plan_build`` record payload (see ``obs/schema.py``): width
+    classes and the padded gather slots per edge, the number the
+    width-ladder work optimizes. ``bins`` is always 0 (the record's key
+    from when a binned family existed; its readers select on it)."""
+    slots = _bucketed_padded_slots(plan)
+    return {
+        "family": "bucketed",
+        "bins": 0,
+        "width_classes": len(plan.vertex_ids),
+        "padded_slots_per_edge": round(slots / max(int(num_edges), 1), 3),
+    }
+
+
+def emit_plan_records(
+    sink, op: str, plan, reason: str, seconds: float, cached: bool,
+    num_edges: int, num_messages: int, num_vertices: int | None = None,
+) -> None:
+    """Emit the ``impl_selected`` + ``plan_build`` provenance pair for one
+    auto-plan resolution (no-op without a sink). ``plan=None`` (sort
+    family) emits only ``impl_selected``: there is no plan to build.
+
+    Both records carry the decision's evidence: the active crossover
+    ``thresholds`` (:func:`crossover_thresholds`) and the analytical
+    ``cost`` sub-record (:func:`graphmine_tpu.obs.costmodel.superstep_cost`,
+    exact padded slots when a plan exists)."""
+    if sink is None:
+        return
+    family = "sort" if plan is None else "bucketed"
+    v = (
+        num_vertices if num_vertices is not None
+        else getattr(plan, "num_vertices", 0)
+    )
+    cost = superstep_cost(
+        op, family, v, num_messages, num_edges, plan=plan
+    )
+    sink.emit(
+        "impl_selected", op=op, impl=family, n=num_messages, reason=reason,
+        thresholds=crossover_thresholds(), cost=cost.record(),
+    )
+    if plan is None:
+        return
+    sink.emit(
+        "plan_build", op=op, seconds=round(seconds, 6), cached=cached,
+        cost=cost.record(), **plan_build_stats(plan, num_edges),
+    )
+
+
+def timed_plan_build(build) -> tuple:
+    """``(plan, seconds)`` for one host plan build."""
+    t0 = time.perf_counter()
+    plan = build()
+    return plan, time.perf_counter() - t0

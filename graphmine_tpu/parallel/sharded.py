@@ -302,58 +302,6 @@ class ShardedGraph:
     # aligned slot-for-slot with bucket_send (padding slots 0). Empty on
     # unweighted graphs.
     bucket_weight: tuple = ()
-    # Stacked propagation-blocking plan (r7, ops/blocking.py): each
-    # shard's vertex chunk is a BIN GROUP — destination-range bins over
-    # the shard's local CSR, shard-local tiles, the same one-all_gather
-    # ring exchange. blk_src[d]: int32 [Mp] sender ids in sender-major
-    # order (padding = padded_vertices, the label sentinel slot);
-    # blk_pos[d]: each streamed message's slot in the shard's binned tile
-    # (padding messages land in a scratch region past the bins). Per
-    # width class c: blk_row_idx[c] int32 [D, n_c, w_c] TILE slots
-    # (padding = the reserved sentinel slot), blk_row_target[c] int32
-    # [D, n_c] LOCAL owned-vertex indices (padding rows = chunk_size + j
-    # scratch, the bucketed plan's trick), blk_row_weight[c] optional
-    # float32 [D, n_c, w_c]. None/empty = no blocked plan.
-    blk_src: jax.Array | None = None
-    blk_pos: jax.Array | None = None
-    blk_row_idx: tuple = ()
-    blk_row_target: tuple = ()
-    blk_row_weight: tuple = ()
-    blk_tile_alloc: int = dataclasses.field(
-        metadata=dict(static=True), default=0
-    )
-    # 2D edge partition with neighbor-only frontier exchange (r16,
-    # ISSUE 15): the blocked bin groups above, with the in-edges of each
-    # shard additionally grouped by the OWNER shard of their sources.
-    # Labels stay vertex-range SHARDED (no replicated V-vector, no full
-    # all_gather); per superstep each shard ships to each peer exactly
-    # the label slots that peer's bins read, as one padded
-    # ``lax.ppermute`` shift per peer offset.
-    #
-    # x2d_send_tab : int32 [D, D-1, B] — LOCAL indices of this shard's
-    #                own chunk to ship at peer offset r (axis-1 index
-    #                r-1); padding slots = 0 (shipped but never read).
-    # x2d_src_local: int32 [D, Mp] — the blocked sender-major stream
-    #                remapped onto the COMPACT label table
-    #                ``[own (Vc) | peer bufs (D-1)*B | sentinel]``;
-    #                padding messages point at the sentinel slot.
-    # x2d_boundary : B, the padded per-peer boundary width (static —
-    #                one shared SPMD width across all (shard, peer)
-    #                pairs). x2d_boundary_total: the exact UNPADDED
-    #                boundary slot count summed over every (shard, peer)
-    #                pair — the cost model's exchanged-bytes numerator.
-    # A 2D partition drops ``blk_src`` (the replicated-gather stream ids
-    # it replaces); the remaining blk_* arrays are shared verbatim, so
-    # the bin tiles — and therefore the labels — are bit-identical to
-    # the blocked family's.
-    x2d_send_tab: jax.Array | None = None
-    x2d_src_local: jax.Array | None = None
-    x2d_boundary: int = dataclasses.field(
-        metadata=dict(static=True), default=0
-    )
-    x2d_boundary_total: int = dataclasses.field(
-        metadata=dict(static=True), default=0
-    )
 
     @property
     def padded_vertices(self) -> int:
@@ -368,9 +316,6 @@ def partition_graph(
     mesh=None,
     pad_multiple: int = 8,
     build_bucket_plan: bool = False,
-    build_blocked_plan: bool = False,
-    blocked_tile_slots: int | None = None,
-    build_plan2d: bool = False,
     lpa_only: bool = False,
     timings: dict | None = None,
 ) -> ShardedGraph:
@@ -381,39 +326,22 @@ def partition_graph(
     precomputes the stacked degree-bucket plan the fast LPA shard body
     uses (host work + its own HBM, amortized once per graph like the CSR
     itself) — opt in when the partition feeds LPA; CC/PageRank/ring
-    consumers never read it. ``build_blocked_plan`` (r7, mutually
-    exclusive with ``build_bucket_plan``) precomputes the stacked
-    propagation-blocking plan instead: each shard's chunk becomes a bin
-    group of shard-local destination tiles (``ops/blocking.py``), used by
-    the blocked LPA **and** CC shard bodies; ``blocked_tile_slots``
-    overrides the per-bin tile budget (tests force multi-bin layouts).
-    ``build_plan2d`` (r16) extends the blocked bin groups with the
-    source axis: each shard's in-edges are additionally grouped by the
-    owner shard of their sources, yielding the per-peer boundary gather
-    tables of the ``sharded_2d`` family (labels sharded, neighbor-only
-    ``ppermute`` exchange instead of the full all_gather); the blocked
-    stream ids are remapped onto the compact per-shard label table and
-    ``blk_src`` is dropped. ``lpa_only`` (needs one of the three plans)
+    consumers never read it. ``lpa_only`` (needs the bucket plan)
     never materializes the sort-body arrays ``shard_graph_arrays(...,
     lpa_only=True)`` would drop anyway: at 10^9 messages they are 8 GB of
     host copies nothing reads. ``timings``, when given, receives
     ``plan_seconds``: the part of this call spent in the plan builders
     (the ``plan_build`` record's seconds; the rest is slicing).
 
-    The per-shard work (slice copies and every plan builder's per-shard
+    The per-shard work (slice copies and the plan builder's per-shard
     pass) runs in threads, one per shard or per (shard, class): NumPy
     releases the interpreter lock in its copies, sorts and gathers, so
     set-up is not D x serial.
     """
-    if build_bucket_plan and (build_blocked_plan or build_plan2d):
+    if lpa_only and not build_bucket_plan:
         raise ValueError(
-            "build_bucket_plan and build_blocked_plan/build_plan2d are "
-            "mutually exclusive — one plan family per partition"
-        )
-    if lpa_only and not (build_bucket_plan or build_blocked_plan or build_plan2d):
-        raise ValueError(
-            "lpa_only drops the sort-body arrays; pass build_bucket_plan, "
-            "build_blocked_plan or build_plan2d with it"
+            "lpa_only drops the sort-body arrays; pass build_bucket_plan "
+            "with it"
         )
     if mesh is not None and num_shards is None:
         num_shards = mesh.size
@@ -490,13 +418,6 @@ def partition_graph(
         bucket_send, bucket_target, bucket_weight = _build_shard_bucket_plan(
             deg, send_pad, counts, vc, d, w_pad
         )
-    blk = {}
-    if build_blocked_plan or build_plan2d:
-        blk = _build_shard_blocked_plan(
-            deg, send_pad, counts, vc, d, w_pad, blocked_tile_slots
-        )
-    if build_plan2d:
-        blk.update(_build_shard_plan2d(blk.pop("blk_src"), vc, d, pad_multiple))
     if timings is not None:
         timings["plan_seconds"] = time.perf_counter() - t_plan
 
@@ -514,20 +435,7 @@ def partition_graph(
         bucket_target=bucket_target,
         msg_weight=None if lpa_only else w_pad,
         bucket_weight=bucket_weight,
-        **blk,
     )
-
-
-#: What ``partition_graph`` builds for each superstep family on a mesh
-#: (`ops/blocking.select_superstep_family(..., num_devices=D)` names the
-#: family; the mesh entry of ``ops/lpa.py`` and the pipeline's replicated
-#: schedule both read the flags here).
-FAMILY_PARTITION_FLAGS = {
-    "bucketed": {"build_bucket_plan": True},
-    "blocked": {"build_blocked_plan": True},
-    "sharded_2d": {"build_plan2d": True},
-    "sort": {},
-}
 
 
 def _in_threads(fn, tasks) -> list:
@@ -638,184 +546,6 @@ def _build_shard_bucket_plan(deg, send_pad, counts, chunk_size, d, w_pad=None):
     return tuple(bucket_send), tuple(bucket_target), tuple(bucket_weight)
 
 
-def _build_shard_blocked_plan(
-    deg, send_pad, counts, chunk_size, d, w_pad=None, tile_slots=None
-):
-    """Stacked per-shard propagation-blocking plan with uniform shapes.
-
-    Each shard's vertex chunk is a bin group: the shard's LOCAL message
-    CSR is split into destination-range bins (``ops/blocking._blocked_layout``
-    — the single layout owner, so the sharded tiles are semantically
-    identical to the fused plan's), on ONE shared width ladder and ONE
-    tile width (the max across shards) so a single SPMD program serves
-    all devices. Padding messages (the CSR rows past ``counts[s]``)
-    stream the label-sentinel sender and scatter into a per-shard scratch
-    region past the bins; padding rows target ``chunk_size + j`` scratch
-    slots exactly like the bucketed plan. The per-shard layouts are built
-    one thread per shard (vectorized NumPy each).
-    """
-    import os as _os
-
-    from graphmine_tpu.ops.blocking import (
-        DEFAULT_TILE_SLOTS,
-        _bin_bounds,
-        _blocked_layout,
-    )
-
-    if tile_slots is None:
-        tile_slots = int(
-            _os.environ.get("GRAPHMINE_BLOCKED_TILE_SLOTS", DEFAULT_TILE_SLOTS)
-        )
-    sentinel_send = chunk_size * d              # the label sentinel slot
-    mp = send_pad.shape[1]
-    widths = _extend_widths(int(deg.max(initial=1)))
-
-    # Local CSR pointers + a first pass for the shared tile width.
-    ptrs, tb = [], 8
-    for s in range(d):
-        ptr_s = np.zeros(chunk_size + 1, dtype=np.int64)
-        np.cumsum(deg[s], out=ptr_s[1:])
-        ptrs.append(ptr_s)
-        bounds = _bin_bounds(ptr_s, tile_slots)
-        sizes = ptr_s[bounds[1:]] - ptr_s[bounds[:-1]]
-        tb = max(tb, -(-int(sizes.max(initial=1)) // 8) * 8)
-
-    shard_layouts = _in_threads(
-        lambda s: _blocked_layout(
-            ptrs[s], send_pad[s], tile_slots, widths=widths, tile_width=tb,
-            weights=None if w_pad is None else w_pad[s],
-        ),
-        range(d),
-    )
-    n_bins_max = max([1] + [len(layout[2]) - 1 for layout in shard_layouts])
-
-    tile_total = n_bins_max * tb
-    tile_alloc = tile_total + mp + 1
-    sentinel_slot = tile_alloc - 1
-
-    blk_src = np.full((d, mp), sentinel_send, dtype=np.int32)
-    blk_pos = np.empty((d, mp), dtype=np.int32)
-    class_rows: dict = {}
-    for s, (src_sorted, scatter_pos, _bounds, _tb, rows) in enumerate(
-        shard_layouts
-    ):
-        n = len(src_sorted)
-        blk_src[s, :n] = src_sorted
-        blk_pos[s, :n] = scatter_pos
-        # padding messages: distinct scratch slots past the bins (their
-        # streamed value is the label sentinel; unique indices hold)
-        blk_pos[s, n:] = tile_total + np.arange(n, mp, dtype=np.int64)
-        for c, payload in rows.items():
-            class_rows.setdefault(c, [None] * d)[s] = payload
-
-    blk_row_idx, blk_row_target, blk_row_weight = [], [], []
-    for c in sorted(class_rows):
-        w = int(widths[c])
-        per_shard = class_rows[c]
-        n_c = max(
-            (p[0].shape[0] for p in per_shard if p is not None), default=0
-        )
-        idx_c = np.full((d, n_c, w), sentinel_slot, dtype=np.int32)
-        tgt_c = np.empty((d, n_c), dtype=np.int32)
-        tgt_c[:] = chunk_size + np.arange(n_c, dtype=np.int64)[None, :]
-        wgt_c = (
-            None if w_pad is None else np.zeros((d, n_c, w), dtype=np.float32)
-        )
-        for s, payload in enumerate(per_shard):
-            if payload is None:
-                continue
-            vr, idx, wmat = payload
-            n = len(vr)
-            idx_c[s, :n] = np.where(idx < 0, sentinel_slot, idx)
-            tgt_c[s, :n] = vr
-            if wgt_c is not None:
-                wgt_c[s, :n] = wmat
-        blk_row_idx.append(idx_c)
-        blk_row_target.append(tgt_c)
-        if wgt_c is not None:
-            blk_row_weight.append(wgt_c)
-    return dict(
-        blk_src=blk_src,
-        blk_pos=blk_pos,
-        blk_row_idx=tuple(blk_row_idx),
-        blk_row_target=tuple(blk_row_target),
-        blk_row_weight=tuple(blk_row_weight),
-        blk_tile_alloc=tile_alloc,
-    )
-
-
-def _build_shard_plan2d(blk_src, chunk_size, d, pad_multiple=8):
-    """Source-axis extension of the blocked bin groups (r16): per-peer
-    boundary gather tables + the compact-table stream remap.
-
-    For each shard ``s`` and peer offset ``r`` (1..D-1), the boundary
-    set ``need(s, r)`` is the sorted unique LOCAL indices (within the
-    owner's chunk) of the senders shard ``s``'s bins read from owner
-    ``(s - r) % D`` — exactly the label slots that must cross the ICI
-    for that (shard, peer) pair, however small the live frontier keeps
-    them. All sets pad to one shared width ``B`` (SPMD needs one
-    program), and ``send_tab[s, r-1]`` holds what shard ``s`` SHIPS at
-    shift ``r``: ``need((s + r) % D, r)`` — the ppermute at shift ``r``
-    delivers it to precisely the peer that reads it. The blocked
-    sender-major stream (global ids in ``blk_src``) is remapped onto the
-    compact per-shard table ``[own (Vc) | bufs (D-1)*B | sentinel]`` so
-    the bin phase never touches a replicated label vector; padding
-    messages point at the sentinel slot (the blocked plan's padding
-    contract, relocated)."""
-    mp = blk_src.shape[1]
-    # One sorted-unique pass per shard, not one masked unique per
-    # (shard, peer) pair: uniq is ascending, so owner ranges are
-    # contiguous slices found by searchsorted on the chunk boundaries —
-    # O(M log M) total host work (the same order as the blocked plan
-    # build this rides on), independent of D.
-    def boundary_sets(s):
-        uniq = np.unique(blk_src[s].astype(np.int64))     # incl. sentinel
-        bound = np.searchsorted(uniq, np.arange(d + 1) * chunk_size)
-        need_s = []
-        for r in range(1, d):
-            peer = (s - r) % d
-            ids = uniq[bound[peer]: bound[peer + 1]]
-            need_s.append(ids - peer * chunk_size)
-        return uniq, bound, need_s
-
-    per = _in_threads(boundary_sets, range(d))
-    uniqs = [p[0] for p in per]
-    bounds = [p[1] for p in per]
-    need = [p[2] for p in per]
-    b = max(
-        (len(ids) for row in need for ids in row), default=1
-    )
-    b = max(-(-max(b, 1) // pad_multiple) * pad_multiple, pad_multiple)
-    send_tab = np.zeros((d, max(d - 1, 0), b), dtype=np.int32)
-    for s in range(d):
-        for r in range(1, d):
-            ids = need[(s + r) % d][r - 1]
-            send_tab[s, r - 1, : len(ids)] = ids
-    sentinel_slot = chunk_size + (d - 1) * b
-    src_local = np.full((d, mp), sentinel_slot, dtype=np.int32)
-
-    def remap(s):
-        g = blk_src[s].astype(np.int64)
-        owner = g // chunk_size                           # pad -> d
-        # one global position pass: index within need[s][r-1] is the
-        # position in uniq minus the owner range's start
-        pos = np.searchsorted(uniqs[s], g)
-        in_need = pos - bounds[s][np.minimum(owner, d - 1)]
-        r_of = (s - owner) % d
-        out = chunk_size + (r_of - 1) * b + in_need
-        out = np.where(owner == s, g - s * chunk_size, out)
-        src_local[s] = np.where(owner >= d, sentinel_slot, out)
-
-    _in_threads(remap, range(d))
-    total = sum(len(ids) for row in need for ids in row)
-    return dict(
-        x2d_send_tab=send_tab,
-        x2d_src_local=src_local,
-        x2d_boundary=int(b),
-        x2d_boundary_total=int(total),
-    )
-
-
 def shard_graph_arrays(sg: ShardedGraph, mesh, lpa_only: bool = False) -> ShardedGraph:
     """Place the per-shard arrays on the mesh (leading dim over the vertex axis).
 
@@ -829,14 +559,9 @@ def shard_graph_arrays(sg: ShardedGraph, mesh, lpa_only: bool = False) -> Sharde
     axes = _vertex_axes(mesh)
     spec = NamedSharding(mesh, P(axes, None))
     spec3 = NamedSharding(mesh, P(axes, None, None))
-    if (
-        lpa_only and not sg.bucket_send and sg.blk_src is None
-        and sg.x2d_src_local is None
-    ):
+    if lpa_only and not sg.bucket_send:
         raise ValueError(
-            "lpa_only requires partition_graph(build_bucket_plan=True), "
-            "partition_graph(build_blocked_plan=True) or "
-            "partition_graph(build_plan2d=True)"
+            "lpa_only requires partition_graph(build_bucket_plan=True)"
         )
     place = (lambda a, s: None) if lpa_only else jax.device_put
     return ShardedGraph(
@@ -852,22 +577,6 @@ def shard_graph_arrays(sg: ShardedGraph, mesh, lpa_only: bool = False) -> Sharde
         # bucket_weight) — drop it under lpa_only like the rest.
         msg_weight=None if sg.msg_weight is None else place(sg.msg_weight, spec),
         bucket_weight=tuple(jax.device_put(b, spec3) for b in sg.bucket_weight),
-        blk_src=None if sg.blk_src is None else jax.device_put(sg.blk_src, spec),
-        blk_pos=None if sg.blk_pos is None else jax.device_put(sg.blk_pos, spec),
-        blk_row_idx=tuple(jax.device_put(b, spec3) for b in sg.blk_row_idx),
-        blk_row_target=tuple(jax.device_put(t, spec) for t in sg.blk_row_target),
-        blk_row_weight=tuple(jax.device_put(b, spec3) for b in sg.blk_row_weight),
-        blk_tile_alloc=sg.blk_tile_alloc,
-        x2d_send_tab=(
-            None if sg.x2d_send_tab is None
-            else jax.device_put(sg.x2d_send_tab, spec3)
-        ),
-        x2d_src_local=(
-            None if sg.x2d_src_local is None
-            else jax.device_put(sg.x2d_src_local, spec)
-        ),
-        x2d_boundary=sg.x2d_boundary,
-        x2d_boundary_total=sg.x2d_boundary_total,
     )
 
 
@@ -968,172 +677,6 @@ def _shard_row_modes(table, own, row_idx, row_target, row_weight):
         with jax.named_scope("write_back"):
             own = own.at[tgt[0]].set(vals, unique_indices=True)
     return own
-
-
-def _blocked_shard_tile(labels_full, blk_src, blk_pos, tile_alloc, fill):
-    """Per-device bin phase (ops/blocking.py §2, shard-local): stream the
-    padded label vector in sender-major order (monotone gather) and
-    scatter each message into its slot of this shard's destination-binned
-    tile. Padding messages carry the sentinel value into scratch slots
-    past the bins; unwritten slots keep ``fill``."""
-    with jax.named_scope("bin_gather"):
-        lbl_pad = jnp.concatenate(
-            [labels_full, jnp.full((1,), fill, jnp.int32)]
-        )
-        vals = lbl_pad[blk_src[0]]
-    with jax.named_scope("bin_scatter"):
-        tile = jnp.full((tile_alloc,), fill, jnp.int32)
-        return tile.at[blk_pos[0]].set(vals, unique_indices=True)
-
-
-def _lpa_shard_body_blocked(
-    labels_full, blk_src, blk_pos, row_idx, row_target, row_weight=None, *,
-    chunk_size, tile_alloc, axes
-):
-    """Blocked LPA shard body: bin phase into the shard-local tile, then
-    the bucketed-mode row reduce with TILE-local indices (bounded by the
-    tile, not V). Same comms as the other LPA bodies — one tiled
-    all_gather. Padding rows scatter to the ``chunk_size + j`` scratch
-    extension (sliced away), exactly like the bucketed body; see the OOB
-    warning there for why the scratch exists."""
-    with jax.named_scope("lpa_sharded"):
-        tile = _blocked_shard_tile(
-            labels_full, blk_src, blk_pos, tile_alloc, _SENTINEL
-        )
-        start = lax.axis_index(axes).astype(jnp.int32) * chunk_size
-        own = lax.dynamic_slice(labels_full, (start,), (chunk_size,))
-        own = _shard_row_modes(tile, own, row_idx, row_target, row_weight)
-        with jax.named_scope("exchange"):
-            return lax.all_gather(
-                own[:chunk_size].astype(jnp.int32), axes, tiled=True
-            )
-
-
-def _cc_shard_body_blocked(
-    labels_full, blk_src, blk_pos, row_idx, row_target, *,
-    chunk_size, tile_alloc, axes
-):
-    """Blocked CC shard body: the min-reduce twin of
-    :func:`_lpa_shard_body_blocked` — shard-local bin tile, per-row min
-    (the int32-max sentinel never wins), pointer jump on the gathered
-    full vector (no extra comms), matching :func:`_cc_shard_body`
-    step-for-step."""
-    tile = _blocked_shard_tile(labels_full, blk_src, blk_pos, tile_alloc, _SENTINEL)
-    start = lax.axis_index(axes).astype(jnp.int32) * chunk_size
-    own = lax.dynamic_slice(labels_full, (start,), (chunk_size,))
-    n_max = max((t.shape[-1] for t in row_target), default=0)
-    own = jnp.concatenate([own, jnp.zeros((n_max,), own.dtype)])
-    for ridx, tgt in zip(row_idx, row_target):
-        row_min = jnp.min(tile[ridx[0]], axis=1)
-        own = own.at[tgt[0]].min(row_min, unique_indices=True)
-    full = lax.all_gather(
-        own[:chunk_size].astype(jnp.int32), axes, tiled=True
-    )
-    return jnp.minimum(full, full[full])
-
-
-def _check_2d_mesh(mesh) -> None:
-    """The 2D family's neighbor exchange is a ring of ``ppermute`` shifts
-    over ONE mesh axis (the parallel/ring.py schedule's topology) —
-    reject multi-axis meshes with a real error instead of a cryptic
-    trace-time axis failure; the replicated schedules handle 2-D
-    ``("dcn", "ici")`` meshes."""
-    if len(tuple(mesh.axis_names)) != 1:
-        raise ValueError(
-            f"the sharded_2d family needs a 1-D mesh for its ppermute "
-            f"neighbor exchange (got axes {tuple(mesh.axis_names)}); use "
-            "the one-all_gather families on multi-slice meshes"
-        )
-
-
-def _exchange_2d(own, send_tab, *, axes, num_shards):
-    """Neighbor-only frontier exchange (r16): one ``lax.ppermute`` shift
-    per peer offset r, each carrying ONE padded boundary buffer — the
-    label slots the receiving peer's bins actually read
-    (``send_tab[r-1]``, host-computed by :func:`_build_shard_plan2d`) —
-    instead of one tiled all_gather of the full label chunk. Exchanged
-    bytes per chip drop from ``4·Vc·(D-1)`` to ``4·Σ_peer |boundary|``
-    (padded to B). Returns the D-1 received buffers in peer-offset
-    order, matching the compact-table layout the stream remap indexes."""
-    bufs = []
-    with jax.named_scope("exchange"):
-        for r in range(1, num_shards):
-            perm = [(i, (i + r) % num_shards) for i in range(num_shards)]
-            bufs.append(lax.ppermute(own[send_tab[r - 1]], axes, perm))
-    return bufs
-
-
-def _table_2d(own, bufs, fill):
-    """The compact per-shard label table ``[own | peer bufs | sentinel]``
-    the 2D stream remap (``x2d_src_local``) gathers from — the
-    neighbor-exchange replacement for the replicated padded label
-    vector."""
-    return jnp.concatenate(
-        [own, *bufs, jnp.full((1,), fill, own.dtype)]
-    )
-
-
-def _lpa_shard_body_2d(
-    own, src_local, blk_pos, send_tab, row_idx, row_target, row_weight=None,
-    *, chunk_size, tile_alloc, axes, num_shards
-):
-    """2D LPA shard body: neighbor-only exchange into the compact label
-    table, then the blocked bin phase + bucketed row reduce with
-    tile-local indices. The tile contents are value-for-value identical
-    to :func:`_lpa_shard_body_blocked`'s (the stream remap points each
-    message at the same sender's label; padding at the same sentinel),
-    so the labels are bit-identical to the blocked family — and hence to
-    the sort oracle (the r8 order-independence contract). Labels stay
-    SHARDED: input and output are the shard's own ``[Vc]`` chunk; no
-    replicated V-vector exists anywhere in the superstep."""
-    with jax.named_scope("lpa_sharded"):
-        bufs = _exchange_2d(own, send_tab[0], axes=axes, num_shards=num_shards)
-        with jax.named_scope("bin_gather"):
-            vals = _table_2d(own, bufs, _SENTINEL)[src_local[0]]
-        with jax.named_scope("bin_scatter"):
-            tile = jnp.full((tile_alloc,), _SENTINEL, jnp.int32)
-            tile = tile.at[blk_pos[0]].set(vals, unique_indices=True)
-        out = _shard_row_modes(tile, own, row_idx, row_target, row_weight)
-        return out[:chunk_size].astype(jnp.int32)
-
-
-def _cc_shard_body_2d(
-    own, src_local, blk_pos, send_tab, row_idx, row_target, *,
-    chunk_size, tile_alloc, axes, num_shards
-):
-    """2D CC shard body: the min-reduce twin of
-    :func:`_lpa_shard_body_2d`, plus a CHUNK-LOCAL pointer jump. The
-    full-vector jump (``full[full]``) of the one-all_gather bodies needs
-    random access to arbitrary global label slots — exactly the O(V)
-    exchange this family removes — so compression only follows labels
-    that land in the shard's own range (sound: any label is a same-
-    component vertex id, so ``min(own, labels[label])`` over local
-    labels is monotone and component-preserving). Convergence trades
-    O(log V) supersteps for O(D + log Vc)-ish on range-clustered
-    components — up to O(diameter) when a chain's labels alternate
-    shards and the local jump never fires (the serve repair path grants
-    its 2D CC runs a D-scaled budget for exactly this) — and the
-    FIXPOINT — labels = component-min — is unchanged, so final labels
-    stay bit-identical to the oracle and a fixpoint stays a fixpoint
-    under one more superstep (the serve-path sampled-exact-check
-    predicate)."""
-    bufs = _exchange_2d(own, send_tab[0], axes=axes, num_shards=num_shards)
-    table = _table_2d(own, bufs, _SENTINEL)
-    vals = table[src_local[0]]
-    tile = jnp.full((tile_alloc,), _SENTINEL, jnp.int32).at[blk_pos[0]].set(
-        vals, unique_indices=True
-    )
-    n_max = max((t.shape[-1] for t in row_target), default=0)
-    out = jnp.concatenate([own, jnp.zeros((n_max,), own.dtype)])
-    for ridx, tgt in zip(row_idx, row_target):
-        row_min = jnp.min(tile[ridx[0]], axis=1)
-        out = out.at[tgt[0]].min(row_min, unique_indices=True)
-    new = out[:chunk_size].astype(jnp.int32)
-    start = lax.axis_index(axes).astype(jnp.int32) * chunk_size
-    loc = new - start
-    in_chunk = (loc >= 0) & (loc < chunk_size)
-    jumped = new[jnp.clip(loc, 0, chunk_size - 1)]
-    return jnp.minimum(new, jnp.where(in_chunk, jumped, new))
 
 
 def _cc_shard_body(labels_full, recv_local, send, deg, *, chunk_size, axes):
@@ -1316,62 +859,6 @@ def _build_lpa_step(sg: ShardedGraph, mesh):
     repair entry (:func:`_sharded_lpa_fixpoint_jit`). Traced under jit."""
     axes = _vertex_axes(mesh)
     rep = P()
-    if sg.x2d_src_local is not None:
-        # 2D edge partition (r16): labels sharded, neighbor-only
-        # ppermute exchange (partition_graph(build_plan2d=True)). The
-        # step's carry is the SHARDED [D*Vc] label vector — the loop
-        # drivers and tripwires operate on the logical array unchanged.
-        _check_2d_mesh(mesh)
-        n = len(sg.blk_row_idx)
-        nw = len(sg.blk_row_weight)
-        body = shard_map(
-            partial(
-                _lpa_shard_body_2d, chunk_size=sg.chunk_size,
-                tile_alloc=sg.blk_tile_alloc, axes=axes,
-                num_shards=sg.num_shards,
-            ),
-            mesh=mesh,
-            in_specs=(
-                P(axes),
-                P(axes, None),
-                P(axes, None),
-                P(axes, None, None),
-                (P(axes, None, None),) * n,
-                (P(axes, None),) * n,
-                (P(axes, None, None),) * nw,
-            ),
-            out_specs=P(axes),
-        )
-        return lambda l: body(
-            l, sg.x2d_src_local, sg.blk_pos, sg.x2d_send_tab,
-            sg.blk_row_idx, sg.blk_row_target, sg.blk_row_weight,
-        )
-    if sg.blk_src is not None:
-        # Propagation-blocking path (r7): shard-local bin tiles, same
-        # one-all_gather exchange (partition_graph(build_blocked_plan=True)).
-        n = len(sg.blk_row_idx)
-        nw = len(sg.blk_row_weight)
-        body = shard_map(
-            partial(
-                _lpa_shard_body_blocked, chunk_size=sg.chunk_size,
-                tile_alloc=sg.blk_tile_alloc, axes=axes,
-            ),
-            mesh=mesh,
-            in_specs=(
-                rep,
-                P(axes, None),
-                P(axes, None),
-                (P(axes, None, None),) * n,
-                (P(axes, None),) * n,
-                (P(axes, None, None),) * nw,
-            ),
-            out_specs=rep,
-            check_vma=False,
-        )
-        return lambda l: body(
-            l, sg.blk_src, sg.blk_pos, sg.blk_row_idx, sg.blk_row_target,
-            sg.blk_row_weight,
-        )
     if sg.bucket_send:
         # Fast path: stacked degree-bucket plan (built by partition_graph);
         # weighted graphs carry slot-aligned bucket_weight matrices (r2).
@@ -1508,60 +995,14 @@ def _sharded_cc_jit(
     _check_mesh(sg, mesh)
     in_specs, rep = _shard_specs(mesh)
     axes = _vertex_axes(mesh)
-    if sg.x2d_src_local is not None:
-        # 2D neighbor-exchange CC (r16): sharded labels, chunk-local
-        # pointer jumping — see _cc_shard_body_2d for the convergence
-        # trade; the fixpoint (and thus every published label) is
-        # bit-identical to the one-all_gather families'.
-        _check_2d_mesh(mesh)
-        n = len(sg.blk_row_idx)
-        body = shard_map(
-            partial(
-                _cc_shard_body_2d, chunk_size=sg.chunk_size,
-                tile_alloc=sg.blk_tile_alloc, axes=axes,
-                num_shards=sg.num_shards,
-            ),
-            mesh=mesh,
-            in_specs=(
-                P(axes), P(axes, None), P(axes, None),
-                P(axes, None, None),
-                (P(axes, None, None),) * n, (P(axes, None),) * n,
-            ),
-            out_specs=P(axes),
-        )
-        step = lambda l: body(
-            l, sg.x2d_src_local, sg.blk_pos, sg.x2d_send_tab,
-            sg.blk_row_idx, sg.blk_row_target,
-        )
-    elif sg.blk_src is not None:
-        # Blocked CC shard body (r7): shard-local bin tiles, same
-        # fixpoint driver, bit-identical labels (virtual-mesh parity).
-        n = len(sg.blk_row_idx)
-        body = shard_map(
-            partial(
-                _cc_shard_body_blocked, chunk_size=sg.chunk_size,
-                tile_alloc=sg.blk_tile_alloc, axes=axes,
-            ),
-            mesh=mesh,
-            in_specs=(
-                rep, P(axes, None), P(axes, None),
-                (P(axes, None, None),) * n, (P(axes, None),) * n,
-            ),
-            out_specs=rep,
-            check_vma=False,
-        )
-        step = lambda l: body(
-            l, sg.blk_src, sg.blk_pos, sg.blk_row_idx, sg.blk_row_target
-        )
-    else:
-        body = shard_map(
-            partial(_cc_shard_body, chunk_size=sg.chunk_size, axes=axes),
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=rep,
-            check_vma=False,
-        )
-        step = lambda l: body(l, sg.msg_recv_local, sg.msg_send, sg.degrees)
+    body = shard_map(
+        partial(_cc_shard_body, chunk_size=sg.chunk_size, axes=axes),
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=rep,
+        check_vma=False,
+    )
+    step = lambda l: body(l, sg.msg_recv_local, sg.msg_send, sg.degrees)
     return _fixpoint_supersteps(
         step, sg,
         max_iter, tripwire_every=tripwire_every, init_labels=init_labels,

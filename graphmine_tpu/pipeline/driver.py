@@ -299,38 +299,19 @@ def _run_pipeline(config: PipelineConfig, m: MetricsSink) -> PipelineResult:
     # when that path will run — it is pure HBM/host waste for louvain,
     # graphframes, and sharded runs.
     wants_plan = run_plan is not None and run_plan.schedule == "single"
-    # Which plan FAMILY that single-device path runs (r7): the planner
-    # resolves it at plan time through the single crossover-policy owner
-    # (ops/blocking.select_superstep_family: bucketed or sort, blocked
-    # only when forced) with its degradation rung — same provenance
-    # treatment as the r6 IVF flip. "sort" at tiny scale still builds the
-    # bucketed plan here (the shared-CSR-pass build is the historical
-    # single-path behavior; the plan is cheap exactly where "sort" wins).
+    # Which plan FAMILY that single-device path runs: bucketed at every
+    # size, degrading to sort. The policy's crossover is the plan-build
+    # cost, and here the plan is built in the SAME message-CSR pass as the
+    # Graph, so it is cheap exactly where `auto` would say "sort".
     sstep_plan = None
     if wants_plan:
-        import dataclasses as _dc
+        from graphmine_tpu.pipeline.planner import SuperstepPlan
 
-        from graphmine_tpu.pipeline.planner import plan_superstep
-
-        sstep_plan = plan_superstep(
-            table.num_vertices, 2 * table.num_edges,
-            weighted=table.weights is not None,
+        sstep_plan = SuperstepPlan(
+            family="bucketed",
+            reason="single-device pipeline path: the plan is built in the "
+            "graph's own message-CSR pass, so bucketed runs at every size",
         )
-        if sstep_plan.family == "sort" and not os.environ.get(
-            "GRAPHMINE_SUPERSTEP_FAMILY"
-        ):
-            # AUTO resolved "sort" on size alone — but the single-device
-            # path has always built the fused plan in the SAME
-            # message-CSR pass as the Graph, so the crossover's
-            # plan-build-cost rationale doesn't apply here: keep the
-            # bucketed kernel and say so, rather than record a family
-            # the driver doesn't run. An EXPLICIT env force of "sort"
-            # is honored as-is (the sort superstep really runs).
-            sstep_plan = _dc.replace(
-                sstep_plan, family="bucketed", degrade_to="sort",
-                reason=sstep_plan.reason + " — driver single path: plan "
-                "build shares the graph's CSR pass, bucketed kernel kept",
-            )
         # Plan-time memory pre-degrade (ISSUE 14): a family whose MODELED
         # footprint already exceeds the planning budget cannot survive
         # the build — consume its rung NOW, with the oversized inventory
@@ -339,7 +320,6 @@ def _run_pipeline(config: PipelineConfig, m: MetricsSink) -> PipelineResult:
         # sized the run wants the OOM, not a silently leaner family).
         if config.resilience.degradation == "auto":
             from graphmine_tpu.obs.memmodel import predegrade_superstep
-            from graphmine_tpu.pipeline.planner import _SUPERSTEP_DEGRADE
 
             fam, _fit, steps = predegrade_superstep(
                 sstep_plan.family, table.num_vertices, 2 * table.num_edges,
@@ -358,15 +338,14 @@ def _run_pipeline(config: PipelineConfig, m: MetricsSink) -> PipelineResult:
                     mem=oversized.record(),
                 )
             if steps:
-                sstep_plan = _dc.replace(
-                    sstep_plan, family=fam,
-                    degrade_to=_SUPERSTEP_DEGRADE[fam],
+                sstep_plan = SuperstepPlan(
+                    family=fam,
                     reason=sstep_plan.reason
                     + f" — pre-degraded to {fam!r}: modeled footprint of "
                     f"{steps[0][0]!r} exceeds the memory budget",
                 )
         from graphmine_tpu.obs.costmodel import superstep_cost
-        from graphmine_tpu.ops.blocking import crossover_thresholds
+        from graphmine_tpu.ops.superstep_policy import crossover_thresholds
 
         m.emit(
             "impl_selected", op="lpa_superstep", impl=sstep_plan.family,
@@ -398,18 +377,11 @@ def _run_pipeline(config: PipelineConfig, m: MetricsSink) -> PipelineResult:
     def _build():
         resilience.fault_point("build_graph")
         if wants_plan and sstep_plan.family != "sort":
-            from graphmine_tpu.ops.blocking import (
-                build_graph_and_blocked_plan,
-                plan_build_stats,
-            )
             from graphmine_tpu.ops.bucketed_mode import build_graph_and_plan
+            from graphmine_tpu.ops.superstep_policy import plan_build_stats
 
-            builder = (
-                build_graph_and_blocked_plan
-                if sstep_plan.family == "blocked" else build_graph_and_plan
-            )
             t0 = time.perf_counter()
-            g, plan = builder(
+            g, plan = build_graph_and_plan(
                 table.src, table.dst, num_vertices=table.num_vertices,
                 edge_weights=table.weights,
             )
@@ -939,7 +911,6 @@ def _run_lpa(
     )
     from graphmine_tpu.parallel.mesh import make_mesh
     from graphmine_tpu.parallel.sharded import (
-        FAMILY_PARTITION_FLAGS,
         partition_graph,
         shard_graph_arrays,
         sharded_label_propagation,
@@ -1104,7 +1075,7 @@ def _run_lpa(
                 sg = shard_graph_arrays(
                     partition_graph(
                         graph, mesh=mesh, lpa_only=lpa_only,
-                        **FAMILY_PARTITION_FLAGS[run_plan.family or "bucketed"],
+                        build_bucket_plan=run_plan.family != "sort",
                     ),
                     mesh,
                     lpa_only=lpa_only,
@@ -1139,45 +1110,10 @@ def _run_lpa(
             )
             step = jax.jit(lpa_superstep)
             return lambda lbl: step(lbl, graph)
-        if variant == "single_bucketed":
-            # Blocked→bucketed degradation rung (r7): the blocked plan's
-            # tile + stream arrays were released on entry (plan_holder
-            # cleared below); rebuild the degree-bucketed fused plan —
-            # identical labels, less HBM than tile + rows — and record
-            # its host cost like every other plan build.
-            from graphmine_tpu.ops.blocking import plan_build_stats
-            from graphmine_tpu.ops.bucketed_mode import lpa_superstep_bucketed
-            from graphmine_tpu.ops.lpa import _cached_auto_plan
-
-            plan, secs, cached = _cached_auto_plan(graph, "bucketed")
-            current["cost"] = superstep_cost(
-                "lpa_superstep", "bucketed", graph.num_vertices,
-                graph.num_messages, graph.num_edges, plan=plan,
-            )
-            current["mem"] = superstep_footprint(
-                "lpa_superstep", "bucketed", graph.num_vertices,
-                graph.num_messages, num_edges=graph.num_edges, plan=plan,
-            )
-            m.emit(
-                "plan_build", op="lpa_superstep", seconds=round(secs, 6),
-                cached=cached, cost=current["cost"].record(),
-                **plan_build_stats(plan, graph.num_edges),
-            )
-            current["chunk_size"] = graph.num_vertices
-            step = jax.jit(lpa_superstep_bucketed)
-            return lambda lbl: step(lbl, graph, plan)
-        # "single": the planner-resolved fused plan family — the
-        # degree-bucketed kernel (ops/bucketed_mode.py, ~3x the sort
-        # superstep) or the propagation-blocking bin-then-reduce engine
-        # (ops/blocking.py; only when forced, auto never resolves it
-        # on one device); identical labels
-        # either way. The plan was built alongside the Graph from one
-        # shared message-CSR pass (wants_plan in run_pipeline is true
-        # exactly for this branch).
-        from graphmine_tpu.ops.blocking import (
-            BlockedPlan,
-            lpa_superstep_blocked,
-        )
+        # "single": the degree-bucketed kernel (ops/bucketed_mode.py,
+        # ~3x the sort superstep). The plan was built alongside the Graph
+        # from one shared message-CSR pass (wants_plan in run_pipeline is
+        # true exactly for this branch).
         from graphmine_tpu.ops.bucketed_mode import lpa_superstep_bucketed
 
         if plan_holder[0] is None:
@@ -1193,10 +1129,7 @@ def _run_lpa(
             "lpa_superstep", "auto", graph.num_vertices,
             graph.num_messages, num_edges=graph.num_edges, plan=plan,
         )
-        step = jax.jit(
-            lpa_superstep_blocked if isinstance(plan, BlockedPlan)
-            else lpa_superstep_bucketed
-        )
+        step = jax.jit(lpa_superstep_bucketed)
         return lambda lbl: step(lbl, graph, plan)
 
     def save_ck(iteration: int) -> None:
@@ -1498,8 +1431,8 @@ def _run_lpa(
             device_rungs.append(
                 ("single_sort@1dev", make_runner("single_sort", 1))
             )
-    # An explicitly forced "sort" family (env) runs the sort superstep
-    # as its primary — no plan was built, and "single" would demand one.
+    # A run pre-degraded to the "sort" family runs the sort superstep as
+    # its primary — no plan was built, and "single" would demand one.
     primary = (
         "single_sort"
         if (

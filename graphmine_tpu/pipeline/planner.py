@@ -103,7 +103,7 @@ class RunPlan:
     reason: str              # one-line human-readable selection rationale
     estimates: dict = field(default_factory=dict)  # schedule -> bytes/device
     # The superstep family a replicated mesh run partitions for, from the
-    # one policy owner (ops/blocking.select_superstep_family with
+    # one policy owner (ops/superstep_policy.select_superstep_family with
     # num_devices=D); None where the schedule leaves no choice (single: the
     # driver's plan_superstep call decides; ring: the sort CSR).
     family: str | None = None
@@ -163,14 +163,11 @@ def degradation_ladder(
     Each rung trades speed for strictly less per-device memory, per the
     model above:
 
-    - ``single`` with the ``blocked`` plan family (r7) →
-      ``single_bucketed`` → ``single_sort``: first drop the blocked
-      plan's tile + stream arrays and rebuild the degree-bucketed fused
-      plan (the r5/r6-measured path, less HBM than tile + rows), then
-      drop plans entirely for the sort superstep.
     - ``single`` → ``single_sort``: drop the fused kernel's padded bucket
       matrices and per-bucket gather transients (~5E of the 36 B/edge);
-      the plain sort-based superstep runs over the bare message CSR.
+      the plain sort-based superstep runs over the bare message CSR
+      (the family order is :data:`memmodel.FAMILY_DEGRADE`'s; a run
+      already on ``sort`` has no rung left).
     - ``replicated`` → ``ring``: drop the replicated V-length label
       vector (the 16 B/vertex term) — labels stay sharded, chunks rotate
       over ICI.
@@ -181,11 +178,11 @@ def degradation_ladder(
     last good label state, recording a ``degrade`` metrics event.
     """
     if schedule == "single" or num_devices <= 1:
-        if family == "blocked":
-            return ["single_bucketed", "single_sort"]
-        if family == "sort":
-            return []  # already the memory floor; the failure surfaces
-        return ["single_sort"]
+        rungs = []
+        while memmodel.FAMILY_DEGRADE[family] is not None:
+            family = memmodel.FAMILY_DEGRADE[family]
+            rungs.append(f"single_{family}")
+        return rungs
     if schedule == "replicated":
         return ["ring"]
     return []
@@ -219,52 +216,39 @@ def elastic_device_ladder(schedule: str, num_devices: int) -> list[int]:
 class SuperstepPlan:
     """Resolved superstep plan family for one graph (r7).
 
-    ``family`` is the selected layout (``"sharded_2d"`` / ``"blocked"``
-    / ``"bucketed"`` / ``"sort"``); ``degrade_to`` is the family a
-    resource failure steps down to — sharded_2d degrades to blocked
-    (drop the per-peer boundary tables, fall back to the one-all_gather
-    exchange), blocked to bucketed (drop the tile + stream arrays, keep
-    dense rows), bucketed to sort (drop all padded plan matrices), sort
-    has nowhere leaner to go."""
+    ``family`` is the selected layout (``"bucketed"`` / ``"sort"``);
+    ``degrade_to`` is the family a resource failure steps down to, read
+    off the one order (:data:`memmodel.FAMILY_DEGRADE`: bucketed drops
+    its padded plan matrices for sort; sort has nowhere leaner to go and
+    names itself)."""
 
-    family: str        # "sharded_2d" | "blocked" | "bucketed" | "sort"
-    degrade_to: str    # next rung's family
+    family: str        # "bucketed" | "sort"
     reason: str        # one-line selection rationale (measured provenance)
 
-
-_SUPERSTEP_DEGRADE = {
-    "sharded_2d": "blocked", "blocked": "bucketed", "bucketed": "sort",
-    "sort": "sort",
-}
+    @property
+    def degrade_to(self) -> str:
+        return memmodel.FAMILY_DEGRADE[self.family] or self.family
 
 
 def plan_superstep(
     num_vertices: int, num_messages: int, requested: str = "auto",
-    weighted: bool = False, num_devices: int = 1,
+    num_devices: int = 1,
 ) -> SuperstepPlan:
     """Resolve the LPA/CC superstep plan family at plan time.
 
     Thin planner wrapper over
-    :func:`graphmine_tpu.ops.blocking.select_superstep_family` (the
-    single crossover-policy owner, with the measured-provenance table)
-    so the driver's single-device dispatch AND its blocked→bucketed
-    degradation rung come from one plan-time decision — the same
-    treatment :func:`plan_lof` gives the IVF flip. ``num_devices`` (r16)
-    gates the ``sharded_2d`` family: >= 2-device callers (the serve
-    sharded repair path, the exchange bench tier) resolve the
-    neighbor-exchange family here, with its degradation rung back to the
-    one-all_gather ``blocked`` family. NOTE: imports the ops layer
-    (hence jax) lazily, like ``plan_lof``.
+    :func:`graphmine_tpu.ops.superstep_policy.select_superstep_family`
+    (the single crossover-policy owner): the family with its degradation
+    rung, as :func:`plan_lof` pairs the LOF impl with its own. NOTE:
+    imports the ops layer (hence jax) lazily, like ``plan_lof``.
     """
-    from graphmine_tpu.ops.blocking import select_superstep_family
+    from graphmine_tpu.ops.superstep_policy import select_superstep_family
 
     family, reason = select_superstep_family(
-        num_vertices, num_messages, requested=requested, weighted=weighted,
+        num_vertices, num_messages, requested=requested,
         num_devices=num_devices,
     )
-    return SuperstepPlan(
-        family=family, degrade_to=_SUPERSTEP_DEGRADE[family], reason=reason
-    )
+    return SuperstepPlan(family=family, reason=reason)
 
 
 @dataclass(frozen=True)
@@ -355,11 +339,10 @@ def plan_run(
         # (hence jax) lazily and only for a distributed schedule
         if sched != "replicated":
             return None
-        from graphmine_tpu.ops.blocking import select_superstep_family
+        from graphmine_tpu.ops.superstep_policy import select_superstep_family
 
         return select_superstep_family(
-            num_vertices, 2 * num_edges, weighted=weighted,
-            num_devices=num_devices,
+            num_vertices, 2 * num_edges, num_devices=num_devices
         )[0]
 
     def _idx_ok(s):

@@ -951,10 +951,6 @@ class DeltaIngestor:
         # padded shard shapes of the last sharded apply (jit-cache
         # eviction key; see _clear_sharded_jit_caches)
         self._shard_jit_key = None
-        # superstep family of the last sharded repair ("sharded_2d" past
-        # the r16 crossover, else "sort"; None before any sharded apply
-        # / on single-shard ingestors)
-        self.last_shard_family = None
         # LOF-staleness backlog (admission rung 2, serve/admission.py):
         # vertices whose scores a deferred apply skipped. The next
         # lof_mode="refresh" apply re-scores the union. A snapshot loaded
@@ -1005,69 +1001,13 @@ class DeltaIngestor:
             )
         return self._repair_sharded(graph, delta, seed)
 
-    def _resolve_shard_family(self, graph) -> str:
-        """Plan-time superstep-family resolution for the sharded repair
-        path (r16): the planner's single crossover owner picks between
-        the 2D neighbor-exchange partition and the one-all_gather sort
-        bodies, then the memory plane pre-degrades a 2D pick whose
-        per-peer boundary tables (modeled at worst case — the pre-build
-        view cannot know the real boundary) would not fit the HBM
-        budget, with the oversized inventory in the degrade record (the
-        r15 contract). Returns ``"sharded_2d"`` or ``"sort"`` — any
-        degraded rung routes to the plain partition these repairs always
-        ran. NOTE the degrade is a return to the pre-r16 status quo, not
-        a claim of a leaner footprint: the replicated-label sort path
-        can model MORE per-chip bytes than the 2D family it declined
-        (2D label terms are sharded) — what the pre-degrade protects is
-        the NEW, worst-case-modeled per-peer boundary tables, whose
-        real width is unknown until the partition is built."""
-        from graphmine_tpu.obs.memmodel import predegrade_superstep
-        from graphmine_tpu.pipeline import planner
-
-        plan_family = planner.plan_superstep(
-            graph.num_vertices, graph.num_messages,
-            weighted=self.weights is not None,
-            num_devices=self.num_shards,
-        ).family
-        if plan_family != "sharded_2d":
-            return "sort"
-        budget = int(
-            planner.hbm_bytes_per_device() * planner._HBM_HEADROOM
-        )
-        fam, _fit, steps = predegrade_superstep(
-            "sharded_2d", graph.num_vertices, graph.num_messages,
-            graph.num_edges, self.weights is not None, budget,
-            num_devices=self.num_shards,
-        )
-        if not steps:
-            return "sharded_2d"
-        if self.sink is not None:
-            frm, _to, oversized = steps[0]
-            self.sink.emit(
-                "degrade", stage="delta_repair_plan", to="sort", depth=1,
-                kind="mem_plan",
-                error=(
-                    f"plan-time memory pre-degrade: modeled {frm!r} "
-                    f"footprint {oversized.total_bytes:,} B (per-peer "
-                    f"exchange tables included) exceeds the {budget:,} B "
-                    "budget — repairing via the one-all_gather partition"
-                ),
-                mem=oversized.record(),
-            )
-        return "sort"
-
     def _repair_sharded(
         self, graph, delta: EdgeDelta, seed: int = 0
     ) -> RepairResult:
         """Mesh twin of :func:`repair_labels`: same inits, propagation
         through the sharded entries, same shared verify/fallback tail
-        (:func:`_verify_or_fallback`). The partition family comes from
-        :meth:`_resolve_shard_family` — past the 2D crossover the
-        repair supersteps run the neighbor-only boundary exchange
-        (``partition_graph(build_plan2d=True)``), so a near-empty
-        repair frontier stops paying an O(V) label all_gather per
-        fixpoint superstep; labels are bit-identical either way (the
-        r16 parity pins)."""
+        (:func:`_verify_or_fallback`). The partition carries no plan: the
+        repair supersteps run the sort shard bodies."""
         from graphmine_tpu.obs.costmodel import emit_shard_exchange
         from graphmine_tpu.parallel.mesh import make_mesh
         from graphmine_tpu.parallel.sharded import (
@@ -1080,14 +1020,7 @@ class DeltaIngestor:
         v = graph.num_vertices
         budget = frontier_budget(v, len(affected_vertices(delta)))
         mesh = make_mesh(self.num_shards)
-        family = self._resolve_shard_family(graph)
-        self.last_shard_family = family
-        sg = shard_graph_arrays(
-            partition_graph(
-                graph, mesh=mesh, build_plan2d=family == "sharded_2d"
-            ),
-            mesh,
-        )
+        sg = shard_graph_arrays(partition_graph(graph, mesh=mesh), mesh)
         emit_shard_exchange(
             self.sink, "delta_repair", sg, version=self.snapshot.version
         )
@@ -1112,26 +1045,13 @@ class DeltaIngestor:
         )
         # telemetry rides the while-loop carry and gives the convergence
         # verdict the bare call lacks: exhausted-at-budget iff the final
-        # superstep still changed labels. The 2D family's CC replaces
-        # the full-vector pointer jump with a CHUNK-LOCAL one (the
-        # global jump needs exactly the O(V) random access the family
-        # removes), so min-propagation converges in O(D + log Vc)-ish
-        # supersteps on range-clustered repairs but up to O(diameter)
-        # when a repaired chain alternates shards — grant the CC run a
-        # D-scaled budget (each 2D superstep is exactly the cheap
-        # exchange this family buys) so those repairs still land warm;
-        # a genuinely pathological diameter exhausts it and takes the
-        # cold-recompute fallback, same as always.
-        budget_cc = (
-            min(budget * self.num_shards, 512)
-            if family == "sharded_2d" else budget
-        )
+        # superstep still changed labels.
         cc, tele = sharded_connected_components(
-            sg, mesh, max_iter=budget_cc,
+            sg, mesh, max_iter=budget,
             init_labels=jnp.asarray(cc_repair_init(self.cc_labels, v, delta)),
             telemetry=True,
         )
-        conv_c = tele.iterations < budget_cc or (
+        conv_c = tele.iterations < budget or (
             len(tele.labels_changed) > 0 and int(tele.labels_changed[-1]) == 0
         )
         return _verify_or_fallback(

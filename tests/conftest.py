@@ -56,15 +56,6 @@ def pytest_configure(config):
     )
     config.addinivalue_line(
         "markers",
-        "blocking: propagation-blocking superstep suite "
-        "(tests/test_blocking.py: blocked-vs-sort bit parity for "
-        "LPA/CC/PageRank fused + sharded, the crossover policy owner, "
-        "plan_build records, the blocking bench-tier smoke); runs in the "
-        "default CPU pass — select with -m blocking or "
-        "tools/run_tier1.sh --blocking-only",
-    )
-    config.addinivalue_line(
-        "markers",
         "admission: write-path admission-control suite "
         "(tests/test_admission.py: the accept/queue/coalesce/shed policy "
         "owner, order-exact delta coalescing, deadline shedding, the "
@@ -123,19 +114,6 @@ def pytest_configure(config):
         "fleet sketch-merge e2e, the obs_report quality timeline and "
         "its exit-4 canary gate); runs in the default CPU pass — "
         "select with -m quality or tools/run_tier1.sh --quality-only",
-    )
-    config.addinivalue_line(
-        "markers",
-        "sharded2d: 2D-edge-partition neighbor-exchange suite "
-        "(tests/test_sharded2d.py: LPA/CC bit-parity vs the sort oracle "
-        "over power-law/ring/self-loop/isolated/duplicate-edge graphs "
-        "fused + virtual-mesh sharded (weighted included), per-peer "
-        "boundary index-table exactness on hand-built 3-shard graphs, "
-        "the planner ladder + env-override policy pins, costmodel/"
-        "memmodel exact-arithmetic pins, plan-time per-peer-buffer "
-        "pre-degrade, the serve warm-repair 2D e2e and the exchange "
-        "bench-tier smoke); runs in the default CPU pass — select with "
-        "-m sharded2d or tools/run_tier1.sh --sharded2d-only",
     )
     config.addinivalue_line(
         "markers",

@@ -1,7 +1,7 @@
 """Compute-plane performance observability (ISSUE 12, marker `perf`):
 
 - the analytical cost model exact against HAND-COMPUTED tiny plans for
-  all three superstep families (fused + sharded, weighted) and both LOF
+  both superstep families (fused + sharded, weighted) and both LOF
   impls — the derivation reads the plan objects, so these tests pin the
   byte/slot accounting to paper arithmetic;
 - roofline anchor overrides (env / file) and provenance;
@@ -11,8 +11,7 @@
   exchange split;
 - obs_report's roofline section + the waterfall threshold/model lines;
 - tools/bench_diff.py: regression / no-regression / tolerance-edge gates
-  on synthetic BENCH files, the silicon-capture manifest and the
-  blocked-crossover suggestion;
+  on synthetic BENCH files and the silicon-capture manifest;
 - schema: half-stamped cost sub-records fail validation; schema_lint
   flags inline cost=... literals outside the single builder.
 """
@@ -46,7 +45,6 @@ import bench_diff  # noqa: E402
 # measurements; tests want round numbers).
 ANCHORS = {
     "gather_slots_per_sec": {"v": 100.0, "src": "test"},
-    "binned_slots_per_sec": {"v": 50.0, "src": "test"},
     "exchange_bytes_per_sec": {"v": 400.0, "src": "test"},
     "lof_exact_pairs_per_sec": {"v": 1000.0, "src": "test"},
     "lof_ivf_points_per_sec": {"v": 50.0, "src": "test"},
@@ -117,36 +115,6 @@ def test_bucketed_cost_exact_ring_and_star():
     assert c2.predicted_seconds == pytest.approx(43 / 100.0)
 
 
-def test_blocked_cost_exact_and_weighted():
-    from graphmine_tpu.ops.blocking import BlockedPlan
-
-    plan = BlockedPlan.from_graph(ring4())
-    c = costmodel.superstep_cost(
-        "lpa_superstep", "blocked", 4, 8, 4, plan=plan, anchors=ANCHORS
-    )
-    # stream pass M=8 at the binned rate + 8 reduce-row slots at gather
-    assert (c.family, c.slots, c.padded_slots) == ("blocked", 8, 16)
-    assert c.bytes_gathered == 4 * (8 + 8)
-    assert c.bytes_scattered == 4 * 8 + 4 * 4   # tile scatter + writeback
-    assert c.predicted_seconds == pytest.approx(8 / 50.0 + 8 / 100.0)
-
-    gw = star21(weights=np.ones(21, np.float32) * 2.0)
-    planw = BlockedPlan.from_graph(gw)
-    cw = costmodel.superstep_cost(
-        "lpa_superstep", "blocked", 22, 42, 21, plan=planw, anchors=ANCHORS
-    )
-    # weight payload rides the reduce rows only (stream carries labels)
-    assert cw.padded_slots == 42 + 43
-    assert cw.bytes_gathered == 4 * (42 + 43 * 2)
-    assert cw.predicted_seconds == pytest.approx(42 / 50.0 + 43 * 2 / 100.0)
-    # explicit weighted=False models a weight-blind op on the same plan
-    cc = costmodel.superstep_cost(
-        "cc_superstep", "blocked", 22, 42, 21, plan=planw, weighted=False,
-        anchors=ANCHORS,
-    )
-    assert cc.bytes_gathered == 4 * (42 + 43)
-
-
 def test_sharded_cost_exact_all_families():
     from graphmine_tpu.parallel.sharded import partition_graph
 
@@ -174,14 +142,10 @@ def test_sharded_cost_exact_all_families():
     )
     assert (cb.family, cb.padded_slots) == ("bucketed", 16)
     assert cb.compute_seconds == pytest.approx(16 / 100.0)
-
-    # blocked bin groups: stream Mp=16 + [2, 8, 2] reduce rows
-    sgk = partition_graph(g, num_shards=2, build_blocked_plan=True)
-    ck = costmodel.sharded_superstep_cost(
-        "lpa_superstep", sgk, 16, num_messages=32, anchors=ANCHORS
-    )
-    assert (ck.family, ck.padded_slots) == ("blocked", 16 + 16)
-    assert ck.compute_seconds == pytest.approx(16 / 50.0 + 16 / 100.0)
+    # the record names the anchors the estimate used, and no other
+    assert set(cb.roofline) == {
+        "gather_slots_per_sec", "exchange_bytes_per_sec"
+    }
 
 
 def test_lof_cost_exact():
@@ -213,7 +177,7 @@ def test_roofline_seeds_carry_provenance():
     assert costmodel.MODEL_DEVICE_KIND in a["gather_slots_per_sec"]["src"]
     # the unmeasured seeds SAY they are unmeasured
     assert "unmeasured" in a["exchange_bytes_per_sec"]["src"]
-    assert "blocking" in a["binned_slots_per_sec"]["src"]
+    assert "binned_slots_per_sec" not in a  # went with the blocked family
 
 
 @pytest.mark.parametrize(
@@ -252,12 +216,12 @@ def test_roofline_env_and_file_overrides(monkeypatch, tmp_path):
     # file override: the re-seed path a fresh silicon capture uses
     p = tmp_path / "roof.json"
     p.write_text(json.dumps(
-        {"binned_slots_per_sec": 2.5e8, "unknown_anchor": 1.0}
+        {"exchange_bytes_per_sec": 2.5e8, "unknown_anchor": 1.0}
     ))
     monkeypatch.setenv("GRAPHMINE_ROOFLINE_FILE", str(p))
     a = costmodel.rooflines()
-    assert a["binned_slots_per_sec"]["v"] == 2.5e8
-    assert a["binned_slots_per_sec"]["src"].startswith("file:")
+    assert a["exchange_bytes_per_sec"]["v"] == 2.5e8
+    assert a["exchange_bytes_per_sec"]["src"].startswith("file:")
     # env still beats file for the anchor both set
     assert a["gather_slots_per_sec"]["src"] == "env"
     # malformed file raises instead of silently un-anchoring the model
@@ -392,16 +356,13 @@ def test_lof_impl_selected_carries_threshold_and_cost():
 def test_superstep_auto_seam_impl_selected_carries_thresholds(monkeypatch):
     from graphmine_tpu.ops.lpa import label_propagation
 
-    from graphmine_tpu.ops.blocking import BUCKETED_MIN_MESSAGES
+    from graphmine_tpu.ops.superstep_policy import BUCKETED_MIN_MESSAGES
 
     m = _sink()
     label_propagation(ring4(), max_iter=1, sink=m)
     (sel,) = [r for r in m.records if r["phase"] == "impl_selected"]
     # the constants that decided ship with the record
     assert sel["thresholds"] == {"bucketed_min_messages": BUCKETED_MIN_MESSAGES}
-    # only constants that decide something ship (PR 26: auto has no
-    # blocked crossover on one device)
-    assert not [k for k in sel["thresholds"] if k.startswith("blocked")]
     assert sel["cost"]["family"] == sel["impl"]
 
 
@@ -631,54 +592,29 @@ def test_bench_diff_capture_change_gates_only_under_strict(tmp_path):
 def test_bench_diff_manifest_tracks_fallback_only_tiers(tmp_path, capsys):
     real = _bench_file(tmp_path, "BENCH_r90.json", 90, _chip(100_000_000))
     fb_rec = {
-        "metric": "blocking_binned_slots_per_sec_cpu_fallback",
-        "value": 1000.0, "unit": "slots/s", "vs_baseline": 0.1,
-        "detail": {"binned_vs_random_gather": 0.5,
+        "metric": "streaming_lof_points_per_sec_cpu_fallback",
+        "value": 1000.0, "unit": "points/s", "vs_baseline": 0.1,
+        "detail": {"ivf_reuse": {"speedup": 0.5},
                    "capture": {"cpu_fallback": "tpu unreachable"}},
     }
     fb = _bench_file(
         tmp_path, "BENCH_r91.json", 91,
-        {"blocking": {
-            "metric": "blocking_binned_slots_per_sec_cpu_fallback",
-            "value": 1000.0, "unit": "slots/s"}},
+        {"stream": {
+            "metric": "streaming_lof_points_per_sec_cpu_fallback",
+            "value": 1000.0, "unit": "points/s"}},
         tail_records=[fb_rec],
     )
     assert bench_diff.main([real, fb, "--manifest", "--no-gate"]) == 0
     out = capsys.readouterr().out
     manifest = json.loads(out.split("== silicon-capture manifest ==")[1])
     assert manifest["tiers"]["chip"] == "silicon"
-    assert manifest["tiers"]["blocking"] == "cpu_fallback"
-    assert manifest["sub_records"][
-        "blocking.binned_vs_random_gather"] == "cpu_fallback"
-    assert "blocking" in manifest["pending"]
+    assert manifest["tiers"]["stream"] == "cpu_fallback"
+    assert manifest["sub_records"]["stream.ivf_reuse"] == "cpu_fallback"
+    assert "stream" in manifest["pending"]
+    # the tiers that measured the deleted families went with them
+    assert not {"blocking", "exchange"} & set(manifest["tiers"])
     assert "chip" not in manifest["pending"]
     # --strict turns a non-empty backlog into exit 1
     assert bench_diff.main(
         [real, fb, "--manifest", "--strict", "--no-gate"]
     ) == 1
-
-
-def test_bench_diff_crossover_suggestion_on_silicon_blocking(tmp_path, capsys):
-    rec = {
-        "metric": "blocking_binned_slots_per_sec", "value": 2.6e8,
-        "unit": "slots/s", "vs_baseline": 2.0,
-        "detail": {"binned_vs_random_gather": 1.9,
-                   "capture": {"cpu_fallback": None}},
-    }
-    f = _bench_file(
-        tmp_path, "BENCH_r90.json", 90,
-        {"blocking": {"metric": "blocking_binned_slots_per_sec",
-                      "value": 2.6e8, "unit": "slots/s"}},
-        tail_records=[rec],
-    )
-    assert bench_diff.main([f, "--no-gate"]) == 0
-    out = capsys.readouterr().out
-    assert "blocked-crossover suggestion" in out
-    assert "1.90x" in out
-    assert "GRAPHMINE_SUPERSTEP_FAMILY=blocked" in out
-    # no crossover constant is left to parse or to suggest a value for
-    # (PR 26: auto resolves no graph to blocked)
-    assert "BLOCKED_MIN" not in out
-    assert not hasattr(bench_diff, "_current_blocked_constants")
-    # a CPU-fallback ratio must NOT produce a suggestion
-    capsys.readouterr()

@@ -21,6 +21,7 @@
   rule, bench_diff's memory sub-record gate (bytes regress UP).
 """
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -127,8 +128,7 @@ def test_prebuild_footprints_anchor_to_the_planner_seeds():
     """Without a plan, the fused bucketed estimate IS the schedule model
     the planner accepted the run with (an admitted run can never
     spuriously pre-degrade off its own family); sort drops the
-    plan-mats term; blocked adds the stream pair + tile the 36 B/edge
-    seed predates."""
+    plan-mats term."""
     bu = memmodel.superstep_footprint("lpa_superstep", "bucketed", 4, 8,
                                       num_edges=4)
     assert bu.inventory == memmodel.schedule_inventory("single", 4, 4, 1)
@@ -139,19 +139,15 @@ def test_prebuild_footprints_anchor_to_the_planner_seeds():
                                       num_edges=4)
     assert "plan_mats" not in so.inventory
     assert so.total_bytes == bu.total_bytes - 4 * 6  # 6 B/edge plan term
-    bl = memmodel.superstep_footprint("lpa_superstep", "blocked", 4, 8,
-                                      num_edges=4)
-    assert bl.inventory["stream"] == 2 * 4 * 8
-    assert bl.inventory["tile"] == 4 * 8        # min(M, tile-slot seed)
-    assert bl.total_bytes == bu.total_bytes + 64 + 32
-    assert not any(e.exact for e in (bu, so, bl))
+    assert not any(e.exact for e in (bu, so))
     # weighted adds the seed's 16 B/edge payload terms
     ew = memmodel.superstep_footprint("lpa_superstep", "sort", 4, 8,
                                       num_edges=4, weighted=True)
     assert ew.inventory["msg_weights"] == 4 * 8
     assert ew.inventory["weight_mats"] == 4 * 8
-    with pytest.raises(ValueError):
-        memmodel.superstep_footprint("x", "mesh2d", 4, 8)
+    for gone in ("mesh2d", "blocked", "sharded_2d"):
+        with pytest.raises(ValueError, match="unknown superstep family"):
+            memmodel.superstep_footprint("x", gone, 4, 8)
 
 
 def test_bucketed_footprint_exact_ring_and_star():
@@ -190,41 +186,6 @@ def test_bucketed_footprint_exact_ring_and_star():
     assert ew.inventory["msg_weights"] == 4 * 42
 
 
-def test_blocked_footprint_exact_and_weighted():
-    from graphmine_tpu.ops.blocking import BlockedPlan
-
-    plan = BlockedPlan.from_graph(ring4())
-    e = memmodel.superstep_footprint(
-        "lpa_superstep", "blocked", 4, 8, num_edges=4, plan=plan
-    )
-    # stream pair 2*4*8; tile = the plan's real alloc; 8 reduce-row
-    # slots + 4 owners; transient rides the rows
-    assert e.inventory["stream"] == 2 * 4 * 8
-    assert e.inventory["tile"] == 4 * int(plan.tile_alloc)
-    assert e.inventory["reduce_rows"] == 4 * 8
-    assert e.inventory["row_vertex"] == 4 * 4
-    assert e.inventory["gather_transient"] == 4 * 8
-    assert (e.family, e.exact) == ("blocked", True)
-
-    gw = star21(weights=np.ones(21, np.float32) * 2.0)
-    planw = BlockedPlan.from_graph(gw)
-    ew = memmodel.superstep_footprint(
-        "lpa_superstep", "blocked", 22, 42, num_edges=21, plan=planw
-    )
-    # weight mats align with the 43 padded reduce-row slots
-    assert ew.inventory["reduce_rows"] == 4 * 43
-    assert ew.inventory["weight_mats"] == 4 * 43
-    assert ew.inventory["msg_weights"] == 4 * 42
-    # the family ladder shrinks strictly: blocked > bucketed > sort
-    fams = [
-        memmodel.superstep_footprint(
-            "lpa_superstep", f, 22, 42, num_edges=21
-        ).total_bytes
-        for f in ("blocked", "bucketed", "sort")
-    ]
-    assert fams[0] > fams[1] > fams[2]
-
-
 def test_sharded_footprint_exact_all_families():
     from graphmine_tpu.parallel.sharded import partition_graph
 
@@ -260,16 +221,7 @@ def test_sharded_footprint_exact_all_families():
     assert eb.family == "bucketed"
     assert eb.inventory["plan_mats"] == 4 * 8 * 2
     assert eb.inventory["plan_vertex_ids"] == 4 * 8
-    assert eb.total_bytes == 576
-
-    # blocked bin groups: stream pair + shard-local tile + [2, 8, 2] rows
-    sgk = partition_graph(g, num_shards=2, build_blocked_plan=True)
-    ek = memmodel.sharded_superstep_footprint("lpa_superstep", sgk)
-    assert ek.family == "blocked"
-    assert ek.inventory["stream"] == 2 * 4 * 16
-    assert ek.inventory["tile"] == 4 * int(sgk.blk_tile_alloc)
-    assert ek.inventory["reduce_rows"] == 4 * 8 * 2
-    assert ek.total_bytes > eb.total_bytes > e.total_bytes
+    assert eb.total_bytes == 576 > e.total_bytes
 
 
 def test_lof_footprint_exact_and_ivf_workspace():
@@ -391,9 +343,9 @@ def test_predegrade_walks_to_fit():
     ).total_bytes
     # generous budget: the requested family fits, no steps
     fam, fit, steps = memmodel.predegrade_superstep(
-        "blocked", v, mcount, e, False, 1 << 30
+        "bucketed", v, mcount, e, False, 1 << 30
     )
-    assert (fam, steps) == ("blocked", []) and fit.family == "blocked"
+    assert (fam, steps) == ("bucketed", []) and fit.total_bytes == bu
     # budget between sort and bucketed: bucketed steps down exactly once
     fam, fit, steps = memmodel.predegrade_superstep(
         "bucketed", v, mcount, e, False, (bu + so) // 2
@@ -404,9 +356,11 @@ def test_predegrade_walks_to_fit():
     # below even the sort floor: the floor is returned (there is nothing
     # leaner; the reactive ladder owns what happens next)
     fam, fit, steps = memmodel.predegrade_superstep(
-        "blocked", v, mcount, e, False, 16
+        "bucketed", v, mcount, e, False, 16
     )
-    assert fam == "sort" and len(steps) == 2
+    assert fam == "sort" and len(steps) == 1
+    # the walk is the one degrade order, read by the planner too
+    assert memmodel.FAMILY_DEGRADE == {"bucketed": "sort", "sort": None}
 
 
 # ---------------------------------------------------------------------------
@@ -564,54 +518,55 @@ def test_oom_degrade_carries_watermark_and_inventory(tmp_path):
 
 
 def test_plan_time_predegrade_e2e(tmp_path, monkeypatch):
-    """A budget squeezed between the blocked and bucketed footprints
-    makes the driver consume the family rung at PLAN time: a degrade
-    record with kind=mem_plan and the oversized inventory, the bucketed
-    kernel actually deployed — and degradation='off' keeps the family.
-    (The bucketed pre-build estimate IS the planner's accepted model,
-    so only the blocked family — whose stream + tile the 36 B/edge seed
-    predates — can exceed a budget the planner admitted.)"""
+    """A family whose MODELED footprint exceeds the budget is consumed at
+    PLAN time: a degrade record with kind=mem_plan and the oversized
+    inventory, the sort superstep actually deployed as the run's primary
+    — and degradation='off' keeps the family. The bucketed pre-build
+    estimate IS the planner's accepted model, so an admitted run never
+    pre-degrades on its own; the oversized term is injected here (a
+    scratch the seed does not know), as a leaner-than-modeled family
+    would bring one."""
     v, e = 160, 800
-    bl = memmodel.superstep_footprint(
-        "lpa_superstep", "blocked", v, 2 * e, num_edges=e
-    ).total_bytes
     floor = memmodel.schedule_bytes_per_device("single", v, e, 1)
-    assert floor < bl, "fixture must leave a pre-degrade window"
-    budget = (bl + floor) // 2
-    monkeypatch.setenv("GRAPHMINE_SUPERSTEP_FAMILY", "blocked")
+    scratch = 4096
+    real = memmodel.superstep_footprint
+
+    def with_scratch(op, family, *a, **kw):
+        est = real(op, family, *a, **kw)
+        if family != "bucketed":
+            return est
+        return dataclasses.replace(
+            est, inventory={**est.inventory, "scratch": scratch}
+        )
+
+    budget = floor + scratch // 2
     monkeypatch.setenv("GRAPHMINE_HBM_BYTES", str(int(budget / 0.9) + 1))
+    # an admitted bucketed run NEVER pre-degrades: the pre-build model
+    # is the planner's own arithmetic (the one-owner guarantee)
+    res0 = _run_driver(tmp_path, max_iter=3,
+                       metrics_out=str(tmp_path / "m0.jsonl"))
+    assert not [r for r in res0.metrics.records
+                if r["phase"] == "degrade" and r.get("kind") == "mem_plan"]
+    monkeypatch.setattr(memmodel, "superstep_footprint", with_scratch)
     res = _run_driver(tmp_path, max_iter=3)
     recs = res.metrics.records
     pre = [r for r in recs if r["phase"] == "degrade"
            and r.get("kind") == "mem_plan"]
-    assert len(pre) == 1 and pre[0]["to"] == "bucketed"
+    assert len(pre) == 1 and pre[0]["to"] == "sort"
     assert pre[0]["stage"] == "plan_superstep"
-    assert pre[0]["mem"]["family"] == "blocked"
-    assert pre[0]["mem"]["total_bytes"] == bl > budget
+    assert pre[0]["mem"]["family"] == "bucketed"
+    assert pre[0]["mem"]["total_bytes"] == floor + scratch > budget
     (sel,) = [r for r in recs if r["phase"] == "impl_selected"]
-    assert sel["impl"] == "bucketed" and "pre-degraded" in sel["reason"]
+    assert sel["impl"] == "sort" and "pre-degraded" in sel["reason"]
+    # the sort superstep really ran: no plan was built
+    assert not [r for r in recs if r["phase"] == "plan_build"]
     assert validate_records(recs) == []
-    # labels match an unsqueezed (blocked) run: the rung trades memory,
-    # not results — blocked/bucketed label parity is the r7 contract
-    monkeypatch.setenv("GRAPHMINE_HBM_BYTES", str(1 << 34))
-    res2 = _run_driver(tmp_path, max_iter=3,
-                       metrics_out=str(tmp_path / "m2.jsonl"))
-    np.testing.assert_array_equal(res.labels, res2.labels)
-    # an admitted bucketed run NEVER pre-degrades: the pre-build model
-    # is the planner's own arithmetic (the one-owner guarantee)
-    monkeypatch.delenv("GRAPHMINE_SUPERSTEP_FAMILY")
-    monkeypatch.setenv(
-        "GRAPHMINE_HBM_BYTES", str(int(floor / 0.9) + 2)
-    )
-    res4 = _run_driver(tmp_path, max_iter=1,
-                       metrics_out=str(tmp_path / "m4.jsonl"))
-    assert not [r for r in res4.metrics.records
-                if r["phase"] == "degrade" and r.get("kind") == "mem_plan"]
+    # labels match the unsqueezed (bucketed) run: the rung trades memory,
+    # not results
+    np.testing.assert_array_equal(res.labels, res0.labels)
     # degradation="off": the operator wants the OOM, not a leaner family
     from graphmine_tpu.pipeline.resilience import ResilienceConfig
 
-    monkeypatch.setenv("GRAPHMINE_SUPERSTEP_FAMILY", "blocked")
-    monkeypatch.setenv("GRAPHMINE_HBM_BYTES", str(int(budget / 0.9) + 1))
     res3 = _run_driver(
         tmp_path, max_iter=1, metrics_out=str(tmp_path / "m3.jsonl"),
         resilience=ResilienceConfig(degradation="off"),

@@ -40,7 +40,7 @@ def test_scope_lint_package_is_clean():
     assert schema_lint.scope_violations() == []
     used = {name for name, _, _ in schema_lint.scan_scopes()}
     assert used == set(schema.DEVICE_SCOPES)  # every registered scope is used
-    assert {"lpa_blocked", "bin_gather", "ivf", "search_topk"} <= used
+    assert {"lpa_bucketed", "row_gather", "ivf", "search_topk"} <= used
 
 
 def test_scope_lint_catches_unregistered_and_computed_names(tmp_path):
@@ -49,7 +49,7 @@ def test_scope_lint_catches_unregistered_and_computed_names(tmp_path):
     (tmp_path / "mod.py").write_text(
         "import jax\n"
         "def f(x, name, ridx):\n"
-        '    with jax.named_scope("lpa_blocked"):\n'
+        '    with jax.named_scope("lpa_bucketed"):\n'
         '        with jax.named_scope("not_a_registered_scope"):\n'
         "            x = x + 1\n"
         "        with jax.named_scope(name):\n"
@@ -87,7 +87,7 @@ def _scopes_in(op_names) -> set:
 
 
 def _superstep_case(family, algorithm):
-    from graphmine_tpu.ops import blocking, cc, lpa
+    from graphmine_tpu.ops import cc, lpa
     from graphmine_tpu.ops.bucketed_mode import (
         BucketedModePlan,
         lpa_superstep_bucketed,
@@ -98,11 +98,6 @@ def _superstep_case(family, algorithm):
     if family == "sort":
         fn = lpa.lpa_superstep if algorithm == "lpa" else cc.cc_superstep
         return fn, (labels, g)
-    if family == "blocked":
-        plan = blocking.BlockedPlan.from_graph(g, tile_slots=256)
-        if algorithm == "lpa":
-            return blocking.lpa_superstep_blocked, (labels, g, plan)
-        return blocking.cc_superstep_blocked, (labels, plan)
     plan = BucketedModePlan.from_graph(g, with_send=True)
     if algorithm == "lpa":
         return lpa_superstep_bucketed, (labels, g, plan)
@@ -110,12 +105,6 @@ def _superstep_case(family, algorithm):
 
 
 @pytest.mark.parametrize("family,algorithm,want", [
-    ("blocked", "lpa", {"lpa_blocked/bin_gather", "lpa_blocked/bin_scatter",
-                        "lpa_blocked/row_gather", "lpa_blocked/row_mode",
-                        "lpa_blocked/write_back"}),
-    ("blocked", "cc", {"cc_blocked/bin_gather", "cc_blocked/bin_scatter",
-                       "cc_blocked/row_gather", "cc_blocked/row_min",
-                       "cc_blocked/pointer_jump"}),
     ("bucketed", "lpa", {"lpa_bucketed/row_gather", "lpa_bucketed/row_mode",
                          "lpa_bucketed/write_back"}),
     ("bucketed", "cc", {"cc_bucketed/row_gather", "cc_bucketed/row_min",
@@ -317,7 +306,7 @@ def test_stage_span_without_a_sink_records_nothing_and_syncs_nothing():
 
 # ---- (4) the reduction of a capture, on hand-made events -------------------
 
-_REG = frozenset({"lpa_blocked", "bin_gather", "row_gather", "superstep",
+_REG = frozenset({"lpa_bucketed", "hist", "row_gather", "superstep",
                   "changed_count", "sort"})
 
 
@@ -327,22 +316,22 @@ def _op(name, start, end, op_name=None):
 
 def test_scope_of_takes_the_first_two_registered_levels():
     f = devtrace.scope_of
-    assert f("jit(f)/lpa_blocked/while/body/closed_call/row_gather/w8/gather:",
-             _REG) == "lpa_blocked/row_gather"
-    assert f("jit(f)/lpa_blocked/bin_gather/gather", _REG) == "lpa_blocked/bin_gather"
+    assert f("jit(f)/lpa_bucketed/while/body/closed_call/row_gather/w8/gather:",
+             _REG) == "lpa_bucketed/row_gather"
+    assert f("jit(f)/lpa_bucketed/hist/gather", _REG) == "lpa_bucketed/hist"
     # the primitive is never a scope, even when a scope shares its name
-    assert f("jit(f)/lpa_blocked/sort", _REG) == "lpa_blocked"
-    assert f("jit(f)/lpa_blocked/sort/sort", _REG) == "lpa_blocked/sort"
+    assert f("jit(f)/lpa_bucketed/sort", _REG) == "lpa_bucketed"
+    assert f("jit(f)/lpa_bucketed/sort/sort", _REG) == "lpa_bucketed/sort"
     assert f("jit(_mean)/div:", _REG) == "unscoped"
     assert f("", _REG) == "unscoped"
 
 
 def test_reduce_capture_groups_counts_a_loop_body_once_and_books_by_span():
     ops = {"/device:TPU:0": [
-        _op("while.1", 1.0, 9.0, "jit(f)/lpa_blocked/while"),  # spans its body
-        _op("fusion.1", 1.0, 4.0, "jit(f)/lpa_blocked/while/body/bin_gather/gather:"),
-        _op("fusion.2", 4.0, 6.0, "jit(f)/lpa_blocked/while/body/row_gather/w4/gather:"),
-        _op("fusion.3", 6.0, 7.0, "jit(f)/lpa_blocked/while/body/row_gather/w8/gather:"),
+        _op("while.1", 1.0, 9.0, "jit(f)/lpa_bucketed/while"),  # spans its body
+        _op("fusion.1", 1.0, 4.0, "jit(f)/lpa_bucketed/while/body/hist/gather:"),
+        _op("fusion.2", 4.0, 6.0, "jit(f)/lpa_bucketed/while/body/row_gather/w4/gather:"),
+        _op("fusion.3", 6.0, 7.0, "jit(f)/lpa_bucketed/while/body/row_gather/w8/gather:"),
         _op("fusion.4", 7.0, 8.5, "jit(f)/superstep/while/body/changed_count/reduce_sum:"),
         _op("copy.1", 8.5, 9.0),                              # no op_name at all
         _op("fusion.9", 20.0, 21.0, "jit(g)/div:"),           # no registered level
@@ -355,8 +344,8 @@ def test_reduce_capture_groups_counts_a_loop_body_once_and_books_by_span():
     rows = {(r["module"], r["scope"], r["stage_path"]):
             (r["device_seconds"], r["events"]) for r in out["scopes"]}
     assert rows == {
-        ("jit_f", "lpa_blocked/bin_gather", "run/lpa/stage"): (3.0, 1),
-        ("jit_f", "lpa_blocked/row_gather", "run/lpa/stage"): (3.0, 2),
+        ("jit_f", "lpa_bucketed/hist", "run/lpa/stage"): (3.0, 1),
+        ("jit_f", "lpa_bucketed/row_gather", "run/lpa/stage"): (3.0, 2),
         ("jit_f", "superstep/changed_count", "run/lpa/stage"): (1.5, 1),
         ("jit_f", "unscoped", "run/lpa/stage"): (0.5, 1),
         ("jit_g", "unscoped", "run/lpa"): (1.0, 1),
@@ -374,7 +363,7 @@ def test_reduce_capture_groups_counts_a_loop_body_once_and_books_by_span():
 def test_a_program_is_booked_to_the_span_open_at_its_middle():
     # the device's clock a little ahead of the host's: the program seems
     # to start before the span that launched it
-    ops = {"d": [_op("fusion.1", 0.9990, 1.5, "jit(f)/lpa_blocked/bin_gather/gather:")]}
+    ops = {"d": [_op("fusion.1", 0.9990, 1.5, "jit(f)/lpa_bucketed/hist/gather:")]}
     modules = {"d": [("jit_f(1)", 0.9990, 1.5, {})]}
     spans = [("run/a", 0.0, 1.0, {}), ("run/b", 1.0, 2.0, {})]
     (row,) = devtrace.reduce_capture(ops, modules, spans, _REG)["scopes"]
@@ -423,7 +412,7 @@ def test_read_xplane_reads_a_hand_made_file(tmp_path):
     device = _field(1, (
         _field(2, "/device:TPU:0") + stat_names
         + _event_metadata(1, "%fusion.7 = s32[8] fusion(...)", "fusion.7",
-                          "jit(f)/lpa_blocked/bin_gather/gather:")
+                          "jit(f)/lpa_bucketed/hist/gather:")
         + _event_metadata(2, "jit_f(55)")
         + _line("XLA Ops", 1_000_000_000, [(1, 500_000_000_000, 250_000_000_000)])
         + _line("XLA Modules", 1_000_000_000, [(2, 500_000_000_000, 300_000_000_000)])
@@ -438,12 +427,12 @@ def test_read_xplane_reads_a_hand_made_file(tmp_path):
     path.write_bytes(host + device)
     ops, modules, spans = devtrace.read_xplane(str(path), "run")
     assert ops == {"/device:TPU:0": [
-        ("fusion.7", 1.5, 1.75, {"tf_op": "jit(f)/lpa_blocked/bin_gather/gather:"})]}
+        ("fusion.7", 1.5, 1.75, {"tf_op": "jit(f)/lpa_bucketed/hist/gather:"})]}
     assert modules == {"/device:TPU:0": [("jit_f(55)", 1.5, 1.8, {})]}
     assert spans == [("run/cdlp", 1.0, 3.0, {})]  # "runner" is not under "run/"
     (row,) = devtrace.reduce_capture(ops, modules, spans, _REG)["scopes"]
     assert (row["module"], row["scope"], row["stage_path"]) == (
-        "jit_f", "lpa_blocked/bin_gather", "run/cdlp")
+        "jit_f", "lpa_bucketed/hist", "run/cdlp")
 
 
 # ---- (5) a record per compile ----------------------------------------------

@@ -22,11 +22,6 @@ holding the artifacts):
   ``*_cpu_fallback`` records (or not at all) across the whole
   trajectory — the machine-readable replacement for ROADMAP's
   hand-maintained "Silicon capture backlog" list.
-- **Crossover suggestion**: when a real (non-fallback) ``blocking``
-  capture lands, its ``detail.binned_vs_random_gather`` ratio is set
-  beside the whole-superstep measurement that took the ``blocked``
-  family out of ``plan="auto"`` (PERF.md §6, PR 26), with what the
-  ratio would have to be followed by before an auto branch returns.
 
 Inputs: ``BENCH_*.json`` driver artifacts (``{n, cmd, rc, tail,
 parsed}`` — ``tail`` holds the stdout tail's JSON record lines,
@@ -61,7 +56,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # tests/test_costmodel.py so the two can never drift; bench.py imports
 # numpy at module load, which this stdlib-only tool must not).
 ALL_TIERS = (
-    "chip", "roofline", "blocking", "northstar", "sharded", "exchange",
+    "chip", "roofline", "northstar", "sharded",
     "cc", "e2e", "lof", "snap", "quality", "weighted", "stream", "serve",
 )
 
@@ -69,11 +64,6 @@ ALL_TIERS = (
 # tier's record `detail` and counts as silicon-captured only when seen in
 # a NON-fallback record (the ROADMAP backlog named exactly these).
 SUB_RECORDS = {
-    "blocking": ("binned_vs_random_gather",),
-    # the neighbor-exchange vs all_gather WALL ratio needs a real
-    # multi-chip ICI window (the committed records are virtual-mesh CPU
-    # fallbacks whose modeled bytes are exact but whose seconds are not)
-    "exchange": ("neighbor_vs_allgather",),
     "stream": ("ivf_reuse",),
     "serve": ("write_load", "replicated_read", "writer_failover",
               "latency_quantiles", "quality_pass", "multi_tenant",
@@ -90,9 +80,7 @@ _METRIC_TIER_PREFIXES = (
     ("lpa_100m", "northstar"),
     ("lpa_", "chip"),
     ("roofline_", "roofline"),
-    ("blocking_", "blocking"),
     ("sharded_lpa", "sharded"),
-    ("exchange_", "exchange"),
     ("cc_", "cc"),
     ("e2e_", "e2e"),
     ("lof_", "lof"),
@@ -122,9 +110,7 @@ TIER_TOLERANCE = {
 }
 
 # Units where DOWN is an improvement (everything else: up is better).
-# "frac" is the exchange tier's neighbor/all_gather bytes fraction —
-# fewer bytes on the wire is the whole point of the 2D family.
-LOWER_BETTER_UNITS = frozenset(("s", "seconds", "ms", "us", "frac"))
+LOWER_BETTER_UNITS = frozenset(("s", "seconds", "ms", "us"))
 
 # Per-tier memory sub-record gate (ISSUE 14): peak bytes regress UP.
 # Child RSS is noisier than kernel rates (allocator arenas, import
@@ -474,57 +460,6 @@ def silicon_manifest(captures: list) -> dict:
     }
 
 
-# ---- crossover suggestion --------------------------------------------------
-
-
-def crossover_suggestion(captures: list) -> list:
-    """When a real (non-fallback) ``blocking`` capture carries
-    ``detail.binned_vs_random_gather``, say what the measured ratio
-    means for the ``blocked`` family, which ``plan="auto"`` resolves on
-    no graph (``ops/blocking.py`` policy comment: whole supersteps on a
-    TPU v5e, PERF.md §6, PR 26). Empty list until that capture lands."""
-    best = None
-    for cap in reversed(captures):  # newest capture wins
-        entry = cap["tiers"].get("blocking")
-        if not entry or "err" in entry or entry.get("cpu_fallback"):
-            continue
-        ratio = (entry.get("detail") or {}).get("binned_vs_random_gather")
-        if isinstance(ratio, (int, float)):
-            best = (cap["label"], float(ratio))
-            break
-    if best is None:
-        return []
-    label, ratio = best
-    lines = [
-        f"  silicon blocking capture in {label}: "
-        f"binned_vs_random_gather = {ratio:.2f}x",
-        "  auto resolves no graph to blocked (ops/blocking.py): a whole "
-        "blocked LPA superstep took 6.13 s against 1.14 s bucketed on a "
-        "v5e at 128 M messages (PERF.md, PR 26)",
-    ]
-    if ratio >= 1.05:
-        lines.append(
-            "  suggestion: the binned pass BEATS the random gather in "
-            "this capture, which whole supersteps did not show — before "
-            "an auto branch returns, A/B whole jobs of the benchmark's "
-            "cdlp-g500-22 cell with GRAPHMINE_SUPERSTEP_FAMILY=blocked "
-            "against the default"
-        )
-    elif ratio <= 0.95:
-        lines.append(
-            "  suggestion: the binned pass LOSES to the random gather, "
-            "as whole supersteps did — blocked stays on request only "
-            "(ROADMAP D2 deletes the family)"
-        )
-    else:
-        lines.append(
-            "  suggestion: measured ratio is within noise of 1.0 — the "
-            "blocked family then pays its two further passes for "
-            "nothing; it stays on request only"
-        )
-    return lines
-
-
 # ---- CLI -------------------------------------------------------------------
 
 
@@ -643,12 +578,6 @@ def main(argv=None) -> int:
             rc = 1
         else:
             print("  gate: clean (no regression past tolerance)")
-
-    suggestion = crossover_suggestion(captures)
-    if suggestion:
-        print("\n== blocked-crossover suggestion ==")
-        for line in suggestion:
-            print(line)
 
     if args.manifest:
         manifest = silicon_manifest(captures)

@@ -215,7 +215,7 @@ def _phase_waterfall(records, t0):
             if extra:
                 out.append(f"      {extra}")
     # plan builds (r7): the host cost of materializing a superstep plan
-    # (bins/buckets + padded slots/edge) — visible here instead of
+    # (width classes + padded slots/edge) — visible here instead of
     # hiding inside first-call latency.
     for r in records:
         if r.get("phase") == "plan_build":
@@ -223,7 +223,6 @@ def _phase_waterfall(records, t0):
             out.append(
                 f"  [plan_build] {r.get('op', '?')}: {r.get('family', '?')}"
                 f" in {float(r.get('seconds', 0.0)):.3f}s{cached} — "
-                f"bins={r.get('bins', '?')}, "
                 f"classes={r.get('width_classes', '?')}, "
                 f"slots/edge={r.get('padded_slots_per_edge', '?')}"
             )
@@ -377,7 +376,7 @@ def _roofline_section(records, min_frac: float):
             out.append(
                 f"  !! {exchange_windows} window(s) carry an exchange "
                 "split anchored to the UNMEASURED exchange_bytes_per_sec "
-                "model seed — capture the sharded/exchange bench tiers "
+                "model seed — capture the sharded bench tier "
                 "(and re-seed via GRAPHMINE_ROOFLINE_FILE) before "
                 "trusting a below-model exchange verdict "
                 "(docs/RUNBOOKS.md §15)"

@@ -7,7 +7,7 @@
 # (-m faults: tests/test_resilience.py + the tripwire/reshard cases in
 # tests/test_sharded.py) is part of this default pass.
 #
-# Usage: tools/run_tier1.sh [--faults-only|--obs-only|--ann-only|--serve-only|--slo-only|--blocking-only|--admission-only|--fleet-only|--wal-only|--trace-only|--perf-only|--quality-only|--mem-only|--sharded2d-only|--tenancy-only|--shardplane-only] [extra pytest args...]
+# Usage: tools/run_tier1.sh [--faults-only|--obs-only|--ann-only|--serve-only|--slo-only|--admission-only|--fleet-only|--wal-only|--trace-only|--perf-only|--quality-only|--mem-only|--tenancy-only|--shardplane-only] [extra pytest args...]
 #   --shardplane-only run just the `shardplane`-marked sharded-write-
 #                  plane suite (tests/test_shardplane.py: range plan
 #                  ownership, deterministic delta splitter bit-parity,
@@ -20,14 +20,6 @@
 #                  tenant-scoped WAL replay, per-tenant alerting and
 #                  the noisy-neighbor chaos acceptance) — the fast
 #                  slice when iterating on tenancy
-#   --sharded2d-only run just the `sharded2d`-marked 2D-edge-partition
-#                  suite (tests/test_sharded2d.py: neighbor-exchange
-#                  bit-parity vs the sort oracle, per-peer boundary
-#                  index tables, the crossover/env policy pins,
-#                  cost/memmodel exact pins, plan-time pre-degrade, the
-#                  serve warm-repair 2D e2e and the exchange bench-tier
-#                  smoke) — the fast slice when iterating on the 2D
-#                  partition or its exchange plan
 #   --mem-only     run just the `mem`-marked memory-plane suite
 #                  (tests/test_memmodel.py: the HBM footprint inventory
 #                  exact against hand-computed tiny plans, the planner
@@ -64,11 +56,6 @@
 #                  (tests/test_serve.py: snapshot round-trip/rollback,
 #                  delta repair equivalence, query engine, live-swap
 #                  server) — the fast slice when iterating on serve/
-#   --blocking-only run just the `blocking`-marked propagation-blocking
-#                  suite (tests/test_blocking.py: blocked-vs-sort bit
-#                  parity for LPA/CC/PageRank fused + sharded, crossover
-#                  policy, plan_build records, bench-tier smoke) — the
-#                  fast slice when iterating on ops/blocking.py
 #   --slo-only     run just the `slo`-marked serving-SLO suite
 #                  (tests/test_slo.py: histograms + merge associativity,
 #                  live /metrics + /statusz under the query hammer,
@@ -114,9 +101,6 @@ elif [ "${1:-}" = "--serve-only" ]; then
 elif [ "${1:-}" = "--slo-only" ]; then
     shift
     MARKER='slo and not slow'
-elif [ "${1:-}" = "--blocking-only" ]; then
-    shift
-    MARKER='blocking and not slow'
 elif [ "${1:-}" = "--admission-only" ]; then
     shift
     MARKER='admission and not slow'
@@ -138,9 +122,6 @@ elif [ "${1:-}" = "--quality-only" ]; then
 elif [ "${1:-}" = "--mem-only" ]; then
     shift
     MARKER='mem and not slow'
-elif [ "${1:-}" = "--sharded2d-only" ]; then
-    shift
-    MARKER='sharded2d and not slow'
 elif [ "${1:-}" = "--tenancy-only" ]; then
     shift
     MARKER='tenancy and not slow'
