@@ -21,6 +21,7 @@ a bug we do not copy).
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from functools import partial
 
@@ -31,12 +32,16 @@ from jax import lax
 
 from graphmine_tpu.graph.container import Graph
 from graphmine_tpu.obs.spans import stage_span
+from graphmine_tpu.ops.bucketed_mode import (
+    _SENTINEL,
+    BucketedModePlan,
+    lpa_superstep_bucketed,
+)
 from graphmine_tpu.ops.segment import segment_mode
 
 
-@partial(jax.jit, static_argnames=("max_iter",))
 def masked_label_propagation(
-    graph: Graph, communities: jax.Array, max_iter: int = 5
+    graph: Graph, communities: jax.Array, max_iter: int = 5, plan=None
 ) -> jax.Array:
     """LPA restricted to intra-community edges, for all communities at once.
 
@@ -44,7 +49,33 @@ def masked_label_propagation(
     every community's induced subgraph (the dead spec at
     ``Graphframes.py:122-126``), because labels can only flow along
     messages whose endpoints share a community.
+
+    ``plan``: the graph's fused :class:`BucketedModePlan`, where the
+    caller still holds one (the pipeline does, from its LPA chapter):
+    the supersteps then reduce through the plan's degree-class rows,
+    masked once, instead of a gather and a sort over the messages. The
+    labels are the same bit for bit; ``None`` is the sort-based reference.
     """
+    return _masked_lpa(graph, communities, max_iter, plan)[0]
+
+
+def _masked_lpa(graph: Graph, communities, max_iter: int, plan):
+    """Labels and the count of slots the mask left, from the family the
+    inputs select: ``bucketed`` when a plan is there, ``sort`` when not."""
+    communities = jnp.asarray(communities, jnp.int32)
+    if plan is None:
+        return _masked_lpa_sort(graph, communities, max_iter=max_iter)
+    if plan.send_idx is None:
+        raise ValueError(
+            "the masked pass reads sender ids: it needs a fused plan "
+            "(build_graph_and_plan, from_edges, from_graph(with_send=True))"
+        )
+    return _masked_lpa_bucketed(graph, plan, communities, max_iter)
+
+
+@partial(jax.jit, static_argnames=("max_iter",))
+def _masked_lpa_sort(graph: Graph, communities: jax.Array, max_iter: int):
+    """The reference family: mask, gather and sort the messages."""
     v = graph.num_vertices
     with jax.named_scope("masked_lpa"), jax.named_scope("mask"):
         keep = communities[graph.msg_send] == communities[graph.msg_recv]
@@ -64,7 +95,76 @@ def masked_label_propagation(
         return new, None
 
     labels, _ = lax.scan(step, labels0, None, length=max_iter)
-    return labels
+    return labels, deg.sum(dtype=jnp.int32)
+
+
+_lpa_superstep = jax.jit(lpa_superstep_bucketed)
+
+
+def _masked_lpa_bucketed(graph: Graph, plan: BucketedModePlan, communities, max_iter):
+    """The plan family. The community mask goes onto the plan's index
+    rows once; the supersteps are then the LPA chapter's own compiled
+    superstep over the masked plan, unweighted whatever graph and plan
+    carry. Calling that program, not a scan of a second one over the same
+    rows, is what keeps the pass out of device memory: one superstep over
+    the pipeline cell's 65 degree classes is 80 MB of code on the chip,
+    and a scan of it with the mask in one program was 149 MB, resident
+    while the LOF chapter sets the job's peak (PERF.md, PR 30)."""
+    rows, hist_offset, hub_alive, kept = _mask_plan_rows(plan, communities)
+    masked = dataclasses.replace(
+        plan, send_idx=rows, hist_row_offset=hist_offset,
+        weight_mat=None, hist_weight=None,
+    )
+    if graph.msg_weight is not None:
+        graph = dataclasses.replace(graph, msg_weight=None)
+    labels = jnp.arange(plan.num_vertices, dtype=jnp.int32)
+    for _ in range(max_iter):
+        # refuses a plan of another graph, as it does for the LPA chapter
+        new = _lpa_superstep(labels, graph, masked)
+        labels = _keep_unreached(new, labels, plan.hist_vertex_ids, hub_alive)
+    return labels, kept
+
+
+@jax.jit
+def _mask_plan_rows(plan: BucketedModePlan, communities: jax.Array):
+    """The plan's index rows under the community mask: a cross-community
+    slot points at the sentinel slot of the padded label vector, where row
+    padding already points, and every width's reduce ignores it. A masked
+    hub message gets the row offset past the last histogram row, so its
+    flat index is out of range and dropped. Also which hubs keep a message
+    at all, and how many slots do."""
+    v = plan.num_vertices
+    with jax.named_scope("masked_lpa"), jax.named_scope("mask"):
+        comm_pad = jnp.concatenate([communities, jnp.zeros((1,), jnp.int32)])
+        rows, kept = [], jnp.int32(0)
+        for ids, idx in zip(plan.vertex_ids, plan.send_idx):
+            keep = (idx < v) & (comm_pad[idx] == communities[ids][:, None])
+            rows.append(jnp.where(keep, idx, v))
+            kept += keep.sum(dtype=jnp.int32)
+        hubs = plan.hist_vertex_ids
+        if hubs is None:
+            return tuple(rows), None, None, kept
+        n_hist = hubs.shape[0]
+        row = plan.hist_row_offset // v
+        keep = communities[plan.hist_send] == communities[hubs][row]
+        hist_offset = jnp.where(keep, plan.hist_row_offset, n_hist * v)
+        hub_alive = jnp.zeros((n_hist,), jnp.bool_).at[row].max(keep)
+        return tuple(rows), hist_offset, hub_alive, kept + keep.sum(dtype=jnp.int32)
+
+
+@jax.jit
+def _keep_unreached(new, labels, hubs, hub_alive):
+    """A vertex with no surviving message keeps its label. Its row reduced
+    to the sentinel, in every width's form; a hub's empty histogram gave
+    label 0, which plain LPA never sees and the masked pass can."""
+    with jax.named_scope("masked_lpa"), jax.named_scope("write_back"):
+        new = jnp.where(new == _SENTINEL, labels, new)
+        if hubs is not None:
+            new = new.at[hubs].set(
+                jnp.where(hub_alive, new[hubs], labels[hubs]),
+                unique_indices=True, mode="drop",
+            )
+    return new
 
 
 @dataclass(frozen=True)
@@ -80,7 +180,7 @@ class OutlierReport:
 
 def recursive_lpa_outliers(
     graph: Graph, communities: jax.Array, max_iter: int = 5,
-    decile: float = 0.1, sink=None,
+    decile: float = 0.1, sink=None, plan=None,
 ) -> OutlierReport:
     """Parity outlier detector (dead spec, ``Graphframes.py:121-137``).
 
@@ -88,15 +188,31 @@ def recursive_lpa_outliers(
     per-parent decile thresholds over the (tiny) sub-community size table.
     ``sink``: optional MetricsSink; the two sides are then the stage
     spans ``masked_lpa`` (to the fetched labels) and ``decile_report``.
+    ``plan``: the graph's fused plan, as :func:`masked_label_propagation`
+    takes it; the ``masked_lpa`` span says which family ran and how many
+    of its slots the mask left.
     """
-    with stage_span(sink, "masked_lpa"):
-        sub = np.asarray(
-            masked_label_propagation(graph, communities, max_iter=max_iter)
+    with stage_span(sink, "masked_lpa") as stage:
+        sub, kept = _masked_lpa(graph, communities, max_iter, plan)
+        sub = np.asarray(sub)
+        stage.note(
+            family="sort" if plan is None else "bucketed",
+            padded_slots=_padded_slots(graph, plan),
+            kept_slots=int(kept),
         )
     with stage_span(sink, "decile_report") as stage:
         report = _decile_report(sub, np.asarray(communities), decile)
         stage.note(sub_communities=len(report.sub_sizes))
     return report
+
+
+def _padded_slots(graph: Graph, plan) -> int:
+    """Slots one masked superstep reads: the messages themselves on the
+    sort family, the plan's padded rows and hub messages on the other."""
+    if plan is None:
+        return graph.num_messages
+    hubs = 0 if plan.hist_send is None else plan.hist_send.shape[0]
+    return sum(idx.size for idx in plan.send_idx) + hubs
 
 
 def recursive_lpa_outliers_sharded(

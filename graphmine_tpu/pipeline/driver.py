@@ -483,9 +483,11 @@ def _run_pipeline(config: PipelineConfig, m: MetricsSink) -> PipelineResult:
         else:
             from graphmine_tpu.ops.outliers import recursive_lpa_outliers
 
+            # the LPA chapter's fused plan, where it is still held (None
+            # after a degrade to sort: the masked pass then sorts too)
             scorer = lambda: recursive_lpa_outliers(
                 graph, labels, max_iter=config.sub_max_iter,
-                decile=config.decile, sink=m,
+                decile=config.decile, sink=m, plan=plan_holder[0],
             )
             timing_kv = {}
 

@@ -139,6 +139,17 @@ def test_lpa_superstep_bucketed_compiles_for_v5e(one_chip, fused_plan, planted):
     )
 
 
+def test_masked_lpa_plan_mask_compiles_for_v5e(one_chip, fused_plan, planted):
+    """The recursive outlier pass's one program of its own size: the
+    community mask over the plan's rows (ISSUE 30). Its supersteps are
+    ``lpa_superstep_bucketed``, compiled above."""
+    from graphmine_tpu.ops.outliers import _mask_plan_rows
+
+    _, plan = fused_plan
+    comm = jax.ShapeDtypeStruct((planted[2],), jnp.int32, sharding=one_chip)
+    _compile(_mask_plan_rows, _shapes(plan, one_chip), comm)
+
+
 def test_cc_superstep_bucketed_compiles_for_v5e(one_chip, fused_plan, planted):
     from graphmine_tpu.ops.cc import cc_superstep_bucketed
 

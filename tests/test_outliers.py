@@ -31,12 +31,26 @@ def test_intra_mask_matches_numpy(rng):
     np.testing.assert_array_equal(mask, l[src] == l[dst])
 
 
-def test_masked_lpa_stays_within_communities(rng):
+def _fused_plan(g):
+    from graphmine_tpu.ops.bucketed_mode import BucketedModePlan
+
+    return BucketedModePlan.from_graph(g, with_send=True)
+
+
+_FAMILIES = pytest.mark.parametrize(
+    "plan_of", [lambda g: None, _fused_plan], ids=["sort", "bucketed"]
+)
+
+
+@_FAMILIES
+def test_masked_lpa_stays_within_communities(rng, plan_of):
     src = rng.integers(0, 60, 300)
     dst = rng.integers(0, 60, 300)
     g = build_graph(src, dst, num_vertices=60)
     comm = label_propagation(g, max_iter=3)
-    sub = np.asarray(masked_label_propagation(g, comm, max_iter=5))
+    sub = np.asarray(
+        masked_label_propagation(g, comm, max_iter=5, plan=plan_of(g))
+    )
     comm_np = np.asarray(comm)
     # every sub-community is contained in exactly one parent community
     for s in np.unique(sub):
@@ -44,17 +58,129 @@ def test_masked_lpa_stays_within_communities(rng):
         assert len(np.unique(comm_np[members])) == 1
 
 
-def test_masked_lpa_equals_per_community_lpa():
+@_FAMILIES
+def test_masked_lpa_equals_per_community_lpa(plan_of):
     # Two disjoint triangles: masking with the 2-community partition must give
     # the same result as running LPA on each triangle separately.
     src = np.array([0, 1, 2, 3, 4, 5])
     dst = np.array([1, 2, 0, 4, 5, 3])
     g = build_graph(src, dst)
     comm = jnp.array([0, 0, 0, 1, 1, 1], jnp.int32)
-    sub = np.asarray(masked_label_propagation(g, comm, max_iter=4))
+    sub = np.asarray(
+        masked_label_propagation(g, comm, max_iter=4, plan=plan_of(g))
+    )
     ga = build_graph([0, 1, 2], [1, 2, 0])
     sub_a = np.asarray(label_propagation(ga, max_iter=4))
     assert (sub[:3] == sub_a).all()
+
+
+def _both_families(g, comm, max_iter=5, plan=None):
+    """The two families' reports on one graph, equal field for field; the
+    plan family's is handed back."""
+    ref = recursive_lpa_outliers(g, comm, max_iter=max_iter)
+    got = recursive_lpa_outliers(
+        g, comm, max_iter=max_iter, plan=plan or _fused_plan(g))
+    assert got.sub_labels.tobytes() == ref.sub_labels.tobytes()
+    np.testing.assert_array_equal(got.outlier_vertices, ref.outlier_vertices)
+    np.testing.assert_array_equal(got.sub_sizes, ref.sub_sizes)
+    np.testing.assert_array_equal(got.sub_parents, ref.sub_parents)
+    assert got.thresholds == ref.thresholds
+    return got
+
+
+@pytest.mark.parametrize("masked", ["all", "all_but_one"])
+@pytest.mark.parametrize("degree", [1, 2, 7, 42])  # the four row forms
+def test_masked_lpa_plan_row_with_no_surviving_message_keeps_its_label(
+    rng, degree, masked,
+):
+    """Vertex 0 sits in a community of its own with ``degree`` neighbours
+    (width 1 copies the slot, width 2 is ``min``, 7 counts pairwise, 42
+    sorts), all in the other community or all but one: plain LPA never
+    reduces a row of sentinels, the masked pass does."""
+    v = 120
+    src = rng.integers(1, v, 500)
+    dst = rng.integers(1, v, 500)
+    own = np.arange(1, degree + 1)
+    g = build_graph(
+        np.concatenate([src, np.zeros(degree, np.int64)]),
+        np.concatenate([dst, own]), num_vertices=v,
+    )
+    comm = np.ones(v, np.int32)
+    comm[0] = 0
+    if masked == "all_but_one":
+        comm[degree] = 0
+    plan = _fused_plan(g)
+    (row_class,) = [i for i, ids in enumerate(plan.vertex_ids) if 0 in np.asarray(ids)]
+    assert plan.send_idx[row_class].shape[1] == degree
+    report = _both_families(g, jnp.asarray(comm))
+    if masked == "all":
+        assert report.sub_labels[0] == 0
+
+
+@pytest.mark.parametrize("hub_community", ["alone", "half", "everyone"])
+def test_masked_lpa_plan_hub_histogram_respects_the_mask(rng, hub_community):
+    """A hub above the histogram threshold whose neighbours are all in
+    other communities (an empty histogram row: it keeps its label), half
+    in its own, or all in its own (nothing masked)."""
+    from graphmine_tpu.ops.bucketed_mode import _HIST_MIN_DEG
+
+    v = _HIST_MIN_DEG + 600
+    spokes = np.arange(1, _HIST_MIN_DEG + 200)
+    src = np.concatenate([np.zeros(len(spokes), np.int64), rng.integers(1, v, 6000)])
+    dst = np.concatenate([spokes, rng.integers(1, v, 6000)])
+    g = build_graph(src, dst, num_vertices=v)
+    plan = _fused_plan(g)
+    assert np.asarray(plan.hist_vertex_ids).tolist() == [0]
+    comm = (np.arange(v) % 3 + 1).astype(np.int32)
+    if hub_community == "alone":
+        comm[0] = 0
+    elif hub_community == "everyone":
+        comm[:] = 1
+    report = _both_families(g, jnp.asarray(comm))
+    if hub_community == "alone":
+        assert report.sub_labels[0] == 0
+
+
+def test_masked_lpa_plan_ignores_the_plan_s_weights(rng):
+    """The plan of a weighted graph carries ``weight_mat``; the recursive
+    pass is a count on both families."""
+    src = rng.integers(0, 200, 1200).astype(np.int32)
+    dst = rng.integers(0, 200, 1200).astype(np.int32)
+    w = (rng.integers(1, 16, 1200) / 4.0).astype(np.float32)
+    g = build_graph(src, dst, num_vertices=200, edge_weights=w)
+    assert _fused_plan(g).weight_mat is not None
+    comm = label_propagation(g, max_iter=3)
+    got = _both_families(g, comm, max_iter=4)
+    plain = build_graph(src, dst, num_vertices=200)
+    want = masked_label_propagation(plain, comm, max_iter=4)
+    assert got.sub_labels.tobytes() == np.asarray(want).tobytes()
+
+
+def test_masked_lpa_plan_on_a_graph_with_communities():
+    """Twelve planted blocks with 15 % of the edges across them, the LPA
+    chapter's own labels as the mask: the shape of the pipeline's call."""
+    from graphmine_tpu.ops.bucketed_mode import build_graph_and_plan
+
+    rng = np.random.default_rng(11)
+    v, e, block = 1536, 24000, 128
+    src = rng.integers(0, v, e)
+    near = (src // block) * block + rng.integers(0, block, e)
+    dst = np.where(rng.random(e) < 0.85, near, rng.integers(0, v, e))
+    g, plan = build_graph_and_plan(src, dst, num_vertices=v)
+    comm = label_propagation(g, max_iter=5, plan=plan)
+    assert len(_both_families(g, comm, plan=plan).sub_sizes) < v
+
+
+def test_masked_lpa_refuses_a_plan_of_another_graph_or_without_senders(rng):
+    from graphmine_tpu.ops.bucketed_mode import BucketedModePlan
+
+    g = build_graph(rng.integers(0, 30, 90), rng.integers(0, 30, 90), num_vertices=30)
+    other = build_graph(rng.integers(0, 30, 80), rng.integers(0, 30, 80), num_vertices=30)
+    comm = jnp.zeros(30, jnp.int32)
+    with pytest.raises(ValueError, match="plan/graph mismatch"):
+        masked_label_propagation(g, comm, plan=_fused_plan(other))
+    with pytest.raises(ValueError, match="fused plan"):
+        masked_label_propagation(g, comm, plan=BucketedModePlan.from_graph(g))
 
 
 def test_recursive_outliers_bundled(bundled_graph):

@@ -124,6 +124,34 @@ def test_one_superstep_of_each_family_carries_its_scopes(family, algorithm, want
             "/segment_mode/sort/" in n for n in names), sorted(names)
 
 
+@pytest.mark.parametrize("family,want", [
+    # its supersteps are the LPA chapter's program: lpa_bucketed/*, above
+    ("bucketed", {"masked_lpa/mask", "masked_lpa/write_back"}),
+    ("sort", {"masked_lpa/mask", "masked_lpa/msg_gather",
+              "masked_lpa/segment_mode", "masked_lpa/write_back"}),
+])
+def test_the_masked_pass_of_each_family_carries_its_scopes(family, want):
+    from graphmine_tpu.ops import outliers
+    from graphmine_tpu.ops.bucketed_mode import _HIST_MIN_DEG, BucketedModePlan
+
+    v = _HIST_MIN_DEG + 64  # vertex 0 is a hub: the histogram rows are there
+    rng = np.random.default_rng(5)
+    src = np.concatenate([np.zeros(v - 1, np.int64), rng.integers(1, v, 900)])
+    dst = np.concatenate([np.arange(1, v), rng.integers(1, v, 900)])
+    g = build_graph(src, dst, num_vertices=v)
+    comm = jnp.asarray(np.arange(v) % 2, jnp.int32)
+    if family == "sort":
+        names = _op_names(
+            lambda *a: outliers._masked_lpa_sort(*a, max_iter=2), g, comm)
+    else:
+        plan = BucketedModePlan.from_graph(g, with_send=True)
+        assert plan.hist_vertex_ids is not None
+        names = _op_names(outliers._mask_plan_rows, plan, comm) | _op_names(
+            outliers._keep_unreached, comm, comm, plan.hist_vertex_ids,
+            jnp.ones((1,), bool))
+    assert want <= _scopes_in(names), sorted(names)
+
+
 def test_the_loop_around_a_superstep_names_its_own_bookkeeping():
     from graphmine_tpu.ops.cc import _connected_components
     from graphmine_tpu.ops.lpa import _label_propagation
@@ -227,6 +255,19 @@ def test_run_pipeline_yields_every_stage_span_and_the_same_answers(
     (decile,) = _by_name(recs, "decile_report")
     assert chapter_of(masked) == chapter_of(decile) == "outliers_recursive_lpa"
     assert decile["sub_communities"] == len(res.outliers.sub_sizes)
+    # the masked pass ran on the LPA chapter's bucket rows; every message
+    # is a slot of them, and the mask left the intra-community ones
+    assert masked["family"] == "bucketed"
+    g = res.graph
+    intra = int((res.labels[np.asarray(g.msg_send)]
+                 == res.labels[np.asarray(g.msg_recv)]).sum())
+    assert masked["kept_slots"] == intra
+    assert g.num_messages <= masked["padded_slots"] <= 1.5 * g.num_messages
+    # ... through the LPA chapter's compiled superstep: the pass compiles a
+    # mask and a fix-up, and no superstep program of its own to hold in HBM
+    compiled = {r["fun_name"] for r in res.metrics.of_phase("compile")
+                if r["span_path"].startswith(masked["span_path"])}
+    assert not any("superstep" in name for name in compiled), compiled
 
     # LOF: every stage of the path that ran, with parent and counts
     (lof,) = _by_name(recs, "outliers_lof")
@@ -266,14 +307,29 @@ def test_run_pipeline_yields_every_stage_span_and_the_same_answers(
     from graphmine_tpu.ops.lpa import label_propagation
     from graphmine_tpu.ops.outliers import recursive_lpa_outliers
 
-    g = res.graph
     labels = np.asarray(label_propagation(g, max_iter=3))
     assert (labels == res.labels).all()
+    # no plan here: the sort family, against the pipeline's bucketed one
     sub = recursive_lpa_outliers(g, jnp.asarray(labels)).sub_labels
     assert (sub == res.outliers.sub_labels).all()
     scores = np.asarray(lof_scores(
         standardize(vertex_features(g, jnp.asarray(labels))), k=16))
     assert scores.tobytes() == np.asarray(res.lof).tobytes()
+
+
+def test_masked_lpa_span_names_the_sort_family_when_no_plan_is_passed():
+    from graphmine_tpu.ops.outliers import recursive_lpa_outliers
+
+    g = _graph()
+    sink = MetricsSink(tracer=Tracer())
+    comm = jnp.asarray(np.arange(g.num_vertices) % 3, jnp.int32)
+    recursive_lpa_outliers(g, comm, max_iter=2, sink=sink)
+    (masked,) = _by_name(sink.records, "masked_lpa")
+    assert masked["family"] == "sort"
+    assert masked["padded_slots"] == g.num_messages
+    comm_np = np.asarray(comm)
+    assert masked["kept_slots"] == int(
+        (comm_np[np.asarray(g.msg_send)] == comm_np[np.asarray(g.msg_recv)]).sum())
 
 
 def test_stage_span_without_a_sink_records_nothing_and_syncs_nothing():
