@@ -102,6 +102,14 @@ register("superstep_timing", "op", "family", "variant", "iteration",
          "predicted_edges_per_sec_per_chip", "achieved_fraction",
          "devices", "cost")
 
+# fixpoint: one per `connected_components(..., sink=)` call (ops/cc.py):
+# the supersteps the `lax.while_loop` took (the confirming pass included)
+# and the labels each one moved, from a fixed-size vector in the loop's
+# carry; `changed` is cut to `supersteps` (to 64 entries on a longer run).
+# Every pass reads all M messages whatever moved: these counts size a
+# frontier. Benchmark metric `wcc_quiet_pass_share` reads it.
+register("fixpoint", "op", "supersteps", "changed", "num_vertices", "family")
+
 # memory_watermark (ISSUE 14): predicted-vs-measured HBM/RSS for one
 # operating point, emitted by obs/memmodel.emit_memory_watermark (the
 # single builder) at the existing phase/rung/telemetry cadence — zero

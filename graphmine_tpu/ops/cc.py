@@ -19,9 +19,15 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from graphmine_tpu.graph.container import Graph
+
+# Slots of the per-superstep changed-label counts the fixpoint loop carries.
+# Pointer jumping keeps the passes far under this (5 on graph500-22); a
+# longer run keeps overwriting the last slot.
+_CHANGED_SLOTS = 64
 
 
 def cc_superstep(labels: jax.Array, graph: Graph) -> jax.Array:
@@ -120,7 +126,10 @@ def connected_components(
     ``build_graph_and_plan`` can pass their plan directly. ``sink``:
     optional MetricsSink — auto
     resolutions emit ``impl_selected`` + ``plan_build`` provenance
-    records (see ``label_propagation``).
+    records (see ``label_propagation``), and every call one ``fixpoint``
+    record: the supersteps it took and how many labels each one moved
+    (the last entry is the confirming pass's 0 unless ``max_iter`` cut
+    the run short).
     """
     if isinstance(plan, str) and plan == "auto":
         from graphmine_tpu.ops.lpa import _cached_auto_plan
@@ -155,7 +164,7 @@ def connected_components(
             timed_fixpoint,
         )
 
-        (labels, iters), secs, cold = timed_fixpoint(
+        (labels, iters, changed), secs, cold = timed_fixpoint(
             lambda: _connected_components(graph, max_iter, True, plan),
         )
         iters = int(iters)
@@ -170,10 +179,16 @@ def connected_components(
             sink, "cc_superstep", cost, iters, iters, secs,
             graph.num_edges, variant="fused", cold_compile=cold,
         )
+        sink.emit(
+            "fixpoint", op="cc_superstep", supersteps=iters,
+            changed=np.asarray(changed)[:iters].tolist(),
+            num_vertices=graph.num_vertices, family=cost.family,
+        )
         if return_iterations:
             return labels, iters
         return labels
-    return _connected_components(graph, max_iter, return_iterations, plan)
+    out = _connected_components(graph, max_iter, return_iterations, plan)
+    return out[:2] if return_iterations else out  # the counts are the sink's
 
 
 @partial(jax.jit, static_argnames=("max_iter", "return_iterations"))
@@ -184,24 +199,29 @@ def _connected_components(
     limit = max_iter if max_iter > 0 else graph.num_vertices + 2
 
     def cond(state):
-        labels, prev_changed, it = state
+        labels, prev_changed, it, _ = state
         with jax.named_scope("superstep"), jax.named_scope("converged"):
             return (prev_changed > 0) & (it < limit)
 
     def body(state):
-        labels, _, it = state
+        labels, _, it, per_step = state
         if plan is None:
             new = cc_superstep(labels, graph)
         else:
             new = cc_superstep_bucketed(labels, plan)
         with jax.named_scope("superstep"), jax.named_scope("changed_count"):
             changed = jnp.sum(new != labels, dtype=jnp.int32)
-        return new, changed, it + 1
+            per_step = per_step.at[jnp.minimum(it, _CHANGED_SLOTS - 1)].set(
+                changed
+            )
+        return new, changed, it + 1, per_step
 
     labels0 = jnp.arange(graph.num_vertices, dtype=jnp.int32)
-    labels, _, iters = lax.while_loop(
-        cond, body, (labels0, jnp.int32(1), jnp.int32(0))
+    labels, _, iters, per_step = lax.while_loop(
+        cond, body,
+        (labels0, jnp.int32(1), jnp.int32(0),
+         jnp.zeros((_CHANGED_SLOTS,), jnp.int32)),
     )
     if return_iterations:
-        return labels, iters
+        return labels, iters, per_step
     return labels
