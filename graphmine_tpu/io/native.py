@@ -139,6 +139,17 @@ def _bind(lib: ctypes.CDLL) -> None:
         ctypes.POINTER(ctypes.POINTER(ctypes.c_int32)),
         ctypes.POINTER(ctypes.POINTER(ctypes.c_float)),
     ]
+    # Positions grouped by key (PR 32). Absent from older builds.
+    if not hasattr(lib, "gb_positions_by_key"):
+        return
+    lib.gb_positions_by_key.restype = None
+    lib.gb_positions_by_key.argtypes = [
+        np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+        ctypes.c_int64,
+        ctypes.c_int64,
+        np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS"),
+        np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+    ]
 
 
 def available() -> bool:
@@ -335,3 +346,20 @@ def build_message_csr(src, dst, num_vertices: int, symmetric: bool = True,
         ptr, recv_sorted[:m], send_sorted[:m],
         None if w_sorted is None else w_sorted[:m],
     )
+
+
+def positions_by_key(keys, num_keys: int):
+    """``(ptr int64 [num_keys + 1], out int32)``: for each key ``k`` in
+    ``[0, num_keys)`` the positions ``i`` with ``keys[i] == k``, ascending,
+    at ``out[ptr[k]:ptr[k + 1]]``; keys outside the range name nothing. A
+    stable counting sort in threads, O(n + num_keys) against a NumPy
+    stable argsort's O(n log n). ``None`` when the library (or this entry
+    point) is unavailable."""
+    lib = _lib()
+    if lib is None or not hasattr(lib, "gb_positions_by_key"):
+        return None
+    keys = np.ascontiguousarray(keys, dtype=np.int32)
+    ptr = np.empty(num_keys + 1, dtype=np.int64)
+    out = np.empty(max(len(keys), 1), dtype=np.int32)
+    lib.gb_positions_by_key(keys, len(keys), num_keys, ptr, out)
+    return ptr, out[:int(ptr[-1])]

@@ -110,6 +110,18 @@ register("superstep_timing", "op", "family", "variant", "iteration",
 # frontier. Benchmark metric `wcc_quiet_pass_share` reads it.
 register("fixpoint", "op", "supersteps", "changed", "num_vertices", "family")
 
+# superstep_delta: one per `label_propagation(..., sink=)` call that ran
+# the carried-rows scan (ops/lpa.py: a fused plan with its slot index, one
+# chip). Per superstep, in order: `branch`, how the superstep brought its
+# rows up to date ("full": every class gathered anew; a number: the rung,
+# the static cap of the slots rewritten through the index), and what it
+# left for the next one: `changed_vertices`, the labels it moved, and
+# `changed_messages` (K), the messages those vertices send, which picks
+# the next branch. `rungs` are `ops/superstep_policy.delta_rungs(M)`. From
+# int32[max_iter] outputs of the scan, read after the labels: no sync.
+register("superstep_delta", "op", "changed_vertices", "changed_messages",
+         "branch", "rungs", "num_messages")
+
 # memory_watermark (ISSUE 14): predicted-vs-measured HBM/RSS for one
 # operating point, emitted by obs/memmodel.emit_memory_watermark (the
 # single builder) at the existing phase/rung/telemetry cadence — zero
@@ -315,6 +327,8 @@ DEVICE_SCOPES = frozenset((
     "hist", "write_back", "pointer_jump", "msg_gather", "segment_mode",
     "segment_min", "sort", "run_reduce", "mask", "exchange",
     "changed_count", "converged",
+    # carried rows (ops/bucketed_mode.rewrite_rows): outer, then its passes
+    "delta", "compact", "expand", "scatter",
     # inner: census / modularity
     "sizes", "edge_counts", "q",
     # inner: features / triangles

@@ -153,6 +153,14 @@ class BucketedModePlan:
     # send_idx/msg_idx (padding = 0), plus the hub messages' weights.
     weight_mat: tuple | None = None
     hist_weight: jax.Array | None = None
+    # Slot index by sender (fused plans; added by with_slot_index for the
+    # carried-rows scan of ops/lpa.py, never by the builders below): with
+    # the classes' [n_b, w_b] rows laid end to end as one flat int32[S]
+    # buffer, sender s's label sits in the flat slots
+    # out_slot[out_ptr[s]:out_ptr[s + 1]]. A message a histogram hub
+    # receives has no slot and maps to S.
+    out_ptr: jax.Array | None = None
+    out_slot: jax.Array | None = None
 
     @classmethod
     def from_graph(cls, graph: Graph, with_send: bool = False) -> "BucketedModePlan":
@@ -328,6 +336,14 @@ def build_graph_and_plan(
     )
 
 
+def _with_sentinel(values: jax.Array) -> jax.Array:
+    """``values`` as int32 with the sentinel appended: what a padding
+    index (``len(values)``) gathers."""
+    return jnp.concatenate(
+        [values.astype(jnp.int32), jnp.full((1,), _SENTINEL, jnp.int32)]
+    )
+
+
 def _rowwise_mode(lbl: jax.Array) -> jax.Array:
     """Mode of each row of a ``[n, w]`` int32 matrix; sentinel entries
     ignored; ties break toward the smallest value. Rows must contain at
@@ -487,9 +503,7 @@ def bucketed_mode(plan: BucketedModePlan, messages: jax.Array, fallback: jax.Arr
             f"plan built for M={plan.num_messages}, V={plan.num_vertices} but got "
             f"M={messages.shape[0]}, V={fallback.shape[0]} — plan/graph mismatch"
         )
-    msgs_pad = jnp.concatenate(
-        [messages.astype(jnp.int32), jnp.full((1,), _SENTINEL, jnp.int32)]
-    )
+    msgs_pad = _with_sentinel(messages)
     wmats = (
         plan.weight_mat
         if weights == "plan" and plan.weight_mat is not None
@@ -509,13 +523,18 @@ def _row_modes(values_pad, out, vertex_ids, row_idx, wmats):
         width = f"w{idx.shape[1]}"
         with jax.named_scope("row_gather"), jax.named_scope(width):
             mat = values_pad[idx]
-        with jax.named_scope("row_mode"), jax.named_scope(width):
-            mode = (
-                _bucket_mode(mat) if wmat is None else _bucket_wmode(mat, wmat)
-            )
-        with jax.named_scope("write_back"):
-            out = out.at[ids].set(mode, unique_indices=True, mode="drop")
+        out = _reduce_rows(out, ids, mat, wmat)
     return out
+
+
+def _reduce_rows(out, ids, mat, wmat):
+    """One class's reduce: the row-wise mode of its dense rows ``mat``,
+    written to the class's vertices ``ids`` in ``out``."""
+    width = f"w{mat.shape[1]}"
+    with jax.named_scope("row_mode"), jax.named_scope(width):
+        mode = _bucket_mode(mat) if wmat is None else _bucket_wmode(mat, wmat)
+    with jax.named_scope("write_back"):
+        return out.at[ids].set(mode, unique_indices=True, mode="drop")
 
 
 def lpa_superstep_bucketed(
@@ -533,22 +552,8 @@ def lpa_superstep_bucketed(
     carries slot-aligned weight matrices and the row modes become argmax
     of per-label weight sums (ties toward the smallest label, matching
     ``segment_mode(weights=...)``)."""
-    if graph.msg_weight is not None and plan.weight_mat is None:
-        raise ValueError(
-            "graph carries msg_weight but the plan has no weight payload; "
-            "build it with build_graph_and_plan(edge_weights=...), "
-            "BucketedModePlan.from_graph, or from_ptr(weights_sorted=...)"
-        )
+    check_plan_fits(labels, graph, plan)
     if plan.send_idx is not None:
-        if (
-            labels.shape[0] != plan.num_vertices
-            or graph.num_messages != plan.num_messages
-        ):
-            raise ValueError(
-                f"plan built for V={plan.num_vertices}, M={plan.num_messages} "
-                f"but got V={labels.shape[0]}, M={graph.num_messages} — "
-                "plan/graph mismatch"
-            )
         with jax.named_scope("lpa_bucketed"):
             return _lpa_superstep_fused(labels, plan)
     with jax.named_scope("lpa_bucketed"):
@@ -557,17 +562,40 @@ def lpa_superstep_bucketed(
         return bucketed_mode(plan, msg, labels)
 
 
+def check_plan_fits(labels: jax.Array, graph: Graph, plan: BucketedModePlan):
+    """Raise unless ``plan`` was built for this graph's shapes and weights
+    (a fused plan reads nothing of the graph, so nothing else would)."""
+    if graph.msg_weight is not None and plan.weight_mat is None:
+        raise ValueError(
+            "graph carries msg_weight but the plan has no weight payload; "
+            "build it with build_graph_and_plan(edge_weights=...), "
+            "BucketedModePlan.from_graph, or from_ptr(weights_sorted=...)"
+        )
+    if plan.send_idx is not None and (
+        labels.shape[0] != plan.num_vertices
+        or graph.num_messages != plan.num_messages
+    ):
+        raise ValueError(
+            f"plan built for V={plan.num_vertices}, M={plan.num_messages} "
+            f"but got V={labels.shape[0]}, M={graph.num_messages} — "
+            "plan/graph mismatch"
+        )
+
+
 def _lpa_superstep_fused(labels: jax.Array, plan: BucketedModePlan):
     """The fused superstep body: every degree class gathers its senders'
     labels straight from the padded label vector."""
-    lbl_pad = jnp.concatenate(
-        [labels.astype(jnp.int32), jnp.full((1,), _SENTINEL, jnp.int32)]
-    )
+    lbl_pad = _with_sentinel(labels)
     wmats = plan.weight_mat or (None,) * len(plan.vertex_ids)
     out = _row_modes(
         lbl_pad, labels.astype(jnp.int32), plan.vertex_ids, plan.send_idx,
         wmats,
     )
+    return _hist_modes(labels, out, plan)
+
+
+def _hist_modes(labels: jax.Array, out: jax.Array, plan: BucketedModePlan):
+    """The histogram hubs' modes of ``labels``, written over ``out``."""
     if plan.hist_vertex_ids is not None:
         # Mega-hub mode: per-hub label histogram + argmax. Exact slot
         # count (no padding), no wide sort; argmax's first-max rule is
@@ -600,3 +628,152 @@ def _lpa_superstep_fused(labels: jax.Array, plan: BucketedModePlan):
                 modes, unique_indices=True, mode="drop"
             )
     return out
+
+
+# ---- carried rows: the gathered message rows as state across supersteps ----
+#
+# A class's gathered rows depend on the labels only through the senders
+# behind their slots, so a scan that keeps the rows (ops/lpa.py) has to
+# read again only what the senders whose label changed wrote. The rows of
+# all classes live end to end in one flat int32[S] buffer; padding slots
+# hold the sentinel from the first full gather on and are never written
+# again. Measured on a TPU v5e the row gather is bound by issue per index
+# (116-151 M slots/s whatever the table, PERF.md §7.4), so the lever is the
+# count of indices and not the bytes.
+
+
+def row_slots(plan: BucketedModePlan) -> int:
+    """S: the padded slots of the plan's dense rows (fused plans)."""
+    return sum(idx.shape[0] * idx.shape[1] for idx in plan.send_idx)
+
+
+def _positions_by_key(keys: np.ndarray, num_keys: int):
+    """``(ptr int64 [num_keys + 1], out int32)``: positions of ``keys``
+    grouped by key, ascending inside a key; keys outside ``[0, num_keys)``
+    name nothing. The native counting sort when the library is there, else
+    a NumPy stable argsort (the same layout, tested)."""
+    from graphmine_tpu.io import native
+
+    out = native.positions_by_key(keys, num_keys)
+    if out is not None:
+        return out
+    pos = np.nonzero((keys >= 0) & (keys < num_keys))[0]
+    kept = keys[pos]
+    ptr = np.zeros(num_keys + 1, np.int64)
+    np.cumsum(np.bincount(kept, minlength=num_keys), out=ptr[1:])
+    return ptr, pos[np.argsort(kept, kind="stable")].astype(np.int32)
+
+
+def with_slot_index(plan: BucketedModePlan) -> BucketedModePlan:
+    """The fused ``plan`` with its slot index by sender (``out_ptr``,
+    ``out_slot``): the transpose of ``send_idx``, from one stable counting
+    sort of the senders behind the padded slots. It reads the plan and
+    nothing of the graph, so it is as right for ``symmetric=False`` and
+    weighted plans (weights are slot-aligned and never move). A plan with
+    no dense rows, or with more slots than an int32 counts, comes back as
+    it is: there is nothing to carry, or no index to carry it by."""
+    if plan.send_idx is None:
+        raise ValueError("the slot index needs a fused plan (send_idx)")
+    s = row_slots(plan)
+    if s == 0 or s >= np.iinfo(np.int32).max:
+        return plan
+    keys = [np.asarray(idx).reshape(-1) for idx in plan.send_idx]
+    if plan.hist_send is not None:
+        keys.append(np.asarray(plan.hist_send))  # positions from S on
+    ptr, slot = _positions_by_key(np.concatenate(keys), plan.num_vertices)
+    np.minimum(slot, s, out=slot)  # a hub's message has no slot: S
+    return dataclasses.replace(
+        plan, out_ptr=jnp.asarray(ptr.astype(np.int32)),
+        out_slot=jnp.asarray(slot),
+    )
+
+
+def gather_rows(rows: jax.Array, labels: jax.Array, plan: BucketedModePlan):
+    """Every class's rows gathered anew from ``labels`` into the flat
+    buffer ``rows``: what :func:`_row_modes` gathers, kept."""
+    lbl_pad = _with_sentinel(labels)
+    off = 0
+    with jax.named_scope("lpa_bucketed"):
+        for idx in plan.send_idx:
+            width = f"w{idx.shape[1]}"
+            with jax.named_scope("row_gather"), jax.named_scope(width):
+                rows = lax.dynamic_update_slice(
+                    rows, lbl_pad[idx].reshape(-1), (off,)
+                )
+            off += idx.shape[0] * idx.shape[1]
+    return rows
+
+
+def rewrite_rows(
+    rows: jax.Array, labels: jax.Array, changed: jax.Array,
+    plan: BucketedModePlan, cap: int,
+):
+    """The slots of ``rows`` behind the ``changed`` senders rewritten with
+    their ``labels``; every other slot stays. ``cap`` (static) bounds the
+    messages the changed senders send, the caller's promise: the rows then
+    equal :func:`gather_rows`'s slot for slot.
+
+    Two per-index passes over ``cap`` (read ``out_slot``, scatter the
+    label) instead of one over every slot of the graph. The changed
+    senders are compacted by one sort that carries their ``out_ptr`` span
+    and label with the key (at most ``min(cap, V)`` of them: each sends a
+    message); the spans are laid end to end, and each span's offset into
+    ``out_slot`` and its sender's label are spread over the span by one
+    scattered difference at the span's start and a ``cumsum``, so nothing
+    is looked up per slot but the slot itself. Measured on a TPU v5e at
+    V = 2^22 (PERF.md §6, PR 32): the sort 12-16 ms whatever ``cap``, a
+    read of ``out_slot`` 23 ns and a scatter into the rows 8 ns a place
+    of ``cap``."""
+    v, m, s = plan.num_vertices, plan.out_slot.shape[0], rows.shape[0]
+    senders = min(cap, v)
+    out_deg = plan.out_ptr[1:] - plan.out_ptr[:-1]
+    with jax.named_scope("delta"):
+        with jax.named_scope("compact"):
+            send = changed & (out_deg > 0)
+            key = jnp.where(send, jnp.arange(v, dtype=jnp.int32), v)
+            _, start, count, label = (
+                x[:senders] for x in lax.sort(
+                    (key, plan.out_ptr[:-1], jnp.where(send, out_deg, 0),
+                     labels.astype(jnp.int32)),
+                    num_keys=1,
+                )
+            )
+        with jax.named_scope("expand"):
+            end = jnp.cumsum(count)
+            first = end - count  # a span's first place; the last ends at K
+            at = jnp.where(count > 0, first, cap)  # past the senders: dropped
+
+            def spread(per_sender):
+                step = jnp.diff(per_sender, prepend=jnp.zeros((1,), jnp.int32))
+                return jnp.cumsum(
+                    jnp.zeros((cap,), jnp.int32).at[at].add(step, mode="drop")
+                )
+
+            place = jnp.arange(cap, dtype=jnp.int32)
+            # place p of a span that starts at `first` reads out_slot[p + skip]
+            source = place + spread(start - first)
+            value = spread(label)
+        with jax.named_scope("scatter"):
+            # past the last span lie other senders' slots: name none
+            slot = jnp.where(
+                place < end[-1], plan.out_slot[jnp.clip(source, 0, m - 1)], s
+            )
+            return rows.at[slot].set(value, mode="drop")
+
+
+def lpa_modes_from_rows(
+    rows: jax.Array, labels: jax.Array, plan: BucketedModePlan
+) -> jax.Array:
+    """The superstep's reduce over carried rows: the new labels
+    :func:`lpa_superstep_bucketed` would give from ``labels`` when ``rows``
+    hold those labels' gathered rows."""
+    wmats = plan.weight_mat or (None,) * len(plan.vertex_ids)
+    out, off = labels.astype(jnp.int32), 0
+    with jax.named_scope("lpa_bucketed"):
+        for ids, idx, wmat in zip(plan.vertex_ids, plan.send_idx, wmats):
+            n = idx.shape[0] * idx.shape[1]
+            out = _reduce_rows(
+                out, ids, rows[off:off + n].reshape(idx.shape), wmat
+            )
+            off += n
+        return _hist_modes(labels, out, plan)

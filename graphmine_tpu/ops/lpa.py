@@ -78,11 +78,21 @@ def label_propagation(
     don't) or under an enclosing jit trace, where host plan construction
     is impossible. Pass ``None`` to force the sort-based superstep.
 
+    With a fused plan (``"auto"``'s, or one with ``send_idx``) the
+    supersteps run as :func:`_carried_rows_scan`: the gathered message
+    rows are state of the scan, and a superstep that follows few changed
+    labels rewrites only the slots behind their senders, through a slot
+    index built once per plan (:func:`_cached_slot_index`). Labels are
+    bit-identical to the stateless supersteps'; nothing selects it but
+    what each superstep counts.
+
     ``sink``: optional MetricsSink — each auto resolution emits an
     ``impl_selected`` record, and each plan materialization a
-    ``plan_build`` record (family, build seconds, width classes, padded
-    slots/edge), so host plan cost is visible in obs_report instead of
-    hiding inside first-call latency.
+    ``plan_build`` record (family, build seconds with the slot index's,
+    width classes, padded slots/edge), so host plan cost is visible in
+    obs_report instead of hiding inside first-call latency; a
+    carried-rows job one ``superstep_delta`` record (per superstep: the
+    branch taken, the labels moved, the messages their vertices send).
 
     ``mesh``: a ``jax.sharding.Mesh`` runs the job across its devices
     (``None`` is the one-device path above, byte for byte). The graph
@@ -122,6 +132,8 @@ def label_propagation(
                 # Weighted graphs ride the fast path too (r2): the plan
                 # carries the slot-aligned weight payload.
                 plan, seconds, cached = _cached_auto_plan(graph)
+                plan, index_seconds = _cached_slot_index(plan)
+                seconds += index_seconds
             emit_plan_records(
                 sink, "lpa_superstep", plan, reason, seconds, cached,
                 graph.num_edges, graph.num_messages,
@@ -131,6 +143,12 @@ def label_propagation(
         raise ValueError(
             f"plan must be 'auto', None or a BucketedModePlan; got {plan!r}"
         )
+    elif (
+        plan is not None
+        and plan.send_idx
+        and not isinstance(plan.send_idx[0], jax.core.Tracer)
+    ):
+        plan, _ = _cached_slot_index(plan)
     if (
         isinstance(plan, BucketedModePlan)
         and plan.hist_vertex_ids is not None
@@ -162,10 +180,8 @@ def label_propagation(
             timed_fixpoint,
         )
 
-        out, secs, cold = timed_fixpoint(
-            lambda: _label_propagation(
-                graph, max_iter, init_labels, return_history, plan
-            ),
+        (labels, per_step), secs, cold = timed_fixpoint(
+            lambda: _label_propagation(graph, max_iter, init_labels, plan),
         )
         cost = superstep_cost(
             "lpa_superstep",
@@ -177,8 +193,32 @@ def label_propagation(
             sink, "lpa_superstep", cost, max_iter, max_iter, secs,
             graph.num_edges, variant="fused", cold_compile=cold,
         )
-        return out
-    return _label_propagation(graph, max_iter, init_labels, return_history, plan)
+        if "branch" in per_step:
+            _emit_superstep_delta(sink, per_step, plan.num_messages)
+    else:
+        labels, per_step = _label_propagation(
+            graph, max_iter, init_labels, plan
+        )
+    if return_history:
+        return labels, per_step["changed_vertices"]
+    return labels
+
+
+def _emit_superstep_delta(sink, per_step: dict, num_messages: int) -> None:
+    """The ``superstep_delta`` record of one carried-rows job, from the
+    scan's per-superstep outputs (the job's labels are already back)."""
+    import numpy as np
+
+    from graphmine_tpu.ops.superstep_policy import delta_rungs
+
+    names = [*delta_rungs(num_messages), "full"]
+    sink.emit(
+        "superstep_delta", op="lpa_superstep",
+        changed_vertices=np.asarray(per_step["changed_vertices"]).tolist(),
+        changed_messages=np.asarray(per_step["changed_messages"]).tolist(),
+        branch=[names[b] for b in np.asarray(per_step["branch"]).tolist()],
+        rungs=names[:-1], num_messages=num_messages,
+    )
 
 
 _auto_plan_cache: dict = {}
@@ -208,6 +248,40 @@ def _cached_auto_plan(graph: Graph):
     )
     _auto_plan_cache[key] = (ref, plan)
     return plan, seconds, False
+
+
+_slot_index_cache: dict = {}
+
+
+def _cached_slot_index(plan):
+    """``(plan with its slot index, build seconds)``: the index of the
+    carried-rows scan (:func:`~graphmine_tpu.ops.bucketed_mode.
+    with_slot_index`), paid once per fused plan as the plan is paid once
+    per graph (0.0 seconds on a hit). Keyed by the identity of the plan's
+    first row matrix; a weakref finalizer evicts the entry with it. The
+    index stays out of the plan the cache of :func:`_cached_auto_plan`
+    holds: ``connected_components`` shares that plan and reads no index."""
+    import dataclasses
+    import weakref
+
+    from graphmine_tpu.ops.bucketed_mode import with_slot_index
+    from graphmine_tpu.ops.superstep_policy import timed_plan_build
+
+    if plan.out_slot is not None or not plan.send_idx:
+        return plan, 0.0
+    anchor = plan.send_idx[0]
+    key = id(anchor)
+    hit = _slot_index_cache.get(key)
+    seconds = 0.0
+    if hit is None or hit[0]() is not anchor:
+        indexed, seconds = timed_plan_build(lambda: with_slot_index(plan))
+        # the index alone: a cached plan would keep its own anchor alive
+        hit = (
+            weakref.ref(anchor, lambda _, k=key: _slot_index_cache.pop(k, None)),
+            indexed.out_ptr, indexed.out_slot,
+        )
+        _slot_index_cache[key] = hit
+    return dataclasses.replace(plan, out_ptr=hit[1], out_slot=hit[2]), seconds
 
 
 _mesh_partition_cache: dict = {}
@@ -329,19 +403,28 @@ def _cached_mesh_partition(graph: Graph, mesh, family: str):
     return sg, stats, False
 
 
-@partial(jax.jit, static_argnames=("max_iter", "return_history"))
+@partial(jax.jit, static_argnames=("max_iter",))
 def _label_propagation(
     graph: Graph,
     max_iter: int = 5,
     init_labels: jax.Array | None = None,
-    return_history: bool = False,
     plan=None,
 ):
+    """``(labels, per_step)``: all ``max_iter`` supersteps as one
+    ``lax.scan``. ``per_step`` holds ``int32[max_iter]`` vectors:
+    ``changed_vertices`` always, and with carried rows (a fused plan with
+    its slot index, :func:`_carried_rows_scan`) ``changed_messages`` and
+    ``branch`` too."""
     labels = (
         jnp.arange(graph.num_vertices, dtype=jnp.int32)
         if init_labels is None
         else init_labels.astype(jnp.int32)
     )
+    if plan is not None and plan.out_slot is not None:
+        from graphmine_tpu.ops.bucketed_mode import check_plan_fits
+
+        check_plan_fits(labels, graph, plan)
+        return _carried_rows_scan(labels, plan, max_iter)
 
     if plan is None:
         superstep = lambda lbl: lpa_superstep(lbl, graph)
@@ -357,9 +440,59 @@ def _label_propagation(
         return new, changed
 
     labels, changed = lax.scan(step, labels, None, length=max_iter)
-    if return_history:
-        return labels, changed
-    return labels
+    return labels, {"changed_vertices": changed}
+
+
+def _carried_rows_scan(labels: jax.Array, plan, max_iter: int):
+    """The supersteps over a fused plan with its slot index: the gathered
+    rows are carried state, and a superstep reads again only what changed.
+
+    Each superstep first brings the rows up to the labels it starts from,
+    by the branch its predecessor's count picks: K, the messages sent by
+    the vertices whose label changed. K above every rung of
+    :func:`~graphmine_tpu.ops.superstep_policy.delta_rungs` gathers every
+    class anew (the first superstep always: the carry starts at M + 1, so
+    the classes' gathers are in the program once); K <= a rung rewrites
+    that many slots through the index. Then the row modes, the histogram
+    hubs and the write back run over the rows, as in
+    ``lpa_superstep_bucketed``: the labels are its labels bit for bit."""
+    from graphmine_tpu.ops.bucketed_mode import (
+        gather_rows,
+        lpa_modes_from_rows,
+        rewrite_rows,
+        row_slots,
+    )
+    from graphmine_tpu.ops.superstep_policy import delta_rungs
+
+    rungs = delta_rungs(plan.num_messages)
+    out_deg = plan.out_ptr[1:] - plan.out_ptr[:-1]
+    branches = [
+        partial(rewrite_rows, plan=plan, cap=rung) for rung in rungs
+    ] + [lambda rows, labels, changed: gather_rows(rows, labels, plan)]
+
+    def step(carry, _):
+        labels, rows, changed, k = carry
+        branch = jnp.sum(k > jnp.array(rungs, jnp.int32), dtype=jnp.int32)
+        rows = lax.switch(branch, branches, rows, labels, changed)
+        new = lpa_modes_from_rows(rows, labels, plan)
+        with jax.named_scope("superstep"), jax.named_scope("changed_count"):
+            changed = new != labels
+            k = jnp.sum(jnp.where(changed, out_deg, 0), dtype=jnp.int32)
+            count = jnp.sum(changed, dtype=jnp.int32)
+        return (new, rows, changed, k), (count, k, branch)
+
+    carry = (
+        labels,
+        jnp.zeros((row_slots(plan),), jnp.int32),
+        jnp.zeros(labels.shape, bool),
+        jnp.int32(plan.num_messages + 1),
+    )
+    (labels, _, _, _), (count, k, branch) = lax.scan(
+        step, carry, None, length=max_iter
+    )
+    return labels, {
+        "changed_vertices": count, "changed_messages": k, "branch": branch,
+    }
 
 
 def num_communities(labels: jax.Array) -> jax.Array:

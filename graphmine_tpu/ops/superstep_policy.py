@@ -44,6 +44,32 @@ BUCKETED_MIN_MESSAGES = 1 << 16
 # the families, fast to lean: the keys of the one degrade order
 FAMILIES = tuple(FAMILY_DEGRADE)
 
+# Carried rows (PR 32): the one-chip LPA scan keeps the gathered rows and,
+# when the senders whose label changed send K <= a rung messages, rewrites
+# K slots (padded to the rung: a static shape) instead of gathering all S.
+# A rung is M over a divisor; K above the last rung takes the full gather.
+# Measured on a TPU v5e, V = 2^22, M = 128.3 M, S = 137.8 M (PERF.md §6,
+# PR 32): the full gather 0.970 s a superstep; a rewrite 0.012 s for the
+# sort that compacts the changed senders whatever the rung, 0.03-0.09 s to
+# lay their spans out, then 21.2 ns a place of the rung to read `out_slot`
+# (fusion s32[21384447] 0.454 s) and 8.3 ns to scatter the label, so a rung
+# of R costs about 0.10 s + 29.5 ns x R and meets the full gather at
+# R = 29.5 M = M / 4.35. The top rung stands at three quarters of that
+# crossover (M / 6: 0.73 s), so the worst K under it still repays the
+# switch; the rungs below step by 16, and the lowest two cost 0.02 and
+# 0.05 s, under the 0.13 s of row modes and write back that every
+# superstep pays either way.
+DELTA_RUNG_DIVISORS = (4096, 256, 16, 6)
+
+
+def delta_rungs(num_messages: int) -> tuple:
+    """The rungs of the carried-rows scan, ascending: the static caps on
+    the messages a sparse superstep rewrites (none on a graph too small
+    to have one: every superstep then gathers in full)."""
+    return tuple(sorted(
+        {num_messages // d for d in DELTA_RUNG_DIVISORS} - {0}
+    ))
+
 
 def crossover_thresholds() -> dict:
     """The family-crossover constants that decide every ``plan="auto"``

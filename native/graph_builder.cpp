@@ -13,6 +13,7 @@
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
+#include <functional>
 #include <new>
 #include <string>
 #include <string_view>
@@ -322,6 +323,30 @@ namespace {
 // the layout is the single-threaded one byte for byte — while the random
 // writes of a thread stay inside 1/T of the arrays instead of all of them
 // (at 10^9 messages the one-thread sort is a cache miss per message).
+// Threads over ranges of a key space [0, v), for a counting sort of n
+// items: every thread reads all the items and owns the keys of one range.
+struct KeyRanges {
+  int64_t v;
+  int64_t threads = 1;
+  KeyRanges(int64_t n, int64_t v) : v(v) {
+    if (n >= (int64_t{1} << 22)) {
+      threads = static_cast<int64_t>(std::thread::hardware_concurrency());
+      threads = std::max<int64_t>(1, std::min<int64_t>(threads, 16));
+      threads = std::min(threads, std::max<int64_t>(v, 1));
+    }
+  }
+  int64_t bound(int64_t t) const { return v * t / threads; }
+  // body(lo, hi) once per range, the first on the calling thread
+  void run(const std::function<void(int64_t, int64_t)>& body) const {
+    std::vector<std::thread> pool;
+    for (int64_t t = 1; t < threads; ++t) {
+      pool.emplace_back(body, bound(t), bound(t + 1));
+    }
+    body(bound(0), bound(1));
+    for (auto& th : pool) th.join();
+  }
+};
+
 int build_csr_impl(const int32_t* src, const int32_t* dst,
                    const float* weights, int64_t e, int64_t v, int symmetric,
                    int64_t* ptr, int32_t* recv_sorted, int32_t* send_sorted,
@@ -329,25 +354,11 @@ int build_csr_impl(const int32_t* src, const int32_t* dst,
   for (int64_t i = 0; i < e; ++i) {
     if (src[i] < 0 || src[i] >= v || dst[i] < 0 || dst[i] >= v) return -1;
   }
-  int64_t threads = 1;
-  if (e >= (int64_t{1} << 22)) {
-    threads = static_cast<int64_t>(std::thread::hardware_concurrency());
-    threads = std::max<int64_t>(1, std::min<int64_t>(threads, 16));
-    threads = std::min(threads, v);
-  }
+  const KeyRanges receivers(e, v);
   // recv of message i: dst[i] for i < e, then src[i - e] (symmetric only).
   // ptr[r + 1] first holds receiver r's count, then the running sum.
   memset(ptr, 0, sizeof(int64_t) * (static_cast<size_t>(v) + 1));
-  auto range = [&](int64_t t) { return v * t / threads; };
-  auto in_threads = [&](auto&& body) {
-    std::vector<std::thread> pool;
-    for (int64_t t = 1; t < threads; ++t) {
-      pool.emplace_back(body, range(t), range(t + 1));
-    }
-    body(range(0), range(1));
-    for (auto& th : pool) th.join();
-  };
-  in_threads([&](int64_t lo, int64_t hi) {
+  receivers.run([&](int64_t lo, int64_t hi) {
     for (int64_t i = 0; i < e; ++i) {
       if (dst[i] >= lo && dst[i] < hi) ++ptr[static_cast<size_t>(dst[i]) + 1];
     }
@@ -359,7 +370,7 @@ int build_csr_impl(const int32_t* src, const int32_t* dst,
   });
   for (int64_t i = 0; i < v; ++i) ptr[i + 1] += ptr[i];
   std::vector<int64_t> cursor(ptr, ptr + v);
-  in_threads([&](int64_t lo, int64_t hi) {
+  receivers.run([&](int64_t lo, int64_t hi) {
     for (int64_t i = 0; i < e; ++i) {
       if (dst[i] < lo || dst[i] >= hi) continue;
       int64_t pos = cursor[static_cast<size_t>(dst[i])]++;
@@ -401,6 +412,32 @@ int gb_build_message_csr_weighted(const int32_t* src, const int32_t* dst,
                                   float* w_sorted) {
   return build_csr_impl(src, dst, weights, e, v, symmetric, ptr, recv_sorted,
                         send_sorted, w_sorted);
+}
+
+// Positions grouped by key, a stable counting sort in threads over key
+// ranges (as build_csr_impl): for each key k in [0, v), the positions i
+// with keys[i] == k in ascending order; keys outside [0, v) name nothing.
+// Caller allocates ptr[v+1] (int64) and out[number of keys in range]
+// (int32; n at most, n < 2^31). The slot index of the carried-rows LPA
+// scan (ops/bucketed_mode.py:with_slot_index): keys are the senders behind
+// a plan's padded slots, positions the flat slots.
+void gb_positions_by_key(const int32_t* keys, int64_t n, int64_t v,
+                         int64_t* ptr, int32_t* out) {
+  const KeyRanges owners(n, v);
+  memset(ptr, 0, sizeof(int64_t) * (static_cast<size_t>(v) + 1));
+  owners.run([&](int64_t lo, int64_t hi) {
+    for (int64_t i = 0; i < n; ++i) {
+      if (keys[i] >= lo && keys[i] < hi) ++ptr[static_cast<size_t>(keys[i]) + 1];
+    }
+  });
+  for (int64_t i = 0; i < v; ++i) ptr[i + 1] += ptr[i];
+  std::vector<int64_t> cursor(ptr, ptr + v);
+  owners.run([&](int64_t lo, int64_t hi) {
+    for (int64_t i = 0; i < n; ++i) {
+      if (keys[i] < lo || keys[i] >= hi) continue;
+      out[cursor[static_cast<size_t>(keys[i])]++] = static_cast<int32_t>(i);
+    }
+  });
 }
 
 void gb_free(void* p) { free(p); }

@@ -11,6 +11,10 @@ a graph with narrow, pairwise, sorted and histogram rows. Whoever means
 to change these programs (ROADMAP S4) replaces the digests in that PR.
 PR 31 did for ``_connected_components`` alone: its ``while_loop`` carries
 the per-superstep changed counts of the ``fixpoint`` record (ISSUE 31).
+PR 32 did for ``_label_propagation`` alone: over a fused plan with its slot
+index the scan carries the gathered rows and rewrites the changed senders'
+slots (ISSUE 32); the other two digests stand, which is the proof that the
+pipeline's and WCC's programs did not move.
 """
 
 import hashlib
@@ -25,6 +29,7 @@ from graphmine_tpu.ops.bucketed_mode import (
     _HIST_MIN_DEG,
     BucketedModePlan,
     lpa_superstep_bucketed,
+    with_slot_index,
 )
 from graphmine_tpu.ops.cc import _connected_components
 from graphmine_tpu.ops.lpa import _label_propagation
@@ -45,7 +50,9 @@ def _lowered(name):
         labels = jnp.arange(g.num_vertices, dtype=jnp.int32)
         return jax.jit(lpa_superstep_bucketed).lower(labels, g, plan)
     if name == "_label_propagation":
-        return _label_propagation.lower(g, max_iter=10, plan=plan)
+        return _label_propagation.lower(
+            g, max_iter=10, plan=with_slot_index(plan)
+        )
     return _connected_components.lower(g, plan=plan)
 
 
@@ -53,7 +60,7 @@ _PARENT_DIGESTS = {
     "lpa_superstep_bucketed":
         "f6997c7ecbe220e9bdbd9b8f2be3c5fd7205ec611cdfd6460eb9e5c1b125dace",
     "_label_propagation":
-        "6532ef590d95d06e91e158c7382c8c4b1ee51f910b09689273b510a04ad22a2e",
+        "66d099320083c8cb2c5a2e009aa9074f81be462008719609e1255471b9c75061",
     "_connected_components":
         "c65ca4a6c2759859380430036393bd2d46d3bc1d496320a65d7eff2f851a16ac",
 }
@@ -64,3 +71,17 @@ def test_the_cdlp_programs_lower_to_the_parent_s_text(name):
     text = _lowered(name).as_text()  # no source locations in this form
     assert "loc(" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == _PARENT_DIGESTS[name]
+
+
+def test_the_classes_gathers_are_in_the_carried_rows_program_once():
+    """The first superstep takes the full branch by its carry (K = M + 1),
+    not by a copy of the gathers peeled before the loop: one ``case`` in
+    the scan's body, and each class's ``[n, w]`` row gather in it once."""
+    _, plan = _graph_and_plan()
+    text = _lowered("_label_propagation").as_text()
+    assert text.count("stablehlo.case") == 1
+    gathers = [ln for ln in text.splitlines() if "stablehlo.gather" in ln]
+    for idx in plan.send_idx:
+        n, w = idx.shape
+        rows = [ln for ln in gathers if ln.endswith(f"-> tensor<{n}x{w}xi32>")]
+        assert len(rows) == 1, (n, w, len(rows))

@@ -139,6 +139,21 @@ def test_lpa_superstep_bucketed_compiles_for_v5e(one_chip, fused_plan, planted):
     )
 
 
+def test_carried_rows_scan_compiles_for_v5e(one_chip, fused_plan, planted):
+    """The one-chip CDLP job (ISSUE 32): the scan that carries the gathered
+    rows, a ``switch`` between the classes' full gathers and the rung-capped
+    rewrites through the slot index, and the row modes over flat slices."""
+    from graphmine_tpu.ops.bucketed_mode import with_slot_index
+    from graphmine_tpu.ops.lpa import _label_propagation
+
+    graph, plan = fused_plan
+    compiled = _compile(
+        _label_propagation, _shapes(graph, one_chip), max_iter=10,
+        plan=_shapes(with_slot_index(plan), one_chip),
+    )
+    assert " conditional(" in compiled.as_text()
+
+
 def test_masked_lpa_plan_mask_compiles_for_v5e(one_chip, fused_plan, planted):
     """The recursive outlier pass's one program of its own size: the
     community mask over the plan's rows (ISSUE 30). Its supersteps are

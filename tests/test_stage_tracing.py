@@ -157,11 +157,23 @@ def test_the_loop_around_a_superstep_names_its_own_bookkeeping():
     from graphmine_tpu.ops.lpa import _label_propagation
 
     g = _graph()
-    lpa = _scopes_in(_op_names(  # the counts are dead code without history
-        lambda gg: _label_propagation(gg, 2, return_history=True), g))
+    lpa = _scopes_in(_op_names(lambda gg: _label_propagation(gg, 2), g))
     assert {"superstep/changed_count", "lpa_sort/segment_mode"} <= lpa
     cc = _scopes_in(_op_names(lambda gg: _connected_components(gg), g))
     assert {"superstep/changed_count", "superstep/converged"} <= cc
+
+
+def test_the_carried_rows_scan_names_both_branches():
+    from graphmine_tpu.ops.bucketed_mode import BucketedModePlan, with_slot_index
+    from graphmine_tpu.ops.lpa import _label_propagation
+
+    g = _graph()
+    plan = with_slot_index(BucketedModePlan.from_graph(g, with_send=True))
+    got = _scopes_in(_op_names(
+        lambda gg, p: _label_propagation(gg, 3, plan=p), g, plan))
+    assert {"lpa_bucketed/row_gather", "lpa_bucketed/row_mode",
+            "lpa_bucketed/write_back", "delta/compact", "delta/expand",
+            "delta/scatter", "superstep/changed_count"} <= got, sorted(got)
 
 
 def test_ivf_search_and_merge_and_lof_carry_their_scopes():
