@@ -78,6 +78,18 @@ _I32 = 4  # bytes per int32/float32 slot — the one word size
 # aligned weight mats), and the per-vertex label/exchange terms of each
 # schedule. ``pipeline/planner.py`` derives its ``_BYTES_PER_*``
 # constants FROM these — edit here, both consumers move.
+#
+# Checked, not retuned, on the graph that fills one chip (graph500-24,
+# E = 260,376,136; TPU v5e, PERF.md §6, PR 33). Without the carried rows:
+# the arrays are 33.8 B/edge (endpoints 8.0 + message CSR 16.3 + plan
+# 9.5) and the allocator's peak 36.8 B/edge (9.57 GB: the arrays, labels,
+# 0.65 GB of program code). The compiled scan's temporaries (22.4 B/edge,
+# 5.83 GB) come on top: `peak_bytes_in_use` does not count them
+# (`peak_bytes_reserved` does) though they are taken from the same memory:
+# the seed's 6 B/edge of "gather transient" is a quarter of them. With the carried rows and their slot
+# index the arrays are 51.4 B/edge (+ 9.3 rows + 8.3 index) and the
+# temporaries 37.6 B/edge (9.80 GB): 89 B/edge, which one v5e does not hold
+# beside this graph (`carried_rows_inventory` below; ROADMAP D4).
 BYTES_PER_EDGE = 36.0
 BYTES_PER_EDGE_WEIGHTED = 16.0
 SINGLE_BYTES_PER_VERTEX = 8.0
@@ -237,8 +249,17 @@ def superstep_footprint(
 
     With a built ``plan`` the counts are EXACT — the plan's own matrix
     shapes: edge endpoints + the message CSR + labels in/out + msg
-    weights, plus the width-ladder mats + vertex ids (+ slot-aligned
-    weight mats) + the gathered transient (bucketed).
+    weights, plus the width-ladder mats + vertex ids + the hubs' row
+    offsets (+ slot-aligned weight mats) + the gathered transient
+    (bucketed). A plan with its slot index adds what the carried-rows
+    scan of ``ops/lpa.py`` holds on the device
+    (:func:`carried_rows_inventory`: the rows as scan state and the slot
+    index by sender, exact; the copies of the rows the compiled scan
+    keeps beside its carry and the hubs' histograms, as the chip's
+    compiler counts them). The plan's own terms (``plan_mats``,
+    ``plan_vertex_ids``, ``plan_hub_offsets``, ``weight_mats``,
+    ``slot_index``) sum to the ``nbytes`` of the plan's arrays to the
+    byte.
 
     WITHOUT a plan (the driver's plan-time pre-degrade fires before any
     build) the estimate is anchored to the SAME seed constants the
@@ -287,13 +308,58 @@ def superstep_footprint(
             ids += int(plan.hist_vertex_ids.shape[0])
         inv["plan_mats"] = _I32 * padded
         inv["plan_vertex_ids"] = _I32 * ids
+        if plan.hist_row_offset is not None:
+            inv["plan_hub_offsets"] = _I32 * int(plan.hist_row_offset.shape[0])
         if weighted:
             inv["weight_mats"] = _I32 * padded
-        inv["gather_transient"] = _I32 * padded
+        if getattr(plan, "out_slot", None) is not None:
+            inv.update(carried_rows_inventory(plan))
+        else:
+            inv["gather_transient"] = _I32 * padded
     return MemEstimate(
         op=op, family=family, devices=1, weighted=weighted,
         inventory=inv, exact=True,
     )
+
+
+def _slots(mat) -> int:
+    return int(mat.shape[0]) * int(mat.shape[1])
+
+
+# The compiled carried-rows scan holds its rows about four times over:
+# XLA's buffer assignment gives the scan's carry, the `switch`'s result and
+# the copies between them a buffer each (three `copy-done` of the whole
+# `s32[S]` buffer in the compiled text). `memory_analysis()` of the program
+# compiled for a v5e (PERF.md §6, PR 33): temporaries 2.81 GB at S = 137.8 M
+# slots (graph500-22) and 9.80 GB at S = 607.6 M (graph500-24), of which
+# the hubs' histograms (below) are 0.54 GB: 4.1 and 3.8 x 4S. An accident
+# of this compiler's buffer assignment, kept here only because the
+# admission has to count what the device will be asked for: it goes (to 1)
+# when the `switch` updates the rows in place (ROADMAP 2a).
+CARRIED_ROWS_COPIES = 4
+
+
+def carried_rows_inventory(plan) -> dict:
+    """What the carried-rows scan holds on the device beyond a fused
+    ``plan``, known from the plan's shapes before the index is built.
+    Exact: ``carried_rows``, the classes' rows end to end as scan state,
+    and ``slot_index``, one slot per message and ``V + 1`` offsets by
+    sender. As the chip's compiler counts them (:data:`CARRIED_ROWS_COPIES`):
+    ``gather_transient``, the further copies of the rows the compiled scan
+    keeps, and ``hub_histograms``, the hubs' ``[n, V]`` counts and the
+    scatter's copy of them. The admission of
+    ``ops/superstep_policy.admit_carried_rows`` holds their sum against
+    the device's free memory."""
+    rows = _I32 * sum(_slots(x) for x in plan.send_idx or ())
+    hubs = 0 if plan.hist_vertex_ids is None else int(plan.hist_vertex_ids.shape[0])
+    return {
+        "carried_rows": rows,
+        "slot_index": _I32 * (
+            int(plan.num_messages) + int(plan.num_vertices) + 1
+        ),
+        "gather_transient": (CARRIED_ROWS_COPIES - 1) * rows,
+        "hub_histograms": 2 * _I32 * hubs * int(plan.num_vertices),
+    }
 
 
 # ---- sharded supersteps ----------------------------------------------------

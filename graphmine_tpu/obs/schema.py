@@ -110,17 +110,42 @@ register("superstep_timing", "op", "family", "variant", "iteration",
 # frontier. Benchmark metric `wcc_quiet_pass_share` reads it.
 register("fixpoint", "op", "supersteps", "changed", "num_vertices", "family")
 
-# superstep_delta: one per `label_propagation(..., sink=)` call that ran
-# the carried-rows scan (ops/lpa.py: a fused plan with its slot index, one
-# chip). Per superstep, in order: `branch`, how the superstep brought its
-# rows up to date ("full": every class gathered anew; a number: the rung,
-# the static cap of the slots rewritten through the index), and what it
-# left for the next one: `changed_vertices`, the labels it moved, and
-# `changed_messages` (K), the messages those vertices send, which picks
-# the next branch. `rungs` are `ops/superstep_policy.delta_rungs(M)`. From
-# int32[max_iter] outputs of the scan, read after the labels: no sync.
+# superstep_delta: one per `label_propagation(..., sink=)` call over a
+# fused plan's dense rows on one chip (ops/lpa.py). Per superstep, in
+# order: `branch`, how the superstep brought its rows up to date ("full":
+# every class gathered anew; a number: the rung, the static cap of the
+# slots rewritten through the index), and what it left for the next one:
+# `changed_vertices`, the labels it moved, and `changed_messages` (K), the
+# messages those vertices send, which picks the next branch. `rungs` are
+# `ops/superstep_policy.delta_rungs(M)`. From int32[max_iter] outputs of
+# the scan, read after the labels: no sync. A job whose rows were not
+# admitted to the device (`device_residency` says `scan: plain`) runs the
+# stateless scan, which keeps no rows and counts no K: every `branch` is
+# "full", `changed_messages` and `rungs` are empty. Benchmark metric
+# `cdlp_sparse_superstep_share` reads `branch`.
 register("superstep_delta", "op", "changed_vertices", "changed_messages",
          "branch", "rungs", "num_messages")
+
+# device_residency: one per plan materialisation of a one-device
+# `label_propagation(..., plan="auto", sink=)` call on the bucketed family
+# (ops/superstep_policy.emit_device_residency): what the device holds for
+# this graph's supersteps, in bytes by array group, beside the allocator's
+# `bytes_limit` and `bytes_in_use` at the time (None on a backend that
+# keeps no statistics). `graph_bytes` and `plan_bytes` are the arrays' own
+# `nbytes` (0 for a host-resident graph; the plan without its slot index),
+# `slot_index_bytes` the index's, `rows_bytes` the carried rows' (scan
+# state; 0 under `plain`), `labels_bytes` labels in and out, `code_bytes`
+# the executable's size where one reports it (None: a jitted call hands
+# none back). Arrays only: what the compiled scan takes beside them while
+# it runs (its temporaries, which the allocator's `peak_bytes_in_use`
+# leaves out too) is in no field; under `carried` the `reason` holds the
+# admission's count of it. `scan` is the admission's answer (`carried` |
+# `plain`, ops/superstep_policy.admit_carried_rows) and `reason` its
+# arithmetic, of the device's memory alone. Benchmark metric
+# `plan_resident_gb` reads it.
+register("device_residency", "op", "scan", "reason", "bytes_limit",
+         "graph_bytes", "plan_bytes", "rows_bytes", "slot_index_bytes",
+         "labels_bytes", "code_bytes")
 
 # memory_watermark (ISSUE 14): predicted-vs-measured HBM/RSS for one
 # operating point, emitted by obs/memmodel.emit_memory_watermark (the

@@ -84,15 +84,34 @@ def label_propagation(
     labels rewrites only the slots behind their senders, through a slot
     index built once per plan (:func:`_cached_slot_index`). Labels are
     bit-identical to the stateless supersteps'; nothing selects it but
-    what each superstep counts.
+    what each superstep counts, and what the device has free: the rows
+    and the index go on the device only if :func:`~graphmine_tpu.ops.
+    superstep_policy.admit_carried_rows` finds room for them, once per
+    plan, before the index is built; otherwise the stateless bucketed
+    scan runs (``impl_selected`` says ``scan`` and ``scan_reason``).
 
     ``sink``: optional MetricsSink — each auto resolution emits an
     ``impl_selected`` record, and each plan materialization a
     ``plan_build`` record (family, build seconds with the slot index's,
     width classes, padded slots/edge), so host plan cost is visible in
-    obs_report instead of hiding inside first-call latency; a
-    carried-rows job one ``superstep_delta`` record (per superstep: the
-    branch taken, the labels moved, the messages their vertices send).
+    obs_report instead of hiding inside first-call latency, and a
+    ``device_residency`` record (the bytes the device holds for this
+    graph's supersteps, by array group, beside its limit); a job over
+    a fused plan's rows one ``superstep_delta`` record (per superstep: the
+    branch taken, the labels moved, the messages their vertices send; all
+    ``full`` where the rows were not admitted).
+
+    On one device the graph may be host-resident too
+    (``build_graph(..., to_device=False)``): the fused plan is built from
+    the host arrays and placed alone, and the compiled scan, which reads
+    the plan and nothing of the graph, is handed none of the graph's
+    arrays (``device_residency`` then says ``graph_bytes: 0``); the same
+    entry, the same scan, equal labels (tested on the CPU; no chip run has
+    taken it at a size that fills the chip). The admission sizes the
+    device alone: with graph500-24 host-resident it would answer
+    ``carried`` (12.4 GB against 14.4 GB free), and that program's compile
+    took 28 GB of host memory where the chip's machine has 40 GiB for
+    everything (PERF.md §6, PR 33).
 
     ``mesh``: a ``jax.sharding.Mesh`` runs the job across its devices
     (``None`` is the one-device path above, byte for byte). The graph
@@ -117,6 +136,7 @@ def label_propagation(
         )
     from graphmine_tpu.ops.bucketed_mode import BucketedModePlan
     from graphmine_tpu.ops.superstep_policy import (
+        emit_device_residency,
         emit_plan_records,
         select_superstep_family,
     )
@@ -127,18 +147,20 @@ def label_propagation(
             family, reason = select_superstep_family(
                 graph.num_vertices, graph.num_messages
             )
-            seconds, cached = 0.0, False
+            seconds, cached, scan = 0.0, False, None
             if family == "bucketed":
                 # Weighted graphs ride the fast path too (r2): the plan
                 # carries the slot-aligned weight payload.
                 plan, seconds, cached = _cached_auto_plan(graph)
-                plan, index_seconds = _cached_slot_index(plan)
+                plan, index_seconds, scan = _cached_slot_index(plan)
                 seconds += index_seconds
             emit_plan_records(
                 sink, "lpa_superstep", plan, reason, seconds, cached,
                 graph.num_edges, graph.num_messages,
-                num_vertices=graph.num_vertices,
+                num_vertices=graph.num_vertices, scan=scan,
             )
+            if plan is not None:
+                emit_device_residency(sink, "lpa_superstep", graph, plan, scan)
     elif plan is not None and not isinstance(plan, BucketedModePlan):
         raise ValueError(
             f"plan must be 'auto', None or a BucketedModePlan; got {plan!r}"
@@ -148,7 +170,7 @@ def label_propagation(
         and plan.send_idx
         and not isinstance(plan.send_idx[0], jax.core.Tracer)
     ):
-        plan, _ = _cached_slot_index(plan)
+        plan, _, _ = _cached_slot_index(plan)
     if (
         isinstance(plan, BucketedModePlan)
         and plan.hist_vertex_ids is not None
@@ -193,7 +215,7 @@ def label_propagation(
             sink, "lpa_superstep", cost, max_iter, max_iter, secs,
             graph.num_edges, variant="fused", cold_compile=cold,
         )
-        if "branch" in per_step:
+        if plan is not None and plan.send_idx:
             _emit_superstep_delta(sink, per_step, plan.num_messages)
     else:
         labels, per_step = _label_propagation(
@@ -205,19 +227,27 @@ def label_propagation(
 
 
 def _emit_superstep_delta(sink, per_step: dict, num_messages: int) -> None:
-    """The ``superstep_delta`` record of one carried-rows job, from the
-    scan's per-superstep outputs (the job's labels are already back)."""
+    """The ``superstep_delta`` record of one job over a fused plan's dense
+    rows, from the scan's per-superstep outputs (the job's labels are
+    already back). The stateless scan, which runs where the rows were not
+    admitted to the device, has no ``branch`` to report: every one of its
+    supersteps is a full gather, and the record says that."""
     import numpy as np
 
     from graphmine_tpu.ops.superstep_policy import delta_rungs
 
-    names = [*delta_rungs(num_messages), "full"]
+    changed = np.asarray(per_step["changed_vertices"]).tolist()
+    if "branch" in per_step:
+        names = [*delta_rungs(num_messages), "full"]
+        branch = [names[b] for b in np.asarray(per_step["branch"]).tolist()]
+        messages = np.asarray(per_step["changed_messages"]).tolist()
+        rungs = names[:-1]
+    else:
+        branch, messages, rungs = ["full"] * len(changed), [], []
     sink.emit(
-        "superstep_delta", op="lpa_superstep",
-        changed_vertices=np.asarray(per_step["changed_vertices"]).tolist(),
-        changed_messages=np.asarray(per_step["changed_messages"]).tolist(),
-        branch=[names[b] for b in np.asarray(per_step["branch"]).tolist()],
-        rungs=names[:-1], num_messages=num_messages,
+        "superstep_delta", op="lpa_superstep", changed_vertices=changed,
+        changed_messages=messages, branch=branch, rungs=rungs,
+        num_messages=num_messages,
     )
 
 
@@ -254,10 +284,15 @@ _slot_index_cache: dict = {}
 
 
 def _cached_slot_index(plan):
-    """``(plan with its slot index, build seconds)``: the index of the
-    carried-rows scan (:func:`~graphmine_tpu.ops.bucketed_mode.
-    with_slot_index`), paid once per fused plan as the plan is paid once
-    per graph (0.0 seconds on a hit). Keyed by the identity of the plan's
+    """``(plan, build seconds, (scan, reason))``: the fused ``plan`` as the
+    scan will run it. Once per plan (as the plan is paid once per graph)
+    :func:`~graphmine_tpu.ops.superstep_policy.admit_carried_rows` says
+    whether the carried rows and their index fit the device beside what
+    it holds; only then is the index of the carried-rows scan built
+    (:func:`~graphmine_tpu.ops.bucketed_mode.with_slot_index`), and the
+    plan comes back with it. Under ``plain`` the plan comes back as it
+    is, and runs the stateless bucketed scan. The answer and the index
+    are kept (0.0 seconds on a hit), keyed by the identity of the plan's
     first row matrix; a weakref finalizer evicts the entry with it. The
     index stays out of the plan the cache of :func:`_cached_auto_plan`
     holds: ``connected_components`` shares that plan and reads no index."""
@@ -265,23 +300,35 @@ def _cached_slot_index(plan):
     import weakref
 
     from graphmine_tpu.ops.bucketed_mode import with_slot_index
-    from graphmine_tpu.ops.superstep_policy import timed_plan_build
+    from graphmine_tpu.ops.superstep_policy import (
+        admit_carried_rows,
+        device_memory_stats,
+        timed_plan_build,
+    )
 
-    if plan.out_slot is not None or not plan.send_idx:
-        return plan, 0.0
+    if plan.out_slot is not None:
+        return plan, 0.0, ("carried", "the plan came with its slot index")
+    if not plan.send_idx:
+        return plan, 0.0, ("plain", "no dense rows to carry")
     anchor = plan.send_idx[0]
     key = id(anchor)
     hit = _slot_index_cache.get(key)
     seconds = 0.0
     if hit is None or hit[0]() is not anchor:
-        indexed, seconds = timed_plan_build(lambda: with_slot_index(plan))
+        scan = admit_carried_rows(plan, device_memory_stats(plan))
+        out_ptr = out_slot = None
+        if scan[0] == "carried":
+            indexed, seconds = timed_plan_build(lambda: with_slot_index(plan))
+            out_ptr, out_slot = indexed.out_ptr, indexed.out_slot
         # the index alone: a cached plan would keep its own anchor alive
         hit = (
             weakref.ref(anchor, lambda _, k=key: _slot_index_cache.pop(k, None)),
-            indexed.out_ptr, indexed.out_slot,
+            out_ptr, out_slot, scan,
         )
         _slot_index_cache[key] = hit
-    return dataclasses.replace(plan, out_ptr=hit[1], out_slot=hit[2]), seconds
+    if hit[2] is not None:
+        plan = dataclasses.replace(plan, out_ptr=hit[1], out_slot=hit[2])
+    return plan, seconds, hit[3]
 
 
 _mesh_partition_cache: dict = {}

@@ -12,7 +12,10 @@ This module owns the choice (:func:`select_superstep_family`) and the
 ``impl_selected`` / ``plan_build`` records that explain it
 (:func:`emit_plan_records`); ``ops/lpa.py``, ``ops/cc.py``,
 ``ops/pagerank.py``, ``pipeline/planner.py`` and ``pipeline/driver.py``
-read both from here.
+read both from here. Beside it, for the one-chip LPA scan over a fused
+plan: whether the gathered rows and their slot index go on the device
+(:func:`admit_carried_rows`) and the ``device_residency`` record that
+says what the device then holds (:func:`emit_device_residency`).
 """
 
 from __future__ import annotations
@@ -20,7 +23,12 @@ from __future__ import annotations
 import time
 
 from graphmine_tpu.obs.costmodel import _bucketed_padded_slots, superstep_cost
-from graphmine_tpu.obs.memmodel import FAMILY_DEGRADE
+from graphmine_tpu.obs.memmodel import (
+    CARRIED_ROWS_COPIES,
+    FAMILY_DEGRADE,
+    carried_rows_inventory,
+    superstep_footprint,
+)
 
 # bucketed beats sort from ~2^16 messages (r1 measurement, the threshold
 # label_propagation has shipped since; the plan build amortizes past there).
@@ -69,6 +77,63 @@ def delta_rungs(num_messages: int) -> tuple:
     return tuple(sorted(
         {num_messages // d for d in DELTA_RUNG_DIVISORS} - {0}
     ))
+
+
+_INT32_MAX = (1 << 31) - 1
+
+
+def device_memory_stats(plan) -> dict | None:
+    """The allocator's statistics of the device that holds ``plan``'s rows
+    (a host-side query, no sync); ``None`` where the backend keeps none
+    (the CPU) or the rows sit on no one device."""
+    placed = plan.send_idx[0].devices() if plan.send_idx else ()
+    return next(iter(placed)).memory_stats() if len(placed) == 1 else None
+
+
+def admit_carried_rows(plan, stats: dict | None) -> tuple[str, str]:
+    """``("carried" | "plain", reason)`` for the one-chip LPA scan over the
+    fused ``plan``: do the carried rows and the slot index go on the
+    device beside what it already holds? Taken once per plan, on the host,
+    before the index is built, from the plan's shapes
+    (:func:`~graphmine_tpu.obs.memmodel.carried_rows_inventory`: the rows,
+    the index, the copies the compiled scan keeps, the hubs' histograms)
+    against ``stats`` (:func:`device_memory_stats`): ``bytes_limit`` less
+    ``bytes_in_use``, the graph and the plan being in use already.
+    ``plain`` is the stateless bucketed scan, the same labels bit for bit
+    at a full gather every superstep. A device that reports no limit
+    admits ``carried``.
+
+    The DEVICE's memory alone is sized. The host's is not: compiling the
+    carried-rows program for graph500-24's plan (607.6 M slots, 62
+    classes) took 28 GB of host memory and the stateless program 20 GB
+    (PERF.md §6, PR 33), and a job admitted here whose compile does not
+    fit the host still ends there, minutes later. Every ``reason`` says
+    so."""
+    need = carried_rows_inventory(plan)
+    slots = need["carried_rows"] // 4
+    if slots == 0 or slots >= _INT32_MAX:
+        return "plain", (
+            f"{slots} padded slots: nothing to carry, or more than an "
+            "int32 slot index counts"
+        )
+    total = sum(need.values())
+    said = (
+        f"rows {need['carried_rows']} B + {CARRIED_ROWS_COPIES - 1} copies "
+        f"in the compiled scan {need['gather_transient']} B + slot index "
+        f"{need['slot_index']} B + hub histograms {need['hub_histograms']} B "
+        f"= {total} B"
+    )
+    unsized = " (device memory alone: the host's compile memory is not sized)"
+    limit = (stats or {}).get("bytes_limit")
+    if not limit:
+        return "carried", said + "; the device reports no limit" + unsized
+    free = int(limit) - int(stats.get("bytes_in_use", 0))
+    said += f" against {free} B free of {int(limit)} B"
+    if total <= free:
+        return "carried", said + unsized
+    return "plain", (
+        said + ": a full gather every superstep, nothing kept" + unsized
+    )
 
 
 def crossover_thresholds() -> dict:
@@ -137,10 +202,13 @@ def plan_build_stats(plan, num_edges: int) -> dict:
 def emit_plan_records(
     sink, op: str, plan, reason: str, seconds: float, cached: bool,
     num_edges: int, num_messages: int, num_vertices: int | None = None,
+    scan: tuple[str, str] | None = None,
 ) -> None:
     """Emit the ``impl_selected`` + ``plan_build`` provenance pair for one
     auto-plan resolution (no-op without a sink). ``plan=None`` (sort
     family) emits only ``impl_selected``: there is no plan to build.
+    ``scan`` is :func:`admit_carried_rows`'s answer, where the caller
+    asked: ``impl_selected`` then says ``scan`` and ``scan_reason``.
 
     Both records carry the decision's evidence: the active crossover
     ``thresholds`` (:func:`crossover_thresholds`) and the analytical
@@ -156,15 +224,59 @@ def emit_plan_records(
     cost = superstep_cost(
         op, family, v, num_messages, num_edges, plan=plan
     )
+    admitted = {} if scan is None else {"scan": scan[0], "scan_reason": scan[1]}
     sink.emit(
         "impl_selected", op=op, impl=family, n=num_messages, reason=reason,
-        thresholds=crossover_thresholds(), cost=cost.record(),
+        thresholds=crossover_thresholds(), cost=cost.record(), **admitted,
     )
     if plan is None:
         return
     sink.emit(
         "plan_build", op=op, seconds=round(seconds, 6), cached=cached,
         cost=cost.record(), **plan_build_stats(plan, num_edges),
+    )
+
+
+def emit_device_residency(
+    sink, op: str, graph, plan, scan: tuple[str, str],
+) -> None:
+    """The ``device_residency`` record of one plan materialisation (see
+    ``obs/schema.py``): what the device holds for this graph's supersteps,
+    by array group, from the arrays' own ``nbytes`` and, for the scan's
+    state, ``superstep_footprint``'s terms; beside the device's
+    ``bytes_limit`` (:func:`device_memory_stats`, asked here). No-op
+    without a sink. ``plan`` is the plan the scan runs, with its slot
+    index when ``scan`` says ``carried``."""
+    if sink is None:
+        return
+    import dataclasses
+
+    import jax
+
+    def on_device(tree, fields) -> int:
+        return sum(
+            int(x.nbytes) for name in fields
+            for x in jax.tree.leaves(getattr(tree, name))
+            if isinstance(x, jax.Array)
+        )
+
+    index = ("out_ptr", "out_slot")
+    names = lambda tree: [f.name for f in dataclasses.fields(tree)]
+    inv = superstep_footprint(
+        op, "bucketed", plan.num_vertices, plan.num_messages, plan=plan
+    ).inventory
+    stats = device_memory_stats(plan) or {}
+    sink.emit(
+        "device_residency", op=op, scan=scan[0], reason=scan[1],
+        bytes_limit=stats.get("bytes_limit"),
+        bytes_in_use=stats.get("bytes_in_use"),
+        graph_bytes=on_device(graph, names(graph)),
+        plan_bytes=on_device(plan, set(names(plan)) - set(index)),
+        rows_bytes=inv.get("carried_rows", 0),
+        slot_index_bytes=on_device(plan, index),
+        labels_bytes=inv["labels"],
+        # the jitted call hands back no executable to ask for its size
+        code_bytes=None,
     )
 
 

@@ -314,7 +314,7 @@ def test_the_index_cache_lets_go_of_a_dropped_plan():
 
     g, plan, _, _ = _case("path")
     before = len(lpa._slot_index_cache)
-    indexed, _ = lpa._cached_slot_index(plan)
+    indexed, _, _ = lpa._cached_slot_index(plan)
     assert len(lpa._slot_index_cache) == before + 1
     del plan, indexed
     gc.collect()
