@@ -362,6 +362,7 @@ DEVICE_SCOPES = frozenset((
     # inner: kNN / IVF / LOF
     "distance", "topk", "assign", "lloyd_update", "search_gather",
     "search_distance", "search_topk", "merge_gather", "merge_topk",
+    "lists_census", "lists_tables", "lists_take",
     "reach", "lrd", "score",
 ))
 

@@ -14,12 +14,18 @@ TPU-first shape discipline — everything the device sees is static:
 - **k-means** (:func:`kmeans`): Lloyd iterations where the assignment
   step is the row-tiled `cross_knn` matmul (MXU) and the update is one
   `segment_sum`; empty clusters keep their previous center.
-- **Inverted lists**: points are permuted host-side into cluster order,
-  every cluster's member row padded to one static ``Lmax``.
+- **Inverted lists** (:func:`_inverted_lists`): the points sorted into
+  cluster order, clusters cut into sublists of at most ``l_cap``
+  members, every sublist's member row padded to one static ``Lmax``.
+  Built on the device, from the probe table where it lies: one program
+  sorts and counts, the host fetches two ``[C]`` vectors and two
+  scalars, decides the shapes and runs the guards, a second program cuts
+  the tables (a NumPy builder held the chip idle 1.7 s of an 11.4 s
+  job, PERF.md §6, PR 34; it is the reference in ``tests/test_ann.py``).
 - **Cluster-batched search**: each query probes its ``n_probe`` nearest
-  centers; (query, cluster) pairs are grouped BY CLUSTER host-side and
-  padded to one static ``Qmax``, so the device runs a single
-  ``lax.map`` over clusters of ``[Qmax, F] x [F, Lmax]`` distance
+  centers; (query, sublist) pairs are grouped BY SUBLIST and chopped
+  into chunks of one static ``B = 4096`` query slots, so the device runs
+  a single ``lax.map`` over chunks of ``[B, F] x [F, Lmax]`` distance
   blocks + a selection of the ``k`` nearest — no irregular
   [N, n_probe * Lmax] gather (XLA's gather runs at ~0.2 G elem/s on this
   chip whatever the table; PERF.md §7.4). A member belongs to exactly
@@ -36,9 +42,10 @@ TPU-first shape discipline — everything the device sees is static:
 The result contract matches :func:`graphmine_tpu.ops.knn.knn`:
 ``(d2, idx)`` ascending, self excluded — so
 :func:`graphmine_tpu.ops.lof.lof_from_knn` consumes it unchanged
-(``lof_scores(impl="ivf")``). Shapes (C, Qmax, Lmax) are data-dependent,
-so one XLA compile per dataset shape — the same trade the bucketed LPA
-plan makes, amortized over every LOF call on that cloud.
+(``lof_scores(impl="ivf")``). Shapes (C, n_sub, Lmax, R, p_max) are
+data-dependent, so one XLA compile per dataset shape — the same trade
+the bucketed LPA plan makes, amortized over every LOF call on that
+cloud.
 
 The reference has no kNN at all; this extends the north-star scorer
 (BASELINE.json "kNN-graph + LOF") past the all-pairs wall.
@@ -59,6 +66,8 @@ from graphmine_tpu.ops.knn import cross_knn
 
 
 _ASSIGN_TILE = 1 << 15  # [32768, C] distance tiles: 64 MB at C=512
+_CHUNK_B = 4096          # query slots of one search chunk
+_MERGE_T = 16384         # queries of one merge tile
 
 
 def default_n_clusters(n: int) -> int:
@@ -142,8 +151,9 @@ def _select_k(d2, ids, k: int):
     callers. The two-key form (``num_keys=2``, unstable, one operand
     fewer: 0.85 against 1.26 ms a chunk) was measured and dropped: it
     needs position order and id order to agree on every tie, which holds
-    inside one search chunk (``_inverted_lists`` lays members out in id
-    order) and NOT in the merge, where equal distances from two probed
+    inside one search chunk (``_probe_census`` sorts the members of a
+    cluster stably, so ``_index_tables`` lays them out in id order) and
+    NOT in the merge, where equal distances from two probed
     clusters sit in probe order; it changed 1,494 of 262,144 neighbour
     lists on the pipeline cell's cloud (PERF.md §6, PR 28).
 
@@ -183,11 +193,12 @@ def _search_clusters(q_vec, q_gid, m_vec, m_gid, m_valid, k: int):
 
 def _search_chunks(pts, m_gid, m_valid, q_gid, row_sub, k: int):
     """Default (single-device) executor for the cluster-batched search:
-    one ``lax.map`` over the fixed-size query chunks. Inputs are the host
-    tables :func:`ivf_knn` built (float32 points, int32 member/query ids,
-    bool member validity); returns ``([R, B, k] d2, [R, B, k] gid)``.
-    Padded duplicate query slots produce junk rows; they are never read
-    back (``slot_of_pair`` only maps REAL pairs)."""
+    one ``lax.map`` over the fixed-size query chunks. Inputs are the
+    host float32 points and the tables :func:`_inverted_lists` built
+    (int32 member/query ids and bool member validity, device arrays;
+    ``row_sub`` a host vector); returns ``([R, B, k] d2, [R, B, k]
+    gid)``. Padded duplicate query slots produce junk rows; they are
+    never read back (the take table only maps REAL pairs)."""
     pts_dev = jnp.asarray(pts)
     m_gid_dev = jnp.asarray(m_gid)
     m_valid_dev = jnp.asarray(m_valid)
@@ -270,7 +281,10 @@ def ivf_knn(
     cluster-batched search stage — ``(pts, m_gid, m_valid, q_gid,
     row_sub, k) -> (d2_all, gid_all)`` of shape ``[R', B, k]`` with
     ``R' >= R`` chunk rows (extra padded rows appended at the END are
-    sliced off; their results are never read). The mesh-sharded LOF path
+    sliced off; their results are never read). ``pts`` and ``row_sub``
+    are host arrays; ``m_gid``, ``m_valid`` and ``q_gid`` are arrays on
+    the default device (``np.asarray`` fetches one, as the mesh executor
+    does to pad its rows). The mesh-sharded LOF path
     distributes exactly this stage — the dominant distance work — over
     devices (:func:`graphmine_tpu.parallel.knn.sharded_lof`).
     """
@@ -306,15 +320,16 @@ def ivf_knn(
     # probe assignment: each query's n_probe nearest centers; column 0
     # is the owning cluster (a point is always a member of its own
     # nearest cluster's list).
-    with stage_span(sink, "ivf_probe", n=n, n_clusters=n_clusters):
-        _, probe = cross_knn(jnp.asarray(pts), centers, n_probe)
-        probe = np.asarray(probe)
+    with stage_span(sink, "ivf_probe", n=n, n_clusters=n_clusters) as stage:
+        probe = stage.sync(cross_knn(jnp.asarray(pts), centers, n_probe)[1])
     try:
         with stage_span(sink, "ivf_lists") as stage:
-            lists = _inverted_lists(pts, k, probe, n_clusters, n_probe)
-            stage.note(**lists.counts)
+            lists = _inverted_lists(probe, k, n_clusters)
+            stage.note(**lists.counts, host_bytes=lists.host_bytes)
+            stage.sync((lists.m_gid, lists.m_valid, lists.q_gid, *lists.slots))
     except _GuardTripped as tripped:
         return _exact_fallback(pts, k, tripped.guard, tripped.detail, sink)
+    del probe
 
     r_rows, chunk_b, p_max = lists.r_rows, lists.chunk_b, lists.p_max
     exec_fn = search_exec if search_exec is not None else _search_chunks
@@ -324,10 +339,9 @@ def ivf_knn(
         d2_all, gid_all = stage.sync(exec_fn(
             pts, lists.m_gid, lists.m_valid, lists.q_gid, lists.row_sub, k
         ))
-    # the host half of the merge: ivf_lists again. The two concatenates
-    # it dispatches run on the device while the host builds the take
-    # table, so this span does not wait for them (ivf_merge does).
-    with stage_span(sink, "ivf_lists", p_max=p_max):
+    # the merge's half of the index: ivf_lists again, the take table built
+    # on the device (nothing fetched, nothing handed on as a host array).
+    with stage_span(sink, "ivf_lists", p_max=p_max, host_bytes=0) as stage:
         if d2_all.shape[0] < r_rows or d2_all.shape != (
             d2_all.shape[0], chunk_b, k
         ) or gid_all.shape != d2_all.shape:
@@ -339,6 +353,15 @@ def ivf_knn(
                 f"{tuple(gid_all.shape)}; expected [R'>= {r_rows}, "
                 f"{chunk_b}, {k}] with extra rows appended at the end"
             )
+        junk = r_rows * chunk_b
+        take = stage.sync(_take_table(
+            *lists.slots, p_max=p_max, junk=junk, merge_t=_MERGE_T
+        ))
+        # the concatenates below are where the job's device memory peaks
+        # (the search results, their flat copies and a slice on its way
+        # into one, 2.7 GB each at 262K x 128; PERF.md §6, PR 34): of the
+        # index only the take table is still alive there
+        del lists
         # [R', B, k] -> per-pair rows -> tiled [T, p_max * k] merges (one
         # monolithic [N, p_max * k] gather + top_k would hold ~4 GB of
         # merge operands at 262K x 16 x 128). Queries with fewer than
@@ -348,49 +371,51 @@ def ivf_knn(
         # device-count multiple) AND pins the junk-row sentinel id below
         # at the same flat index either way.
         d2_flat = jnp.concatenate(
-            [d2_all.reshape(-1, k)[: r_rows * chunk_b],
+            [d2_all.reshape(-1, k)[:junk],
              jnp.full((1, k), jnp.inf, d2_all.dtype)]
         )
         gid_flat = jnp.concatenate(
-            [gid_all.reshape(-1, k)[: r_rows * chunk_b],
-             jnp.full((1, k), -1, jnp.int32)]
+            [gid_all.reshape(-1, k)[:junk], jnp.full((1, k), -1, jnp.int32)]
         )
-        junk = r_rows * chunk_b
-        merge_t = 16384
-        n_pad = -(-n // merge_t) * merge_t
-        take = np.full((n_pad, p_max), junk, np.int64)
-        pairs_per_q = lists.pairs_per_q
-        pair_col = (
-            np.arange(lists.n_pairs)
-            - np.repeat(np.cumsum(pairs_per_q) - pairs_per_q, pairs_per_q)
-        )
-        take[lists.pair_q, pair_col] = lists.slot_of_pair
-        # Explicit int32, not an implicit jnp downcast: the bound above
-        # guarantees every row id (junk sentinel included) fits, and the
-        # cast states the invariant instead of relying on x64-mode
-        # defaults.
-        take = take.astype(np.int32).reshape(n_pad // merge_t, merge_t, p_max)
+        stage.sync((d2_flat, gid_flat))
 
     # NB: the flat result arrays are jit ARGUMENTS, not closure captures
     # — a closed-over concrete array is baked into the HLO as a constant,
     # and serializing the ~GB-scale [R * B, k] buffers hung XLA:TPU
     # compilation for minutes (found the hard way, r5).
+    n_pad = take.shape[0] * take.shape[1]
     with stage_span(sink, "ivf_merge", n=n, k=k, p_max=p_max) as stage:
-        d2_out, gid_out = _merge_tiles(d2_flat, gid_flat, jnp.asarray(take), k)
+        d2_out, gid_out = _merge_tiles(d2_flat, gid_flat, take, k)
         return stage.sync((
             d2_out.reshape(n_pad, k)[:n],
             gid_out.reshape(n_pad, k)[:n],
         ))
 
 
-def _inverted_lists(pts, k: int, probe, n_clusters: int, n_probe: int):
-    """Host side of the index, all NumPy: from the fetched probe table
-    to the member and query tables the search executor takes and the
-    pair bookkeeping the merge needs. Raises :class:`_GuardTripped` when
-    a pathology guard fires."""
-    n = len(pts)
-    assign = probe[:, 0]
-    # ---- host: SIZE-CAPPED inverted sublists ---------------------------
+def _exclusive_cumsum(counts):
+    return np.cumsum(counts) - counts
+
+
+def _inverted_lists(probe, k: int, n_clusters: int):
+    """The index, from the probe table where it lies on the device: the
+    member and query tables the search executor takes and, per probed
+    (query, cluster) cell, where the pair's result rows will lie (what
+    :func:`_take_table` turns into the merge's take table). Two jitted
+    programs build every table of ``n`` or ``n_pairs`` rows; between
+    them the host fetches two ``[C]`` vectors and two scalars, runs the
+    four pathology guards (raising :class:`_GuardTripped`) and does the
+    O(C + R) bookkeeping that decides the shapes."""
+    n, n_probe = probe.shape
+    chunk_b = _CHUNK_B
+    if n * n_probe >= (1 << 31):
+        # every pair takes a result row, so the row ids below pass the
+        # int32 bound as well; and the pairs' own ids would not fit
+        raise _GuardTripped(
+            "index_bound",
+            f"merge-gather row ids reach {n * n_probe:,} >= 2^31 "
+            "(int32 device gather would wrap)",
+        )
+    # ---- SIZE-CAPPED inverted sublists ---------------------------------
     # k-means on clustered data skews hard (one blob -> one giant
     # cluster); an uncapped member matrix sets Lmax = that cluster's
     # size, and every chunk probing it pays [B, Lmax] distance + top_k
@@ -398,20 +423,21 @@ def _inverted_lists(pts, k: int, probe, n_clusters: int, n_probe: int):
     # clusters are split into sublists of at most l_cap members; a query
     # probing the cluster searches all of its sublists (pairs expand
     # accordingly; the per-query merge pads to the max pair count).
-    order = np.argsort(assign, kind="stable")     # members in cluster order
-    sizes = np.bincount(assign, minlength=n_clusters)
-    starts = np.zeros(n_clusters, np.int64)
-    np.cumsum(sizes[:-1], out=starts[1:])
     l_cap = max(2 * (-(-n // n_clusters)), k + 1)
+    order, pair_cell, probe_subs, *small = _probe_census(
+        probe, n_clusters=n_clusters, l_cap=l_cap
+    )
+    small = jax.device_get(small)
+    sizes, c_queries = (v.astype(np.int64) for v in small[:2])
+    probed_min, p_max = (int(v) for v in small[2:])
+    starts = _exclusive_cumsum(sizes)
     n_subs_per_c = np.maximum(-(-sizes // l_cap), 1)
     n_sub = int(n_subs_per_c.sum())
     sub_cluster = np.repeat(np.arange(n_clusters), n_subs_per_c)
-    sub_first = np.zeros(n_clusters, np.int64)
-    np.cumsum(n_subs_per_c[:-1], out=sub_first[1:])
+    sub_first = _exclusive_cumsum(n_subs_per_c)
     sub_rank = np.arange(n_sub) - sub_first[sub_cluster]
     sub_start = starts[sub_cluster] + sub_rank * l_cap
-    sub_len = np.minimum(sizes[sub_cluster] - sub_rank * l_cap, l_cap)
-    sub_len = np.maximum(sub_len, 0)
+    sub_len = np.clip(sizes[sub_cluster] - sub_rank * l_cap, 0, l_cap)
     l_max = int(sub_len.max())
     if k >= sizes.max():
         # no cluster can fill its own top-k; recall craters — the honest
@@ -420,26 +446,6 @@ def _inverted_lists(pts, k: int, probe, n_clusters: int, n_probe: int):
             "k_unfillable",
             f"k={k} >= largest cluster size {int(sizes.max())}",
         )
-    # member id matrix [n_sub, Lmax] (clamps keep empty sublists
-    # in-bounds; their rows are fully masked)
-    j = np.arange(l_max)
-    m_rows = sub_start[:, None] + np.minimum(
-        j[None, :], np.maximum(sub_len[:, None] - 1, 0)
-    )
-    m_gid = order[np.minimum(m_rows, n - 1)].astype(np.int32)
-    m_valid = j[None, :] < sub_len[:, None]
-
-    # (query, sublist) pairs grouped by sublist, then chopped into
-    # FIXED-size chunks of B query slots: one hot sublist probed by half
-    # the queries would otherwise set a padded [Qmax] batch shape and an
-    # O(n_sub x Qmax x k) result — the first 262K run OOMed exactly
-    # there. Chunk rows bound the device working set independent of
-    # probe skew.
-    chunk_b = 4096
-    probe_subs = n_subs_per_c[probe]              # [N, p] sublists/probe
-    pairs_per_q = probe_subs.sum(axis=1)          # [N]
-    p_max = int(pairs_per_q.max())
-
     # Two pathology guards (code-review r5), both -> honest exact path:
     #
     # 1. CAPACITY: a query whose probed clusters hold < k+1 members
@@ -451,11 +457,10 @@ def _inverted_lists(pts, k: int, probe, n_clusters: int, n_probe: int):
     #    and [n_pairs, k] result buffers then scale with that skew —
     #    the same blowup class the sublist cap fixed on the member
     #    side. IVF has nothing to exploit on such a cloud anyway.
-    probed_sizes = sizes[probe].sum(axis=1)       # members across probes
-    if int(probed_sizes.min()) < k + 1:
+    if probed_min < k + 1:
         raise _GuardTripped(
             "capacity",
-            f"a query's probed clusters hold {int(probed_sizes.min())} "
+            f"a query's probed clusters hold {probed_min} "
             f"members < k+1={k + 1} (its top-k cannot fill)",
         )
     if p_max > 4 * n_probe:
@@ -465,33 +470,24 @@ def _inverted_lists(pts, k: int, probe, n_clusters: int, n_probe: int):
             f"{4 * n_probe} (one dominant cluster; IVF has no structure "
             "to exploit)",
         )
-    pair_q = np.repeat(
-        np.arange(n, dtype=np.int64), pairs_per_q
-    )
-    # expand each probed cluster c into sub_first[c] .. +n_subs_per_c[c]
-    flat_c = probe.reshape(-1).astype(np.int64)
-    flat_q_subs = probe_subs.reshape(-1)
-    pair_c = (
-        np.repeat(sub_first[flat_c], flat_q_subs)
-        + (
-            np.arange(int(flat_q_subs.sum()))
-            - np.repeat(
-                np.cumsum(flat_q_subs) - flat_q_subs, flat_q_subs
-            )
-        )
-    )
-    n_pairs = len(pair_q)
-    pair_order = np.argsort(pair_c, kind="stable")
-    q_counts = np.bincount(pair_c, minlength=n_sub)
-    q_starts = np.zeros(n_sub, np.int64)
-    np.cumsum(q_counts[:-1], out=q_starts[1:])
+    # (query, sublist) pairs grouped by sublist, then chopped into
+    # FIXED-size chunks of B query slots: one hot sublist probed by half
+    # the queries would otherwise set a padded [Qmax] batch shape and an
+    # O(n_sub x Qmax x k) result — the first 262K run OOMed exactly
+    # there. Chunk rows bound the device working set independent of
+    # probe skew. The sublists of one cluster are probed by the same
+    # queries, so the device sorted the pairs by CLUSTER (n * n_probe
+    # keys, not n_pairs) and a sublist's chunks are runs of its
+    # cluster's segment of that order.
+    q_counts = c_queries[sub_cluster]
+    n_pairs = int(q_counts.sum())
     chunks_per_s = -(-q_counts // chunk_b)       # ceil; 0 for unprobed
     r_rows = int(chunks_per_s.sum())
     # Loud int32 bound (ADVICE r5): the merge-gather take table indexes
-    # the flat [r_rows * chunk_b + 1] result rows, and jnp.asarray would
-    # SILENTLY downcast an int64 host table to int32 on device — a row id
-    # past 2^31-1 would wrap to a junk gather instead of failing. The
-    # junk-row sentinel id r_rows * chunk_b is the largest value stored.
+    # the flat [r_rows * chunk_b + 1] result rows in int32 on the device
+    # — a row id past 2^31-1 would wrap to a junk gather instead of
+    # failing. The junk-row sentinel id r_rows * chunk_b is the largest
+    # value stored.
     if r_rows * chunk_b >= (1 << 31):
         raise _GuardTripped(
             "index_bound",
@@ -499,38 +495,131 @@ def _inverted_lists(pts, k: int, probe, n_clusters: int, n_probe: int):
             "(int32 device gather would wrap)",
         )
     row_sub = np.repeat(np.arange(n_sub), chunks_per_s)
-    chunk_rank = (
-        np.arange(r_rows) - np.repeat(
-            np.cumsum(chunks_per_s) - chunks_per_s, chunks_per_s
-        )
+    chunk_first = _exclusive_cumsum(chunks_per_s)
+    chunk_rank = np.arange(r_rows) - np.repeat(chunk_first, chunks_per_s)
+    c_start = _exclusive_cumsum(c_queries)
+    row_start = c_start[sub_cluster[row_sub]] + chunk_rank * chunk_b
+    row_len = np.minimum(q_counts[row_sub] - chunk_rank * chunk_b, chunk_b)
+    # Valid (row, slot) cells in row-major order visit the pairs in
+    # sublist order (chunks ascend within each ascending sublist), so a
+    # pair's flat [R * B] result row is the first cell of its sublist's
+    # first chunk plus the query's rank among the cluster's probers; a
+    # cluster's sublists have the same chunk count, `stride` cells apart.
+    c_base = chunk_first[sub_first] * chunk_b - c_start
+    c_stride = chunks_per_s[sub_first] * chunk_b
+    row_sub = row_sub.astype(np.int32)
+    offsets = [a.astype(np.int32) for a in (
+        sub_start, sub_len, row_start, row_len, c_base, c_stride
+    )]
+    m_gid, m_valid, q_gid, slot_base, slot_stride = _index_tables(
+        order, pair_cell, probe, *offsets, l_max=l_max, chunk_b=chunk_b
     )
-    row_start = q_starts[row_sub] + chunk_rank * chunk_b
-    row_len = np.minimum(
-        q_counts[row_sub] - chunk_rank * chunk_b, chunk_b
-    )
-    jb = np.arange(chunk_b)
-    q_rows = row_start[:, None] + np.minimum(
-        jb[None, :], np.maximum(row_len[:, None] - 1, 0)
-    )
-    q_valid = jb[None, :] < row_len[:, None]
-    q_gid = pair_q[pair_order[q_rows]].astype(np.int32)  # [R, B]
-
-    # inverse mapping: valid (row, slot) cells in row-major order visit
-    # sorted pair positions 0..P-1 in order (chunks ascend within each
-    # ascending sublist), so each REAL pair's flat [R * B] result row is
-    # its valid-cell flat index.
-    slot_of_pair = np.empty(n_pairs, np.int64)
-    slot_of_pair[pair_order] = np.arange(
-        r_rows * chunk_b
-    ).reshape(r_rows, chunk_b)[q_valid]
     return SimpleNamespace(
-        m_gid=m_gid, m_valid=m_valid, q_gid=q_gid,
-        row_sub=row_sub.astype(np.int32), r_rows=r_rows, chunk_b=chunk_b,
-        p_max=p_max, n_pairs=n_pairs, pair_q=pair_q, pairs_per_q=pairs_per_q,
-        slot_of_pair=slot_of_pair,
+        m_gid=m_gid, m_valid=m_valid, q_gid=q_gid, row_sub=row_sub,
+        r_rows=r_rows, chunk_b=chunk_b, p_max=p_max, n_pairs=n_pairs,
+        slots=(probe_subs, slot_base, slot_stride),
         counts=dict(n_sub=n_sub, l_max=l_max, n_pairs=n_pairs, p_max=p_max,
                     chunk_rows=r_rows),
+        host_bytes=sum(a.nbytes for a in [*small, *offsets, row_sub]),
     )
+
+
+@partial(jax.jit, static_argnames=("n_clusters", "l_cap"))
+def _probe_census(probe, n_clusters: int, l_cap: int):
+    """What the host needs of the probe table ``[n, n_probe]`` to decide
+    the index's shapes, and the two orders its tables are cut from:
+
+    - ``order [n]``: the points in cluster order, ascending id inside a
+      cluster (a stable sort of column 0, the owning cluster);
+    - ``pair_cell [n * n_probe]``: the flat (query, probe column) cells
+      in cluster order, query-ascending inside a cluster (a stable sort
+      on the cluster id alone);
+    - ``probe_subs [n, n_probe]``: sublists behind each probed cluster;
+    - ``sizes [C]``, ``c_queries [C]``: members of, and queries probing,
+      each cluster; the fewest members any query's probes hold; the most
+      sublists any query probes (``p_max``).
+    """
+    n, n_probe = probe.shape
+    with jax.named_scope("ivf"), jax.named_scope("lists_census"):
+        edges = jnp.arange(n_clusters + 1, dtype=jnp.int32)
+
+        def by_cluster(keys):
+            keys, cells = lax.sort(
+                (keys, jnp.arange(keys.shape[0], dtype=jnp.int32)),
+                num_keys=1, is_stable=True,
+            )
+            return cells, jnp.diff(jnp.searchsorted(keys, edges))
+
+        order, sizes = by_cluster(probe[:, 0])
+        pair_cell, c_queries = by_cluster(probe.reshape(-1))
+        probe_sizes = sizes[probe]
+        probe_subs = jnp.maximum(-(-probe_sizes // l_cap), 1)
+        return (
+            order, pair_cell, probe_subs, sizes, c_queries,
+            probe_sizes.sum(axis=1).min(), probe_subs.sum(axis=1).max(),
+        )
+
+
+def _runs(flat, start, length, width: int):
+    """``[R, width]`` rows of ``flat``: row ``r`` is the run
+    ``flat[start[r] : start[r] + length[r]]``, its tail repeating the
+    run's last element (an empty run repeats ``flat[start[r]]``, clamped
+    into ``flat``); with the mask of real cells."""
+    last = flat[jnp.minimum(
+        start + jnp.maximum(length - 1, 0), flat.shape[0] - 1
+    )]
+    padded = jnp.pad(flat, (0, width))
+    rows = jax.vmap(lambda s: lax.dynamic_slice(padded, (s,), (width,)))(start)
+    real = jnp.arange(width)[None, :] < length[:, None]
+    return jnp.where(real, rows, last[:, None]), real
+
+
+@partial(jax.jit, static_argnames=("l_max", "chunk_b"))
+def _index_tables(
+    order, pair_cell, probe, sub_start, sub_len, row_start, row_len,
+    c_base, c_stride, l_max: int, chunk_b: int,
+):
+    """The tables of ``n`` and ``n_pairs`` rows, cut from
+    :func:`_probe_census`'s two orders at the offsets the host worked
+    out: member ids ``[n_sub, l_max]`` with their validity, the chunked
+    query ids ``[R, chunk_b]`` (padded slots repeat the chunk's last
+    real query), and per (query, probe column) cell the flat result row
+    of its pair in the cluster's first sublist and the distance to the
+    same query's row in the next."""
+    n, n_probe = probe.shape
+    with jax.named_scope("ivf"), jax.named_scope("lists_tables"):
+        m_gid, m_valid = _runs(order, sub_start, sub_len, l_max)
+        q_gid, _ = _runs(pair_cell // n_probe, row_start, row_len, chunk_b)
+        # a cell's rank in cluster order: the inverse permutation, by a
+        # sort (the keys are distinct) where a scatter is 3x slower
+        _, rank = lax.sort(
+            (pair_cell, jnp.arange(n * n_probe, dtype=jnp.int32)), num_keys=1
+        )
+        slot_base = rank.reshape(n, n_probe) + c_base[probe]
+        return m_gid, m_valid, q_gid, slot_base, c_stride[probe]
+
+
+@partial(jax.jit, static_argnames=("p_max", "junk", "merge_t"))
+def _take_table(probe_subs, slot_base, slot_stride, p_max: int, junk: int,
+                merge_t: int):
+    """The merge's take table ``[T, merge_t, p_max]``: each query's flat
+    result rows in probe-column order, a split cluster's sublists
+    ascending inside its column; columns past the query's pairs, and the
+    rows that pad ``n`` to whole tiles, hold the ``junk`` row."""
+    n = probe_subs.shape[0]
+    with jax.named_scope("ivf"), jax.named_scope("lists_take"):
+        col_start = jnp.cumsum(probe_subs, axis=1) - probe_subs
+        # [n, p_max, n_probe]: rank of column j inside each probed
+        # cluster's span of columns; exactly one cluster holds a real j
+        t = jnp.arange(p_max)[None, :, None] - col_start[:, None, :]
+        hit = (t >= 0) & (t < probe_subs[:, None, :])
+        rows = slot_base[:, None, :] + t * slot_stride[:, None, :]
+        take = jnp.where(
+            hit.any(axis=2), jnp.where(hit, rows, 0).sum(axis=2), junk
+        )
+        n_pad = -(-n // merge_t) * merge_t
+        take = jnp.pad(take, ((0, n_pad - n), (0, 0)), constant_values=junk)
+        return take.reshape(n_pad // merge_t, merge_t, p_max)
 
 
 @partial(jax.jit, static_argnames=("k",))

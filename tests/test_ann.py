@@ -194,7 +194,7 @@ def _oracle_select(d2, ids, k):
 
 
 def _search_block(valid_len, k, seed):
-    """One cluster's block as ``_inverted_lists`` lays it out: member ids
+    """One cluster's block as ``ann._index_tables`` lays it out: member ids
     ascending, padded slots repeating the last id behind ``m_valid``
     False; the first half of the queries are members (a self slot each),
     the second half come from outside the sublist."""
@@ -296,3 +296,293 @@ def test_selection_keeps_top_k_order(site, finite):
         np.asarray(got_d2).view(np.uint32), want_d2.view(np.uint32)
     )
     np.testing.assert_array_equal(np.asarray(got_ids), want_ids)
+
+
+# -- the index tables, built on the device (PR 34) ----------------------------
+#
+# ``_oracle_lists`` is the NumPy builder that lived in ``ops/ann.py`` until
+# PR 34 (``_inverted_lists`` and the take table of ``ivf_knn``'s second
+# ``ivf_lists`` span), kept as the plain reference: stable argsorts,
+# ``repeat`` / ``cumsum`` expansions and fancy indexing over the (query,
+# sublist) pairs, nothing of the device builder's sorts by cluster and runs.
+# Why each step is what it is stays written in ``ops/ann.py``.
+
+
+def _oracle_lists(n, k, probe, n_clusters, chunk_b=4096, merge_t=16384):
+    from types import SimpleNamespace
+
+    from graphmine_tpu.ops.ann import _GuardTripped
+
+    n_probe = probe.shape[1]
+    assign = probe[:, 0]
+    order = np.argsort(assign, kind="stable")     # members in cluster order
+    sizes = np.bincount(assign, minlength=n_clusters)
+    starts = np.zeros(n_clusters, np.int64)
+    np.cumsum(sizes[:-1], out=starts[1:])
+    l_cap = max(2 * (-(-n // n_clusters)), k + 1)
+    n_subs_per_c = np.maximum(-(-sizes // l_cap), 1)
+    n_sub = int(n_subs_per_c.sum())
+    sub_cluster = np.repeat(np.arange(n_clusters), n_subs_per_c)
+    sub_first = np.zeros(n_clusters, np.int64)
+    np.cumsum(n_subs_per_c[:-1], out=sub_first[1:])
+    sub_rank = np.arange(n_sub) - sub_first[sub_cluster]
+    sub_start = starts[sub_cluster] + sub_rank * l_cap
+    sub_len = np.minimum(sizes[sub_cluster] - sub_rank * l_cap, l_cap)
+    sub_len = np.maximum(sub_len, 0)
+    l_max = int(sub_len.max())
+    if k >= sizes.max():
+        raise _GuardTripped(
+            "k_unfillable",
+            f"k={k} >= largest cluster size {int(sizes.max())}",
+        )
+    j = np.arange(l_max)
+    m_rows = sub_start[:, None] + np.minimum(
+        j[None, :], np.maximum(sub_len[:, None] - 1, 0)
+    )
+    m_gid = order[np.minimum(m_rows, n - 1)].astype(np.int32)
+    m_valid = j[None, :] < sub_len[:, None]
+
+    probe_subs = n_subs_per_c[probe]              # [N, p] sublists/probe
+    pairs_per_q = probe_subs.sum(axis=1)          # [N]
+    p_max = int(pairs_per_q.max())
+    probed_sizes = sizes[probe].sum(axis=1)       # members across probes
+    if int(probed_sizes.min()) < k + 1:
+        raise _GuardTripped(
+            "capacity",
+            f"a query's probed clusters hold {int(probed_sizes.min())} "
+            f"members < k+1={k + 1} (its top-k cannot fill)",
+        )
+    if p_max > 4 * n_probe:
+        raise _GuardTripped(
+            "skew",
+            f"probe expansion {p_max} sublists/query > 4*n_probe="
+            f"{4 * n_probe} (one dominant cluster; IVF has no structure "
+            "to exploit)",
+        )
+    pair_q = np.repeat(np.arange(n, dtype=np.int64), pairs_per_q)
+    # expand each probed cluster c into sub_first[c] .. +n_subs_per_c[c]
+    flat_c = probe.reshape(-1).astype(np.int64)
+    flat_q_subs = probe_subs.reshape(-1)
+    pair_c = (
+        np.repeat(sub_first[flat_c], flat_q_subs)
+        + (
+            np.arange(int(flat_q_subs.sum()))
+            - np.repeat(np.cumsum(flat_q_subs) - flat_q_subs, flat_q_subs)
+        )
+    )
+    n_pairs = len(pair_q)
+    pair_order = np.argsort(pair_c, kind="stable")
+    q_counts = np.bincount(pair_c, minlength=n_sub)
+    q_starts = np.zeros(n_sub, np.int64)
+    np.cumsum(q_counts[:-1], out=q_starts[1:])
+    chunks_per_s = -(-q_counts // chunk_b)       # ceil; 0 for unprobed
+    r_rows = int(chunks_per_s.sum())
+    if r_rows * chunk_b >= (1 << 31):
+        raise _GuardTripped(
+            "index_bound",
+            f"merge-gather row ids reach {r_rows * chunk_b:,} >= 2^31 "
+            "(int32 device gather would wrap)",
+        )
+    row_sub = np.repeat(np.arange(n_sub), chunks_per_s)
+    chunk_rank = (
+        np.arange(r_rows) - np.repeat(
+            np.cumsum(chunks_per_s) - chunks_per_s, chunks_per_s
+        )
+    )
+    row_start = q_starts[row_sub] + chunk_rank * chunk_b
+    row_len = np.minimum(q_counts[row_sub] - chunk_rank * chunk_b, chunk_b)
+    jb = np.arange(chunk_b)
+    q_rows = row_start[:, None] + np.minimum(
+        jb[None, :], np.maximum(row_len[:, None] - 1, 0)
+    )
+    q_valid = jb[None, :] < row_len[:, None]
+    q_gid = pair_q[pair_order[q_rows]].astype(np.int32)  # [R, B]
+    # valid (row, slot) cells in row-major order visit sorted pair
+    # positions 0..P-1 in order, so each REAL pair's flat [R * B] result
+    # row is its valid-cell flat index
+    slot_of_pair = np.empty(n_pairs, np.int64)
+    slot_of_pair[pair_order] = np.arange(
+        r_rows * chunk_b
+    ).reshape(r_rows, chunk_b)[q_valid]
+
+    # the merge's take table: a query's pairs in pair order, short
+    # queries and the rows past n padded with the junk row
+    n_pad = -(-n // merge_t) * merge_t
+    take = np.full((n_pad, p_max), r_rows * chunk_b, np.int64)
+    pair_col = (
+        np.arange(n_pairs)
+        - np.repeat(np.cumsum(pairs_per_q) - pairs_per_q, pairs_per_q)
+    )
+    take[pair_q, pair_col] = slot_of_pair
+    take = take.astype(np.int32).reshape(n_pad // merge_t, merge_t, p_max)
+    return SimpleNamespace(
+        m_gid=m_gid, m_valid=m_valid, q_gid=q_gid,
+        row_sub=row_sub.astype(np.int32), r_rows=r_rows, p_max=p_max,
+        n_pairs=n_pairs, n_sub=n_sub, q_counts=q_counts, take=take,
+    )
+
+
+def _list_cloud(case):
+    """``(points, centers, n_probe, k)`` of one table case."""
+    rng = np.random.default_rng(34)
+    f = 8
+    if case == "skewed":
+        # 40% of the mass in one tight blob: its cluster splits
+        tight = rng.normal(size=(4800, f)) * 0.1
+        pts = np.concatenate([tight, rng.normal(size=(7200, f)) * 5])
+        pts = pts.astype(np.float32)
+        return pts, kmeans(pts, 16, seed=0), 8, 16
+    pts = rng.normal(size=(9000, f)).astype(np.float32)
+    if case == "balanced":
+        return pts, kmeans(pts, 96, seed=0), 16, 24
+    if case == "spill":
+        # 12 clusters probed by 9000 * 6 / 12 queries each, more than one
+        # chunk of 4096; a 13th centre far from every point, which owns
+        # nothing and which no query probes
+        centers = np.asarray(kmeans(pts, 12, seed=0))
+        far = np.full((1, f), 1e3, np.float32)
+        return pts, np.concatenate([centers, far]), 6, 16
+    # untrained centres handed in, as ``centers=`` takes them: a sample of
+    # the points, neither a multiple of 8 nor balanced (some split)
+    return pts, pts[rng.choice(len(pts), 37, replace=False)], 5, 16
+
+
+@pytest.mark.parametrize("case", ["balanced", "skewed", "spill", "centers"])
+def test_device_built_index_tables_equal_the_numpy_reference(case):
+    """Every table of the index, array for array: members of a sublist in
+    ascending id, pairs grouped by sublist and query-ascending inside it,
+    chunks of 4096 in sublist order with padded slots repeating the last
+    real query, a query's result rows in probe-column order in ``take``
+    (a split cluster's sublists ascending inside its column), the junk
+    row everywhere else."""
+    import jax.numpy as jnp
+
+    from graphmine_tpu.ops import ann
+    from graphmine_tpu.ops.knn import cross_knn
+
+    pts, centers, n_probe, k = _list_cloud(case)
+    n, n_clusters = len(pts), len(centers)
+    _, probe = cross_knn(jnp.asarray(pts), jnp.asarray(centers), n_probe)
+    want = _oracle_lists(n, k, np.asarray(probe), n_clusters)
+    # the case holds what its name says
+    assert (want.p_max > n_probe) == (case in ("skewed", "centers"))
+    assert ((want.q_counts > ann._CHUNK_B).any()
+            and (want.q_counts == 0).any()) == (case == "spill")
+
+    got = ann._inverted_lists(probe, k, n_clusters)
+    for name in ("m_gid", "m_valid", "q_gid", "row_sub"):
+        table = np.asarray(getattr(got, name))
+        assert table.dtype == getattr(want, name).dtype, name
+        np.testing.assert_array_equal(table, getattr(want, name), name)
+    assert (got.r_rows, got.p_max, got.n_pairs, got.counts["n_sub"]) == (
+        want.r_rows, want.p_max, want.n_pairs, want.n_sub
+    )
+    take = ann._take_table(
+        *got.slots, p_max=got.p_max, junk=got.r_rows * ann._CHUNK_B,
+        merge_t=ann._MERGE_T,
+    )
+    assert take.dtype == want.take.dtype
+    np.testing.assert_array_equal(np.asarray(take), want.take)
+    # nothing of n or n_pairs rows crossed to the host to get there
+    assert got.host_bytes < 16 * (n_clusters + want.n_sub + want.r_rows) + 64
+
+
+def _guard_cloud(guard):
+    """``(points, k, ivf_knn keywords)`` on which ``guard`` fires."""
+    rng = np.random.default_rng(0)
+    if guard == "k_unfillable":
+        # k above any cluster's size
+        return rng.normal(size=(64, 4)).astype(np.float32), 40, dict(n_clusters=8)
+    if guard == "capacity":
+        # one probe a query, and a far cluster of 10 points for k = 20
+        pts = np.concatenate([
+            rng.normal(size=(190, 4)), rng.normal(size=(10, 4)) + 100.0
+        ]).astype(np.float32)
+        centers = np.concatenate([pts[:3], pts[-1:]])
+        return pts, 20, dict(centers=centers, n_probe=1)
+    # duplicate rows pile into one cluster (see the skew test above)
+    dup = np.tile(rng.normal(size=(1, 8)), (7200, 1))
+    pts = np.concatenate([dup, rng.normal(size=(800, 8)) * 8])
+    return pts.astype(np.float32), 16, dict(n_clusters=64, n_probe=8)
+
+
+@pytest.mark.parametrize(
+    "guard", ["k_unfillable", "capacity", "skew", "index_bound"]
+)
+def test_each_guard_trips_as_the_reference_does_and_lands_exact(
+    guard, monkeypatch
+):
+    """The four guards fire from the device's reductions on the inputs,
+    under the names and with the details the NumPy builder gave, and end
+    in ``_exact_fallback``: a warning, an ``ivf_fallback`` record, the
+    exact neighbours."""
+    import jax.numpy as jnp
+
+    from graphmine_tpu.ops import ann
+    from graphmine_tpu.ops.knn import cross_knn
+    from graphmine_tpu.pipeline.metrics import MetricsSink
+
+    chunk_b = ann._CHUNK_B
+    if guard == "index_bound":
+        # no cloud a test can hold has 2^31 result rows: make the rows
+        # 2^26 slots long instead, on the balanced cloud of the tables test
+        chunk_b = 1 << 26
+        monkeypatch.setattr(ann, "_CHUNK_B", chunk_b)
+        pts, centers, n_probe, k = _list_cloud("balanced")
+        kw = dict(centers=centers, n_probe=n_probe)
+    else:
+        pts, k, kw = _guard_cloud(guard)
+    sink = MetricsSink()
+    with pytest.warns(UserWarning, match=f"ivf_knn guard '{guard}'"):
+        d2, idx = ivf_knn(pts, k=k, sink=sink, **kw)
+    (rec,) = sink.of_phase("ivf_fallback")
+    assert rec["guard"] == guard
+    want_d2, want_idx = knn(pts, k, impl="auto")
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_idx))
+    np.testing.assert_array_equal(np.asarray(d2), np.asarray(want_d2))
+
+    # the reference trips the same guard with the same words
+    centers = kw.get("centers")
+    if centers is None:
+        centers = kmeans(pts, kw["n_clusters"], iters=5, seed=0)
+    n_probe = min(kw.get("n_probe", 16), len(centers))
+    _, probe = cross_knn(jnp.asarray(pts), jnp.asarray(centers), n_probe)
+    with pytest.raises(ann._GuardTripped) as tripped:
+        _oracle_lists(
+            len(pts), k, np.asarray(probe), len(centers), chunk_b=chunk_b
+        )
+    assert (tripped.value.guard, tripped.value.detail) == (guard, rec["detail"])
+
+
+def test_ivf_knn_equals_a_run_through_the_reference_tables(clouds):
+    """``ivf_knn``'s ``(d2, idx)`` against the same search and merge fed
+    the NumPy reference's tables: bit for bit."""
+    import jax.numpy as jnp
+
+    from graphmine_tpu.ops import ann
+    from graphmine_tpu.ops.knn import cross_knn
+
+    pts, k, n_probe = clouds["blobs"], 8, 8
+    n, n_clusters = len(pts), ann.default_n_clusters(len(pts))
+    d2, idx = ivf_knn(pts, k=k, n_probe=n_probe)
+
+    centers = kmeans(pts, n_clusters, iters=5, seed=0)
+    _, probe = cross_knn(jnp.asarray(pts), centers, n_probe)
+    ref = _oracle_lists(n, k, np.asarray(probe), n_clusters)
+    d2_all, gid_all = ann._search_chunks(
+        pts, ref.m_gid, ref.m_valid, ref.q_gid, ref.row_sub, k
+    )
+    d2_flat = np.concatenate(
+        [np.asarray(d2_all).reshape(-1, k), np.full((1, k), np.inf, np.float32)]
+    )
+    gid_flat = np.concatenate(
+        [np.asarray(gid_all).reshape(-1, k), np.full((1, k), -1, np.int32)]
+    )
+    want_d2, want_idx = ann._merge_tiles(d2_flat, gid_flat, ref.take, k)
+    np.testing.assert_array_equal(
+        np.asarray(d2).view(np.uint32),
+        np.asarray(want_d2).reshape(-1, k)[:n].view(np.uint32),
+    )
+    np.testing.assert_array_equal(
+        np.asarray(idx), np.asarray(want_idx).reshape(-1, k)[:n]
+    )

@@ -128,6 +128,45 @@ def test_ivf_selection_compiles_without_a_gather(one_chip, site):
     assert not gathers, gathers[:2]
 
 
+def test_ivf_list_programs_compile_for_v5e(one_chip):
+    """The three programs that build the IVF index on the device (ISSUE
+    34) at the pipeline cell's shapes: 262,144 x 16 probes of 512
+    clusters, 640 sublists of 1,024 members, 1,295 chunks, 18 pairs a
+    query. They sort twice where a scatter would invert the order, and
+    cut the member and query tables as runs, not index by index: the
+    only gathers of ``n * n_probe`` indices left are the three lookups in
+    the 512-entry cluster tables."""
+    from graphmine_tpu.ops.ann import _index_tables, _probe_census, _take_table
+
+    def i32(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.int32, sharding=one_chip)
+
+    n, n_probe, c, n_sub, l_max, r_rows, p_max = 262144, 16, 512, 640, 1024, 1295, 18
+    cell = i32(n, n_probe)
+    programs = {
+        "census": _compile(_probe_census, cell, n_clusters=c, l_cap=1024),
+        "tables": _compile(
+            _index_tables, i32(n), i32(n * n_probe), cell, i32(n_sub),
+            i32(n_sub), i32(r_rows), i32(r_rows), i32(c), i32(c),
+            l_max=l_max, chunk_b=4096,
+        ),
+        "take": _compile(
+            _take_table, cell, cell, cell, p_max=p_max, junk=r_rows * 4096,
+            merge_t=16384,
+        ),
+    }
+    wide = {}
+    for name, compiled in programs.items():
+        hlo = compiled.as_text()
+        assert " scatter(" not in hlo, name
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30, name
+        wide[name] = sum(
+            " gather(" in line and f"s32[{n},{n_probe}]" in line.split(" gather(")[0]
+            for line in hlo.splitlines()
+        )
+    assert wide == {"census": 1, "tables": 2, "take": 0}
+
+
 def test_lpa_superstep_bucketed_compiles_for_v5e(one_chip, fused_plan, planted):
     from graphmine_tpu.ops.bucketed_mode import lpa_superstep_bucketed
 

@@ -299,8 +299,13 @@ def test_run_pipeline_yields_every_stage_span_and_the_same_answers(
         assert names.count("ivf_lists") == 2  # before the search, and after
         (train,) = _by_name(under_lof, "ivf_train")
         assert (train["n"], train["k"]) == (n, 16) and train["n_clusters"] >= 8
-        lists = _by_name(under_lof, "ivf_lists")[0]
+        lists, take = _by_name(under_lof, "ivf_lists")
         assert lists["n_sub"] >= train["n_clusters"] and lists["l_max"] > 16
+        # the index is built where the probe table lies: no table of n or
+        # n_pairs rows is fetched or handed on as a host array (4 n bytes
+        # is one int32 column of the probe table)
+        assert 0 < lists["host_bytes"] < 4 * n < 1 << 20
+        assert take["host_bytes"] == 0 and take["p_max"] == lists["p_max"]
         (search,) = _by_name(under_lof, "ivf_search")
         assert search["n_pairs"] == lists["n_pairs"] >= n
         assert search["chunk_rows"] == lists["chunk_rows"] >= 1
