@@ -258,8 +258,10 @@ register("shard_degraded", "shard", "status", "reason")
 # "Fleet tracing") ---------------------------------------------------------
 # delta_stages: one per accepted delta batch at publish time, emitted in
 # the BATCH's own trace (the propagated traceparent context) — the
-# writer-side causal chain: admission accept -> WAL fsync -> queued ->
-# apply -> snapshot publish, each stage in seconds; delta_visible: one
+# writer-side causal chain on the spans' clock: admission accept -> WAL
+# fsync -> queued -> apply (the `delta_apply` span) -> commit (engine
+# swap, WAL watermark, epoch), each stage in seconds under `stages`
+# (DELTA_STAGES below; `total_s` is their sum); delta_visible: one
 # per (delta, replica) from the fleet router when a replica first serves
 # the version that absorbed the delta — the read-side tail of
 # time-to-visible, feeding the router's merged histogram.
@@ -367,6 +369,39 @@ DEVICE_SCOPES = frozenset((
 ))
 
 
+# Every stage span of the package, once: the literal names
+# ``obs/spans.stage_span`` is called with. A stage is a ``span`` record
+# at close and a ``TraceAnnotation`` on the profiler's clock, one level
+# or more under a chapter span of the run (or under ``delta_apply`` on
+# the served write path); the benchmark's readers and the idle-gap
+# labels of a device trace go by these names, so they are registered
+# like the named scopes above and ``tools/schema_lint.py`` holds the
+# package to the list both ways (docs/OBSERVABILITY.md "Stage spans").
+STAGE_SPANS = frozenset((
+    # under `load`
+    "ingest_file", "ingest_decode", "ingest_intern", "ingest_concat",
+    # under `outliers_recursive_lpa`
+    "masked_lpa", "decile_report",
+    # under `outliers_lof` (and wherever else ivf_knn / lof_scores run)
+    "lof_features", "triangles_host", "triangles_device",
+    "features_device", "ivf_train", "ivf_probe", "ivf_lists", "ivf_search",
+    "ivf_merge", "knn_exact", "lof_formula",
+    # the write path to a snapshot. Under `snapshot_publish` (pipeline):
+    # the CC; under it and under `delta_apply` alike, the five stages of
+    # serve/snapshot.publish_result
+    "publish_cc", "publish_fetch", "publish_canary", "publish_fingerprint",
+    "publish_write", "publish_quality",
+    # under `delta_apply` (serve/delta.DeltaIngestor.apply)
+    "delta_splice", "delta_build_graph", "delta_repair", "delta_lof",
+    "delta_census",
+))
+
+# The stages of one served delta's `delta_stages` record, in order; the
+# last is the sum of the others to the microsecond. `wal_fsync_s` is
+# absent on a server without a WAL.
+DELTA_STAGES = ("wal_fsync_s", "queued_s", "apply_s", "commit_s", "total_s")
+
+
 # The `cost` sub-record shape (obs/costmodel.CostEstimate.record — the
 # single builder; tools/schema_lint.py flags inline cost={...} literals
 # elsewhere in the package). Like trace identity, the sub-record is
@@ -432,6 +467,13 @@ def validate_record(rec) -> list:
             problems.append(
                 f"{phase}: tenant key {tval!r} does not match the tenant-id "
                 "grammar [a-z0-9_-]{1,64} (serve/tenancy.py)"
+            )
+    if phase == "delta_stages" and isinstance(rec.get("stages"), dict):
+        unknown = sorted(set(rec["stages"]) - set(DELTA_STAGES))
+        if unknown:
+            problems.append(
+                f"{phase}: unknown stage(s) {unknown} — the stages of a "
+                "served delta are schema.DELTA_STAGES"
             )
     for key in rec:
         if not key.endswith("_sketch"):

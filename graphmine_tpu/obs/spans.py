@@ -273,11 +273,21 @@ def xla_annotation(name: str):
 
 class _Stage:
     """What :func:`stage_span` yields: the two things a stage does to
-    its own span. Without a sink both do nothing, so an ops function
-    called with ``sink=None`` records nothing and syncs nothing."""
+    its own span, and the span's seconds. Without a sink both do
+    nothing, so an ops function called with ``sink=None`` records
+    nothing and syncs nothing."""
 
-    def __init__(self, sink=None):
+    def __init__(self, sink=None, span=None):
         self._sink = sink
+        self._span = span
+
+    @property
+    def seconds(self) -> float | None:
+        """The stage's span seconds (its age while open): what a caller
+        that also writes a summary record reports, instead of holding a
+        second clock beside the span's. ``None`` where there is no span
+        (no sink, or a sink without a tracer)."""
+        return None if self._span is None else self._span.seconds
 
     def note(self, **attrs) -> None:
         """Counts that explain the seconds, set on the open span."""
@@ -310,5 +320,5 @@ def stage_span(sink, name: str, **attrs):
     if sink is None:
         yield _NO_STAGE
         return
-    with sink.span(name, **attrs):
-        yield _Stage(sink)
+    with sink.span(name, **attrs) as span:
+        yield _Stage(sink, span)

@@ -191,6 +191,15 @@ def run_pipeline(config: PipelineConfig) -> PipelineResult:
         # flush must not mask the pipeline's own outcome.
         if hb is not None:
             hb.stop()
+        # The root span closes here and is written like every other
+        # span, so the seconds of a run that no chapter span holds can
+        # be read (root minus its children) and need not be inferred.
+        root = tracer.close()
+        m.emit(
+            "span", _span=root, name=root.name,
+            seconds=round(root.seconds, 4),
+            status="ok" if run_err is None else "error",
+        )
         if run_err is None:
             m.emit("run_end", ok=True)
         else:
@@ -198,7 +207,6 @@ def run_pipeline(config: PipelineConfig) -> PipelineResult:
                 "run_end", ok=False, error=resilience.classify_error(run_err),
                 error_detail=repr(run_err),
             )
-        tracer.close()
         import logging
 
         if config.prom_out:
@@ -715,69 +723,83 @@ def _publish_snapshot(config: PipelineConfig, result: PipelineResult, m: Metrics
     just ruled out materializing them on one device. Wrapped in
     ``run_phase`` so transient publish weather retries like any phase.
     """
-    from graphmine_tpu.serve.snapshot import SnapshotStore
+    from graphmine_tpu.serve.snapshot import SnapshotStore, publish_result
 
     table, graph = result.edge_table, result.graph
     n_dev = config.num_devices or _visible_devices()
 
     def _publish():
         resilience.fault_point("snapshot_publish")
-        if isinstance(graph.src, np.ndarray):
-            from graphmine_tpu.parallel.mesh import make_mesh
-            from graphmine_tpu.parallel.sharded import (
-                partition_graph,
-                shard_graph_arrays,
-                sharded_connected_components,
-            )
+        # Stage spans (docs/OBSERVABILITY.md "Stage spans"): the CC and
+        # the fetch of its labels are `publish_cc`; the five stages of
+        # the shared tail (serve/snapshot.publish_result) follow it.
+        with stage_span(m, "publish_cc") as stage:
+            if isinstance(graph.src, np.ndarray):
+                from graphmine_tpu.parallel.mesh import make_mesh
+                from graphmine_tpu.parallel.sharded import (
+                    partition_graph,
+                    shard_graph_arrays,
+                    sharded_connected_components,
+                )
 
-            from graphmine_tpu.obs.costmodel import (
-                emit_superstep_timing,
-                sharded_superstep_cost,
-                timed_fixpoint,
-            )
+                from graphmine_tpu.obs.costmodel import (
+                    emit_superstep_timing,
+                    sharded_superstep_cost,
+                    timed_fixpoint,
+                )
 
-            mesh = make_mesh(n_dev)
-            sg = shard_graph_arrays(partition_graph(graph, mesh=mesh), mesh)
-            # telemetry=True returns the real supersteps-to-fixpoint on
-            # the existing while-loop carry (no extra device syncs) — the
-            # CC phase's achieved-vs-model window (ISSUE 12).
-            (cc_labels, tele), secs, cold = timed_fixpoint(
-                lambda: sharded_connected_components(sg, mesh, telemetry=True)
-            )
-            emit_superstep_timing(
-                m, "cc_superstep",
-                sharded_superstep_cost(
-                    "cc_superstep", sg, graph.num_edges,
-                    num_messages=graph.num_messages, weighted=False,
-                ),
-                tele.iterations, tele.iterations, secs, graph.num_edges,
-                variant="sharded", cold_compile=cold,
-            )
+                mesh = make_mesh(n_dev)
+                sg = shard_graph_arrays(
+                    partition_graph(graph, mesh=mesh), mesh
+                )
+                # telemetry=True returns the real supersteps-to-fixpoint
+                # on the existing while-loop carry (no extra device
+                # syncs) — the CC phase's achieved-vs-model window
+                # (ISSUE 12).
+                (cc_labels, tele), secs, cold = timed_fixpoint(
+                    lambda: sharded_connected_components(
+                        sg, mesh, telemetry=True
+                    )
+                )
+                supersteps = tele.iterations
+                emit_superstep_timing(
+                    m, "cc_superstep",
+                    sharded_superstep_cost(
+                        "cc_superstep", sg, graph.num_edges,
+                        num_messages=graph.num_messages, weighted=False,
+                    ),
+                    supersteps, supersteps, secs, graph.num_edges,
+                    variant="sharded", cold_compile=cold,
+                )
+            else:
+                from graphmine_tpu.ops.cc import connected_components
+
+                # sink=m: the auto seam emits impl_selected/plan_build
+                # AND the CC phase's superstep_timing record (ops/cc.py);
+                # with a sink the program counts its supersteps anyway.
+                cc_labels, supersteps = connected_components(
+                    graph, return_iterations=True, sink=m
+                )
             cc = np.asarray(cc_labels)
-        else:
-            from graphmine_tpu.ops.cc import connected_components
-
-            # sink=m: the auto seam emits impl_selected/plan_build AND
-            # the CC phase's superstep_timing record (ops/cc.py).
-            cc = np.asarray(connected_components(graph, sink=m))
+            stage.note(supersteps=int(supersteps))
         present, sizes, edge_counts = result.community_table
-        arrays = {
-            "src": np.asarray(table.src, np.int32),
-            "dst": np.asarray(table.dst, np.int32),
-            "labels": np.asarray(result.labels, np.int32),
-            "cc_labels": cc.astype(np.int32),
-            "census_present": np.asarray(present),
-            "census_sizes": np.asarray(sizes),
-            "census_edges": np.asarray(edge_counts),
+        columns = {
+            "src": (table.src, np.int32),
+            "dst": (table.dst, np.int32),
+            "labels": (result.labels, np.int32),
+            "cc_labels": (cc, np.int32),
+            "census_present": present,
+            "census_sizes": sizes,
+            "census_edges": edge_counts,
         }
         if result.lof is not None:
-            arrays["lof"] = np.asarray(result.lof, np.float32)
+            columns["lof"] = (result.lof, np.float32)
         if table.weights is not None:
             # Preserved so queries/provenance keep the real graph; the
             # delta-repair path refuses weighted snapshots loudly (its
             # propagations are unweighted — repairing weighted-LPA labels
             # with unweighted supersteps would silently change semantics).
-            arrays["weights"] = np.asarray(table.weights, np.float32)
+            columns["weights"] = (table.weights, np.float32)
         store = SnapshotStore(config.snapshot_out)
         # Result-quality plane (ISSUE 13, docs/OBSERVABILITY.md "Result
         # quality"): a driver publish is the version chain's first link —
@@ -785,55 +807,42 @@ def _publish_snapshot(config: PipelineConfig, result: PipelineResult, m: Metrics
         # SAME frozen probe, read the parent's result columns for drift,
         # and emit quality_snapshot/quality_drift/canary_score in the
         # publishing trace. GRAPHMINE_QUALITY=0 disables; failures are
-        # telemetry-only and must never fail the publish phase.
-        quality_on = os.environ.get("GRAPHMINE_QUALITY", "1") != "0"
-        parent_arrays, parent_meta, canary = {}, {}, None
-        if quality_on:
+        # telemetry-only and never fail the publish phase (publish_result).
+        parent: dict = {"arrays": {}, "meta": {}}
+
+        def _canary():
             from graphmine_tpu.obs.quality import CanaryProbe
 
-            try:
-                peeked = store.peek_arrays(
-                    ("labels", "lof", "canary_features", "canary_is_anomaly")
-                )
-                if peeked is not None:
-                    parent_arrays, parent_meta = peeked
-                canary = CanaryProbe.from_arrays(parent_arrays, parent_meta)
-                if canary is None:
-                    canary = CanaryProbe.generate(
-                        seed=int(os.environ.get("GRAPHMINE_CANARY_SEED", "0"))
-                    )
-                arrays.update(canary.arrays())
-            except Exception as e:  # noqa: BLE001 — telemetry only
-                m.emit("warning", message=f"canary probe unavailable: {e!r}")
-                canary = None
-        snap = store.publish(
-            arrays,
-            fingerprint=ckpt.graph_fingerprint(
-                table.src, table.dst, table.weights
-            ),
-            run_id=m.tracer.run_id if m.tracer is not None else "",
-            mesh_shape=[n_dev],
-            extra_meta={"canary": canary.meta()} if canary is not None
-            else None,
-            sink=m,
-        )
-        if quality_on:
+            peeked = store.peek_arrays(
+                ("labels", "lof", "canary_features", "canary_is_anomaly")
+            )
+            if peeked is not None:
+                parent["arrays"], parent["meta"] = peeked
+            return CanaryProbe.from_arrays(
+                parent["arrays"], parent["meta"]
+            ) or CanaryProbe.generate(
+                seed=int(os.environ.get("GRAPHMINE_CANARY_SEED", "0"))
+            )
+
+        def _quality(snap, arrays, canary):
             from graphmine_tpu.obs.quality import run_quality_pass
 
-            try:
-                run_quality_pass(
-                    arrays["labels"], arrays.get("lof"), snap.version,
-                    parent_labels=parent_arrays.get("labels"),
-                    parent_lof=parent_arrays.get("lof"),
-                    parent_version=parent_meta.get("version"),
-                    canary=canary, sink=m, registry=m.registry,
-                )
-            except Exception as e:  # noqa: BLE001 — telemetry only: the
-                # publish already COMMITTED; raising here would hand a
-                # succeeded publish to run_phase as a failure and a
-                # retry would publish a duplicate version
-                m.emit("warning", message=f"quality pass failed: {e!r}")
-        return snap
+            run_quality_pass(
+                arrays["labels"], arrays.get("lof"), snap.version,
+                parent_labels=parent["arrays"].get("labels"),
+                parent_lof=parent["arrays"].get("lof"),
+                parent_version=parent["meta"].get("version"),
+                canary=canary, sink=m, registry=m.registry,
+            )
+
+        quality_on = os.environ.get("GRAPHMINE_QUALITY", "1") != "0"
+        return publish_result(
+            store, columns, sink=m,
+            canary=_canary if quality_on else None,
+            quality=_quality if quality_on else None,
+            run_id=m.tracer.run_id if m.tracer is not None else "",
+            mesh_shape=[n_dev],
+        )
 
     with m.span("snapshot_publish"):
         resilience.run_phase(

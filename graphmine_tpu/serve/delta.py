@@ -52,9 +52,13 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from graphmine_tpu.obs.spans import stage_span
 from graphmine_tpu.pipeline import resilience
-from graphmine_tpu.pipeline.checkpoint import graph_fingerprint
-from graphmine_tpu.serve.snapshot import Snapshot, SnapshotStore
+from graphmine_tpu.serve.snapshot import (
+    Snapshot,
+    SnapshotStore,
+    publish_result,
+)
 
 # Growth guard: a typo'd insert id must not allocate a billion-row label
 # vector. Inserts past current V + this bound are quarantined.
@@ -971,6 +975,9 @@ class DeltaIngestor:
             quality = os.environ.get("GRAPHMINE_QUALITY", "1") != "0"
         self.quality_enabled = bool(quality)
         self.last_quality = None       # QualityReport of the last apply
+        # the `delta_apply` Span of the last apply (None without a
+        # tracer): the apply worker reads its two ends for `apply_s`
+        self.last_apply_span = None
         self._quality_state = None     # parent state reused next apply
         self._canary = None
         if self.quality_enabled:
@@ -1155,11 +1162,16 @@ class DeltaIngestor:
                 f"lof_mode must be 'refresh' or 'defer', got {lof_mode!r}"
             )
         t0 = time.perf_counter()
-        span = (
-            self.sink.span("delta_apply") if self.sink is not None
-            else _null_ctx()
-        )
-        with span:
+        sink = self.sink
+        span = sink.span("delta_apply") if sink is not None else _null_ctx()
+        # Stage spans (docs/OBSERVABILITY.md "Stage spans"): five
+        # `delta_*` stages, then the five `publish_*` stages of the tail
+        # this writer shares with the pipeline's publish chapter
+        # (serve/snapshot.publish_result). Each stage ends in a host
+        # fetch or is host-only, so a span's close is where the host
+        # already waited; `compile` records land under the stage whose
+        # program compiled.
+        with span as self.last_apply_span:
             # Parent snapshot's result columns, captured BEFORE the
             # repair overwrites them: the quality pass's drift baseline.
             # References, not copies — the LOF splice is copy-on-write
@@ -1167,76 +1179,68 @@ class DeltaIngestor:
             # parent's arrays.
             prev_labels, prev_lof = self.labels, self.lof
             prev_version = self.snapshot.version
-            clean, quarantine = validate_delta(delta, self.num_vertices)
-            if self.weights is not None:
-                src2, dst2, w2, v2, stats = splice_edges(
-                    self.src, self.dst, self.num_vertices, clean,
-                    weights=self.weights,
+            with stage_span(sink, "delta_splice") as stage:
+                clean, quarantine = validate_delta(delta, self.num_vertices)
+                if self.weights is not None:
+                    src2, dst2, w2, v2, stats = splice_edges(
+                        self.src, self.dst, self.num_vertices, clean,
+                        weights=self.weights,
+                    )
+                else:
+                    src2, dst2, v2, stats = splice_edges(
+                        self.src, self.dst, self.num_vertices, clean
+                    )
+                    w2 = None
+                quarantine["unmatched_deletes"] += stats.pop(
+                    "unmatched_deletes"
                 )
-            else:
-                src2, dst2, v2, stats = splice_edges(
-                    self.src, self.dst, self.num_vertices, clean
+                stage.note(
+                    inserted=stats["inserted"], deleted=stats["deleted"],
+                    quarantined=sum(quarantine.values()),
                 )
-                w2 = None
-            quarantine["unmatched_deletes"] += stats.pop("unmatched_deletes")
             from graphmine_tpu.graph.container import build_graph
 
-            graph = build_graph(
-                src2, dst2, num_vertices=v2, edge_weights=w2
-            )
-            t_r = time.perf_counter()
-            result = self._repair(graph, clean)
-            repair_seconds = time.perf_counter() - t_r
+            with stage_span(sink, "delta_build_graph", num_edges=len(src2)):
+                graph = build_graph(
+                    src2, dst2, num_vertices=v2, edge_weights=w2
+                )
+            with stage_span(sink, "delta_repair") as repair_stage:
+                result = self._repair(graph, clean)
+                repair_stage.note(
+                    method=result.method, iterations=result.iterations,
+                    budget=result.budget,
+                )
             self.src, self.dst, self.weights = src2, dst2, w2
             self.labels, self.cc_labels = result.labels, result.cc_labels
             aff = affected_vertices(clean)
-            t_l = time.perf_counter()
-            lof_stale = self._lof_pass(graph, result.labels, aff, lof_mode)
-            lof_seconds = time.perf_counter() - t_l
+            with stage_span(
+                sink, "delta_lof", mode=lof_mode, affected=len(aff)
+            ) as lof_stage:
+                lof_stale = self._lof_pass(graph, result.labels, aff, lof_mode)
+                lof_stage.note(stale=bool(lof_stale))
 
             from graphmine_tpu.ops.census import census_table
 
-            present, sizes, edge_counts = census_table(result.labels, graph)
-            arrays = {
+            with stage_span(sink, "delta_census"):
+                present, sizes, edge_counts = (
+                    np.asarray(a) for a in census_table(result.labels, graph)
+                )
+            columns = {
                 "src": self.src,
                 "dst": self.dst,
                 "labels": self.labels,
                 "cc_labels": self.cc_labels,
                 "lof": self.lof,
-                "census_present": np.asarray(present),
-                "census_sizes": np.asarray(sizes),
-                "census_edges": np.asarray(edge_counts),
+                "census_present": present,
+                "census_sizes": sizes,
+                "census_edges": edge_counts,
             }
             if self.weights is not None:
-                arrays["weights"] = self.weights
+                columns["weights"] = self.weights
             if self._centers is not None:
-                arrays["lof_centers"] = np.asarray(self._centers, np.float32)
-            if self._canary is not None:
-                # probe identity rides the store (the lof_centers
-                # pattern): a restarted or promoted writer re-scores the
-                # SAME frozen probe, so canary recall is comparable
-                # across the whole version chain
-                arrays.update(self._canary.arrays())
-            snap = self.store.publish(
-                arrays,
-                fingerprint=graph_fingerprint(
-                    self.src, self.dst, self.weights
-                ),
-                run_id=self.snapshot.meta.get("run_id", ""),
-                mesh_shape=[self.num_shards],
-                extra_meta={
-                    **(extra_meta or {}),
-                    **({"lof_stale": True} if lof_stale else {}),
-                    **(
-                        {"canary": self._canary.meta()}
-                        if self._canary is not None else {}
-                    ),
-                } or None,
-                sink=self.sink,
-                epoch=self.epoch,
-            )
-            self.snapshot = snap
-            if self.quality_enabled:
+                columns["lof_centers"] = (self._centers, np.float32)
+
+            def _quality(snap, arrays, canary):
                 # The result-quality pass (ISSUE 13): still inside the
                 # delta_apply span, so quality_snapshot/quality_drift/
                 # canary_score land span-joined to the publishing trace.
@@ -1255,37 +1259,44 @@ class DeltaIngestor:
                     and parent_state.version != prev_version
                 ):
                     parent_state = None
-                try:
-                    report = run_quality_pass(
-                        self.labels, self.lof, snap.version,
-                        parent_labels=prev_labels, parent_lof=prev_lof,
-                        parent_version=prev_version,
-                        parent_state=parent_state,
-                        canary=self._canary,
-                        sink=self.sink,
-                        registry=(
-                            self.sink.registry if self.sink is not None
-                            else None
-                        ),
-                    )
-                    self.last_quality = report
-                    self._quality_state = report.state
-                except Exception as e:  # noqa: BLE001 — telemetry only:
-                    # a quality-pass crash must never fail (or appear to
-                    # fail) a publish that already landed
-                    if self.sink is not None:
-                        self.sink.emit(
-                            "warning",
-                            message=f"quality pass failed: {e!r}",
-                        )
+                report = run_quality_pass(
+                    self.labels, self.lof, snap.version,
+                    parent_labels=prev_labels, parent_lof=prev_lof,
+                    parent_version=prev_version,
+                    parent_state=parent_state,
+                    canary=canary,
+                    sink=sink,
+                    registry=sink.registry if sink is not None else None,
+                )
+                self.last_quality = report
+                self._quality_state = report.state
+
+            # The canary probe's identity rides the store (the
+            # lof_centers pattern): a restarted or promoted writer
+            # re-scores the SAME frozen probe, so canary recall is
+            # comparable across the whole version chain.
+            snap = publish_result(
+                self.store, columns, sink=sink,
+                canary=(lambda: self._canary) if self.quality_enabled
+                else None,
+                quality=_quality if self.quality_enabled else None,
+                extra_meta={
+                    **(extra_meta or {}),
+                    **({"lof_stale": True} if lof_stale else {}),
+                },
+                run_id=self.snapshot.meta.get("run_id", ""),
+                mesh_shape=[self.num_shards],
+                epoch=self.epoch,
+            )
+            self.snapshot = snap
             # Settle the debt ledger BEFORE emitting, so the record's
             # repair_debt snapshot reflects this apply as drained.
             self.debt.applied(
                 method=result.method, iterations=result.iterations,
                 budget=result.budget, batches=batches,
             )
-            if self.sink is not None:
-                self.sink.emit(
+            if sink is not None:
+                sink.emit(
                     "delta_apply",
                     inserts=stats["inserted"],
                     deletes=stats["deleted"],
@@ -1301,12 +1312,14 @@ class DeltaIngestor:
                     lof_mode=lof_mode,
                     lof_stale=bool(lof_stale),
                     seconds=round(time.perf_counter() - t0, 4),
-                    # stage split: the repair-vs-recompute comparison the
+                    # stage split, the seconds of the `delta_repair` and
+                    # `delta_lof` spans (None under a sink that has no
+                    # tracer): the repair-vs-recompute comparison the
                     # bench serve tier reports is the repair term; LOF
                     # refresh amortizes (full bootstrap only on the first
                     # apply of an ingestor's lifetime)
-                    repair_seconds=round(repair_seconds, 4),
-                    lof_seconds=round(lof_seconds, 4),
+                    repair_seconds=_rounded(repair_stage.seconds),
+                    lof_seconds=_rounded(lof_stage.seconds),
                     # the repair-debt ledger as of this publish — the
                     # obs_report SLO section's debt-timeline raw material
                     repair_debt=self.debt.snapshot(),
@@ -1345,3 +1358,7 @@ def _null_ctx():
     import contextlib
 
     return contextlib.nullcontext()
+
+
+def _rounded(seconds: float | None) -> float | None:
+    return None if seconds is None else round(seconds, 4)

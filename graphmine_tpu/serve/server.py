@@ -236,12 +236,15 @@ class _PendingDelta:
         # Trace identity + causal-chain stamps (ISSUE 11 time-to-visible
         # SLO): `trace` is the accepting request's propagated traceparent
         # header (WAL-durable, so it survives kill/replay and log
-        # shipping); t_accept/t_durable are monotonic marks of the
-        # admission verdict and the WAL fsync — the apply worker turns
-        # them into the per-stage breakdown (`delta_stages` record +
-        # graphmine_serve_delta_stage_seconds histograms).
+        # shipping); t_accept/t_durable mark the admission verdict and
+        # the WAL fsync on the spans' clock (time.perf_counter: one
+        # clock for a served delta, so `apply_s` IS the `delta_apply`
+        # span) — the apply worker turns them into the per-stage
+        # breakdown (`delta_stages` record +
+        # graphmine_serve_delta_stage_seconds histograms). Deadlines
+        # stay on time.monotonic; the two are never mixed.
         self.trace = ""
-        self.t_accept = time.monotonic()
+        self.t_accept = time.perf_counter()
         self.t_durable: float | None = None
         # WAL identity (serve/wal.py): seq is the batch's durable log
         # position (None = no WAL on this server), delta_id the client's
@@ -1095,7 +1098,7 @@ class SnapshotServer:
                         sub["reason"], sub["retry_after_s"]
                     )
                 pending.shard_seqs = sub["shard_seqs"]
-                pending.t_durable = time.monotonic()
+                pending.t_durable = time.perf_counter()
             elif self.wal is not None:
                 seq, dup = self.wal.append(
                     payload, delta_id=delta_id or "", deadline_s=deadline_s,
@@ -1110,7 +1113,7 @@ class SnapshotServer:
                         delta_id or "", seq, tenant=tenant,
                     )
                 pending.seq = seq
-                pending.t_durable = time.monotonic()
+                pending.t_durable = time.perf_counter()
         finally:
             enqueued = False
             with self._queue_cv:
@@ -1836,7 +1839,6 @@ class SnapshotServer:
         and each batch additionally gets its own `delta_stages` record
         in its OWN trace — so a coalesced group's non-leader batches
         still stitch end-to-end."""
-        t_apply_start = time.monotonic()
         ts = self._tenants[tenant]
         leader_ctx = None
         if self.sink is not None:
@@ -1924,10 +1926,18 @@ class SnapshotServer:
                     }
                 else:
                     extra = None
+                # `apply_s` is the ingestor's call and nothing else: the
+                # two ends of its `delta_apply` span where there is one
+                # (a sink with a tracer), this clock pair otherwise.
+                t_apply0 = time.perf_counter()
                 snap = ing.apply(
                     merged, lof_mode=lof_mode, batches=len(group),
                     extra_meta=extra,
                 )
+                t_apply1 = time.perf_counter()
+                sp = ing.last_apply_span
+                if sp is not None:
+                    t_apply0, t_apply1 = sp.start_mono, sp.end_mono
             except BaseException:
                 if ts.debt.applies_total == settled_before:
                     for _ in group:
@@ -1962,7 +1972,7 @@ class SnapshotServer:
                 if merged_seqs:
                     ts.plane.commit_applied(merged_seqs, snap.version)
                 self._publish_epoch(ts, snap)
-        self._emit_delta_stages(group, snap, t_apply_start)
+        self._emit_delta_stages(group, snap, t_apply0, t_apply1)
         # Publish-time alert evaluation (outside the delta lock — a
         # record fsync must not serialize handlers): a quality or canary
         # regression this publish introduced fires NOW, not at the next
@@ -2009,31 +2019,46 @@ class SnapshotServer:
         return epoch
 
     # -- per-delta time-to-visible stages ---------------------------------
-    def _emit_delta_stages(self, group: list, snap, t_apply_start: float):
+    def _emit_delta_stages(
+        self, group: list, snap, t_apply0: float, t_apply1: float,
+    ):
         """The writer-side causal chain of every batch this publish
         absorbed: admission accept → WAL fsync → queued → apply →
-        published, observed into per-stage histograms
+        commit → published, observed into per-stage histograms
         (``graphmine_serve_delta_stage_seconds{stage=...}``, the
         ``/statusz`` breakdown) and emitted as one ``delta_stages``
         record per batch IN THAT BATCH's trace — telemetry only, so a
-        failure here must never fail a publish that already landed."""
-        t_done = time.monotonic()
+        failure here must never fail a publish that already landed.
+
+        One clock (``time.perf_counter``, the spans'), five marks a
+        batch: accepted, durable, the ingestor's call and its return
+        (the two ends of the ``delta_apply`` span), and now, when the
+        new engine serves and the WAL watermark and the epoch are
+        committed. ``queued_s`` therefore ends where the ingestor takes
+        the batch (it holds the worker's rebase guard, the ingestor's
+        construction on a first delta, and the coalesce), ``apply_s``
+        is the span, ``commit_s`` is what follows it. Each mark is
+        rounded to the microsecond BEFORE the differences are taken, so
+        ``total_s`` is the sum of the other stages to the microsecond."""
+        t_done = time.perf_counter()
         try:
             for p in group:
+                marks = [
+                    round(max(0.0, t - p.t_accept), 6)
+                    for t in (p.t_durable or p.t_accept, t_apply0,
+                              t_apply1, t_done)
+                ]
+                # every batch of a group was durable before the pop, so
+                # this only guards the differences against a clock oddity
+                for i in range(1, len(marks)):
+                    marks[i] = max(marks[i], marks[i - 1])
                 stages = {}
                 if p.t_durable is not None:
-                    stages["wal_fsync_s"] = round(
-                        max(0.0, p.t_durable - p.t_accept), 6
-                    )
-                stages["queued_s"] = round(
-                    max(0.0, t_apply_start - (p.t_durable or p.t_accept)), 6
-                )
-                stages["apply_s"] = round(
-                    max(0.0, t_done - t_apply_start), 6
-                )
-                stages["total_s"] = round(
-                    max(0.0, t_done - p.t_accept), 6
-                )
+                    stages["wal_fsync_s"] = marks[0]
+                stages["queued_s"] = round(marks[1] - marks[0], 6)
+                stages["apply_s"] = round(marks[2] - marks[1], 6)
+                stages["commit_s"] = round(marks[3] - marks[2], 6)
+                stages["total_s"] = marks[3]
                 for stage, seconds in stages.items():
                     self.registry.histogram(
                         "graphmine_serve_delta_stage_seconds",
@@ -2674,18 +2699,33 @@ class _Handler(BaseHTTPRequestHandler):
     def log_message(self, fmt, *args):  # noqa: A003
         pass
 
-    def _reply(self, code: int, payload: dict, headers: dict | None = None) -> None:
+    def _reply(
+        self, code: int, payload: dict, headers: dict | None = None,
+        records_first: bool = False,
+    ) -> None:
         body = json.dumps(_jsonable(payload)).encode()
-        self._send(code, body, "application/json", headers=headers)
+        self._send(
+            code, body, "application/json", headers=headers,
+            records_first=records_first,
+        )
 
     def _reply_text(self, code: int, text: str, content_type: str) -> None:
         self._send(code, text.encode(), content_type)
 
     def _send(
         self, code: int, body: bytes, content_type: str,
-        headers: dict | None = None,
+        headers: dict | None = None, records_first: bool = False,
     ) -> None:
         self._status = code
+        if records_first:
+            # /delta's guarantee (docs/SERVING.md): every record of the
+            # request, `access_log` included, is in the sink BEFORE the
+            # answer goes out, so a client that has its answer can read
+            # its own trace whole. The record's seconds then end here,
+            # at the last instant before the write, and a client that
+            # hangs up during the write is logged with the status it
+            # was being sent.
+            self._finish()
         self.send_response(code)
         self.send_header("Content-Type", content_type)
         self.send_header("Content-Length", str(len(body)))
@@ -2731,6 +2771,7 @@ class _Handler(BaseHTTPRequestHandler):
         self._raw_body = b""
         self._tenant = ""
         self._tenant_explicit = False
+        self._tail = (method, endpoint, rid)
         self.srv.request_started()
         chaos = self.srv.chaos_delay_s
         if chaos > 0:
@@ -2750,7 +2791,7 @@ class _Handler(BaseHTTPRequestHandler):
             if ctx is not None and self.srv.sink is not None
             else contextlib.nullcontext()
         )
-        t0 = time.perf_counter()
+        self._t0 = time.perf_counter()
         with span:
             try:
                 if handler is None:
@@ -2794,11 +2835,19 @@ class _Handler(BaseHTTPRequestHandler):
                 # tail of impatient clients actually leaves.
                 self._status = 499
             finally:
-                self.srv.request_finished(
-                    method, endpoint, self._status,
-                    time.perf_counter() - t0, rid, body=self._raw_body,
-                    tenant=self._tenant,
-                )
+                self._finish()
+
+    def _finish(self) -> None:
+        """The middleware tail, once a request: after the handler, or
+        (``records_first``) just before its answer is written."""
+        if self._tail is None:
+            return
+        (method, endpoint, rid), self._tail = self._tail, None
+        self.srv.request_finished(
+            method, endpoint, self._status,
+            time.perf_counter() - self._t0, rid, body=self._raw_body,
+            tenant=self._tenant,
+        )
 
     def do_GET(self) -> None:  # noqa: N802
         self._serve("GET", _GET_ROUTES)
@@ -2993,9 +3042,10 @@ class _Handler(BaseHTTPRequestHandler):
             # WAL-durable, not yet published: the honest 202
             self._reply(202, out)
         elif verdict == "duplicate":
-            self._reply(200 if out.get("applied") else 202, out)
+            applied = bool(out.get("applied"))
+            self._reply(200 if applied else 202, out, records_first=applied)
         else:
-            self._reply(200, out)
+            self._reply(200, out, records_first=True)
 
     def _ep_wal(self, url) -> None:
         qs = parse_qs(url.query)

@@ -50,6 +50,7 @@ except ImportError:  # non-POSIX: single-process stores only
 
 import numpy as np
 
+from graphmine_tpu.obs.spans import stage_span
 from graphmine_tpu.pipeline import resilience
 from graphmine_tpu.serve.tenancy import (
     DEFAULT_TENANT,
@@ -66,6 +67,7 @@ from graphmine_tpu.pipeline.checkpoint import (
     _load_with_rollback,
     _manifest_checksum,
     _tree_bytes,
+    graph_fingerprint,
 )
 
 MANIFEST_NAME = "manifest.json"
@@ -693,3 +695,88 @@ class SnapshotStore:
                 seconds=round(time.perf_counter() - t0, 4),
             )
         return snap
+
+
+def publish_result(
+    store: SnapshotStore, columns: dict, *, sink=None, canary=None,
+    quality=None, extra_meta: dict | None = None, **publish_kw,
+) -> Snapshot:
+    """The tail of the write path to a snapshot, from finished result
+    columns to a published generation: the one function both writers
+    end in (the pipeline's publish chapter, ``pipeline/driver.py``, and
+    a served delta's apply, ``serve/delta.py``), so its five stage
+    spans carry the same names under ``snapshot_publish`` and under
+    ``delta_apply`` (docs/OBSERVABILITY.md "Stage spans"):
+
+    - ``publish_fetch``: every column as a host array. ``columns`` maps
+      a snapshot array name to the column, or to ``(column, dtype)``
+      where the writer fixes the dtype; a column that is a NumPy array
+      of that dtype already is published as the same buffer, not a
+      copy. ``host_bytes`` counts what was not a host array before.
+    - ``publish_canary``: ``canary()`` hands back the frozen
+      :class:`~graphmine_tpu.obs.quality.CanaryProbe` this generation
+      carries (or None); its arrays join the columns and its meta the
+      manifest. Telemetry only: a probe that cannot be had is a
+      ``warning`` record, never a failed publish.
+    - ``publish_fingerprint``: ``graph_fingerprint`` of the published
+      ``src`` / ``dst`` / ``weights`` columns.
+    - ``publish_write``: :meth:`SnapshotStore.publish` (which writes
+      its own timed ``snapshot_publish`` record, as before).
+    - ``publish_quality``: ``quality(snap, arrays, probe)``, the
+      writer's ``run_quality_pass`` call. It runs after the generation
+      COMMITTED, so a failure is a ``warning`` record and the snapshot
+      is returned all the same (a raise would hand a landed publish to
+      ``run_phase`` as a failure, and the retry would publish a
+      duplicate version).
+
+    ``publish_kw`` goes to :meth:`SnapshotStore.publish` as it is
+    (``run_id``, ``mesh_shape``, ``epoch``). Without ``canary`` /
+    ``quality`` those two stages open no span.
+    """
+    with stage_span(sink, "publish_fetch") as stage:
+        arrays, host_bytes = {}, 0
+        for name, column in columns.items():
+            column, dtype = (
+                column if isinstance(column, tuple) else (column, None)
+            )
+            arrays[name] = np.asarray(column, dtype)
+            if not isinstance(column, np.ndarray):
+                host_bytes += arrays[name].nbytes
+        stage.note(host_bytes=host_bytes, arrays=len(arrays))
+    probe = None
+    if canary is not None:
+        with stage_span(sink, "publish_canary"):
+            try:
+                probe = canary()
+                if probe is not None:
+                    arrays.update(probe.arrays())
+            except Exception as e:  # noqa: BLE001 — telemetry only
+                probe = None
+                if sink is not None:
+                    sink.emit(
+                        "warning", message=f"canary probe unavailable: {e!r}"
+                    )
+    if probe is not None:
+        extra_meta = {**(extra_meta or {}), "canary": probe.meta()}
+    with stage_span(sink, "publish_fingerprint", rows=len(arrays["src"])):
+        fingerprint = graph_fingerprint(
+            arrays["src"], arrays["dst"], arrays.get("weights")
+        )
+    with stage_span(sink, "publish_write") as stage:
+        snap = store.publish(
+            arrays, fingerprint=fingerprint, extra_meta=extra_meta or None,
+            sink=sink, **publish_kw,
+        )
+        stage.note(
+            bytes=snap.nbytes, arrays=len(arrays), version=snap.version
+        )
+    if quality is not None:
+        with stage_span(sink, "publish_quality"):
+            try:
+                quality(snap, arrays, probe)
+            except Exception as e:  # noqa: BLE001 — telemetry only
+                if sink is not None:
+                    sink.emit(
+                        "warning", message=f"quality pass failed: {e!r}"
+                    )
+    return snap

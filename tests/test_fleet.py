@@ -1162,7 +1162,24 @@ def test_fleet_cli_up_multiprocess_smoke(tmp_path):
             s.bind(("127.0.0.1", 0))
             return s.getsockname()[1]
 
-    router_port, base_port = free_port(), free_port()
+    def free_port_pair():
+        """Replica i listens on base + i, seconds from now. A port the
+        kernel hands out for port 0 has a neighbour the next port-0 bind
+        of any other test worker may take first (seen under xdist:
+        `Address already in use` on base + 1), so the pair is drawn
+        below the ephemeral range, where nobody binds port 0."""
+        rng = np.random.default_rng(os.getpid())
+        for base in rng.integers(20000, 30000, 64).tolist():
+            try:
+                with socket.socket() as a, socket.socket() as b:
+                    a.bind(("127.0.0.1", base))
+                    b.bind(("127.0.0.1", base + 1))
+                return base
+            except OSError:
+                continue
+        raise RuntimeError("no free port pair")
+
+    router_port, base_port = free_port(), free_port_pair()
     repo = os.path.join(os.path.dirname(__file__), "..")
     proc = subprocess.Popen(
         [

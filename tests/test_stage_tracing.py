@@ -64,6 +64,38 @@ def test_scope_lint_catches_unregistered_and_computed_names(tmp_path):
     assert "computed" in out[1] and "mod.py:6" in out[1]
 
 
+def test_stage_lint_package_is_clean():
+    import schema_lint
+
+    assert schema_lint.stage_violations() == []
+    used = {arg.strip("\"'") for arg, _, _ in schema_lint.scan_stages()}
+    assert used == set(schema.STAGE_SPANS)  # every registered stage is opened
+    assert {"ivf_search", "triangles_host", "publish_write", "delta_repair"} <= used
+
+
+def test_stage_lint_catches_unregistered_and_computed_names(tmp_path):
+    import schema_lint
+
+    (tmp_path / "mod.py").write_text(
+        "from graphmine_tpu.obs.spans import stage_span\n"
+        "def f(sink, name):\n"
+        '    with stage_span(sink, "publish_write", rows=3):\n'
+        "        pass\n"
+        "    with stage_span(\n"
+        '        self.sink, "not_a_registered_stage"\n'
+        "    ) as stage:\n"
+        "        pass\n"
+        "    with stage_span(sink, name):\n"
+        "        pass\n"
+    )
+    out = schema_lint.stage_violations(str(tmp_path), check_unused=False)
+    assert len(out) == 2, out
+    assert "not_a_registered_stage" in out[0] and "mod.py:5" in out[0]
+    assert "computed" in out[1] and "mod.py:9" in out[1]
+    # the whole-package lint that tier-1 runs holds them too
+    assert [v for v in schema_lint.violations(str(tmp_path)) if "stage" in v] == out
+
+
 # ---- (2) the compiled programs carry their scopes --------------------------
 
 
@@ -357,10 +389,11 @@ def test_stage_span_without_a_sink_records_nothing_and_syncs_nothing():
     with stage_span(None, "ivf_search", n_pairs=3) as stage:
         stage.note(k=1)
         assert isinstance(stage.sync(Lazy()), Lazy)
+    assert stage.seconds is None  # no span, no seconds: not a clock of its own
     sink = MetricsSink()  # a sink without a tracer: still nothing to record
     with stage_span(sink, "ivf_search") as stage:
         stage.note(k=1)
-    assert sink.records == []
+    assert sink.records == [] and stage.seconds is None
     traced = MetricsSink(tracer=Tracer())
     synced = []
 
@@ -373,6 +406,8 @@ def test_stage_span_without_a_sink_records_nothing_and_syncs_nothing():
         stage.note(chunk_rows=2)
     (rec,) = traced.of_phase("span")
     assert (rec["n_pairs"], rec["chunk_rows"], len(synced)) == (3, 2, 2)
+    # a caller that writes a summary record reads the span's seconds
+    assert rec["seconds"] == round(stage.seconds, 4)
     traced.span_attrs(ignored=True)  # the root span writes no record
     assert "ignored" not in traced.tracer.root.attrs
 

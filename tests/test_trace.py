@@ -24,7 +24,7 @@ import pytest
 
 from graphmine_tpu.graph.container import build_graph
 from graphmine_tpu.obs.histogram import Histogram
-from graphmine_tpu.obs.schema import validate_records
+from graphmine_tpu.obs.schema import DELTA_STAGES, validate_records
 from graphmine_tpu.obs.spans import TRACE_HEADER, TraceContext, Tracer
 from graphmine_tpu.pipeline.checkpoint import graph_fingerprint
 from graphmine_tpu.pipeline.metrics import MetricsSink, shard_sink
@@ -514,10 +514,15 @@ def test_delta_stages_record_in_the_clients_trace(tmp_path):
             r for r in by_phase["delta_stages"]
             if r["trace_id"] == ctx.trace_id
         ][-1]["stages"]
-        assert set(stages) == {
-            "wal_fsync_s", "queued_s", "apply_s", "total_s"
-        }
-        assert stages["total_s"] >= stages["apply_s"] >= 0
+        assert tuple(stages) == DELTA_STAGES == (
+            "wal_fsync_s", "queued_s", "apply_s", "commit_s", "total_s"
+        )
+        assert all(seconds >= 0 for seconds in stages.values())
+        # one clock, marks rounded before the differences are taken:
+        # the four stages ARE the total, to the microsecond
+        assert sum(
+            stages[k] for k in DELTA_STAGES[:-1]
+        ) == pytest.approx(stages["total_s"], abs=1e-6)
         # the WAL entry carries the header durably
         entry = srv.wal.entries(1)[0]
         assert TraceContext.from_header(
@@ -525,11 +530,153 @@ def test_delta_stages_record_in_the_clients_trace(tmp_path):
         ).trace_id == ctx.trace_id
         # /statusz serves the per-stage breakdown
         statusz = _get(host, port, "/statusz")
-        assert "total" in statusz["delta_stages"]
+        assert set(statusz["delta_stages"]) == {
+            "wal_fsync", "queued", "apply", "commit", "total"
+        }
         assert statusz["delta_stages"]["wal_fsync"]["count"] >= 1
+        assert statusz["delta_stages"]["commit"]["count"] >= 1
         assert validate_records(sink.records) == []
     finally:
         srv.stop()
+
+
+_DELTA_STAGE_SPANS = ("delta_splice", "delta_build_graph", "delta_repair",
+                      "delta_lof", "delta_census")
+_PUBLISH_STAGE_SPANS = ("publish_fetch", "publish_canary",
+                        "publish_fingerprint", "publish_write",
+                        "publish_quality")
+
+
+def test_a_served_delta_nests_its_ten_stage_spans_under_delta_apply(tmp_path):
+    """The five `delta_*` stages and the five `publish_*` stages of the
+    shared tail, in order, under `delta_apply`, in the CLIENT's trace;
+    `apply_s` of the request's `delta_stages` is that span, and the
+    record's `repair_seconds` / `lof_seconds` are two of the stages."""
+    sink = MetricsSink(tracer=Tracer())
+    store, _ = _publish_base(tmp_path)
+    srv = SnapshotServer(store, sink=sink, wal=str(tmp_path / "wal"))
+    host, port = srv.start()
+    ctx = TraceContext("cc" * 8, "dd" * 4)
+    try:
+        code, body, _ = _post(
+            host, port, "/delta", {"insert": [[1, 39]], "delete": [[0, 1]]},
+            headers={TRACE_HEADER: ctx.to_header()},
+        )
+        assert code == 200 and body["version"] == 2
+        mine = [r for r in sink.records if r.get("trace_id") == ctx.trace_id]
+        spans = [r for r in mine if r["phase"] == "span"]
+        (apply_span,) = [r for r in spans if r["name"] == "delta_apply"]
+        under = [r for r in spans
+                 if r.get("parent_span_id") == apply_span["span_id"]]
+        assert [r["name"] for r in under] == list(
+            _DELTA_STAGE_SPANS + _PUBLISH_STAGE_SPANS
+        )
+        for r in under:
+            assert r["span_path"] == f"{apply_span['span_path']}/{r['name']}"
+            assert r["status"] == "ok"
+        by_name = {r["name"]: r for r in under}
+        # the counts that explain the seconds
+        assert (by_name["delta_splice"]["inserted"],
+                by_name["delta_splice"]["deleted"],
+                by_name["delta_splice"]["quarantined"]) == (1, 1, 0)
+        (record,) = [r for r in mine if r["phase"] == "delta_apply"]
+        assert by_name["delta_build_graph"]["num_edges"] == record["num_edges"]
+        assert by_name["delta_repair"]["method"] == record["method"]
+        assert by_name["delta_repair"]["iterations"] == record["iterations"]
+        assert by_name["delta_lof"]["mode"] == "refresh"
+        assert by_name["delta_lof"]["stale"] is False
+        assert by_name["publish_fetch"]["arrays"] >= 8
+        assert by_name["publish_fingerprint"]["rows"] == record["num_edges"]
+        assert by_name["publish_write"]["version"] == 2
+        assert by_name["publish_write"]["arrays"] == len(
+            srv.engine.snapshot.arrays
+        )
+        # the store's own timed record stays, inside its stage
+        (written,) = [r for r in mine if r["phase"] == "snapshot_publish"]
+        assert written["span_path"] == by_name["publish_write"]["span_path"]
+        assert written["seconds"] <= by_name["publish_write"]["seconds"] + 1e-4
+        # no second pair of clocks: the record's stage split IS the spans'
+        assert record["repair_seconds"] == by_name["delta_repair"]["seconds"]
+        assert record["lof_seconds"] == by_name["delta_lof"]["seconds"]
+        # ... and the stages cover the apply (what none names stays small)
+        assert sum(r["seconds"] for r in under) >= 0.9 * apply_span["seconds"]
+        # one clock for the served delta: apply_s is the span
+        (stages,) = [r["stages"] for r in mine if r["phase"] == "delta_stages"]
+        assert stages["apply_s"] == pytest.approx(
+            apply_span["seconds"], abs=1e-4  # the span record's rounding
+        )
+        # a first delta compiles; its compile records name their stage
+        compiles = [r for r in mine if r["phase"] == "compile"]
+        assert compiles and all(
+            r["span_path"].startswith(apply_span["span_path"] + "/delta_")
+            or r["span_path"].startswith(apply_span["span_path"] + "/publish_")
+            for r in compiles
+        ), sorted({r["span_path"] for r in compiles})
+        assert validate_records(sink.records) == []
+    finally:
+        srv.stop()
+
+
+def test_a_pipeline_job_closes_its_span_tree_and_stages_its_publish(tmp_path):
+    """The root `run` span is a record beside `run_end`; the six
+    `publish_*` stages lie under `snapshot_publish` and cover it."""
+    from graphmine_tpu.pipeline.config import PipelineConfig
+    from graphmine_tpu.pipeline.driver import run_pipeline
+
+    rng = np.random.default_rng(7)
+    # big enough that the warm chapter is tens of milliseconds: its self
+    # time is a few calls and seven records' rounding, well under 1 ms
+    v, e = 20000, 200000
+    src = rng.integers(0, v, e)
+    dst = (src // 50 * 50 + rng.integers(0, 50, e)) % v  # 400 communities
+    path = tmp_path / "edges.txt"
+    path.write_text("".join(f"n{a} n{b}\n" for a, b in zip(src, dst)))
+    res = run_pipeline(PipelineConfig(
+        data_path=str(path), data_format="edgelist", max_iter=3,
+        outlier_method="none", num_devices=1,
+        snapshot_out=str(tmp_path / "snap"),
+    ))
+    recs = res.metrics.records
+    assert validate_records(recs) == []
+    spans = [r for r in recs if r["phase"] == "span"]
+    # the tree closes: the root is written like any span, last of them,
+    # just before run_end, and holds every chapter
+    assert recs[-1]["phase"] == "run_end" and recs[-2] is spans[-1]
+    root = spans[-1]
+    assert (root["name"], root["span_path"], root["status"]) == (
+        "run", "run", "ok"
+    )
+    assert "parent_span_id" not in root
+    chapters = [r for r in spans if r.get("parent_span_id") == root["span_id"]]
+    assert [r["name"] for r in chapters] == [
+        "load", "build_graph", "lpa", "census", "snapshot_publish",
+    ]
+    outside = root["seconds"] - sum(r["seconds"] for r in chapters)
+    assert 0 <= outside < 0.05 * root["seconds"] + 0.05
+    # the publish chapter: six stages, in order, one level under the
+    # phase's rung, and what none of them names under 5 % of the chapter
+    chapter = chapters[-1]
+    stages = [r for r in spans
+              if r["span_path"].startswith(chapter["span_path"] + "/")
+              and r["name"].startswith("publish_")]
+    assert [r["name"] for r in stages] == ["publish_cc", *_PUBLISH_STAGE_SPANS]
+    assert len({r["parent_span_id"] for r in stages}) == 1
+    by_name = {r["name"]: r for r in stages}
+    assert by_name["publish_cc"]["supersteps"] >= 2
+    assert by_name["publish_fetch"]["arrays"] == 7
+    assert by_name["publish_fingerprint"]["rows"] == e
+    assert by_name["publish_write"]["version"] == 1
+    self_s = chapter["seconds"] - sum(r["seconds"] for r in stages)
+    assert -1e-3 <= self_s < 0.05 * chapter["seconds"], (self_s, chapter)
+    # the records that were there land under their stage
+    for phase, stage in (("superstep_timing", "publish_cc"),
+                         ("fixpoint", "publish_cc"),
+                         ("snapshot_publish", "publish_write"),
+                         ("quality_snapshot", "publish_quality"),
+                         ("canary_score", "publish_quality")):
+        (rec,) = [r for r in recs if r["phase"] == phase
+                  and r["span_path"].startswith(chapter["span_path"] + "/")]
+        assert rec["span_path"] == by_name[stage]["span_path"], phase
 
 
 # ---- router: time-to-visible merged histogram + statusz -------------------

@@ -96,6 +96,13 @@ _SCOPE_RE = re.compile(r"\bjax\.named_scope\(\s*([^)]*?)\s*\)")
 _SCOPE_LITERAL_RE = re.compile(r"[\"']([A-Za-z_][A-Za-z0-9_]*)[\"']$")
 _WIDTH_BINDING_RE = re.compile(r"\bwidth\s*=\s*(.*)")
 
+# Stage spans (ISSUE 35): the first argument after the sink of every
+# `stage_span(...)` call is a string literal registered in
+# schema.STAGE_SPANS: the benchmark's readers and a device trace's idle
+# gaps go by these names. `\s*` crosses newlines.
+_STAGE_RE = re.compile(r"\bstage_span\(\s*[^,()]+,\s*([^,)]*?)\s*[,)]")
+_STAGE_OWNER = os.path.join("graphmine_tpu", "obs", "spans.py")
+
 PACKAGE_DIR = os.path.join(_REPO, "graphmine_tpu")
 
 
@@ -225,6 +232,48 @@ def scope_violations(root: str = PACKAGE_DIR, check_unused: bool = True) -> list
     return out
 
 
+def scan_stages(root: str = PACKAGE_DIR) -> list:
+    """``(name argument text, file, line)`` of every ``stage_span(sink,
+    <name>, ...)`` call outside the module that defines it."""
+    found = []
+    for rel, text in _py_files(root):
+        if rel == _STAGE_OWNER:
+            continue
+        for m in _STAGE_RE.finditer(text):
+            line = text.count("\n", 0, m.start()) + 1
+            found.append((m.group(1), rel, line))
+    return found
+
+
+def stage_violations(root: str = PACKAGE_DIR, check_unused: bool = True) -> list:
+    """Stage spans that are not registered in schema.STAGE_SPANS or whose
+    name is computed, and registered stages that no code opens."""
+    from graphmine_tpu.obs.schema import STAGE_SPANS
+
+    out, used = [], set()
+    for arg, path, line in scan_stages(root):
+        m = _SCOPE_LITERAL_RE.match(arg)
+        if m is None:
+            out.append(
+                f"{path}:{line}: computed stage span name {arg!r} — stage "
+                "names are string literals"
+            )
+            continue
+        used.add(m.group(1))
+        if m.group(1) not in STAGE_SPANS:
+            out.append(
+                f"{path}:{line}: stage span {m.group(1)!r} is not registered "
+                "in graphmine_tpu/obs/schema.py (STAGE_SPANS)"
+            )
+    if check_unused:
+        out.extend(
+            f"graphmine_tpu/obs/schema.py: STAGE_SPANS lists {name!r}, "
+            "which no stage_span opens"
+            for name in sorted(STAGE_SPANS - used)
+        )
+    return out
+
+
 def violations(root: str = PACKAGE_DIR) -> list:
     """Emitted-but-unregistered phases plus inline cost sub-records:
     list of human-readable strings (empty = clean). The tier-1 test
@@ -263,6 +312,7 @@ def violations(root: str = PACKAGE_DIR) -> list:
     # unused registrations are a fact about the package, not about
     # whatever tree a caller points the lint at
     out.extend(scope_violations(root, check_unused=root == PACKAGE_DIR))
+    out.extend(stage_violations(root, check_unused=root == PACKAGE_DIR))
     return out
 
 
