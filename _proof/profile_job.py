@@ -1,0 +1,92 @@
+"""One carried-rows CDLP job of a benchmark cell under a profiler capture,
+reduced by scope and by program (ISSUE 36, PERF.md §5).
+
+    python _proof/profile_job.py cdlp-g500-24 [out.json]
+
+Set-up is the cell's own driver's (draw, build_graph, the warm-up job that
+builds plan and index and compiles or loads the programs); then one job
+through the public entry with a sink, inside a capture. The capture does
+NOT put op metadata into the compile-cache key (``maybe_profile`` does, and
+every program would compile anew): run it in a call whose cache this
+checkout alone has filled. Prints JSON lines; the last holds the scopes."""
+import json
+import os
+import sys
+import tempfile
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+sys.path.insert(0, ROOT)
+
+
+def say(**record):
+    print(json.dumps(record, default=str), flush=True)
+
+
+def main():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "bench_run", os.path.join(ROOT, "benchmark", "run.py"))
+    run = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(run)
+    cell = run.load_cell(ROOT, sys.argv[1])
+    driver = run.load_module("drivers", cell["traffic"]["driver"])
+
+    import jax
+
+    import graphmine_tpu as gm
+    from graphmine_tpu.compile_cache import enable_compile_cache
+    from graphmine_tpu.obs import devtrace
+    from graphmine_tpu.obs.schema import DEVICE_SCOPES
+    from graphmine_tpu.pipeline.metrics import MetricsSink
+
+    say(cache_dir=enable_compile_cache(), device=str(jax.devices()[0]))
+    scratch = tempfile.mkdtemp(prefix="prof_")
+    ctx = {"config": cell["config"], "traffic": cell["traffic"],
+           "sizes": cell["config"]["rehearsal"] if os.environ.get("REHEARSE") else cell["config"],
+           "seed": 1, "scratch": scratch,
+           "chips": 1, "say": say}
+    state = driver.setup(ctx)
+    graph, iters = state["graph"], cell["traffic"]["iterations"]
+    device = jax.devices()[0]
+
+    # an untraced job first, with a sink: the record and the job's seconds
+    sink = MetricsSink()
+    t0 = time.perf_counter()
+    gm.label_propagation(graph, max_iter=iters, plan="auto", sink=sink).block_until_ready()
+    say(plain_job_s=time.perf_counter() - t0,
+        superstep_delta=[{k: v for k, v in r.items() if k not in ("phase", "t")}
+                         for r in sink.records if r["phase"] == "superstep_delta"],
+        memory=device.memory_stats())
+
+    trace_dir = os.path.join(scratch, "trace")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    t0 = time.perf_counter()
+    gm.label_propagation(graph, max_iter=iters, plan="auto").block_until_ready()
+    job_s = time.perf_counter() - t0
+    jax.profiler.stop_trace()
+    planes = devtrace.read_xplane(devtrace.newest_xplane(trace_dir), "run")
+    reduced = devtrace.reduce_capture(*planes, DEVICE_SCOPES)
+    by_scope, by_program = {}, {}
+    for row in reduced["scopes"]:
+        by_scope[row["scope"]] = by_scope.get(row["scope"], 0.0) + row["device_seconds"]
+        name = row["module"]
+        by_program[name] = by_program.get(name, 0.0) + row["device_seconds"]
+    out = {"cell": sys.argv[1], "job_s": job_s, "busy_s": reduced["busy_seconds"],
+           "idle_s": job_s - reduced["busy_seconds"],
+           "by_scope": dict(sorted(by_scope.items(), key=lambda kv: -kv[1])),
+           "by_program": dict(sorted(by_program.items(), key=lambda kv: -kv[1])),
+           "memory": device.memory_stats()}
+    say(**out)
+    if len(sys.argv) > 2:
+        os.makedirs(os.path.dirname(os.path.abspath(sys.argv[2])), exist_ok=True)
+        with open(sys.argv[2], "w") as f:
+            json.dump(dict(out, rows=reduced["scopes"]), f)
+
+
+if __name__ == "__main__":
+    main()

@@ -1,0 +1,84 @@
+"""Step 0 (ISSUE 36): each program of the host-stepped carried-rows job
+compiled for a described v5e from a plan of SHAPES (no data, no chip), one
+program a process so that the peak RSS is that program's compile alone.
+
+    python _proof/size_programs.py _proof/g500_24_shapes.json            # all, one child each
+    python _proof/size_programs.py _proof/g500_24_shapes.json modes      # one, in this process
+
+Prints one JSON line a program: temp / alias / argument / output / code
+bytes of memory_analysis(), the count of copy-done in the compiled text,
+compile seconds, peak RSS."""
+import json, os, resource, subprocess, sys, time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
+
+
+def plan_of_shapes(said, sharding):
+    import jax, jax.numpy as jnp
+    from graphmine_tpu.ops.bucketed_mode import BucketedModePlan
+
+    def i32(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.int32, sharding=sharding)
+
+    v, m = said["num_vertices"], said["num_messages"]
+    hubs = said["hubs"]
+    return BucketedModePlan(
+        vertex_ids=tuple(i32(n) for n, _ in said["classes"]), msg_idx=None,
+        num_vertices=v, num_messages=m,
+        send_idx=tuple(i32(n, w) for n, w in said["classes"]),
+        hist_vertex_ids=i32(hubs) if hubs else None,
+        hist_send=i32(said["hist_send"]) if hubs else None,
+        hist_row_offset=i32(said["hist_row_offset"]) if hubs else None,
+        out_ptr=i32(v + 1), out_slot=i32(m),
+    )
+
+
+def one(said, name):
+    import jax, jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from graphmine_tpu.ops import lpa
+    from graphmine_tpu.ops.superstep_policy import delta_rungs
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+    plan = plan_of_shapes(said, chip)
+    v, s = said["num_vertices"], said["slots"]
+    rows = jax.ShapeDtypeStruct((s,), jnp.int32, sharding=chip)
+    labels = jax.ShapeDtypeStruct((v,), jnp.int32, sharding=chip)
+    changed = jax.ShapeDtypeStruct((v,), jnp.bool_, sharding=chip)
+    t0 = time.time()
+    if name == "gather":
+        lowered = lpa._gather_program.lower(rows, labels, plan)
+    elif name == "modes":
+        lowered = lpa._modes_program.lower(rows, labels, plan)
+    elif name.startswith("rewrite:"):
+        cap = delta_rungs(said["num_messages"])[int(name.split(":")[1])]
+        lowered = lpa._rewrite_program.lower(rows, labels, changed, plan, cap=cap)
+    else:
+        raise SystemExit(f"no program {name!r}")
+    compiled = lowered.compile()
+    secs = time.time() - t0
+    ma = compiled.memory_analysis()
+    text = compiled.as_text()
+    print(json.dumps({
+        "program": name, "rows_bytes": 4 * s,
+        "temp": ma.temp_size_in_bytes, "alias": ma.alias_size_in_bytes,
+        "argument": ma.argument_size_in_bytes, "output": ma.output_size_in_bytes,
+        "code": ma.generated_code_size_in_bytes,
+        "temp_over_rows": round(ma.temp_size_in_bytes / (4 * s), 4),
+        "copy_done": text.count(" copy-done("), "compile_s": round(secs, 1),
+        "peak_rss_gb": round(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1e6, 2),
+    }), flush=True)
+
+
+if __name__ == "__main__":
+    said = json.load(open(sys.argv[1]))
+    if len(sys.argv) > 2:
+        one(said, sys.argv[2])
+    else:
+        for name in ["gather", "rewrite:0", "rewrite:1", "rewrite:2", "rewrite:3", "modes"]:
+            subprocess.run([sys.executable, os.path.abspath(__file__), sys.argv[1], name])

@@ -86,10 +86,12 @@ _I32 = 4  # bytes per int32/float32 slot — the one word size
 # 0.65 GB of program code). The compiled scan's temporaries (22.4 B/edge,
 # 5.83 GB) come on top: `peak_bytes_in_use` does not count them
 # (`peak_bytes_reserved` does) though they are taken from the same memory:
-# the seed's 6 B/edge of "gather transient" is a quarter of them. With the carried rows and their slot
-# index the arrays are 51.4 B/edge (+ 9.3 rows + 8.3 index) and the
-# temporaries 37.6 B/edge (9.80 GB): 89 B/edge, which one v5e does not hold
-# beside this graph (`carried_rows_inventory` below; ROADMAP D4).
+# the seed's 6 B/edge of "gather transient" is a quarter of them. With the
+# carried rows and their slot index the arrays are 51.4 B/edge (+ 9.3 rows
+# + 8.3 index); since PR 36 the rows are held once, each program of the
+# job updating them in place, and the largest program's temporaries are
+# 6.7 B/edge (1.74 GB: `carried_rows_inventory` below), where the one
+# compiled scan of PRs 32-35 took 37.6 (9.80 GB) and did not fit.
 BYTES_PER_EDGE = 36.0
 BYTES_PER_EDGE_WEIGHTED = 16.0
 SINGLE_BYTES_PER_VERTEX = 8.0
@@ -252,11 +254,12 @@ def superstep_footprint(
     weights, plus the width-ladder mats + vertex ids + the hubs' row
     offsets (+ slot-aligned weight mats) + the gathered transient
     (bucketed). A plan with its slot index adds what the carried-rows
-    scan of ``ops/lpa.py`` holds on the device
-    (:func:`carried_rows_inventory`: the rows as scan state and the slot
-    index by sender, exact; the copies of the rows the compiled scan
-    keeps beside its carry and the hubs' histograms, as the chip's
-    compiler counts them). The plan's own terms (``plan_mats``,
+    job of ``ops/lpa.py`` holds on the device
+    (:func:`carried_rows_inventory`: the rows, once, and the slot index by
+    sender, exact; the hubs' histograms and the temporaries of the full
+    gather, as the chip's compiler counts them; the rewrite's, which
+    depend on the policy's top rung, are the admission's to add). The
+    plan's own terms (``plan_mats``,
     ``plan_vertex_ids``, ``plan_hub_offsets``, ``weight_mats``,
     ``slot_index``) sum to the ``nbytes`` of the plan's arrays to the
     byte.
@@ -326,39 +329,60 @@ def _slots(mat) -> int:
     return int(mat.shape[0]) * int(mat.shape[1])
 
 
-# The compiled carried-rows scan holds its rows about four times over:
-# XLA's buffer assignment gives the scan's carry, the `switch`'s result and
-# the copies between them a buffer each (three `copy-done` of the whole
-# `s32[S]` buffer in the compiled text). `memory_analysis()` of the program
-# compiled for a v5e (PERF.md §6, PR 33): temporaries 2.81 GB at S = 137.8 M
-# slots (graph500-22) and 9.80 GB at S = 607.6 M (graph500-24), of which
-# the hubs' histograms (below) are 0.54 GB: 4.1 and 3.8 x 4S. An accident
-# of this compiler's buffer assignment, kept here only because the
-# admission has to count what the device will be asked for: it goes (to 1)
-# when the `switch` updates the rows in place (ROADMAP 2a).
-CARRIED_ROWS_COPIES = 4
+# Words (int32) a program of the carried-rows job holds per element while
+# it runs, as the chip's compiler assigned them (`memory_analysis()` of each
+# program compiled alone for a described v5e, from shapes: PERF.md §6, PR
+# 36, graph500-24's and graph500-22's plans and two planted graphs). The
+# rows themselves are in none of these: they are each updating program's
+# donated argument, aliased to its result, and held once.
+#   * a class's rows, gathered or sorted: the `[n, w]` result in the tiled
+#     layout and its flat copy (the gather), the sort's input and output
+#     (the modes): twice the widest class, the classes taking turns in the
+#     same two buffers;
+#   * the rewrite's compaction: a four-operand sort of V keys, in and out;
+#   * the rewrite's expansion: five cap-long vectors (the scattered
+#     differences and their running sums for source and value, the slot),
+#     after the sort's operands are dead.
+_CLASS_ROWS_COPIES = 2
+_REWRITE_SORT_WORDS = 8
+_REWRITE_CAP_WORDS = 5
 
 
-def carried_rows_inventory(plan) -> dict:
-    """What the carried-rows scan holds on the device beyond a fused
-    ``plan``, known from the plan's shapes before the index is built.
-    Exact: ``carried_rows``, the classes' rows end to end as scan state,
-    and ``slot_index``, one slot per message and ``V + 1`` offsets by
-    sender. As the chip's compiler counts them (:data:`CARRIED_ROWS_COPIES`):
-    ``gather_transient``, the further copies of the rows the compiled scan
-    keeps, and ``hub_histograms``, the hubs' ``[n, V]`` counts and the
-    scatter's copy of them. The admission of
-    ``ops/superstep_policy.admit_carried_rows`` holds their sum against
-    the device's free memory."""
-    rows = _I32 * sum(_slots(x) for x in plan.send_idx or ())
+def carried_rows_inventory(plan, top_rung: int = 0) -> dict:
+    """What the carried-rows job of ``ops/lpa.py`` holds on the device
+    beyond a fused ``plan``, known from the plan's shapes before the index
+    is built. Exact: ``carried_rows``, the classes' rows end to end, ONCE
+    (every program that updates them does so in place; held by
+    ``tests/test_chip_compile.py``); ``slot_index``, one slot per message
+    and ``V + 1`` offsets by sender; ``labels`` in and out and the
+    ``changed_mask``. As the chip's compiler counts them:
+    ``hub_histograms``, the hubs' ``[n, V]`` counts and the scatter's copy
+    of them, and ``gather_transient``, the other temporaries of the job's
+    largest program: the full gather (the widest class twice and the
+    padded labels), the row modes (the widest class twice) or the rewrite
+    at ``top_rung`` messages, the job's largest rung (its sort of V keys,
+    then its cap-long vectors). The sum holds the histograms beside the
+    largest program's temporaries though the modes program alone holds
+    both: an over-count where the rewrite is the largest. Program code is
+    device memory too and is in no term (0.75 GB for graph500-24's
+    programs). The admission of
+    ``ops/superstep_policy.admit_carried_rows`` holds the sum against the
+    device's free memory."""
+    v = int(plan.num_vertices)
+    classes = [_slots(x) for x in plan.send_idx or ()]
     hubs = 0 if plan.hist_vertex_ids is None else int(plan.hist_vertex_ids.shape[0])
+    widest = _CLASS_ROWS_COPIES * _I32 * max(classes, default=0)
     return {
-        "carried_rows": rows,
-        "slot_index": _I32 * (
-            int(plan.num_messages) + int(plan.num_vertices) + 1
+        "carried_rows": _I32 * sum(classes),
+        "slot_index": _I32 * (int(plan.num_messages) + v + 1),
+        "labels": 2 * _I32 * v,
+        "changed_mask": v,
+        "hub_histograms": 2 * _I32 * hubs * v,
+        "gather_transient": max(
+            widest + _I32 * (v + 1),
+            _REWRITE_SORT_WORDS * _I32 * v if top_rung else 0,
+            _REWRITE_CAP_WORDS * _I32 * int(top_rung),
         ),
-        "gather_transient": (CARRIED_ROWS_COPIES - 1) * rows,
-        "hub_histograms": 2 * _I32 * hubs * int(plan.num_vertices),
     }
 
 

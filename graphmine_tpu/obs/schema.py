@@ -117,11 +117,13 @@ register("fixpoint", "op", "supersteps", "changed", "num_vertices", "family")
 # slots rewritten through the index), and what it left for the next one:
 # `changed_vertices`, the labels it moved, and `changed_messages` (K), the
 # messages those vertices send, which picks the next branch. `rungs` are
-# `ops/superstep_policy.delta_rungs(M)`. From int32[max_iter] outputs of
-# the scan, read after the labels: no sync. A job whose rows were not
-# admitted to the device (`device_residency` says `scan: plain`) runs the
-# stateless scan, which keeps no rows and counts no K: every `branch` is
-# "full", `changed_messages` and `rungs` are empty. Benchmark metric
+# `ops/superstep_policy.delta_rungs(M)`. The host's own counts: the job
+# steps from the host and reads K and the labels moved once a superstep to
+# pick the next update (PR 36). A job whose rows were not admitted to the
+# device (`device_residency` says `scan: plain`) runs the stateless scan,
+# which keeps no rows and counts no K (its int32[max_iter] of labels moved
+# comes back with the labels): every `branch` is "full",
+# `changed_messages` and `rungs` are empty. Benchmark metric
 # `cdlp_sparse_superstep_share` reads `branch`.
 register("superstep_delta", "op", "changed_vertices", "changed_messages",
          "branch", "rungs", "num_messages")
@@ -133,13 +135,13 @@ register("superstep_delta", "op", "changed_vertices", "changed_messages",
 # `bytes_limit` and `bytes_in_use` at the time (None on a backend that
 # keeps no statistics). `graph_bytes` and `plan_bytes` are the arrays' own
 # `nbytes` (0 for a host-resident graph; the plan without its slot index),
-# `slot_index_bytes` the index's, `rows_bytes` the carried rows' (scan
-# state; 0 under `plain`), `labels_bytes` labels in and out, `code_bytes`
-# the executable's size where one reports it (None: a jitted call hands
-# none back). Arrays only: what the compiled scan takes beside them while
-# it runs (its temporaries, which the allocator's `peak_bytes_in_use`
-# leaves out too) is in no field; under `carried` the `reason` holds the
-# admission's count of it. `scan` is the admission's answer (`carried` |
+# `slot_index_bytes` the index's, `rows_bytes` the carried rows' (one
+# buffer a job, updated in place; 0 under `plain`), `labels_bytes` labels
+# in and out, `code_bytes` the executable's size where one reports it
+# (None: a jitted call hands none back). Arrays only: what a compiled
+# program takes beside them while it runs (its temporaries, which the
+# allocator's `peak_bytes_in_use` leaves out too) is in no field; under
+# `carried` the `reason` holds the admission's count of it. `scan` is the admission's answer (`carried` |
 # `plain`, ops/superstep_policy.admit_carried_rows) and `reason` its
 # arithmetic, of the device's memory alone. Benchmark metric
 # `plan_resident_gb` reads it.

@@ -13,8 +13,10 @@ invoked at ``Graphframes.py:81`` (GraphX Pregel LPA):
   defined, so cross-engine validation compares partitions, not ids).
 
 The superstep is one gather + one segment-mode over the precomputed message
-CSR — no shuffle, no driver round-trips. Under jit the whole ``max_iter``
-loop is a single ``lax.scan`` XLA program.
+CSR — no shuffle, no driver round-trips. The stateless supersteps run as a
+single ``lax.scan`` XLA program; over a fused plan whose rows the device has
+room for, the supersteps step from the host and keep their gathered rows
+(:func:`_carried_rows_job`).
 """
 
 from __future__ import annotations
@@ -79,16 +81,22 @@ def label_propagation(
     is impossible. Pass ``None`` to force the sort-based superstep.
 
     With a fused plan (``"auto"``'s, or one with ``send_idx``) the
-    supersteps run as :func:`_carried_rows_scan`: the gathered message
-    rows are state of the scan, and a superstep that follows few changed
-    labels rewrites only the slots behind their senders, through a slot
-    index built once per plan (:func:`_cached_slot_index`). Labels are
-    bit-identical to the stateless supersteps'; nothing selects it but
+    supersteps run as :func:`_carried_rows_job`, stepped from the host:
+    the gathered message rows live in one device buffer across supersteps,
+    and a superstep that follows few changed labels rewrites only the
+    slots behind their senders, through a slot index built once per plan
+    (:func:`_cached_slot_index`). Each superstep is two programs, the
+    rows' update (a full gather or a rung's rewrite, the rows donated to
+    it and so updated in place) and the row modes; the host reads one
+    count between them to pick the next update, and ``max_iter`` is the
+    length of its loop (a job of another length compiles nothing). Labels
+    are bit-identical to the stateless supersteps'; nothing selects it but
     what each superstep counts, and what the device has free: the rows
     and the index go on the device only if :func:`~graphmine_tpu.ops.
     superstep_policy.admit_carried_rows` finds room for them, once per
-    plan, before the index is built; otherwise the stateless bucketed
-    scan runs (``impl_selected`` says ``scan`` and ``scan_reason``).
+    plan, before the index is built; otherwise, and under a caller's
+    trace, where the host cannot step, the stateless bucketed scan runs
+    (``impl_selected`` says ``scan`` and ``scan_reason``).
 
     ``sink``: optional MetricsSink — each auto resolution emits an
     ``impl_selected`` record, and each plan materialization a
@@ -103,15 +111,14 @@ def label_propagation(
 
     On one device the graph may be host-resident too
     (``build_graph(..., to_device=False)``): the fused plan is built from
-    the host arrays and placed alone, and the compiled scan, which reads
-    the plan and nothing of the graph, is handed none of the graph's
+    the host arrays and placed alone, and the job's programs, which read
+    the plan and nothing of the graph, are handed none of the graph's
     arrays (``device_residency`` then says ``graph_bytes: 0``); the same
-    entry, the same scan, equal labels (tested on the CPU; no chip run has
-    taken it at a size that fills the chip). The admission sizes the
-    device alone: with graph500-24 host-resident it would answer
-    ``carried`` (12.4 GB against 14.4 GB free), and that program's compile
-    took 28 GB of host memory where the chip's machine has 40 GiB for
-    everything (PERF.md §6, PR 33).
+    entry, the same programs, equal labels (tested on the CPU; no chip run
+    has taken it at a size that fills the chip). The admission sizes the
+    device alone: the host compiles each program of the job alone, the
+    largest in about the stateless scan's memory (19.7 against 20 GB for
+    graph500-24's plan; PERF.md §6, PR 36).
 
     ``mesh``: a ``jax.sharding.Mesh`` runs the job across its devices
     (``None`` is the one-device path above, byte for byte). The graph
@@ -165,11 +172,7 @@ def label_propagation(
         raise ValueError(
             f"plan must be 'auto', None or a BucketedModePlan; got {plan!r}"
         )
-    elif (
-        plan is not None
-        and plan.send_idx
-        and not isinstance(plan.send_idx[0], jax.core.Tracer)
-    ):
+    elif plan is not None and plan.send_idx:
         plan, _, _ = _cached_slot_index(plan)
     if (
         isinstance(plan, BucketedModePlan)
@@ -190,9 +193,13 @@ def label_propagation(
                 f"[{int(il.min())}, {int(il.max())}] — pass plan=None for "
                 "arbitrary label values"
             )
+    carried = (
+        plan is not None and plan.out_slot is not None and not _under_a_trace()
+    )
+    job = _carried_rows_job if carried else _label_propagation
     if sink is not None and not isinstance(graph.msg_ptr, jax.core.Tracer):
         # Achieved-vs-model attribution (ISSUE 12): wall-time the whole
-        # compiled scan as one window of max_iter supersteps and judge it
+        # job as one window of max_iter supersteps and judge it
         # against the analytical cost model — one superstep_timing record
         # per call, zero extra device syncs beyond the result fetch the
         # caller was about to pay anyway.
@@ -203,7 +210,7 @@ def label_propagation(
         )
 
         (labels, per_step), secs, cold = timed_fixpoint(
-            lambda: _label_propagation(graph, max_iter, init_labels, plan),
+            lambda: job(graph, max_iter, init_labels, plan),
         )
         cost = superstep_cost(
             "lpa_superstep",
@@ -218,20 +225,19 @@ def label_propagation(
         if plan is not None and plan.send_idx:
             _emit_superstep_delta(sink, per_step, plan.num_messages)
     else:
-        labels, per_step = _label_propagation(
-            graph, max_iter, init_labels, plan
-        )
+        labels, per_step = job(graph, max_iter, init_labels, plan)
     if return_history:
-        return labels, per_step["changed_vertices"]
+        return labels, jnp.asarray(per_step["changed_vertices"], jnp.int32)
     return labels
 
 
 def _emit_superstep_delta(sink, per_step: dict, num_messages: int) -> None:
     """The ``superstep_delta`` record of one job over a fused plan's dense
-    rows, from the scan's per-superstep outputs (the job's labels are
-    already back). The stateless scan, which runs where the rows were not
-    admitted to the device, has no ``branch`` to report: every one of its
-    supersteps is a full gather, and the record says that."""
+    rows, from the job's per-superstep counts (the host's own by now; the
+    stateless scan's come back with its labels). The stateless scan, which
+    runs where the rows were not admitted to the device, has no ``branch``
+    to report: every one of its supersteps is a full gather, and the
+    record says that."""
     import numpy as np
 
     from graphmine_tpu.ops.superstep_policy import delta_rungs
@@ -239,8 +245,8 @@ def _emit_superstep_delta(sink, per_step: dict, num_messages: int) -> None:
     changed = np.asarray(per_step["changed_vertices"]).tolist()
     if "branch" in per_step:
         names = [*delta_rungs(num_messages), "full"]
-        branch = [names[b] for b in np.asarray(per_step["branch"]).tolist()]
-        messages = np.asarray(per_step["changed_messages"]).tolist()
+        branch = [names[b] for b in per_step["branch"]]
+        messages = per_step["changed_messages"]
         rungs = names[:-1]
     else:
         branch, messages, rungs = ["full"] * len(changed), [], []
@@ -249,6 +255,12 @@ def _emit_superstep_delta(sink, per_step: dict, num_messages: int) -> None:
         changed_messages=messages, branch=branch, rungs=rungs,
         num_messages=num_messages,
     )
+
+
+def _under_a_trace() -> bool:
+    """A caller's ``jit`` (or other transform) is tracing: no value is
+    concrete, so the host cannot read a count between two programs."""
+    return not jax.core.trace_ctx.is_top_level()
 
 
 _auto_plan_cache: dict = {}
@@ -285,13 +297,14 @@ _slot_index_cache: dict = {}
 
 def _cached_slot_index(plan):
     """``(plan, build seconds, (scan, reason))``: the fused ``plan`` as the
-    scan will run it. Once per plan (as the plan is paid once per graph)
+    job will run it. Once per plan (as the plan is paid once per graph)
     :func:`~graphmine_tpu.ops.superstep_policy.admit_carried_rows` says
     whether the carried rows and their index fit the device beside what
-    it holds; only then is the index of the carried-rows scan built
+    it holds; only then is the index of the carried-rows job built
     (:func:`~graphmine_tpu.ops.bucketed_mode.with_slot_index`), and the
     plan comes back with it. Under ``plain`` the plan comes back as it
-    is, and runs the stateless bucketed scan. The answer and the index
+    is, and runs the stateless bucketed scan; under a caller's trace too,
+    with no question asked and nothing kept. The answer and the index
     are kept (0.0 seconds on a hit), keyed by the identity of the plan's
     first row matrix; a weakref finalizer evicts the entry with it. The
     index stays out of the plan the cache of :func:`_cached_auto_plan`
@@ -306,6 +319,10 @@ def _cached_slot_index(plan):
         timed_plan_build,
     )
 
+    if _under_a_trace():
+        return plan, 0.0, (
+            "plain", "under a trace the supersteps cannot step from the host"
+        )
     if plan.out_slot is not None:
         return plan, 0.0, ("carried", "the plan came with its slot index")
     if not plan.send_idx:
@@ -457,22 +474,16 @@ def _label_propagation(
     init_labels: jax.Array | None = None,
     plan=None,
 ):
-    """``(labels, per_step)``: all ``max_iter`` supersteps as one
-    ``lax.scan``. ``per_step`` holds ``int32[max_iter]`` vectors:
-    ``changed_vertices`` always, and with carried rows (a fused plan with
-    its slot index, :func:`_carried_rows_scan`) ``changed_messages`` and
-    ``branch`` too."""
+    """``(labels, per_step)``: all ``max_iter`` stateless supersteps as one
+    ``lax.scan``: the sort family (``plan=None``), a plan without a slot
+    index, and every plan under a caller's trace. ``per_step`` holds
+    ``changed_vertices``, ``int32[max_iter]``. A plan with its slot index
+    outside a trace runs :func:`_carried_rows_job` instead."""
     labels = (
         jnp.arange(graph.num_vertices, dtype=jnp.int32)
         if init_labels is None
         else init_labels.astype(jnp.int32)
     )
-    if plan is not None and plan.out_slot is not None:
-        from graphmine_tpu.ops.bucketed_mode import check_plan_fits
-
-        check_plan_fits(labels, graph, plan)
-        return _carried_rows_scan(labels, plan, max_iter)
-
     if plan is None:
         superstep = lambda lbl: lpa_superstep(lbl, graph)
     else:
@@ -490,55 +501,97 @@ def _label_propagation(
     return labels, {"changed_vertices": changed}
 
 
-def _carried_rows_scan(labels: jax.Array, plan, max_iter: int):
-    """The supersteps over a fused plan with its slot index: the gathered
-    rows are carried state, and a superstep reads again only what changed.
+# The carried-rows job's three kinds of program (ISSUE 36). The rows are a
+# donated argument of the two that update them, so the chip's compiler
+# writes each class's gather and the rewrite's scatter into the buffer it
+# was handed: no copy of the rows, no temporary of their size (held by
+# tests/test_chip_compile.py). The plan is an argument of each: closed
+# over, its arrays would be constants of the program.
+
+
+@partial(jax.jit, static_argnames=("slots",))
+def _blank_rows(slots: int):
+    """The job's rows before the first superstep's full gather."""
+    return jnp.zeros((slots,), jnp.int32)
+
+
+@partial(jax.jit, donate_argnums=0)
+def _gather_program(rows, labels, plan):
+    from graphmine_tpu.ops.bucketed_mode import gather_rows
+
+    return gather_rows(rows, labels, plan)
+
+
+@partial(jax.jit, static_argnames=("cap",), donate_argnums=0)
+def _rewrite_program(rows, labels, changed, plan, cap: int):
+    from graphmine_tpu.ops.bucketed_mode import rewrite_rows
+
+    return rewrite_rows(rows, labels, changed, plan, cap)
+
+
+@jax.jit
+def _modes_program(rows, labels, plan):
+    """``(new labels, changed, K, count)`` of one superstep over ``rows``:
+    K the messages the changed vertices send, which picks the next
+    superstep's update, and count the changed vertices."""
+    from graphmine_tpu.ops.bucketed_mode import lpa_modes_from_rows
+
+    new = lpa_modes_from_rows(rows, labels, plan)
+    with jax.named_scope("superstep"), jax.named_scope("changed_count"):
+        changed = new != labels
+        out_deg = plan.out_ptr[1:] - plan.out_ptr[:-1]
+        k = jnp.sum(jnp.where(changed, out_deg, 0), dtype=jnp.int32)
+        count = jnp.sum(changed, dtype=jnp.int32)
+    return new, changed, k, count
+
+
+def _carried_rows_job(graph: Graph, max_iter: int, init_labels, plan):
+    """``(labels, per_step)`` of ``max_iter`` supersteps over a fused plan
+    with its slot index, stepped from the host: the gathered rows live in
+    one buffer across supersteps, and a superstep reads again only what
+    changed.
 
     Each superstep first brings the rows up to the labels it starts from,
-    by the branch its predecessor's count picks: K, the messages sent by
+    by the update its predecessor's count picks: K, the messages sent by
     the vertices whose label changed. K above every rung of
     :func:`~graphmine_tpu.ops.superstep_policy.delta_rungs` gathers every
-    class anew (the first superstep always: the carry starts at M + 1, so
-    the classes' gathers are in the program once); K <= a rung rewrites
-    that many slots through the index. Then the row modes, the histogram
-    hubs and the write back run over the rows, as in
-    ``lpa_superstep_bucketed``: the labels are its labels bit for bit."""
-    from graphmine_tpu.ops.bucketed_mode import (
-        gather_rows,
-        lpa_modes_from_rows,
-        rewrite_rows,
-        row_slots,
-    )
+    class anew (:func:`_gather_program`; the first superstep always);
+    K <= a rung rewrites that many slots through the index
+    (:func:`_rewrite_program`, compiled when a job first takes the rung).
+    Then :func:`_modes_program` runs the row modes, the histogram hubs and
+    the write back over the rows, as in ``lpa_superstep_bucketed``: the
+    labels are its labels bit for bit. The host waits once a superstep,
+    for K; ``max_iter`` is the length of this loop and no program's
+    argument. ``per_step`` holds ``changed_vertices``,
+    ``changed_messages`` and ``branch`` (the rung's place, or
+    ``len(rungs)`` for a full gather), one a superstep."""
+    from graphmine_tpu.ops.bucketed_mode import check_plan_fits, row_slots
     from graphmine_tpu.ops.superstep_policy import delta_rungs
 
+    labels = (
+        jnp.arange(graph.num_vertices, dtype=jnp.int32)
+        if init_labels is None
+        else jnp.asarray(init_labels).astype(jnp.int32)
+    )
+    check_plan_fits(labels, graph, plan)
     rungs = delta_rungs(plan.num_messages)
-    out_deg = plan.out_ptr[1:] - plan.out_ptr[:-1]
-    branches = [
-        partial(rewrite_rows, plan=plan, cap=rung) for rung in rungs
-    ] + [lambda rows, labels, changed: gather_rows(rows, labels, plan)]
-
-    def step(carry, _):
-        labels, rows, changed, k = carry
-        branch = jnp.sum(k > jnp.array(rungs, jnp.int32), dtype=jnp.int32)
-        rows = lax.switch(branch, branches, rows, labels, changed)
-        new = lpa_modes_from_rows(rows, labels, plan)
-        with jax.named_scope("superstep"), jax.named_scope("changed_count"):
-            changed = new != labels
-            k = jnp.sum(jnp.where(changed, out_deg, 0), dtype=jnp.int32)
-            count = jnp.sum(changed, dtype=jnp.int32)
-        return (new, rows, changed, k), (count, k, branch)
-
-    carry = (
-        labels,
-        jnp.zeros((row_slots(plan),), jnp.int32),
-        jnp.zeros(labels.shape, bool),
-        jnp.int32(plan.num_messages + 1),
-    )
-    (labels, _, _, _), (count, k, branch) = lax.scan(
-        step, carry, None, length=max_iter
-    )
+    rows = _blank_rows(row_slots(plan))
+    changed, k = None, plan.num_messages + 1
+    count, sent, branch = [], [], []
+    for _ in range(max_iter):
+        branch.append(sum(k > rung for rung in rungs))
+        if branch[-1] == len(rungs):
+            rows = _gather_program(rows, labels, plan)
+        else:
+            rows = _rewrite_program(
+                rows, labels, changed, plan, cap=rungs[branch[-1]]
+            )
+        labels, changed, k, moved = _modes_program(rows, labels, plan)
+        k, moved = (int(x) for x in jax.device_get((k, moved)))  # the one wait
+        sent.append(k)
+        count.append(moved)
     return labels, {
-        "changed_vertices": count, "changed_messages": k, "branch": branch,
+        "changed_vertices": count, "changed_messages": sent, "branch": branch,
     }
 
 

@@ -12,7 +12,7 @@ This module owns the choice (:func:`select_superstep_family`) and the
 ``impl_selected`` / ``plan_build`` records that explain it
 (:func:`emit_plan_records`); ``ops/lpa.py``, ``ops/cc.py``,
 ``ops/pagerank.py``, ``pipeline/planner.py`` and ``pipeline/driver.py``
-read both from here. Beside it, for the one-chip LPA scan over a fused
+read both from here. Beside it, for the one-chip LPA job over a fused
 plan: whether the gathered rows and their slot index go on the device
 (:func:`admit_carried_rows`) and the ``device_residency`` record that
 says what the device then holds (:func:`emit_device_residency`).
@@ -24,7 +24,6 @@ import time
 
 from graphmine_tpu.obs.costmodel import _bucketed_padded_slots, superstep_cost
 from graphmine_tpu.obs.memmodel import (
-    CARRIED_ROWS_COPIES,
     FAMILY_DEGRADE,
     carried_rows_inventory,
     superstep_footprint,
@@ -52,7 +51,7 @@ BUCKETED_MIN_MESSAGES = 1 << 16
 # the families, fast to lean: the keys of the one degrade order
 FAMILIES = tuple(FAMILY_DEGRADE)
 
-# Carried rows (PR 32): the one-chip LPA scan keeps the gathered rows and,
+# Carried rows (PR 32): the one-chip LPA job keeps the gathered rows and,
 # when the senders whose label changed send K <= a rung messages, rewrites
 # K slots (padded to the rung: a static shape) instead of gathering all S.
 # A rung is M over a divisor; K above the last rung takes the full gather.
@@ -71,7 +70,7 @@ DELTA_RUNG_DIVISORS = (4096, 256, 16, 6)
 
 
 def delta_rungs(num_messages: int) -> tuple:
-    """The rungs of the carried-rows scan, ascending: the static caps on
+    """The rungs of the carried-rows job, ascending: the static caps on
     the messages a sparse superstep rewrites (none on a graph too small
     to have one: every superstep then gathers in full)."""
     return tuple(sorted(
@@ -91,25 +90,28 @@ def device_memory_stats(plan) -> dict | None:
 
 
 def admit_carried_rows(plan, stats: dict | None) -> tuple[str, str]:
-    """``("carried" | "plain", reason)`` for the one-chip LPA scan over the
+    """``("carried" | "plain", reason)`` for the one-chip LPA job over the
     fused ``plan``: do the carried rows and the slot index go on the
     device beside what it already holds? Taken once per plan, on the host,
     before the index is built, from the plan's shapes
     (:func:`~graphmine_tpu.obs.memmodel.carried_rows_inventory`: the rows,
-    the index, the copies the compiled scan keeps, the hubs' histograms)
-    against ``stats`` (:func:`device_memory_stats`): ``bytes_limit`` less
-    ``bytes_in_use``, the graph and the plan being in use already.
-    ``plain`` is the stateless bucketed scan, the same labels bit for bit
-    at a full gather every superstep. A device that reports no limit
-    admits ``carried``.
+    held once, the index, the labels, the hubs' histograms and the
+    temporaries of the job's largest program, the rewrite at the top rung
+    of :func:`delta_rungs` among them) against ``stats``
+    (:func:`device_memory_stats`): ``bytes_limit`` less ``bytes_in_use``,
+    the graph and the plan being in use already. ``plain`` is the
+    stateless bucketed scan, the same labels bit for bit at a full gather
+    every superstep. A device that reports no limit admits ``carried``.
 
-    The DEVICE's memory alone is sized. The host's is not: compiling the
-    carried-rows program for graph500-24's plan (607.6 M slots, 62
-    classes) took 28 GB of host memory and the stateless program 20 GB
-    (PERF.md §6, PR 33), and a job admitted here whose compile does not
-    fit the host still ends there, minutes later. Every ``reason`` says
-    so."""
-    need = carried_rows_inventory(plan)
+    The DEVICE's memory alone is sized. The host's is not: each program of
+    the job compiles alone, and for graph500-24's plan (607.6 M slots, 58
+    classes) the largest, the row modes, took 19.7 GB of host memory, as
+    the stateless program's 20 GB (PERF.md §6, PR 36); a job admitted here
+    whose compile does not fit the host still ends there, minutes later.
+    Every ``reason`` says so."""
+    need = carried_rows_inventory(
+        plan, top_rung=max(delta_rungs(int(plan.num_messages)), default=0)
+    )
     slots = need["carried_rows"] // 4
     if slots == 0 or slots >= _INT32_MAX:
         return "plain", (
@@ -118,10 +120,11 @@ def admit_carried_rows(plan, stats: dict | None) -> tuple[str, str]:
         )
     total = sum(need.values())
     said = (
-        f"rows {need['carried_rows']} B + {CARRIED_ROWS_COPIES - 1} copies "
-        f"in the compiled scan {need['gather_transient']} B + slot index "
-        f"{need['slot_index']} B + hub histograms {need['hub_histograms']} B "
-        f"= {total} B"
+        f"rows, held once, {need['carried_rows']} B + slot index "
+        f"{need['slot_index']} B + labels and changed mask "
+        f"{need['labels'] + need['changed_mask']} B + hub histograms "
+        f"{need['hub_histograms']} B + the largest program's other "
+        f"temporaries {need['gather_transient']} B = {total} B"
     )
     unsized = " (device memory alone: the host's compile memory is not sized)"
     limit = (stats or {}).get("bytes_limit")
@@ -242,10 +245,10 @@ def emit_device_residency(
 ) -> None:
     """The ``device_residency`` record of one plan materialisation (see
     ``obs/schema.py``): what the device holds for this graph's supersteps,
-    by array group, from the arrays' own ``nbytes`` and, for the scan's
-    state, ``superstep_footprint``'s terms; beside the device's
+    by array group, from the arrays' own ``nbytes`` and, for the job's
+    rows and labels, ``superstep_footprint``'s terms; beside the device's
     ``bytes_limit`` (:func:`device_memory_stats`, asked here). No-op
-    without a sink. ``plan`` is the plan the scan runs, with its slot
+    without a sink. ``plan`` is the plan the job runs, with its slot
     index when ``scan`` says ``carried``."""
     if sink is None:
         return

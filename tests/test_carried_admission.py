@@ -1,8 +1,10 @@
-"""The admission of the carried-rows LPA scan (ISSUE 33): the memory model
-counts the carried rows and the slot index to the byte, the policy admits
-them under the device's free memory and answers ``plain`` otherwise,
-before any index is built; either way the labels are the same bit for bit,
-and the ``device_residency`` record says what the device holds."""
+"""The admission of the carried-rows LPA job (ISSUE 33; the rows held once
+since ISSUE 36): the memory model counts the carried rows and the slot
+index to the byte and the temporaries of the job's largest program from
+the plan's shapes, the policy admits them under the device's free memory
+and answers ``plain`` otherwise, before any index is built; either way the
+labels are the same bit for bit, and the ``device_residency`` record says
+what the device holds."""
 
 import sys
 
@@ -16,7 +18,7 @@ from graphmine_tpu.obs.schema import validate_records
 from graphmine_tpu.ops import lpa, superstep_policy
 from graphmine_tpu.ops.bucketed_mode import row_slots, with_slot_index
 from graphmine_tpu.ops.lpa import label_propagation
-from graphmine_tpu.ops.superstep_policy import admit_carried_rows
+from graphmine_tpu.ops.superstep_policy import admit_carried_rows, delta_rungs
 from graphmine_tpu.pipeline.metrics import MetricsSink
 
 from test_lpa_delta import _case, _fused, _rmat
@@ -48,10 +50,13 @@ def test_the_footprint_of_an_indexed_plan_is_its_arrays_to_the_byte(name):
     inv = _footprint(g, indexed)
     assert sum(inv.get(term, 0) for term in PLAN_TERMS) == _nbytes(indexed)
     assert inv["slot_index"] == indexed.out_ptr.nbytes + indexed.out_slot.nbytes
-    assert inv["carried_rows"] == 4 * row_slots(plan)
-    # as the chip's compiler counts them: the scan's further copies of its
-    # rows, the hubs' [n, V] histograms and the scatter's copy of them
-    assert inv["gather_transient"] == 3 * inv["carried_rows"]
+    assert inv["carried_rows"] == 4 * row_slots(plan)  # once: updated in place
+    assert inv["labels"] == 8 * g.num_vertices and inv["changed_mask"] == g.num_vertices
+    # as the chip's compiler counts them: the widest class's rows twice and
+    # the padded labels (the full gather, the largest program where no rung
+    # is named), the hubs' [n, V] histograms and the scatter's copy of them
+    widest = max(n * w for n, w in (idx.shape for idx in plan.send_idx))
+    assert inv["gather_transient"] == 8 * widest + 4 * (g.num_vertices + 1)
     hubs = 0 if plan.hist_vertex_ids is None else plan.hist_vertex_ids.shape[0]
     assert inv["hub_histograms"] == 8 * hubs * g.num_vertices
     assert (hubs > 0) == (name in ("rmat_with_a_histogram_hub", "star"))
@@ -61,14 +66,99 @@ def test_the_footprint_of_an_indexed_plan_is_its_arrays_to_the_byte(name):
     # before the index is built the same terms are known from the shapes
     assert memmodel.carried_rows_inventory(plan).items() <= inv.items()
     plain = _footprint(g, plan)
-    assert not set(memmodel.carried_rows_inventory(plan)) - {"gather_transient"} & set(plain)
+    assert not set(memmodel.carried_rows_inventory(plan)) - {"gather_transient", "labels"} & set(plain)
     assert plain["gather_transient"] == 4 * (row_slots(plan) + (
         0 if plan.hist_send is None else plan.hist_send.shape[0]))
     assert sum(plain.get(term, 0) for term in PLAN_TERMS) == _nbytes(plan)
 
 
 def _need(g, plan) -> int:
-    return sum(memmodel.carried_rows_inventory(plan).values())
+    """What the admission holds against the free memory: the inventory with
+    the rewrite at the plan's top rung among the programs."""
+    top = max(delta_rungs(plan.num_messages), default=0)
+    return sum(memmodel.carried_rows_inventory(plan, top_rung=top).values())
+
+
+# -- the plan of the graph that fills one chip, from its shapes ---------------
+
+# graphalytics-g500-24's fused plan, [n, w] a class (a host-only build of the
+# configuration's draw, _proof/shapes24.py): V = 2^24, M = 520,752,272,
+# S = 607,595,159 padded slots, four histogram hubs
+_G500_24_CLASSES = [
+    (2157572, 1), (1102139, 2), (681618, 3), (465124, 4), (366473, 5),
+    (330564, 6), (307882, 7), (269267, 8), (216382, 9), (159026, 10),
+    (107252, 11), (69081, 12), (46079, 13), (35663, 14), (35137, 15),
+    (42527, 16), (53486, 17), (67211, 18), (80915, 19), (93489, 20),
+    (210322, 22), (211937, 24), (181899, 26), (134477, 28), (85468, 30),
+    (61899, 33), (19908, 36), (4881, 39), (1041, 42), (462, 46), (1589, 50),
+    (10003, 55), (36574, 60), (113755, 66), (189136, 72), (218013, 79),
+    (120469, 86), (39756, 94), (5562, 103), (311, 113), (5, 124), (73, 179),
+    (3272, 196), (48159, 215), (169957, 236), (113231, 259), (11286, 284),
+    (126, 312), (1011, 665), (70739, 731), (62433, 804), (413, 884),
+    (42504, 3072), (545, 6912), (10081, 10368), (2024, 23328), (276, 78732),
+    (21, 177147),
+]
+_G500_24_LIMIT, _G500_24_IN_USE = 16_909_336_064, 8_850_000_000  # PERF.md §4
+
+
+def _g500_24_plan():
+    """Shapes only: the admission reads nothing else of a plan."""
+    i32 = lambda *dims: jax.ShapeDtypeStruct(dims, np.int32)
+    v, m, hist_send = 1 << 24, 520_752_272, 897_725
+    return bucketed_mode.BucketedModePlan(
+        vertex_ids=tuple(i32(n) for n, _ in _G500_24_CLASSES), msg_idx=None,
+        num_vertices=v, num_messages=m,
+        send_idx=tuple(i32(n, w) for n, w in _G500_24_CLASSES),
+        hist_vertex_ids=i32(4), hist_send=i32(hist_send),
+        hist_row_offset=i32(hist_send),
+    )
+
+
+def test_the_inventory_of_graph500_24_s_plan_term_by_term():
+    """Each term from the shapes, beside what the chip's compiler assigned
+    the programs compiled alone for a v5e (PERF.md §6, PR 36)."""
+    plan = _g500_24_plan()
+    v, m, s = plan.num_vertices, plan.num_messages, 607_595_159
+    top = delta_rungs(m)[-1]
+    assert row_slots(plan) == s and top == m // 6 == 86_792_045
+    inv = memmodel.carried_rows_inventory(plan, top_rung=top)
+    widest = 42504 * 3072
+    assert inv == {
+        "carried_rows": 4 * s,            # 2.43 GB, once
+        "slot_index": 4 * (m + v + 1),    # 2.15 GB
+        "labels": 8 * v, "changed_mask": v,
+        "hub_histograms": 8 * 4 * v,      # 0.54 GB
+        # the rewrite at M / 6: five cap-long vectors (compiled: 1,736,506,880)
+        "gather_transient": 20 * top,
+    }
+    assert 20 * top > 8 * widest + 4 * (v + 1) > 32 * v
+    # without a rung the largest program is the full gather (compiled:
+    # 1,115,328,512; the row modes 1,580,267,520 with the histograms)
+    no_rung = memmodel.carried_rows_inventory(plan)
+    assert no_rung["gather_transient"] == 8 * widest + 4 * (v + 1) == 1_111_687_172
+    # a lower rung's rewrite is its sort of V keys, in and out (compiled:
+    # 537,257,984 at M / 4096)
+    low = memmodel.carried_rows_inventory(plan, top_rung=delta_rungs(m)[0])
+    assert low["gather_transient"] == no_rung["gather_transient"]
+    assert 32 * v == 536_870_912 < no_rung["gather_transient"]
+    assert sum(inv.values()) == 7_004_205_348
+
+
+@pytest.mark.parametrize("in_use,want", [
+    (_G500_24_IN_USE, "carried"),                            # 8.06 GB free: the cell
+    (_G500_24_LIMIT - 7_004_205_348, "carried"),             # to the byte
+    (_G500_24_LIMIT - 7_004_205_348 + 1, "plain"),
+    (_G500_24_LIMIT - 4 * 607_595_159, "plain"),             # room for the rows alone
+], ids=["beside-the-resident-graph", "exactly", "a-byte-short", "rows-only"])
+def test_graph500_24_s_plan_is_admitted_beside_its_device_resident_graph(in_use, want):
+    """The parent counted the rows four times (12.41 GB) and answered
+    ``plain`` at 8.06 GB free; held once the job asks for 7.00 GB."""
+    scan, reason = admit_carried_rows(
+        _g500_24_plan(), {"bytes_limit": _G500_24_LIMIT, "bytes_in_use": in_use})
+    assert scan == want
+    assert "7004205348 B" in reason and "held once" in reason
+    assert f"against {_G500_24_LIMIT - in_use} B free" in reason
+    assert "not sized" in reason
 
 
 @pytest.mark.parametrize("free,want", [(-1, "plain"), (0, "carried"), (1 << 20, "carried")])

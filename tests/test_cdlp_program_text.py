@@ -13,8 +13,14 @@ PR 31 did for ``_connected_components`` alone: its ``while_loop`` carries
 the per-superstep changed counts of the ``fixpoint`` record (ISSUE 31).
 PR 32 did for ``_label_propagation`` alone: over a fused plan with its slot
 index the scan carries the gathered rows and rewrites the changed senders'
-slots (ISSUE 32); the other two digests stand, which is the proof that the
-pipeline's and WCC's programs did not move.
+slots (ISSUE 32). PR 36 replaced that one digest by six: the scan and its
+``switch`` are gone, and a carried job steps from the host through
+``_gather_program``, ``_rewrite_program`` (one a rung) and ``_modes_program``
+of ``ops/lpa.py``, each pinned here; ``_label_propagation`` is the stateless
+scan alone (the text the parent's lowered to over a plan without its index,
+which the ``plain`` admission ran: pinned too). The
+other two digests stand, which is the proof that the pipeline's and WCC's
+programs did not move.
 """
 
 import hashlib
@@ -29,10 +35,17 @@ from graphmine_tpu.ops.bucketed_mode import (
     _HIST_MIN_DEG,
     BucketedModePlan,
     lpa_superstep_bucketed,
+    row_slots,
     with_slot_index,
 )
 from graphmine_tpu.ops.cc import _connected_components
-from graphmine_tpu.ops.lpa import _label_propagation
+from graphmine_tpu.ops.lpa import (
+    _gather_program,
+    _label_propagation,
+    _modes_program,
+    _rewrite_program,
+)
+from graphmine_tpu.ops.superstep_policy import delta_rungs
 
 
 def _graph_and_plan():
@@ -50,19 +63,41 @@ def _lowered(name):
         labels = jnp.arange(g.num_vertices, dtype=jnp.int32)
         return jax.jit(lpa_superstep_bucketed).lower(labels, g, plan)
     if name == "_label_propagation":
-        return _label_propagation.lower(
-            g, max_iter=10, plan=with_slot_index(plan)
-        )
-    return _connected_components.lower(g, plan=plan)
+        return _label_propagation.lower(g, max_iter=10, plan=plan)
+    if name == "_connected_components":
+        return _connected_components.lower(g, plan=plan)
+    # the carried job's programs: shapes are all a lowering reads
+    plan = with_slot_index(plan)
+    rows = jax.ShapeDtypeStruct((row_slots(plan),), jnp.int32)
+    labels = jax.ShapeDtypeStruct((g.num_vertices,), jnp.int32)
+    if name == "_gather_program":
+        return _gather_program.lower(rows, labels, plan)
+    if name == "_modes_program":
+        return _modes_program.lower(rows, labels, plan)
+    changed = jax.ShapeDtypeStruct((g.num_vertices,), jnp.bool_)
+    rung = delta_rungs(g.num_messages)[int(name.rsplit(":", 1)[1])]
+    return _rewrite_program.lower(rows, labels, changed, plan, cap=rung)
 
 
 _PARENT_DIGESTS = {
     "lpa_superstep_bucketed":
         "f6997c7ecbe220e9bdbd9b8f2be3c5fd7205ec611cdfd6460eb9e5c1b125dace",
     "_label_propagation":
-        "66d099320083c8cb2c5a2e009aa9074f81be462008719609e1255471b9c75061",
+        "a2ba5004c2c1483a70bf8d46dd9716cdeaa254c5ad5e15c3037b35eb63b9daf9",
     "_connected_components":
         "c65ca4a6c2759859380430036393bd2d46d3bc1d496320a65d7eff2f851a16ac",
+    "_gather_program":
+        "1e713dce6aa57bd64d11d8dd3c27a7431abf543c911336e84f36bcf1cee8d3af",
+    "_modes_program":
+        "b69dc9867c6e86f00844a1470059cddf9eb035a1292a578f801905726ec23dc4",
+    "_rewrite_program:0":
+        "1e1c513ed232b5f7cbf2c60519a81ae4f95dbb14b9a2a7636f14105106e5f0ed",
+    "_rewrite_program:1":
+        "4ce2a940455086c6f4111759feef2010ddb76565f6d4d2f657fa7693afe4ae80",
+    "_rewrite_program:2":
+        "bfa41019117a6942bb1f37c04b145be552b4f725ac2f89874d067a88ccd9a777",
+    "_rewrite_program:3":
+        "f76dd23541cb9296c6dd4e4296cad7b168df6ec5639b6908ddeb61afdebf2d7d",
 }
 
 
@@ -73,15 +108,19 @@ def test_the_cdlp_programs_lower_to_the_parent_s_text(name):
     assert hashlib.sha256(text.encode()).hexdigest() == _PARENT_DIGESTS[name]
 
 
-def test_the_classes_gathers_are_in_the_carried_rows_program_once():
-    """The first superstep takes the full branch by its carry (K = M + 1),
-    not by a copy of the gathers peeled before the loop: one ``case`` in
-    the scan's body, and each class's ``[n, w]`` row gather in it once."""
+def test_each_class_s_gather_is_in_the_gather_program_once_and_in_no_other():
+    """The full gather is a program of its own: each class's ``[n, w]`` row
+    gather is in ``_gather_program`` once, and neither the rewrites nor the
+    row modes hold one (they read the rows they are handed). No program of
+    the job picks a branch on the device: the host does."""
     _, plan = _graph_and_plan()
-    text = _lowered("_label_propagation").as_text()
-    assert text.count("stablehlo.case") == 1
-    gathers = [ln for ln in text.splitlines() if "stablehlo.gather" in ln]
-    for idx in plan.send_idx:
-        n, w = idx.shape
-        rows = [ln for ln in gathers if ln.endswith(f"-> tensor<{n}x{w}xi32>")]
-        assert len(rows) == 1, (n, w, len(rows))
+    texts = {name: _lowered(name).as_text() for name in _PARENT_DIGESTS
+             if name.endswith("_program") or "_program:" in name}
+    assert len(texts) == 6
+    for name, text in texts.items():
+        assert "stablehlo.case" not in text and "stablehlo.while" not in text
+        gathers = [ln for ln in text.splitlines() if "stablehlo.gather" in ln]
+        for idx in plan.send_idx:
+            n, w = idx.shape
+            rows = [ln for ln in gathers if ln.endswith(f"-> tensor<{n}x{w}xi32>")]
+            assert len(rows) == (name == "_gather_program"), (name, n, w, len(rows))

@@ -178,19 +178,42 @@ def test_lpa_superstep_bucketed_compiles_for_v5e(one_chip, fused_plan, planted):
     )
 
 
-def test_carried_rows_scan_compiles_for_v5e(one_chip, fused_plan, planted):
-    """The one-chip CDLP job (ISSUE 32): the scan that carries the gathered
-    rows, a ``switch`` between the classes' full gathers and the rung-capped
-    rewrites through the slot index, and the row modes over flat slices."""
-    from graphmine_tpu.ops.bucketed_mode import with_slot_index
-    from graphmine_tpu.ops.lpa import _label_propagation
+@pytest.mark.parametrize("program", ["gather", "rewrite", "modes"])
+def test_carried_rows_programs_compile_for_v5e(one_chip, fused_plan, planted, program):
+    """The one-chip CDLP job's three kinds of program (ISSUE 36), each
+    compiled alone: the classes' full gathers and the top rung's rewrite
+    through the slot index update the rows IN PLACE, because the rows are
+    their donated argument: the compiler aliases the whole ``s32[S]``
+    buffer to the result and keeps no temporary of its size. The admission
+    (``obs/memmodel.carried_rows_inventory``) counts the rows once on the
+    strength of this."""
+    from graphmine_tpu.ops import lpa
+    from graphmine_tpu.ops.bucketed_mode import row_slots, with_slot_index
+    from graphmine_tpu.ops.superstep_policy import delta_rungs
 
-    graph, plan = fused_plan
-    compiled = _compile(
-        _label_propagation, _shapes(graph, one_chip), max_iter=10,
-        plan=_shapes(with_slot_index(plan), one_chip),
-    )
-    assert " conditional(" in compiled.as_text()
+    _, plan = fused_plan
+    plan = _shapes(with_slot_index(plan), one_chip)
+    v, rows_bytes = planted[2], 4 * row_slots(plan)
+    rows = jax.ShapeDtypeStruct((row_slots(plan),), jnp.int32, sharding=one_chip)
+    labels = jax.ShapeDtypeStruct((v,), jnp.int32, sharding=one_chip)
+    if program == "gather":
+        compiled = _compile(lpa._gather_program, rows, labels, plan)
+    elif program == "rewrite":
+        changed = jax.ShapeDtypeStruct((v,), jnp.bool_, sharding=one_chip)
+        compiled = _compile(
+            lpa._rewrite_program, rows, labels, changed, plan,
+            cap=delta_rungs(plan.num_messages)[-1],
+        )
+    else:
+        compiled = _compile(lpa._modes_program, rows, labels, plan)
+    held = compiled.memory_analysis()
+    assert " conditional(" not in compiled.as_text()
+    if program == "modes":  # reads the rows, writes V-sized results
+        assert held.alias_size_in_bytes == 0
+        assert held.temp_size_in_bytes < rows_bytes
+    else:
+        assert held.alias_size_in_bytes >= rows_bytes
+        assert held.temp_size_in_bytes < rows_bytes // 4
 
 
 def test_masked_lpa_plan_mask_compiles_for_v5e(one_chip, fused_plan, planted):

@@ -1,9 +1,12 @@
-"""The carried-rows LPA scan (ISSUE 32): ``label_propagation`` over a fused
-plan keeps the gathered rows across supersteps and rewrites only the slots
-behind the senders whose label changed. Its labels are those of a host
-loop of ``lpa_superstep_bucketed`` and of the sort family after every
-superstep, its rows those of a full gather slot for slot, and its
-``superstep_delta`` record counts what a NumPy recount counts."""
+"""The carried-rows LPA job (ISSUE 32; stepped from the host since ISSUE
+36): ``label_propagation`` over a fused plan keeps the gathered rows across
+supersteps and rewrites only the slots behind the senders whose label
+changed. Its labels are those of a host loop of ``lpa_superstep_bucketed``
+and of the sort family after every superstep, its rows those of a full
+gather slot for slot at every superstep, and its ``superstep_delta`` record
+counts what a NumPy recount counts."""
+
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +24,7 @@ from graphmine_tpu.ops.bucketed_mode import (
     row_slots,
     with_slot_index,
 )
+from graphmine_tpu.ops import lpa
 from graphmine_tpu.ops.lpa import label_propagation, lpa_superstep
 from graphmine_tpu.ops.superstep_policy import delta_rungs
 from graphmine_tpu.pipeline.metrics import MetricsSink
@@ -97,20 +101,35 @@ def _out_degree(g):
     return np.bincount(np.asarray(g.msg_send), minlength=g.num_vertices)
 
 
+def _rows_held_to_a_full_gather():
+    """Every superstep's reduce is handed rows that equal a full gather of
+    the labels it starts from, slot for slot, whichever update made them."""
+    real = lpa._modes_program
+
+    def watched(rows, labels, plan):
+        want = gather_rows(jnp.zeros_like(rows), labels, plan)
+        np.testing.assert_array_equal(np.asarray(rows), np.asarray(want))
+        return real(rows, labels, plan)
+
+    return mock.patch.object(lpa, "_modes_program", watched)
+
+
 def _check(g, plan, steps, init_labels=None):
-    """The carried-rows scan against the host loops: labels after every
-    superstep (one scan per length), the history, and the record's counts
-    against a NumPy recount. Returns the ``branch`` list of the longest run."""
+    """The carried-rows job against the host loops: labels after every
+    superstep (one job per length) and the rows before every reduce, the
+    history, and the record's counts against a NumPy recount. Returns the
+    ``branch`` list of the longest run."""
     want = _host_loops(g, plan, init_labels, steps)
     out_deg = _out_degree(g)
     init = None if init_labels is None else jnp.asarray(init_labels, jnp.int32)
     record = None
     for k in range(1, steps + 1):
         sink = MetricsSink()
-        labels, history = label_propagation(
-            g, max_iter=k, plan=plan, init_labels=init, return_history=True,
-            sink=sink,
-        )
+        with _rows_held_to_a_full_gather():
+            labels, history = label_propagation(
+                g, max_iter=k, plan=plan, init_labels=init,
+                return_history=True, sink=sink,
+            )
         np.testing.assert_array_equal(np.asarray(labels), want[k])
         moved = [want[i + 1] != want[i] for i in range(k)]
         assert np.asarray(history).tolist() == [int(c.sum()) for c in moved]
@@ -205,6 +224,24 @@ def test_a_quiet_graph_overflows_every_rung_from_a_mid_superstep_on():
     rungs = delta_rungs(g.num_messages)
     assert branch[:4] == ["full", rungs[0], rungs[0], rungs[0]]
     assert branch[4:] == ["full"] * 4  # h2, the block and its sinks flip for good
+
+
+@pytest.mark.parametrize("max_iter", [1, 9, 10])
+def test_a_job_of_any_length_runs_the_programs_already_compiled(max_iter):
+    """``max_iter`` is the length of the host's loop and no program's
+    argument: a job of another length compiles nothing (on the chip a
+    program of the cells' size compiles for minutes)."""
+    g, plan, _, _ = _case("rmat_with_a_histogram_hub")
+    programs = (lpa._gather_program, lpa._rewrite_program, lpa._modes_program)
+    sink = MetricsSink()
+    want = label_propagation(g, max_iter=10, plan=plan, sink=sink)
+    (record,) = [r for r in sink.records if r["phase"] == "superstep_delta"]
+    assert len(set(record["branch"])) >= 3  # full and two rungs at least
+    compiled = [p._cache_size() for p in programs]
+    got = label_propagation(g, max_iter=max_iter, plan=plan)
+    assert [p._cache_size() for p in programs] == compiled
+    if max_iter == 10:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # -- the index alone ----------------------------------------------------------
@@ -321,11 +358,18 @@ def test_the_index_cache_lets_go_of_a_dropped_plan():
     assert len(lpa._slot_index_cache) == before
 
 
-def test_a_plan_under_a_trace_runs_without_an_index():
+@pytest.mark.parametrize("plan_is", ["an_argument", "closed_over"])
+def test_a_plan_under_a_trace_runs_without_an_index(plan_is):
+    """Under a caller's trace the host cannot step a job: the stateless
+    scan runs, whether the plan's arrays are tracers or concrete arrays the
+    traced function closes over (where K would be a tracer all the same)."""
     g, plan, _, _ = _case("path")
     want = np.asarray(label_propagation(g, max_iter=3, plan=plan))
-    traced = jax.jit(lambda p: label_propagation(g, max_iter=3, plan=p))
-    np.testing.assert_array_equal(np.asarray(traced(plan)), want)
+    if plan_is == "an_argument":
+        got = jax.jit(lambda p: label_propagation(g, max_iter=3, plan=p))(plan)
+    else:
+        got = jax.jit(lambda: label_propagation(g, max_iter=3, plan=plan))()
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 @pytest.mark.parametrize("fault", ["another_graph", "no_weight_payload"])

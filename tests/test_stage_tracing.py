@@ -195,17 +195,38 @@ def test_the_loop_around_a_superstep_names_its_own_bookkeeping():
     assert {"superstep/changed_count", "superstep/converged"} <= cc
 
 
-def test_the_carried_rows_scan_names_both_branches():
-    from graphmine_tpu.ops.bucketed_mode import BucketedModePlan, with_slot_index
-    from graphmine_tpu.ops.lpa import _label_propagation
+@pytest.mark.parametrize("program,want", [
+    ("gather", {"lpa_bucketed/row_gather"}),
+    ("rewrite", {"delta/compact", "delta/expand", "delta/scatter"}),
+    ("modes", {"lpa_bucketed/row_mode", "lpa_bucketed/write_back",
+               "superstep/changed_count"}),
+])
+def test_the_carried_rows_programs_name_their_scopes(program, want):
+    """Each program of the host-stepped job carries its own scopes and none
+    of another's: a capture books a superstep's update and its reduce apart."""
+    from graphmine_tpu.ops import lpa
+    from graphmine_tpu.ops.bucketed_mode import (
+        BucketedModePlan,
+        row_slots,
+        with_slot_index,
+    )
 
     g = _graph()
     plan = with_slot_index(BucketedModePlan.from_graph(g, with_send=True))
-    got = _scopes_in(_op_names(
-        lambda gg, p: _label_propagation(gg, 3, plan=p), g, plan))
-    assert {"lpa_bucketed/row_gather", "lpa_bucketed/row_mode",
-            "lpa_bucketed/write_back", "delta/compact", "delta/expand",
-            "delta/scatter", "superstep/changed_count"} <= got, sorted(got)
+    rows = jnp.zeros((row_slots(plan),), jnp.int32)
+    labels = jnp.arange(g.num_vertices, dtype=jnp.int32)
+    if program == "gather":
+        names = _op_names(lpa._gather_program, rows, labels, plan)
+    elif program == "rewrite":
+        names = _op_names(
+            lambda *a: lpa._rewrite_program(*a, cap=64), rows, labels,
+            labels > 90, plan)
+    else:
+        names = _op_names(lpa._modes_program, rows, labels, plan)
+    got = _scopes_in(names)
+    assert want <= got, sorted(got)
+    others = {"lpa_bucketed/row_gather", "delta/scatter", "lpa_bucketed/row_mode"} - want
+    assert not others & got, sorted(got)
 
 
 def test_ivf_search_and_merge_and_lof_carry_their_scopes():
