@@ -8,7 +8,8 @@ builds plan and index and compiles or loads the programs); then one job
 through the public entry with a sink, inside a capture. The capture does
 NOT put op metadata into the compile-cache key (``maybe_profile`` does, and
 every program would compile anew): run it in a call whose cache this
-checkout alone has filled. Prints JSON lines; the last holds the scopes."""
+checkout alone has filled. Prints JSON lines; the last holds the scopes,
+and the rows' passes by width class (``by_width``: ``row_mode/w33``)."""
 import json
 import os
 import sys
@@ -76,10 +77,18 @@ def main():
         by_scope[row["scope"]] = by_scope.get(row["scope"], 0.0) + row["device_seconds"]
         name = row["module"]
         by_program[name] = by_program.get(name, 0.0) + row["device_seconds"]
+    # the third scope level: the rows' passes by width class (`w<width>`)
+    passes = frozenset(("row_gather", "row_mode"))
+    by_width = {}
+    for row in devtrace.reduce_capture(
+            *planes, passes | {f"w{w}" for w in range(1, 1 << 15)})["scopes"]:
+        if row["scope"].split("/")[0] in passes:
+            by_width[row["scope"]] = by_width.get(row["scope"], 0.0) + row["device_seconds"]
     out = {"cell": sys.argv[1], "job_s": job_s, "busy_s": reduced["busy_seconds"],
            "idle_s": job_s - reduced["busy_seconds"],
            "by_scope": dict(sorted(by_scope.items(), key=lambda kv: -kv[1])),
            "by_program": dict(sorted(by_program.items(), key=lambda kv: -kv[1])),
+           "by_width": dict(sorted(by_width.items(), key=lambda kv: -kv[1])),
            "memory": device.memory_stats()}
     say(**out)
     if len(sys.argv) > 2:

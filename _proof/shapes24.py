@@ -1,15 +1,22 @@
-"""Step 0a (ISSUE 36): the class shapes of graphalytics-g500-24's fused plan,
-from a host-only build of the configuration's own draw. Writes
-_proof/g500_<scale>_shapes.json: [[n, w], ...] per class, hubs, hist_send
-length, V, M. No device array is made of the graph."""
+"""Step 0a (ISSUE 36, ISSUE 38): the class shapes of a configuration's fused
+plan, from a host-only build of the configuration's own draw.
+
+    python _proof/shapes24.py 24                             # graphalytics-g500-24 -> g500_24_shapes.json
+    python _proof/shapes24.py 24 gap-urand-24 urand          # -> urand_24_shapes.json
+
+Writes _proof/<prefix>_<scale>_shapes.json: [[n, w], ...] per class, hubs,
+hist_send length, V, M, the degree distribution's marks. No device array is
+made of the graph."""
 import json, os, sys, time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmark"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import numpy as np
 import generators
 scale = int(sys.argv[1]) if len(sys.argv) > 1 else 24
+config = sys.argv[2] if len(sys.argv) > 2 else "graphalytics-g500-24"
+prefix = sys.argv[3] if len(sys.argv) > 3 else "g500"
 cfg = json.load(open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs",
-                                  "graphalytics-g500-24.json")))
+                                  config + ".json")))
 args = dict(cfg["generator_args"], scale=scale)
 t0 = time.time()
 u, v = generators.make(cfg["generator"], args, cfg["dataset_seed"])
@@ -38,5 +45,12 @@ out = {
     "weighted": plan.weight_mat is not None,
 }
 out["slots"] = sum(n * w for n, w in out["classes"])
-json.dump(out, open(os.path.join(os.path.dirname(__file__), f"g500_{scale}_shapes.json"), "w"))
+deg = np.diff(np.asarray(g.msg_ptr).astype(np.int64))
+touched = deg > 0
+out["edges"] = int(len(u))
+out["vertices_with_edge"] = int(touched.sum())
+out["degree"] = {"mean": float(deg.mean()), "max": int(deg.max()),
+                 "p01": int(np.percentile(deg, 1)), "p50": int(np.percentile(deg, 50)),
+                 "p99": int(np.percentile(deg, 99))}
+json.dump(out, open(os.path.join(os.path.dirname(__file__), f"{prefix}_{scale}_shapes.json"), "w"))
 print(json.dumps(out))

@@ -4,6 +4,7 @@ program a process so that the peak RSS is that program's compile alone.
 
     python _proof/size_programs.py _proof/g500_24_shapes.json            # all, one child each
     python _proof/size_programs.py _proof/g500_24_shapes.json modes      # one, in this process
+    python _proof/size_programs.py _proof/urand_24_shapes.json gather out.txt  # and its compiled text
 
 Prints one JSON line a program: temp / alias / argument / output / code
 bytes of memory_analysis(), the count of copy-done in the compiled text,
@@ -35,7 +36,7 @@ def plan_of_shapes(said, sharding):
     )
 
 
-def one(said, name):
+def one(said, name, text_out=None):
     import jax, jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
@@ -64,6 +65,9 @@ def one(said, name):
     secs = time.time() - t0
     ma = compiled.memory_analysis()
     text = compiled.as_text()
+    if text_out:
+        with open(text_out, "w") as f:
+            f.write(text)
     print(json.dumps({
         "program": name, "rows_bytes": 4 * s,
         "temp": ma.temp_size_in_bytes, "alias": ma.alias_size_in_bytes,
@@ -78,7 +82,7 @@ def one(said, name):
 if __name__ == "__main__":
     said = json.load(open(sys.argv[1]))
     if len(sys.argv) > 2:
-        one(said, sys.argv[2])
+        one(said, *sys.argv[2:4])
     else:
         for name in ["gather", "rewrite:0", "rewrite:1", "rewrite:2", "rewrite:3", "modes"]:
             subprocess.run([sys.executable, os.path.abspath(__file__), sys.argv[1], name])

@@ -329,23 +329,87 @@ def _slots(mat) -> int:
     return int(mat.shape[0]) * int(mat.shape[1])
 
 
-# Words (int32) a program of the carried-rows job holds per element while
-# it runs, as the chip's compiler assigned them (`memory_analysis()` of each
-# program compiled alone for a described v5e, from shapes: PERF.md §6, PR
-# 36, graph500-24's and graph500-22's plans and two planted graphs). The
-# rows themselves are in none of these: they are each updating program's
-# donated argument, aliased to its result, and held once.
-#   * a class's rows, gathered or sorted: the `[n, w]` result in the tiled
-#     layout and its flat copy (the gather), the sort's input and output
-#     (the modes): twice the widest class, the classes taking turns in the
-#     same two buffers;
+# What a program of the carried-rows job holds beside its arguments while it
+# runs, as the chip's compiler assigned it (`memory_analysis()` of each
+# program compiled alone for a described v5e, from shapes: PERF.md §6, PR 36
+# and PR 38: graph500-24's and graph500-22's plans, GAP Urand's at scale 24,
+# two planted graphs). The rows themselves are in none of it: they are each
+# updating program's donated argument, aliased to its result, held once.
+#
+# The classes take turns (the compiler schedules one class's work after
+# another's, on a flat plan as on a skewed one), so a program's temporaries
+# are its largest class's. What a class takes is set by how the chip lays an
+# int32 `[n, w]` out, in tiles of 8 x 128: column-major (`w` up to 8, `n` up
+# to 128: what the compiler picks for a narrow class, the plan's matrices
+# among them) or row-major (`n` up to 8, `w` up to 128 LANES: 3.9 times the
+# matrix at w = 33). The flat rows are row-major, so a narrow class passes
+# through the lane-padded form on its way in (the gather) and out (the
+# modes): that, not the class count, is why a plan of 28 like-sized narrow
+# classes took 1.10 x its rows where graph500-24's took 0.46 x.
+#   * the gather: the class's indices brought in range, as the plan holds
+#     them, and the row-major form they are flattened from;
+#   * the modes: the same two forms on the way out, then the reduce: the
+#     pairwise count's matrix in and out; the row sort's key and the iota
+#     the compiler adds to keep it stable, in and out (four; three where
+#     the rows' slice is the matrix the sort reads: row-major, whole lanes);
+#   * both: the labels, padded, in two memory spaces at once;
 #   * the rewrite's compaction: a four-operand sort of V keys, in and out;
 #   * the rewrite's expansion: five cap-long vectors (the scattered
 #     differences and their running sums for source and value, the slot),
 #     after the sort's operands are dead.
-_CLASS_ROWS_COPIES = 2
+_TILE = (8, 128)
+_PAIRWISE_MAX_W = 32  # ops/bucketed_mode.py's (this module imports no jax): wider classes are sorted
 _REWRITE_SORT_WORDS = 8
 _REWRITE_CAP_WORDS = 5
+
+
+def _tiled(n: int, w: int) -> tuple[int, int]:
+    """Bytes of an int32 ``[n, w]`` on the chip, ``(as the compiler lays
+    it out, row-major)``: the smaller of the two tiled layouts, and the
+    one the flat rows reshape to. A single column is a vector."""
+    up = lambda x, tile: -(-x // tile) * tile
+    if w == 1:
+        return (_I32 * up(n, 1024),) * 2
+    col = _I32 * up(w, _TILE[0]) * up(n, _TILE[1])
+    row = _I32 * up(n, _TILE[0]) * up(w, _TILE[1])
+    return min(col, row), row
+
+
+def carried_job_transients(plan, top_rung: int = 0) -> dict:
+    """Bytes of temporaries by program of the carried-rows job, from the
+    plan's shapes (the note above): ``gather``, ``modes`` and ``rewrite``
+    at ``top_rung`` messages (0 without a rung). The hubs' histograms are
+    the inventory's own term. A weighted plan's reduce holds more (the
+    weights ride through the sort) and no compile holds its count: at
+    2^16 vertices the compiler kept one class's ``[n, w, w]`` pairwise
+    products whole, in fast memory, which it cannot at a size that
+    matters."""
+    v = int(plan.num_vertices)
+    weighted = _plan_weighted(plan)
+    gather = modes = 0
+    for idx in plan.send_idx or ():
+        n, w = int(idx.shape[0]), int(idx.shape[1])
+        kept, row = _tiled(n, w)
+        gather = max(gather, kept + row)
+        sort_in_place = kept == row and w % _TILE[1] == 0
+        if weighted:
+            # labels, weights, scores and mask; the sort's key, weight and
+            # iota, in and out, and the segmented sum's pair
+            reduce = (4 if w <= _PAIRWISE_MAX_W else 8) * kept
+        elif w <= _PAIRWISE_MAX_W:
+            reduce = 2 * kept
+        else:
+            reduce = (3 if sort_in_place else 4) * kept
+        modes = max(modes, kept + row, reduce)
+    labels = 2 * _I32 * (v + 1)
+    return {
+        "gather": gather + labels,
+        "modes": modes + labels,
+        "rewrite": max(
+            _REWRITE_SORT_WORDS * _I32 * v,
+            _REWRITE_CAP_WORDS * _I32 * int(top_rung),
+        ) if top_rung else 0,
+    }
 
 
 def carried_rows_inventory(plan, top_rung: int = 0) -> dict:
@@ -355,23 +419,26 @@ def carried_rows_inventory(plan, top_rung: int = 0) -> dict:
     (every program that updates them does so in place; held by
     ``tests/test_chip_compile.py``); ``slot_index``, one slot per message
     and ``V + 1`` offsets by sender; ``labels`` in and out and the
-    ``changed_mask``. As the chip's compiler counts them:
+    ``changed_mask``. Counted as the chip's compiler holds them, from the
+    shapes and the chip's tiling and from no per-graph constant
+    (:func:`carried_job_transients`; held to the compiler's own count on a
+    skewed and on a flat plan by ``tests/test_chip_compile.py``):
     ``hub_histograms``, the hubs' ``[n, V]`` counts and the scatter's copy
     of them, and ``gather_transient``, the other temporaries of the job's
-    largest program: the full gather (the widest class twice and the
-    padded labels), the row modes (the widest class twice) or the rewrite
-    at ``top_rung`` messages, the job's largest rung (its sort of V keys,
-    then its cap-long vectors). The sum holds the histograms beside the
-    largest program's temporaries though the modes program alone holds
-    both: an over-count where the rewrite is the largest. Program code is
-    device memory too and is in no term (0.75 GB for graph500-24's
-    programs). The admission of
+    largest program: the full gather, the row modes, or the rewrite at
+    ``top_rung`` messages, the job's largest rung (its sort of V keys,
+    then its cap-long vectors). On a plan of narrow classes the gather
+    and the modes are the largest whatever the rung (GAP Urand at scale
+    24: 2.49 and 2.38 GB against the top rung's 1.79). The sum holds the
+    histograms beside the largest program's temporaries though the modes
+    program holds them after its rows: an over-count on a graph with
+    hubs. Program code is device memory too and is in no term (0.75 GB
+    for graph500-24's programs, 0.2 GB for Urand's). The admission of
     ``ops/superstep_policy.admit_carried_rows`` holds the sum against the
     device's free memory."""
     v = int(plan.num_vertices)
     classes = [_slots(x) for x in plan.send_idx or ()]
     hubs = 0 if plan.hist_vertex_ids is None else int(plan.hist_vertex_ids.shape[0])
-    widest = _CLASS_ROWS_COPIES * _I32 * max(classes, default=0)
     return {
         "carried_rows": _I32 * sum(classes),
         "slot_index": _I32 * (int(plan.num_messages) + v + 1),
@@ -379,9 +446,7 @@ def carried_rows_inventory(plan, top_rung: int = 0) -> dict:
         "changed_mask": v,
         "hub_histograms": 2 * _I32 * hubs * v,
         "gather_transient": max(
-            widest + _I32 * (v + 1),
-            _REWRITE_SORT_WORDS * _I32 * v if top_rung else 0,
-            _REWRITE_CAP_WORDS * _I32 * int(top_rung),
+            carried_job_transients(plan, top_rung).values()
         ),
     }
 

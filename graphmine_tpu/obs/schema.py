@@ -87,6 +87,12 @@ register("impl_selected", "op", "impl", "n", "reason")
 # build): host build seconds, family, width classes, padded gather slots per
 # edge. Host plan cost grows with the tighter ladders; this record keeps
 # it visible in obs_report instead of hiding inside first-call latency.
+# A one-device plan's record (ops/superstep_policy.plan_build_stats; the
+# mesh entry's has none of these) also says how the plan's rows reduce:
+# `padded_slots_per_message`, `rows_pairwise` (vertices in classes up to
+# the pairwise width: copy, min, pairwise count), `rows_sorted` (wider:
+# the row sort), `rows_hist` (hubs), `max_width`. Benchmark metric
+# `plan_slots_per_message` reads the first.
 register("plan_build", "op", "family", "seconds", "padded_slots_per_edge")
 # superstep_timing (ISSUE 12): achieved-vs-model throughput for one
 # window of supersteps, emitted at the existing tripwire/telemetry
@@ -123,8 +129,13 @@ register("fixpoint", "op", "supersteps", "changed", "num_vertices", "family")
 # device (`device_residency` says `scan: plain`) runs the stateless scan,
 # which keeps no rows and counts no K (its int32[max_iter] of labels moved
 # comes back with the labels): every `branch` is "full",
-# `changed_messages` and `rungs` are empty. Benchmark metric
-# `cdlp_sparse_superstep_share` reads `branch`.
+# `changed_messages` and `rungs` are empty. `seconds`: the host's clock
+# from one superstep's fetch of K to the next (the first from the job's
+# start), one a superstep, read where the host waits for K anyway: no
+# sync of its own; empty for the stateless scan, one program that the
+# host does not step. Benchmark metrics `cdlp_sparse_superstep_share`
+# (`branch`) and `full_superstep_ms` (`seconds` where `branch` is "full")
+# read it.
 register("superstep_delta", "op", "changed_vertices", "changed_messages",
          "branch", "rungs", "num_messages")
 

@@ -21,6 +21,7 @@ room for, the supersteps step from the host and keep their gathered rows
 
 from __future__ import annotations
 
+import time
 from functools import partial
 
 import jax
@@ -106,8 +107,9 @@ def label_propagation(
     ``device_residency`` record (the bytes the device holds for this
     graph's supersteps, by array group, beside its limit); a job over
     a fused plan's rows one ``superstep_delta`` record (per superstep: the
-    branch taken, the labels moved, the messages their vertices send; all
-    ``full`` where the rows were not admitted).
+    branch taken, the labels moved, the messages their vertices send, and
+    its seconds on the host's clock, read where the host fetches the
+    count; all ``full`` and no seconds where the rows were not admitted).
 
     On one device the graph may be host-resident too
     (``build_graph(..., to_device=False)``): the fused plan is built from
@@ -209,8 +211,9 @@ def label_propagation(
             timed_fixpoint,
         )
 
+        timed = {"clock": time.perf_counter} if carried else {}
         (labels, per_step), secs, cold = timed_fixpoint(
-            lambda: job(graph, max_iter, init_labels, plan),
+            lambda: job(graph, max_iter, init_labels, plan, **timed),
         )
         cost = superstep_cost(
             "lpa_superstep",
@@ -254,6 +257,7 @@ def _emit_superstep_delta(sink, per_step: dict, num_messages: int) -> None:
         "superstep_delta", op="lpa_superstep", changed_vertices=changed,
         changed_messages=messages, branch=branch, rungs=rungs,
         num_messages=num_messages,
+        seconds=[round(s, 6) for s in per_step.get("seconds", ())],
     )
 
 
@@ -405,7 +409,6 @@ def _cached_mesh_partition(graph: Graph, mesh, family: str):
     of the graph's ``msg_ptr`` (host or device array); a weakref finalizer
     evicts the entry with it. Only the placed shards are kept — the host
     copies go as soon as they are on the devices."""
-    import time
     import weakref
 
     import numpy as np
@@ -545,7 +548,9 @@ def _modes_program(rows, labels, plan):
     return new, changed, k, count
 
 
-def _carried_rows_job(graph: Graph, max_iter: int, init_labels, plan):
+def _carried_rows_job(
+    graph: Graph, max_iter: int, init_labels, plan, clock=None
+):
     """``(labels, per_step)`` of ``max_iter`` supersteps over a fused plan
     with its slot index, stepped from the host: the gathered rows live in
     one buffer across supersteps, and a superstep reads again only what
@@ -564,7 +569,10 @@ def _carried_rows_job(graph: Graph, max_iter: int, init_labels, plan):
     for K; ``max_iter`` is the length of this loop and no program's
     argument. ``per_step`` holds ``changed_vertices``,
     ``changed_messages`` and ``branch`` (the rung's place, or
-    ``len(rungs)`` for a full gather), one a superstep."""
+    ``len(rungs)`` for a full gather), one a superstep; with a ``clock``
+    (the caller's, where a sink wants them) also ``seconds``, the clock's
+    reading after each fetch of K less the reading before it: a
+    superstep's seconds on the host's clock, at the wait the job has."""
     from graphmine_tpu.ops.bucketed_mode import check_plan_fits, row_slots
     from graphmine_tpu.ops.superstep_policy import delta_rungs
 
@@ -578,6 +586,7 @@ def _carried_rows_job(graph: Graph, max_iter: int, init_labels, plan):
     rows = _blank_rows(row_slots(plan))
     changed, k = None, plan.num_messages + 1
     count, sent, branch = [], [], []
+    marks = [clock()] if clock else []
     for _ in range(max_iter):
         branch.append(sum(k > rung for rung in rungs))
         if branch[-1] == len(rungs):
@@ -590,9 +599,14 @@ def _carried_rows_job(graph: Graph, max_iter: int, init_labels, plan):
         k, moved = (int(x) for x in jax.device_get((k, moved)))  # the one wait
         sent.append(k)
         count.append(moved)
-    return labels, {
+        if clock:
+            marks.append(clock())
+    per_step = {
         "changed_vertices": count, "changed_messages": sent, "branch": branch,
     }
+    if clock:
+        per_step["seconds"] = [b - a for a, b in zip(marks, marks[1:])]
+    return labels, per_step
 
 
 def num_communities(labels: jax.Array) -> jax.Array:

@@ -25,6 +25,7 @@ import time
 from graphmine_tpu.obs.costmodel import _bucketed_padded_slots, superstep_cost
 from graphmine_tpu.obs.memmodel import (
     FAMILY_DEGRADE,
+    carried_job_transients,
     carried_rows_inventory,
     superstep_footprint,
 )
@@ -96,8 +97,9 @@ def admit_carried_rows(plan, stats: dict | None) -> tuple[str, str]:
     before the index is built, from the plan's shapes
     (:func:`~graphmine_tpu.obs.memmodel.carried_rows_inventory`: the rows,
     held once, the index, the labels, the hubs' histograms and the
-    temporaries of the job's largest program, the rewrite at the top rung
-    of :func:`delta_rungs` among them) against ``stats``
+    temporaries of the job's largest program as the chip tiles them: the
+    full gather, the row modes or the rewrite at the top rung of
+    :func:`delta_rungs`; the ``reason`` names which) against ``stats``
     (:func:`device_memory_stats`): ``bytes_limit`` less ``bytes_in_use``,
     the graph and the plan being in use already. ``plain`` is the
     stateless bucketed scan, the same labels bit for bit at a full gather
@@ -109,9 +111,10 @@ def admit_carried_rows(plan, stats: dict | None) -> tuple[str, str]:
     the stateless program's 20 GB (PERF.md §6, PR 36); a job admitted here
     whose compile does not fit the host still ends there, minutes later.
     Every ``reason`` says so."""
-    need = carried_rows_inventory(
-        plan, top_rung=max(delta_rungs(int(plan.num_messages)), default=0)
-    )
+    top_rung = max(delta_rungs(int(plan.num_messages)), default=0)
+    need = carried_rows_inventory(plan, top_rung=top_rung)
+    by_program = carried_job_transients(plan, top_rung=top_rung)
+    largest = max(by_program, key=by_program.get)
     slots = need["carried_rows"] // 4
     if slots == 0 or slots >= _INT32_MAX:
         return "plain", (
@@ -124,7 +127,9 @@ def admit_carried_rows(plan, stats: dict | None) -> tuple[str, str]:
         f"{need['slot_index']} B + labels and changed mask "
         f"{need['labels'] + need['changed_mask']} B + hub histograms "
         f"{need['hub_histograms']} B + the largest program's other "
-        f"temporaries {need['gather_transient']} B = {total} B"
+        f"temporaries ({largest}; " + ", ".join(
+            f"{name} {held} B" for name, held in by_program.items()
+        ) + f") {need['gather_transient']} B = {total} B"
     )
     unsized = " (device memory alone: the host's compile memory is not sized)"
     limit = (stats or {}).get("bytes_limit")
@@ -191,14 +196,32 @@ def select_superstep_family(
 def plan_build_stats(plan, num_edges: int) -> dict:
     """The ``plan_build`` record payload (see ``obs/schema.py``): width
     classes and the padded gather slots per edge, the number the
-    width-ladder work optimizes. ``bins`` is always 0 (the record's key
-    from when a binned family existed; its readers select on it)."""
+    width-ladder work optimizes, and the plan's shape by how its rows
+    reduce: ``padded_slots_per_message`` (1.17 on a Kronecker draw, 1.03
+    on a uniform one), the vertices whose row is reduced by the copy, the
+    min or the pairwise count (``rows_pairwise``: classes up to
+    ``_PAIRWISE_MAX_W`` wide), by the row sort (``rows_sorted``) and by a
+    histogram (``rows_hist``), and the widest class (``max_width``).
+    ``bins`` is always 0 (the record's key from when a binned family
+    existed; its readers select on it)."""
+    from graphmine_tpu.ops.bucketed_mode import _PAIRWISE_MAX_W
+
     slots = _bucketed_padded_slots(plan)
+    mats = plan.send_idx if plan.send_idx is not None else plan.msg_idx
+    shapes = [(int(m.shape[0]), int(m.shape[1])) for m in mats or ()]
+    hubs = plan.hist_vertex_ids
     return {
         "family": "bucketed",
         "bins": 0,
         "width_classes": len(plan.vertex_ids),
         "padded_slots_per_edge": round(slots / max(int(num_edges), 1), 3),
+        "padded_slots_per_message": round(
+            slots / max(int(plan.num_messages), 1), 4
+        ),
+        "rows_pairwise": sum(n for n, w in shapes if w <= _PAIRWISE_MAX_W),
+        "rows_sorted": sum(n for n, w in shapes if w > _PAIRWISE_MAX_W),
+        "rows_hist": 0 if hubs is None else int(hubs.shape[0]),
+        "max_width": max((w for _, w in shapes), default=0),
     }
 
 

@@ -52,11 +52,16 @@ def test_the_footprint_of_an_indexed_plan_is_its_arrays_to_the_byte(name):
     assert inv["slot_index"] == indexed.out_ptr.nbytes + indexed.out_slot.nbytes
     assert inv["carried_rows"] == 4 * row_slots(plan)  # once: updated in place
     assert inv["labels"] == 8 * g.num_vertices and inv["changed_mask"] == g.num_vertices
-    # as the chip's compiler counts them: the widest class's rows twice and
-    # the padded labels (the full gather, the largest program where no rung
-    # is named), the hubs' [n, V] histograms and the scatter's copy of them
-    widest = max(n * w for n, w in (idx.shape for idx in plan.send_idx))
-    assert inv["gather_transient"] == 8 * widest + 4 * (g.num_vertices + 1)
+    # as the chip holds them: the largest program's temporaries (without a
+    # rung the full gather or the row modes), its largest class in the
+    # chip's tiles and the padded labels twice; the hubs' [n, V] histograms
+    # and the scatter's copy of them
+    by_program = memmodel.carried_job_transients(plan)
+    assert by_program["rewrite"] == 0
+    assert inv["gather_transient"] == max(by_program.values())
+    assert by_program["gather"] == 8 * (g.num_vertices + 1) + max(
+        sum(memmodel._tiled(*idx.shape)) for idx in plan.send_idx)
+    assert by_program["modes"] >= by_program["gather"]
     hubs = 0 if plan.hist_vertex_ids is None else plan.hist_vertex_ids.shape[0]
     assert inv["hub_histograms"] == 8 * hubs * g.num_vertices
     assert (hubs > 0) == (name in ("rmat_with_a_histogram_hub", "star"))
@@ -131,17 +136,104 @@ def test_the_inventory_of_graph500_24_s_plan_term_by_term():
         # the rewrite at M / 6: five cap-long vectors (compiled: 1,736,506,880)
         "gather_transient": 20 * top,
     }
-    assert 20 * top > 8 * widest + 4 * (v + 1) > 32 * v
-    # without a rung the largest program is the full gather (compiled:
-    # 1,115,328,512; the row modes 1,580,267,520 with the histograms)
+    # without a rung the largest program is the row modes: the widest
+    # class's sort, key and stability iota in and out, the key read where
+    # it lies in the rows (three of the class), and the labels twice
+    # (compiled: 1,580,267,520, the histograms' 536,870,912 after the rows
+    # and not beside them); the full gather holds the class twice
+    # (compiled: 1,115,328,512)
+    assert memmodel.carried_job_transients(plan, top_rung=top) == {
+        "gather": 8 * widest + 8 * (v + 1), "modes": 12 * widest + 8 * (v + 1),
+        "rewrite": 20 * top}
+    assert 20 * top > 12 * widest + 8 * (v + 1) > 8 * widest + 8 * (v + 1) > 32 * v
     no_rung = memmodel.carried_rows_inventory(plan)
-    assert no_rung["gather_transient"] == 8 * widest + 4 * (v + 1) == 1_111_687_172
+    assert no_rung["gather_transient"] == 12 * widest + 8 * (v + 1) == 1_701_085_192
     # a lower rung's rewrite is its sort of V keys, in and out (compiled:
     # 537,257,984 at M / 4096)
     low = memmodel.carried_rows_inventory(plan, top_rung=delta_rungs(m)[0])
     assert low["gather_transient"] == no_rung["gather_transient"]
     assert 32 * v == 536_870_912 < no_rung["gather_transient"]
     assert sum(inv.values()) == 7_004_205_348
+
+
+# GAP Urand at scale 24 (benchmark/configs/gap-urand-24.json): V = 2^24,
+# M = 536,870,374, S = 551,072,031 padded slots in 28 narrow classes, no hub
+# (_proof/urand_24_shapes.json, a host-only build of the configuration's draw)
+_URAND_24_CLASSES = [
+    (2, 7), (5, 8), (21, 9), (77, 10), (168, 11), (495, 12), (1305, 13),
+    (2913, 14), (6085, 15), (12189, 16), (23232, 17), (40998, 18), (69383, 19),
+    (111343, 20), (413837, 22), (796345, 24), (1299908, 26), (1821602, 28),
+    (2214635, 30), (3504377, 33), (2936114, 36), (1917093, 39), (994879, 42),
+    (482345, 46), (108040, 50), (18555, 55), (1229, 60), (41, 66),
+]
+
+
+def _urand_24_plan():
+    i32 = lambda *dims: jax.ShapeDtypeStruct(dims, np.int32)
+    return bucketed_mode.BucketedModePlan(
+        vertex_ids=tuple(i32(n) for n, _ in _URAND_24_CLASSES), msg_idx=None,
+        num_vertices=1 << 24, num_messages=536_870_374,
+        send_idx=tuple(i32(n, w) for n, w in _URAND_24_CLASSES),
+    )
+
+
+def test_the_inventory_of_gap_urand_24_s_plan_term_by_term():
+    """A flat plan's largest programs are the gather and the modes whatever
+    the rung: the chip keeps a narrow class column-major in tiles of 8 x 128
+    (3,504,377 x 33 as 3,504,384 x 40) and the flat rows row-major, so the
+    class passes through 3,504,384 rows of 128 LANES, 3.9 times its size, on
+    its way into the rows and out of them. Beside what the chip's compiler
+    assigned the programs compiled alone for a v5e (PERF.md §6, PR 38)."""
+    plan = _urand_24_plan()
+    v, m, s = plan.num_vertices, plan.num_messages, 551_072_031
+    top = delta_rungs(m)[-1]
+    assert row_slots(plan) == s and top == m // 6 == 89_478_395
+    kept, lanes = 4 * 40 * 3_504_384, 4 * 3_504_384 * 128
+    assert memmodel._tiled(3_504_377, 33) == (kept, lanes) == (560_701_440, 1_794_244_608)
+    assert memmodel.carried_job_transients(plan, top_rung=top) == {
+        "gather": kept + lanes + 8 * (v + 1),  # compiled: 2,423,228,928
+        # in and out through the lanes; the sort's four, 4 x kept, are less
+        "modes": kept + lanes + 8 * (v + 1),   # compiled: 2,359,736,320
+        "rewrite": 20 * top,                   # compiled: 1,790,246,400
+    }
+    assert kept + lanes > 4 * kept and 20 * top == 1_789_567_900
+    inv = memmodel.carried_rows_inventory(plan, top_rung=top)
+    assert inv == {
+        "carried_rows": 4 * s,            # 2.20 GB, once
+        "slot_index": 4 * (m + v + 1),    # 2.21 GB
+        "labels": 8 * v, "changed_mask": v, "hub_histograms": 0,
+        "gather_transient": 2_489_163_784,
+    }
+    # the term does not lean on the top rung: it is the same without one
+    assert memmodel.carried_rows_inventory(plan) == inv
+    assert sum(inv.values()) == 7_059_037_216
+    # the chip tiles this plan to 2.47 GB where its nbytes say 2.20: beside
+    # the 6.51 GB graph 7.86 GB are free, not graph500-24's 8.06
+    scan, reason = admit_carried_rows(
+        plan, {"bytes_limit": _G500_24_LIMIT, "bytes_in_use": 9_051_000_000})
+    assert scan == "carried" and "7059037216 B against 7858336064 B free" in reason
+    assert "(gather; gather 2489163784 B, modes 2489163784 B, rewrite 1789567900 B)" in reason
+    scan, reason = admit_carried_rows(
+        plan, {"bytes_limit": _G500_24_LIMIT, "bytes_in_use": _G500_24_LIMIT - 7_059_037_215})
+    assert scan == "plain" and "a full gather every superstep" in reason
+
+
+def test_the_crossover_the_transients_count_by_is_the_plan_builder_s():
+    """``obs/memmodel.py`` imports no jax and so keeps its own copy of the
+    width above which a class is reduced by the row sort."""
+    assert memmodel._PAIRWISE_MAX_W == bucketed_mode._PAIRWISE_MAX_W
+
+
+@pytest.mark.parametrize("n,w,want", [
+    (1000, 1, (4096, 4096)),              # a single column is a vector
+    (1000, 2, (4 * 8 * 1024, 4 * 1000 * 128)),
+    (1000, 33, (4 * 40 * 1024, 4 * 1000 * 128)),
+    (1001, 128, (4 * 1008 * 128,) * 2),   # whole lanes: row-major is the smaller
+    (42504, 3072, (4 * 42504 * 3072,) * 2),
+    (41, 66, (4 * 48 * 128,) * 2),
+], ids=lambda x: str(x))
+def test_a_class_s_bytes_on_the_chip_follow_its_tiles(n, w, want):
+    assert memmodel._tiled(n, w) == want
 
 
 @pytest.mark.parametrize("in_use,want", [

@@ -178,22 +178,50 @@ def test_lpa_superstep_bucketed_compiles_for_v5e(one_chip, fused_plan, planted):
     )
 
 
+@pytest.fixture(scope="module")
+def flat_plan():
+    """GAP Urand's plan at scale 16 (the benchmark's own generator at
+    a = b = c = 0.25, as ``gap-urand-24``): two dozen narrow classes of
+    like size, no hub."""
+    import sys
+
+    from graphmine_tpu.ops.bucketed_mode import BucketedModePlan
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark"))
+    import generators
+
+    u, v = generators.rmat_undirected(16, 16, 0.25, 0.25, 0.25, seed=2147483659)
+    plan = BucketedModePlan.from_edges(u, v, 1 << 16)
+    assert plan.hist_vertex_ids is None and len(plan.send_idx) > 20
+    return plan
+
+
 @pytest.mark.parametrize("program", ["gather", "rewrite", "modes"])
-def test_carried_rows_programs_compile_for_v5e(one_chip, fused_plan, planted, program):
+@pytest.mark.parametrize("graph", ["kronecker", "flat"])
+def test_carried_rows_programs_compile_for_v5e(
+    one_chip, fused_plan, flat_plan, planted, program, graph
+):
     """The one-chip CDLP job's three kinds of program (ISSUE 36), each
     compiled alone: the classes' full gathers and the top rung's rewrite
     through the slot index update the rows IN PLACE, because the rows are
     their donated argument: the compiler aliases the whole ``s32[S]``
     buffer to the result and keeps no temporary of its size. The admission
     (``obs/memmodel.carried_rows_inventory``) counts the rows once on the
-    strength of this."""
+    strength of this, and each program's other temporaries from the plan's
+    shapes and the chip's tiles (ISSUE 38): at or above what the compiler
+    assigns, on a skewed plan with hubs and on a flat one of narrow
+    classes, where a class passes through a 128-lane form of 3.9 times
+    its size."""
+    from graphmine_tpu.obs.memmodel import carried_job_transients
     from graphmine_tpu.ops import lpa
     from graphmine_tpu.ops.bucketed_mode import row_slots, with_slot_index
     from graphmine_tpu.ops.superstep_policy import delta_rungs
 
-    _, plan = fused_plan
+    plan = fused_plan[1] if graph == "kronecker" else flat_plan
     plan = _shapes(with_slot_index(plan), one_chip)
     v, rows_bytes = planted[2], 4 * row_slots(plan)
+    top_rung = delta_rungs(plan.num_messages)[-1]
     rows = jax.ShapeDtypeStruct((row_slots(plan),), jnp.int32, sharding=one_chip)
     labels = jax.ShapeDtypeStruct((v,), jnp.int32, sharding=one_chip)
     if program == "gather":
@@ -201,19 +229,23 @@ def test_carried_rows_programs_compile_for_v5e(one_chip, fused_plan, planted, pr
     elif program == "rewrite":
         changed = jax.ShapeDtypeStruct((v,), jnp.bool_, sharding=one_chip)
         compiled = _compile(
-            lpa._rewrite_program, rows, labels, changed, plan,
-            cap=delta_rungs(plan.num_messages)[-1],
+            lpa._rewrite_program, rows, labels, changed, plan, cap=top_rung,
         )
     else:
         compiled = _compile(lpa._modes_program, rows, labels, plan)
     held = compiled.memory_analysis()
     assert " conditional(" not in compiled.as_text()
+    counted = carried_job_transients(plan, top_rung=top_rung)[program]
     if program == "modes":  # reads the rows, writes V-sized results
         assert held.alias_size_in_bytes == 0
-        assert held.temp_size_in_bytes < rows_bytes
+        hubs = 0 if plan.hist_vertex_ids is None else plan.hist_vertex_ids.shape[0]
+        assert held.temp_size_in_bytes <= max(counted, 8 * hubs * v + 8 * v)
     else:
         assert held.alias_size_in_bytes >= rows_bytes
-        assert held.temp_size_in_bytes < rows_bytes // 4
+        assert held.temp_size_in_bytes <= counted
+    if graph == "kronecker":  # wide classes: well under the rows
+        assert held.temp_size_in_bytes < (rows_bytes if program == "modes"
+                                          else rows_bytes // 4)
 
 
 def test_masked_lpa_plan_mask_compiles_for_v5e(one_chip, fused_plan, planted):
