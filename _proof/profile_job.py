@@ -2,6 +2,7 @@
 reduced by scope and by program (ISSUE 36, PERF.md §5).
 
     python _proof/profile_job.py cdlp-g500-24 [out.json]
+    python _proof/profile_job.py cdlp-g500-25-x4 [out.json]   # a mesh cell: on its four chips
 
 Set-up is the cell's own driver's (draw, build_graph, the warm-up job that
 builds plan and index and compiles or loads the programs); then one job
@@ -48,26 +49,31 @@ def main():
     ctx = {"config": cell["config"], "traffic": cell["traffic"],
            "sizes": cell["config"]["rehearsal"] if os.environ.get("REHEARSE") else cell["config"],
            "seed": 1, "scratch": scratch,
-           "chips": 1, "say": say}
+           "chips": cell["chips"], "say": say}
     state = driver.setup(ctx)
     graph, iters = state["graph"], cell["traffic"]["iterations"]
     device = jax.devices()[0]
+    # a mesh cell's driver keeps its mesh: the job goes through the same entry
+    on_mesh = {"mesh": state["mesh"]} if "mesh" in state else {}
+    memory = lambda: [d.memory_stats() for d in state.get("devices", [device])]
 
     # an untraced job first, with a sink: the record and the job's seconds
     sink = MetricsSink()
     t0 = time.perf_counter()
-    gm.label_propagation(graph, max_iter=iters, plan="auto", sink=sink).block_until_ready()
+    gm.label_propagation(graph, max_iter=iters, plan="auto", sink=sink, **on_mesh).block_until_ready()
     say(plain_job_s=time.perf_counter() - t0,
-        superstep_delta=[{k: v for k, v in r.items() if k not in ("phase", "t")}
-                         for r in sink.records if r["phase"] == "superstep_delta"],
-        memory=device.memory_stats())
+        records=[{k: v for k, v in r.items() if k not in ("t", "cost", "thresholds")}
+                 for r in sink.records
+                 if r["phase"] in ("superstep_delta", "impl_selected", "device_residency",
+                                   "plan_build", "partition")],
+        memory=memory())
 
     trace_dir = os.path.join(scratch, "trace")
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     jax.profiler.start_trace(trace_dir, profiler_options=options)
     t0 = time.perf_counter()
-    gm.label_propagation(graph, max_iter=iters, plan="auto").block_until_ready()
+    gm.label_propagation(graph, max_iter=iters, plan="auto", **on_mesh).block_until_ready()
     job_s = time.perf_counter() - t0
     jax.profiler.stop_trace()
     planes = devtrace.read_xplane(devtrace.newest_xplane(trace_dir), "run")
@@ -89,7 +95,7 @@ def main():
            "by_scope": dict(sorted(by_scope.items(), key=lambda kv: -kv[1])),
            "by_program": dict(sorted(by_program.items(), key=lambda kv: -kv[1])),
            "by_width": dict(sorted(by_width.items(), key=lambda kv: -kv[1])),
-           "memory": device.memory_stats()}
+           "memory": memory()}
     say(**out)
     if len(sys.argv) > 2:
         os.makedirs(os.path.dirname(os.path.abspath(sys.argv[2])), exist_ok=True)

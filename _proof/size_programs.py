@@ -6,6 +6,12 @@ program a process so that the peak RSS is that program's compile alone.
     python _proof/size_programs.py _proof/g500_24_shapes.json modes      # one, in this process
     python _proof/size_programs.py _proof/urand_24_shapes.json gather out.txt  # and its compiled text
 
+A shapes file of a MESH partition (``shards`` in it: _proof/mesh_shapes_and_k.py,
+ISSUE 39) compiles the mesh job's programs (``parallel/sharded.py``) for the
+described 2x2 instead; the bytes are then one chip's.
+
+    python _proof/size_programs.py _proof/g500_25_x4_shapes.json
+
 Prints one JSON line a program: temp / alias / argument / output / code
 bytes of memory_analysis(), the count of copy-done in the compiled text,
 compile seconds, peak RSS."""
@@ -36,6 +42,43 @@ def plan_of_shapes(said, sharding):
     )
 
 
+def mesh_lowered(said, name, topo):
+    """The mesh job's program ``name`` lowered over a partition of shapes."""
+    import jax, jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from graphmine_tpu.ops.superstep_policy import delta_rungs
+    from graphmine_tpu.parallel import sharded
+    from graphmine_tpu.parallel.mesh import make_mesh
+
+    d, vc = said["shards"], said["chunk_size"]
+    mesh = make_mesh(d, devices=topo.devices)
+    axes = sharded._vertex_axes(mesh)
+
+    def i32(*dims, dtype=jnp.int32, spec=None):
+        spec = P(axes, *[None] * (len(dims) - 1)) if spec is None else spec
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=NamedSharding(mesh, spec))
+
+    m_max, s = max(said["messages_per_shard"]), said["slots"]
+    sg = sharded.ShardedGraph(
+        msg_recv_local=None, msg_send=None, degrees=None,
+        num_vertices=said["num_vertices"], chunk_size=vc, num_shards=d,
+        bucket_send=tuple(i32(d, n, w) for n, w in said["classes"]),
+        bucket_target=tuple(i32(d, n) for n, _ in said["classes"]),
+        out_ptr=i32(d * (d * vc + 1)), out_slot=i32(d * m_max),
+    )
+    rows = i32(d * s)
+    labels = i32(d * vc, spec=P())
+    changed = i32(d * vc, dtype=jnp.bool_, spec=P())
+    if name == "gather":
+        return sharded._mesh_gather_program.lower(rows, labels, sg, mesh)
+    if name == "modes":
+        return sharded._mesh_modes_program.lower(rows, labels, sg, mesh)
+    if name.startswith("rewrite:"):
+        cap = delta_rungs(m_max)[int(name.split(":")[1])]
+        return sharded._mesh_rewrite_program.lower(rows, labels, changed, sg, mesh, cap=cap)
+    raise SystemExit(f"no program {name!r}")
+
+
 def one(said, name, text_out=None):
     import jax, jax.numpy as jnp
     from jax.experimental import topologies
@@ -46,13 +89,17 @@ def one(said, name, text_out=None):
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
-    plan = plan_of_shapes(said, chip)
     v, s = said["num_vertices"], said["slots"]
-    rows = jax.ShapeDtypeStruct((s,), jnp.int32, sharding=chip)
-    labels = jax.ShapeDtypeStruct((v,), jnp.int32, sharding=chip)
-    changed = jax.ShapeDtypeStruct((v,), jnp.bool_, sharding=chip)
     t0 = time.time()
-    if name == "gather":
+    plan = rows = labels = changed = None
+    if "shards" not in said:
+        plan = plan_of_shapes(said, chip)
+        rows = jax.ShapeDtypeStruct((s,), jnp.int32, sharding=chip)
+        labels = jax.ShapeDtypeStruct((v,), jnp.int32, sharding=chip)
+        changed = jax.ShapeDtypeStruct((v,), jnp.bool_, sharding=chip)
+    if plan is None:
+        lowered = mesh_lowered(said, name, topo)
+    elif name == "gather":
         lowered = lpa._gather_program.lower(rows, labels, plan)
     elif name == "modes":
         lowered = lpa._modes_program.lower(rows, labels, plan)

@@ -361,6 +361,7 @@ _TILE = (8, 128)
 _PAIRWISE_MAX_W = 32  # ops/bucketed_mode.py's (this module imports no jax): wider classes are sorted
 _REWRITE_SORT_WORDS = 8
 _REWRITE_CAP_WORDS = 5
+_MESH_CLASSES_AT_ONCE = 3  # carried_job_transients: the mesh `modes` program's classes overlap
 
 
 def _tiled(n: int, w: int) -> tuple[int, int]:
@@ -375,7 +376,7 @@ def _tiled(n: int, w: int) -> tuple[int, int]:
     return min(col, row), row
 
 
-def carried_job_transients(plan, top_rung: int = 0) -> dict:
+def carried_job_transients(plan, top_rung: int = 0, shards: int = 1) -> dict:
     """Bytes of temporaries by program of the carried-rows job, from the
     plan's shapes (the note above): ``gather``, ``modes`` and ``rewrite``
     at ``top_rung`` messages (0 without a rung). The hubs' histograms are
@@ -383,14 +384,27 @@ def carried_job_transients(plan, top_rung: int = 0) -> dict:
     weights ride through the sort) and no compile holds its count: at
     2^16 vertices the compiler kept one class's ``[n, w, w]`` pairwise
     products whole, in fast memory, which it cannot at a size that
-    matters."""
+    matters.
+
+    ``shards`` > 1: ``plan`` is one shard's, by shapes, and the programs
+    are the mesh job's (``parallel/sharded.py``). The gather and the
+    rewrite are the one-chip programs on a shard's slice and hold what
+    those hold (compiled for four described chips from graph500-25's
+    partition: 628,905,984 B against 629,164,032 B for one chip over the
+    very same shapes; PERF.md §6, PR 39). The classes of ``modes`` do not
+    quite take turns there (a program with a collective is scheduled to
+    hide its latency, not for the least memory): it held what its two
+    largest classes take, at once, from graph500-25's partition (to
+    0.1 %), and 2 % more than that from graph500-22's, where the one-chip
+    program held one class. So the mesh ``modes`` is counted at its THREE
+    largest classes."""
     v = int(plan.num_vertices)
     weighted = _plan_weighted(plan)
-    gather = modes = 0
+    gather, modes = [], []
     for idx in plan.send_idx or ():
         n, w = int(idx.shape[0]), int(idx.shape[1])
         kept, row = _tiled(n, w)
-        gather = max(gather, kept + row)
+        gather.append(kept + row)
         sort_in_place = kept == row and w % _TILE[1] == 0
         if weighted:
             # labels, weights, scores and mask; the sort's key, weight and
@@ -400,11 +414,12 @@ def carried_job_transients(plan, top_rung: int = 0) -> dict:
             reduce = 2 * kept
         else:
             reduce = (3 if sort_in_place else 4) * kept
-        modes = max(modes, kept + row, reduce)
+        modes.append(max(kept + row, reduce))
+    at_once = _MESH_CLASSES_AT_ONCE if shards > 1 else 1
     labels = 2 * _I32 * (v + 1)
     return {
-        "gather": gather + labels,
-        "modes": modes + labels,
+        "gather": max(gather, default=0) + labels,
+        "modes": sum(sorted(modes, reverse=True)[:at_once]) + labels,
         "rewrite": max(
             _REWRITE_SORT_WORDS * _I32 * v,
             _REWRITE_CAP_WORDS * _I32 * int(top_rung),
@@ -412,7 +427,7 @@ def carried_job_transients(plan, top_rung: int = 0) -> dict:
     }
 
 
-def carried_rows_inventory(plan, top_rung: int = 0) -> dict:
+def carried_rows_inventory(plan, top_rung: int = 0, shards: int = 1) -> dict:
     """What the carried-rows job of ``ops/lpa.py`` holds on the device
     beyond a fused ``plan``, known from the plan's shapes before the index
     is built. Exact: ``carried_rows``, the classes' rows end to end, ONCE
@@ -435,7 +450,11 @@ def carried_rows_inventory(plan, top_rung: int = 0) -> dict:
     hubs. Program code is device memory too and is in no term (0.75 GB
     for graph500-24's programs, 0.2 GB for Urand's). The admission of
     ``ops/superstep_policy.admit_carried_rows`` holds the sum against the
-    device's free memory."""
+    device's free memory. ``shards`` > 1: ``plan`` is one shard's, by
+    shapes, of the mesh job: the terms are then ONE chip's (its rows, its
+    index by global sender over the largest shard's messages, the padded
+    label vector, replicated, twice), the programs the mesh job's
+    (:func:`carried_job_transients`)."""
     v = int(plan.num_vertices)
     classes = [_slots(x) for x in plan.send_idx or ()]
     hubs = 0 if plan.hist_vertex_ids is None else int(plan.hist_vertex_ids.shape[0])
@@ -446,7 +465,7 @@ def carried_rows_inventory(plan, top_rung: int = 0) -> dict:
         "changed_mask": v,
         "hub_histograms": 2 * _I32 * hubs * v,
         "gather_transient": max(
-            carried_job_transients(plan, top_rung).values()
+            carried_job_transients(plan, top_rung, shards).values()
         ),
     }
 

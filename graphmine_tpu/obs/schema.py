@@ -81,6 +81,12 @@ register("outliers_recursive_lpa", "seconds")
 register("outliers_lof", "seconds", "k", "devices", "features")
 register("outlier_summary", "method")
 register("ivf_fallback", "guard", "detail")
+# impl_selected: one per resolution of a superstep family or LOF impl. Where
+# `label_propagation` asked whether the carried rows go on the device (one
+# chip: once per plan; a mesh: once per (graph, mesh), of the fullest chip)
+# it says `scan` (`carried` | `plain`) and `scan_reason`, the admission's
+# arithmetic or why nothing was asked (the sort family, a caller's trace, a
+# mesh that spans processes).
 register("impl_selected", "op", "impl", "n", "reason")
 # plan_build: one per superstep-plan materialization
 # (ops/superstep_policy.emit_plan_records and the driver's single-device
@@ -92,7 +98,11 @@ register("impl_selected", "op", "impl", "n", "reason")
 # `padded_slots_per_message`, `rows_pairwise` (vertices in classes up to
 # the pairwise width: copy, min, pairwise count), `rows_sorted` (wider:
 # the row sort), `rows_hist` (hubs), `max_width`. Benchmark metric
-# `plan_slots_per_message` reads the first.
+# `plan_slots_per_message` reads the first. The mesh entry's record
+# (ops/lpa.py:_mesh_label_propagation) says `index_seconds`: the part of
+# `seconds` spent on the carried-rows job's admission, the shards' slot
+# index (built in threads from the host partition) and its placement; 0.0
+# where the rows were not admitted and on a cache hit.
 register("plan_build", "op", "family", "seconds", "padded_slots_per_edge")
 # superstep_timing (ISSUE 12): achieved-vs-model throughput for one
 # window of supersteps, emitted at the existing tripwire/telemetry
@@ -135,7 +145,15 @@ register("fixpoint", "op", "supersteps", "changed", "num_vertices", "family")
 # sync of its own; empty for the stateless scan, one program that the
 # host does not step. Benchmark metrics `cdlp_sparse_superstep_share`
 # (`branch`) and `full_superstep_ms` (`seconds` where `branch` is "full")
-# read it.
+# read it. On a mesh (`label_propagation(..., mesh=)`, PR 39:
+# parallel/sharded.carried_label_propagation) the record also says
+# `shards`, and `num_messages`, `rungs` and `changed_messages` are the
+# LARGEST shard's: the messages it receives, the rungs cut from them, and
+# K as the most messages the changed vertices send into any one shard (one
+# `pmax`), which picks one branch for every shard; `changed_vertices` is
+# the whole graph's. The mesh entry writes no record where the one
+# compiled program runs (`impl_selected` says `scan: plain`): that program
+# counts nothing a superstep.
 register("superstep_delta", "op", "changed_vertices", "changed_messages",
          "branch", "rungs", "num_messages")
 
@@ -155,7 +173,12 @@ register("superstep_delta", "op", "changed_vertices", "changed_messages",
 # `carried` the `reason` holds the admission's count of it. `scan` is the admission's answer (`carried` |
 # `plain`, ops/superstep_policy.admit_carried_rows) and `reason` its
 # arithmetic, of the device's memory alone. Benchmark metric
-# `plan_resident_gb` reads it.
+# `plan_resident_gb` reads it. The mesh entry's record (PR 39:
+# ops/superstep_policy.emit_shard_residency) says `shards` and counts what
+# ONE chip holds: its shard of the stacked plan, of the rows and of the
+# slot index, the padded label vector (replicated) in and out;
+# `graph_bytes` 0 (the graph stays on the host), `bytes_limit` and
+# `bytes_in_use` the fullest chip's, which the admission was asked of.
 register("device_residency", "op", "scan", "reason", "bytes_limit",
          "graph_bytes", "plan_bytes", "rows_bytes", "slot_index_bytes",
          "labels_bytes", "code_bytes")

@@ -16,7 +16,8 @@ The superstep is one gather + one segment-mode over the precomputed message
 CSR — no shuffle, no driver round-trips. The stateless supersteps run as a
 single ``lax.scan`` XLA program; over a fused plan whose rows the device has
 room for, the supersteps step from the host and keep their gathered rows
-(:func:`_carried_rows_job`).
+(:func:`_carried_rows_job`; on a mesh a shard's rows a chip,
+``parallel/sharded.py:carried_label_propagation``).
 """
 
 from __future__ import annotations
@@ -127,15 +128,29 @@ def label_propagation(
     may — and past one chip's memory must — be host-resident
     (``build_graph(..., to_device=False)``): it is partitioned into
     vertex-range shards once per (graph, mesh, family), each shard placed
-    straight on its device, and all ``max_iter`` supersteps run as one
-    compiled program (:func:`~graphmine_tpu.parallel.sharded.
-    sharded_label_propagation`); no device ever holds the whole edge list
-    or message CSR. ``plan`` is then ``"auto"`` or a family name, and the
-    family comes from ``select_superstep_family(..., num_devices=D)``.
-    ``sink`` receives ``impl_selected``, ``partition``, ``plan_build`` and
-    one ``exchange`` record per call (bytes a chip receives per superstep,
-    messages and padded slots per shard). ``return_history`` is the
-    one-device path's.
+    straight on its device; no device ever holds the whole edge list or
+    message CSR. The supersteps then run as the carried-rows job on the
+    mesh (:func:`~graphmine_tpu.parallel.sharded.
+    carried_label_propagation`): each chip keeps its shard's gathered rows
+    in one donated buffer, the host steps a ``gather`` or a ``rewrite``
+    and then a ``modes`` program a superstep, and the rung is picked by
+    the largest shard's K, which the host reads from its own replica. The
+    rows and a slot index a shard go on the chips only if
+    ``admit_carried_rows`` finds room on the fullest one, asked once per
+    (graph, mesh) before the index is built; otherwise, under a caller's
+    trace, on a mesh that spans processes and on the ``sort`` family all
+    ``max_iter`` supersteps run as one compiled program
+    (:func:`~graphmine_tpu.parallel.sharded.sharded_label_propagation`),
+    the same labels bit for bit. ``plan`` is then ``"auto"`` or a family
+    name, and the family comes from
+    ``select_superstep_family(..., num_devices=D)``. ``sink`` receives
+    ``impl_selected`` (with ``scan`` and ``scan_reason``), ``partition``,
+    ``plan_build`` (its seconds with the index's), a ``device_residency``
+    record of what ONE chip holds, one ``exchange`` record per call (bytes
+    a chip receives per superstep, messages and padded slots per shard)
+    and, from the carried job, ``superstep_delta`` (with ``shards``; K,
+    the rungs and ``num_messages`` are the largest shard's).
+    ``return_history`` is the one-device path's.
     """
     if mesh is not None:
         if return_history:
@@ -234,13 +249,17 @@ def label_propagation(
     return labels
 
 
-def _emit_superstep_delta(sink, per_step: dict, num_messages: int) -> None:
+def _emit_superstep_delta(
+    sink, per_step: dict, num_messages: int, shards: int | None = None
+) -> None:
     """The ``superstep_delta`` record of one job over a fused plan's dense
     rows, from the job's per-superstep counts (the host's own by now; the
     stateless scan's come back with its labels). The stateless scan, which
     runs where the rows were not admitted to the device, has no ``branch``
     to report: every one of its supersteps is a full gather, and the
-    record says that."""
+    record says that. On a mesh (``shards``) ``num_messages`` and
+    ``changed_messages`` are the largest shard's: what the rungs are cut
+    from and what picks one for every shard."""
     import numpy as np
 
     from graphmine_tpu.ops.superstep_policy import delta_rungs
@@ -258,6 +277,7 @@ def _emit_superstep_delta(sink, per_step: dict, num_messages: int) -> None:
         changed_messages=messages, branch=branch, rungs=rungs,
         num_messages=num_messages,
         seconds=[round(s, 6) for s in per_step.get("seconds", ())],
+        **({} if shards is None else {"shards": shards}),
     )
 
 
@@ -266,6 +286,9 @@ def _under_a_trace() -> bool:
     concrete, so the host cannot read a count between two programs."""
     return not jax.core.trace_ctx.is_top_level()
 
+
+# the admission's answer where no value is concrete and the host reads no K
+_NO_HOST_STEPS = ("plain", "under a trace the supersteps cannot step from the host")
 
 _auto_plan_cache: dict = {}
 
@@ -324,9 +347,7 @@ def _cached_slot_index(plan):
     )
 
     if _under_a_trace():
-        return plan, 0.0, (
-            "plain", "under a trace the supersteps cannot step from the host"
-        )
+        return plan, 0.0, _NO_HOST_STEPS
     if plan.out_slot is not None:
         return plan, 0.0, ("carried", "the plan came with its slot index")
     if not plan.send_idx:
@@ -357,13 +378,22 @@ _mesh_partition_cache: dict = {}
 
 def _mesh_label_propagation(graph, mesh, max_iter, init_labels, plan, sink):
     """The mesh half of :func:`label_propagation`: resolve the family,
-    partition and place the graph (cached), emit the provenance records,
+    partition and place the graph (cached, with the shards' slot index
+    where the chips have room for the carried rows), emit the provenance
+    records, then step the carried-rows job from the host
+    (:func:`~graphmine_tpu.parallel.sharded.carried_label_propagation`) or,
+    where there is no index or the host cannot step (a caller's trace),
     run the one compiled program."""
     from graphmine_tpu.ops.superstep_policy import (
         crossover_thresholds,
+        emit_shard_residency,
         select_superstep_family,
     )
-    from graphmine_tpu.parallel.sharded import sharded_label_propagation
+    from graphmine_tpu.parallel.sharded import (
+        carried_label_propagation,
+        shard_messages,
+        sharded_label_propagation,
+    )
 
     if not isinstance(plan, str):
         raise ValueError(
@@ -375,12 +405,16 @@ def _mesh_label_propagation(graph, mesh, max_iter, init_labels, plan, sink):
         num_devices=mesh.size,
     )
     sg, stats, cached = _cached_mesh_partition(graph, mesh, family)
+    scan = stats["scan"]
+    if scan[0] == "carried" and _under_a_trace():
+        scan = _NO_HOST_STEPS
     if sink is not None:
         cost = stats["cost"]
         sink.emit(
             "impl_selected", op="lpa_superstep", impl=family,
             n=graph.num_messages, reason=reason, devices=mesh.size,
             thresholds=crossover_thresholds(), cost=cost,
+            scan=scan[0], scan_reason=scan[1],
         )
         sink.emit(
             "partition", shards=mesh.size, family=family, cached=cached,
@@ -390,7 +424,10 @@ def _mesh_label_propagation(graph, mesh, max_iter, init_labels, plan, sink):
         if family != "sort":
             sink.emit(
                 "plan_build", op="lpa_superstep", family=family, cached=cached,
-                seconds=0.0 if cached else round(stats["plan_seconds"], 6),
+                seconds=0.0 if cached else round(
+                    stats["plan_seconds"] + stats["index_seconds"], 6
+                ),
+                index_seconds=0.0 if cached else round(stats["index_seconds"], 6),
                 width_classes=stats["width_classes"],
                 padded_slots_per_edge=round(
                     stats["padded_slots_per_shard"] * mesh.size
@@ -398,28 +435,66 @@ def _mesh_label_propagation(graph, mesh, max_iter, init_labels, plan, sink):
                 ),
                 cost=cost,
             )
+            emit_shard_residency(sink, "lpa_superstep", sg, mesh, scan)
         sink.emit("exchange", op="lpa_superstep", family=family, **stats["exchange"])
+    if scan[0] == "carried":
+        labels, per_step = carried_label_propagation(
+            sg, mesh, max_iter, init_labels,
+            clock=time.perf_counter if sink is not None else None,
+        )
+        if sink is not None:
+            _emit_superstep_delta(
+                sink, per_step, shard_messages(sg), shards=sg.num_shards
+            )
+        return labels
+    if sg.out_slot is not None:  # the one program is handed what it reads
+        import dataclasses
+
+        sg = dataclasses.replace(sg, out_ptr=None, out_slot=None)
     return sharded_label_propagation(sg, mesh, max_iter, init_labels)
 
 
 def _cached_mesh_partition(graph: Graph, mesh, family: str):
     """``(sharded graph on the mesh, stats, cached)`` per (graph, mesh,
-    family): the host partition, its plan and the placement are paid once,
-    as :func:`_cached_auto_plan` pays a plan once. Keyed by the identity
-    of the graph's ``msg_ptr`` (host or device array); a weakref finalizer
-    evicts the entry with it. Only the placed shards are kept — the host
-    copies go as soon as they are on the devices."""
+    family): the host partition, its plan, the placement and the shards'
+    slot index are paid once, as :func:`_cached_auto_plan` and
+    :func:`_cached_slot_index` pay a plan and its index once. Keyed by the
+    identity of the graph's ``msg_ptr`` (host or device array); a weakref
+    finalizer evicts the entry with it. Only the placed shards are kept:
+    the host copies go as soon as they are on the devices.
+
+    Once the plan is placed, :func:`~graphmine_tpu.ops.superstep_policy.
+    admit_carried_rows` is asked, of the fullest chip of the mesh, whether
+    a shard's carried rows and slot index go on it beside what it holds;
+    only then is the index built, from the host partition before it is let
+    go (:func:`~graphmine_tpu.parallel.sharded.with_shard_slot_index`), and
+    placed. ``stats["scan"]`` keeps the answer and its arithmetic,
+    ``stats["index_seconds"]`` the build and the placement. The ``sort``
+    family keeps no rows; a mesh that spans processes is not asked (the
+    host steps the job by reading K from its own replica, and cannot step
+    another host's chips); under a caller's trace nothing is asked and
+    nothing kept, as on one chip."""
+    import dataclasses
     import weakref
 
     import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec
 
     from graphmine_tpu.obs.costmodel import sharded_superstep_cost
+    from graphmine_tpu.ops.superstep_policy import (
+        admit_carried_rows,
+        mesh_memory_stats,
+    )
     from graphmine_tpu.parallel.sharded import (
         _shard_message_offsets,
+        _vertex_axes,
         partition_graph,
         shard_graph_arrays,
+        shard_plan_shapes,
+        with_shard_slot_index,
     )
 
+    traced = _under_a_trace()
     key = id(graph.msg_ptr)
     hit = _mesh_partition_cache.get(key)
     if hit is None or hit[0]() is not graph.msg_ptr:
@@ -427,7 +502,8 @@ def _cached_mesh_partition(graph: Graph, mesh, family: str):
             graph.msg_ptr, lambda _, k=key: _mesh_partition_cache.pop(k, None)
         )
         hit = (ref, {})
-        _mesh_partition_cache[key] = hit
+        if not traced:
+            _mesh_partition_cache[key] = hit
     placed = hit[1]
     where = (tuple(d.id for d in mesh.devices.flat), mesh.axis_names, family)
     if where in placed:
@@ -435,26 +511,54 @@ def _cached_mesh_partition(graph: Graph, mesh, family: str):
     lpa_only = family != "sort"
     timings: dict = {}
     t0 = time.perf_counter()
-    sg = shard_graph_arrays(
-        partition_graph(
-            graph, mesh=mesh, lpa_only=lpa_only, timings=timings,
-            build_bucket_plan=family == "bucketed",
-        ),
-        mesh, lpa_only=lpa_only,
+    host = partition_graph(
+        graph, mesh=mesh, lpa_only=lpa_only, timings=timings,
+        build_bucket_plan=family == "bucketed",
     )
+    sg = shard_graph_arrays(host, mesh, lpa_only=lpa_only)
     jax.block_until_ready(sg)  # the transfer is set-up's, not the first job's
     seconds = time.perf_counter() - t0
+    counts = np.diff(_shard_message_offsets(
+        np.asarray(graph.msg_ptr), sg.num_shards, sg.chunk_size
+    ))
+    t0 = time.perf_counter()
+    if family != "bucketed":
+        scan = ("plain", f"the {family} family keeps no gathered rows")
+    elif traced:
+        scan = _NO_HOST_STEPS
+    elif jax.process_count() > 1:
+        scan = ("plain", "the mesh spans processes: one host cannot step "
+                         "another's chips by reading K from its own replica")
+    else:
+        scan = admit_carried_rows(
+            shard_plan_shapes(sg, int(counts.max(initial=0))),
+            mesh_memory_stats(mesh), shards=sg.num_shards,
+        )
+    if scan[0] == "carried":
+        indexed = with_shard_slot_index(host, counts)
+        if indexed.out_slot is None:
+            scan = ("plain", "no slot index: a shard's slots are none, or "
+                             "more than an int32 counts")
+        else:
+            spec = NamedSharding(mesh, PartitionSpec(_vertex_axes(mesh)))
+            sg = dataclasses.replace(
+                sg, out_ptr=jax.device_put(indexed.out_ptr, spec),
+                out_slot=jax.device_put(indexed.out_slot, spec),
+            )
+            jax.block_until_ready((sg.out_ptr, sg.out_slot))
+        del indexed
+    del host
+    index_seconds = time.perf_counter() - t0
     # shapes only: the padded slots a shard streams and the bytes a chip
     # receives per superstep have one owner, the cost model
     cost = sharded_superstep_cost(
         "lpa_superstep", sg, graph.num_edges, num_messages=graph.num_messages
     )
-    counts = np.diff(_shard_message_offsets(
-        np.asarray(graph.msg_ptr), sg.num_shards, sg.chunk_size
-    ))
     stats = {
         "partition_seconds": seconds - timings["plan_seconds"],
         "plan_seconds": timings["plan_seconds"],
+        "index_seconds": index_seconds,
+        "scan": scan,
         "width_classes": len(sg.bucket_send),
         "padded_slots_per_shard": cost.padded_slots,
         "cost": cost.record(),
@@ -552,9 +656,10 @@ def _carried_rows_job(
     graph: Graph, max_iter: int, init_labels, plan, clock=None
 ):
     """``(labels, per_step)`` of ``max_iter`` supersteps over a fused plan
-    with its slot index, stepped from the host: the gathered rows live in
-    one buffer across supersteps, and a superstep reads again only what
-    changed.
+    with its slot index, stepped from the host
+    (:func:`~graphmine_tpu.ops.superstep_policy.step_carried_rows`): the
+    gathered rows live in one buffer across supersteps, and a superstep
+    reads again only what changed.
 
     Each superstep first brings the rows up to the labels it starts from,
     by the update its predecessor's count picks: K, the messages sent by
@@ -566,15 +671,13 @@ def _carried_rows_job(
     Then :func:`_modes_program` runs the row modes, the histogram hubs and
     the write back over the rows, as in ``lpa_superstep_bucketed``: the
     labels are its labels bit for bit. The host waits once a superstep,
-    for K; ``max_iter`` is the length of this loop and no program's
+    for K; ``max_iter`` is the length of its loop and no program's
     argument. ``per_step`` holds ``changed_vertices``,
     ``changed_messages`` and ``branch`` (the rung's place, or
     ``len(rungs)`` for a full gather), one a superstep; with a ``clock``
-    (the caller's, where a sink wants them) also ``seconds``, the clock's
-    reading after each fetch of K less the reading before it: a
-    superstep's seconds on the host's clock, at the wait the job has."""
+    (the caller's, where a sink wants them) also ``seconds``."""
     from graphmine_tpu.ops.bucketed_mode import check_plan_fits, row_slots
-    from graphmine_tpu.ops.superstep_policy import delta_rungs
+    from graphmine_tpu.ops.superstep_policy import delta_rungs, step_carried_rows
 
     labels = (
         jnp.arange(graph.num_vertices, dtype=jnp.int32)
@@ -582,31 +685,16 @@ def _carried_rows_job(
         else jnp.asarray(init_labels).astype(jnp.int32)
     )
     check_plan_fits(labels, graph, plan)
-    rungs = delta_rungs(plan.num_messages)
-    rows = _blank_rows(row_slots(plan))
-    changed, k = None, plan.num_messages + 1
-    count, sent, branch = [], [], []
-    marks = [clock()] if clock else []
-    for _ in range(max_iter):
-        branch.append(sum(k > rung for rung in rungs))
-        if branch[-1] == len(rungs):
-            rows = _gather_program(rows, labels, plan)
-        else:
-            rows = _rewrite_program(
-                rows, labels, changed, plan, cap=rungs[branch[-1]]
-            )
-        labels, changed, k, moved = _modes_program(rows, labels, plan)
-        k, moved = (int(x) for x in jax.device_get((k, moved)))  # the one wait
-        sent.append(k)
-        count.append(moved)
-        if clock:
-            marks.append(clock())
-    per_step = {
-        "changed_vertices": count, "changed_messages": sent, "branch": branch,
-    }
-    if clock:
-        per_step["seconds"] = [b - a for a, b in zip(marks, marks[1:])]
-    return labels, per_step
+    return step_carried_rows(
+        max_iter, delta_rungs(plan.num_messages), plan.num_messages + 1,
+        _blank_rows(row_slots(plan)), labels,
+        gather=lambda rows, labels: _gather_program(rows, labels, plan),
+        rewrite=lambda rows, labels, changed, cap: _rewrite_program(
+            rows, labels, changed, plan, cap=cap
+        ),
+        modes=lambda rows, labels: _modes_program(rows, labels, plan),
+        clock=clock,
+    )
 
 
 def num_communities(labels: jax.Array) -> jax.Array:

@@ -79,6 +79,51 @@ def delta_rungs(num_messages: int) -> tuple:
     ))
 
 
+def step_carried_rows(
+    max_iter: int, rungs: tuple, over: int, rows, labels,
+    gather, rewrite, modes, clock=None,
+):
+    """``(labels, per_step)`` of ``max_iter`` supersteps of a carried-rows
+    job, stepped from the host: the loop of ``ops/lpa.py:_carried_rows_job``
+    and of its mesh form (``parallel/sharded.py:carried_label_propagation``),
+    which hand it their programs. Each superstep first brings ``rows`` up
+    to the labels it starts from by the update its predecessor's K picks
+    (K above every rung: ``gather(rows, labels)``, the first superstep
+    always, ``over`` being a K above them all; K <= a rung:
+    ``rewrite(rows, labels, changed, rung)``), then ``modes(rows, labels)``
+    gives ``(new labels, changed, K, count)``. The host waits once a
+    superstep, for K and the count; ``max_iter`` is the length of this
+    loop and no program's argument. ``per_step`` holds
+    ``changed_vertices``, ``changed_messages`` (K) and ``branch`` (the
+    rung's place, or ``len(rungs)`` for a full gather), one a superstep;
+    with a ``clock`` also ``seconds``, the clock's reading after each
+    fetch of K less the reading before it: a superstep's seconds on the
+    host's clock, at the wait the job has."""
+    import jax
+
+    changed, k = None, over
+    count, sent, branch = [], [], []
+    marks = [clock()] if clock else []
+    for _ in range(max_iter):
+        branch.append(sum(k > rung for rung in rungs))
+        if branch[-1] == len(rungs):
+            rows = gather(rows, labels)
+        else:
+            rows = rewrite(rows, labels, changed, rungs[branch[-1]])
+        labels, changed, k, moved = modes(rows, labels)
+        k, moved = (int(x) for x in jax.device_get((k, moved)))  # the one wait
+        sent.append(k)
+        count.append(moved)
+        if clock:
+            marks.append(clock())
+    per_step = {
+        "changed_vertices": count, "changed_messages": sent, "branch": branch,
+    }
+    if clock:
+        per_step["seconds"] = [b - a for a, b in zip(marks, marks[1:])]
+    return labels, per_step
+
+
 _INT32_MAX = (1 << 31) - 1
 
 
@@ -90,10 +135,30 @@ def device_memory_stats(plan) -> dict | None:
     return next(iter(placed)).memory_stats() if len(placed) == 1 else None
 
 
-def admit_carried_rows(plan, stats: dict | None) -> tuple[str, str]:
-    """``("carried" | "plain", reason)`` for the one-chip LPA job over the
-    fused ``plan``: do the carried rows and the slot index go on the
-    device beside what it already holds? Taken once per plan, on the host,
+def mesh_memory_stats(mesh) -> dict | None:
+    """The allocator's statistics of the FULLEST device of ``mesh``: the
+    one with the least ``bytes_limit`` less ``bytes_in_use`` (every chip
+    runs the one SPMD program, so the fullest decides for all); ``None``
+    where a device keeps none (the CPU)."""
+    stats = [d.memory_stats() for d in mesh.devices.flat]
+    if not all(s and s.get("bytes_limit") for s in stats):
+        return None
+    return min(
+        stats, key=lambda s: int(s["bytes_limit"]) - int(s.get("bytes_in_use", 0))
+    )
+
+
+def admit_carried_rows(
+    plan, stats: dict | None, shards: int = 1
+) -> tuple[str, str]:
+    """``("carried" | "plain", reason)`` for the LPA job over the fused
+    ``plan``: do the carried rows and the slot index go on the device
+    beside what it already holds? On a mesh (``shards`` > 1) ``plan`` is
+    ONE shard's, by shapes (``parallel/sharded.shard_plan_shapes``: its
+    vertices the padded vertex space every chip holds the labels of, its
+    messages the largest shard's) and ``stats`` the fullest chip's
+    (:func:`mesh_memory_stats`), asked once per (graph, mesh); the terms
+    are the same, a chip each. Taken once per plan, on the host,
     before the index is built, from the plan's shapes
     (:func:`~graphmine_tpu.obs.memmodel.carried_rows_inventory`: the rows,
     held once, the index, the labels, the hubs' histograms and the
@@ -112,8 +177,8 @@ def admit_carried_rows(plan, stats: dict | None) -> tuple[str, str]:
     whose compile does not fit the host still ends there, minutes later.
     Every ``reason`` says so."""
     top_rung = max(delta_rungs(int(plan.num_messages)), default=0)
-    need = carried_rows_inventory(plan, top_rung=top_rung)
-    by_program = carried_job_transients(plan, top_rung=top_rung)
+    need = carried_rows_inventory(plan, top_rung=top_rung, shards=shards)
+    by_program = carried_job_transients(plan, top_rung=top_rung, shards=shards)
     largest = max(by_program, key=by_program.get)
     slots = need["carried_rows"] // 4
     if slots == 0 or slots >= _INT32_MAX:
@@ -123,7 +188,8 @@ def admit_carried_rows(plan, stats: dict | None) -> tuple[str, str]:
         )
     total = sum(need.values())
     said = (
-        f"rows, held once, {need['carried_rows']} B + slot index "
+        (f"a shard of {shards}, on the fullest chip: " if shards > 1 else "")
+        + f"rows, held once, {need['carried_rows']} B + slot index "
         f"{need['slot_index']} B + labels and changed mask "
         f"{need['labels'] + need['changed_mask']} B + hub histograms "
         f"{need['hub_histograms']} B + the largest program's other "
@@ -302,6 +368,45 @@ def emit_device_residency(
         slot_index_bytes=on_device(plan, index),
         labels_bytes=inv["labels"],
         # the jitted call hands back no executable to ask for its size
+        code_bytes=None,
+    )
+
+
+def emit_shard_residency(
+    sink, op: str, sg, mesh, scan: tuple[str, str],
+) -> None:
+    """The ``device_residency`` record of the mesh entry: what ONE chip
+    holds for this graph's supersteps, by array group, from the stacked
+    arrays' per-shard shapes (every shard's are the same), beside the
+    fullest chip's ``bytes_limit`` and ``bytes_in_use``
+    (:func:`mesh_memory_stats`, asked here); ``shards`` says it is a
+    chip's share. The graph stays on the host (``graph_bytes: 0``); the
+    labels are the padded vector, replicated, in and out. No-op without a
+    sink. ``sg`` is the placed partition, with its slot index when
+    ``scan`` says ``carried``."""
+    if sink is None:
+        return
+    import jax
+
+    def per_chip(*trees) -> int:
+        return sum(
+            int(x.nbytes) // sg.num_shards
+            for tree in trees for x in jax.tree.leaves(tree)
+        )
+
+    carried = scan[0] == "carried"
+    stats = mesh_memory_stats(mesh) or {}
+    sink.emit(
+        "device_residency", op=op, scan=scan[0], reason=scan[1],
+        shards=sg.num_shards,
+        bytes_limit=stats.get("bytes_limit"),
+        bytes_in_use=stats.get("bytes_in_use"),
+        graph_bytes=per_chip(sg.msg_recv_local, sg.msg_send, sg.degrees,
+                             sg.msg_weight),
+        plan_bytes=per_chip(sg.bucket_send, sg.bucket_target, sg.bucket_weight),
+        rows_bytes=per_chip(sg.bucket_send) if carried else 0,
+        slot_index_bytes=per_chip(sg.out_ptr, sg.out_slot),
+        labels_bytes=2 * 4 * sg.padded_vertices,
         code_bytes=None,
     )
 

@@ -40,9 +40,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from graphmine_tpu.graph.container import Graph, build_graph
 from graphmine_tpu.ops.bucketed_mode import (
     _SENTINEL,
+    BucketedModePlan,
     _bucket_mode,
     _bucket_wmode,
     _extend_widths,
+    _positions_by_key,
+    gather_rows,
+    lpa_modes_from_rows,
+    rewrite_rows,
 )
 from graphmine_tpu.ops.segment import segment_mode
 from graphmine_tpu.pipeline.resilience import DivergenceError
@@ -302,6 +307,19 @@ class ShardedGraph:
     # aligned slot-for-slot with bucket_send (padding slots 0). Empty on
     # unweighted graphs.
     bucket_weight: tuple = ()
+    # Slot index by sender, a shard each (added by with_shard_slot_index
+    # for the carried-rows mesh job below, never by partition_graph): with
+    # a shard's class rows laid end to end as one flat int32[S] buffer,
+    # GLOBAL sender s's label sits in that shard's flat slots
+    # out_slot[d][out_ptr[d][s]:out_ptr[d][s + 1]]. Both are FLAT, shard
+    # after shard, placed P(axes): out_ptr int32 [D * (padded_vertices +
+    # 1)], out_slot int32 [D * the largest shard's messages], a shorter
+    # shard's tail padded with S (names no slot). Flat because a chip's
+    # [1, n] block and the [n] vector the row functions read are tiled
+    # differently on a TPU: the squeeze between them is a copy of the
+    # whole block (1.06 GB of out_slot at graph500-25, PERF.md PR 39).
+    out_ptr: jax.Array | None = None
+    out_slot: jax.Array | None = None
 
     @property
     def padded_vertices(self) -> int:
@@ -559,6 +577,7 @@ def shard_graph_arrays(sg: ShardedGraph, mesh, lpa_only: bool = False) -> Sharde
     axes = _vertex_axes(mesh)
     spec = NamedSharding(mesh, P(axes, None))
     spec3 = NamedSharding(mesh, P(axes, None, None))
+    flat = NamedSharding(mesh, P(axes))
     if lpa_only and not sg.bucket_send:
         raise ValueError(
             "lpa_only requires partition_graph(build_bucket_plan=True)"
@@ -577,6 +596,8 @@ def shard_graph_arrays(sg: ShardedGraph, mesh, lpa_only: bool = False) -> Sharde
         # bucket_weight) — drop it under lpa_only like the rest.
         msg_weight=None if sg.msg_weight is None else place(sg.msg_weight, spec),
         bucket_weight=tuple(jax.device_put(b, spec3) for b in sg.bucket_weight),
+        out_ptr=None if sg.out_ptr is None else jax.device_put(sg.out_ptr, flat),
+        out_slot=None if sg.out_slot is None else jax.device_put(sg.out_slot, flat),
     )
 
 
@@ -910,6 +931,248 @@ def _sharded_lpa_jit(
         labels, ys = out
         return labels[: sg.num_vertices], ys
     return out[: sg.num_vertices]
+
+
+# ---- carried rows on a mesh: a shard's gathered rows as state ---------------
+#
+# The mesh form of ``ops/lpa.py:_carried_rows_job`` (ISSUE 39). Each chip
+# keeps its shard's gathered rows across supersteps in one donated buffer
+# (``int32 [D * S]`` placed ``P(axes)``: flat, so that a chip's block IS the
+# ``[S]`` vector the row functions update and no program reshapes it; as
+# ``[D, S]`` every rewrite copied a chip's rows in and out, PERF.md PR 39),
+# and a superstep that follows
+# few changed labels rewrites only the slots behind their senders, through a
+# slot index a shard (``ShardedGraph.out_ptr`` / ``out_slot``). Every chip
+# knows which labels changed: a superstep ends in the tiled ``all_gather``
+# that replicates the new labels. The row update has one implementation,
+# ``ops/bucketed_mode.py``'s ``gather_rows`` / ``rewrite_rows`` /
+# ``lpa_modes_from_rows``: the shard bodies hand them the shard's slice of
+# the stacked plan as a ``BucketedModePlan`` (:func:`_shard_plan`).
+
+
+def shard_row_slots(sg: ShardedGraph) -> int:
+    """S: the padded slots of one shard's dense rows (uniform across
+    shards: the stacked plan's shapes are)."""
+    return sum(int(b.shape[1]) * int(b.shape[2]) for b in sg.bucket_send)
+
+
+def shard_plan_shapes(sg: ShardedGraph, messages_per_shard: int):
+    """One shard's plan by SHAPES (no data): what the admission and the
+    cost of the carried-rows mesh job read. ``num_vertices`` is the padded
+    vertex space (the replicated label vector every chip sorts and
+    indexes), ``num_messages`` the largest shard's."""
+    i32 = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.int32)
+    f32 = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32)
+    return BucketedModePlan(
+        vertex_ids=tuple(i32(*t.shape[1:]) for t in sg.bucket_target),
+        msg_idx=None, num_vertices=sg.padded_vertices,
+        num_messages=int(messages_per_shard),
+        send_idx=tuple(i32(*b.shape[1:]) for b in sg.bucket_send),
+        weight_mat=tuple(f32(*w.shape[1:]) for w in sg.bucket_weight) or None,
+    )
+
+
+def with_shard_slot_index(sg: ShardedGraph, counts) -> ShardedGraph:
+    """The HOST partition ``sg`` (NumPy fields, before
+    :func:`shard_graph_arrays`) with its slot index a shard: per shard the
+    transpose of its ``bucket_send``, one stable counting sort of the
+    senders behind the shard's padded slots
+    (``ops/bucketed_mode._positions_by_key``, what ``with_slot_index``
+    sorts by), the shards in threads. Padding slots name the sentinel
+    ``padded_vertices`` and fall out of the sort; the mesh plan has no
+    histogram hubs, so every message has a slot (held against ``counts``,
+    the shards' true message counts). Comes back as it is when a shard has
+    more slots than an int32 counts, or none."""
+    s, v_pad = shard_row_slots(sg), sg.padded_vertices
+    if s == 0 or s >= np.iinfo(np.int32).max:
+        return sg
+    m_max = max(int(np.max(counts, initial=0)), 1)
+    out_ptr = np.empty((sg.num_shards, v_pad + 1), np.int32)
+    out_slot = np.empty((sg.num_shards, m_max), np.int32)
+
+    def index(d):
+        keys = np.concatenate([b[d].reshape(-1) for b in sg.bucket_send])
+        ptr, slot = _positions_by_key(keys, v_pad)
+        assert len(slot) == int(counts[d]), (d, len(slot), int(counts[d]))
+        out_ptr[d] = ptr
+        out_slot[d, :len(slot)] = slot
+        out_slot[d, len(slot):] = s  # a shorter shard's tail names no slot
+
+    _in_threads(index, range(sg.num_shards))
+    return dataclasses.replace(
+        sg, out_ptr=out_ptr.reshape(-1), out_slot=out_slot.reshape(-1)
+    )
+
+
+def _shard_plan(sg: ShardedGraph, bucket_send=(), bucket_target=(),
+                bucket_weight=(), out_ptr=None, out_slot=None):
+    """One shard's slice of the stacked plan (``[1, ...]`` leaves, as they
+    arrive under ``shard_map``) as the ``BucketedModePlan`` the one-chip
+    row functions read: ``send_idx`` the shard's sender matrices over the
+    padded label vector (padding slots name ``padded_vertices``, the
+    sentinel ``_with_sentinel`` appends), ``vertex_ids`` its LOCAL targets,
+    the index its own. A program hands over the fields it reads; without
+    ``bucket_send`` the classes are there by shape alone (the row modes
+    read the rows they are handed, and of the matrices only the shapes)."""
+    send_idx = tuple(b[0] for b in bucket_send) or tuple(
+        jax.ShapeDtypeStruct(b.shape[1:], jnp.int32) for b in sg.bucket_send
+    )
+    return BucketedModePlan(
+        vertex_ids=tuple(t[0] for t in bucket_target), msg_idx=None,
+        num_vertices=sg.padded_vertices,
+        num_messages=0 if out_slot is None else out_slot.shape[0],
+        send_idx=send_idx,
+        weight_mat=tuple(w[0] for w in bucket_weight) or None,
+        out_ptr=out_ptr, out_slot=out_slot,
+    )
+
+
+def shard_messages(sg: ShardedGraph) -> int:
+    """The largest shard's messages: a shard's length of ``out_slot``."""
+    return sg.out_slot.shape[0] // sg.num_shards
+
+
+@partial(jax.jit, static_argnames=("mesh", "num_vertices", "chunk_size", "slots"))
+def _mesh_job_start(init_labels, mesh, num_vertices, chunk_size, slots):
+    """``(labels, rows)`` before the first superstep: the padded label
+    vector on every chip, and each chip's blank rows."""
+    d = mesh.size
+    labels = jnp.arange(d * chunk_size, dtype=jnp.int32)
+    if init_labels is not None:
+        labels = labels.at[:num_vertices].set(init_labels.astype(jnp.int32))
+    return (
+        lax.with_sharding_constraint(labels, NamedSharding(mesh, P())),
+        lax.with_sharding_constraint(
+            jnp.zeros((d * slots,), jnp.int32),
+            NamedSharding(mesh, P(_vertex_axes(mesh))),
+        ),
+    )
+
+
+@partial(jax.jit, static_argnames=("mesh",), donate_argnums=0)
+def _mesh_gather_program(rows, labels, sg: ShardedGraph, mesh):
+    """Every class of every shard gathered anew from the replicated
+    ``labels`` into the shard's flat rows, in place."""
+    axes = _vertex_axes(mesh)
+    n = len(sg.bucket_send)
+
+    def body(rows, labels, bucket_send):
+        return gather_rows(rows, labels, _shard_plan(sg, bucket_send))
+
+    return shard_map(
+        body, mesh=mesh,
+        in_specs=(P(axes), P(), (P(axes, None, None),) * n),
+        out_specs=P(axes), check_vma=False,
+    )(rows, labels, sg.bucket_send)
+
+
+@partial(jax.jit, static_argnames=("mesh", "cap"), donate_argnums=0)
+def _mesh_rewrite_program(rows, labels, changed, sg: ShardedGraph, mesh, cap: int):
+    """Each shard's slots behind the ``changed`` senders rewritten with
+    their ``labels``, in place; ``cap`` bounds the messages they send to
+    ANY one shard (the caller's promise: the largest shard's K)."""
+    spec = P(_vertex_axes(mesh))
+
+    def body(rows, labels, changed, out_ptr, out_slot):
+        plan = _shard_plan(sg, out_ptr=out_ptr, out_slot=out_slot)
+        return rewrite_rows(rows, labels, changed, plan, cap)
+
+    return shard_map(
+        body, mesh=mesh, in_specs=(spec, P(), P(), spec, spec),
+        out_specs=spec, check_vma=False,
+    )(rows, labels, changed, sg.out_ptr, sg.out_slot)
+
+
+@partial(jax.jit, static_argnames=("mesh",))
+def _mesh_modes_program(rows, labels, sg: ShardedGraph, mesh):
+    """``(new labels, changed, K, count)`` of one superstep over the
+    shards' ``rows``: each shard's row modes written to its owned range,
+    the tiled ``all_gather``, then on the replicated vector ``changed``,
+    the count of changed vertices, and K: the messages the changed
+    vertices send to the shard they send most to (one ``pmax``), which
+    picks the next superstep's update for every shard."""
+    axes = _vertex_axes(mesh)
+    n, nw = len(sg.bucket_send), len(sg.bucket_weight)
+    scratch = max((t.shape[-1] for t in sg.bucket_target), default=0)
+
+    def body(rows, labels, bucket_target, bucket_weight, out_ptr):
+        plan = _shard_plan(
+            sg, bucket_target=bucket_target, bucket_weight=bucket_weight,
+            out_ptr=out_ptr,
+        )
+        start = lax.axis_index(axes).astype(jnp.int32) * sg.chunk_size
+        own = lax.dynamic_slice(labels, (start,), (sg.chunk_size,))
+        # padding rows write to distinct in-range scratch places past the
+        # owned range (see _shard_row_modes): sliced away
+        own = jnp.concatenate([own, jnp.zeros((scratch,), jnp.int32)])
+        own = lpa_modes_from_rows(rows, own, plan)[: sg.chunk_size]
+        with jax.named_scope("lpa_sharded"), jax.named_scope("exchange"):
+            new = lax.all_gather(own, axes, tiled=True)
+        with jax.named_scope("superstep"), jax.named_scope("changed_count"):
+            changed = new != labels
+            out_deg = plan.out_ptr[1:] - plan.out_ptr[:-1]
+            k = lax.pmax(
+                jnp.sum(jnp.where(changed, out_deg, 0), dtype=jnp.int32), axes
+            )
+            count = jnp.sum(changed, dtype=jnp.int32)
+        return new, changed, k, count
+
+    flat, spec, spec3 = P(axes), P(axes, None), P(axes, None, None)
+    return shard_map(
+        body, mesh=mesh,
+        in_specs=(flat, P(), (spec,) * n, (spec3,) * nw, flat),
+        out_specs=(P(), P(), P(), P()), check_vma=False,
+    )(rows, labels, sg.bucket_target, sg.bucket_weight, sg.out_ptr)
+
+
+def carried_label_propagation(
+    sg: ShardedGraph, mesh, max_iter: int = 5,
+    init_labels: jax.Array | None = None, clock=None,
+):
+    """``(labels [V], per_step)`` of ``max_iter`` LPA supersteps over a
+    placed partition with its slot index (:func:`with_shard_slot_index`),
+    stepped from the host: ``ops/lpa.py:_carried_rows_job`` on a mesh, the
+    same labels as :func:`sharded_label_propagation` bit for bit.
+
+    Each superstep first brings every shard's rows up to the labels it
+    starts from, by the update its predecessor's count picks: K, the
+    messages the changed vertices send to the shard that receives most of
+    them. ``cap`` is a static argument and SPMD needs one program for all
+    shards, so the rung is picked by the LARGEST shard's K (a sum over
+    shards would not bound one shard's count) against
+    ``delta_rungs(the largest shard's messages)``. K above every rung
+    gathers every class anew (:func:`_mesh_gather_program`; the first
+    superstep always); K <= a rung rewrites that many slots a shard
+    (:func:`_mesh_rewrite_program`, compiled when a job first takes the
+    rung). Then :func:`_mesh_modes_program`. The loop is the one-chip
+    job's (``ops/superstep_policy.step_carried_rows``): the host waits once
+    a superstep, for K, which it reads from its own replica; ``max_iter``
+    is the length of that loop and no program's argument. ``per_step`` is
+    ``_carried_rows_job``'s: ``changed_vertices``, ``changed_messages``
+    (the largest shard's K), ``branch``, and with a ``clock`` ``seconds``.
+
+    One process only: the host steps the chips it addresses."""
+    from graphmine_tpu.ops.superstep_policy import delta_rungs, step_carried_rows
+
+    _check_mesh(sg, mesh)
+    if sg.out_slot is None:
+        raise ValueError("the partition has no slot index (with_shard_slot_index)")
+    labels, rows = _mesh_job_start(
+        init_labels, mesh, sg.num_vertices, sg.chunk_size, shard_row_slots(sg)
+    )
+    labels, per_step = step_carried_rows(
+        max_iter, delta_rungs(shard_messages(sg)), shard_messages(sg) + 1,
+        rows, labels,
+        gather=lambda rows, labels: _mesh_gather_program(rows, labels, sg, mesh),
+        rewrite=lambda rows, labels, changed, cap: _mesh_rewrite_program(
+            rows, labels, changed, sg, mesh, cap=cap
+        ),
+        modes=lambda rows, labels: _mesh_modes_program(rows, labels, sg, mesh),
+        clock=clock,
+    )
+    if sg.num_vertices != sg.padded_vertices:
+        labels = labels[: sg.num_vertices]
+    return labels, per_step
 
 
 def sharded_lpa_fixpoint(

@@ -404,3 +404,109 @@ def test_without_a_sink_the_record_asks_the_device_nothing(monkeypatch):
     _, plan, _, _ = _case("path")
     assert superstep_policy.emit_device_residency(
         None, "lpa_superstep", None, plan, ("plain", "a test")) is None
+
+
+# -- a shard's share, on a mesh (ISSUE 39) ------------------------------------
+
+# graph500-25 over four chips (benchmark/configs/graphalytics-g500-25.json):
+# _proof/g500_25_x4_shapes.json, the stacked plan's per-shard class shapes
+# and the shards' message counts from a host-only build of the
+# configuration's own draw (_proof/mesh_shapes_and_k.py): no draw here
+_X4_LIMIT, _X4_IN_USE = 16_909_336_064, 1_986_000_000  # PERF.md §4: 11.7 % a chip
+_X4_SUM = 5_250_178_152
+
+
+def _g500_25_x4():
+    """``(one shard's plan by shapes, shards)``, as the mesh entry hands it
+    to the admission (``parallel/sharded.shard_plan_shapes``)."""
+    import json
+    import os
+
+    from graphmine_tpu.parallel.sharded import ShardedGraph, shard_plan_shapes
+
+    said = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "_proof", "g500_25_x4_shapes.json")))
+    d = said["shards"]
+    i32 = lambda *dims: jax.ShapeDtypeStruct(dims, np.int32)
+    sg = ShardedGraph(
+        msg_recv_local=None, msg_send=None, degrees=None,
+        num_vertices=said["num_vertices"], chunk_size=said["chunk_size"],
+        num_shards=d,
+        bucket_send=tuple(i32(d, n, w) for n, w in said["classes"]),
+        bucket_target=tuple(i32(d, n) for n, _ in said["classes"]),
+    )
+    return shard_plan_shapes(sg, max(said["messages_per_shard"])), d
+
+
+def test_the_inventory_of_a_shard_of_graph500_25_term_by_term():
+    """What ONE of four chips holds for the carried mesh job, beside what
+    the chip's compiler assigned the mesh programs compiled for four
+    described chips from these shapes (PERF.md §6, PR 39)."""
+    plan, d = _g500_25_x4()
+    v_pad, largest, s = 1 << 25, 265_143_516, 307_343_931
+    assert (d, plan.num_vertices, plan.num_messages, row_slots(plan)) == (
+        4, v_pad, largest, s)
+    assert len(plan.send_idx) == 62 and plan.hist_vertex_ids is None
+    rungs = delta_rungs(largest)
+    assert rungs == (64_732, 1_035_716, 16_571_469, 44_190_586)
+    # the gather holds its largest class as kept and as its row-major form,
+    # as on one chip; the mesh `modes` its three largest classes at once
+    # (its classes do not take turns; compiled, the two largest): the row
+    # sort of [13363, 4608] where it lies in the rows (three of the class:
+    # whole lanes), of [3197, 15552] and of [38187, 1175] (four each: 121.5
+    # and 9.2 lanes); the labels, padded, twice
+    # (as the chip tiles them: 13363 rows up to 13368; [3197, 15552] kept
+    # column-major as 3200 x 15552; [38187, 1175] as 38272 x 1176)
+    wide, wider, third = 13_368 * 4_608 * 4, 3_200 * 15_552 * 4, 38_272 * 1_176 * 4
+    labels = 8 * (v_pad + 1)
+    assert memmodel.carried_job_transients(plan, top_rung=rungs[-1], shards=d) == {
+        "gather": 2 * wide + labels,                         # compiled: 628,905,984
+        "modes": 3 * wide + 4 * wider + 4 * third + labels,  # compiled: 1,802,584,064
+        "rewrite": 32 * v_pad,             # compiled: 1,074,322,432 at most (M/16)
+    }
+    # on one chip the classes take turns: the same shapes count one class
+    # (compiled for one chip from these shapes: 629,164,032 and 1,006,589,440)
+    assert memmodel.carried_job_transients(plan, top_rung=rungs[-1]) == {
+        "gather": 2 * wide + labels, "modes": 4 * wider + labels,
+        "rewrite": 32 * v_pad}
+    assert 32 * v_pad > 20 * rungs[-1]  # the sort of V keys, not the top rung
+    inv = memmodel.carried_rows_inventory(plan, top_rung=rungs[-1], shards=d)
+    assert inv == {
+        "carried_rows": 4 * s,                           # 1.23 GB, once
+        "slot_index": 4 * (largest + v_pad + 1),         # 1.19 GB
+        "labels": 8 * v_pad, "changed_mask": v_pad,      # replicated: a chip holds all
+        "hub_histograms": 0,                             # no hubs on a mesh
+        "gather_transient": 3 * wide + 4 * wider + 4 * third + labels,
+    }
+    assert sum(inv.values()) == _X4_SUM
+
+
+@pytest.mark.parametrize("in_use,want", [
+    (_X4_IN_USE, "carried"),                    # 14.9 GB free: the cell
+    (_X4_LIMIT - _X4_SUM, "carried"),           # to the byte
+    (_X4_LIMIT - _X4_SUM + 1, "plain"),
+], ids=["beside-the-placed-plan", "exactly", "a-byte-short"])
+def test_a_shard_of_graph500_25_is_admitted_by_the_fullest_chip_s_free_bytes(in_use, want):
+    plan, d = _g500_25_x4()
+    scan, reason = admit_carried_rows(
+        plan, {"bytes_limit": _X4_LIMIT, "bytes_in_use": in_use}, shards=d)
+    assert scan == want
+    assert reason.startswith("a shard of 4, on the fullest chip: rows, held once")
+    assert f"= {_X4_SUM} B against {_X4_LIMIT - in_use} B free" in reason
+    assert "(modes; gather" in reason and "not sized" in reason
+
+
+def test_the_fullest_chip_of_a_mesh_decides():
+    """Every chip runs the one SPMD program: the admission is asked of the
+    one with the least free memory; a chip that keeps no statistics (the
+    CPU) leaves the mesh without a limit."""
+    from types import SimpleNamespace
+
+    chip = lambda limit, in_use: SimpleNamespace(memory_stats=lambda: (
+        None if limit is None else {"bytes_limit": limit, "bytes_in_use": in_use}))
+    mesh = lambda *chips: SimpleNamespace(devices=np.array(chips, dtype=object))
+    stats = superstep_policy.mesh_memory_stats(
+        mesh(chip(100, 10), chip(100, 60), chip(90, 45), chip(100, 20)))
+    assert stats == {"bytes_limit": 100, "bytes_in_use": 60}
+    assert superstep_policy.mesh_memory_stats(mesh(chip(100, 10), chip(None, 0))) is None

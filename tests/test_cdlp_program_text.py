@@ -20,9 +20,16 @@ of ``ops/lpa.py``, each pinned here; ``_label_propagation`` is the stateless
 scan alone (the text the parent's lowered to over a plan without its index,
 which the ``plain`` admission ran: pinned too). The
 other two digests stand, which is the proof that the pipeline's and WCC's
-programs did not move.
+programs did not move. PR 39 added the mesh job's programs
+(``parallel/sharded.py``: ``_mesh_gather_program``, ``_mesh_rewrite_program``
+at the top and the lowest rung, ``_mesh_modes_program``, over four devices)
+and the one-program mesh scan ``_sharded_lpa_jit``, which a ``plain``
+admission still runs and whose text that PR did not move; the nine
+one-chip digests stand: the mesh job calls the one-chip row functions and
+edits none.
 """
 
+import dataclasses
 import hashlib
 
 import jax
@@ -57,7 +64,45 @@ def _graph_and_plan():
     return g, BucketedModePlan.from_graph(g, with_send=True)
 
 
+def _lowered_on_a_mesh(name):
+    """The mesh job's programs over four devices, from the same graph
+    kept on the host: shapes and shardings are all a lowering reads."""
+    import graphmine_tpu as gm
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from graphmine_tpu.parallel import sharded
+
+    g, _ = _graph_and_plan()
+    host = build_graph(np.asarray(g.src), np.asarray(g.dst),
+                       num_vertices=g.num_vertices, to_device=False)
+    mesh = gm.make_mesh(4)
+    part = sharded.partition_graph(host, mesh=mesh, build_bucket_plan=True)
+    if name == "_sharded_lpa_jit":
+        placed = sharded.shard_graph_arrays(part, mesh)
+        return sharded._sharded_lpa_jit.lower(placed, mesh, 10, None, 0, False)
+    counts = np.diff(sharded._shard_message_offsets(
+        np.asarray(host.msg_ptr), 4, part.chunk_size))
+    part = dataclasses.replace(
+        sharded.with_shard_slot_index(part, counts),
+        msg_recv_local=None, msg_send=None, degrees=None)
+    sg = sharded.shard_graph_arrays(part, mesh, lpa_only=True)
+    put = lambda x, spec: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=NamedSharding(mesh, spec))
+    rows = put(np.empty(4 * sharded.shard_row_slots(sg), np.int32),
+               P(sharded._vertex_axes(mesh)))
+    labels = put(np.empty(sg.padded_vertices, np.int32), P())
+    if name == "_mesh_gather_program":
+        return sharded._mesh_gather_program.lower(rows, labels, sg, mesh)
+    if name == "_mesh_modes_program":
+        return sharded._mesh_modes_program.lower(rows, labels, sg, mesh)
+    changed = put(np.empty(sg.padded_vertices, np.bool_), P())
+    rung = delta_rungs(int(counts.max()))[int(name.rsplit(":", 1)[1])]
+    return sharded._mesh_rewrite_program.lower(
+        rows, labels, changed, sg, mesh, cap=rung)
+
+
 def _lowered(name):
+    if name.startswith("_mesh_") or name == "_sharded_lpa_jit":
+        return _lowered_on_a_mesh(name)
     g, plan = _graph_and_plan()
     if name == "lpa_superstep_bucketed":
         labels = jnp.arange(g.num_vertices, dtype=jnp.int32)
@@ -98,6 +143,18 @@ _PARENT_DIGESTS = {
         "bfa41019117a6942bb1f37c04b145be552b4f725ac2f89874d067a88ccd9a777",
     "_rewrite_program:3":
         "f76dd23541cb9296c6dd4e4296cad7b168df6ec5639b6908ddeb61afdebf2d7d",
+    # on a mesh of four (PR 39): the one-program scan as the parent of
+    # PR 39 lowered it, and the carried job's programs as that PR wrote them
+    "_sharded_lpa_jit":
+        "a89ef237aa464644ed93b1587389e48c5eacc6b0b0954a763d8b34a5231bd2dc",
+    "_mesh_gather_program":
+        "c28df3a4bd597485f35b51c77df0aae07be1c6de2391f7dd1b1b3ddb2e2190bf",
+    "_mesh_modes_program":
+        "0e82c253efdb430c9dbf7a297363c24323d180d72f38f327ee20c3936286cf21",
+    "_mesh_rewrite_program:0":
+        "fd1e6248a9168110370d9a9abd4983c33a736a989636a684ca7620ef421489c8",
+    "_mesh_rewrite_program:3":
+        "214f0b732100bf51068a2d7239f3a734f10bdc3b740a73d951398ab497181a79",
 }
 
 
@@ -115,7 +172,7 @@ def test_each_class_s_gather_is_in_the_gather_program_once_and_in_no_other():
     the job picks a branch on the device: the host does."""
     _, plan = _graph_and_plan()
     texts = {name: _lowered(name).as_text() for name in _PARENT_DIGESTS
-             if name.endswith("_program") or "_program:" in name}
+             if name.startswith(("_gather", "_modes", "_rewrite"))}
     assert len(texts) == 6
     for name, text in texts.items():
         assert "stablehlo.case" not in text and "stablehlo.while" not in text

@@ -318,3 +318,98 @@ def test_sharded_lpa_superstep_compiles_for_four_chips(topo, planted):
     )
     compiled = _compile(_sharded_lpa_jit, sg, mesh, 1, None, 0, False)
     assert "all-gather" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def mesh_partition(topo):
+    """``(mesh of the four described chips, a Kronecker graph's partition
+    with its slot index as shapes placed the way the entry places them,
+    the largest shard's messages)``: the benchmark's own generator at
+    scale 17 (the cell's R-MAT at 1/256 of its vertices): wide classes,
+    whose reduce is what a program holds, as at the cell's size; on the
+    planted graph's sixty classes of under 2,000 rows the compiler keeps
+    many at once, which it cannot at a size that matters."""
+    import sys
+
+    from graphmine_tpu.graph.container import build_graph
+    from graphmine_tpu.parallel.mesh import make_mesh
+    from graphmine_tpu.parallel.sharded import (
+        _shard_message_offsets,
+        _vertex_axes,
+        partition_graph,
+        with_shard_slot_index,
+    )
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark"))
+    import generators
+
+    src, dst = generators.rmat_undirected(17, 16, 0.57, 0.19, 0.19, seed=7)
+    v = 1 << 17
+    mesh = make_mesh(4, devices=topo.devices)
+    axes = _vertex_axes(mesh)
+    host = build_graph(src, dst, num_vertices=v, to_device=False)
+    sg = partition_graph(host, mesh=mesh, lpa_only=True, build_bucket_plan=True)
+    counts = np.diff(_shard_message_offsets(np.asarray(host.msg_ptr), 4, sg.chunk_size))
+    sg = with_shard_slot_index(sg, counts)
+    assert sg.out_slot.shape == (4 * counts.max(),)
+    sg = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            np.shape(a), a.dtype,
+            sharding=NamedSharding(mesh, P(axes, *[None] * (np.ndim(a) - 1))),
+        ),
+        sg,
+    )
+    return mesh, sg, int(counts.max())
+
+
+@pytest.mark.parametrize("program", ["gather", "rewrite:top", "rewrite:low", "modes"])
+def test_mesh_carried_rows_programs_compile_for_four_chips(mesh_partition, program):
+    """The mesh CDLP job's three kinds of program (ISSUE 39), each compiled
+    alone for four described chips. The rows are the donated argument of
+    ``gather`` and of every ``rewrite``, through ``jit`` and ``shard_map``:
+    the compiler aliases a chip's whole ``s32[S]`` buffer to the result
+    and copies none of its size in the chip's memory, so a chip holds its
+    rows once (flat ``[D * S]`` rows: as ``[D, S]`` the squeeze to ``[S]``
+    was a copy in and a copy out, 2.46 GB of temporaries at graph500-25). The
+    ``all_gather`` of the labels is in ``modes`` and in no other. The
+    admission counts each program's temporaries from ONE shard's shapes
+    (``parallel/sharded.shard_plan_shapes``): at or above the compiler's."""
+    from graphmine_tpu.obs.memmodel import carried_job_transients
+    from graphmine_tpu.ops.superstep_policy import delta_rungs
+    from graphmine_tpu.parallel import sharded
+
+    mesh, sg, largest = mesh_partition
+    axes = sharded._vertex_axes(mesh)
+    slots, v_pad = sharded.shard_row_slots(sg), sg.padded_vertices
+    rungs = delta_rungs(largest)
+    rows = jax.ShapeDtypeStruct(
+        (4 * slots,), jnp.int32, sharding=NamedSharding(mesh, P(axes)))
+    rep = NamedSharding(mesh, P())
+    labels = jax.ShapeDtypeStruct((v_pad,), jnp.int32, sharding=rep)
+    changed = jax.ShapeDtypeStruct((v_pad,), jnp.bool_, sharding=rep)
+    if program == "gather":
+        compiled = _compile(sharded._mesh_gather_program, rows, labels, sg, mesh)
+    elif program == "modes":
+        compiled = _compile(sharded._mesh_modes_program, rows, labels, sg, mesh)
+    else:
+        cap = rungs[-1] if program == "rewrite:top" else rungs[1]
+        compiled = _compile(
+            sharded._mesh_rewrite_program, rows, labels, changed, sg, mesh, cap=cap)
+    held, text = compiled.memory_analysis(), compiled.as_text()
+    assert " conditional(" not in text and " while(" not in text
+    assert ("all-gather" in text) == (program == "modes")
+    counted = carried_job_transients(
+        sharded.shard_plan_shapes(sg, largest), top_rung=rungs[-1], shards=4
+    )[program.split(":")[0]]
+    assert held.temp_size_in_bytes <= counted
+    if program == "modes":  # reads the rows, writes V-sized results
+        assert held.alias_size_in_bytes == 0
+        return
+    assert held.alias_size_in_bytes >= 4 * slots
+    # (a prefetch into fast memory, `S(1)`, is no copy in the chip's HBM:
+    # at this size the rows fit there, at the cell's they do not)
+    copies = [ln for ln in text.splitlines()
+              if (" copy(" in ln or " copy-start(" in ln)
+              and f"s32[{slots}]" in ln and "S(1)" not in ln]
+    assert copies == []

@@ -97,15 +97,21 @@ def test_mesh_entry_partitions_once_and_keeps_nothing_whole(mesh):
     assert leaves
     for leaf in leaves:
         # every array is split over the four devices, none committed to one
+        # (a stacked [D, ...] array a row each, the flat slot index of the
+        # carried rows, PR 39, a quarter of its length each)
         assert len(leaf.sharding.device_set) == D
-        assert leaf.addressable_shards[0].data.shape[0] == 1
+        assert leaf.addressable_shards[0].data.shape[0] * D == leaf.shape[0]
     total = sum(leaf.size for leaf in leaves)
     for dev in mesh.devices.flat:
         held = sum(s.data.size for leaf in leaves
                    for s in leaf.addressable_shards if s.device == dev)
         assert held * D == total  # a quarter each, the padding included
+    # nothing of the graph's size sits whole on one device (a chip's own
+    # piece of a placed array is its share: on this skewed draw the first
+    # shard's slot index alone is longer than the edge list)
+    pieces = {id(s.data) for leaf in leaves for s in leaf.addressable_shards}
     for arr in jax.live_arrays():
-        if arr.size >= host.num_edges:
+        if arr.size >= host.num_edges and id(arr) not in pieces:
             assert len(arr.sharding.device_set) == D
     # the cache goes with the graph
     key = id(host.msg_ptr)

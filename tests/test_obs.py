@@ -389,6 +389,50 @@ def test_schema_register_extends():
         del schema.SCHEMAS["obs_test_phase"]
 
 
+@pytest.mark.parametrize("scan", ["carried", "plain"])
+def test_the_mesh_entry_s_records_say_what_each_field_holds_on_a_mesh(scan, monkeypatch):
+    """One ``label_propagation(..., mesh=, sink=)`` call (ISSUE 39): every
+    record registered and complete; ``impl_selected`` says which job ran,
+    ``plan_build`` the index's seconds inside its own, ``device_residency``
+    one chip's share, and the carried job's ``superstep_delta`` the shards
+    and the LARGEST shard's messages; the one compiled program, which
+    counts nothing a superstep, writes none."""
+    import graphmine_tpu as gm
+    from graphmine_tpu.ops import superstep_policy
+
+    if scan == "plain":
+        monkeypatch.setattr(superstep_policy, "mesh_memory_stats",
+                            lambda mesh: {"bytes_limit": 4096, "bytes_in_use": 0})
+    rng = np.random.default_rng(39)
+    u, v = rng.integers(0, 2000, 30000), rng.integers(0, 2000, 30000)
+    host = gm.build_graph(u, v, num_vertices=2000, to_device=False)
+    m = MetricsSink()
+    gm.label_propagation(host, max_iter=4, mesh=gm.make_mesh(4), sink=m)
+    assert schema.validate_records(m.records) == []
+    by_phase = {r["phase"]: r for r in m.records}
+    assert by_phase["impl_selected"]["scan"] == scan
+    assert by_phase["impl_selected"]["scan_reason"].startswith("a shard of 4")
+    build = by_phase["plan_build"]
+    assert 0.0 <= build["index_seconds"] <= build["seconds"]
+    held = by_phase["device_residency"]
+    assert (held["shards"], held["scan"], held["graph_bytes"]) == (4, scan, 0)
+    padded = 4 * 504  # four shards of 500 vertices, each up to a multiple of 8
+    assert held["labels_bytes"] == 8 * padded and held["plan_bytes"] > 0
+    assert held["bytes_limit"] == (4096 if scan == "plain" else None)
+    largest = by_phase["exchange"]["messages_per_shard_max"]
+    if scan == "plain":
+        assert held["rows_bytes"] == held["slot_index_bytes"] == 0
+        assert "superstep_delta" not in by_phase
+        return
+    assert held["slot_index_bytes"] == 4 * (padded + 1 + largest)
+    assert held["rows_bytes"] == 4 * by_phase["exchange"]["padded_slots_per_shard"]
+    delta = by_phase["superstep_delta"]
+    assert delta["shards"] == 4 and delta["num_messages"] == largest
+    assert delta["rungs"] == sorted({largest // d for d in (4096, 256, 16, 6)} - {0})
+    assert len(delta["branch"]) == len(delta["seconds"]) == 4
+    assert max(delta["changed_messages"]) <= largest  # one shard's K, not the sum
+
+
 # ---------------------------------------------------------------------------
 # on-device superstep telemetry (sharded API)
 # ---------------------------------------------------------------------------
