@@ -1,14 +1,26 @@
 """Driver ``graph_kernel_job``: one job is one whole run of a graph kernel
-through the program's public entry point, on a graph built once in set-up,
-for kernels that say themselves when they are done.
+through the program's public entry point, on a graph built once in set-up.
 
-``kernel_job`` times a stated count of supersteps (CDLP's 10). Here the
-count is the program's answer: a job runs to its fixpoint, reports the
-supersteps it took, and the driver states the last job's count as the fact
-``iterations``. Which kernel runs is a row of ``ALGORITHMS``, keyed by the
-traffic file's ``algorithm``: how to run it, its plain reference, its
-control and the name of its comparison. The next kernel is a row and a
-reference, not a driver.
+Which kernel runs is a file, ``benchmark/algorithms/<name>.py``, named by the
+traffic file's ``algorithm`` and loaded by path, as ``run.py`` loads drivers
+and readers. The module states
+
+- ``run(graph, sink, traffic) -> (answer on the device, supersteps)``: a
+  kernel that says itself when it is done (WCC, to its fixpoint) returns the
+  supersteps it took; one that runs a stated count returns the traffic
+  file's ``iterations``;
+- ``reference(u, v, num_vertices, traffic)``: the plain answer;
+- ``control(u, v, num_vertices, traffic)``: an answer with one stated
+  guarantee broken, or computed in the next lower precision;
+- ``compare(got, want) -> list of check records``: each with ``check``,
+  ``value``, ``limit`` and ``ok``; an exact answer states the limit 0, a
+  float answer the tolerance its source states.
+
+The next kernel is such a file, a reference and a traffic file, not a
+driver. What is common stays here: set-up, the timed job, ``evps``, the
+records, the facts, and the check that the jobs of one window agree on
+their supersteps. The last job's count is the fact ``iterations``, as
+``kernel_job`` states CDLP's 10.
 
 The superstep family is whatever ``auto`` resolves, the driver pins none.
 The plan is built by the warm-up job and cached by the program per graph,
@@ -19,66 +31,37 @@ loading from processing time. The warm-up job alone carries a
 
 from __future__ import annotations
 
+import os
 import time
-from typing import Callable, NamedTuple
 
 import numpy as np
 
 import generators
-import references
-import references_wcc
 
 # A superstep is quiet when it moves the label of under this share of the
 # vertices that have an edge: a frontier would not run it at full width.
 QUIET_SHARE = 0.01
 
-
-class Algorithm(NamedTuple):
-    run: Callable        # (graph, sink) -> (answer on the device, supersteps)
-    reference: Callable  # (u, v, num_vertices) -> the answer, exact
-    control: Callable    # (u, v, num_vertices) -> an answer with one guarantee broken
-    check: str           # the name of the comparison
-    classes: str         # what the distinct values of the answer are called
+_ALGORITHMS = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "algorithms")
 
 
-def _run_wcc(graph, sink):
-    import graphmine_tpu as gm
-
-    return gm.connected_components(
-        graph, plan="auto", return_iterations=True, sink=sink)
-
-
-def _wcc_reference(u, v, num_vertices: int):
-    return references.canonical_partition(references.scipy_cc(u, v, num_vertices))
-
-
-def _wcc_control(u, v, num_vertices: int):
-    """The fixpoint guarantee broken: an engine that stops after two
-    supersteps."""
-    return references_wcc.numpy_min_label(u, v, num_vertices, max_supersteps=2)[0]
-
-
-ALGORITHMS = {
-    "wcc": Algorithm(_run_wcc, _wcc_reference, _wcc_control,
-                     "wcc_label_mismatches", "components"),
-}
-
-
-def _timed(algorithm: Algorithm, graph, sink=None):
+def _timed(algorithm, graph, traffic, sink=None):
     """One job, ended by a sync on both of its results."""
     t0 = time.perf_counter()
-    answer, supersteps = algorithm.run(graph, sink)
+    answer, supersteps = algorithm.run(graph, sink, traffic)
     answer.block_until_ready()
     supersteps = int(supersteps)
     return answer, supersteps, time.perf_counter() - t0
 
 
 def setup(ctx) -> dict:
-    name = ctx["traffic"]["algorithm"]
-    if name not in ALGORITHMS:
-        raise ValueError(f"graph_kernel_job has no algorithm {name!r}; "
-                         f"have {sorted(ALGORITHMS)}")
-    algorithm = ALGORITHMS[name]
+    traffic = ctx["traffic"]
+    name = traffic["algorithm"]
+    have = sorted(f[:-3] for f in os.listdir(_ALGORITHMS) if f.endswith(".py"))
+    if name not in have:  # before any input is made
+        raise ValueError(f"graph_kernel_job has no algorithm {name!r}; have {have}")
+    algorithm = ctx["load_module"]("algorithms", name)
 
     import graphmine_tpu as gm
     from graphmine_tpu.pipeline.metrics import MetricsSink
@@ -97,7 +80,7 @@ def setup(ctx) -> dict:
     touched[v] = True
     vertices_with_edge = int(touched.sum())
     sink = MetricsSink()
-    _, supersteps, warm_s = _timed(algorithm, graph, sink)  # builds the plan too
+    _, supersteps, warm_s = _timed(algorithm, graph, traffic, sink)  # builds the plan too
     plan_s = sum(r.get("seconds", 0.0) for r in sink.records
                  if r.get("phase") == "plan_build")
     family = [r.get("impl") for r in sink.records
@@ -129,7 +112,8 @@ def setup(ctx) -> dict:
 
 
 def job(state, index: int) -> dict:
-    state["answer"], supersteps, seconds = _timed(state["algorithm"], state["graph"])
+    state["answer"], supersteps, seconds = _timed(
+        state["algorithm"], state["graph"], state["ctx"]["traffic"])
     state["iterations"] = supersteps
     return {"seconds": seconds, "supersteps": supersteps}
 
@@ -157,21 +141,19 @@ def facts(state) -> dict:
 
 
 def check(state, jobs, control: bool) -> list:
-    """Every value the window's last job produced against the plain
-    reference's, over the whole vertex space at the timed size. The answer
-    is integers and stated exact: the limit is 0. A job that reports no
-    superstep, or jobs of one window that disagree on how many the same
-    graph took, fail too: the count is what two per-layer metrics divide by."""
-    algorithm = state["algorithm"]
+    """What the window's last job produced against the plain reference's, by
+    the algorithm's own ``compare``, over the whole vertex space at the
+    timed size. A job that reports no superstep, or jobs of one window that
+    disagree on how many the same graph took, fail too: the count is what
+    two per-layer metrics divide by."""
+    algorithm, traffic = state["algorithm"], state["ctx"]["traffic"]
     u, v, n = state["u"], state["v"], state["num_vertices"]
-    want = algorithm.reference(u, v, n)
-    got = algorithm.control(u, v, n) if control else np.asarray(state["answer"])
-    bad = int((got != want).sum())
+    want = algorithm.reference(u, v, n, traffic)
+    got = (algorithm.control(u, v, n, traffic) if control
+           else np.asarray(state["answer"]))
     counts = [j["supersteps"] for j in jobs]
     odd = sum(c <= 0 or c != counts[-1] for c in counts)
-    return [
-        {"check": algorithm.check, "value": bad, "limit": 0, "ok": bad == 0,
-         "compared": n, algorithm.classes: int(len(np.unique(want)))},
+    return algorithm.compare(got, want) + [
         {"check": "jobs_that_disagree_on_supersteps", "value": odd, "limit": 0,
          "ok": odd == 0, "supersteps": counts[-1], "jobs": len(counts)},
     ]

@@ -24,9 +24,10 @@ takes 20 to 28 GB for a program of this size, frees it, and glibc keeps it
 20.2 GB resident before, 1.6 GB after, 1.2 s). And the warm-up job's
 program records are handed on: ``facts()`` states what the device keeps for
 this graph between jobs (``device_residency``), which scan was admitted,
-and how many supersteps rewrote rows instead of gathering them anew
-(``superstep_delta``). A program that writes neither record states neither
-fact.
+how many supersteps rewrote rows instead of gathering them anew and what
+one that gathers every row anew costs (``superstep_delta``), and how the
+plan's rows are padded (``plan_build``). A program that writes no such
+record states no such fact.
 
 A program that sizes nothing against the device (it registers no
 ``device_residency`` record) is turned away before any input is made: at
@@ -40,6 +41,7 @@ import ctypes
 import importlib.util
 import inspect
 import os
+import statistics
 import time
 
 import numpy as np
@@ -83,9 +85,14 @@ def _memory(device) -> dict:
 
 
 def _program_facts(records: list) -> dict:
-    """What the warm-up job's records say of the device and of the scan."""
+    """What the warm-up job's records say of the device, of the scan and of
+    the plan. Each fact is left out where its record, or the key it reads,
+    is missing."""
     by_phase = {r["phase"]: r for r in records}
     facts = {}
+    slots = by_phase.get("plan_build", {}).get("padded_slots_per_message")
+    if slots is not None:
+        facts["padded_slots_per_message"] = slots
     held = by_phase.get("device_residency")
     if held is not None:
         facts["scan"] = held["scan"]
@@ -96,6 +103,12 @@ def _program_facts(records: list) -> dict:
     delta = by_phase.get("superstep_delta")
     if delta is not None:
         facts["sparse_supersteps"] = sum(b != "full" for b in delta["branch"])
+        # the median: the first full superstep of a warm-up job loads its
+        # programs; the stateless scan writes no seconds
+        full = [s for s, b in zip(delta.get("seconds", ()), delta["branch"])
+                if b == "full"]
+        if full:
+            facts["full_superstep_seconds"] = statistics.median(full)
     return facts
 
 

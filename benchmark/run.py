@@ -57,7 +57,7 @@ def _named(name: str) -> str:
 
 def load_module(kind: str, name: str):
     """``benchmark/<kind>/<name>.py``, loaded by file so that a later PR
-    adds a driver or a reader as a file and edits nothing."""
+    adds a driver, a reader or an algorithm as a file and edits nothing."""
     path = os.path.join(HERE, kind, _named(name) + ".py")
     spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
     module = importlib.util.module_from_spec(spec)
@@ -214,7 +214,7 @@ def main() -> int:
             "config": cell["config"], "traffic": cell["traffic"],
             "sizes": cell["config"]["rehearsal"] if args.rehearse else cell["config"],
             "seed": args.seed % (1 << 63), "scratch": scratch,
-            "chips": cell["chips"], "say": say,
+            "chips": cell["chips"], "say": say, "load_module": load_module,
         }
         state = driver.setup(ctx)
         setup_s = time.perf_counter() - _T0

@@ -30,8 +30,11 @@ def leaf_events(events) -> list[Event]:
     """Events of one timeline that contain no other event. A ``while`` or a
     ``call`` on the device spans its whole body, idle time between the
     body's operations included: counting it as busy would hide that time,
-    so only what runs innermost counts."""
-    ordered = sorted(events, key=lambda e: (e[1], -(e[2] - e[1])))
+    so only what runs innermost counts. An event that ends where it starts
+    holds no time: it is neither a leaf nor a child, so the operation
+    around it stays a leaf."""
+    ordered = sorted((e for e in events if e[2] > e[1]),
+                     key=lambda e: (e[1], -(e[2] - e[1])))
     leaves, stack = [], []  # stack of [event, has_child]
     for ev in ordered:
         while stack and stack[-1][0][2] <= ev[1]:

@@ -8,16 +8,16 @@ import json
 import os
 import re
 import shutil
-import subprocess
 import sys
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-BENCH_DIR = os.path.join(REPO, "benchmark")
+from _bench import BENCH_DIR, REPO, Bench, lines as _lines, run as _run
+from _bench import bench, grown_root  # noqa: F401  (fixtures)
+
 RUN = os.path.join(BENCH_DIR, "run.py")
-with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
-    BENCH = json.load(_f)
+ACCEPTED = Bench()
+BENCH = ACCEPTED.json
 CELLS = [w["name"] for w in BENCH["workloads"]]
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -27,75 +27,83 @@ import roofline  # noqa: E402
 import trace_reduce  # noqa: E402
 
 
-def _run(*argv, code=None, timeout=900):
-    """run.py (or ``code`` that ends by running it) on one CPU device."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
-    cmd = [sys.executable, RUN] if code is None else [sys.executable, "-c", code]
-    return subprocess.run([*cmd, *argv], capture_output=True, text=True, env=env,
-                          timeout=timeout, cwd=REPO)
-
-
-def _lines(out):
-    return [json.loads(line) for line in out.stdout.splitlines()
-            if line.startswith("{")]
-
-
 def _has_result(out) -> bool:
     return any("correct" in r and "metrics" in r and "device" in r
                for r in _lines(out))
 
 
-def _reporting(metric, end_to_end_of):
+def _reporting(bench, metric) -> list:
     """The cells a per-layer metric is read in."""
     return metric.get("workloads") or [
-        c for c in CELLS if metric["moves"] in end_to_end_of[c]]
-
-
-END_TO_END_OF = {
-    c: {m["name"] for m in BENCH["end_to_end"]
-        if "workloads" not in m or c in m["workloads"]}
-    for c in CELLS
-}
+        w["name"] for w in bench.json["workloads"]
+        if metric["moves"] in bench.end_to_end_of(w["name"])]
 
 
 # -- BENCHMARK.json and the files it names ------------------------------------
 
 
-def test_named_files_exist_and_names_are_well_formed():
-    assert BENCH["command"][1] == "benchmark/run.py" and os.path.exists(RUN)
-    for path in BENCH["paths"]:
+def test_named_files_exist_and_names_are_well_formed(bench):
+    b = bench.json
+    assert b["command"][1] == "benchmark/run.py"
+    assert os.path.exists(os.path.join(bench.root, b["command"][1]))
+    for path in b["paths"]:
         assert os.path.isdir(os.path.join(REPO, path))
-    for config in BENCH["configs"]:
+    for config in b["configs"]:
         assert NAME.match(config["name"])
         assert len(config["source"]) <= 200
-        with open(os.path.join(REPO, config["file"])) as f:
+        with open(os.path.join(bench.root, config["file"])) as f:
             body = json.load(f)
         assert body["source"] == config["source"]
         assert body["reduced"] == config["reduced"]
         assert "rehearsal" in body and body["chips"] in (1, 4)
-    for cell in BENCH["workloads"]:
+    files = [c["file"] for c in b["configs"]]
+    assert len(set(files)) == len(files)  # no two configurations share a file
+    for cell in b["workloads"]:
         assert NAME.match(cell["name"]) and NAME.match(cell["traffic"])
         assert len(cell["why"]) <= 200
-        with open(os.path.join(BENCH_DIR, "traffic", cell["traffic"] + ".json")) as f:
-            driver = json.load(f)["driver"]
-        assert os.path.exists(os.path.join(BENCH_DIR, "drivers", driver + ".py"))
-    for metric in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert cell["chips"] == bench.data(
+            "configs", bench.config(cell["config"])["file"].rsplit("/", 1)[-1])["chips"]
+        traffic = bench.data("traffic", cell["traffic"] + ".json")
+        assert os.path.exists(
+            os.path.join(bench.dir, "drivers", traffic["driver"] + ".py"))
+        if traffic["driver"] == "graph_kernel_job":  # a kernel is a file
+            assert os.path.exists(
+                os.path.join(bench.dir, "algorithms", traffic["algorithm"] + ".py"))
+    pairs = [(w["config"], w["traffic"]) for w in b["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+    for metric in b["end_to_end"] + b["per_layer"]:
         assert NAME.match(metric["name"]) and UNIT.match(metric["unit"])
         assert metric["better"] in ("lower", "higher")
-    assert "setup_s" in {m["name"] for m in BENCH["end_to_end"]}
-    assert all(END_TO_END_OF[c] - {"setup_s"} for c in CELLS)
+        listed = metric.get("workloads", [])
+        assert len(set(listed)) == len(listed)  # a cell stands once in a list
+        for cell in listed:
+            bench.cell(cell)
+    assert "setup_s" in {m["name"] for m in b["end_to_end"]}
+    assert all(bench.end_to_end_of(w["name"]) - {"setup_s"} for w in b["workloads"])
+
+
+def _moves_what_each_of_its_cells_reports(bench, metric):
+    spec = bench.reader_of(metric["name"])
+    assert os.path.exists(os.path.join(bench.dir, "readers", spec["reader"] + ".py"))
+    if "bytes_module" in spec.get("args", {}):  # a kernel's least bytes is a file
+        assert os.path.exists(
+            os.path.join(bench.dir, spec["args"]["bytes_module"] + ".py"))
+    cells = _reporting(bench, metric)
+    assert cells, "a per-layer metric that no cell reports"
+    for cell in cells:
+        assert metric["moves"] in bench.end_to_end_of(cell)
 
 
 @pytest.mark.parametrize("metric", BENCH["per_layer"], ids=lambda m: m["name"])
 def test_per_layer_metric_moves_what_each_of_its_cells_reports(metric):
-    with open(os.path.join(BENCH_DIR, "layer_metrics", metric["name"] + ".json")) as f:
-        reader = json.load(f)["reader"]
-    assert os.path.exists(os.path.join(BENCH_DIR, "readers", reader + ".py"))
-    cells = _reporting(metric, END_TO_END_OF)
-    assert cells, "a per-layer metric that no cell reports"
-    for cell in cells:
-        assert metric["moves"] in END_TO_END_OF[cell]
+    _moves_what_each_of_its_cells_reports(ACCEPTED, metric)
+
+
+def test_every_per_layer_metric_of_the_grown_benchmark_does_too(grown_root):
+    grown = Bench(grown_root)
+    assert {m["name"] for m in grown.json["per_layer"]} > {m["name"] for m in BENCH["per_layer"]}
+    for metric in grown.json["per_layer"]:
+        _moves_what_each_of_its_cells_reports(grown, metric)
 
 
 # -- run.py off the chip ------------------------------------------------------
@@ -120,9 +128,10 @@ def test_rehearsal_exits_4_and_prints_no_result(cell, trace):
     assert last["rehearsal"] == "passed"
     wanted = (
         {m["name"] for m in BENCH["per_layer"]
-         if cell in _reporting(m, END_TO_END_OF) and m["source"] != "device_trace"
-         and m["name"] != "peak_hbm_share"}  # the CPU reports no memory statistics
-        if trace == "1" else END_TO_END_OF[cell]
+         if cell in _reporting(ACCEPTED, m) and m["source"] != "device_trace"
+         # the CPU reports no memory statistics, whatever the metric is called
+         and ACCEPTED.reader_of(m["name"])["reader"] != "peak_memory_share"}
+        if trace == "1" else ACCEPTED.end_to_end_of(cell)
     )
     assert wanted <= set(last["metrics"]), last["metrics"]
     checks = [r for r in _lines(out) if "check" in r]
@@ -206,7 +215,7 @@ def test_a_cell_a_configuration_and_a_metric_are_added_as_files(tmp_path):
     (root / "benchmark" / "traffic" / "dummy-traffic.json").write_text(json.dumps(traffic))
     (root / "benchmark" / "layer_metrics" / "dummy_job_ms.json").write_text(json.dumps(
         {"reader": "job_seconds_per", "args": {"per": "iterations", "scale": 3000.0}}))
-    bench = json.loads(json.dumps(BENCH))
+    bench = json.loads(json.dumps(BENCH))  # a copy: the entries below are appended
     bench["configs"].append({
         "name": "dummy-config", "source": config["source"],
         "file": "benchmark/configs/dummy-config.json", "reduced": [], "why": "test"})
@@ -246,6 +255,39 @@ def test_union_merges_overlapping_and_touching_intervals():
 def test_leaf_events_leave_out_the_loop_that_spans_its_body():
     assert [e[0] for e in trace_reduce.leaf_events(DEVICE)] == \
         ["fusion.1", "fusion.2", "fusion.1", "copy"]
+
+
+# the same loop with the markers the profiler writes inside an operation: an
+# event that ends where it starts, in the body's second fusion and in the copy
+MARKED = DEVICE + [("marker", 3.0, 3.0), ("marker", 12.5, 12.5), ("marker", 15.0, 15.0)]
+
+
+def test_an_event_of_no_duration_is_neither_a_leaf_nor_a_child():
+    """It holds no time. Taken for a child, it made the operation around it
+    a parent, and the whole operation was booked idle."""
+    assert trace_reduce.leaf_events(MARKED) == trace_reduce.leaf_events(DEVICE)
+    # the copy at 12-13 s stays busy with a marker inside it
+    assert ("copy", 12.0, 13.0) in trace_reduce.leaf_events(
+        [("copy", 12.0, 13.0), ("marker", 12.0, 12.0), ("marker", 13.0, 13.0)])
+    got = trace_reduce.reduce_events({"/device:TPU:0": MARKED}, HOST, (0.0, 20.0))
+    assert got == trace_reduce.reduce_events({"/device:TPU:0": DEVICE}, HOST, (0.0, 20.0))
+    assert got["busy_s"] == pytest.approx(4.5)
+    assert "marker" not in [name for name, _ in got["device_ops"]]
+
+
+def test_nested_loops_read_as_before():
+    """A ``while`` in a ``while`` with events that share a start or an end:
+    only what runs innermost counts, as before."""
+    nested = [("while.outer", 0.0, 10.0), ("while.inner", 0.0, 4.0),
+              ("fusion.1", 0.0, 1.0), ("fusion.2", 3.0, 4.0),
+              ("call", 6.0, 10.0), ("fusion.3", 8.0, 10.0), ("copy", 10.0, 11.0)]
+    assert trace_reduce.leaf_events(nested) == [
+        ("fusion.1", 0.0, 1.0), ("fusion.2", 3.0, 4.0), ("fusion.3", 8.0, 10.0),
+        ("copy", 10.0, 11.0)]
+    busy, gaps = trace_reduce.busy_and_gaps(
+        trace_reduce.leaf_events(nested), (0.0, 12.0))
+    assert busy == pytest.approx(5.0)
+    assert gaps == [(4.0, 8.0), (1.0, 3.0), (11.0, 12.0)]
 
 
 def test_reduce_events_gives_the_hand_computed_busy_gaps_and_operations():

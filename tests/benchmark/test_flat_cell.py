@@ -3,68 +3,52 @@ configuration is ``graphalytics-g500-24``'s but for the source, the three
 quadrant weights, ``reduced`` and ``assumed``; the draw is uniform; the cell
 rehearses with both values of ``--trace`` and every listed metric is read;
 its control comes out not correct; a program without the two new records
-leaves the two new metrics out; and the cell's name sits once in the five
-shared lists, with ``wcc-g500-22`` still last."""
+leaves the two new metrics out; and the cell's name stands once in each list
+it reports under: the five shared ones, the three it shares with
+``cdlp-g500-24`` under the accepted names (its twins and its driver went
+with ISSUE 40) and the two that came with it."""
 
-import importlib.util
-import json
 import os
-import subprocess
 import sys
 
 import numpy as np
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-BENCH_DIR = os.path.join(REPO, "benchmark")
+from _bench import BENCH_DIR, Bench, lines as _lines, load as _load, run as _run
+from _bench import bench, grown_root  # noqa: F401  (fixtures)
+
 RUN = os.path.join(BENCH_DIR, "run.py")
 CELL, CONFIG, TRAFFIC = "cdlp-urand-24", "gap-urand-24", "cdlp-batch-flat"
 SHARED = ("evps", "superstep_ms", "superstep_roofline_share",
           "device_idle_share.kernel", "graph_build_s.setup")
-TWINS = {"cdlp_sparse_superstep_share.flat": "cdlp_sparse_superstep_share",
-         "plan_resident_gb.flat": "plan_resident_gb",
-         "peak_hbm_share.flat": "peak_hbm_share.kernel"}
-NEW_COUNTERS = ("full_superstep_ms", "plan_slots_per_message")
-
-with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
-    BENCH = json.load(_f)
+# read under the names cdlp-g500-24 brought: one reader, one list, two cells
+WITH_THE_SIBLING = {
+    "cdlp_sparse_superstep_share": {
+        "reader": "fact_value",
+        "args": {"fact": "sparse_supersteps", "over": "iterations", "scale": 100.0}},
+    "plan_resident_gb": {
+        "reader": "fact_value", "args": {"fact": "resident_bytes", "scale": 1e-09}},
+    "peak_hbm_share.kernel": {"reader": "peak_memory_share"},
+}
+NEW_COUNTERS = {
+    "full_superstep_ms": {
+        "reader": "fact_value",
+        "args": {"fact": "full_superstep_seconds", "scale": 1000.0}},
+    "plan_slots_per_message": {
+        "reader": "fact_value", "args": {"fact": "padded_slots_per_message"}},
+}
+_json = Bench().data
 
 sys.path.insert(0, BENCH_DIR)
 import generators  # noqa: E402
 
 
-def _load(kind, name):
-    spec = importlib.util.spec_from_file_location(
-        f"flat_cell_{kind}_{name}", os.path.join(BENCH_DIR, kind, name + ".py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-def _json(*parts):
-    with open(os.path.join(BENCH_DIR, *parts)) as f:
-        return json.load(f)
-
-
-def _run(*argv, code=None, timeout=900):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
-    cmd = [sys.executable, RUN] if code is None else [sys.executable, "-c", code]
-    return subprocess.run([*cmd, *argv], capture_output=True, text=True, env=env,
-                          timeout=timeout, cwd=REPO)
-
-
-def _lines(out):
-    return [json.loads(line) for line in out.stdout.splitlines()
-            if line.startswith("{")]
-
-
 # -- the configuration and the cell -------------------------------------------
 
 
-def test_the_configuration_is_graph500_24_s_but_for_the_skew():
-    config = _json("configs", CONFIG + ".json")
-    sibling = _json("configs", "graphalytics-g500-24.json")
+def test_the_configuration_is_graph500_24_s_but_for_the_skew(bench):
+    config = bench.data("configs", CONFIG + ".json")
+    sibling = bench.data("configs", "graphalytics-g500-24.json")
     for key in ("generator", "dataset_seed", "guarantees", "chips"):
         assert config[key] == sibling[key], key  # the five guarantees word for word
     assert len(config["guarantees"]) == 5
@@ -87,48 +71,74 @@ def test_the_configuration_is_graph500_24_s_but_for_the_skew():
         assert word in config["deployment"]
 
 
-def test_the_cell_is_one_chip_under_the_flat_batch_traffic():
-    cells = {w["name"]: w for w in BENCH["workloads"]}
-    assert cells[CELL] == dict(cells[CELL], config=CONFIG, traffic=TRAFFIC, chips=1)
-    assert BENCH["workloads"][-1]["name"] == CELL and len(cells[CELL]["why"]) <= 200
-    entry = BENCH["configs"][-1]
-    assert entry["name"] == CONFIG and entry["reduced"] == ["scale"]
-    assert entry["file"] == f"benchmark/configs/{CONFIG}.json"
-    assert entry["source"] == _json("configs", CONFIG + ".json")["source"]
-    # the loop rule word for word; the driver alone differs
-    assert _json("traffic", TRAFFIC + ".json") == dict(
-        _json("traffic", "cdlp-batch-large.json"), driver="kernel_job_flat")
-    assert [w["chips"] for w in BENCH["workloads"]].count(4) == 1
-    assert len(BENCH["workloads"]) == 6 and len(BENCH["configs"]) == 6
+def test_the_cell_is_one_chip_under_the_flat_batch_traffic(bench):
+    cell = bench.cell(CELL)
+    assert cell == dict(cell, config=CONFIG, traffic=TRAFFIC, chips=1)
+    assert len(cell["why"]) <= 200
+    assert bench.config(CONFIG) == dict(
+        bench.config(CONFIG), file=f"benchmark/configs/{CONFIG}.json",
+        reduced=["scale"], source=bench.data("configs", CONFIG + ".json")["source"])
+    # the loop rule, the driver and the algorithm word for word the sibling's:
+    # the traffic file keeps its name because the ledger knows the cell by it
+    assert bench.data("traffic", TRAFFIC + ".json") == \
+        bench.data("traffic", "cdlp-batch-large.json")
+    assert not os.path.exists(os.path.join(bench.dir, "drivers", "kernel_job_flat.py"))
 
 
-def test_the_name_sits_once_in_the_five_shared_lists_and_wcc_is_still_last():
-    listing = {m["name"]: m.get("workloads", []) for m in
-               BENCH["end_to_end"] + BENCH["per_layer"]}
-    for name in SHARED:
-        assert listing[name].count(CELL) == 1 and listing[name][-1] == "wcc-g500-22"
-        assert listing[name].index(CELL) == listing[name].index("cdlp-g500-24") + 1
-    by_name = {m["name"]: m for m in BENCH["per_layer"]}
-    for name in (*TWINS, *NEW_COUNTERS):
-        assert listing[name] == [CELL] and by_name[name]["moves"] == "evps"
-    reporting = {name for name, cells in listing.items() if CELL in cells}
-    assert reporting == {*SHARED, *TWINS, *NEW_COUNTERS}  # evps + nine per layer
-    # the lists that test_large_cell.py holds to one name are as they were
-    for name in TWINS.values():
-        assert listing[name] == ["cdlp-g500-24"]
-    assert [m["name"] for m in BENCH["per_layer"]][-5:] == [*TWINS, *NEW_COUNTERS]
+def test_the_name_stands_once_in_every_list_the_cell_reports_under(bench):
+    wanted = {*SHARED, *WITH_THE_SIBLING, *NEW_COUNTERS}  # evps + nine per layer
+    assert bench.reported_by(CELL) == wanted
+    for name in wanted:
+        assert bench.lists(name, CELL), name
+    for name, reader in {**WITH_THE_SIBLING, **NEW_COUNTERS}.items():
+        assert bench.reader_of(name) == reader
+        assert bench.metric(name)["moves"] == "evps"
+    # no twin is left: not an entry, not a file
+    names = {m["name"] for m in bench.json["per_layer"]}
+    files = os.listdir(os.path.join(bench.dir, "layer_metrics"))
+    assert not [n for n in (*names, *files) if ".flat" in n]
 
 
-@pytest.mark.parametrize("twin,accepted", sorted(TWINS.items()))
-def test_a_twin_reads_what_the_accepted_metric_reads(twin, accepted):
-    """Three lists are pinned to ``cdlp-g500-24`` alone by an accepted test,
-    so this cell reports the same readings under names of its own: the same
-    reader, the same arguments, the same unit, layer and source."""
-    assert _json("layer_metrics", twin + ".json") == \
-        _json("layer_metrics", accepted + ".json")
-    by_name = {m["name"]: m for m in BENCH["per_layer"]}
-    for key in ("unit", "better", "source", "layer", "moves"):
-        assert by_name[twin][key] == by_name[accepted][key], key
+@pytest.fixture(scope="module")
+def traced_rehearsal():
+    out = _run("--workload", CELL, "--seed", "2147483701", "--seconds", "1",
+               "--trace", "1", "--rehearse")
+    assert out.returncode == 4, out.stderr[-3000:]
+    return _lines(out)
+
+
+@pytest.mark.parametrize("accepted", sorted(WITH_THE_SIBLING))
+def test_an_accepted_metric_lists_both_cells_and_reads_on_this_one(
+        accepted, traced_rehearsal):
+    """The three readings this cell shares with ``cdlp-g500-24`` stand under
+    the names that cell brought (PR 33): one entry, one reader file, both
+    cells in its list, one driver that hands both the same facts, and the
+    value read in this cell's rehearsal is what its own records say."""
+    bench = Bench()
+    assert bench.lists(accepted, CELL) and bench.lists(accepted, "cdlp-g500-24")
+    assert bench.reader_of(accepted) == WITH_THE_SIBLING[accepted]
+    for cell in (CELL, "cdlp-g500-24"):
+        traffic = bench.data("traffic", bench.cell(cell)["traffic"] + ".json")
+        assert traffic["driver"] == "kernel_job_large"
+    said = next(r for r in traced_rehearsal if "device_residency" in r)
+    held, delta = said["device_residency"], said["superstep_delta"]
+    metrics = traced_rehearsal[-1]["metrics"]
+    want = {
+        "cdlp_sparse_superstep_share":
+            10.0 * sum(b != "full" for b in delta["branch"]),
+        "plan_resident_gb": 1e-9 * (held["graph_bytes"] + held["plan_bytes"]
+                                    + held["slot_index_bytes"]),
+        "peak_hbm_share.kernel": None,  # a CPU keeps no memory statistics
+    }[accepted]
+    if want is None:
+        assert accepted not in metrics
+        memory = {"memory_peak_bytes": 13_845_838_336,
+                  "memory_limit_bytes": 16_909_336_064}
+        read = _load("readers", bench.reader_of(accepted)["reader"]).read
+        assert read({}, {"memory": memory}) == pytest.approx(81.8828, abs=1e-3)
+    else:
+        assert metrics[accepted]["value"] == pytest.approx(want)
+    assert not [name for name in metrics if ".flat" in name]
 
 
 # -- the draw -------------------------------------------------------------------
@@ -166,11 +176,14 @@ def test_the_draw_is_uniform(scale):
 
 
 @pytest.mark.parametrize("trace", ["0", "1"])
-def test_the_cell_rehearses_and_every_listed_metric_is_read(trace):
-    out = _run("--workload", CELL, "--seed", "2147483700", "--seconds", "1",
-               "--trace", trace, "--rehearse")
-    assert out.returncode == 4, out.stderr[-3000:]
-    lines = _lines(out)
+def test_the_cell_rehearses_and_every_listed_metric_is_read(trace, request):
+    if trace == "1":
+        lines = request.getfixturevalue("traced_rehearsal")
+    else:
+        out = _run("--workload", CELL, "--seed", "2147483700", "--seconds", "1",
+                   "--trace", trace, "--rehearse")
+        assert out.returncode == 4, out.stderr[-3000:]
+        lines = _lines(out)
     said = next(r for r in lines if "device_residency" in r)
     assert said["family"] == "bucketed" and said["scan"] == "carried"
     # the reason states the sum it compared, by program
@@ -186,17 +199,17 @@ def test_the_cell_rehearses_and_every_listed_metric_is_read(trace):
         metrics = last["metrics"]
         # nine per layer, less the two a CPU cannot read (a trace of the
         # device, the allocator's peak)
-        assert set(metrics) == {*SHARED, *TWINS, *NEW_COUNTERS} - {
+        assert set(metrics) == {*SHARED, *WITH_THE_SIBLING, *NEW_COUNTERS} - {
             "evps", "superstep_roofline_share", "device_idle_share.kernel",
-            "peak_hbm_share.flat"}
+            "peak_hbm_share.kernel"}
         assert metrics["full_superstep_ms"] == {
             "value": pytest.approx(1000.0 * float(np.median(full))), "unit": "ms"}
         assert metrics["plan_slots_per_message"]["unit"] == "ratio"
         assert 1.0 < metrics["plan_slots_per_message"]["value"] < 1.05
-        assert metrics["cdlp_sparse_superstep_share.flat"] == {
+        assert metrics["cdlp_sparse_superstep_share"] == {
             "value": pytest.approx(10.0 * (10 - len(full))), "unit": "%"}
         resident = held["graph_bytes"] + held["plan_bytes"] + held["slot_index_bytes"]
-        assert metrics["plan_resident_gb.flat"] == {
+        assert metrics["plan_resident_gb"] == {
             "value": pytest.approx(resident * 1e-9), "unit": "GB"}
     else:
         assert set(last["metrics"]) == {"evps", "setup_s"}
@@ -247,40 +260,11 @@ def test_a_program_without_the_two_records_leaves_the_two_metrics_out(dropped, l
     metrics = _lines(out)[-1]["metrics"]
     assert not left_out & set(metrics)
     assert set(NEW_COUNTERS) - left_out <= set(metrics)
-    assert {"superstep_ms", "graph_build_s.setup", "plan_resident_gb.flat",
-            "cdlp_sparse_superstep_share.flat"} <= set(metrics)
+    assert {"superstep_ms", "graph_build_s.setup", "plan_resident_gb",
+            "cdlp_sparse_superstep_share"} <= set(metrics)
 
 
 # -- the facts and the readers, on hand-made records ---------------------------
-
-
-def test_the_flat_driver_is_the_large_driver_with_wider_facts():
-    flat, large = _load("drivers", "kernel_job_flat"), _load("drivers", "kernel_job_large")
-    for name in ("setup", "job", "end_to_end", "records", "facts", "check"):
-        theirs = getattr(large, name)
-        mine = getattr(flat, name)
-        assert mine.__code__ is not None and mine.__name__ == theirs.__name__
-        assert mine.__code__.co_code == theirs.__code__.co_code, name
-    held = {"phase": "device_residency", "scan": "carried", "graph_bytes": 6_500,
-            "plan_bytes": 2_300, "slot_index_bytes": 2_200, "rows_bytes": 2_200}
-    built = {"phase": "plan_build", "padded_slots_per_message": 1.0265}
-    delta = {"phase": "superstep_delta",
-             "branch": ["full"] * 8 + [89478395, 2097149],
-             "seconds": [31.0, 4.1, 4.0, 4.2, 4.1, 4.3, 4.1, 4.0, 3.5, 0.9]}
-    narrow = large._program_facts([held, built, delta])
-    assert narrow == {"scan": "carried", "resident_bytes": 11_000, "sparse_supersteps": 2}
-    # the median of the eight full supersteps: the first loaded the programs
-    assert flat._program_facts([held, built, delta]) == dict(
-        narrow, padded_slots_per_message=1.0265, full_superstep_seconds=4.1)
-    # the parent's records: neither fact, and the narrow ones as they were
-    older = [held, {"phase": "plan_build"}, {k: v for k, v in delta.items()
-                                             if k != "seconds"}]
-    assert flat._program_facts(older) == narrow
-    # the plain scan: ten full supersteps in one program, no seconds to read
-    plain = {"phase": "superstep_delta", "branch": ["full"] * 10, "seconds": []}
-    assert "full_superstep_seconds" not in flat._program_facts([held, built, plain])
-    # the large driver's own module is not touched by the flat one's
-    assert large._program_facts([held, built, delta]) == narrow
 
 
 @pytest.mark.parametrize("metric,facts,want", [
@@ -288,9 +272,9 @@ def test_the_flat_driver_is_the_large_driver_with_wider_facts():
     ("full_superstep_ms", {}, None),
     ("plan_slots_per_message", {"padded_slots_per_message": 1.0265}, 1.0265),
     ("plan_slots_per_message", {}, None),
-    ("plan_resident_gb.flat", {"resident_bytes": 10_994_000_000}, 10.994),
-    ("cdlp_sparse_superstep_share.flat", {"sparse_supersteps": 2, "iterations": 10}, 20.0),
-    ("cdlp_sparse_superstep_share.flat", {"iterations": 10}, None),
+    ("plan_resident_gb", {"resident_bytes": 10_994_000_000}, 10.994),
+    ("cdlp_sparse_superstep_share", {"sparse_supersteps": 2, "iterations": 10}, 20.0),
+    ("cdlp_sparse_superstep_share", {"iterations": 10}, None),
 ])
 def test_the_new_fact_metrics_read_their_facts(metric, facts, want):
     spec = _json("layer_metrics", metric + ".json")
@@ -299,7 +283,7 @@ def test_the_new_fact_metrics_read_their_facts(metric, facts, want):
 
 
 def test_the_peak_share_is_the_devices_peak_over_its_limit():
-    spec = _json("layer_metrics", "peak_hbm_share.flat.json")
+    spec = _json("layer_metrics", "peak_hbm_share.kernel.json")
     read = _load("readers", spec["reader"]).read
     memory = {"memory_peak_bytes": 13_800_000_000, "memory_limit_bytes": 16_909_336_064}
     assert read(spec.get("args", {}), {"memory": memory}) == pytest.approx(81.6117, abs=1e-3)

@@ -5,57 +5,31 @@ the scale-22 sibling's but for the scale, it rehearses with both values of
 timed path come out not correct, and a program that lacks what the driver
 needs is turned away before any input is made."""
 
-import importlib.util
-import json
 import os
-import subprocess
-import sys
+import statistics
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-BENCH_DIR = os.path.join(REPO, "benchmark")
+from _bench import BENCH_DIR, Bench, lines as _lines, load as _load, run as _run
+from _bench import bench, grown_root  # noqa: F401  (fixtures)
+
 RUN = os.path.join(BENCH_DIR, "run.py")
-CELL = "cdlp-g500-24"
+CELL, CONFIG, TRAFFIC = "cdlp-g500-24", "graphalytics-g500-24", "cdlp-batch-large"
+SHARED = ("evps", "superstep_ms", "superstep_roofline_share",
+          "device_idle_share.kernel", "graph_build_s.setup")
 NEW_METRICS = ("peak_hbm_share.kernel", "plan_resident_gb",
                "cdlp_sparse_superstep_share")
-
-with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
-    BENCH = json.load(_f)
-
-
-def _load(kind, name):
-    spec = importlib.util.spec_from_file_location(
-        f"large_cell_{kind}_{name}", os.path.join(BENCH_DIR, kind, name + ".py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-def _json(*parts):
-    with open(os.path.join(BENCH_DIR, *parts)) as f:
-        return json.load(f)
-
-
-def _run(*argv, code=None, timeout=900):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
-    cmd = [sys.executable, RUN] if code is None else [sys.executable, "-c", code]
-    return subprocess.run([*cmd, *argv], capture_output=True, text=True, env=env,
-                          timeout=timeout, cwd=REPO)
-
-
-def _lines(out):
-    return [json.loads(line) for line in out.stdout.splitlines()
-            if line.startswith("{")]
+# two that PR 38 brought for the flat sibling and that read here too
+WIDER_FACTS = ("full_superstep_ms", "plan_slots_per_message")
+_json = Bench().data
 
 
 # -- the configuration and the cell -------------------------------------------
 
 
-def test_the_configuration_is_the_scale_22_siblings_but_for_the_scale():
-    config = _json("configs", "graphalytics-g500-24.json")
-    sibling = _json("configs", "graphalytics-g500-22.json")
+def test_the_configuration_is_the_scale_22_siblings_but_for_the_scale(bench):
+    config = bench.data("configs", CONFIG + ".json")
+    sibling = bench.data("configs", "graphalytics-g500-22.json")
     for key in ("generator", "dataset_seed", "guarantees", "reduced", "rehearsal",
                 "chips"):
         assert config[key] == sibling[key], key  # the guarantees word for word
@@ -76,33 +50,38 @@ def test_the_configuration_is_the_scale_22_siblings_but_for_the_scale():
     for count in ("8,870,509", "260,376,136", "8.87 M", "260.4 M"):
         assert count in assumed["draw_counts"]  # the draw's counts beside LDBC's
     assert config["deployment"] != sibling["deployment"]
-    for word in ("GB", "device-resident", "B per edge"):
+    # what runs since PR 36: the rows carried and admitted, four full gathers
+    for word in ("GB", "device-resident", "B per edge", "carried rows", "admitted",
+                 "four of ten supersteps gather in full"):
         assert word in config["deployment"]
+    assert "not admitted" not in config["deployment"]
     assert set(config) == set(sibling)
 
 
-def test_the_cell_is_one_chip_under_the_large_batch_traffic():
-    cells = {w["name"]: w for w in BENCH["workloads"]}
-    assert cells[CELL] == dict(cells[CELL], config="graphalytics-g500-24",
-                               traffic="cdlp-batch-large", chips=1)
-    entry = next(c for c in BENCH["configs"] if c["name"] == "graphalytics-g500-24")
-    assert entry["reduced"] == [] and entry["file"].endswith("graphalytics-g500-24.json")
-    traffic = _json("traffic", "cdlp-batch-large.json")
-    small = _json("traffic", "cdlp-batch.json")
+def test_the_cell_is_one_chip_under_the_large_batch_traffic(bench):
+    cell = bench.cell(CELL)
+    assert cell == dict(cell, config=CONFIG, traffic=TRAFFIC, chips=1)
+    assert len(cell["why"]) <= 200 and "four full gathers" in cell["why"]
+    assert bench.config(CONFIG) == dict(
+        bench.config(CONFIG), file=f"benchmark/configs/{CONFIG}.json", reduced=[],
+        source=bench.data("configs", CONFIG + ".json")["source"])
+    traffic = bench.data("traffic", TRAFFIC + ".json")
+    small = bench.data("traffic", "cdlp-batch.json")
     assert traffic == dict(small, driver="kernel_job_large")  # the loop rule word for word
-    listing = {m["name"]: m.get("workloads", []) for m in
-               BENCH["end_to_end"] + BENCH["per_layer"]}
-    for name in ("evps", "superstep_ms", "superstep_roofline_share",
-                 "device_idle_share.kernel", "graph_build_s.setup"):
-        # ahead of wcc-g500-22, which test_wcc_cell.py holds to the last place
-        assert listing[name].count(CELL) == 1 and listing[name][-1] == "wcc-g500-22"
-    by_name = {m["name"]: m for m in BENCH["per_layer"]}
-    for name in NEW_METRICS:
-        assert listing[name] == [CELL] and by_name[name]["moves"] == "evps"
+    assert bench.reported_by(CELL) == {*SHARED, *NEW_METRICS, *WIDER_FACTS}
+    for name in (*SHARED, *NEW_METRICS, *WIDER_FACTS):
+        assert bench.lists(name, CELL), name
+    for name in (*NEW_METRICS, *WIDER_FACTS):
+        assert bench.metric(name)["moves"] == "evps"
+    assert bench.reader_of("peak_hbm_share.kernel") == {"reader": "peak_memory_share"}
+    assert bench.reader_of("plan_resident_gb") == {
+        "reader": "fact_value", "args": {"fact": "resident_bytes", "scale": 1e-09}}
+    assert bench.reader_of("cdlp_sparse_superstep_share") == {
+        "reader": "fact_value",
+        "args": {"fact": "sparse_supersteps", "over": "iterations", "scale": 100.0}}
     # read on the chip alone, as peak_hbm_share.x4 is
-    assert by_name["peak_hbm_share.kernel"]["source"] == \
-        by_name["peak_hbm_share.x4"]["source"] == "device_trace"
-    assert [w["chips"] for w in BENCH["workloads"]].count(4) == 1
+    assert bench.metric("peak_hbm_share.kernel")["source"] == \
+        bench.metric("peak_hbm_share.x4")["source"] == "device_trace"
 
 
 # -- run.py on the cell, off the chip -----------------------------------------
@@ -131,6 +110,12 @@ def test_the_cell_rehearses_and_its_metrics_read_the_program_records(trace):
         assert metrics["cdlp_sparse_superstep_share"] == {
             "value": pytest.approx(10.0 * sparse), "unit": "%"}
         assert "peak_hbm_share.kernel" not in metrics  # a CPU keeps no statistics
+        # the two facts this driver states since the flat driver folded into it
+        full = [s for s, b in zip(delta["seconds"], delta["branch"]) if b == "full"]
+        assert metrics["full_superstep_ms"] == {
+            "value": pytest.approx(1000.0 * statistics.median(full)), "unit": "ms"}
+        assert metrics["plan_slots_per_message"]["unit"] == "ratio"
+        assert 1.0 < metrics["plan_slots_per_message"]["value"] < 1.5
         assert {"superstep_ms", "graph_build_s.setup"} <= set(metrics)
     else:
         assert set(last["metrics"]) == {"evps", "setup_s"}
@@ -277,21 +262,50 @@ def test_a_program_that_lacks_what_the_driver_needs_is_turned_away_at_once(code,
 # -- the facts and the readers, on hand-made records ---------------------------
 
 
+_HELD = {"phase": "device_residency", "scan": "carried", "graph_bytes": 6_000,
+         "plan_bytes": 2_500, "slot_index_bytes": 2_100, "rows_bytes": 2_400}
+_BUILT = {"phase": "plan_build", "padded_slots_per_message": 1.167}
+_DELTA = {"phase": "superstep_delta",
+          "branch": ["full", "full", "full", "full", 2034188] + [127136] * 5,
+          "seconds": [31.0, 5.8, 5.9, 5.8, 0.9, 0.5, 0.5, 0.5, 0.5, 0.5]}
+_NARROW = {"scan": "carried", "resident_bytes": 10_600, "sparse_supersteps": 6}
+_NO_SECONDS = {k: v for k, v in _DELTA.items() if k != "seconds"}
+_RESIDENT = {"scan": "carried", "resident_bytes": 10_600}
+
+
 def test_the_program_facts_are_what_the_records_say():
     driver = _load("drivers", "kernel_job_large")
-    held = {"phase": "device_residency", "scan": "carried", "graph_bytes": 6_000,
-            "plan_bytes": 2_500, "slot_index_bytes": 2_100, "rows_bytes": 2_400}
-    delta = {"phase": "superstep_delta",
-             "branch": ["full", "full", "full", 86792045, 2034188] + [127136] * 5}
-    assert driver._program_facts([held, delta]) == {
-        "scan": "carried", "resident_bytes": 10_600, "sparse_supersteps": 7}
-    plain = dict(held, scan="plain", slot_index_bytes=0, rows_bytes=0)
+    assert driver._program_facts([_HELD, _NO_SECONDS]) == _NARROW
+    plain = dict(_HELD, scan="plain", slot_index_bytes=0, rows_bytes=0)
     every_one_full = {"phase": "superstep_delta", "branch": ["full"] * 10}
     assert driver._program_facts([plain, every_one_full]) == {
         "scan": "plain", "resident_bytes": 8_500, "sparse_supersteps": 0}
     # the share is the program's word alone: no record, no fact
     assert driver._program_facts([plain]) == {"scan": "plain", "resident_bytes": 8_500}
     assert driver._program_facts([{"phase": "plan_build"}]) == {}
+
+
+@pytest.mark.parametrize("records,want", [
+    # the median of the four full supersteps: the first loaded the programs
+    ([_HELD, _BUILT, _DELTA], dict(_NARROW, padded_slots_per_message=1.167,
+                                   full_superstep_seconds=5.85)),
+    # the parent of PR 38: a plan_build without the plan's shape, a
+    # superstep_delta without its seconds
+    ([_HELD, {"phase": "plan_build"}, _NO_SECONDS], _NARROW),
+    ([_HELD, _BUILT], dict(_RESIDENT, padded_slots_per_message=1.167)),
+    ([_HELD, _DELTA], dict(_NARROW, full_superstep_seconds=5.85)),
+    # the plain scan: ten full supersteps in one program, no seconds to read
+    ([_HELD, _BUILT, {"phase": "superstep_delta", "branch": ["full"] * 10,
+                      "seconds": []}],
+     dict(_RESIDENT, sparse_supersteps=0, padded_slots_per_message=1.167)),
+], ids=["both-records", "the-parent-s-records", "no-superstep-delta",
+        "no-plan-build", "the-plain-scan"])
+def test_the_two_wider_facts_are_stated_from_the_records_and_left_out_without(
+        records, want):
+    """What ``kernel_job_flat`` stated (PR 38) is this driver's own since PR
+    40: each fact from its record, each left out when the record or the key
+    it reads is missing, and the narrow facts as they were."""
+    assert _load("drivers", "kernel_job_large")._program_facts(records) == want
 
 
 @pytest.mark.parametrize("metric,facts,want", [

@@ -2,62 +2,66 @@
 and says so, its control fails, a program without the mesh entry is turned
 away before any input is made, and the two new readers match hand counts."""
 
-import json
+import functools
 import os
-import subprocess
 import sys
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-BENCH_DIR = os.path.join(REPO, "benchmark")
+import _bench
+from _bench import BENCH_DIR, lines as _lines
+from _bench import bench, grown_root  # noqa: F401  (fixtures)
+
 RUN = os.path.join(BENCH_DIR, "run.py")
-CELL = "cdlp-g500-25-x4"
+CELL, CONFIG, TRAFFIC = "cdlp-g500-25-x4", "graphalytics-g500-25", "cdlp-batch-mesh"
+SHARED = ("evps", "superstep_ms", "device_idle_share.kernel", "graph_build_s.setup")
+OWN = {
+    "superstep_roofline_share.x4": {"reader": "roofline_mesh", "args": {
+        "bytes_function": "lpa_superstep_min_bytes_per_chip",
+        "bytes_args": ["num_vertices", "num_messages", "chips"],
+        "calls_per_job": "iterations"}},
+    "peak_hbm_share.x4": {"reader": "peak_memory_share"},
+    "partition_s.setup": {"reader": "phase_seconds", "args": {
+        "scope": "setup", "select": [{"phase": "partition"}]}},
+    "exchange_mb_per_superstep": {"reader": "fact_value", "args": {
+        "fact": "bytes_per_superstep", "scale": 1e-06}},
+    "shard_message_imbalance": {"reader": "fact_value", "args": {
+        "fact": "messages_per_shard_max", "over": "messages_per_shard_mean"}},
+}
 
 sys.path.insert(0, BENCH_DIR)
 import roofline_mesh  # noqa: E402  (benchmark/roofline_mesh.py, not the reader)
 
-with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
-    BENCH = json.load(_f)
+_run = functools.partial(_bench.run, devices=4)
+_reader = functools.partial(_bench.load, "readers")
 
 
-def _reader(name):
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "reader_" + name, os.path.join(BENCH_DIR, "readers", name + ".py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-def _run(*argv, code=None, devices=4, timeout=900):
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
-    cmd = [sys.executable, RUN] if code is None else [sys.executable, "-c", code]
-    return subprocess.run([*cmd, *argv], capture_output=True, text=True, env=env,
-                          timeout=timeout, cwd=REPO)
-
-
-def _lines(out):
-    return [json.loads(line) for line in out.stdout.splitlines()
-            if line.startswith("{")]
-
-
-def test_one_cell_of_three_asks_for_four_chips():
-    cells = {w["name"]: w for w in BENCH["workloads"]}
-    assert cells[CELL]["chips"] == 4
-    assert [w["chips"] for w in BENCH["workloads"]].count(4) == 1
-    with open(os.path.join(BENCH_DIR, "configs", "graphalytics-g500-25.json")) as f:
-        config = json.load(f)
-    with open(os.path.join(BENCH_DIR, "configs", "graphalytics-g500-22.json")) as f:
-        sibling = json.load(f)
+def test_this_cell_asks_for_four_chips_and_its_metrics_are_its_own(bench):
+    """What is true of this cell. How many cells ask for four chips is the
+    driver's rule (at most half of them, rounded down), not a test's."""
+    cell = bench.cell(CELL)
+    assert cell == dict(cell, config=CONFIG, traffic=TRAFFIC, chips=4)
+    config = bench.data("configs", CONFIG + ".json")
+    sibling = bench.data("configs", "graphalytics-g500-22.json")
+    assert bench.config(CONFIG) == dict(
+        bench.config(CONFIG), file=f"benchmark/configs/{CONFIG}.json", reduced=[],
+        source=config["source"])
     assert config["chips"] == 4 and config["reduced"] == []
     assert config["guarantees"] == sibling["guarantees"]  # word for word
     assert config["generator_args"] == dict(sibling["generator_args"], scale=25)
     assert config["source"] == sibling["source"].replace(
         "graph500-22", "graph500-25").replace("scale 22", "scale 25").replace(
         "class S", "class L")
+    assert bench.data("traffic", TRAFFIC + ".json")["driver"] == "kernel_job_mesh"
+    assert bench.reported_by(CELL) == {*SHARED, *OWN}
+    for name in (*SHARED, *OWN):
+        assert bench.lists(name, CELL), name
+    for name, reader in OWN.items():
+        assert bench.reader_of(name) == reader
+        assert bench.metric(name)["moves"] == (
+            "setup_s" if name == "partition_s.setup" else "evps")
+    four = [w for w in bench.json["workloads"] if w["chips"] == 4]
+    assert 2 * len(four) <= len(bench.json["workloads"])  # the driver's own rule
 
 
 @pytest.mark.parametrize("trace", ["0", "1"])

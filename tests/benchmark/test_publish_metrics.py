@@ -4,18 +4,18 @@ publish chapter add up to the chapter on a hand-made record list, and
 ``run_self_s`` is the root span minus the chapters, nothing where the
 program writes no root span."""
 
-import json
 import os
-import subprocess
 import sys
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-BENCH_DIR = os.path.join(REPO, "benchmark")
+from _bench import BENCH_DIR, Bench, lines as _lines, run as _run
+from _bench import bench, grown_root  # noqa: F401  (fixtures)
+
 CELL = "pipeline-outlinks-262k"
 METRICS = ("publish_chapter_s", "publish_cc_s", "publish_host_s",
            "publish_write_s", "publish_self_s", "triangles_host_s", "run_self_s")
+_reader_of = Bench().reader_of
 
 sys.path.insert(0, os.path.join(BENCH_DIR, "readers"))
 import phase_seconds  # noqa: E402
@@ -25,8 +25,7 @@ READERS = {"phase_seconds": phase_seconds, "span_tree": span_tree}
 
 
 def _read(metric, records):
-    with open(os.path.join(BENCH_DIR, "layer_metrics", metric + ".json")) as f:
-        spec = json.load(f)
+    spec = _reader_of(metric)
     jobs = sorted({r["job"] for r in records})
     return READERS[spec["reader"]].read(
         spec["args"], {"records": records, "jobs": jobs})
@@ -100,15 +99,10 @@ def test_a_program_without_the_new_spans_reads_nothing_and_raises_nothing():
 
 
 def test_the_seven_read_a_value_in_the_cells_rehearsal():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
-    out = subprocess.run(
-        [sys.executable, os.path.join(BENCH_DIR, "run.py"), "--workload", CELL,
-         "--seed", "3000000019", "--seconds", "1", "--trace", "1", "--rehearse"],
-        capture_output=True, text=True, env=env, timeout=900, cwd=REPO)
+    out = _run("--workload", CELL, "--seed", "3000000019", "--seconds", "1",
+               "--trace", "1", "--rehearse")
     assert out.returncode == 4, out.stderr[-3000:]
-    last = [json.loads(line) for line in out.stdout.splitlines()
-            if line.startswith("{")][-1]
+    last = _lines(out)[-1]
     got = {m: last["metrics"][m]["value"] for m in METRICS}
     assert all(isinstance(v, float) and v >= 0 for v in got.values()), got
     assert got["publish_chapter_s"] > 0 and got["publish_cc_s"] > 0
@@ -118,9 +112,13 @@ def test_the_seven_read_a_value_in_the_cells_rehearsal():
             + got["publish_self_s"]) == pytest.approx(
                 got["publish_chapter_s"], abs=1e-9)
     assert got["publish_self_s"] < 0.05 * got["publish_chapter_s"] + 1e-3
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        entries = {m["name"]: m for m in json.load(f)["per_layer"]}
-    for metric in METRICS:
-        assert entries[metric]["workloads"] == [CELL]
-        assert entries[metric]["moves"] == "makespan_s"
-        assert entries[metric]["source"] == "program_span"
+
+
+@pytest.mark.parametrize("metric", METRICS)
+def test_each_of_the_seven_is_the_pipeline_cells_and_moves_its_makespan(metric, bench):
+    assert bench.lists(metric, CELL)
+    entry = bench.metric(metric)
+    assert entry == dict(entry, moves="makespan_s", source="program_span", unit="s",
+                         better="lower")
+    assert bench.reader_of(metric)["reader"] in READERS
+    assert "makespan_s" in bench.end_to_end_of(CELL)

@@ -9,50 +9,39 @@ away before any input is made, and the two new metrics read the
 import json
 import os
 import shutil
-import subprocess
 import sys
 
 import numpy as np
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-BENCH_DIR = os.path.join(REPO, "benchmark")
+from _bench import BENCH_DIR, Bench, lines as _lines, load, run as _run
+from _bench import bench, grown_root  # noqa: F401  (fixtures)
+
 RUN = os.path.join(BENCH_DIR, "run.py")
-CELL = "wcc-g500-22"
+CELL, CONFIG, TRAFFIC = "wcc-g500-22", "graphalytics-g500-22-wcc", "wcc-batch"
+SHARED = ("evps", "superstep_ms", "superstep_roofline_share",
+          "device_idle_share.kernel", "graph_build_s.setup")
+OWN = {"wcc_supersteps": {"fact": "iterations"},
+       "wcc_quiet_pass_share": {"fact": "quiet_passes", "over": "fixpoint_supersteps",
+                                "scale": 100.0}}
 
 sys.path.insert(0, BENCH_DIR)
 import generators  # noqa: E402
 import references  # noqa: E402
 import references_wcc  # noqa: E402
 
-with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
-    BENCH = json.load(_f)
-
-
-def _run(*argv, code=None, timeout=900):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
-    cmd = [sys.executable, RUN] if code is None else [sys.executable, "-c", code]
-    return subprocess.run([*cmd, *argv], capture_output=True, text=True, env=env,
-                          timeout=timeout, cwd=REPO)
-
-
-def _lines(out):
-    return [json.loads(line) for line in out.stdout.splitlines()
-            if line.startswith("{")]
-
 
 # -- the configuration and the cell -------------------------------------------
 
 
-def test_the_configuration_is_the_cdlp_cells_draw_under_wccs_guarantees():
-    cells = {w["name"]: w for w in BENCH["workloads"]}
-    assert cells[CELL] == dict(cells[CELL], config="graphalytics-g500-22-wcc",
-                               traffic="wcc-batch", chips=1)
-    with open(os.path.join(BENCH_DIR, "configs", "graphalytics-g500-22-wcc.json")) as f:
-        config = json.load(f)
-    with open(os.path.join(BENCH_DIR, "configs", "graphalytics-g500-22.json")) as f:
-        sibling = json.load(f)
+def test_the_configuration_is_the_cdlp_cells_draw_under_wccs_guarantees(bench):
+    cell = bench.cell(CELL)
+    assert cell == dict(cell, config=CONFIG, traffic=TRAFFIC, chips=1)
+    config = bench.data("configs", CONFIG + ".json")
+    sibling = bench.data("configs", "graphalytics-g500-22.json")
+    assert bench.config(CONFIG) == dict(
+        bench.config(CONFIG), file=f"benchmark/configs/{CONFIG}.json",
+        source=config["source"], reduced=[])
     for key in ("generator", "generator_args", "dataset_seed", "rehearsal", "chips"):
         assert config[key] == sibling[key]  # the same draw: the kernel alone differs
     assert config["reduced"] == [] and config["guarantees"] != sibling["guarantees"]
@@ -62,16 +51,16 @@ def test_the_configuration_is_the_cdlp_cells_draw_under_wccs_guarantees():
     said = " ".join(config["guarantees"])
     for word in ("weakly", "fixpoint", "path", "smallest vertex id", "isolated", "exact"):
         assert word in said
-    with open(os.path.join(BENCH_DIR, "traffic", "wcc-batch.json")) as f:
-        traffic = json.load(f)
+    traffic = bench.data("traffic", TRAFFIC + ".json")
     assert traffic["driver"] == "graph_kernel_job" and traffic["algorithm"] == "wcc"
     assert "iterations" not in traffic  # the supersteps are the program's answer
-    listing = {m["name"]: m.get("workloads", []) for m in
-               BENCH["end_to_end"] + BENCH["per_layer"]}
-    for name in ("evps", "superstep_ms", "superstep_roofline_share",
-                 "device_idle_share.kernel", "graph_build_s.setup"):
-        assert listing[name][-1] == CELL
-    assert listing["wcc_supersteps"] == listing["wcc_quiet_pass_share"] == [CELL]
+    assert os.path.exists(os.path.join(bench.dir, "algorithms", "wcc.py"))
+    assert bench.reported_by(CELL) == {*SHARED, *OWN}
+    for name in (*SHARED, *OWN):
+        assert bench.lists(name, CELL), name
+    for name, args in OWN.items():
+        assert bench.reader_of(name) == {"reader": "fact_value", "args": args}
+        assert bench.metric(name)["moves"] == "evps"
 
 
 # -- the reference, SciPy and both families of the program --------------------
@@ -196,12 +185,29 @@ def test_an_unknown_algorithm_is_turned_away_before_anything_is_generated(tmp_pa
     with open(os.path.join(BENCH_DIR, "traffic", "wcc-batch.json")) as f:
         traffic = dict(json.load(f), algorithm="pagerank")
     (root / "benchmark" / "traffic" / "wcc-batch.json").write_text(json.dumps(traffic))
-    (root / "BENCHMARK.json").write_text(json.dumps(dict(BENCH, per_layer=[])))
+    (root / "BENCHMARK.json").write_text(json.dumps(dict(Bench().json, per_layer=[])))
     out = _run("--root", str(root), "--workload", CELL, "--seed", "3",
                "--seconds", "1", "--trace", "0", "--rehearse")
     assert out.returncode not in (0, 4), out.stdout[-2000:]
     assert "graph_kernel_job has no algorithm 'pagerank'" in out.stderr
     assert not [r for r in _lines(out) if "vertices" in r]  # nothing was drawn
+
+
+def test_the_algorithm_files_compare_prints_the_accepted_record():
+    """``algorithms/wcc.py`` states what ``graph_kernel_job``'s table row
+    stated before ISSUE 40: the ledger's ``last_line_numbers`` know the
+    comparison by these keys, in this order."""
+    wcc = load("algorithms", "wcc")
+    want = np.array([0, 0, 2, 2, 4, 0])
+    assert wcc.compare(want.copy(), want) == [{
+        "check": "wcc_label_mismatches", "value": 0, "limit": 0, "ok": True,
+        "compared": 6, "components": 3}]
+    (record,) = wcc.compare(np.array([0, 1, 2, 2, 4, 5]), want)
+    assert list(record) == ["check", "value", "limit", "ok", "compared", "components"]
+    assert record == dict(record, value=2, ok=False, limit=0, components=3)
+    u, v, n = _chain(50)
+    np.testing.assert_array_equal(wcc.reference(u, v, n, {}), np.zeros(n, int))
+    np.testing.assert_array_equal(wcc.control(u, v, n, {})[:5], [0, 0, 0, 1, 2])
 
 
 def test_the_two_new_metrics_read_the_fixpoint_record_on_a_rehearsal():
