@@ -1,4 +1,4 @@
-"""One carried-rows CDLP job of a benchmark cell under a profiler capture,
+"""One job of a kernel cell (a carried-rows CDLP job, or an algorithm file's run) under a profiler capture,
 reduced by scope and by program (ISSUE 36, PERF.md §5).
 
     python _proof/profile_job.py cdlp-g500-24 [out.json]
@@ -49,23 +49,28 @@ def main():
     ctx = {"config": cell["config"], "traffic": cell["traffic"],
            "sizes": cell["config"]["rehearsal"] if os.environ.get("REHEARSE") else cell["config"],
            "seed": 1, "scratch": scratch,
-           "chips": cell["chips"], "say": say}
+           "chips": cell["chips"], "say": say, "load_module": run.load_module}
     state = driver.setup(ctx)
     graph, iters = state["graph"], cell["traffic"]["iterations"]
-    device = jax.devices()[0]
     # a mesh cell's driver keeps its mesh: the job goes through the same entry
     on_mesh = {"mesh": state["mesh"]} if "mesh" in state else {}
+    if "algorithm" in state:  # an algorithm-file driver (ISSUE 41): the job is the file's run()
+        job = lambda sink=None: state["algorithm"].run(graph, sink, cell["traffic"])[0]
+    else:
+        job = lambda sink=None: gm.label_propagation(
+            graph, max_iter=iters, plan="auto", sink=sink, **on_mesh)
+    device = jax.devices()[0]
     memory = lambda: [d.memory_stats() for d in state.get("devices", [device])]
 
     # an untraced job first, with a sink: the record and the job's seconds
     sink = MetricsSink()
     t0 = time.perf_counter()
-    gm.label_propagation(graph, max_iter=iters, plan="auto", sink=sink, **on_mesh).block_until_ready()
+    job(sink).block_until_ready()
     say(plain_job_s=time.perf_counter() - t0,
         records=[{k: v for k, v in r.items() if k not in ("t", "cost", "thresholds")}
                  for r in sink.records
                  if r["phase"] in ("superstep_delta", "impl_selected", "device_residency",
-                                   "plan_build", "partition")],
+                                   "plan_build", "partition", "superstep_timing")],
         memory=memory())
 
     trace_dir = os.path.join(scratch, "trace")
@@ -73,7 +78,7 @@ def main():
     options.python_tracer_level = 0
     jax.profiler.start_trace(trace_dir, profiler_options=options)
     t0 = time.perf_counter()
-    gm.label_propagation(graph, max_iter=iters, plan="auto", **on_mesh).block_until_ready()
+    job().block_until_ready()
     job_s = time.perf_counter() - t0
     jax.profiler.stop_trace()
     planes = devtrace.read_xplane(devtrace.newest_xplane(trace_dir), "run")
@@ -84,7 +89,7 @@ def main():
         name = row["module"]
         by_program[name] = by_program.get(name, 0.0) + row["device_seconds"]
     # the third scope level: the rows' passes by width class (`w<width>`)
-    passes = frozenset(("row_gather", "row_mode"))
+    passes = frozenset(("row_gather", "row_mode", "row_sum"))
     by_width = {}
     for row in devtrace.reduce_capture(
             *planes, passes | {f"w{w}" for w in range(1, 1 << 15)})["scopes"]:

@@ -106,6 +106,21 @@ def one(said, name, text_out=None):
     elif name.startswith("rewrite:"):
         cap = delta_rungs(said["num_messages"])[int(name.split(":")[1])]
         lowered = lpa._rewrite_program.lower(rows, labels, changed, plan, cap=cap)
+    elif name == "pagerank":  # ISSUE 41: the one program of gm.pagerank(directed=False)
+        import dataclasses
+        import importlib
+
+        from graphmine_tpu.graph.container import Graph
+
+        # ops/__init__ exports the function `pagerank` under the module's name
+        pagerank = importlib.import_module("graphmine_tpu.ops.pagerank")
+        i32 = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32, sharding=chip)
+        m = said["num_messages"]
+        graph = Graph(src=i32(m // 2), dst=i32(m // 2), msg_recv=i32(m),
+                      msg_send=i32(m), msg_ptr=i32(v + 1), num_vertices=v)
+        lowered = pagerank._pagerank_messages_jit.lower(
+            graph, dataclasses.replace(plan, out_ptr=None, out_slot=None),
+            0.85, 10, None, None)
     else:
         raise SystemExit(f"no program {name!r}")
     compiled = lowered.compile()

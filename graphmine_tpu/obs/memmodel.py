@@ -427,6 +427,26 @@ def carried_job_transients(plan, top_rung: int = 0, shards: int = 1) -> dict:
     }
 
 
+def row_sum_transients(plan) -> int:
+    """Bytes of temporaries of one PageRank iteration over the fused
+    ``plan`` (``ops/pagerank.py:_bucketed_iteration``, the program the
+    message reading steps from the host), from the plan's shapes. Compiled
+    alone the classes take turns, as the carried-rows job's do, so the
+    program holds its largest class: the class's indices brought in range
+    as the plan holds them and the gathered float32 rows in the row-major
+    form the sum reads, beside the padded contributions in two memory
+    spaces (:func:`carried_job_transients`'s count of the gather, which
+    holds the same); and the V-vectors of the update (the contributions, the inflow, the rank
+    out, the dangling select, the delta's difference). The same body
+    inside a ``while_loop`` holds EVERY class's two forms at once
+    (5,256,151,040 B at graph500-24's shapes, 11,005,842,432 B at GAP
+    Urand's at scale 24, to 0.5 % the sum over the classes there; PERF.md
+    §6, PR 41), which is why the job steps from the host. At or above the
+    compiler's own count on a skewed and on a flat plan
+    (``tests/test_chip_compile.py``)."""
+    return carried_job_transients(plan)["gather"] + 5 * _I32 * int(plan.num_vertices)
+
+
 def carried_rows_inventory(plan, top_rung: int = 0, shards: int = 1) -> dict:
     """What the carried-rows job of ``ops/lpa.py`` holds on the device
     beyond a fused ``plan``, known from the plan's shapes before the index

@@ -86,7 +86,8 @@ register("ivf_fallback", "guard", "detail")
 # chip: once per plan; a mesh: once per (graph, mesh), of the fullest chip)
 # it says `scan` (`carried` | `plain`) and `scan_reason`, the admission's
 # arithmetic or why nothing was asked (the sort family, a caller's trace, a
-# mesh that spans processes).
+# mesh that spans processes). `pagerank(..., directed=False, plan="auto")`
+# writes one too (`op: pagerank_inflow`), with no `scan`: it asks nothing.
 register("impl_selected", "op", "impl", "n", "reason")
 # plan_build: one per superstep-plan materialization
 # (ops/superstep_policy.emit_plan_records and the driver's single-device
@@ -102,7 +103,9 @@ register("impl_selected", "op", "impl", "n", "reason")
 # (ops/lpa.py:_mesh_label_propagation) says `index_seconds`: the part of
 # `seconds` spent on the carried-rows job's admission, the shards' slot
 # index (built in threads from the host partition) and its placement; 0.0
-# where the rows were not admitted and on a cache hit.
+# where the rows were not admitted and on a cache hit. PageRank's message
+# reading (`op: pagerank_inflow`) reads the plan the other two ops cache:
+# on a graph one of them planned its record says `cached: true`, 0.0 s.
 register("plan_build", "op", "family", "seconds", "padded_slots_per_edge")
 # superstep_timing (ISSUE 12): achieved-vs-model throughput for one
 # window of supersteps, emitted at the existing tripwire/telemetry
@@ -179,6 +182,13 @@ register("superstep_delta", "op", "changed_vertices", "changed_messages",
 # slot index, the padded label vector (replicated) in and out;
 # `graph_bytes` 0 (the graph stays on the host), `bytes_limit` and
 # `bytes_in_use` the fullest chip's, which the admission was asked of.
+# `pagerank(..., directed=False, plan="auto", sink=)` writes one on the
+# bucketed family too (`op: pagerank_inflow`, PR 41:
+# ops/superstep_policy.stepped_residency): `scan: plain` (every iteration
+# gathers every row anew), `rows_bytes` and `slot_index_bytes` 0 (nothing
+# is carried, no index is built), `labels_bytes` the ranks in and out, and
+# in `reason` the reckoned temporaries of the one compiled iteration the
+# host steps, beside the device's free bytes, and whether they fit.
 register("device_residency", "op", "scan", "reason", "bytes_limit",
          "graph_bytes", "plan_bytes", "rows_bytes", "slot_index_bytes",
          "labels_bytes", "code_bytes")
@@ -381,12 +391,13 @@ RECOVERY_PHASES = frozenset((
 # ``tests/test_trace.py`` holds the package to this list both ways.
 DEVICE_SCOPES = frozenset((
     # outer: algorithm x family
-    "lpa_bucketed", "cc_bucketed",
-    "lpa_sort", "cc_sort", "lpa_sharded", "masked_lpa", "superstep", "census",
+    "lpa_bucketed", "cc_bucketed", "pagerank_bucketed",
+    "lpa_sort", "cc_sort", "pagerank_sort", "lpa_sharded", "masked_lpa", "superstep", "census",
     "modularity", "features", "triangles", "ivf", "knn_exact",
     "knn_cross", "lof",
     # inner: superstep passes
-    "row_gather", "row_mode", "row_min",
+    "row_gather", "row_mode", "row_min", "row_sum", "hub_sum",
+    "segment_sum", "dangling_mass", "rank_update",
     "hist", "write_back", "pointer_jump", "msg_gather", "segment_mode",
     "segment_min", "sort", "run_reduce", "mask", "exchange",
     "changed_count", "converged",

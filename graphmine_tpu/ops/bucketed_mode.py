@@ -630,6 +630,66 @@ def _hist_modes(labels: jax.Array, out: jax.Array, plan: BucketedModePlan):
     return out
 
 
+# The span of a hub's messages one float32 partial sum runs over. A running
+# float32 sum of n like terms drifts by about sqrt(n) x 6e-8 of its value:
+# 6e-5 at the 10^6 neighbours of graph500-24's first hub, against the 1e-4
+# LDBC Graphalytics validates PageRank to. Summed in chunks the longest
+# chain is this long (2e-6) and the chunks' partials reduce as a row.
+_HUB_SUM_CHUNK = 1024
+
+
+def row_sums(values: jax.Array, plan: BucketedModePlan) -> jax.Array:
+    """``[V]`` float32: for every vertex the sum of ``values[sender]`` over
+    the messages it receives, on a fused plan: the sum twin of the row
+    modes (:func:`_row_modes`) and the row min
+    (:func:`~graphmine_tpu.ops.cc.cc_superstep_bucketed`), and the first
+    reduce of the plan that is not idempotent. Every message is one slot
+    of one class or one entry of ``hist_send``, so each is counted once;
+    a padding slot names index ``V`` and reads the 0.0 appended to
+    ``values``, which adds nothing; a vertex that receives nothing is in
+    no class and stays 0.0.
+
+    The histogram hubs (degree > ``_HIST_MIN_DEG``) sum their exact
+    message spans in chunks of :data:`_HUB_SUM_CHUNK`: one sorted
+    ``segment_sum`` into a partial per (hub, chunk), then the partials
+    summed as one dense row a hub."""
+    if plan.send_idx is None:
+        raise ValueError(
+            "row_sums needs a fused plan (send_idx); build it with "
+            "build_graph_and_plan or BucketedModePlan.from_edges"
+        )
+    v = plan.num_vertices
+    pad = jnp.concatenate(
+        [values.astype(jnp.float32), jnp.zeros((1,), jnp.float32)]
+    )
+    out = jnp.zeros((v,), jnp.float32)
+    for ids, sidx in zip(plan.vertex_ids, plan.send_idx):
+        width = f"w{sidx.shape[1]}"
+        with jax.named_scope("row_gather"), jax.named_scope(width):
+            mat = pad[sidx]
+        with jax.named_scope("row_sum"), jax.named_scope(width):
+            total = jnp.sum(mat, axis=1)
+        with jax.named_scope("write_back"):
+            out = out.at[ids].set(total, unique_indices=True, mode="drop")
+    if plan.hist_vertex_ids is not None:
+        with jax.named_scope("hub_sum"):
+            n_hist = plan.hist_vertex_ids.shape[0]
+            count = plan.hist_send.shape[0]
+            chunks = -(-count // _HUB_SUM_CHUNK)
+            hub = plan.hist_row_offset // jnp.int32(v)
+            chunk = jnp.arange(count, dtype=jnp.int32) // _HUB_SUM_CHUNK
+            partial_sums = jax.ops.segment_sum(
+                pad[plan.hist_send], hub * chunks + chunk,
+                num_segments=n_hist * chunks, indices_are_sorted=True,
+            )
+            total = jnp.sum(partial_sums.reshape(n_hist, chunks), axis=1)
+        with jax.named_scope("write_back"):
+            out = out.at[plan.hist_vertex_ids].set(
+                total, unique_indices=True, mode="drop"
+            )
+    return out
+
+
 # ---- carried rows: the gathered message rows as state across supersteps ----
 #
 # A class's gathered rows depend on the labels only through the senders

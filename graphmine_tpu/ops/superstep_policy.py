@@ -15,7 +15,9 @@ This module owns the choice (:func:`select_superstep_family`) and the
 read both from here. Beside it, for the one-chip LPA job over a fused
 plan: whether the gathered rows and their slot index go on the device
 (:func:`admit_carried_rows`) and the ``device_residency`` record that
-says what the device then holds (:func:`emit_device_residency`).
+says what the device then holds (:func:`emit_device_residency`); for the
+PageRank job over the same plan, which carries nothing, what its one
+iteration's program takes beside the plan (:func:`stepped_residency`).
 """
 
 from __future__ import annotations
@@ -27,6 +29,7 @@ from graphmine_tpu.obs.memmodel import (
     FAMILY_DEGRADE,
     carried_job_transients,
     carried_rows_inventory,
+    row_sum_transients,
     superstep_footprint,
 )
 
@@ -208,6 +211,35 @@ def admit_carried_rows(
     return "plain", (
         said + ": a full gather every superstep, nothing kept" + unsized
     )
+
+
+def stepped_residency(plan) -> tuple[str, str]:
+    """``("plain", reason)`` for the PageRank job over the fused ``plan``
+    (``ops/pagerank.py``, the message reading): the ``scan`` and
+    ``reason`` of its ``device_residency`` record. Nothing is carried and
+    nothing is chosen: every rank moves in every iteration, so every
+    iteration gathers every row anew (``plain``, the stateless scan's
+    word), no slot index is built, and one compiled iteration is stepped
+    from the host. The ``reason`` answers "does that program fit beside
+    this graph": its temporaries from the plan's shapes
+    (:func:`~graphmine_tpu.obs.memmodel.row_sum_transients`) against the
+    device's ``bytes_limit`` less ``bytes_in_use``, the graph and the plan
+    being in use already. A program that does not fit is still handed to
+    the device, which then says so itself: there is no leaner path to
+    take."""
+    need = row_sum_transients(plan)
+    said = (
+        "no rows carried, no slot index: every iteration gathers in full; "
+        "one compiled iteration stepped from the host, temporaries "
+        f"{need} B"
+    )
+    stats = device_memory_stats(plan) or {}
+    limit = stats.get("bytes_limit")
+    if not limit:
+        return "plain", said + "; the device reports no limit"
+    free = int(limit) - int(stats.get("bytes_in_use", 0))
+    fits = "fits" if need <= free else "DOES NOT FIT"
+    return "plain", said + f" against {free} B free of {int(limit)} B: {fits}"
 
 
 def crossover_thresholds() -> dict:

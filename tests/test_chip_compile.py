@@ -248,6 +248,41 @@ def test_carried_rows_programs_compile_for_v5e(
                                           else rows_bytes // 4)
 
 
+@pytest.mark.parametrize("graph", ["kronecker", "flat"])
+def test_pagerank_iteration_compiles_for_v5e_and_the_loop_holds_every_class(
+    one_chip, fused_plan, flat_plan, planted, graph
+):
+    """The one compiled iteration the message reading of ``gm.pagerank``
+    steps from the host (ISSUE 41): its temporaries are its largest
+    class's, at or under ``obs/memmodel.row_sum_transients``' count from
+    the plan's shapes. The same body inside a ``while_loop`` holds several
+    times that (every class's rows and indices at once: 5.26 GB against
+    1.13 GB at graph500-24's shapes, 11.0 against 2.43 GB at GAP Urand's),
+    which is why the job steps from the host and is not one program."""
+    import importlib
+
+    from graphmine_tpu.obs.memmodel import row_sum_transients
+
+    pagerank = importlib.import_module("graphmine_tpu.ops.pagerank")
+    host_graph, plan = fused_plan[0], fused_plan[1] if graph == "kronecker" else flat_plan
+    plan = _shapes(plan, one_chip)
+    v = planted[2]
+    f32 = jax.ShapeDtypeStruct((v,), jnp.float32, sharding=one_chip)
+    stepped = _compile(
+        pagerank._bucketed_iteration, f32, f32, f32, plan, 0.85, with_delta=False,
+    )
+    held = stepped.memory_analysis()
+    assert held.alias_size_in_bytes >= 4 * v  # the ranks are written where they were read
+    assert held.temp_size_in_bytes <= row_sum_transients(plan)
+    if graph == "kronecker":  # the loop needs a graph of the plan's own shapes
+        looped = _compile(
+            pagerank._pagerank_messages_jit, _shapes(host_graph, one_chip), plan,
+            0.85, 10, None, None,
+        )
+        assert looped.memory_analysis().temp_size_in_bytes > \
+            3 * held.temp_size_in_bytes
+
+
 def test_masked_lpa_plan_mask_compiles_for_v5e(one_chip, fused_plan, planted):
     """The recursive outlier pass's one program of its own size: the
     community mask over the plan's rows (ISSUE 30). Its supersteps are

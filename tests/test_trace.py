@@ -552,6 +552,12 @@ def test_a_served_delta_nests_its_ten_stage_spans_under_delta_apply(tmp_path):
     shared tail, in order, under `delta_apply`, in the CLIENT's trace;
     `apply_s` of the request's `delta_stages` is that span, and the
     record's `repair_seconds` / `lof_seconds` are two of the stages."""
+    import jax
+
+    # "a first delta compiles" is asserted below: true of a fresh process,
+    # not of an xdist worker that ran tests/test_serve.py before this file
+    # and still holds the delta's programs. Make it true here.
+    jax.clear_caches()
     sink = MetricsSink(tracer=Tracer())
     store, _ = _publish_base(tmp_path)
     srv = SnapshotServer(store, sink=sink, wal=str(tmp_path / "wal"))
