@@ -5,6 +5,8 @@ program a process so that the peak RSS is that program's compile alone.
     python _proof/size_programs.py _proof/g500_24_shapes.json            # all, one child each
     python _proof/size_programs.py _proof/g500_24_shapes.json modes      # one, in this process
     python _proof/size_programs.py _proof/urand_24_shapes.json gather out.txt  # and its compiled text
+    python _proof/size_programs.py _proof/g500_24_shapes.json pagerank_step  # gm.pagerank's iteration
+    python _proof/size_programs.py _proof/g500_22_shapes.json wcc            # WCC's while_loop
 
 A shapes file of a MESH partition (``shards`` in it: _proof/mesh_shapes_and_k.py,
 ISSUE 39) compiles the mesh job's programs (``parallel/sharded.py``) for the
@@ -106,7 +108,7 @@ def one(said, name, text_out=None):
     elif name.startswith("rewrite:"):
         cap = delta_rungs(said["num_messages"])[int(name.split(":")[1])]
         lowered = lpa._rewrite_program.lower(rows, labels, changed, plan, cap=cap)
-    elif name == "pagerank":  # ISSUE 41: the one program of gm.pagerank(directed=False)
+    elif name in ("pagerank", "pagerank_step", "wcc"):
         import dataclasses
         import importlib
 
@@ -118,9 +120,18 @@ def one(said, name, text_out=None):
         m = said["num_messages"]
         graph = Graph(src=i32(m // 2), dst=i32(m // 2), msg_recv=i32(m),
                       msg_send=i32(m), msg_ptr=i32(v + 1), num_vertices=v)
-        lowered = pagerank._pagerank_messages_jit.lower(
-            graph, dataclasses.replace(plan, out_ptr=None, out_slot=None),
-            0.85, 10, None, None)
+        plan = dataclasses.replace(plan, out_ptr=None, out_slot=None)
+        if name == "pagerank":  # ISSUE 41: ten iterations as ONE program (not run)
+            lowered = pagerank._pagerank_messages_jit.lower(
+                graph, plan, 0.85, 10, None, None)
+        elif name == "pagerank_step":  # the one iteration gm.pagerank steps from the host
+            f32 = jax.ShapeDtypeStruct((v,), jnp.float32, sharding=chip)
+            lowered = pagerank._bucketed_iteration.lower(
+                f32, f32, f32, plan, 0.85, with_delta=False)
+        else:  # WCC's loop, as connected_components(return_iterations=True) runs it
+            from graphmine_tpu.ops import cc
+
+            lowered = cc._connected_components.lower(graph, 0, True, plan)
     else:
         raise SystemExit(f"no program {name!r}")
     compiled = lowered.compile()

@@ -353,6 +353,10 @@ def _slots(mat) -> int:
 #     the compiler adds to keep it stable, in and out (four; three where
 #     the rows' slice is the matrix the sort reads: row-major, whole lanes);
 #   * both: the labels, padded, in two memory spaces at once;
+#   * not counted, because `lpa_modes_from_rows` bars it: a class that
+#     starts at a multiple of its width `w` in rows whose length is one too
+#     would be cut from a `[S / w, w]` view of ALL the rows, tiled to 128
+#     lanes (92.6 GB for graph500-24's plan at w = 3; PERF.md §6, PR 42);
 #   * the rewrite's compaction: a four-operand sort of V keys, in and out;
 #   * the rewrite's expansion: five cap-long vectors (the scattered
 #     differences and their running sums for source and value, the slot),

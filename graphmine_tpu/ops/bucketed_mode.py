@@ -24,7 +24,9 @@ arithmetic is ~10x cheaper), so the design minimizes *gathered slots*:
 - mega-hubs (degree > 2048) skip dense rows entirely: their neighbor
   labels scatter-add into a per-hub histogram over the label space and
   ``argmax`` picks the mode (first-max = smallest label, matching the
-  tie rule). This caps both padding and the widest sort compiles.
+  tie rule) — for as many hubs as ``_HIST_BUDGET // V`` admits (4 at 2^24
+  vertices, none on a mesh). The other rows past 2048 are dense rows on
+  the same 1.10x ladder, continued at its own step (``_extend_widths``).
 
 Power-law skew (SURVEY §7 hard part 3) is exactly what this absorbs: the
 million degree<=8 vertices ride in narrow rows while a degree-100K hub
@@ -57,8 +59,9 @@ _SENTINEL = jnp.iinfo(jnp.int32).max
 # host plan build kept growing — the kernel is AT the ~130M slots/s
 # measured gather roofline from here). Degrees 1-20 get exact widths
 # (zero padding where most power-law vertices live). Degrees beyond the
-# ladder (fused plans only) go to the histogram path; non-fused plans
-# extend the ladder as the max degree needs.
+# ladder go to the histogram path (fused plans only) as far as its budget
+# admits; every other row rides the ladder continued at the same step
+# (``_extend_widths``).
 _WIDTHS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15, 16, 17, 18,
            19, 20, 22, 24, 26, 28, 30, 33, 36, 39, 42, 46, 50, 55, 60, 66,
            72, 79, 86, 94, 103, 113, 124, 136, 149, 163, 179, 196, 215,
@@ -71,13 +74,15 @@ _HIST_BUDGET = 1 << 26    # max total int32 entries across all histograms
 
 
 def _extend_widths(max_deg: int) -> np.ndarray:
-    """The width ladder, extended by 1.5x steps beyond its 2048 cap to
-    cover ``max_deg`` (coarser out there on purpose: degrees past the
-    histogram threshold are few, so padding on their rows is cheap while
-    every extra wide class is another sort network to compile)."""
+    """The width ladder, continued past its last entry at the ladder's own
+    1.10x step (next width ``ceil(1.10 * last)``) until it covers
+    ``max_deg``: padding <= 10% holds for every row a plan builds, however
+    long. Rows past 2048 are not few where the histogram budget admits few
+    hubs (``_HIST_BUDGET // V``: 4 at 2^24 vertices, none on a mesh): on a
+    Kronecker graph at scale 24 they hold half the plan's slots."""
     ws = list(_WIDTHS)
     while ws[-1] < max_deg:
-        ws.append(ws[-1] + ws[-1] // 2)
+        ws.append(int(np.ceil(1.10 * ws[-1])))
     return np.asarray(ws, dtype=np.int64)
 
 
@@ -828,12 +833,19 @@ def lpa_modes_from_rows(
     :func:`lpa_superstep_bucketed` would give from ``labels`` when ``rows``
     hold those labels' gathered rows."""
     wmats = plan.weight_mat or (None,) * len(plan.vertex_ids)
-    out, off = labels.astype(jnp.int32), 0
+    out, off, s = labels.astype(jnp.int32), 0, rows.shape[0]
     with jax.named_scope("lpa_bucketed"):
         for ids, idx, wmat in zip(plan.vertex_ids, plan.send_idx, wmats):
-            n = idx.shape[0] * idx.shape[1]
-            out = _reduce_rows(
-                out, ids, rows[off:off + n].reshape(idx.shape), wmat
-            )
+            n, w = idx.shape[0] * idx.shape[1], idx.shape[1]
+            mat = rows[off:off + n]
+            if w > 1 and off % w == 0 and s % w == 0:
+                # Where the class starts at a multiple of its width in rows
+                # whose length is one too, the chip's compiler cuts the
+                # class out of a [S / w, w] view of ALL the rows, which it
+                # tiles to 128 lanes: 42 x the rows at w = 3 (92.6 GB for
+                # graph500-24's plan; PERF.md §6, PR 42). The barrier keeps
+                # the slice a slice; every other class's text is as it was.
+                mat = lax.optimization_barrier(mat)
+            out = _reduce_rows(out, ids, mat.reshape(idx.shape), wmat)
             off += n
         return _hist_modes(labels, out, plan)

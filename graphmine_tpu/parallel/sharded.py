@@ -488,7 +488,10 @@ def _build_shard_bucket_plan(deg, send_pad, counts, chunk_size, d, w_pad=None):
     ladder (``ops/bucketed_mode._extend_widths``); per class the row count
     is padded to the max across shards so one SPMD program serves all
     devices. No histogram path here — a per-shard [n, V] count matrix
-    would replicate per device; mega-hubs ride wide sort rows instead.
+    would replicate per device; mega-hubs ride wide sort rows instead, on
+    the same ladder at the same step however long the row (a tenth of
+    padding at most: at graph500-25 over four chips the rows past 2048
+    are nearly half a shard's slots).
 
     Two passes in threads: a stable argsort groups a shard's vertices by
     class and counts them (the row counts have to be known across shards

@@ -88,7 +88,7 @@ def _need(g, plan) -> int:
 
 # graphalytics-g500-24's fused plan, [n, w] a class (a host-only build of the
 # configuration's draw, _proof/shapes24.py): V = 2^24, M = 520,752,272,
-# S = 607,595,159 padded slots, four histogram hubs
+# S = 542,524,857 padded slots, four histogram hubs
 _G500_24_CLASSES = [
     (2157572, 1), (1102139, 2), (681618, 3), (465124, 4), (366473, 5),
     (330564, 6), (307882, 7), (269267, 8), (216382, 9), (159026, 10),
@@ -100,8 +100,11 @@ _G500_24_CLASSES = [
     (120469, 86), (39756, 94), (5562, 103), (311, 113), (5, 124), (73, 179),
     (3272, 196), (48159, 215), (169957, 236), (113231, 259), (11286, 284),
     (126, 312), (1011, 665), (70739, 731), (62433, 804), (413, 884),
-    (42504, 3072), (545, 6912), (10081, 10368), (2024, 23328), (276, 78732),
-    (21, 177147),
+    # past 2048 the ladder keeps its 1.10x step (PR 42; 1.5x before:
+    # (42504, 3072), (545, 6912), (10081, 10368), (2024, 23328),
+    # (276, 78732), (21, 177147), 607,595,159 slots in all)
+    (11092, 2253), (31411, 2479), (1, 2727), (7322, 7083), (3304, 7792),
+    (2024, 22240), (276, 63466), (21, 164623),
 ]
 _G500_24_LIMIT, _G500_24_IN_USE = 16_909_336_064, 8_850_000_000  # PERF.md §4
 
@@ -121,15 +124,20 @@ def _g500_24_plan():
 
 def test_the_inventory_of_graph500_24_s_plan_term_by_term():
     """Each term from the shapes, beside what the chip's compiler assigned
-    the programs compiled alone for a v5e (PERF.md §6, PR 36)."""
+    the programs compiled alone for a v5e (PERF.md §6, PR 36; PR 42 for
+    the plan whose rows past 2048 are on the 1.10x ladder)."""
     plan = _g500_24_plan()
-    v, m, s = plan.num_vertices, plan.num_messages, 607_595_159
+    v, m, s = plan.num_vertices, plan.num_messages, 542_524_857
     top = delta_rungs(m)[-1]
     assert row_slots(plan) == s and top == m // 6 == 86_792_045
     inv = memmodel.carried_rows_inventory(plan, top_rung=top)
-    widest = 42504 * 3072
+    # the largest class is [31411, 2479] now ([42504, 3072] before): no
+    # multiple of 128, so the chip keeps it column-major as 31488 x 2480
+    # and the flat rows' slice of it is 31416 rows of 2560 lanes
+    kept, lanes = 4 * 2_480 * 31_488, 4 * 31_416 * 2_560
+    assert memmodel._tiled(31_411, 2_479) == (kept, lanes) == (312_360_960, 321_699_840)
     assert inv == {
-        "carried_rows": 4 * s,            # 2.43 GB, once
+        "carried_rows": 4 * s,            # 2.17 GB, once (2.43 before)
         "slot_index": 4 * (m + v + 1),    # 2.15 GB
         "labels": 8 * v, "changed_mask": v,
         "hub_histograms": 8 * 4 * v,      # 0.54 GB
@@ -137,23 +145,27 @@ def test_the_inventory_of_graph500_24_s_plan_term_by_term():
         "gather_transient": 20 * top,
     }
     # without a rung the largest program is the row modes: the widest
-    # class's sort, key and stability iota in and out, the key read where
-    # it lies in the rows (three of the class), and the labels twice
-    # (compiled: 1,580,267,520, the histograms' 536,870,912 after the rows
-    # and not beside them); the full gather holds the class twice
-    # (compiled: 1,115,328,512)
+    # class's sort, key and stability iota in and out (four of the class
+    # as kept: its slice of the rows is not the matrix the sort reads),
+    # and the labels twice (compiled: 1,667,266,560, which is 0.28 GB over
+    # this term: a hub histogram's 268,435,456 lies beside the sort now,
+    # inside the inventory's own `hub_histograms` beside it); the full
+    # gather holds the class as kept and as its lanes (compiled: 705,036,800)
     assert memmodel.carried_job_transients(plan, top_rung=top) == {
-        "gather": 8 * widest + 8 * (v + 1), "modes": 12 * widest + 8 * (v + 1),
+        "gather": kept + lanes + 8 * (v + 1), "modes": 4 * kept + 8 * (v + 1),
         "rewrite": 20 * top}
-    assert 20 * top > 12 * widest + 8 * (v + 1) > 8 * widest + 8 * (v + 1) > 32 * v
+    assert 20 * top > 4 * kept + 8 * (v + 1) > kept + lanes + 8 * (v + 1) > 32 * v
     no_rung = memmodel.carried_rows_inventory(plan)
-    assert no_rung["gather_transient"] == 12 * widest + 8 * (v + 1) == 1_701_085_192
+    assert no_rung["gather_transient"] == 4 * kept + 8 * (v + 1) == 1_383_661_576
     # a lower rung's rewrite is its sort of V keys, in and out (compiled:
     # 537,257,984 at M / 4096)
     low = memmodel.carried_rows_inventory(plan, top_rung=delta_rungs(m)[0])
     assert low["gather_transient"] == no_rung["gather_transient"]
     assert 32 * v == 536_870_912 < no_rung["gather_transient"]
-    assert sum(inv.values()) == 7_004_205_348
+    assert sum(inv.values()) == 6_743_924_140
+    # PageRank's one iteration on the same plan (compiled: 716,777,984)
+    assert memmodel.row_sum_transients(plan) == kept + lanes + 8 * (v + 1) + 20 * v \
+        == 1_103_822_856
 
 
 # GAP Urand at scale 24 (benchmark/configs/gap-urand-24.json): V = 2^24,
@@ -230,6 +242,7 @@ def test_the_crossover_the_transients_count_by_is_the_plan_builder_s():
     (1000, 33, (4 * 40 * 1024, 4 * 1000 * 128)),
     (1001, 128, (4 * 1008 * 128,) * 2),   # whole lanes: row-major is the smaller
     (42504, 3072, (4 * 42504 * 3072,) * 2),
+    (31411, 2479, (4 * 2480 * 31488, 4 * 31416 * 2560)),  # no whole lanes, either way
     (41, 66, (4 * 48 * 128,) * 2),
 ], ids=lambda x: str(x))
 def test_a_class_s_bytes_on_the_chip_follow_its_tiles(n, w, want):
@@ -238,17 +251,18 @@ def test_a_class_s_bytes_on_the_chip_follow_its_tiles(n, w, want):
 
 @pytest.mark.parametrize("in_use,want", [
     (_G500_24_IN_USE, "carried"),                            # 8.06 GB free: the cell
-    (_G500_24_LIMIT - 7_004_205_348, "carried"),             # to the byte
-    (_G500_24_LIMIT - 7_004_205_348 + 1, "plain"),
-    (_G500_24_LIMIT - 4 * 607_595_159, "plain"),             # room for the rows alone
+    (_G500_24_LIMIT - 6_743_924_140, "carried"),             # to the byte
+    (_G500_24_LIMIT - 6_743_924_140 + 1, "plain"),
+    (_G500_24_LIMIT - 4 * 542_524_857, "plain"),             # room for the rows alone
 ], ids=["beside-the-resident-graph", "exactly", "a-byte-short", "rows-only"])
 def test_graph500_24_s_plan_is_admitted_beside_its_device_resident_graph(in_use, want):
-    """The parent counted the rows four times (12.41 GB) and answered
-    ``plain`` at 8.06 GB free; held once the job asks for 7.00 GB."""
+    """PR 33 counted the rows four times (12.41 GB) and answered ``plain``
+    at 8.06 GB free; held once the job asked for 7.00 GB (PR 36), and 6.74
+    GB since the ladder keeps its step past 2048 (PR 42)."""
     scan, reason = admit_carried_rows(
         _g500_24_plan(), {"bytes_limit": _G500_24_LIMIT, "bytes_in_use": in_use})
     assert scan == want
-    assert "7004205348 B" in reason and "held once" in reason
+    assert "6743924140 B" in reason and "held once" in reason
     assert f"against {_G500_24_LIMIT - in_use} B free" in reason
     assert "not sized" in reason
 
@@ -413,7 +427,7 @@ def test_without_a_sink_the_record_asks_the_device_nothing(monkeypatch):
 # and the shards' message counts from a host-only build of the
 # configuration's own draw (_proof/mesh_shapes_and_k.py): no draw here
 _X4_LIMIT, _X4_IN_USE = 16_909_336_064, 1_986_000_000  # PERF.md §4: 11.7 % a chip
-_X4_SUM = 5_250_178_152
+_X4_SUM = 4_957_738_916
 
 
 def _g500_25_x4():
@@ -444,40 +458,41 @@ def test_the_inventory_of_a_shard_of_graph500_25_term_by_term():
     the chip's compiler assigned the mesh programs compiled for four
     described chips from these shapes (PERF.md §6, PR 39)."""
     plan, d = _g500_25_x4()
-    v_pad, largest, s = 1 << 25, 265_143_516, 307_343_931
+    v_pad, largest, s = 1 << 25, 265_143_516, 278_577_418
     assert (d, plan.num_vertices, plan.num_messages, row_slots(plan)) == (
         4, v_pad, largest, s)
-    assert len(plan.send_idx) == 62 and plan.hist_vertex_ids is None
+    # no hubs on a mesh: every vertex past 2048 is a row, on the ladder's own
+    # 1.10x step since PR 42 (ten classes past 2048, the longest [1, 687684];
+    # six on the 1.5x steps, 62 classes and 307,343,931 slots in all)
+    assert len(plan.send_idx) == 66 and plan.hist_vertex_ids is None
     rungs = delta_rungs(largest)
     assert rungs == (64_732, 1_035_716, 16_571_469, 44_190_586)
     # the gather holds its largest class as kept and as its row-major form,
     # as on one chip; the mesh `modes` its three largest classes at once
-    # (its classes do not take turns; compiled, the two largest): the row
-    # sort of [13363, 4608] where it lies in the rows (three of the class:
-    # whole lanes), of [3197, 15552] and of [38187, 1175] (four each: 121.5
-    # and 9.2 lanes); the labels, padded, twice
-    # (as the chip tiles them: 13363 rows up to 13368; [3197, 15552] kept
-    # column-major as 3200 x 15552; [38187, 1175] as 38272 x 1176)
-    wide, wider, third = 13_368 * 4_608 * 4, 3_200 * 15_552 * 4, 38_272 * 1_176 * 4
+    # (its classes do not take turns): the row sorts of [13302, 3632],
+    # [38187, 1175] and [3197, 11411], four of each as the chip keeps it
+    # (column-major: 13312 x 3632, 38272 x 1176, 3200 x 11416; none is whole
+    # lanes where it lies in the rows); the labels, padded, twice
+    wide, wider, third = 13_312 * 3_632 * 4, 38_272 * 1_176 * 4, 3_200 * 11_416 * 4
+    lanes = 13_304 * 3_712 * 4  # [13302, 3632] row-major
     labels = 8 * (v_pad + 1)
     assert memmodel.carried_job_transients(plan, top_rung=rungs[-1], shards=d) == {
-        "gather": 2 * wide + labels,                         # compiled: 628,905,984
-        "modes": 3 * wide + 4 * wider + 4 * third + labels,  # compiled: 1,802,584,064
+        "gather": wide + lanes + labels,                     # compiled: 527,318,016
+        "modes": 4 * (wide + wider + third) + labels,        # compiled: 1,678,616,576
         "rewrite": 32 * v_pad,             # compiled: 1,074,322,432 at most (M/16)
     }
     # on one chip the classes take turns: the same shapes count one class
-    # (compiled for one chip from these shapes: 629,164,032 and 1,006,589,440)
     assert memmodel.carried_job_transients(plan, top_rung=rungs[-1]) == {
-        "gather": 2 * wide + labels, "modes": 4 * wider + labels,
+        "gather": wide + lanes + labels, "modes": 4 * wide + labels,
         "rewrite": 32 * v_pad}
     assert 32 * v_pad > 20 * rungs[-1]  # the sort of V keys, not the top rung
     inv = memmodel.carried_rows_inventory(plan, top_rung=rungs[-1], shards=d)
     assert inv == {
-        "carried_rows": 4 * s,                           # 1.23 GB, once
+        "carried_rows": 4 * s,                           # 1.11 GB, once (1.23 before)
         "slot_index": 4 * (largest + v_pad + 1),         # 1.19 GB
         "labels": 8 * v_pad, "changed_mask": v_pad,      # replicated: a chip holds all
         "hub_histograms": 0,                             # no hubs on a mesh
-        "gather_transient": 3 * wide + 4 * wider + 4 * third + labels,
+        "gather_transient": 4 * (wide + wider + third) + labels,
     }
     assert sum(inv.values()) == _X4_SUM
 

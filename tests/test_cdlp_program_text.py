@@ -26,7 +26,23 @@ at the top and the lowest rung, ``_mesh_modes_program``, over four devices)
 and the one-program mesh scan ``_sharded_lpa_jit``, which a ``plain``
 admission still runs and whose text that PR did not move; the nine
 one-chip digests stand: the mesh job calls the one-chip row functions and
-edits none.
+edits none. PR 42 replaced the five mesh digests and no other: the width
+ladder keeps its 1.10x step past 2048 (``_extend_widths``), and on a mesh,
+which has no histogram path, this graph's hub of degree 2,111 is a row, of
+width 2253 where it was 3072; no function of ``parallel/sharded.py`` was
+edited. On one chip the hub is a histogram and no row is past 2048, so the
+one-chip digests stand but one, which is the proof that the programs of a
+plan with no row past 2048 (GAP Urand's, the pipeline's) did not move. The
+one: ``_modes_program`` gained an ``optimization_barrier`` around the
+slice of a class that starts at a multiple of its width ``w`` in rows whose
+length is a multiple of ``w`` too, because for such a class the chip's
+compiler cuts the slice out of a ``[S / w, w]`` view of ALL the rows, tiled
+to 128 lanes (42 times the rows at w = 3: step 0 of PR 42 could not compile
+graph500-24's ``modes``, 92.6 GB). This graph has three such classes, so
+its digest moved; lowered with the barrier taken out the text is the
+parent's to the byte (``_modes_program:no-barrier``), and a plan with no
+such class (Urand's, graph500-22's, a shard of graph500-25) lowers no
+barrier at all (``test_a_barrier_stands_where_the_rows_would_be_viewed_whole``).
 """
 
 import dataclasses
@@ -119,6 +135,16 @@ def _lowered(name):
         return _gather_program.lower(rows, labels, plan)
     if name == "_modes_program":
         return _modes_program.lower(rows, labels, plan)
+    if name == "_modes_program:no-barrier":
+        # a jit of a function object of its own, so that no trace is shared
+        # with (or left in the cache of) the program the job runs
+        def _modes_program_(rows, labels, plan):
+            return _modes_program.__wrapped__(rows, labels, plan)
+
+        _modes_program_.__name__ = "_modes_program"
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+            return jax.jit(_modes_program_).lower(rows, labels, plan)
     changed = jax.ShapeDtypeStruct((g.num_vertices,), jnp.bool_)
     rung = delta_rungs(g.num_messages)[int(name.rsplit(":", 1)[1])]
     return _rewrite_program.lower(rows, labels, changed, plan, cap=rung)
@@ -133,7 +159,13 @@ _PARENT_DIGESTS = {
         "c65ca4a6c2759859380430036393bd2d46d3bc1d496320a65d7eff2f851a16ac",
     "_gather_program":
         "1e713dce6aa57bd64d11d8dd3c27a7431abf543c911336e84f36bcf1cee8d3af",
+    # PR 42: three of this graph's classes ([2, 2], [144, 6], [261, 8]) start
+    # at a multiple of their width in rows whose length (20,112) is one too,
+    # and `lpa_modes_from_rows` cuts such a class behind a barrier; with the
+    # barrier taken out the text is the parent's, to the byte
     "_modes_program":
+        "ba132ac488bef5d200dc16f1894878aa91c2b94a4a97e2beacf97a2d4bb004c5",
+    "_modes_program:no-barrier":
         "b69dc9867c6e86f00844a1470059cddf9eb035a1292a578f801905726ec23dc4",
     "_rewrite_program:0":
         "1e1c513ed232b5f7cbf2c60519a81ae4f95dbb14b9a2a7636f14105106e5f0ed",
@@ -143,18 +175,17 @@ _PARENT_DIGESTS = {
         "bfa41019117a6942bb1f37c04b145be552b4f725ac2f89874d067a88ccd9a777",
     "_rewrite_program:3":
         "f76dd23541cb9296c6dd4e4296cad7b168df6ec5639b6908ddeb61afdebf2d7d",
-    # on a mesh of four (PR 39): the one-program scan as the parent of
-    # PR 39 lowered it, and the carried job's programs as that PR wrote them
+    # on a mesh of four (PR 39's programs; PR 42: the hub's row is 2253 wide)
     "_sharded_lpa_jit":
-        "a89ef237aa464644ed93b1587389e48c5eacc6b0b0954a763d8b34a5231bd2dc",
+        "f131e22a2d2e795f147a58e6e9d0e1ae9ed043393d5e5d18406dac8143158112",
     "_mesh_gather_program":
-        "c28df3a4bd597485f35b51c77df0aae07be1c6de2391f7dd1b1b3ddb2e2190bf",
+        "6e2d7d99a50ed9da2f4c5a6ebe87654422ff31e392ef41d9967d730f7549b009",
     "_mesh_modes_program":
-        "0e82c253efdb430c9dbf7a297363c24323d180d72f38f327ee20c3936286cf21",
+        "4b21300e7f05ca04a8a85c72025f061d4a61ebd62a5ef52523f4689f6ae9d88e",
     "_mesh_rewrite_program:0":
-        "fd1e6248a9168110370d9a9abd4983c33a736a989636a684ca7620ef421489c8",
+        "fed2fa1decc8587ab7c5965b89516fd319911b7c5256e63ab5bbb9902de2148b",
     "_mesh_rewrite_program:3":
-        "214f0b732100bf51068a2d7239f3a734f10bdc3b740a73d951398ab497181a79",
+        "6a3e5325db379920ec04a5336e46aa282f8f5264e5719d1d0e5cf7f5cb2e8313",
 }
 
 
@@ -172,8 +203,11 @@ def test_each_class_s_gather_is_in_the_gather_program_once_and_in_no_other():
     the job picks a branch on the device: the host does."""
     _, plan = _graph_and_plan()
     texts = {name: _lowered(name).as_text() for name in _PARENT_DIGESTS
-             if name.startswith(("_gather", "_modes", "_rewrite"))}
+             if name.startswith(("_gather", "_modes", "_rewrite")) and ":no-" not in name}
     assert len(texts) == 6
+    # this graph's three classes that would be viewed whole, and no other
+    assert texts["_modes_program"].count("stablehlo.optimization_barrier") == len(
+        _viewed_whole([idx.shape for idx in plan.send_idx])) == 3
     for name, text in texts.items():
         assert "stablehlo.case" not in text and "stablehlo.while" not in text
         gathers = [ln for ln in text.splitlines() if "stablehlo.gather" in ln]
@@ -181,3 +215,43 @@ def test_each_class_s_gather_is_in_the_gather_program_once_and_in_no_other():
             n, w = idx.shape
             rows = [ln for ln in gathers if ln.endswith(f"-> tensor<{n}x{w}xi32>")]
             assert len(rows) == (name == "_gather_program"), (name, n, w, len(rows))
+
+
+def _viewed_whole(classes):
+    """The classes the chip's compiler would cut from a view of all the
+    rows: those that start at a multiple of their width in rows whose
+    length is a multiple of it."""
+    s, off, hit = sum(n * w for n, w in classes), 0, []
+    for n, w in classes:
+        if w > 1 and off % w == 0 and s % w == 0:
+            hit.append((n, w))
+        off += n * w
+    return hit
+
+
+@pytest.mark.parametrize("shapes,hit", [
+    ("urand_24", []), ("g500_22", []), ("g500_24", [(681618, 3)]), ("g500_25_x4", []),
+])
+def test_a_barrier_stands_where_the_rows_would_be_viewed_whole(shapes, hit):
+    """``_modes_program`` over the benchmark's plans, by shapes
+    (``_proof/*_shapes.json``; a shard's, for the mesh): one barrier for
+    each class that starts at a multiple of its width in rows whose length
+    is one too, and none in a plan that has no such class: GAP Urand's
+    program is the text it was."""
+    import json
+    import os
+
+    said = json.load(open(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "_proof", shapes + "_shapes.json")))
+    classes = [tuple(c) for c in said["classes"]]
+    assert _viewed_whole(classes) == hit
+    i32 = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.int32)
+    v = said["num_vertices"]
+    plan = BucketedModePlan(
+        vertex_ids=tuple(i32(n) for n, _ in classes), msg_idx=None,
+        num_vertices=v, num_messages=said["num_messages"],
+        send_idx=tuple(i32(n, w) for n, w in classes),
+        out_ptr=i32(v + 1), out_slot=i32(1),
+    )
+    text = _modes_program.lower(i32(said["slots"]), i32(v), plan).as_text()
+    assert text.count("stablehlo.optimization_barrier") == len(hit)
