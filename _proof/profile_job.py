@@ -10,7 +10,8 @@ through the public entry with a sink, inside a capture. The capture does
 NOT put op metadata into the compile-cache key (``maybe_profile`` does, and
 every program would compile anew): run it in a call whose cache this
 checkout alone has filled. Prints JSON lines; the last holds the scopes,
-and the rows' passes by width class (``by_width``: ``row_mode/w33``)."""
+and the rows' passes by width class (``by_width``: ``row_mode/w33``,
+``dirty_rows/w4096``)."""
 import json
 import os
 import sys
@@ -89,7 +90,7 @@ def main():
         name = row["module"]
         by_program[name] = by_program.get(name, 0.0) + row["device_seconds"]
     # the third scope level: the rows' passes by width class (`w<width>`)
-    passes = frozenset(("row_gather", "row_mode", "row_sum"))
+    passes = frozenset(("row_gather", "row_mode", "row_sum", "dirty_rows"))
     by_width = {}
     for row in devtrace.reduce_capture(
             *planes, passes | {f"w{w}" for w in range(1, 1 << 15)})["scopes"]:

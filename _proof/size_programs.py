@@ -7,6 +7,8 @@ program a process so that the peak RSS is that program's compile alone.
     python _proof/size_programs.py _proof/urand_24_shapes.json gather out.txt  # and its compiled text
     python _proof/size_programs.py _proof/g500_24_shapes.json pagerank_step  # gm.pagerank's iteration
     python _proof/size_programs.py _proof/g500_22_shapes.json wcc            # WCC's while_loop
+    python _proof/size_programs.py _proof/g500_24_shapes.json rewrite:0:marked  # the rewrite that says which rows it wrote to
+    python _proof/size_programs.py _proof/g500_24_shapes.json dirty_modes:0     # the reduce over those rows (ISSUE 43)
 
 A shapes file of a MESH partition (``shards`` in it: _proof/mesh_shapes_and_k.py,
 ISSUE 39) compiles the mesh job's programs (``parallel/sharded.py``) for the
@@ -105,9 +107,15 @@ def one(said, name, text_out=None):
         lowered = lpa._gather_program.lower(rows, labels, plan)
     elif name == "modes":
         lowered = lpa._modes_program.lower(rows, labels, plan)
-    elif name.startswith("rewrite:"):
+    elif name.startswith("rewrite:"):  # rewrite:<place>[:marked] (ISSUE 43)
         cap = delta_rungs(said["num_messages"])[int(name.split(":")[1])]
-        lowered = lpa._rewrite_program.lower(rows, labels, changed, plan, cap=cap)
+        lowered = lpa._rewrite_program.lower(
+            rows, labels, changed, plan, cap=cap, marked=name.endswith(":marked"))
+    elif name.startswith("dirty_modes:"):  # after the marked rewrite at <place> (ISSUE 43)
+        cap = delta_rungs(said["num_messages"])[int(name.split(":")[1])]
+        total = sum(n for n, _ in said["classes"])
+        dirty = jax.ShapeDtypeStruct((min(cap, total),), jnp.int32, sharding=chip)
+        lowered = lpa._dirty_modes_program.lower(rows, labels, dirty, plan)
     elif name in ("pagerank", "pagerank_step", "wcc"):
         import dataclasses
         import importlib
@@ -157,5 +165,6 @@ if __name__ == "__main__":
     if len(sys.argv) > 2:
         one(said, *sys.argv[2:4])
     else:
-        for name in ["gather", "rewrite:0", "rewrite:1", "rewrite:2", "rewrite:3", "modes"]:
+        for name in ["gather", "rewrite:0", "rewrite:1", "rewrite:2", "rewrite:3", "modes",
+                     "rewrite:0:marked", "dirty_modes:0"]:
             subprocess.run([sys.executable, os.path.abspath(__file__), sys.argv[1], name])

@@ -360,12 +360,34 @@ def _slots(mat) -> int:
 #   * the rewrite's compaction: a four-operand sort of V keys, in and out;
 #   * the rewrite's expansion: five cap-long vectors (the scattered
 #     differences and their running sums for source and value, the slot),
-#     after the sort's operands are dead.
+#     after the sort's operands are dead. A marked rewrite (PR 43: the
+#     lowest rung's, which also says which rows it wrote to) holds as many
+#     again for the rows' numbers and their two sorts; at a rung of M / 4096
+#     that is a three-hundredth of what the top rung's five take, so it is
+#     never the largest and has no term (graph500-24: 538,084,864 B marked
+#     against the sort's 536,870,912 B; 554,551,808 B at M / 256);
+#   * the dirty reduce (PR 43: `lpa_modes_from_dirty_rows`): ten V-long
+#     vectors (the labels in two memory spaces, the loops' carry, the new
+#     labels, and the count of K: the changed mask, the out-degree's two
+#     slices and their difference, the select), which are all it holds at a
+#     small V (3,644,928 B at 2^16 vertices); the classes' vertex ids laid
+#     end to end; and one trip's rows: the pairwise count's three forms of
+#     `[64, 32, 32]`, and the widest coarse width's `[8, W]` through the
+#     sort and the run scan (eight forms). Its loops take turns and a trip
+#     holds one group's rows, never the flat rows. At 2^24 vertices the
+#     compiler holds fewer of the V-vectors at once (graph500-24:
+#     677,249,536 B with the hubs' histograms' 536,870,912 B in it; GAP
+#     Urand: none): an over-count, under the top rung's rewrite wherever a
+#     vertex sends nine messages or more.
 _TILE = (8, 128)
 _PAIRWISE_MAX_W = 32  # ops/bucketed_mode.py's (this module imports no jax): wider classes are sorted
 _REWRITE_SORT_WORDS = 8
 _REWRITE_CAP_WORDS = 5
 _MESH_CLASSES_AT_ONCE = 3  # carried_job_transients: the mesh `modes` program's classes overlap
+_DIRTY_V_WORDS = 10
+_DIRTY_TRIP_ROWS = 8  # ops/bucketed_mode.py's _DIRTY_GROUP_ROWS: the rows a trip of the dirty reduce sorts
+_DIRTY_TRIP_FORMS = 8
+_DIRTY_PAIRWISE_SLOTS = 3 * 2048 * _PAIRWISE_MAX_W  # three forms of [2048 / 32, 32, 32]
 
 
 def _tiled(n: int, w: int) -> tuple[int, int]:
@@ -382,8 +404,9 @@ def _tiled(n: int, w: int) -> tuple[int, int]:
 
 def carried_job_transients(plan, top_rung: int = 0, shards: int = 1) -> dict:
     """Bytes of temporaries by program of the carried-rows job, from the
-    plan's shapes (the note above): ``gather``, ``modes`` and ``rewrite``
-    at ``top_rung`` messages (0 without a rung). The hubs' histograms are
+    plan's shapes (the note above): ``gather``, ``modes``, ``rewrite`` at
+    ``top_rung`` messages (0 without a rung) and, where the one-chip job
+    has one, ``dirty_modes``. The hubs' histograms are
     the inventory's own term. A weighted plan's reduce holds more (the
     weights ride through the sort) and no compile holds its count: at
     2^16 vertices the compiler kept one class's ``[n, w, w]`` pairwise
@@ -421,7 +444,7 @@ def carried_job_transients(plan, top_rung: int = 0, shards: int = 1) -> dict:
         modes.append(max(kept + row, reduce))
     at_once = _MESH_CLASSES_AT_ONCE if shards > 1 else 1
     labels = 2 * _I32 * (v + 1)
-    return {
+    by_program = {
         "gather": max(gather, default=0) + labels,
         "modes": sum(sorted(modes, reverse=True)[:at_once]) + labels,
         "rewrite": max(
@@ -429,6 +452,18 @@ def carried_job_transients(plan, top_rung: int = 0, shards: int = 1) -> dict:
             _REWRITE_CAP_WORDS * _I32 * int(top_rung),
         ) if top_rung else 0,
     }
+    if top_rung and shards == 1 and not weighted:
+        # the one-chip job's second reduce, which runs after a marked
+        # rewrite (no rung, no rewrite; the mesh job and a weighted plan's
+        # keep the full reduce)
+        shapes = [(int(i.shape[0]), int(i.shape[1])) for i in plan.send_idx or ()]
+        widest = max((w for _, w in shapes), default=1)
+        coarse = max(_PAIRWISE_MAX_W, 1 << (widest - 1).bit_length())
+        by_program["dirty_modes"] = _I32 * (
+            _DIRTY_V_WORDS * (v + 1) + sum(n for n, _ in shapes)
+            + _DIRTY_PAIRWISE_SLOTS + _DIRTY_TRIP_FORMS * _DIRTY_TRIP_ROWS * coarse
+        )
+    return by_program
 
 
 def row_sum_transients(plan) -> int:

@@ -156,9 +156,19 @@ register("fixpoint", "op", "supersteps", "changed", "num_vertices", "family")
 # `pmax`), which picks one branch for every shard; `changed_vertices` is
 # the whole graph's. The mesh entry writes no record where the one
 # compiled program runs (`impl_selected` says `scan: plain`): that program
-# counts nothing a superstep.
+# counts nothing a superstep. `reduce`, `dirty_rows`, `dirty_slots` (PR 43),
+# one a superstep: after a rewrite on the lowest rung
+# (ops/superstep_policy.DIRTY_REDUCE_TOP_PLACE) the one-chip job reduces
+# only the rows the rewrite wrote to (`"dirty"`: a row that was not
+# rewritten keeps its mode), and says how many of the plan's rows those
+# were and how many slots they hold, the histogram hubs apart (they run in
+# every superstep); where every row was reduced (`"full"`: after a full
+# gather, on the rungs above, in every superstep of the stateless scan, of
+# a weighted plan's job and of the mesh job) the two are the plan's rows
+# and S (a shard's, on a mesh).
 register("superstep_delta", "op", "changed_vertices", "changed_messages",
-         "branch", "rungs", "num_messages")
+         "branch", "rungs", "num_messages", "reduce", "dirty_rows",
+         "dirty_slots")
 
 # device_residency: one per plan materialisation of a one-device
 # `label_propagation(..., plan="auto", sink=)` call on the bucketed family
@@ -402,7 +412,11 @@ DEVICE_SCOPES = frozenset((
     "segment_min", "sort", "run_reduce", "mask", "exchange",
     "changed_count", "converged",
     # carried rows (ops/bucketed_mode.rewrite_rows): outer, then its passes
-    "delta", "compact", "expand", "scatter",
+    # (`mark`: the marked rewrite's list of the rows it wrote to, PR 43)
+    "delta", "compact", "expand", "scatter", "mark",
+    # the reduce over those rows alone, under lpa_bucketed; a coarse width
+    # `w<width>` may follow (ops/bucketed_mode.lpa_modes_from_dirty_rows)
+    "dirty_rows",
     # inner: census / modularity
     "sizes", "edge_counts", "q",
     # inner: features / triangles

@@ -353,7 +353,12 @@ def _rowwise_mode(lbl: jax.Array) -> jax.Array:
     """Mode of each row of a ``[n, w]`` int32 matrix; sentinel entries
     ignored; ties break toward the smallest value. Rows must contain at
     least one non-sentinel entry."""
-    s = jnp.sort(lbl, axis=1)
+    return _sorted_rows_mode(jnp.sort(lbl, axis=1))
+
+
+def _sorted_rows_mode(s: jax.Array) -> jax.Array:
+    """:func:`_rowwise_mode` of rows already sorted ascending: the longest
+    run of each row, the first of the longest."""
     w = s.shape[1]
     pos = jnp.arange(w, dtype=jnp.int32)[None, :]
     new_run = jnp.concatenate(
@@ -769,26 +774,13 @@ def gather_rows(rows: jax.Array, labels: jax.Array, plan: BucketedModePlan):
     return rows
 
 
-def rewrite_rows(
+def _rewrite_rows_and_slots(
     rows: jax.Array, labels: jax.Array, changed: jax.Array,
     plan: BucketedModePlan, cap: int,
 ):
-    """The slots of ``rows`` behind the ``changed`` senders rewritten with
-    their ``labels``; every other slot stays. ``cap`` (static) bounds the
-    messages the changed senders send, the caller's promise: the rows then
-    equal :func:`gather_rows`'s slot for slot.
-
-    Two per-index passes over ``cap`` (read ``out_slot``, scatter the
-    label) instead of one over every slot of the graph. The changed
-    senders are compacted by one sort that carries their ``out_ptr`` span
-    and label with the key (at most ``min(cap, V)`` of them: each sends a
-    message); the spans are laid end to end, and each span's offset into
-    ``out_slot`` and its sender's label are spread over the span by one
-    scattered difference at the span's start and a ``cumsum``, so nothing
-    is looked up per slot but the slot itself. Measured on a TPU v5e at
-    V = 2^22 (PERF.md §6, PR 32): the sort 12-16 ms whatever ``cap``, a
-    read of ``out_slot`` 23 ns and a scatter into the rows 8 ns a place
-    of ``cap``."""
+    """``(rows, slot)``: :func:`rewrite_rows`, and the ``cap`` flat slots
+    it wrote to; a place past the last changed sender's span names slot
+    ``S``, which is none."""
     v, m, s = plan.num_vertices, plan.out_slot.shape[0], rows.shape[0]
     senders = min(cap, v)
     out_deg = plan.out_ptr[1:] - plan.out_ptr[:-1]
@@ -823,7 +815,210 @@ def rewrite_rows(
             slot = jnp.where(
                 place < end[-1], plan.out_slot[jnp.clip(source, 0, m - 1)], s
             )
-            return rows.at[slot].set(value, mode="drop")
+            return rows.at[slot].set(value, mode="drop"), slot
+
+
+def rewrite_rows(
+    rows: jax.Array, labels: jax.Array, changed: jax.Array,
+    plan: BucketedModePlan, cap: int,
+):
+    """The slots of ``rows`` behind the ``changed`` senders rewritten with
+    their ``labels``; every other slot stays. ``cap`` (static) bounds the
+    messages the changed senders send, the caller's promise: the rows then
+    equal :func:`gather_rows`'s slot for slot.
+
+    Two per-index passes over ``cap`` (read ``out_slot``, scatter the
+    label) instead of one over every slot of the graph. The changed
+    senders are compacted by one sort that carries their ``out_ptr`` span
+    and label with the key (at most ``min(cap, V)`` of them: each sends a
+    message); the spans are laid end to end, and each span's offset into
+    ``out_slot`` and its sender's label are spread over the span by one
+    scattered difference at the span's start and a ``cumsum``, so nothing
+    is looked up per slot but the slot itself. Measured on a TPU v5e at
+    V = 2^22 (PERF.md §6, PR 32): the sort 12-16 ms whatever ``cap``, a
+    read of ``out_slot`` 23 ns and a scatter into the rows 8 ns a place
+    of ``cap``."""
+    return _rewrite_rows_and_slots(rows, labels, changed, plan, cap)[0]
+
+
+# ---- dirty rows: the reduce of a sparse superstep (ISSUE 43) ----
+#
+# labels_t[v] is the mode of rows_{t-1}[v], and a rewrite brings rows_t up
+# to labels_t slot for slot, touching a row only where a sender's label
+# moved. A row with no rewritten slot is the row it was, so its mode is the
+# label its vertex already holds: only the DIRTY rows, those that hold a
+# rewritten slot, can move. Exact, no tolerance. In the quiet tail of a
+# Kronecker job the dirty rows are a fifth of a per cent of the rows and a
+# twentieth to a tenth of the slots (graph500-22: 3,942 of 2.4 M rows,
+# 4.9 % of S; graph500-24: 20,809 of 8.9 M, 9.3 %: the hubs' rows, touched
+# by leaves that flicker; _proof/dirty_rows_replay.py), so the reduce runs
+# over them alone.
+#
+# A row of the plan has a number: the classes' rows counted end to end, as
+# their slots lie in the flat buffer.
+
+
+def _class_tables(plan: BucketedModePlan):
+    """``(slot offsets [C + 1], row offsets [C + 1], widths [C])`` of the
+    plan's classes as they lie in the flat rows (host ints)."""
+    n = np.asarray([idx.shape[0] for idx in plan.send_idx], np.int64)
+    w = np.asarray([idx.shape[1] for idx in plan.send_idx], np.int64)
+    zero = np.zeros(1, np.int64)
+    return (
+        np.concatenate([zero, np.cumsum(n * w)]),
+        np.concatenate([zero, np.cumsum(n)]), w,
+    )
+
+
+def _pick(ge: jax.Array, table) -> jax.Array:
+    """``table[sum(ge, axis=1)]`` for ``ge[i, c] = x[i] >= bound[c + 1]``
+    over ascending bounds, as a telescoped sum of the table's steps: a
+    lookup in a table of a few dozen entries with no gather."""
+    table = np.asarray(table, np.int64)
+    step = jnp.asarray(np.diff(table), jnp.int32)
+    return jnp.int32(table[0]) + jnp.sum(
+        jnp.where(ge, step[None, :], 0), axis=1, dtype=jnp.int32
+    )
+
+
+def rewrite_rows_marked(
+    rows: jax.Array, labels: jax.Array, changed: jax.Array,
+    plan: BucketedModePlan, cap: int,
+):
+    """``(rows, dirty)``: :func:`rewrite_rows`, and the numbers of the rows
+    it wrote to, ascending and each once, ``int32 [min(cap, rows of the
+    plan)]`` padded with the plan's count of rows: what
+    :func:`lpa_modes_from_dirty_rows` reduces. A place's row comes from
+    its slot by arithmetic (the slot's class by comparing it to the few
+    dozen class offsets, then ``(slot - class offset) // width``), and two
+    ``cap``-long sorts leave each row once; a message a histogram hub
+    receives has no slot and marks no row."""
+    rows, slot = _rewrite_rows_and_slots(rows, labels, changed, plan, cap)
+    offs, rowoffs, widths = _class_tables(plan)
+    total = int(rowoffs[-1])
+    with jax.named_scope("delta"), jax.named_scope("mark"):
+        # no slot (s) is past every class: offset s, width 1, row `total`
+        ge = slot[:, None] >= jnp.asarray(offs[1:], jnp.int32)[None, :]
+        row = _pick(ge, rowoffs) + lax.div(
+            slot - _pick(ge, offs), _pick(ge, np.append(widths, 1))
+        )
+        row = lax.sort(row)
+        again = jnp.concatenate([jnp.zeros((1,), jnp.bool_), row[1:] == row[:-1]])
+        dirty = lax.sort(jnp.where(again, total, row))[: min(cap, total)]
+    return rows, dirty
+
+
+_DIRTY_GROUP_ROWS = 8      # rows a trip of the dirty reduce takes: the sublanes
+# Narrow rows: as many rows a trip as fill this many slots, by one gather (64
+# rows at the coarse width 32, 32 at 64, 16 at 128). Three quarters of a quiet
+# tail's dirty rows are that narrow (graph500-24: 15,841 of 20,809 at width 32,
+# graph500-22: 3,131 of 3,942), and a trip's cost is its couple of dozen small
+# operations, not its slots. Measured on a v5e against 8 rows a trip by
+# `dynamic_slice` (_proof/dirty_trip_forms.py, PERF.md §6, PR 43): the width-32
+# loop 12.2 ms in 248 trips against 34.7 ms in 1,981 (graph500-24), 1.9 against
+# 6.3 ms (graph500-22): 12 % and 7.5 % of a sparse superstep. At 64 the two
+# forms tie; at 128 the slices are 0.5 ms a superstep ahead (1.3 against 1.8).
+_DIRTY_GATHER_SLOTS = 2048
+
+
+def _dirty_groups(widths) -> list:
+    """``[(W, first class, last class + 1)]``: the plan's classes, in
+    order, merged onto a coarse ladder of widths for the dirty reduce
+    (every pairwise class onto ``_PAIRWISE_MAX_W``, a sorted class onto
+    the next power of two). One loop and one sort network a coarse width,
+    not one a class: a network is code, code is device memory, and the
+    padding costs little where a twentieth of the rows run."""
+    groups = []
+    for c, w in enumerate(int(w) for w in widths):
+        coarse = max(_PAIRWISE_MAX_W, 1 << (w - 1).bit_length())
+        if groups and groups[-1][0] == coarse:
+            groups[-1] = (coarse, groups[-1][1], c + 1)
+        else:
+            groups.append((coarse, c, c + 1))
+    return groups
+
+
+def lpa_modes_from_dirty_rows(
+    rows: jax.Array, labels: jax.Array, dirty: jax.Array,
+    plan: BucketedModePlan,
+):
+    """``(new labels, dirty rows, dirty slots)``: the superstep's reduce
+    over carried rows that a rewrite has just brought up to ``labels``,
+    run over the ``dirty`` rows alone (:func:`rewrite_rows_marked`'s list);
+    every other vertex keeps its label, which is what
+    :func:`lpa_modes_from_rows` would give it. The histogram hubs run as
+    they do there.
+
+    The list is ascending, so the dirty rows of a group of neighbouring
+    classes (:func:`_dirty_groups`) are one span of it. A loop a group,
+    its trip count the span's length over the rows a trip takes, so work
+    follows the dirty rows and a group without one costs a loop that does
+    not run. A trip cuts its rows out of the flat buffer at the group's
+    coarse width (a wide row is ``w`` contiguous int32: one
+    ``dynamic_slice``, clamped to the buffer; narrow rows by one gather),
+    blanks what lies outside the row to the sentinel (a mode does not
+    read the order of a row), reduces them as the full reduce does
+    (pairwise count or row sort, ties to the smallest) and writes the
+    labels back. The rows are never viewed whole. Unweighted plans only:
+    a weighted plan's weights are a matrix a class, which no coarse width
+    spans, and its job keeps the full reduce."""
+    if plan.weight_mat is not None:
+        raise ValueError("the dirty reduce takes no weighted plan")
+    v, s = plan.num_vertices, rows.shape[0]
+    offs, rowoffs, widths = _class_tables(plan)
+    out = labels.astype(jnp.int32)
+    with jax.named_scope("lpa_bucketed"), jax.named_scope("dirty_rows"):
+        # the span of the list each class's dirty rows take
+        span = jnp.searchsorted(dirty, jnp.asarray(rowoffs, jnp.int32)).astype(jnp.int32)
+        count = span[1:] - span[:-1]
+        dirty_rows = span[-1]
+        dirty_slots = jnp.sum(count * jnp.asarray(widths, jnp.int32), dtype=jnp.int32)
+        for coarse, c0, c1 in _dirty_groups(widths):
+            cut = min(coarse, s)
+            gathered = coarse * _DIRTY_GROUP_ROWS < _DIRTY_GATHER_SLOTS
+            g = _DIRTY_GATHER_SLOTS // coarse if gathered else _DIRTY_GROUP_ROWS
+            lo, hi = span[c0], span[c1]
+            ids = jnp.concatenate(plan.vertex_ids[c0:c1])
+            bounds = jnp.asarray(rowoffs[c0 + 1:c1], jnp.int32)
+            lane = jnp.arange(cut, dtype=jnp.int32)
+            width = f"w{coarse}"
+
+            def trip(i, out):  # traced here, inside this turn of the loop
+                at = lo + i * g + jnp.arange(g, dtype=jnp.int32)
+                row = dirty[jnp.minimum(at, hi - 1)]  # past the span: the last again
+                ge = row[:, None] >= bounds[None, :]
+                span_of = _pick(ge, widths[c0:c1])  # each row's own width
+                start = _pick(ge, offs[c0:c1]) + (row - _pick(ge, rowoffs[c0:c1])) * span_of
+                if gathered:
+                    inside = lane[None, :] < span_of[:, None]
+                    # past the row's end its last slot is read again, and blanked
+                    reach = jnp.minimum(lane[None, :], span_of[:, None] - 1)
+                    mat = jnp.where(inside, rows[start[:, None] + reach], _SENTINEL)
+                else:
+                    cuts = []
+                    for k in range(g):
+                        first = jnp.minimum(start[k], s - cut)
+                        place = first + lane
+                        cuts.append(jnp.where(
+                            (place >= start[k]) & (place < start[k] + span_of[k]),
+                            lax.dynamic_slice(rows, (first,), (cut,)), _SENTINEL,
+                        ))
+                    mat = jnp.stack(cuts)
+                with jax.named_scope(width):
+                    # one key and no payload: the order of equal labels is
+                    # nobody's, so the sort carries no iota to keep it
+                    mode = (
+                        _rowwise_mode_pairwise(mat) if coarse <= _PAIRWISE_MAX_W
+                        else _sorted_rows_mode(
+                            lax.sort(mat, dimension=1, is_stable=False)
+                        )
+                    )
+                vertex = jnp.where(at < hi, ids[row - jnp.int32(rowoffs[c0])], v)
+                return out.at[vertex].set(mode, mode="drop")
+
+            out = lax.fori_loop(0, (hi - lo + g - 1) // g, trip, out)
+    with jax.named_scope("lpa_bucketed"):
+        return _hist_modes(labels, out, plan), dirty_rows, dirty_slots
 
 
 def lpa_modes_from_rows(

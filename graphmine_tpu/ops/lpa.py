@@ -241,7 +241,10 @@ def label_propagation(
             graph.num_edges, variant="fused", cold_compile=cold,
         )
         if plan is not None and plan.send_idx:
-            _emit_superstep_delta(sink, per_step, plan.num_messages)
+            _emit_superstep_delta(
+                sink, per_step, plan.num_messages,
+                _plan_rows_and_slots(plan.send_idx),
+            )
     else:
         labels, per_step = job(graph, max_iter, init_labels, plan)
     if return_history:
@@ -250,16 +253,20 @@ def label_propagation(
 
 
 def _emit_superstep_delta(
-    sink, per_step: dict, num_messages: int, shards: int | None = None
+    sink, per_step: dict, num_messages: int, full: tuple,
+    shards: int | None = None,
 ) -> None:
     """The ``superstep_delta`` record of one job over a fused plan's dense
     rows, from the job's per-superstep counts (the host's own by now; the
     stateless scan's come back with its labels). The stateless scan, which
     runs where the rows were not admitted to the device, has no ``branch``
     to report: every one of its supersteps is a full gather, and the
-    record says that. On a mesh (``shards``) ``num_messages`` and
+    record says that. ``full`` is the plan's ``(rows, slots)``: what
+    ``dirty_rows`` and ``dirty_slots`` say of a superstep whose reduce ran
+    over every row (``reduce: "full"``; every superstep of the stateless
+    scan and of the mesh job). On a mesh (``shards``) ``num_messages`` and
     ``changed_messages`` are the largest shard's: what the rungs are cut
-    from and what picks one for every shard."""
+    from and what picks one for every shard; ``full`` is one shard's."""
     import numpy as np
 
     from graphmine_tpu.ops.superstep_policy import delta_rungs
@@ -272,13 +279,27 @@ def _emit_superstep_delta(
         rungs = names[:-1]
     else:
         branch, messages, rungs = ["full"] * len(changed), [], []
+    nothing = [None] * len(changed)
+    dirty = [
+        [whole if got is None else got for got in per_step.get(key, nothing)]
+        for key, whole in zip(("dirty_rows", "dirty_slots"), full)
+    ]
     sink.emit(
         "superstep_delta", op="lpa_superstep", changed_vertices=changed,
         changed_messages=messages, branch=branch, rungs=rungs,
         num_messages=num_messages,
+        reduce=per_step.get("reduce", ["full"] * len(changed)),
+        dirty_rows=dirty[0], dirty_slots=dirty[1],
         seconds=[round(s, 6) for s in per_step.get("seconds", ())],
         **({} if shards is None else {"shards": shards}),
     )
+
+
+def _plan_rows_and_slots(send_idx) -> tuple:
+    """``(rows, slots)`` of a plan's dense rows, from its matrices' shapes
+    (a shard's, from the stacked ``[D, n, w]`` matrices of a mesh plan)."""
+    shapes = [idx.shape[-2:] for idx in send_idx]
+    return sum(n for n, _ in shapes), sum(n * w for n, w in shapes)
 
 
 def _under_a_trace() -> bool:
@@ -444,7 +465,8 @@ def _mesh_label_propagation(graph, mesh, max_iter, init_labels, plan, sink):
         )
         if sink is not None:
             _emit_superstep_delta(
-                sink, per_step, shard_messages(sg), shards=sg.num_shards
+                sink, per_step, shard_messages(sg),
+                _plan_rows_and_slots(sg.bucket_send), shards=sg.num_shards,
             )
         return labels
     if sg.out_slot is not None:  # the one program is handed what it reads
@@ -629,11 +651,27 @@ def _gather_program(rows, labels, plan):
     return gather_rows(rows, labels, plan)
 
 
-@partial(jax.jit, static_argnames=("cap",), donate_argnums=0)
-def _rewrite_program(rows, labels, changed, plan, cap: int):
-    from graphmine_tpu.ops.bucketed_mode import rewrite_rows
+@partial(jax.jit, static_argnames=("cap", "marked"), donate_argnums=0)
+def _rewrite_program(rows, labels, changed, plan, cap: int, marked: bool = False):
+    """The rows rewritten in place; ``marked``, ``(rows, dirty)``: beside
+    them the rows the rewrite wrote to, for :func:`_dirty_modes_program`."""
+    from graphmine_tpu.ops.bucketed_mode import rewrite_rows, rewrite_rows_marked
 
+    if marked:
+        return rewrite_rows_marked(rows, labels, changed, plan, cap)
     return rewrite_rows(rows, labels, changed, plan, cap)
+
+
+def _changed_and_k(new, labels, plan):
+    """``(changed, K, count)`` of one superstep: K the messages the changed
+    vertices send, which picks the next superstep's update, and count the
+    changed vertices."""
+    with jax.named_scope("superstep"), jax.named_scope("changed_count"):
+        changed = new != labels
+        out_deg = plan.out_ptr[1:] - plan.out_ptr[:-1]
+        k = jnp.sum(jnp.where(changed, out_deg, 0), dtype=jnp.int32)
+        count = jnp.sum(changed, dtype=jnp.int32)
+    return changed, k, count
 
 
 @jax.jit
@@ -644,12 +682,20 @@ def _modes_program(rows, labels, plan):
     from graphmine_tpu.ops.bucketed_mode import lpa_modes_from_rows
 
     new = lpa_modes_from_rows(rows, labels, plan)
-    with jax.named_scope("superstep"), jax.named_scope("changed_count"):
-        changed = new != labels
-        out_deg = plan.out_ptr[1:] - plan.out_ptr[:-1]
-        k = jnp.sum(jnp.where(changed, out_deg, 0), dtype=jnp.int32)
-        count = jnp.sum(changed, dtype=jnp.int32)
-    return new, changed, k, count
+    return (new, *_changed_and_k(new, labels, plan))
+
+
+@jax.jit
+def _dirty_modes_program(rows, labels, dirty, plan):
+    """:func:`_modes_program` after a marked rewrite, over the ``dirty``
+    rows alone: ``(new labels, changed, K, count, dirty rows, dirty
+    slots)``, the first four its results bit for bit."""
+    from graphmine_tpu.ops.bucketed_mode import lpa_modes_from_dirty_rows
+
+    new, dirty_rows, dirty_slots = lpa_modes_from_dirty_rows(
+        rows, labels, dirty, plan
+    )
+    return (new, *_changed_and_k(new, labels, plan), dirty_rows, dirty_slots)
 
 
 def _carried_rows_job(
@@ -689,10 +735,15 @@ def _carried_rows_job(
         max_iter, delta_rungs(plan.num_messages), plan.num_messages + 1,
         _blank_rows(row_slots(plan)), labels,
         gather=lambda rows, labels: _gather_program(rows, labels, plan),
-        rewrite=lambda rows, labels, changed, cap: _rewrite_program(
-            rows, labels, changed, plan, cap=cap
+        rewrite=lambda rows, labels, changed, cap, marked=False: _rewrite_program(
+            rows, labels, changed, plan, cap=cap, marked=marked
         ),
         modes=lambda rows, labels: _modes_program(rows, labels, plan),
+        # a weighted plan's weights are a matrix a class: its job keeps the
+        # full reduce (bucketed_mode.lpa_modes_from_dirty_rows)
+        dirty_modes=None if plan.weight_mat is not None else (
+            lambda rows, labels, dirty: _dirty_modes_program(rows, labels, dirty, plan)
+        ),
         clock=clock,
     )
 

@@ -72,6 +72,22 @@ FAMILIES = tuple(FAMILY_DEGRADE)
 # superstep pays either way.
 DELTA_RUNG_DIVISORS = (4096, 256, 16, 6)
 
+# The dirty reduce (PR 43): after a rewrite on a rung at or under this place
+# of `delta_rungs` the one-chip job reduces only the rows the rewrite wrote
+# to (`ops/bucketed_mode.py:lpa_modes_from_dirty_rows`); above it, and after
+# a full gather, every row (`lpa_modes_from_rows`). The lowest rung, M/4096,
+# and no higher, by a superstep's seconds on a TPU v5e on both sides of it
+# (PERF.md §5-6, PR 43; `_proof/dirty_place.py`, one process a graph):
+#   * after M/4096 (the quiet tail: 0.2 % of the rows dirty, 5 % of the
+#     slots on graph500-22 and 9 % on graph500-24): 0.1350 -> 0.0589 s at
+#     scale 22, 0.5187 -> 0.1896 s at scale 24;
+#   * after M/256 (4-7 % of the rows, 36-53 % of the slots): 0.1543 ->
+#     0.3152 s at scale 22, 0.6101 -> 1.7825 s at scale 24. A trip of the
+#     dirty reduce takes 8 rows in ~20 us whatever their width, and the rows
+#     a full reduce sweeps at 2 ns a row are narrow ones; by sort cost 54-72 %
+#     of the plan is dirty there anyway.
+DIRTY_REDUCE_TOP_PLACE = 0
+
 
 def delta_rungs(num_messages: int) -> tuple:
     """The rungs of the carried-rows job, ascending: the static caps on
@@ -84,7 +100,7 @@ def delta_rungs(num_messages: int) -> tuple:
 
 def step_carried_rows(
     max_iter: int, rungs: tuple, over: int, rows, labels,
-    gather, rewrite, modes, clock=None,
+    gather, rewrite, modes, dirty_modes=None, clock=None,
 ):
     """``(labels, per_step)`` of ``max_iter`` supersteps of a carried-rows
     job, stepped from the host: the loop of ``ops/lpa.py:_carried_rows_job``
@@ -94,33 +110,52 @@ def step_carried_rows(
     (K above every rung: ``gather(rows, labels)``, the first superstep
     always, ``over`` being a K above them all; K <= a rung:
     ``rewrite(rows, labels, changed, rung)``), then ``modes(rows, labels)``
-    gives ``(new labels, changed, K, count)``. The host waits once a
-    superstep, for K and the count; ``max_iter`` is the length of this
-    loop and no program's argument. ``per_step`` holds
-    ``changed_vertices``, ``changed_messages`` (K) and ``branch`` (the
-    rung's place, or ``len(rungs)`` for a full gather), one a superstep;
-    with a ``clock`` also ``seconds``, the clock's reading after each
-    fetch of K less the reading before it: a superstep's seconds on the
-    host's clock, at the wait the job has."""
+    gives ``(new labels, changed, K, count)``. With ``dirty_modes`` (the
+    one-chip job's), a rewrite on a rung at or under
+    :data:`DIRTY_REDUCE_TOP_PLACE` is asked for the rows it wrote to
+    (``rewrite(..., marked=True)`` gives ``(rows, dirty)``) and
+    ``dirty_modes(rows, labels, dirty)`` reduces those alone: the same
+    four results, then the count of dirty rows and of their slots. A row
+    that was not rewritten keeps its mode, so the labels are the full
+    reduce's bit for bit; after a full gather, and on the rungs above,
+    ``modes`` runs. The host waits once a superstep, for K and the
+    counts; ``max_iter`` is the length of this loop and no program's
+    argument. ``per_step`` holds ``changed_vertices``,
+    ``changed_messages`` (K), ``branch`` (the rung's place, or
+    ``len(rungs)`` for a full gather), ``reduce`` (``"full"`` or
+    ``"dirty"``) and ``dirty_rows`` / ``dirty_slots`` (``None`` where the
+    reduce was full), one a superstep; with a ``clock`` also ``seconds``,
+    the clock's reading after each fetch of K less the reading before it:
+    a superstep's seconds on the host's clock, at the wait the job has."""
     import jax
 
     changed, k = None, over
-    count, sent, branch = [], [], []
+    count, sent, branch, dirty = [], [], [], []
     marks = [clock()] if clock else []
     for _ in range(max_iter):
-        branch.append(sum(k > rung for rung in rungs))
-        if branch[-1] == len(rungs):
-            rows = gather(rows, labels)
+        place = sum(k > rung for rung in rungs)
+        branch.append(place)
+        if place == len(rungs):
+            rows, touched = gather(rows, labels), None
+        elif dirty_modes is not None and place <= DIRTY_REDUCE_TOP_PLACE:
+            rows, touched = rewrite(rows, labels, changed, rungs[place], marked=True)
         else:
-            rows = rewrite(rows, labels, changed, rungs[branch[-1]])
-        labels, changed, k, moved = modes(rows, labels)
-        k, moved = (int(x) for x in jax.device_get((k, moved)))  # the one wait
+            rows, touched = rewrite(rows, labels, changed, rungs[place]), None
+        if touched is None:
+            labels, changed, *counts = modes(rows, labels)
+        else:
+            labels, changed, *counts = dirty_modes(rows, labels, touched)
+        k, moved, *of_dirty = (int(x) for x in jax.device_get(counts))  # the one wait
         sent.append(k)
         count.append(moved)
+        dirty.append(tuple(of_dirty) or None)
         if clock:
             marks.append(clock())
     per_step = {
         "changed_vertices": count, "changed_messages": sent, "branch": branch,
+        "reduce": ["full" if d is None else "dirty" for d in dirty],
+        "dirty_rows": [d and d[0] for d in dirty],
+        "dirty_slots": [d and d[1] for d in dirty],
     }
     if clock:
         per_step["seconds"] = [b - a for a, b in zip(marks, marks[1:])]

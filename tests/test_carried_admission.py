@@ -151,10 +151,20 @@ def test_the_inventory_of_graph500_24_s_plan_term_by_term():
     # this term: a hub histogram's 268,435,456 lies beside the sort now,
     # inside the inventory's own `hub_histograms` beside it); the full
     # gather holds the class as kept and as its lanes (compiled: 705,036,800)
+    # the dirty reduce (PR 43): ten V-vectors, the 8,870,505 rows' vertex
+    # ids end to end, a trip's pairwise forms and eight forms of the widest
+    # coarse width's [8, 262144] (164,623 up to a power of two); compiled:
+    # 677,249,536 with the hubs' histograms' 536,870,912 in it. Never the
+    # largest program: the admission asks what it asked before
+    rows_of_plan = sum(n for n, _ in _G500_24_CLASSES)
+    dirty = 4 * (10 * (v + 1) + rows_of_plan + 3 * 2048 * 32 + 8 * 8 * 262_144)
+    assert rows_of_plan == 8_870_505 and dirty == 774_465_996
     assert memmodel.carried_job_transients(plan, top_rung=top) == {
         "gather": kept + lanes + 8 * (v + 1), "modes": 4 * kept + 8 * (v + 1),
-        "rewrite": 20 * top}
-    assert 20 * top > 4 * kept + 8 * (v + 1) > kept + lanes + 8 * (v + 1) > 32 * v
+        "rewrite": 20 * top, "dirty_modes": dirty}
+    assert 20 * top > 4 * kept + 8 * (v + 1) > dirty > kept + lanes + 8 * (v + 1) > 32 * v
+    # no rung, no rewrite, no dirty reduce
+    assert set(memmodel.carried_job_transients(plan)) == {"gather", "modes", "rewrite"}
     no_rung = memmodel.carried_rows_inventory(plan)
     assert no_rung["gather_transient"] == 4 * kept + 8 * (v + 1) == 1_383_661_576
     # a lower rung's rewrite is its sort of V keys, in and out (compiled:
@@ -207,6 +217,8 @@ def test_the_inventory_of_gap_urand_24_s_plan_term_by_term():
         # in and out through the lanes; the sort's four, 4 x kept, are less
         "modes": kept + lanes + 8 * (v + 1),   # compiled: 2,359,736,320
         "rewrite": 20 * top,                   # compiled: 1,790,246,400
+        # ten V-vectors, 16,777,216 ids, the pairwise forms, [8, 128] eight times
+        "dirty_modes": 4 * (10 * (v + 1) + (1 << 24) + 3 * 2048 * 32 + 8 * 8 * 128),
     }
     assert kept + lanes > 4 * kept and 20 * top == 1_789_567_900
     inv = memmodel.carried_rows_inventory(plan, top_rung=top)
@@ -224,16 +236,20 @@ def test_the_inventory_of_gap_urand_24_s_plan_term_by_term():
     scan, reason = admit_carried_rows(
         plan, {"bytes_limit": _G500_24_LIMIT, "bytes_in_use": 9_051_000_000})
     assert scan == "carried" and "7059037216 B against 7858336064 B free" in reason
-    assert "(gather; gather 2489163784 B, modes 2489163784 B, rewrite 1789567900 B)" in reason
+    assert ("(gather; gather 2489163784 B, modes 2489163784 B, rewrite 1789567900 B, "
+            "dirty_modes 739016744 B)") in reason
     scan, reason = admit_carried_rows(
         plan, {"bytes_limit": _G500_24_LIMIT, "bytes_in_use": _G500_24_LIMIT - 7_059_037_215})
     assert scan == "plain" and "a full gather every superstep" in reason
 
 
-def test_the_crossover_the_transients_count_by_is_the_plan_builder_s():
+@pytest.mark.parametrize("copy,owner", [
+    ("_PAIRWISE_MAX_W", "_PAIRWISE_MAX_W"), ("_DIRTY_TRIP_ROWS", "_DIRTY_GROUP_ROWS")])
+def test_the_constants_the_transients_count_by_are_the_plan_builder_s(copy, owner):
     """``obs/memmodel.py`` imports no jax and so keeps its own copy of the
-    width above which a class is reduced by the row sort."""
-    assert memmodel._PAIRWISE_MAX_W == bucketed_mode._PAIRWISE_MAX_W
+    width above which a class is reduced by the row sort, and of the rows a
+    trip of the dirty reduce takes."""
+    assert getattr(memmodel, copy) == getattr(bucketed_mode, owner)
 
 
 @pytest.mark.parametrize("n,w,want", [
@@ -482,7 +498,10 @@ def test_the_inventory_of_a_shard_of_graph500_25_term_by_term():
         "rewrite": 32 * v_pad,             # compiled: 1,074,322,432 at most (M/16)
     }
     # on one chip the classes take turns: the same shapes count one class
-    assert memmodel.carried_job_transients(plan, top_rung=rungs[-1]) == {
+    # (the one-chip job's dirty reduce, which the mesh job never runs, is
+    # not a shard's to expect)
+    one_chip = memmodel.carried_job_transients(plan, top_rung=rungs[-1])
+    assert {k: one_chip[k] for k in ("gather", "modes", "rewrite")} == {
         "gather": wide + lanes + labels, "modes": 4 * wide + labels,
         "rewrite": 32 * v_pad}
     assert 32 * v_pad > 20 * rungs[-1]  # the sort of V keys, not the top rung

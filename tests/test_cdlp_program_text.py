@@ -43,6 +43,18 @@ its digest moved; lowered with the barrier taken out the text is the
 parent's to the byte (``_modes_program:no-barrier``), and a plan with no
 such class (Urand's, graph500-22's, a shard of graph500-25) lowers no
 barrier at all (``test_a_barrier_stands_where_the_rows_would_be_viewed_whole``).
+PR 43 added two digests and moved none: after a rewrite on the lowest rung
+the one-chip job reduces only the rows the rewrite wrote to, so
+``_rewrite_program`` at that rung is lowered ``marked`` (the same scatter,
+then the list of the rows it touched: ``_rewrite_program:0:marked``) and
+``_dirty_modes_program`` reduces that list. ``marked`` is a
+static argument that defaults to what the program was, so the four
+``_rewrite_program`` digests STAND (ISSUE 43 expected them to move: the top
+rungs' programs are the ones a cache already holds), as do ``_modes_program``,
+``_gather_program``, the stateless scan, WCC's loop and all five mesh digests:
+``rewrite_rows`` was split (``_rewrite_rows_and_slots`` is the body it had
+and also hands back the slots it wrote to) and ``_modes_program``'s count of K
+moved into a helper, and both lower to the text they lowered to.
 """
 
 import dataclasses
@@ -63,6 +75,7 @@ from graphmine_tpu.ops.bucketed_mode import (
 )
 from graphmine_tpu.ops.cc import _connected_components
 from graphmine_tpu.ops.lpa import (
+    _dirty_modes_program,
     _gather_program,
     _label_propagation,
     _modes_program,
@@ -146,8 +159,13 @@ def _lowered(name):
             patch.setattr(jax.lax, "optimization_barrier", lambda x: x)
             return jax.jit(_modes_program_).lower(rows, labels, plan)
     changed = jax.ShapeDtypeStruct((g.num_vertices,), jnp.bool_)
-    rung = delta_rungs(g.num_messages)[int(name.rsplit(":", 1)[1])]
-    return _rewrite_program.lower(rows, labels, changed, plan, cap=rung)
+    rung = delta_rungs(g.num_messages)[int(name.split(":")[1])]
+    total = sum(idx.shape[0] for idx in plan.send_idx)
+    if name.startswith("_dirty_modes_program"):  # after the marked rewrite at that rung
+        dirty = jax.ShapeDtypeStruct((min(rung, total),), jnp.int32)
+        return _dirty_modes_program.lower(rows, labels, dirty, plan)
+    return _rewrite_program.lower(
+        rows, labels, changed, plan, cap=rung, marked=name.endswith(":marked"))
 
 
 _PARENT_DIGESTS = {
@@ -175,6 +193,12 @@ _PARENT_DIGESTS = {
         "bfa41019117a6942bb1f37c04b145be552b4f725ac2f89874d067a88ccd9a777",
     "_rewrite_program:3":
         "f76dd23541cb9296c6dd4e4296cad7b168df6ec5639b6908ddeb61afdebf2d7d",
+    # PR 43: the lowest rung's rewrite that also lists the rows it wrote to,
+    # and the reduce over that list
+    "_rewrite_program:0:marked":
+        "482a7f996bb1b157c234a272e5acb420c90e9587534a7d315c8769380f13afcc",
+    "_dirty_modes_program:0":
+        "5763fe003223880463d6a1c0ecb85d21256d05d3ffddc2867b9edbd2aba70b0e",
     # on a mesh of four (PR 39's programs; PR 42: the hub's row is 2253 wide)
     "_sharded_lpa_jit":
         "f131e22a2d2e795f147a58e6e9d0e1ae9ed043393d5e5d18406dac8143158112",
@@ -204,7 +228,7 @@ def test_each_class_s_gather_is_in_the_gather_program_once_and_in_no_other():
     _, plan = _graph_and_plan()
     texts = {name: _lowered(name).as_text() for name in _PARENT_DIGESTS
              if name.startswith(("_gather", "_modes", "_rewrite")) and ":no-" not in name}
-    assert len(texts) == 6
+    assert len(texts) == 7  # the lowest rung's rewrite also as `marked`
     # this graph's three classes that would be viewed whole, and no other
     assert texts["_modes_program"].count("stablehlo.optimization_barrier") == len(
         _viewed_whole([idx.shape for idx in plan.send_idx])) == 3

@@ -197,7 +197,9 @@ def flat_plan():
     return plan
 
 
-@pytest.mark.parametrize("program", ["gather", "rewrite", "modes"])
+@pytest.mark.parametrize(
+    "program", ["gather", "rewrite", "modes", "rewrite:marked", "dirty_modes"]
+)
 @pytest.mark.parametrize("graph", ["kronecker", "flat"])
 def test_carried_rows_programs_compile_for_v5e(
     one_chip, fused_plan, flat_plan, planted, program, graph
@@ -212,7 +214,11 @@ def test_carried_rows_programs_compile_for_v5e(
     shapes and the chip's tiles (ISSUE 38): at or above what the compiler
     assigns, on a skewed plan with hubs and on a flat one of narrow
     classes, where a class passes through a 128-lane form of 3.9 times
-    its size."""
+    its size. ISSUE 43's two: the rewrite that also lists the rows it wrote
+    to still updates the rows in place (two results, the rows aliased as
+    before), and the dirty reduce holds a trip's rows and never the flat
+    rows: its temporaries are the labels, the ids and the hubs'
+    histograms, under a tenth of the rows where there is no hub."""
     from graphmine_tpu.obs.memmodel import carried_job_transients
     from graphmine_tpu.ops import lpa
     from graphmine_tpu.ops.bucketed_mode import row_slots, with_slot_index
@@ -231,21 +237,39 @@ def test_carried_rows_programs_compile_for_v5e(
         compiled = _compile(
             lpa._rewrite_program, rows, labels, changed, plan, cap=top_rung,
         )
-    else:
+    elif program == "modes":
         compiled = _compile(lpa._modes_program, rows, labels, plan)
+    else:  # ISSUE 43: at the highest rung that takes the dirty reduce
+        from graphmine_tpu.ops.superstep_policy import DIRTY_REDUCE_TOP_PLACE
+
+        cap = delta_rungs(plan.num_messages)[DIRTY_REDUCE_TOP_PLACE]
+        total = sum(idx.shape[0] for idx in plan.send_idx)
+        if program == "rewrite:marked":
+            changed = jax.ShapeDtypeStruct((v,), jnp.bool_, sharding=one_chip)
+            compiled = _compile(
+                lpa._rewrite_program, rows, labels, changed, plan, cap=cap,
+                marked=True,
+            )
+        else:
+            dirty = jax.ShapeDtypeStruct(
+                (min(cap, total),), jnp.int32, sharding=one_chip
+            )
+            compiled = _compile(lpa._dirty_modes_program, rows, labels, dirty, plan)
     held = compiled.memory_analysis()
     assert " conditional(" not in compiled.as_text()
-    counted = carried_job_transients(plan, top_rung=top_rung)[program]
-    if program == "modes":  # reads the rows, writes V-sized results
+    counted = carried_job_transients(plan, top_rung=top_rung)[program.split(":")[0]]
+    if program in ("modes", "dirty_modes"):  # reads the rows, writes V-sized results
         assert held.alias_size_in_bytes == 0
         hubs = 0 if plan.hist_vertex_ids is None else plan.hist_vertex_ids.shape[0]
         assert held.temp_size_in_bytes <= max(counted, 8 * hubs * v + 8 * v)
     else:
         assert held.alias_size_in_bytes >= rows_bytes
         assert held.temp_size_in_bytes <= counted
+    if program == "dirty_modes" and graph == "flat":  # a trip's rows, not the rows
+        assert held.temp_size_in_bytes < rows_bytes // 10
     if graph == "kronecker":  # wide classes: well under the rows
-        assert held.temp_size_in_bytes < (rows_bytes if program == "modes"
-                                          else rows_bytes // 4)
+        assert held.temp_size_in_bytes < (
+            rows_bytes if program in ("modes", "dirty_modes") else rows_bytes // 4)
 
 
 @pytest.mark.parametrize("graph", ["kronecker", "flat"])

@@ -103,15 +103,28 @@ def _out_degree(g):
 
 def _rows_held_to_a_full_gather():
     """Every superstep's reduce is handed rows that equal a full gather of
-    the labels it starts from, slot for slot, whichever update made them."""
-    real = lpa._modes_program
+    the labels it starts from, slot for slot, whichever update made them;
+    and the reduce over the dirty rows alone (ISSUE 43) gives what the full
+    reduce gives on those rows, bit for bit."""
+    import contextlib
+
+    real, real_dirty = lpa._modes_program, lpa._dirty_modes_program
 
     def watched(rows, labels, plan):
         want = gather_rows(jnp.zeros_like(rows), labels, plan)
         np.testing.assert_array_equal(np.asarray(rows), np.asarray(want))
         return real(rows, labels, plan)
 
-    return mock.patch.object(lpa, "_modes_program", watched)
+    def watched_dirty(rows, labels, dirty, plan):
+        out = real_dirty(rows, labels, dirty, plan)
+        for got, full in zip(out, watched(rows, labels, plan)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(full))
+        return out
+
+    stack = contextlib.ExitStack()
+    stack.enter_context(mock.patch.object(lpa, "_modes_program", watched))
+    stack.enter_context(mock.patch.object(lpa, "_dirty_modes_program", watched_dirty))
+    return stack
 
 
 def _check(g, plan, steps, init_labels=None):
@@ -232,7 +245,8 @@ def test_a_job_of_any_length_runs_the_programs_already_compiled(max_iter):
     argument: a job of another length compiles nothing (on the chip a
     program of the cells' size compiles for minutes)."""
     g, plan, _, _ = _case("rmat_with_a_histogram_hub")
-    programs = (lpa._gather_program, lpa._rewrite_program, lpa._modes_program)
+    programs = (lpa._gather_program, lpa._rewrite_program, lpa._modes_program,
+                lpa._dirty_modes_program)
     sink = MetricsSink()
     want = label_propagation(g, max_iter=10, plan=plan, sink=sink)
     (record,) = [r for r in sink.records if r["phase"] == "superstep_delta"]

@@ -118,6 +118,10 @@ def _check(host, mesh, steps, want, init=None):
     rungs = list(delta_rungs(record["num_messages"]))
     assert record["rungs"] == rungs and record["branch"][0] == "full"
     assert len(record["seconds"]) == steps
+    # the mesh job hands over no dirty reduce (ISSUE 43): every reduce runs
+    # over every row of the shard, and the record says so
+    assert record["reduce"] == ["full"] * steps
+    assert len(set(record["dirty_rows"])) == len(set(record["dirty_slots"])) == 1
     for took, k_before in zip(record["branch"][1:], record["changed_messages"]):
         fits = [r for r in rungs if k_before <= r]
         assert took == (fits[0] if fits else "full")
