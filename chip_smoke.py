@@ -6,8 +6,9 @@ The quickest proof that the system still starts on the chip. One process
 ``run_pipeline`` on a generated 262,144-vertex / 25 M-edge string-domain
 parquet, the published snapshot, and an in-process ``SnapshotServer``
 answering HTTP reads and one delta — and checks every answer against
-references written here (NumPy synchronous LPA, SciPy connected
-components, rank-statistic AUROC). Everything is generated from ``--seed``.
+plain references (NumPy synchronous LPA written here; SciPy connected
+components and rank-statistic AUROC from ``benchmark/references.py``).
+Everything is generated from ``--seed``.
 
 Each phase prints one JSON line. The LAST line is exactly
 ``{"ok": true, "device": {...}}`` and exit code 0 — only when the default
@@ -35,6 +36,8 @@ import traceback
 import urllib.request
 
 import numpy as np
+
+from benchmark.references import rank_auroc, scipy_cc
 
 _REPO = os.path.dirname(os.path.abspath(__file__))
 _FAILURES: list[str] = []
@@ -117,7 +120,8 @@ def phase(name: str, fn, *args):
     return out
 
 
-# -- references written here, independent of the code under test ----------
+# -- references, independent of the code under test: ``scipy_cc`` and
+# ``rank_auroc`` are the benchmark's own (benchmark/references.py) ---------
 
 
 def numpy_lpa(src, dst, num_vertices: int, max_iter: int) -> np.ndarray:
@@ -142,34 +146,10 @@ def numpy_lpa(src, dst, num_vertices: int, max_iter: int) -> np.ndarray:
     return labels
 
 
-def scipy_cc(src, dst, num_vertices: int) -> np.ndarray:
-    from scipy.sparse import coo_matrix
-    from scipy.sparse.csgraph import connected_components
-
-    # distinct pairs: summed duplicate multiplicities must not overflow
-    pair = np.unique(np.asarray(src, np.int64) * num_vertices + dst)
-    adj = coo_matrix(
-        (np.ones(len(pair), bool), (pair // num_vertices, pair % num_vertices)),
-        shape=(num_vertices, num_vertices),
-    )
-    return connected_components(adj, directed=False)[1]
-
-
 def same_partition(a, b) -> bool:
     from graphmine_tpu.oracle import canonical_partition
 
     return bool(np.array_equal(canonical_partition(a), canonical_partition(b)))
-
-
-def rank_auroc(scores, positive) -> float:
-    from scipy.stats import rankdata
-
-    ranks = rankdata(np.asarray(scores, np.float64))
-    n_pos = int(positive.sum())
-    n_neg = len(positive) - n_pos
-    return float(
-        (ranks[positive].sum() - n_pos * (n_pos + 1) / 2) / (n_pos * n_neg)
-    )
 
 
 # -- phases ---------------------------------------------------------------
@@ -215,7 +195,7 @@ def exact_phase(detail, args) -> None:
 
 def write_parquet(src, dst, num_vertices: int, path: str) -> None:
     """The reference's ingestion format: domain-string columns
-    ``_c1``/``_c2`` (as ``bench.py``'s e2e tier writes them)."""
+    ``_c1``/``_c2``, one row per outlink, duplicates kept."""
     import pyarrow as pa
     import pyarrow.parquet as pq
 

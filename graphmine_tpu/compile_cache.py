@@ -2,7 +2,7 @@
 
 One superstep program takes tens of seconds to compile for the TPU at
 scale, a cold pipeline minutes; every entry point (``run_pipeline``, the
-snapshot server, ``bench.py``, ``chip_smoke.py``) calls
+snapshot server, ``benchmark/run.py``, ``chip_smoke.py``) calls
 :func:`enable_compile_cache` first so a repeat invocation finds what the
 last one compiled.
 """

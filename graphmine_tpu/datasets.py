@@ -175,8 +175,8 @@ def snap_path(name: str, data_dir: str = "data") -> str | None:
     """Path to the rung's real SNAP edge list, or ``None`` when absent.
 
     The single source of truth for real-vs-stand-in resolution: ``load``
-    uses it to pick the input and ``bench.py --tier snap`` uses it to
-    label the record's ``source`` — the two can't desync.
+    uses it to pick the input, and a caller that labels a record's
+    ``source`` asks it too — the two can't desync.
     """
     rung = LADDER.get(name)
     if rung is None:

@@ -61,23 +61,22 @@ _I32 = 4  # bytes per int32/float32 slot — the compute plane's one word size
 # (jax's ``device_kind``) they were measured on. Provenance discipline:
 # each anchor names the capture that seeded it; anchors nobody has
 # measured on silicon yet say so ("model seed") and are exactly the ones
-# a future capture should replace (tools/bench_diff.py --manifest names
-# the pending tiers). Cost estimates are always computed from
+# a chip record should replace. Cost estimates are always computed from
 # ``MODEL_DEVICE_KIND``'s seeds (plus overrides); what a measurement on
 # ANOTHER kind of device achieved against them is not a fraction of
 # anything — see :func:`anchored`.
 MODEL_DEVICE_KIND = "TPU v5 lite"
 ROOFLINE_SEEDS: dict = {
     MODEL_DEVICE_KIND: {
-        # Random-gather slots/s: the r4/r5 `roofline` bench tier
-        # (131.8M / 132.6M slots/s measured; ops/bucketed_mode.py
-        # header). Governs the sort gather and every bucketed row
+        # Random-gather slots/s: the r4/r5 roofline microbenchmark
+        # (131.8M / 132.6M slots/s; r-series, record deleted in PR 22).
+        # Governs the sort gather and every bucketed row
         # reduce (136 M slots/s on a v5e at 128.3 M messages, PR 26).
         "gather_slots_per_sec": 1.32e8,
-        # ICI exchange bytes/s per chip: NO bench tier measures this yet
+        # ICI exchange bytes/s per chip: no chip record measures this
         # — 4.5e10 B/s is a conservative v5e-interconnect model seed
-        # (order of magnitude below the advertised peak; the sharded
-        # tier's silicon capture is the natural place to measure it).
+        # (order of magnitude below the advertised peak; cell
+        # `cdlp-g500-25-x4`'s `exchange` records are where to read it).
         "exchange_bytes_per_sec": 4.5e10,
         # Exact-kNN distance pairs/s: the r6 LOF crossover provenance
         # table (ops/lof.py): 65,536 points (=> 65,536^2 pairs) in 2.3 s.
@@ -92,7 +91,7 @@ _SEED_PROVENANCE = {
     "gather_slots_per_sec": (
         "r4/r5 roofline capture on TPU v5 lite (record deleted in PR 22)"
     ),
-    "exchange_bytes_per_sec": "model seed (unmeasured; no ICI bench tier yet)",
+    "exchange_bytes_per_sec": "model seed (unmeasured; no chip record)",
     "lof_exact_pairs_per_sec": "ops/lof.py r6 crossover table (65K in 2.3s)",
     "lof_ivf_points_per_sec": "ops/lof.py r6 crossover table (262K in 9.0s)",
 }

@@ -31,7 +31,7 @@ LOF outlier scores — at every snapshot publish:
 numpy is imported inside functions (the ``serve/delta.py`` discipline)
 so the ``obs`` package stays an import-clean stdlib leaf; the quality
 pass itself always runs where numpy already is (the serving write path,
-the driver's publish phase, bench).
+the driver's publish phase).
 """
 
 from __future__ import annotations
@@ -74,7 +74,7 @@ CANARY_META_KEY = "canary"
 
 def lof_threshold() -> float:
     """The env-resolved anomaly threshold (one owner for every caller:
-    the quality pass, /statusz, bench). Malformed env raises."""
+    the quality pass, /statusz). Malformed env raises."""
     return env_float("GRAPHMINE_QUALITY_LOF_THRESHOLD",
                      DEFAULT_LOF_THRESHOLD)
 
@@ -121,9 +121,8 @@ class QualityState:
         cls, labels, lof=None, version: int = 0, threshold: float | None = None,
     ) -> "QualityState":
         """Compute the state from host label/score columns: one bincount
-        for the census, one binning pass per sketch. O(V) host work —
-        the bounded-cost claim ``bench.py``'s ``quality_pass``
-        sub-record measures."""
+        for the census, one binning pass per sketch. O(V) host work
+        (its seconds ride the ``quality_snapshot`` record)."""
         import numpy as np
 
         labels = np.asarray(labels).reshape(-1)
@@ -506,7 +505,7 @@ def run_quality_pass(
     registry=None,
 ) -> QualityReport:
     """The bounded publish-time quality pass, one owner for every
-    publisher (delta ingestor, driver publish, bench):
+    publisher (delta ingestor, driver publish):
 
     1. compute :class:`QualityState` from the published columns;
     2. with a parent (``parent_labels`` [+ ``parent_state`` to reuse the

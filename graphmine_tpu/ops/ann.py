@@ -74,9 +74,9 @@ def default_n_clusters(n: int) -> int:
     """The IVF index's default cluster count for an ``n``-point set:
     ``~sqrt(N)``, rounded to a multiple of 8, min 8. Single owner —
     :func:`ivf_knn`'s default, the streaming re-fit's full-window sizing
-    (and its exact-warmup gate ``n < 4 * C``), and the stream bench's
-    reuse micro-bench must all size the SAME index, or a retune here
-    would silently desync what they build/gate/measure."""
+    (and its exact-warmup gate ``n < 4 * C``) must size the SAME
+    index, or a retune here would silently desync what they
+    build/gate."""
     return max(8, int(round(np.sqrt(n) / 8)) * 8)
 
 
@@ -216,7 +216,7 @@ def _search_chunks(pts, m_gid, m_valid, q_gid, row_sub, k: int):
 def _exact_fallback(pts, k, guard: str, detail: str, sink):
     """The honest exit when an IVF pathology guard trips: run the exact
     path — but LOUDLY (ADVICE r5). The silent version cost a round of
-    bench triage: 'ivf' timings that were secretly exact-path timings.
+    triage: 'ivf' timings that were secretly exact-path timings.
     ``guard`` names which guard fired; the warning + ``ivf_fallback``
     metrics record carry it."""
     import warnings
@@ -261,8 +261,8 @@ def ivf_knn(
     ``n_clusters`` defaults to ``~sqrt(N)`` (rounded to a multiple of 8,
     min 8); ``n_probe`` nearest clusters are searched per query —
     recall rises with ``n_probe / n_clusters`` (measured 0.95–0.98 at
-    6–13% candidate fraction on Gaussian clouds; the bench lof tier
-    records recall on its real feature cloud). Falls back to the exact
+    6–13% candidate fraction on Gaussian clouds; r-series, no chip
+    record). Falls back to the exact
     path when the cloud is too small for the machinery to pay
     (``N < 4 * n_clusters`` or ``k >= Lmax`` after clustering); pathology
     guards (capacity / probe skew / chunk-index bound) also fall back,

@@ -41,8 +41,8 @@ def vertex_features(
     almost no triangles, so the clustering coefficient separates them
     from organically embedded hubs — raw degree cannot under a power-law
     degree distribution: legitimate hubs out-degree injected anomalies by
-    orders of magnitude. Measured on the AUROC harness (`bench.py --tier
-    lof`): r1 CPU-class measurements 0.89–0.91 with the first six
+    orders of magnitude. Measured on the r-series AUROC harness (before
+    the chip records; not in the ledger): r1 CPU-class 0.89–0.91 with the first six
     features, 0.91–0.93 with all eight; r4 real-TPU capture (after the
     true-f32 distance fix, which alone moved the headline from 0.92 to
     0.99) 0.9905 with all eight. Degree-ish features are log-scaled to tame the power
@@ -167,11 +167,10 @@ def vertex_features_host(
       ``<= 1/(2*sqrt(clustering_samples))``), whose cost is independent
       of the wedge count — so the full 8-feature set survives at the
       scale where the exact O(sum d+^2) expansion is infeasible.
-    * ``False`` — zero the column (7 informative features). The lof-tier
-      AUROC harness (``bench.py --tier lof`` detail) scores the 7-feature
-      and sampled-8 configs next to the exact-8 headline every run, so
-      the as-deployed scale-out quality is a recorded number, not a
-      proxy band (VERDICT r3 item 5). r4 real-TPU capture (65K vertices,
+    * ``False`` — zero the column (7 informative features). The
+      r-series AUROC harness scored the 7-feature and sampled-8 configs
+      next to the exact-8 headline (VERDICT r3 item 5; before the chip
+      records, not in the ledger). r4 real-TPU capture (65K vertices,
       64 injected anomalies, k=128, after the true-f32 distance fix):
       exact-8 **0.9905**, host-7 **0.9940**, sampled-8 **0.9887** — all
       three configs within ~0.005 of each other at this scale.

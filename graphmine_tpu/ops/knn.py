@@ -31,8 +31,8 @@ def knn(points: jax.Array, k: int, row_tile: int = 1024, impl: str = "auto"):
 
     Auto-policy provenance (VERDICT r4 item 5 — the selection must cite a
     measurement, not an assumption): timed on a real TPU v5e, 65536x8
-    f32 points, best-of-3 steady-state (round 5, 2026-07-31; the same
-    sweep rides the lof bench tier's ``knn_impl_timing`` detail):
+    f32 points, best-of-3 steady-state (round 5, 2026-07-31; r-series,
+    before the chip records; not in the ledger):
 
         k=8    pallas 0.260 s   xla 0.300 s   pallas 1.15x faster
         k=16   pallas 0.439 s   xla 0.416 s   pallas 0.95x (xla wins)

@@ -27,8 +27,8 @@ from graphmine_tpu.ops.knn import knn
 # a measurement, not an assumption; same discipline as the r5 kNN flip in
 # ops/knn.py). Timed on a real TPU v5e, 8-dim f32 LOF feature clouds,
 # k=128, warm caches (round 5, 2026-07-31; docs/DESIGN.md "IVF-flat
-# approximate kNN"; the lof bench tier's ``knn_impl_timing``/``ivf_lof``
-# details re-measure both ends each capture):
+# approximate kNN"; r-series, before the chip records; not in the
+# ledger):
 #
 #     N=65,536    exact 2.3 s     ivf 4.2 s    exact 1.8x faster
 #     N=262,144   exact 27.8 s    ivf 9.0 s    ivf   3.1x faster
@@ -113,7 +113,7 @@ def lof_scores(
     own dense region, and with ``k`` below the group size each one's kNN
     neighborhood is just the other anomalies, so they score as inliers
     (measured: 64 injected hubs at 65K vertices swing AUROC 0.49 → 0.91
-    going from k=20 to k=100; see ``bench.py --tier lof``).
+    going from k=20 to k=100; r-series, no chip record).
 
     ``impl="auto"`` (r6) is SCALE-AWARE: clouds at or above the measured
     crossover (:data:`LOF_IVF_MIN_POINTS`; provenance table above) route

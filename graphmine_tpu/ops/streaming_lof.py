@@ -132,8 +132,7 @@ class StreamingLOF:
     a small early sample would index every later window badly);
     ``ivf_retrain_every=N`` re-trains every N IVF re-fits to track
     drift (0 = train once, the default — the ring buffer's content
-    drifts one chunk at a time, and the bench stream tier records the
-    reuse win/regression each capture).
+    drifts one chunk at a time; the reuse win has no chip record).
     """
 
     def __init__(self, k: int = 20, capacity: int = 4096,

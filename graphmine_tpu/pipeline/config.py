@@ -47,7 +47,7 @@ class PipelineConfig:
     # LOF neighborhood size. Must exceed the size of any *clustered*
     # anomaly group or the group's members score each other as inliers:
     # measured AUROC 0.49 at k=20 vs 0.91-0.93 at k>=100 on 64 injected
-    # hubs (docs/DESIGN.md, bench.py --tier lof). 128 is the measured
+    # hubs (docs/DESIGN.md; r-series, no chip record). 128 is the measured
     # best; the driver clamps it to num_vertices - 1 on small graphs.
     lof_k: int = 128
     # LOF kNN implementation. "auto" (r6) is SCALE-AWARE: the planner

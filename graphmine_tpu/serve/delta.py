@@ -552,8 +552,8 @@ class RepairDebt:
       stale the served snapshot is against accepted writes);
     - :meth:`applied` when the ingestor *publishes* — drains the oldest
       pending entry and accrues the repair economics: warm vs
-      full-recompute counts (the warm ratio is the number the serve
-      bench tier exists to improve), supersteps spent vs the frontier
+      full-recompute counts (the warm ratio is the number a served
+      write load should improve), supersteps spent vs the frontier
       budget granted (a budget fraction pinned near 1.0 means deltas
       are one graph-growth away from the fallback cliff).
 
@@ -646,8 +646,8 @@ class RepairDebt:
 
     def shed(self, rows: int) -> None:
         """Admission control refused ``rows`` delta rows (a 503 the
-        client must retry) — the lost-write accounting the serve bench
-        tier's shed rate reads. Pure accounting: sheds at the front door
+        client must retry) — the lost-write accounting a shed rate
+        reads. Pure accounting: sheds at the front door
         were never :meth:`submitted`, so nothing drains here (a
         queued-then-shed batch pairs this with :meth:`abandoned`)."""
         with self._lock:
@@ -1245,9 +1245,7 @@ class DeltaIngestor:
                 # delta_apply span, so quality_snapshot/quality_drift/
                 # canary_score land span-joined to the publishing trace.
                 # Bounded O(V) host work + the tiny frozen canary probe;
-                # its seconds ride the quality_snapshot record (the
-                # bench `quality_pass` sub-record measures the same
-                # pass at three graph sizes).
+                # its seconds ride the quality_snapshot record.
                 from graphmine_tpu.obs.quality import run_quality_pass
 
                 # The cached state is reusable only when it describes
@@ -1314,8 +1312,8 @@ class DeltaIngestor:
                     seconds=round(time.perf_counter() - t0, 4),
                     # stage split, the seconds of the `delta_repair` and
                     # `delta_lof` spans (None under a sink that has no
-                    # tracer): the repair-vs-recompute comparison the
-                    # bench serve tier reports is the repair term; LOF
+                    # tracer): a repair-vs-recompute comparison
+                    # reads the repair term; LOF
                     # refresh amortizes (full bootstrap only on the first
                     # apply of an ingestor's lifetime)
                     repair_seconds=_rounded(repair_stage.seconds),

@@ -660,7 +660,7 @@ class SnapshotServer:
 
     def _ensure_worker(self) -> None:
         """Start the apply worker lazily (first delta) so in-process
-        users (serve_cli one-shots, the bench tier) get the full
+        users (serve_cli one-shots, tests) get the full
         admission path without calling :meth:`start`."""
         with self._queue_cv:
             if self._worker_stop:

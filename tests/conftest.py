@@ -99,9 +99,8 @@ def pytest_configure(config):
         "perf: compute-plane performance-observability suite "
         "(tests/test_costmodel.py: analytical cost model exact against "
         "hand-computed plans, superstep_timing achieved-vs-model "
-        "attribution e2e, bench_diff regression gate + trajectory "
-        "self-check over the committed BENCH_*.json, the silicon-capture "
-        "manifest, obs_report roofline section); runs in the default CPU "
+        "attribution e2e, obs_report roofline section); runs in the "
+        "default CPU "
         "pass — select with -m perf or tools/run_tier1.sh --perf-only",
     )
     config.addinivalue_line(
@@ -122,8 +121,8 @@ def pytest_configure(config):
         "hand-computed tiny plans, the planner byte-constant "
         "derivation, memory_watermark emission e2e + the fault-injected "
         "OOM degrade join, serve /statusz + /profilez memory surfaces, "
-        "the obs_report memory waterfall and the bench_diff memory "
-        "gate); runs in the default CPU pass — select with -m mem or "
+        "the obs_report memory waterfall); runs in the default CPU "
+        "pass — select with -m mem or "
         "tools/run_tier1.sh --mem-only",
     )
     config.addinivalue_line(

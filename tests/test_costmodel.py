@@ -10,8 +10,6 @@
   phase span — THE acceptance criterion), and the sharded driver path's
   exchange split;
 - obs_report's roofline section + the waterfall threshold/model lines;
-- tools/bench_diff.py: regression / no-regression / tolerance-edge gates
-  on synthetic BENCH files and the silicon-capture manifest;
 - schema: half-stamped cost sub-records fail validation; schema_lint
   flags inline cost=... literals outside the single builder.
 """
@@ -38,8 +36,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOOLS = os.path.join(REPO, "tools")
 if TOOLS not in sys.path:
     sys.path.insert(0, TOOLS)
-
-import bench_diff  # noqa: E402
 
 # Deterministic anchors for the hand-computed cases (the seeds are real
 # measurements; tests want round numbers).
@@ -270,12 +266,6 @@ def test_schema_lint_flags_inline_cost_literals(tmp_path):
     assert schema_lint.scan_inline_costs() == []
 
 
-def test_bench_diff_tiers_match_bench_py():
-    import bench
-
-    assert tuple(bench._TIER_ORDER) == bench_diff.ALL_TIERS
-
-
 # ---------------------------------------------------------------------------
 # superstep_timing: ops seams
 # ---------------------------------------------------------------------------
@@ -486,135 +476,3 @@ def test_obs_report_flags_below_model_windows(tmp_path):
     assert "<< below" not in obs_report.build_report(
         records, roofline_min_frac=0.1
     )
-
-
-# ---------------------------------------------------------------------------
-# bench_diff: gate, trajectory, manifest, crossover suggestion
-# ---------------------------------------------------------------------------
-
-
-def _bench_file(tmp_path, name, n, tiers, tail_records=()):
-    """Synthetic driver artifact: suite-summary tiers + optional full
-    tail records (the shape bench.py's orchestrator really prints)."""
-    suite_tiers = {}
-    for tier, spec in tiers.items():
-        if "err" in spec:
-            suite_tiers[tier] = {"err": spec["err"]}
-        else:
-            suite_tiers[tier] = {
-                "m": spec["metric"], "v": spec["value"],
-                "u": spec["unit"], "vs": spec.get("vs", 1.0),
-            }
-    tail = "".join(json.dumps(r) + "\n" for r in tail_records)
-    path = tmp_path / name
-    path.write_text(json.dumps({
-        "n": n, "cmd": "python bench.py", "rc": 0, "tail": tail,
-        "parsed": {"metric": "x", "suite": {"tiers": suite_tiers}},
-    }))
-    return str(path)
-
-
-def _chip(v):
-    return {"chip": {
-        "metric": "lpa_edges_per_sec_per_chip", "value": v,
-        "unit": "edges/s/chip",
-    }}
-
-
-def test_bench_diff_gate_no_regression(tmp_path, capsys):
-    a = _bench_file(tmp_path, "BENCH_r90.json", 90, _chip(100_000_000))
-    b = _bench_file(tmp_path, "BENCH_r91.json", 91, _chip(95_000_000))
-    assert bench_diff.main([a, b]) == 0
-    out = capsys.readouterr().out
-    assert "gate: clean" in out
-
-
-def test_bench_diff_gate_regression_names_metric(tmp_path, capsys):
-    a = _bench_file(tmp_path, "BENCH_r90.json", 90, _chip(100_000_000))
-    b = _bench_file(tmp_path, "BENCH_r91.json", 91, _chip(85_000_000))
-    assert bench_diff.main([a, b]) == 1
-    err = capsys.readouterr().err
-    assert "lpa_edges_per_sec_per_chip" in err
-    assert "chip tolerance" in err
-
-
-def test_bench_diff_tolerance_edge_and_direction(tmp_path):
-    # exactly AT the 10% tolerance: not a regression (strict inequality)
-    a = _bench_file(tmp_path, "BENCH_r90.json", 90, _chip(100_000_000))
-    b = _bench_file(tmp_path, "BENCH_r91.json", 91, _chip(90_000_000))
-    assert bench_diff.main([a, b]) == 0
-    # one unit past it (vs the same 100M base): regression
-    c = _bench_file(tmp_path, "BENCH_r92.json", 92, _chip(89_999_999))
-    assert bench_diff.main([a, c]) == 1
-    # seconds regress UPWARD (lower=better)
-    ns = lambda v: {"northstar": {
-        "metric": "lpa_100m_maxiter5_seconds", "value": v, "unit": "s",
-    }}
-    d = _bench_file(tmp_path, "BENCH_r93.json", 93, ns(8.0))
-    e = _bench_file(tmp_path, "BENCH_r94.json", 94, ns(9.5))
-    assert bench_diff.main([d, e]) == 1
-    f = _bench_file(tmp_path, "BENCH_r95.json", 95, ns(7.0))
-    assert bench_diff.main([d, f]) == 0
-    # per-tier override via --tolerance
-    assert bench_diff.main([d, e, "--tolerance", "northstar=0.5"]) == 0
-
-
-def test_bench_diff_single_file_pins_the_gate(tmp_path, monkeypatch, capsys):
-    """Single-file mode gates THE NAMED file even when its round number
-    parses older than the newest committed capture (a re-run of an old
-    round must not silently fall out of the comparison)."""
-    c1 = _bench_file(tmp_path, "BENCH_r01.json", 1, _chip(100_000_000))
-    c2 = _bench_file(tmp_path, "BENCH_r02.json", 2, _chip(101_000_000))
-    monkeypatch.setattr(
-        bench_diff, "committed_bench_files", lambda repo_dir=None: [c1, c2]
-    )
-    fresh_dir = tmp_path / "fresh"
-    fresh_dir.mkdir()
-    recap = _bench_file(fresh_dir, "BENCH_r01.json", 1, _chip(80_000_000))
-    assert bench_diff.main([recap]) == 1
-    err = capsys.readouterr().err
-    assert "lpa_edges_per_sec_per_chip" in err
-
-
-def test_bench_diff_capture_change_gates_only_under_strict(tmp_path):
-    a = _bench_file(tmp_path, "BENCH_r90.json", 90, _chip(100_000_000))
-    fb = {"chip": {
-        "metric": "lpa_edges_per_sec_per_chip_cpu_fallback",
-        "value": 1_000_000, "unit": "edges/s/chip",
-    }}
-    b = _bench_file(tmp_path, "BENCH_r91.json", 91, fb)
-    # a fresh CPU-fallback capture vs committed silicon must NOT fail the
-    # default gate (this container can never produce silicon numbers)
-    assert bench_diff.main([a, b]) == 0
-    assert bench_diff.main([a, b, "--strict-capture"]) == 1
-
-
-def test_bench_diff_manifest_tracks_fallback_only_tiers(tmp_path, capsys):
-    real = _bench_file(tmp_path, "BENCH_r90.json", 90, _chip(100_000_000))
-    fb_rec = {
-        "metric": "streaming_lof_points_per_sec_cpu_fallback",
-        "value": 1000.0, "unit": "points/s", "vs_baseline": 0.1,
-        "detail": {"ivf_reuse": {"speedup": 0.5},
-                   "capture": {"cpu_fallback": "tpu unreachable"}},
-    }
-    fb = _bench_file(
-        tmp_path, "BENCH_r91.json", 91,
-        {"stream": {
-            "metric": "streaming_lof_points_per_sec_cpu_fallback",
-            "value": 1000.0, "unit": "points/s"}},
-        tail_records=[fb_rec],
-    )
-    assert bench_diff.main([real, fb, "--manifest", "--no-gate"]) == 0
-    out = capsys.readouterr().out
-    manifest = json.loads(out.split("== silicon-capture manifest ==")[1])
-    assert manifest["tiers"]["chip"] == "silicon"
-    assert manifest["tiers"]["stream"] == "cpu_fallback"
-    assert manifest["sub_records"]["stream.ivf_reuse"] == "cpu_fallback"
-    assert "stream" in manifest["pending"]
-    # the tiers that measured the deleted families went with them
-    assert not {"blocking", "exchange"} & set(manifest["tiers"])
-    assert "chip" not in manifest["pending"]
-    # --strict turns a non-empty backlog into exit 1
-    assert bench_diff.main(
-        [real, fb, "--manifest", "--strict", "--no-gate"]
-    ) == 1

@@ -91,8 +91,8 @@ def test_planted_anomaly_graph_detects_end_to_end():
     """The e2e dataset's reason to exist (VERDICT r5 weak 1): every timed
     detection chapter produces NONZERO output on it — a long-tailed LPA
     census, populated recursive deciles with flagged vertices, and LOF
-    separating the injected anomalies — at CI scale, same knobs as the
-    bench tier."""
+    separating the injected anomalies — at CI scale.
+    """
     from graphmine_tpu.graph.container import build_graph
     from graphmine_tpu.ops.lof import auroc, lof_scores
     from graphmine_tpu.ops.features import standardize, vertex_features

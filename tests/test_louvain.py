@@ -1,6 +1,8 @@
 """Louvain + modularity tests: hand-checked fixtures, a networkx oracle,
 determinism, and partition-quality comparison against LPA (SURVEY §7.7)."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -157,3 +159,39 @@ def test_leiden_recovers_planted_blocks():
     labels, q = leiden(g)
     assert adjusted_rand_index(np.asarray(labels), blocks) > 0.95
     assert q > 0.5
+
+
+# The SBM at the detectability margin: 50 blocks of 400, in-block degree
+# ~11 against out-block degree ~16, right above the recovery threshold
+# (p_in 0.026 collapses to ARI 0.54, 0.03 saturates at 0.98). The planted
+# blocks above have a 20x ratio every method recovers; here the best of
+# the three methods sits mid-band and can move either way.
+_MARGIN_SIZES, _MARGIN_P_IN, _MARGIN_P_OUT = [400] * 50, 0.028, 0.0008
+_MARGIN_SEEDS = (3, 4, 5)
+
+
+@functools.cache
+def _margin_sbm_best_ari(seed: int) -> float:
+    from graphmine_tpu.datasets import sbm
+    from graphmine_tpu.ops.cluster_metrics import adjusted_rand_index
+    from graphmine_tpu.ops.louvain import leiden
+
+    src, dst, truth = sbm(_MARGIN_SIZES, _MARGIN_P_IN, _MARGIN_P_OUT, seed=seed)
+    g = build_graph(src, dst, num_vertices=len(truth))
+    return max(
+        float(adjusted_rand_index(np.asarray(labels), truth))
+        for labels in (
+            label_propagation(g, max_iter=5), louvain(g)[0], leiden(g)[0]
+        )
+    )
+
+
+@pytest.mark.parametrize("seed", _MARGIN_SEEDS)
+def test_margin_sbm_ari_band(seed):
+    # measured 0.81-0.94 over seeds 3, 4, 5, 11 on the CPU
+    assert 0.7 < _margin_sbm_best_ari(seed) < 0.97
+
+
+def test_margin_sbm_ari_spread():
+    values = [_margin_sbm_best_ari(seed) for seed in _MARGIN_SEEDS]
+    assert max(values) - min(values) < 0.15, values

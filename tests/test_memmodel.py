@@ -18,11 +18,10 @@
 - satellites: device_hbm_bytes min-across-devices, /profilez
   device-memory capture, heartbeat device-memory cache, serve /statusz
   memory section + graphmine_memory_* gauges + the low-headroom alert
-  rule, bench_diff's memory sub-record gate (bytes regress UP).
+  rule.
 """
 
 import dataclasses
-import json
 import os
 import subprocess
 import sys
@@ -48,8 +47,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOOLS = os.path.join(REPO, "tools")
 if TOOLS not in sys.path:
     sys.path.insert(0, TOOLS)
-
-import bench_diff  # noqa: E402
 
 
 def ring4(weights=None):
@@ -722,99 +719,3 @@ def test_obs_report_memory_flags_and_suggestions():
     )
     assert "<< model under-estimates" not in rss
     assert "host-RSS only" in rss
-
-
-# ---------------------------------------------------------------------------
-# bench: per-tier memory sub-record + bench_diff gate
-# ---------------------------------------------------------------------------
-
-
-def _bench_file(tmp_path, name, n, value, mem=None):
-    rec = {"metric": "lpa_edges_per_sec_per_chip", "value": value,
-           "unit": "edges/s/chip", "vs_baseline": 1.0}
-    if mem is not None:
-        rec["detail"] = {"memory": mem}
-    path = tmp_path / name
-    path.write_text(json.dumps({
-        "n": n, "cmd": "python bench.py", "rc": 0,
-        "tail": json.dumps(rec) + "\n",
-        "parsed": {"metric": "x", "suite": {"tiers": {"chip": {
-            "m": rec["metric"], "v": value, "u": rec["unit"], "vs": 1.0,
-        }}}},
-    }))
-    return str(path)
-
-
-def _mem(peak, upper=False, model=None):
-    out = {"peak_rss_bytes": peak, "upper_bound": upper,
-           "source": "rusage_children"}
-    if model is not None:
-        out["model_bytes"] = model
-    return out
-
-
-def test_bench_diff_memory_gate_bytes_regress_up(tmp_path, capsys):
-    a = _bench_file(tmp_path, "BENCH_r90.json", 90, 1e8,
-                    _mem(1_000_000_000, model=900_000_000))
-    b = _bench_file(tmp_path, "BENCH_r91.json", 91, 1e8,
-                    _mem(1_300_000_000))
-    assert bench_diff.main([a, b]) == 1       # +30% past the ±25% band
-    err = capsys.readouterr().err
-    assert "chip.memory.peak_rss_bytes" in err
-    assert "bytes regress UP" in err
-    # within tolerance: clean; DOWN is an improvement, never gates
-    c = _bench_file(tmp_path, "BENCH_r92.json", 92, 1e8,
-                    _mem(1_200_000_000))
-    assert bench_diff.main([a, c]) == 0
-    d = _bench_file(tmp_path, "BENCH_r93.json", 93, 1e8,
-                    _mem(400_000_000))
-    assert bench_diff.main([a, d]) == 0
-    # an upper-bound sample (the child never raised the cumulative
-    # rusage max) is not comparable and must not gate
-    e = _bench_file(tmp_path, "BENCH_r94.json", 94, 1e8,
-                    _mem(1_300_000_000, upper=True))
-    assert bench_diff.main([a, e]) == 0
-    # per-run tolerance override
-    assert bench_diff.main([a, b, "--tolerance", "memory=0.5"]) == 0
-    capsys.readouterr()
-
-
-def test_bench_diff_manifest_tracks_memory_subrecord(tmp_path):
-    with_mem = _bench_file(tmp_path, "BENCH_r90.json", 90, 1e8,
-                           _mem(1_000_000_000))
-    without = _bench_file(tmp_path, "BENCH_r89.json", 89, 1e8)
-    caps = [bench_diff.load_bench(p) for p in (without, with_mem)]
-    manifest = bench_diff.silicon_manifest(caps)
-    assert manifest["sub_records"]["chip.memory"] == "silicon"
-    assert "serve.memory" in manifest["pending"]
-
-
-def test_bench_tier_memory_subrecord_shape():
-    """bench.py's orchestrator-side injection: the helper stamps a
-    schema-stable memory sub-record (peak + upper_bound + model when the
-    record names its workload) onto a parsed tier record. ``before`` is
-    the cumulative reaped-children max sampled before the child spawned
-    — a tier that did not raise it (including one whose apparent raise
-    came from a NON-tier child like the backend audit) reports the
-    bound with upper_bound=true and never feeds the gate."""
-    sys.path.insert(0, REPO)
-    import bench
-
-    # spawn one real child so RUSAGE_CHILDREN is non-zero
-    subprocess.run([sys.executable, "-c", "print('x' * 100000)"],
-                   capture_output=True)
-    rec = {"metric": "x", "detail": {"num_vertices": 1000,
-                                     "num_edges": 5000}}
-    mem = bench._tier_memory_subrecord(rec, before=0)
-    assert mem is not None
-    assert mem["peak_rss_bytes"] > 0
-    assert mem["upper_bound"] is False      # this "child" raised the max
-    assert mem["model_bytes"] == memmodel.schedule_bytes_per_device(
-        "single", 1000, 5000, 1
-    )
-    # a tier that did not raise the cumulative max reports the bound —
-    # another child's peak is never attributed to it
-    now = bench._children_maxrss_bytes()
-    mem2 = bench._tier_memory_subrecord({"metric": "y"}, before=now)
-    assert mem2["upper_bound"] is True
-    assert "model_bytes" not in mem2

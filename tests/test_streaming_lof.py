@@ -1,5 +1,7 @@
 """Streaming LOF tests: sklearn novelty-mode oracle + sliding-window behavior."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -79,6 +81,59 @@ def test_streaming_flags_outliers(rng):
         y[120:] = True
         aurocs.append(auroc(scores, y))
     assert min(aurocs) > 0.95
+
+
+@functools.cache
+def _injected_shell_auroc(seed: int) -> float:
+    """Detection AUROC of outliers that ride a stream of blobs. Inlier
+    radii about each centre follow a chi(8) law (mean ~2.83, 99.9th
+    percentile ~4.4); the injected 0.5 % sit on a uniform [4, 6] radial
+    shell JUST outside that envelope, so the value has room to move both
+    ways (a +/-12 uniform box saturates it at exactly 1.0)."""
+    from graphmine_tpu.ops.lof import auroc
+
+    n, f, chunk, cap = 1 << 14, 8, 1 << 11, 1 << 11
+    rng = np.random.default_rng(seed)
+    centers = rng.normal(size=(32, f)).astype(np.float32) * 4
+    assign = rng.integers(0, 32, n)
+    pts = centers[assign] + rng.normal(size=(n, f)).astype(np.float32)
+    is_out = rng.random(n) < 0.005
+    n_out = int(is_out.sum())
+    direction = rng.normal(size=(n_out, f)).astype(np.float32)
+    direction /= np.linalg.norm(direction, axis=1, keepdims=True)
+    radius = rng.uniform(4.0, 6.0, (n_out, 1)).astype(np.float32)
+    pts[is_out] = centers[assign[is_out]] + direction * radius
+
+    s = StreamingLOF(k=32, capacity=cap)
+    scores = np.empty(n, np.float32)
+    for lo in range(0, n, chunk):
+        scores[lo:lo + chunk] = s.update(pts[lo:lo + chunk])
+    warm = slice(cap, None)  # the first window-fill is a still-warming model
+    return float(auroc(scores[warm], is_out[warm]))
+
+
+_SHELL_SEEDS = (11, 12, 13)
+
+
+@pytest.mark.parametrize("seed", _SHELL_SEEDS)
+def test_injected_shell_auroc_band(seed):
+    import jax
+
+    value = _injected_shell_auroc(seed)
+    assert value < 0.999, value  # not saturated, on every backend
+    if jax.default_backend() == "cpu":
+        # measured 0.9857-0.9901 on the CPU; an accelerator's kNN ties and
+        # rounding may shift it, so the floor holds where it was measured
+        assert value > 0.9, value
+
+
+def test_injected_shell_auroc_spread():
+    import jax
+
+    if jax.default_backend() != "cpu":
+        pytest.skip("the spread was measured on the CPU backend")
+    values = [_injected_shell_auroc(seed) for seed in _SHELL_SEEDS]
+    assert max(values) - min(values) < 0.03, values
 
 
 def test_persistent_cluster_absorbed_without_threshold(rng):

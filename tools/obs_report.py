@@ -376,8 +376,8 @@ def _roofline_section(records, min_frac: float):
             out.append(
                 f"  !! {exchange_windows} window(s) carry an exchange "
                 "split anchored to the UNMEASURED exchange_bytes_per_sec "
-                "model seed — capture the sharded bench tier "
-                "(and re-seed via GRAPHMINE_ROOFLINE_FILE) before "
+                "model seed — no chip record measures it "
+                "(re-seed via GRAPHMINE_ROOFLINE_FILE) before "
                 "trusting a below-model exchange verdict "
                 "(docs/RUNBOOKS.md §15)"
             )
@@ -454,8 +454,8 @@ def _memory_section(records, t0):
     """Memory-plane triage (ISSUE 14, docs/OBSERVABILITY.md "Memory
     plane"): the per-phase predicted-vs-peak waterfall from
     ``memory_watermark`` records, flagged under-estimates, a concrete
-    recalibration suggestion for the ``obs/memmodel.py`` byte seeds
-    (the bench_diff crossover-suggestion pattern), and every
+    recalibration suggestion for the ``obs/memmodel.py`` byte seeds,
+    and every
     memory-attributed degrade — plan-time pre-degrades and reactive
     OOMs with their attached last watermark, joinable back to the full
     record by span path. Empty list = no memory-plane records
@@ -519,8 +519,8 @@ def _memory_section(records, t0):
                 f"  {head:>8}  {src:<6}  {g['n']:>4}"
                 f"  {_bar(g['peak'] / peak_max, 16)}{flag}"
             )
-        # Recalibration suggestion (the bench_diff crossover-suggestion
-        # pattern): what the measured peaks mean for the byte seeds the
+        # Recalibration suggestion: what the measured peaks mean for
+        # the byte seeds the
         # planner AND the model read (one owner — obs/memmodel.py).
         try:
             from graphmine_tpu.obs.memmodel import BYTES_PER_EDGE

@@ -25,9 +25,8 @@
 #                  exact against hand-computed tiny plans, the planner
 #                  constant derivation, memory_watermark e2e + the
 #                  fault-injected OOM degrade join, /statusz + /profilez
-#                  memory surfaces, the obs_report memory section and
-#                  the bench_diff memory gate) — the fast slice when
-#                  iterating on obs/memmodel.py
+#                  memory surfaces and the obs_report memory section)
+#                  — the fast slice when iterating on obs/memmodel.py
 #   --quality-only run just the `quality`-marked result-quality suite
 #                  (tests/test_quality.py: sketch merge associativity,
 #                  PSI drift exactness, canary probe recall + injected
@@ -39,9 +38,8 @@
 #                  observability suite (tests/test_costmodel.py: the
 #                  analytical cost model exact against hand-computed
 #                  plans, superstep_timing achieved-vs-model e2e,
-#                  bench_diff gate on synthetic BENCH files) — the
-#                  fast slice when iterating on
-#                  obs/costmodel.py or tools/bench_diff.py
+#                  the obs_report roofline section) — the fast slice
+#                  when iterating on obs/costmodel.py
 #   --faults-only  run just the `faults`-marked recovery suite — the fast
 #                  pre-commit loop when iterating on resilience paths
 #   --obs-only     run just the `obs`-marked tracing/telemetry suite

@@ -36,8 +36,8 @@ def compute():
     dst = rng.integers(0, v, e).astype(np.int32)
     g = gm.build_graph(src, dst, num_vertices=v)
     gd = gm.build_graph(src, dst, num_vertices=v, symmetric=False)
-    # bucketed-min CC (r5): the fused-plan superstep path the cc bench
-    # tier headlines — audited against CPU like every other kernel
+    # bucketed-min CC (r5): the fused-plan superstep path cell
+    # `wcc-g500-22` runs — audited against CPU like every other kernel
     from graphmine_tpu.ops.bucketed_mode import build_graph_and_plan
 
     gp, plan = build_graph_and_plan(src, dst, num_vertices=v)

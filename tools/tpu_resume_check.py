@@ -16,10 +16,11 @@ then resuming from the surviving npz checkpoint:
               fresh run — LPA is deterministic, so resume-then-finish
               and run-straight-through are the same trajectory.
 
-The dataset is the e2e bench tier's 25M-edge string-domain parquet
-(``bench.main_e2e``): big enough that supersteps are real device work,
-small enough to generate in-tool. The reference has no recovery story at
-all (``persist()`` at ``Graphframes.py:82`` is in-memory caching);
+The dataset is a 25M-edge string-domain parquet over 262,144 domains
+with a Pareto-tail degree skew: big enough that supersteps are real
+device work, small enough to generate in-tool. The reference has no
+recovery story at all (``persist()`` at ``Graphframes.py:82`` is
+in-memory caching);
 SURVEY §5 names checkpoint/resume as the failure-recovery subsystem.
 
 Prints ONE JSON line; exit 0 iff labels match bit-exactly. Run on the
@@ -49,15 +50,23 @@ if _REPO not in sys.path:
 MAX_ITER = 20  # wider kill window than the parity default of 5
 
 
+def _powerlaw_edges(v: int, e: int, seed: int):
+    """Pareto-tail endpoints: degree skew comparable to web graphs (the
+    bundled data's hub pattern, BASELINE.md)."""
+    rng = np.random.default_rng(seed)
+    raw = rng.pareto(1.2, size=2 * e)
+    ids = np.minimum((raw * v / 50).astype(np.int64), v - 1).astype(np.int32)
+    perm = rng.permutation(v).astype(np.int32)  # decorrelate id order
+    ids = perm[ids]
+    return ids[:e], ids[e:]
+
+
 def _make_dataset(tmp: str) -> str:
     import pyarrow as pa
     import pyarrow.parquet as pq
 
-    sys.path.insert(0, _REPO)
-    from bench import powerlaw_edges
-
     v, e = 1 << 18, 25_000_000
-    src, dst = powerlaw_edges(v, e, seed=9)
+    src, dst = _powerlaw_edges(v, e, seed=9)
     names = pa.array([f"d{i:07d}.example" for i in range(v)])
     col = lambda ids: pa.DictionaryArray.from_arrays(
         pa.array(ids, pa.int32()), names
