@@ -297,8 +297,8 @@ class GraphFrame:
         return self._tri
 
     def triangle_count(self):
-        tri, total, _ = self._triangle_cache()
-        return tri, total
+        from graphmine_tpu.ops.triangles import _as_counts
+        return _as_counts(self._triangle_cache())
 
     def bfs(self, from_: _MaskLike, to: _MaskLike, direction: str = "out",
             max_path_length: int = 10):

@@ -106,6 +106,12 @@ register("impl_selected", "op", "impl", "n", "reason")
 # where the rows were not admitted and on a cache hit. PageRank's message
 # reading (`op: pagerank_inflow`) reads the plan the other two ops cache:
 # on a graph one of them planned its record says `cached: true`, 0.0 s.
+# The exact triangle counts' plan (`op: lcc`, ops/triangles.py:_lcc_plan,
+# once per graph, `cached: true` after) says `core_vertices`, `core_edges`,
+# `classes`, `wedges_core` and `wedges_tail` (neighbour pairs to close, in
+# the core's bit rows and outside them), `core_rows`, `tail_edges`,
+# `tail_compares` and `resident_bytes` (what the plan keeps on the device).
+# Benchmark metric `lcc_core_wedge_share` reads the two wedge counts.
 register("plan_build", "op", "family", "seconds", "padded_slots_per_edge")
 # superstep_timing (ISSUE 12): achieved-vs-model throughput for one
 # window of supersteps, emitted at the existing tripwire/telemetry
@@ -421,7 +427,10 @@ DEVICE_SCOPES = frozenset((
     "sizes", "edge_counts", "q",
     # inner: features / triangles
     "degrees", "neighbor_stats", "distinct_communities", "stack",
-    "bsearch", "count",
+    # exact triangle counts (ops/triangles.py): the core's bit rows built and
+    # a centre's own, the rows fetched and and-ed, the tail's row pairs
+    # compared, a block's credits added to the two count words
+    "core_bits", "bit_rows", "row_compare", "credit",
     # inner: kNN / IVF / LOF
     "distance", "topk", "assign", "lloyd_update", "search_gather",
     "search_distance", "search_topk", "merge_gather", "merge_topk",
@@ -445,6 +454,8 @@ STAGE_SPANS = frozenset((
     "masked_lpa", "decile_report",
     # under `outliers_lof` (and wherever else ivf_knn / lof_scores run)
     "lof_features", "triangles_host", "triangles_device",
+    # under `triangles_device`: the two device stages of the exact counts
+    "lcc_core", "lcc_tail",
     "features_device", "ivf_train", "ivf_probe", "ivf_lists", "ivf_search",
     "ivf_merge", "knn_exact", "lof_formula",
     # the write path to a snapshot. Under `snapshot_publish` (pipeline):

@@ -49,15 +49,16 @@ def vertex_features(
     law (max degree 1,223 at 4.6K vertices on the bundled data — SURVEY
     §7 hard part 3); fractions are already in [0, 1].
     """
-    # clustering_coefficient orients the CSR on the host, so it runs
-    # outside jit; everything else is one compiled program.
+    # clustering_coefficient plans on the host (ops/triangles.py:_lcc_plan),
+    # so it runs outside jit; everything else is one compiled program.
     # ``triangles_cache``: a prior ops.triangles._triangles result (e.g.
     # GraphFrame._triangle_cache()) to skip the host pass.
-    # ``include_clustering`` mirrors the host twin: True = exact wedge
-    # pipeline; ``"sampled"`` = the wedge-count-independent estimator
-    # (r5: the exact expansion allocates ~28 B/wedge on the host, which
-    # OOM-killed a 25M-edge mega-hub run at 130 GB — the driver probes
-    # ``oriented_wedge_count`` and passes "sampled" past its budget);
+    # ``include_clustering`` mirrors the host twin: True = the exact
+    # counts; ``"sampled"`` = the wedge-count-independent estimator (r5:
+    # the exact counts then listed ~28 B a wedge on the host, which
+    # OOM-killed a 25M-edge mega-hub run at 130 GB; they list none since
+    # PR 46, and the driver still probes ``oriented_wedge_count`` and
+    # passes "sampled" past its budget);
     # False zeros the column (the measured-weaker host-7 configuration).
     # ``sink``: optional MetricsSink; the triangle pass and the compiled
     # feature program are then stage spans (``triangles_host``,
@@ -158,15 +159,15 @@ def vertex_features_host(
 
     ``include_clustering`` selects the 8th column:
 
-    * ``True`` — the exact wedge pipeline; matches
+    * ``True`` — the exact counts (``ops/triangles.py``); matches
       :func:`vertex_features` within float32 rounding (tested; host
       accumulation is float64).
     * ``"sampled"`` (r4, the scale-out default) — the wedge-sampled
       estimator (:func:`~graphmine_tpu.ops.triangles.
       sampled_clustering_coefficient`, per-vertex stderr
       ``<= 1/(2*sqrt(clustering_samples))``), whose cost is independent
-      of the wedge count — so the full 8-feature set survives at the
-      scale where the exact O(sum d+^2) expansion is infeasible.
+      of the wedge count — so the full 8-feature set survives where
+      the graph does not fit one device (the exact counts run on one).
     * ``False`` — zero the column (7 informative features). The
       r-series AUROC harness scored the 7-feature and sampled-8 configs
       next to the exact-8 headline (VERDICT r3 item 5; before the chip

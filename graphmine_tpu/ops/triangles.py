@@ -4,26 +4,55 @@ Engine-surface parity with GraphFrames' ``triangleCount`` (exposed on the
 object built at ``Graphframes.py:78``; semantics there: direction and
 duplicate edges ignored — triangles of the underlying simple undirected
 graph). Also feeds the clustering-coefficient feature of the LOF outlier
-scorer (SURVEY §7.5).
+scorer (SURVEY §7.5) and is LDBC Graphalytics' LCC (``benchmark/algorithms/
+lcc.py``, cell ``lcc-g500-22``).
 
-TPU design — degree-ordered wedge checking:
+TPU design — a plan in place of a wedge list. No wedge is ever listed on
+the host, and none is looked up one by one on the device (an element
+gather issues 69 M indices a second on a v5e, a binary search of eight
+steps answers 7.7 M pairs a second: 7.2e9 pairs of graph500-22 would take a
+quarter of an hour). Rows are fetched instead, and compared whole:
 
-1. host: simplify edges (dedup, drop self-loops), orient each edge from
-   lower to higher (degree, id) rank; build the oriented CSR and expand
-   the exact wedge list (u, v, w): for every oriented edge (u, v), every
-   oriented neighbor w of u. |wedges| = sum_u d+(u)^2, kept near-linear
-   by the degree ordering (d+ = O(sqrt(m))).
-2. device: one vectorized binary search per wedge — is (v, w) an oriented
-   edge? — as a fori_loop of gathers over the oriented CSR (static
-   iteration count = ceil(log2(max row length))), then three
-   ``segment_sum`` scatters credit each triangle to its corners.
+1. host, once per graph (:func:`_lcc_plan`, O(E log E), cached as the
+   superstep plans are): simple undirected edges, vertices renamed by
+   their (degree, id) rank, every edge oriented from the lower rank to the
+   higher, the oriented CSR sorted by rank. Every triangle ``u < v < w``
+   is then found once, from its lowest corner ``u``, as a pair ``(v, w)``
+   of ``u``'s row that is an edge. The top ``K`` ranks are the *core*
+   (``K`` a power of two, its bitmap at most 32 B an edge and 2 GiB:
+   131,072 vertices at graph500-22, where it holds 98.6 % of the pairs).
+2. device, stage ``lcc_core``: the core's symmetric adjacency as bit rows
+   (``[K, K/32]`` uint32, built on the device from the core's edges and kept
+   with the plan). For a centre ``u`` anywhere, ``b_u`` is the bit row of
+   its neighbours in the core; for each such neighbour ``v`` the row
+   ``A[v]`` is fetched whole and ``popcount(A[v] & b_u)`` is the number of
+   ``u``'s core neighbours adjacent to ``v``: every triangle of ``u`` with
+   its other two corners in the core, credited to ``v`` directly and to
+   ``u`` by half the row's sum. One row fetch an oriented edge into the
+   core, 16 KB each, no lookup.
+3. device, stage ``lcc_tail``: the triangles whose middle corner ``v`` is
+   outside the core. For each oriented edge ``(u, v)`` outside it, ``u``'s
+   row and ``v``'s own row are compared all against all; a match is a
+   triangle, credited to its three corners. The rows of the vertices these
+   edges join are kept padded to whole tiles of 128 in a table of their own
+   and fetched whole (:func:`_tail_table_class`); where that table would
+   pass 2 GiB the two rows are cut out of the CSR as windows instead
+   (:func:`_tail_class`: 1.3 us a window on a v5e, whatever its width).
+4. counts are two uint32 words a vertex (:func:`_add64`: a hub of degree
+   163,352 may close 1.3e10 pairs), and the coefficient is one float32
+   division of them.
 
-No [V, V] densification, no per-vertex host loops; everything after the
-host build is O(|wedges|) gathers.
+Centres and edges are grouped by row width on a half-octave ladder, each
+class one compiled program looping over blocks of bounded size; a block's
+inner loops run to its longest row, the rows of a class being sorted by
+length. ``ops/ktruss.py`` keeps the host wedge list (:func:`_oriented_csr`):
+it needs the edge ids of every triangle's three sides.
 """
 
 from __future__ import annotations
 
+import weakref
+from dataclasses import dataclass, field
 from functools import partial
 
 import jax
@@ -31,12 +60,498 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from graphmine_tpu.graph.container import Graph, simple_undirected_edges
+from graphmine_tpu.graph.container import (
+    Graph, simple_undirected_edges, sorted_pair_keys, split_pair_keys,
+)
 from graphmine_tpu.obs.spans import stage_span
 
 
+# -- the plan -----------------------------------------------------------------
+
+_CORE_MAX = 1 << 17         # core vertices at most: bit rows of 16 KB, 2 GiB of them
+_CORE_BITS_PER_EDGE = 256   # the core's bitmap may take 32 B an edge
+_CORE_BLOCK_ROWS = 1 << 16  # bit rows one core block fetches (centres x width)
+_TAIL_BLOCK_COMPARES = 1 << 26  # comparisons one tail block makes (edges x width^2)
+_TAIL_TABLE_MAX = 1 << 31   # bytes the tail's table of padded rows may take
+
+
+def _ladder(longest: int) -> np.ndarray:
+    """Row widths 2, 3, 4, 6, 8, 12, ... (half octaves) up to ``longest``.
+    Coarser than the supersteps' 1.10x ladder on purpose: a block's loops
+    stop at its longest row, so padding costs the elementwise passes alone,
+    and every width is a program to compile."""
+    widths = [2, 3]
+    while widths[-1] < longest:
+        widths.append(2 * widths[-2])
+    return np.asarray(widths, np.int64)
+
+
+def _core_size(num_edges: int, num_vertices: int) -> int:
+    """Vertices in the core, by the graph: the smallest power of two whose
+    bitmap holds ``_CORE_BITS_PER_EDGE`` bits an edge, at most ``_CORE_MAX``
+    and no more than cover the vertex space (32 at least: one word a row)."""
+    k = 32
+    while (k < _CORE_MAX and k < num_vertices
+           and k * k < _CORE_BITS_PER_EDGE * num_edges):
+        k *= 2
+    return k
+
+
+def _width_classes(width: np.ndarray):
+    """``(ladder width, positions)`` for each width class of the rows whose
+    lengths are ``width``, positions in the order given."""
+    widths = _ladder(int(width.max()))
+    classes = np.searchsorted(widths, width)
+    order = np.argsort(classes, kind="stable")
+    cuts = np.flatnonzero(np.diff(classes[order])) + 1
+    for rows in np.split(order, cuts):
+        yield int(widths[classes[rows[0]]]), rows
+
+
+def _pow2_at_least(n: int) -> int:
+    return 1 << max(int(n) - 1, 0).bit_length()
+
+
+@dataclass
+class _LccPlan:
+    """What :func:`_lcc_plan` keeps per graph: the rank-ordered oriented CSR
+    and the core's bit rows on the device, and per width class the rows
+    each compiled program walks (padded to whole blocks)."""
+
+    num_vertices: int
+    core_start: int              # ranks from here up are the core
+    rank: jax.Array              # int32 [V]: id -> rank
+    degree: jax.Array            # int32 [V]: simple undirected degree, by id
+    col: jax.Array               # int32 [E + pad]: higher neighbours, by rank
+    bits: jax.Array | None       # uint32 [K, K / 32]: the core's adjacency
+    tail_table: jax.Array | None = None  # int32 [rows, 128 n]: the tail's rows, padded with -1
+    core_classes: list = field(default_factory=list)
+    tail_classes: list = field(default_factory=list)
+    stats: dict = field(default_factory=dict)
+
+
+def _blocked(arrays, size: int):
+    """Each int32 array padded with zeros to whole blocks of ``size`` and put
+    on the device; the number of blocks."""
+    n = len(arrays[0])
+    blocks = -(-n // size)
+    out = []
+    for x in arrays:
+        padded = np.zeros(blocks * size, np.int32)
+        padded[:n] = x
+        out.append(jnp.asarray(padded))
+    return out, blocks
+
+
+@partial(jax.jit, static_argnames=("k",))
+def _core_bits(row, column, k: int):
+    """The core's symmetric adjacency as bit rows ``[k, k / 32]`` from its
+    edges (core indices, each edge once): distinct bits, so adding is or."""
+    with jax.named_scope("triangles"), jax.named_scope("core_bits"):
+        words = k // 32
+        one = jnp.uint32(1)
+        flat = jnp.zeros((k * words,), jnp.uint32)
+        flat = flat.at[row * words + (column >> 5)].add(one << (column & 31).astype(jnp.uint32))
+        flat = flat.at[column * words + (row >> 5)].add(one << (row & 31).astype(jnp.uint32))
+        return flat.reshape(k, words)
+
+
+def _build_plan(graph: Graph, simple_edges=None, core_vertices=None) -> _LccPlan:
+    """Host side of the exact kernel (the module's note, step 1) and the
+    hand-over to the device. ``core_vertices`` overrides the rule of
+    :func:`_core_size` (tests: no core, everything core)."""
+    v = graph.num_vertices
+    a, b = simple_edges or simple_undirected_edges(graph)
+    num_edges = len(a)
+    degree = (np.bincount(a, minlength=v) + np.bincount(b, minlength=v)).astype(np.int32)
+    order = np.lexsort((np.arange(v), degree))
+    rank = np.empty(v, np.int32)
+    rank[order] = np.arange(v, dtype=np.int32)
+    bits = max(int(v - 1).bit_length(), 1)
+    keys = sorted_pair_keys(rank[a], rank[b], bits)
+    low, col = split_pair_keys(keys, bits)
+    del keys
+    above = np.bincount(low, minlength=v).astype(np.int64)  # d+ by rank
+    ptr = np.zeros(v + 1, np.int64)
+    np.cumsum(above, out=ptr[1:])
+
+    k = _core_size(num_edges, v) if core_vertices is None else int(core_vertices)
+    core_start = max(v - k, 0) if k else v
+    to_core = col >= core_start
+    in_core = np.bincount(low[to_core], minlength=v).astype(np.int64)  # by centre
+
+    plan = _LccPlan(num_vertices=v, core_start=core_start,
+                    rank=jnp.asarray(rank), degree=jnp.asarray(degree),
+                    col=None, bits=None)
+    longest = 1
+    slots = 0
+
+    # core classes: centres with two core neighbours or more, longest first
+    centres = np.flatnonzero(in_core >= 2)
+    centres = centres[np.argsort(-in_core[centres], kind="stable")]
+    if len(centres):
+        for w, rows in _width_classes(in_core[centres]):
+            rows = centres[rows]
+            nb = min(int(np.clip(_CORE_BLOCK_ROWS // w, 512, 8192)),
+                     _pow2_at_least(len(rows)))
+            arrays, blocks = _blocked(
+                (ptr[rows + 1] - in_core[rows], in_core[rows], rows), nb)
+            plan.core_classes.append((w, nb, blocks, *arrays))
+            longest = max(longest, w)
+            slots += blocks * nb * w
+
+    # tail classes: oriented edges (u, v) with v outside the core
+    at = np.flatnonzero(~to_core)
+    u, mid = low[at], col[at]
+    rest = ptr[u + 1] - (at + 1)  # what is left of u's row after v
+    keep = (rest > 0) & (above[mid] > 0)
+    at, u, mid, rest = at[keep], u[keep], mid[keep], rest[keep]
+    compares = table_width = 0
+    in_tail = None
+    if len(at):
+        # the rows of the vertices these edges join, padded to whole tiles of
+        # 128, are fetched whole where that table fits; where it does not,
+        # the two rows of an edge are cut out of the CSR as windows
+        in_tail = np.unique(np.concatenate([u, mid]))
+        table_width = -(-int(above[in_tail].max()) // 128) * 128
+        if len(in_tail) * table_width * 4 <= _TAIL_TABLE_MAX:
+            width = above[mid]  # a class is a width of the middle's row
+            columns = (np.searchsorted(in_tail, u), np.searchsorted(in_tail, mid),
+                       above[mid], u, mid)
+        else:
+            in_tail, table_width = None, 0
+            width = np.maximum(rest, above[mid])
+            columns = (at + 1, rest, ptr[mid], above[mid], u, mid)
+        for w, rows in _width_classes(width):
+            left = table_width or w  # the low vertex's whole row, or a window of it
+            ne = min(int(np.clip(_TAIL_BLOCK_COMPARES // (left * w), 8, 1 << 16)),
+                     _pow2_at_least(len(rows)))
+            arrays, blocks = _blocked([x[rows] for x in columns], ne)
+            plan.tail_classes.append((w, ne, blocks, *arrays))
+            longest = max(longest, w, table_width)
+            slots += blocks * ne * (w + left)
+            compares += len(rows) * left * w
+
+    # a window never runs off the end: the longest one fits after the last edge
+    plan.col = jnp.asarray(np.concatenate([col, np.zeros(longest, np.int32)]))
+    if in_tail is not None:
+        plan.tail_table = _tail_rows(
+            plan.col, jnp.asarray(ptr[in_tail].astype(np.int32)),
+            jnp.asarray(above[in_tail].astype(np.int32)), width=table_width)
+    inside = low >= core_start
+    core_edges = int(np.count_nonzero(inside))
+    if k:
+        plan.bits = _core_bits(jnp.asarray(low[inside] - core_start),
+                               jnp.asarray(col[inside] - core_start), k=k)
+    wedges = int((above * (above - 1) // 2).sum())
+    wedges_core = int((in_core * (in_core - 1) // 2).sum())
+    held = [x for x in (plan.rank, plan.degree, plan.col, plan.bits, plan.tail_table)
+            if x is not None]
+    held += [x for c in plan.core_classes + plan.tail_classes for x in c[3:]]
+    jax.block_until_ready(held)
+    plan.stats = {
+        "core_vertices": k, "core_edges": core_edges,
+        "classes": len(plan.core_classes) + len(plan.tail_classes),
+        "wedges_core": wedges_core, "wedges_tail": wedges - wedges_core,
+        "core_rows": int(in_core[centres].sum()), "tail_edges": len(at),
+        "tail_compares": compares, "tail_table_rows": 0 if in_tail is None else len(in_tail),
+        "padded_slots_per_edge": slots / max(num_edges, 1),
+        "resident_bytes": int(sum(x.nbytes for x in held)),
+    }
+    return plan
+
+
+_plan_cache: dict = {}
+
+
+def _lcc_plan(graph: Graph, simple_edges=None):
+    """The graph's LCC plan, built once per graph as the superstep plans are
+    (``ops/lpa.py:_cached_auto_plan``: keyed by the identity of the graph's
+    ``msg_ptr``, evicted with it). Returns ``(plan, build seconds,
+    cached)``."""
+    key = id(graph.msg_ptr)
+    hit = _plan_cache.get(key)
+    if hit is not None and hit[0]() is graph.msg_ptr:
+        return hit[1], 0.0, True
+    from graphmine_tpu.ops.superstep_policy import timed_plan_build
+
+    plan, seconds = timed_plan_build(lambda: _build_plan(graph, simple_edges))
+    ref = weakref.ref(graph.msg_ptr, lambda _, k=key: _plan_cache.pop(k, None))
+    _plan_cache[key] = (ref, plan)
+    return plan, seconds, False
+
+
+# -- the device side ----------------------------------------------------------
+
+
+def _add64(lo, hi, part):
+    """``(lo, hi) + part`` for uint32 words: per-vertex triangle counts are
+    two words, since one does not hold a hub's (a vertex of degree 163,352
+    closes up to 1.3e10 pairs). ``part`` is one block's credits, which the
+    block sizes keep under 2**32 a vertex."""
+    new = lo + part
+    return new, hi + (new < part).astype(jnp.uint32)
+
+
+def _windows(flat, starts, width: int):
+    """``flat[start : start + width]`` for every start, as ``[n, width]``:
+    one slice a row, not ``width`` lookups."""
+    dims = lax.GatherDimensionNumbers(
+        offset_dims=(1,), collapsed_slice_dims=(), start_index_map=(0,))
+    return lax.gather(flat, starts[:, None], dims, slice_sizes=(width,),
+                      mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+def _block(arrays, i, size: int):
+    return [lax.dynamic_slice_in_dim(x, i * size, size) for x in arrays]
+
+
+@partial(jax.jit, static_argnames=("w", "nb", "core_start"), donate_argnums=(0, 1))
+def _core_class(lo, hi, bits, col, blocks, starts, lens, centres, *,
+                w: int, nb: int, core_start: int):
+    """One width class of stage ``lcc_core`` (the module's note, step 2):
+    ``blocks`` blocks of ``nb`` centres whose core neighbours are the
+    ``lens`` entries of ``col`` from ``starts``, longest first."""
+    words = bits.shape[1]
+    word_ids = jnp.arange(words, dtype=jnp.int32)[None, :]
+    slots = jnp.arange(w, dtype=jnp.int32)[None, :]
+
+    def block(i, counts):
+        start, length, centre = _block((starts, lens, centres), i, nb)
+        with jax.named_scope("core_bits"):
+            ranks = _windows(col, start, w)
+            valid = slots < length[:, None]
+            index = jnp.where(valid, ranks - core_start, 0)
+            word = index >> 5
+            bit = jnp.where(valid, jnp.uint32(1) << (index & 31).astype(jnp.uint32),
+                            jnp.uint32(0))
+            longest = length[0]  # the class is sorted by length
+
+            def mark(k, b):  # the centre's own bit row, a neighbour at a time
+                at = lax.dynamic_slice_in_dim(word, k, 1, axis=1)
+                return b | jnp.where(at == word_ids,
+                                     lax.dynamic_slice_in_dim(bit, k, 1, axis=1), 0)
+
+            mine = lax.fori_loop(0, longest, mark, jnp.zeros((nb, words), jnp.uint32))
+        with jax.named_scope("bit_rows"):
+
+            def probe(k, shared):  # A[v] & b_u, a neighbour at a time
+                v = lax.dynamic_slice_in_dim(index, k, 1, axis=1)[:, 0]
+                both = lax.population_count(bits[v] & mine).astype(jnp.int32).sum(-1)
+                return lax.dynamic_update_slice_in_dim(shared, both[:, None], k, axis=1)
+
+            shared = lax.fori_loop(0, longest, probe, jnp.zeros((nb, w), jnp.int32))
+            shared = jnp.where(valid, shared, 0).astype(jnp.uint32)
+        with jax.named_scope("credit"):
+            part = jnp.zeros(lo.shape, jnp.uint32)
+            part = part.at[jnp.where(valid, ranks, 0)].add(shared)
+            # every triangle of the centre shows at both of its other corners
+            part = part.at[centre].add(shared.sum(-1) >> 1)
+            return _add64(*counts, part)
+
+    with jax.named_scope("triangles"):
+        return lax.fori_loop(0, blocks, block, (lo, hi))
+
+
+@partial(jax.jit, static_argnames=("width",))
+def _tail_rows(col, starts, lens, width: int):
+    """The tail's table: the rows of the CSR that start at ``starts``, cut
+    out once per plan and padded with -1 to ``width`` (whole tiles of 128),
+    so that a job fetches a row whole, at the rate of a row and not of a
+    window (1.3 us a window of the CSR, whatever its width, on a v5e)."""
+    with jax.named_scope("triangles"), jax.named_scope("row_compare"):
+        slots = jnp.arange(width, dtype=jnp.int32)[None, :]
+        return jnp.where(slots < lens[:, None], _windows(col, starts, width), -1)
+
+
+@partial(jax.jit, static_argnames=("w", "ne"), donate_argnums=(0, 1))
+def _tail_table_class(lo, hi, table, blocks, row_low, row_mid, mid_len, low, mid,
+                      *, w: int, ne: int):
+    """One width class of stage ``lcc_tail`` on the table of padded rows:
+    ``blocks`` blocks of ``ne`` oriented edges ``(low, mid)`` whose rows are
+    ``table[row_low]`` and the first ``w`` of ``table[row_mid]``. Whatever
+    of ``low``'s row is in ``mid``'s ranks above ``mid``, so the whole row
+    stands for what is left of it after ``mid``; a match is credited through
+    ``mid``'s row, which starts at slot 0."""
+    slots = jnp.arange(w, dtype=jnp.int32)[None, :]
+
+    def block(i, counts):
+        at_low, at_mid, length, u, v = _block((row_low, row_mid, mid_len, low, mid), i, ne)
+        with jax.named_scope("row_compare"):
+            left = table[at_low]  # [ne, 128 n], padded with -1
+            ranks = table[at_mid][:, :w]
+            valid = slots < length[:, None]
+            right = jnp.where(valid, ranks, -2)
+            closes = (left[:, :, None] == right[:, None, :]).sum(1, dtype=jnp.uint32)
+            triangles = closes.sum(-1)
+        with jax.named_scope("credit"):
+            part = jnp.zeros(lo.shape, jnp.uint32)
+            part = part.at[jnp.where(valid, ranks, 0)].add(closes)
+            part = part.at[u].add(triangles).at[v].add(triangles)
+            return _add64(*counts, part)
+
+    with jax.named_scope("triangles"):
+        return lax.fori_loop(0, blocks, block, (lo, hi))
+
+
+@partial(jax.jit, static_argnames=("w", "ne"), donate_argnums=(0, 1))
+def _tail_class(lo, hi, col, blocks, rest_start, rest_len, row_start, row_len,
+                low, mid, *, w: int, ne: int):
+    """One width class of stage ``lcc_tail`` (the module's note, step 3):
+    ``blocks`` blocks of ``ne`` oriented edges ``(low, mid)``; what is left
+    of ``low``'s row after ``mid`` against ``mid``'s own row."""
+    slots = jnp.arange(w, dtype=jnp.int32)[None, :]
+
+    def block(i, counts):
+        a_start, a_len, b_start, b_len, u, v = _block(
+            (rest_start, rest_len, row_start, row_len, low, mid), i, ne)
+        with jax.named_scope("row_compare"):
+            a_valid = slots < a_len[:, None]
+            ranks = _windows(col, a_start, w)
+            left = jnp.where(a_valid, ranks, -1)
+            right = jnp.where(slots < b_len[:, None], _windows(col, b_start, w), -2)
+            closes = (left[:, :, None] == right[:, None, :]).sum(-1, dtype=jnp.uint32)
+            triangles = closes.sum(-1)
+        with jax.named_scope("credit"):
+            part = jnp.zeros(lo.shape, jnp.uint32)
+            part = part.at[jnp.where(a_valid, ranks, 0)].add(closes)
+            part = part.at[u].add(triangles).at[v].add(triangles)
+            return _add64(*counts, part)
+
+    with jax.named_scope("triangles"):
+        return lax.fori_loop(0, blocks, block, (lo, hi))
+
+
+@jax.jit
+def _by_id(lo, hi, rank):
+    return lo[rank], hi[rank]
+
+
+def _triangles(graph: Graph, simple_edges=None, sink=None):
+    """Shared pipeline: the plan (built or found), then the two device
+    stages.
+
+    Returns ``(lo, hi, simple_degree)``, each ``[V]`` by vertex id: the
+    triangles through a vertex are ``hi * 2**32 + lo`` (uint32 words).
+    ``sink``: optional MetricsSink; the plan is then the stage span
+    ``triangles_host`` and a ``plan_build`` record (``op: lcc``), the device
+    work ``triangles_device`` with the stages ``lcc_core`` and ``lcc_tail``
+    under it.
+    """
+    with stage_span(sink, "triangles_host") as stage:
+        plan, seconds, cached = _lcc_plan(graph, simple_edges)
+        stats = plan.stats
+        stage.note(wedges=stats["wedges_core"] + stats["wedges_tail"], cached=cached)
+        if sink is not None:
+            sink.emit("plan_build", op="lcc", family="lcc", seconds=round(seconds, 4),
+                      cached=cached, **stats)
+    return _count(plan, sink)
+
+
+def _count(plan: _LccPlan, sink=None):
+    """The device side on a built plan."""
+    v, stats = plan.num_vertices, plan.stats
+    with stage_span(sink, "triangles_device",
+                    wedges=stats["wedges_core"] + stats["wedges_tail"]) as device:
+        lo, hi = jnp.zeros((v,), jnp.uint32), jnp.zeros((v,), jnp.uint32)
+        with stage_span(sink, "lcc_core", blocks=sum(c[2] for c in plan.core_classes),
+                        rows=stats["core_rows"],
+                        bit_products=stats["core_rows"] * stats["core_vertices"]) as stage:
+            for w, nb, blocks, *arrays in plan.core_classes:
+                lo, hi = _core_class(lo, hi, plan.bits, plan.col, blocks, *arrays,
+                                     w=w, nb=nb, core_start=plan.core_start)
+            stage.sync((lo, hi))
+        with stage_span(sink, "lcc_tail", blocks=sum(c[2] for c in plan.tail_classes),
+                        wedges=stats["wedges_tail"], edges=stats["tail_edges"],
+                        compares=stats["tail_compares"]) as stage:
+            for w, ne, blocks, *arrays in plan.tail_classes:
+                if plan.tail_table is not None:
+                    lo, hi = _tail_table_class(lo, hi, plan.tail_table, blocks, *arrays,
+                                               w=w, ne=ne)
+                else:
+                    lo, hi = _tail_class(lo, hi, plan.col, blocks, *arrays, w=w, ne=ne)
+            stage.sync((lo, hi))
+        lo, hi = device.sync(_by_id(lo, hi, plan.rank))
+    return lo, hi, plan.degree
+
+
+def triangle_count(graph: Graph):
+    """Per-vertex triangle counts ``[V]`` (int32) and the global triangle
+    total (a Python int).
+
+    GraphFrames ``triangleCount`` semantics (simple undirected graph). A
+    vertex in 2**31 triangles or more does not fit the answer's type and
+    raises; :func:`clustering_coefficient` reads both words.
+    """
+    return _as_counts(_triangles(graph))
+
+
+def _as_counts(cached):
+    lo, hi, _ = cached
+    words = np.asarray(lo)
+    if np.asarray(hi).any() or (words >> 31).any():
+        raise OverflowError("a vertex is in 2**31 triangles or more; "
+                            "triangle_count answers in int32")
+    return lo.astype(jnp.int32), int(words.sum(dtype=np.uint64)) // 3
+
+
+def oriented_wedge_count(graph: Graph, simple_edges=None) -> int:
+    """Exact count of the oriented wedges ``sum d+^2`` of the degree-ordered
+    orientation, without listing them (O(E log E) host work, O(E) memory).
+
+    It was the feasibility probe of the host wedge list, which the exact
+    counts no longer build (:func:`_oriented_csr` is k-truss's now): 28 B a
+    wedge, ~10^10 wedges on a mega-hub power-law graph at 25M edges, an e2e
+    run OOM-killed at 130 GB RSS. The pipeline driver's LOF feature phase
+    still compares it with ``GRAPHMINE_WEDGE_BUDGET`` and falls back to
+    :func:`sampled_clustering_coefficient` past it (ROADMAP Queue 3: the
+    budget guards a host cost that is gone). ``simple_edges``: optional
+    precomputed :func:`simple_undirected_edges` pair.
+    """
+    v = graph.num_vertices
+    a, b = simple_edges or simple_undirected_edges(graph)
+    if len(a) == 0:
+        return 0
+    deg = np.bincount(a, minlength=v) + np.bincount(b, minlength=v)
+    rank = deg.astype(np.int64) * v + np.arange(v)
+    lo = np.where(rank[a] <= rank[b], a, b)
+    counts = np.bincount(lo, minlength=v).astype(np.int64)
+    # each oriented edge (u, v) expands against u's whole oriented row
+    return int(counts[lo].sum())
+
+
+@jax.jit
+def _coefficient(lo, hi, degree):
+    """``2 T / (d (d - 1))`` in float32 from the two count words."""
+    triangles = hi.astype(jnp.float32) * 4294967296.0 + lo.astype(jnp.float32)
+    d = degree.astype(jnp.float32)
+    pairs = d * (d - 1.0)
+    return jnp.where(pairs > 0, 2.0 * triangles / jnp.maximum(pairs, 1.0), 0.0)
+
+
+def clustering_coefficient(
+    graph: Graph, _cached=None, simple_edges=None, sink=None
+) -> jax.Array:
+    """Local clustering coefficient ``[V]`` (float32): triangles through a
+    vertex over its wedge count on the simplified graph, exact (integer
+    counts, one division; LDBC Graphalytics' LCC on an undirected graph).
+
+    ``_cached`` optionally takes a prior :func:`_triangles` result so a
+    caller needing both counts and coefficients pays the pipeline once;
+    ``simple_edges`` forwards a precomputed dedup (see
+    :func:`_build_plan`); ``sink`` the stage spans of :func:`_triangles`.
+    """
+    lo, hi, degree = (
+        _triangles(graph, simple_edges, sink) if _cached is None else _cached
+    )
+    return _coefficient(lo, hi, degree)
+
+
 def _oriented_csr(graph: Graph, simple_edges=None):
-    """Host-side: simple undirected edges oriented by (degree, id) rank.
+    """Host-side wedge list for :mod:`~graphmine_tpu.ops.ktruss`, which needs
+    every triangle's three edge ids (the exact counts above list no wedge):
+    simple undirected edges oriented by (degree, id) rank, every oriented
+    wedge expanded, ~28 B a wedge of host memory.
 
     Returns ``(ptr, col, wedge_u, wedge_v, wedge_w, simple_degree,
     wedge_e1, wedge_e2)`` — the last two are per-wedge *edge indices*
@@ -84,119 +599,6 @@ def _oriented_csr(graph: Graph, simple_edges=None):
         wedge_e1.astype(np.int32), wedge_e2.astype(np.int32),
     )
 
-
-@partial(jax.jit, static_argnames=("num_vertices", "search_iters"))
-def _count_device(ptr, col, wedge_v, wedge_w, wedge_u, num_vertices: int, search_iters: int):
-    """Vectorized membership test: is (v, w) an oriented edge? Then credit
-    triangles to u, v, w via segment sums."""
-    with jax.named_scope("triangles"):
-        with jax.named_scope("bsearch"):
-            lo = ptr[wedge_v]
-            hi = ptr[wedge_v + 1]
-
-            def bsearch(_, state):
-                lo, hi = state
-                mid = (lo + hi) // 2
-                val = col[jnp.clip(mid, 0, col.shape[0] - 1)]
-                go_right = (val < wedge_w) & (mid < hi)
-                lo = jnp.where(go_right, mid + 1, lo)
-                hi = jnp.where(go_right, hi, jnp.maximum(mid, lo))
-                return lo, hi
-
-            lo_f, _ = lax.fori_loop(0, search_iters, bsearch, (lo, hi))
-            found = (lo_f < ptr[wedge_v + 1]) & (
-                col[jnp.clip(lo_f, 0, col.shape[0] - 1)] == wedge_w
-            )
-            # skip degenerate wedges where v == w (the edge itself)
-            found &= wedge_v != wedge_w
-        with jax.named_scope("count"):
-            hit = found.astype(jnp.int32)
-            tri = (
-                jax.ops.segment_sum(hit, wedge_u, num_segments=num_vertices)
-                + jax.ops.segment_sum(hit, wedge_v, num_segments=num_vertices)
-                + jax.ops.segment_sum(hit, wedge_w, num_segments=num_vertices)
-            )
-            return tri, hit.sum()
-
-
-def _triangles(graph: Graph, simple_edges=None, sink=None):
-    """Shared pipeline: host build + device count once.
-
-    Returns ``(tri [V], total, simple_degree [V])``. ``sink``: optional
-    MetricsSink; the host build and the device count are then the stage
-    spans ``triangles_host`` and ``triangles_device``.
-    """
-    with stage_span(sink, "triangles_host") as stage:
-        ptr, col, wu, wv, ww, deg, _, _ = _oriented_csr(graph, simple_edges)
-        stage.note(wedges=len(wu))
-    if len(wu) == 0:
-        z = jnp.zeros((graph.num_vertices,), jnp.int32)
-        return z, jnp.int32(0), jnp.asarray(deg, jnp.int32)
-    max_row = int(np.max(np.diff(ptr), initial=1))
-    iters = max(int(np.ceil(np.log2(max(max_row, 2)))) + 1, 1)
-    with stage_span(sink, "triangles_device", wedges=len(wu)) as stage:
-        tri, total = stage.sync(_count_device(
-            jnp.asarray(ptr, jnp.int32), jnp.asarray(col),
-            jnp.asarray(wv), jnp.asarray(ww), jnp.asarray(wu),
-            num_vertices=graph.num_vertices, search_iters=iters,
-        ))
-    return tri, total, jnp.asarray(deg, jnp.int32)
-
-
-def triangle_count(graph: Graph):
-    """Per-vertex triangle counts ``[V]`` and the global triangle total.
-
-    GraphFrames ``triangleCount`` semantics (simple undirected graph).
-    """
-    tri, total, _ = _triangles(graph)
-    return tri, total
-
-
-def oriented_wedge_count(graph: Graph, simple_edges=None) -> int:
-    """Exact count of oriented wedges the exact triangle pipeline would
-    materialize — WITHOUT materializing them (O(E log E) host work, O(E)
-    memory).
-
-    This is the feasibility probe for :func:`_oriented_csr`, whose wedge
-    expansion allocates ~28 bytes per wedge on the host: a mega-hub
-    power-law graph at 25M edges reaches ~10^10 oriented wedges (~300 GB)
-    — the round-5 e2e bench run was OOM-killed at 130 GB RSS exactly
-    here. Callers (the pipeline driver's LOF feature phase) compare this
-    against a budget and fall back to
-    :func:`sampled_clustering_coefficient`, whose cost is independent of
-    the wedge count. ``simple_edges``: optional precomputed
-    :func:`simple_undirected_edges` pair (see :func:`_oriented_csr`).
-    """
-    v = graph.num_vertices
-    a, b = simple_edges or simple_undirected_edges(graph)
-    if len(a) == 0:
-        return 0
-    deg = np.bincount(a, minlength=v) + np.bincount(b, minlength=v)
-    rank = deg.astype(np.int64) * v + np.arange(v)
-    lo = np.where(rank[a] <= rank[b], a, b)
-    counts = np.bincount(lo, minlength=v).astype(np.int64)
-    # each oriented edge (u, v) expands against u's whole oriented row
-    return int(counts[lo].sum())
-
-
-def clustering_coefficient(
-    graph: Graph, _cached=None, simple_edges=None, sink=None
-) -> jax.Array:
-    """Local clustering coefficient ``[V]`` (float32): triangles through a
-    vertex over its wedge count on the simplified graph.
-
-    ``_cached`` optionally takes a prior :func:`_triangles` result so a
-    caller needing both counts and coefficients pays the pipeline once;
-    ``simple_edges`` forwards a precomputed dedup (see
-    :func:`_oriented_csr`); ``sink`` the stage spans of
-    :func:`_triangles`.
-    """
-    tri, _, deg = (
-        _triangles(graph, simple_edges, sink) if _cached is None else _cached
-    )
-    deg = deg.astype(jnp.float32)
-    wedges = deg * (deg - 1.0) / 2.0
-    return jnp.where(wedges > 0, tri / jnp.maximum(wedges, 1.0), 0.0).astype(jnp.float32)
 
 
 def _splitmix64(x):

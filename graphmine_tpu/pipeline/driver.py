@@ -577,14 +577,17 @@ def _run_pipeline(config: PipelineConfig, m: MetricsSink) -> PipelineResult:
                 "devices so the sharded kNN/LOF path can run",
             )
             return result
-        # Wedge-budget guard (r5): the exact clustering pipeline
-        # materializes every oriented wedge on the host (~28 B each) —
-        # a mega-hub power-law graph at 25M edges has ~10^10 of them,
-        # and the first e2e bench run was OOM-killed at 130 GB RSS
-        # before this guard existed. The probe is O(E log E) host work;
-        # past the budget the clustering column comes from the sampled
-        # estimator (stderr <= 1/(2*sqrt(64)) per vertex), same as
-        # scale-out mode. Default 2.5e8 wedges ~ 7 GB host scratch.
+        # Wedge-budget guard (r5): the exact clustering pipeline used to
+        # list every oriented wedge on the host (~28 B each) — a mega-hub
+        # power-law graph at 25M edges has ~10^10 of them, and the first
+        # e2e bench run was OOM-killed at 130 GB RSS before this guard
+        # existed. Since PR 46 the exact kernel lists none (ops/triangles.py:
+        # a plan, bit rows, row pairs; 1.4e10 wedges in 9.04 s on a v5e),
+        # so the budget guards a host cost that is gone; it stays this PR so
+        # that the pipeline's answers do not move (ROADMAP Queue 3,
+        # Q3-wedge-budget). The probe is O(E log E) host work; past the
+        # budget the clustering column comes from the sampled estimator
+        # (stderr <= 1/(2*sqrt(64)) per vertex), same as scale-out mode.
         feature_mode = "device-8"
         simple_edges = None
         if not scale_out:
@@ -605,8 +608,7 @@ def _run_pipeline(config: PipelineConfig, m: MetricsSink) -> PipelineResult:
                     "warning",
                     message=f"exact clustering infeasible: {wedges:,} "
                     f"oriented wedges exceed GRAPHMINE_WEDGE_BUDGET="
-                    f"{wedge_budget:,} (~28 B/wedge host scratch); using "
-                    "the wedge-sampled estimator",
+                    f"{wedge_budget:,}; using the wedge-sampled estimator",
                 )
         with m.span("outliers_lof"), m.timed(
                      "outliers_lof", k=config.lof_k,
