@@ -10,6 +10,7 @@ through the public entry with a sink, inside a capture. The capture does
 NOT put op metadata into the compile-cache key (``maybe_profile`` does, and
 every program would compile anew): run it in a call whose cache this
 checkout alone has filled. Prints JSON lines; the last holds the scopes,
+the scopes of each program (``by_program_scope``: ``jit__tail_table_class:credit``),
 and the rows' passes by width class (``by_width``: ``row_mode/w33``,
 ``dirty_rows/w4096``)."""
 import json
@@ -52,7 +53,7 @@ def main():
            "seed": 1, "scratch": scratch,
            "chips": cell["chips"], "say": say, "load_module": run.load_module}
     state = driver.setup(ctx)
-    graph, iters = state["graph"], cell["traffic"]["iterations"]
+    graph, iters = state["graph"], cell["traffic"].get("iterations")  # an algorithm file may state none
     # a mesh cell's driver keeps its mesh: the job goes through the same entry
     on_mesh = {"mesh": state["mesh"]} if "mesh" in state else {}
     if "algorithm" in state:  # an algorithm-file driver (ISSUE 41): the job is the file's run()
@@ -84,11 +85,14 @@ def main():
     jax.profiler.stop_trace()
     planes = devtrace.read_xplane(devtrace.newest_xplane(trace_dir), "run")
     reduced = devtrace.reduce_capture(*planes, DEVICE_SCOPES)
-    by_scope, by_program = {}, {}
+    by_scope, by_program, by_program_scope = {}, {}, {}
     for row in reduced["scopes"]:
         by_scope[row["scope"]] = by_scope.get(row["scope"], 0.0) + row["device_seconds"]
         name = row["module"]
         by_program[name] = by_program.get(name, 0.0) + row["device_seconds"]
+        # one scope name in two programs (LCC's `credit` in both stages) read apart
+        both = f"{name}:{row['scope']}"
+        by_program_scope[both] = by_program_scope.get(both, 0.0) + row["device_seconds"]
     # the third scope level: the rows' passes by width class (`w<width>`)
     passes = frozenset(("row_gather", "row_mode", "row_sum", "dirty_rows"))
     by_width = {}
@@ -100,6 +104,7 @@ def main():
            "idle_s": job_s - reduced["busy_seconds"],
            "by_scope": dict(sorted(by_scope.items(), key=lambda kv: -kv[1])),
            "by_program": dict(sorted(by_program.items(), key=lambda kv: -kv[1])),
+           "by_program_scope": dict(sorted(by_program_scope.items(), key=lambda kv: -kv[1])),
            "by_width": dict(sorted(by_width.items(), key=lambda kv: -kv[1])),
            "memory": memory()}
     say(**out)

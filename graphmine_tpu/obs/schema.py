@@ -110,7 +110,13 @@ register("impl_selected", "op", "impl", "n", "reason")
 # once per graph, `cached: true` after) says `core_vertices`, `core_edges`,
 # `classes`, `wedges_core` and `wedges_tail` (neighbour pairs to close, in
 # the core's bit rows and outside them), `core_rows`, `tail_edges`,
-# `tail_compares` and `resident_bytes` (what the plan keeps on the device).
+# `tail_compares`, `tail_middles` (distinct middle vertices of the tail's
+# edges: a triangle's third corner is credited through its middle's row
+# once a middle a block, not once an edge), `tail_credit_slots` (slots a
+# job's tail programs scatter through those rows, padding in: `blocks x ns
+# x w` over the classes, `ns` the runs of one middle a block may hold; the
+# `lcc_tail` stage span says it again as `credit_slots`) and
+# `resident_bytes` (what the plan keeps on the device).
 # Benchmark metric `lcc_core_wedge_share` reads the two wedge counts.
 register("plan_build", "op", "family", "seconds", "padded_slots_per_edge")
 # superstep_timing (ISSUE 12): achieved-vs-model throughput for one

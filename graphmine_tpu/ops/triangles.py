@@ -35,9 +35,15 @@ quarter of an hour). Rows are fetched instead, and compared whole:
    row and ``v``'s own row are compared all against all; a match is a
    triangle, credited to its three corners. The rows of the vertices these
    edges join are kept padded to whole tiles of 128 in a table of their own
-   and fetched whole (:func:`_tail_table_class`); where that table would
-   pass 2 GiB the two rows are cut out of the CSR as windows instead
-   (:func:`_tail_class`: 1.3 us a window on a v5e, whatever its width).
+   and fetched whole (:func:`_tail_table_class`). The third corner is a
+   slot of ``v``'s row, and the edges that share a middle share that row:
+   the plan puts a middle's edges side by side (:func:`_runs_by_middle`),
+   a block sums their matches slot by slot and the row is credited once a
+   middle, not once an edge (a scatter of a tenth of the slots at
+   graph500-22, where a middle of width 96 lies under 17 edges). Where the
+   table would pass 2 GiB the two rows are cut out of the CSR as windows
+   instead (:func:`_tail_class`: 1.3 us a window on a v5e, whatever its
+   width; credited through ``u``'s window, once an edge).
 4. counts are two uint32 words a vertex (:func:`_add64`: a hub of degree
    163,352 may close 1.3e10 pairs), and the coefficient is one float32
    division of them.
@@ -143,6 +149,66 @@ def _blocked(arrays, size: int):
     return out, blocks
 
 
+def _order(keys: np.ndarray) -> np.ndarray:
+    """``np.argsort(keys, kind="stable")`` of keys from 0 to under 2**31, by
+    one in-place sort of the words ``key << 32 | position`` (a seventh of the
+    stable argsort's time at 2.5 M keys)."""
+    words = (keys.astype(np.int64) << 32) | np.arange(len(keys))
+    words.sort()
+    return words & 0xFFFFFFFF
+
+
+def _runs_by_middle(mid: np.ndarray, ne: int):
+    """A tail class's edges put in an order in which the edges of one middle
+    are neighbours (a *run*), and the runs dealt over the class's blocks of
+    ``ne`` edges so that every block holds a like number of them: the
+    middles sorted by their edges, most first, and dealt round the blocks as
+    cards are, so each block gets its share of long runs and of short ones.
+    A run that straddles a block's end counts in both blocks. One sort of
+    the edges (by middle) and two of the middles.
+
+    Returns ``(order, seg, ns, lead)``: the edges' order; for every edge
+    slot the number of its run within its block (ascending from 0); the runs
+    a block may hold (the fullest block's, rounded up to whole eights, ``ne``
+    at most); and where in that order each block's runs begin, ``[blocks,
+    ns]``, -1 where a block has fewer."""
+    order = _order(mid)
+    by_mid = mid[order]
+    starts = np.flatnonzero(np.r_[True, by_mid[1:] != by_mid[:-1]])
+    edges = np.diff(np.append(starts, len(mid)))
+    blocks = -(-len(mid) // ne)
+    dealt = _order(edges.max() - edges)
+    dealt = dealt[_order(np.arange(len(starts)) % blocks)]
+    edges = edges[dealt]
+    run = np.repeat(np.arange(len(starts)), edges)  # ascending along the class
+    opens = np.cumsum(edges) - edges
+    # a run's edges move together: by where the run lay, less where it lies now
+    order = order[np.arange(len(mid)) + np.repeat(starts[dealt] - opens, edges)]
+    first = run[::ne]  # the run each block opens with
+    seg = run - np.repeat(first, ne)[:len(run)]
+    held = np.append(run[ne - 1::ne], run[-1])[:blocks] - first + 1
+    ns = min(-(-int(held.max()) // 8) * 8, ne)
+    slot = np.arange(ns)[None, :]
+    at = np.minimum(first[:, None] + slot, len(starts) - 1)
+    return order, seg, ns, np.where(slot < held[:, None], opens[at], -1)
+
+
+def _tail_table_arrays(columns, ne: int):
+    """What :func:`_tail_table_class` walks for one width class, from its
+    edges' ``columns`` (the table rows of ``low`` and ``mid``, the length of
+    ``mid``'s row, ``low``, ``mid``), put in runs by :func:`_runs_by_middle`:
+    per edge slot the first four and the edge's run within its block; per
+    run slot (``ns`` a block) its middle's table row, the length of it and
+    the middle. Returns ``(arrays, blocks, ns)``."""
+    order, seg, ns, lead = _runs_by_middle(columns[-1], ne)
+    columns = [x[order] for x in columns]
+    edges, blocks = _blocked((*columns[:4], seg), ne)
+    held = (lead >= 0).ravel()
+    lead = np.where(held, lead.ravel(), 0)
+    runs, _ = _blocked([x[lead] * held for x in columns[1:3] + columns[4:]], ns)
+    return edges + runs, blocks, ns
+
+
 @partial(jax.jit, static_argnames=("k",))
 def _core_bits(row, column, k: int):
     """The core's symmetric adjacency as bit rows ``[k, k / 32]`` from its
@@ -206,7 +272,7 @@ def _build_plan(graph: Graph, simple_edges=None, core_vertices=None) -> _LccPlan
     rest = ptr[u + 1] - (at + 1)  # what is left of u's row after v
     keep = (rest > 0) & (above[mid] > 0)
     at, u, mid, rest = at[keep], u[keep], mid[keep], rest[keep]
-    compares = table_width = 0
+    compares = table_width = middles = credit_slots = 0
     in_tail = None
     if len(at):
         # the rows of the vertices these edges join, padded to whole tiles of
@@ -218,6 +284,7 @@ def _build_plan(graph: Graph, simple_edges=None, core_vertices=None) -> _LccPlan
             width = above[mid]  # a class is a width of the middle's row
             columns = (np.searchsorted(in_tail, u), np.searchsorted(in_tail, mid),
                        above[mid], u, mid)
+            middles = int(np.count_nonzero(np.bincount(mid, minlength=v)))
         else:
             in_tail, table_width = None, 0
             width = np.maximum(rest, above[mid])
@@ -226,9 +293,14 @@ def _build_plan(graph: Graph, simple_edges=None, core_vertices=None) -> _LccPlan
             left = table_width or w  # the low vertex's whole row, or a window of it
             ne = min(int(np.clip(_TAIL_BLOCK_COMPARES // (left * w), 8, 1 << 16)),
                      _pow2_at_least(len(rows)))
-            arrays, blocks = _blocked([x[rows] for x in columns], ne)
+            if in_tail is None:
+                arrays, blocks = _blocked([x[rows] for x in columns], ne)
+                ns = ne  # credited through the low vertex's window, once an edge
+            else:
+                arrays, blocks, ns = _tail_table_arrays([x[rows] for x in columns], ne)
             plan.tail_classes.append((w, ne, blocks, *arrays))
             longest = max(longest, w, table_width)
+            credit_slots += blocks * ns * w
             slots += blocks * ne * (w + left)
             compares += len(rows) * left * w
 
@@ -255,6 +327,7 @@ def _build_plan(graph: Graph, simple_edges=None, core_vertices=None) -> _LccPlan
         "wedges_core": wedges_core, "wedges_tail": wedges - wedges_core,
         "core_rows": int(in_core[centres].sum()), "tail_edges": len(at),
         "tail_compares": compares, "tail_table_rows": 0 if in_tail is None else len(in_tail),
+        "tail_middles": middles, "tail_credit_slots": credit_slots,
         "padded_slots_per_edge": slots / max(num_edges, 1),
         "resident_bytes": int(sum(x.nbytes for x in held)),
     }
@@ -365,29 +438,44 @@ def _tail_rows(col, starts, lens, width: int):
 
 
 @partial(jax.jit, static_argnames=("w", "ne"), donate_argnums=(0, 1))
-def _tail_table_class(lo, hi, table, blocks, row_low, row_mid, mid_len, low, mid,
-                      *, w: int, ne: int):
+def _tail_table_class(lo, hi, table, blocks, row_low, row_mid, mid_len, low, run,
+                      run_row, run_len, middle, *, w: int, ne: int):
     """One width class of stage ``lcc_tail`` on the table of padded rows:
     ``blocks`` blocks of ``ne`` oriented edges ``(low, mid)`` whose rows are
     ``table[row_low]`` and the first ``w`` of ``table[row_mid]``. Whatever
     of ``low``'s row is in ``mid``'s ranks above ``mid``, so the whole row
     stands for what is left of it after ``mid``; a match is credited through
-    ``mid``'s row, which starts at slot 0."""
+    ``mid``'s row, which starts at slot 0, once a middle: the edges of a
+    block lie grouped by their middle in at most ``ns`` runs (``run``: each
+    edge slot's run; per run its middle's table row ``run_row``, the length
+    of it and the ``middle``), their matches are summed along each run (a
+    0/1 product on the MXU, exact in int32; its left operand, the compare
+    ``[ns, ne]``, the chip's compiler folds into the product and never
+    writes out, which ``tests/test_chip_compile.py`` holds it to) and the
+    sum scattered through the middle's row, ``ns x w`` slots a block and
+    not ``ne x w``. A run of one edge is summed like any other: the product
+    costs what the slots it saves do even at 1.1 edges a run."""
     slots = jnp.arange(w, dtype=jnp.int32)[None, :]
+    ns = len(middle) // (len(low) // ne)
+    runs = jnp.arange(ns, dtype=jnp.int32)[:, None]
 
     def block(i, counts):
-        at_low, at_mid, length, u, v = _block((row_low, row_mid, mid_len, low, mid), i, ne)
+        at_low, at_mid, length, u, of = _block((row_low, row_mid, mid_len, low, run), i, ne)
         with jax.named_scope("row_compare"):
             left = table[at_low]  # [ne, 128 n], padded with -1
-            ranks = table[at_mid][:, :w]
-            valid = slots < length[:, None]
-            right = jnp.where(valid, ranks, -2)
+            right = jnp.where(slots < length[:, None], table[at_mid][:, :w], -2)
             closes = (left[:, :, None] == right[:, None, :]).sum(1, dtype=jnp.uint32)
             triangles = closes.sum(-1)
         with jax.named_scope("credit"):
+            at_run, length, v = _block((run_row, run_len, middle), i, ns)
+            closes = lax.dot_general(
+                (of[None, :] == runs).astype(jnp.int8), closes.astype(jnp.int8),
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32,
+            ).astype(jnp.uint32)
+            ranks = jnp.where(slots < length[:, None], table[at_run][:, :w], 0)
             part = jnp.zeros(lo.shape, jnp.uint32)
-            part = part.at[jnp.where(valid, ranks, 0)].add(closes)
-            part = part.at[u].add(triangles).at[v].add(triangles)
+            part = part.at[ranks].add(closes)
+            part = part.at[v].add(closes.sum(-1)).at[u].add(triangles)
             return _add64(*counts, part)
 
     with jax.named_scope("triangles"):
@@ -463,7 +551,8 @@ def _count(plan: _LccPlan, sink=None):
             stage.sync((lo, hi))
         with stage_span(sink, "lcc_tail", blocks=sum(c[2] for c in plan.tail_classes),
                         wedges=stats["wedges_tail"], edges=stats["tail_edges"],
-                        compares=stats["tail_compares"]) as stage:
+                        compares=stats["tail_compares"],
+                        credit_slots=stats["tail_credit_slots"]) as stage:
             for w, ne, blocks, *arrays in plan.tail_classes:
                 if plan.tail_table is not None:
                     lo, hi = _tail_table_class(lo, hi, plan.tail_table, blocks, *arrays,

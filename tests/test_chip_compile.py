@@ -326,6 +326,34 @@ def test_cc_superstep_bucketed_compiles_for_v5e(one_chip, fused_plan, planted):
     _compile(jax.jit(cc_superstep_bucketed), labels, _shapes(plan, one_chip))
 
 
+@pytest.mark.parametrize("v, w, ne, ns", [
+    (1 << 22, 96, 5461, 328),     # graph500-22's widest class: 468 of the 539 tail blocks
+    (1 << 18, 6, 65536, 31032),   # the pipeline cell's planted graph, width 6
+    (1 << 22, 2, 65536, 65536),   # the most a block may hold, every run one edge
+])
+def test_lcc_tail_class_compiles_for_v5e_and_writes_no_runs_by_edges_operand(one_chip, v, w, ne, ns):
+    """LCC's tail class program (ISSUE 47) sums a block's matches along its
+    ``ns`` runs by a 0/1 product whose left operand, ``[ns, ne]``, is a
+    compare the chip's compiler folds into the product: nothing of it is
+    written out. If it were, that is 1.9 GiB at the pipeline cell's width 6
+    and 4 GiB at the most a block may hold, in a cell that sits at 84 % of
+    the chip's memory: the program's temporaries stay under the two fetched
+    rows of every edge and the block's fresh count words."""
+    from graphmine_tpu.ops.triangles import _tail_table_class
+
+    def shape(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    blocks, table_width = 2, 128
+    compiled = _compile(
+        _tail_table_class, shape((v,), jnp.uint32), shape((v,), jnp.uint32),
+        shape((v // 4, table_width)), shape(()),
+        *[shape((blocks * ne,))] * 5, *[shape((blocks * ns,))] * 3, w=w, ne=ne,
+    )
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries <= 2 * ne * table_width * 4 + 4 * v * 4, temporaries
+
+
 def test_query_engine_gather_compiles_for_v5e(one_chip):
     """The served batched read: the engine's own jitted gather, at the
     smoke's table width and its largest batch bucket."""

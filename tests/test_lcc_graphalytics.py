@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 import graphmine_tpu as gm
+from graphmine_tpu.obs.spans import Tracer
 from graphmine_tpu.ops import triangles
 from graphmine_tpu.ops.ktruss import k_truss
 from graphmine_tpu.pipeline.metrics import MetricsSink
@@ -179,6 +180,119 @@ def test_the_tails_table_of_rows_and_its_windows_of_the_csr_count_the_same(tail,
     np.testing.assert_array_equal(_counts(plan), tri)
 
 
+def _one_middle_under_many_edges():
+    """A middle outside every core with 45 lower neighbours and a row of 80:
+    vertex 130 is joined to 80 of a clique of 130 hubs (all of higher degree,
+    so its row is those 80: width class 96) and to 45 vertices of degree 5,
+    each of which also sees a hub the middle sees (a triangle through the
+    middle's row), a hub it does not, and the next of its own kind. Hubs 33
+    to 64 of the clique are middles of that class too, under 30 to 110
+    edges each."""
+    hubs, middle = np.arange(130), 130
+    lows = middle + 1 + np.arange(45)
+    a, b = np.triu_indices(130, 1)
+    u = np.concatenate([hubs[a], np.full(80, middle), np.full(45, middle),
+                        lows, lows, lows[:-1]])
+    v = np.concatenate([hubs[b], hubs[:80], lows,
+                        hubs[np.arange(45) % 80], hubs[80 + np.arange(45) % 50], lows[1:]])
+    return u, v, int(lows[-1]) + 1, middle
+
+
+def _one_edge_a_middle():
+    """Forty middles of degree 6, each under one edge: five of eight hubs
+    that no edge joins (so no hub is a middle) and one vertex of degree 2
+    that sees the middle and one of the middle's hubs, a triangle each."""
+    hubs, middles, lows = np.arange(8), 8 + np.arange(40), 48 + np.arange(40)
+    to_hubs = (np.arange(40)[:, None] + np.arange(5)[None, :]) % 8
+    u = np.concatenate([np.repeat(middles, 5), lows, lows])
+    v = np.concatenate([hubs[to_hubs].ravel(), middles, hubs[to_hubs[:, 2]]])
+    return u, v, 88
+
+
+TAIL_GRAPHS = {
+    "one_edge_a_middle": _one_edge_a_middle,
+    "one_middle_under_many_edges": lambda: _one_middle_under_many_edges()[:3],
+    "rmat": lambda: _rmat(10, 41),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TAIL_GRAPHS))
+def test_the_tail_credits_a_middles_row_once_a_middle_and_counts_as_the_sets_do(name, monkeypatch):
+    """Every tail class sums an edge's matches along its middle's run before
+    the scatter, and counts the same triangles as the sets do: where every
+    middle lies under one edge (a run of one is summed like any other),
+    where one lies under 45, and on a Kronecker draw. Blocks of 32 edges at
+    width 96, so a run of 45 edges lies in two blocks at least."""
+    u, v, n = TAIL_GRAPHS[name]()
+    _, tri = _by_sets(u, v, n)
+    graph = gm.build_graph(u, v, num_vertices=n)
+    monkeypatch.setattr(triangles, "_TAIL_BLOCK_COMPARES", 32 * 256 * 96)
+    plan = triangles._build_plan(graph, core_vertices=0)
+    np.testing.assert_array_equal(_counts(plan), tri)
+    if name == "one_edge_a_middle":
+        (w, ne, blocks, *_, run_len, _), = plan.tail_classes
+        assert w == 6 and np.count_nonzero(np.asarray(run_len)) == plan.stats["tail_edges"] == 40
+        assert tri.sum() == 3 * 40
+    if name == "one_middle_under_many_edges":
+        w, ne, blocks, *_, middles = next(c for c in plan.tail_classes if c[0] == 96)
+        assert ne == 32 and blocks > 2
+        rank = int(np.asarray(plan.rank)[_one_middle_under_many_edges()[3]])
+        in_blocks = (np.asarray(middles).reshape(blocks, -1) == rank).any(axis=1)
+        assert in_blocks.sum() >= 2  # the run straddles a block's end: credited in both
+
+
+@pytest.mark.parametrize("ne", [64, 256, 1000])
+def test_runs_by_middle_groups_a_middles_edges_and_deals_the_runs_evenly(ne):
+    rng = np.random.default_rng(ne)
+    # 300 middles under 1 to 40 edges each, as the vertex ranks have them: in any order
+    mid = rng.permutation(np.repeat(rng.choice(10_000, 300, replace=False),
+                                    rng.integers(1, 41, 300)))
+    order, seg, ns, lead = triangles._runs_by_middle(mid, ne)
+    assert sorted(order.tolist()) == list(range(len(mid)))
+    blocks = -(-len(mid) // ne)
+    assert ns % 8 == 0 and lead.shape == (blocks, ns)
+    block = np.arange(len(mid)) // ne
+    middles = np.where(lead >= 0, mid[order][lead], -1)
+    # every edge slot's run is its own middle's, runs ascend from 0 within a block
+    np.testing.assert_array_equal(middles[block, seg], mid[order])
+    assert (seg[::ne] == 0).all() and (np.diff(seg)[block[1:] == block[:-1]] >= 0).all()
+    assert (np.diff(seg)[block[1:] == block[:-1]] <= 1).all()
+    # a middle's edges are neighbours
+    firsts = np.flatnonzero(np.r_[True, mid[order][1:] != mid[order][:-1]])
+    assert len(firsts) == 300
+    # the whole blocks hold like numbers of runs: none a quarter over the mean
+    held = (middles >= 0).sum(axis=1)
+    if blocks > 2:
+        assert held[:-1].max() <= 1.25 * held[:-1].mean() + 2, held
+
+
+def test_the_plan_counts_its_tail_middles_and_the_slots_its_credits_scatter():
+    u, v, n = _rmat(11, 13)
+    graph = gm.build_graph(u, v, num_vertices=n)
+    plan = triangles._build_plan(graph, core_vertices=64)
+    stats = plan.stats
+    slots, middles, fewer = 0, set(), False
+    for w, ne, blocks, _, _, mid_len, _, run, _, run_len, middle in plan.tail_classes:
+        ns = len(middle) // blocks
+        assert len(run) == blocks * ne and int(np.asarray(run).max()) < ns <= ne
+        slots += blocks * ns * w
+        edges = int(np.count_nonzero(np.asarray(mid_len)))
+        here = set(np.asarray(middle)[np.asarray(run_len) > 0].tolist())
+        assert not here & middles  # a middle's row has one width
+        middles |= here
+        if len(here) < edges // 2:  # middles repeat: fewer slots than the edges would take
+            fewer = True
+            assert blocks * ns * w < edges * w
+    assert fewer
+    assert stats["tail_credit_slots"] == slots < stats["tail_edges"] * max(
+        c[0] for c in plan.tail_classes)
+    assert stats["tail_middles"] == len(middles)
+    sink = MetricsSink(tracer=Tracer())
+    triangles._count(plan, sink)
+    span, = [r for r in sink.records if r["phase"] == "span" and r["name"] == "lcc_tail"]
+    assert span["credit_slots"] == slots and span["edges"] == stats["tail_edges"]
+
+
 def test_the_plan_is_built_once_per_graph_and_says_what_it_holds():
     u, v, n = _rmat(10, 19)
     graph = gm.build_graph(u, v, num_vertices=n)
@@ -190,7 +304,8 @@ def test_the_plan_is_built_once_per_graph_and_says_what_it_holds():
     assert built["op"] == "lcc" and not built["cached"] and found["cached"]
     assert found["seconds"] == 0.0
     for key in ("core_vertices", "core_edges", "classes", "wedges_core",
-                "wedges_tail", "resident_bytes", "padded_slots_per_edge"):
+                "wedges_tail", "resident_bytes", "padded_slots_per_edge",
+                "tail_middles", "tail_credit_slots"):
         assert key in built, key
     above = np.asarray(triangles.oriented_wedge_count(graph))
     # oriented wedges sum d+^2; the plan counts the pairs sum d+ (d+ - 1) / 2
