@@ -52,7 +52,7 @@ plan, _, _ = T._lcc_plan(g)
 lo, hi = jnp.zeros((n,), jnp.uint32), jnp.zeros((n,), jnp.uint32)
 for w, nb, blocks, *arrays in plan.core_classes:
     t0 = time.perf_counter()
-    lo, hi = T._core_class(lo, hi, plan.bits, plan.col, blocks, *arrays, w=w, nb=nb, core_start=plan.core_start)
+    lo, hi = T._core_class(lo, hi, plan.bits, blocks, *arrays, w=w, nb=nb, core_start=plan.core_start)
     lo.block_until_ready()
     say(core_w=w, nb=nb, blocks=blocks, seconds=time.perf_counter() - t0)
 for w, ne, blocks, *arrays in plan.tail_classes:

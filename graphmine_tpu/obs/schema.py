@@ -109,7 +109,10 @@ register("impl_selected", "op", "impl", "n", "reason")
 # The exact triangle counts' plan (`op: lcc`, ops/triangles.py:_lcc_plan,
 # once per graph, `cached: true` after) says `core_vertices`, `core_edges`,
 # `classes`, `wedges_core` and `wedges_tail` (neighbour pairs to close, in
-# the core's bit rows and outside them), `core_rows`, `tail_edges`,
+# the core's bit rows and outside them), `core_rows`, `core_slots` (slots
+# of the centres' neighbour ranks the plan lays out for the core classes,
+# padding in: `blocks x nb x w` over the classes, four bytes each on the
+# device; a job cuts no window of the CSR for them), `tail_edges`,
 # `tail_compares`, `tail_middles` (distinct middle vertices of the tail's
 # edges: a triangle's third corner is credited through its middle's row
 # once a middle a block, not once an edge), `tail_credit_slots` (slots a

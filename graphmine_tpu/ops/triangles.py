@@ -23,13 +23,19 @@ quarter of an hour). Rows are fetched instead, and compared whole:
    131,072 vertices at graph500-22, where it holds 98.6 % of the pairs).
 2. device, stage ``lcc_core``: the core's symmetric adjacency as bit rows
    (``[K, K/32]`` uint32, built on the device from the core's edges and kept
-   with the plan). For a centre ``u`` anywhere, ``b_u`` is the bit row of
-   its neighbours in the core; for each such neighbour ``v`` the row
-   ``A[v]`` is fetched whole and ``popcount(A[v] & b_u)`` is the number of
-   ``u``'s core neighbours adjacent to ``v``: every triangle of ``u`` with
-   its other two corners in the core, credited to ``v`` directly and to
-   ``u`` by half the row's sum. One row fetch an oriented edge into the
-   core, 16 KB each, no lookup.
+   with the plan). A centre's neighbours in the core are the end of its row
+   of the CSR; the plan lays them out once, a padded row of ranks a centre
+   in the order the class programs walk (:func:`_build_plan`), so a job
+   reads a block's slab with one slice and cuts no window of the CSR (a
+   gather of windows of width 6 or more compiles to a loop of one window a
+   trip, 1.16 us each on a v5e: 1.14 M trips, 1.32 s of a 6.6 s job at
+   graph500-22). For a centre ``u`` anywhere, ``b_u`` is the bit row of its
+   neighbours in the core; for each such neighbour ``v`` the row ``A[v]``
+   is fetched whole and ``popcount(A[v] & b_u)`` is the number of ``u``'s
+   core neighbours adjacent to ``v``: every triangle of ``u`` with its other
+   two corners in the core, credited to ``v`` directly and to ``u`` by half
+   the row's sum. One row fetch an oriented edge into the core, 16 KB each,
+   no lookup.
 3. device, stage ``lcc_tail``: the triangles whose middle corner ``v`` is
    outside the core. For each oriented edge ``(u, v)`` outside it, ``u``'s
    row and ``v``'s own row are compared all against all; a match is a
@@ -122,7 +128,9 @@ def _pow2_at_least(n: int) -> int:
 class _LccPlan:
     """What :func:`_lcc_plan` keeps per graph: the rank-ordered oriented CSR
     and the core's bit rows on the device, and per width class the rows
-    each compiled program walks (padded to whole blocks)."""
+    each compiled program walks (padded to whole blocks): a core class is
+    ``(w, nb, blocks, ranks, lens, centres)``, ``ranks`` the centres' core
+    neighbours, ``w`` slots a centre, flat ``[blocks * nb * w]``."""
 
     num_vertices: int
     core_start: int              # ranks from here up are the core
@@ -249,8 +257,12 @@ def _build_plan(graph: Graph, simple_edges=None, core_vertices=None) -> _LccPlan
     plan = _LccPlan(num_vertices=v, core_start=core_start,
                     rank=jnp.asarray(rank), degree=jnp.asarray(degree),
                     col=None, bits=None)
-    longest = 1
-    slots = 0
+    # a window never runs off the end: the widest one fits after the last edge
+    # (a class's is a ladder width, the tail table's whole tiles of 128)
+    widest = int(above.max(initial=0))
+    padded = np.concatenate(
+        [col, np.zeros(max(_ladder(widest)[-1], -(-widest // 128) * 128), np.int32)])
+    core_slots = slots = 0
 
     # core classes: centres with two core neighbours or more, longest first
     centres = np.flatnonzero(in_core >= 2)
@@ -260,11 +272,14 @@ def _build_plan(graph: Graph, simple_edges=None, core_vertices=None) -> _LccPlan
             rows = centres[rows]
             nb = min(int(np.clip(_CORE_BLOCK_ROWS // w, 512, 8192)),
                      _pow2_at_least(len(rows)))
-            arrays, blocks = _blocked(
-                (ptr[rows + 1] - in_core[rows], in_core[rows], rows), nb)
-            plan.core_classes.append((w, nb, blocks, *arrays))
-            longest = max(longest, w)
-            slots += blocks * nb * w
+            # a centre's neighbours in the core are the end of its row: a strided
+            # view of the CSR's windows, copied out once a plan and not once a job
+            ranks = np.lib.stride_tricks.sliding_window_view(padded, w)[
+                ptr[rows + 1] - in_core[rows]]
+            (ranks,), blocks = _blocked((ranks.ravel(),), nb * w)
+            arrays, _ = _blocked((in_core[rows], rows), nb)
+            plan.core_classes.append((w, nb, blocks, ranks, *arrays))
+            core_slots += blocks * nb * w
 
     # tail classes: oriented edges (u, v) with v outside the core
     at = np.flatnonzero(~to_core)
@@ -299,13 +314,11 @@ def _build_plan(graph: Graph, simple_edges=None, core_vertices=None) -> _LccPlan
             else:
                 arrays, blocks, ns = _tail_table_arrays([x[rows] for x in columns], ne)
             plan.tail_classes.append((w, ne, blocks, *arrays))
-            longest = max(longest, w, table_width)
             credit_slots += blocks * ns * w
             slots += blocks * ne * (w + left)
             compares += len(rows) * left * w
 
-    # a window never runs off the end: the longest one fits after the last edge
-    plan.col = jnp.asarray(np.concatenate([col, np.zeros(longest, np.int32)]))
+    plan.col = jnp.asarray(padded)
     if in_tail is not None:
         plan.tail_table = _tail_rows(
             plan.col, jnp.asarray(ptr[in_tail].astype(np.int32)),
@@ -328,7 +341,8 @@ def _build_plan(graph: Graph, simple_edges=None, core_vertices=None) -> _LccPlan
         "core_rows": int(in_core[centres].sum()), "tail_edges": len(at),
         "tail_compares": compares, "tail_table_rows": 0 if in_tail is None else len(in_tail),
         "tail_middles": middles, "tail_credit_slots": credit_slots,
-        "padded_slots_per_edge": slots / max(num_edges, 1),
+        "core_slots": core_slots,
+        "padded_slots_per_edge": (core_slots + slots) / max(num_edges, 1),
         "resident_bytes": int(sum(x.nbytes for x in held)),
     }
     return plan
@@ -380,19 +394,21 @@ def _block(arrays, i, size: int):
 
 
 @partial(jax.jit, static_argnames=("w", "nb", "core_start"), donate_argnums=(0, 1))
-def _core_class(lo, hi, bits, col, blocks, starts, lens, centres, *,
+def _core_class(lo, hi, bits, blocks, rows, lens, centres, *,
                 w: int, nb: int, core_start: int):
     """One width class of stage ``lcc_core`` (the module's note, step 2):
-    ``blocks`` blocks of ``nb`` centres whose core neighbours are the
-    ``lens`` entries of ``col`` from ``starts``, longest first."""
+    ``blocks`` blocks of ``nb`` centres, longest first, whose core
+    neighbours are the first ``lens`` ranks of their ``w`` slots of ``rows``
+    (laid out by the plan, flat; a block's slab is one slice; what the slots
+    past a centre's length hold is never read)."""
     words = bits.shape[1]
     word_ids = jnp.arange(words, dtype=jnp.int32)[None, :]
     slots = jnp.arange(w, dtype=jnp.int32)[None, :]
 
     def block(i, counts):
-        start, length, centre = _block((starts, lens, centres), i, nb)
+        length, centre = _block((lens, centres), i, nb)
         with jax.named_scope("core_bits"):
-            ranks = _windows(col, start, w)
+            ranks = lax.dynamic_slice_in_dim(rows, i * (nb * w), nb * w).reshape(nb, w)
             valid = slots < length[:, None]
             index = jnp.where(valid, ranks - core_start, 0)
             word = index >> 5
@@ -546,7 +562,7 @@ def _count(plan: _LccPlan, sink=None):
                         rows=stats["core_rows"],
                         bit_products=stats["core_rows"] * stats["core_vertices"]) as stage:
             for w, nb, blocks, *arrays in plan.core_classes:
-                lo, hi = _core_class(lo, hi, plan.bits, plan.col, blocks, *arrays,
+                lo, hi = _core_class(lo, hi, plan.bits, blocks, *arrays,
                                      w=w, nb=nb, core_start=plan.core_start)
             stage.sync((lo, hi))
         with stage_span(sink, "lcc_tail", blocks=sum(c[2] for c in plan.tail_classes),

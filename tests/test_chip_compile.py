@@ -72,6 +72,10 @@ def _shapes(tree, sharding):
     )
 
 
+def _shape_on(sharding):
+    return lambda dims, dtype=jnp.int32: jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+
 def _compile(fn, *args, **kwargs):
     compiled = fn.lower(*args, **kwargs).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 15 << 30
@@ -341,9 +345,7 @@ def test_lcc_tail_class_compiles_for_v5e_and_writes_no_runs_by_edges_operand(one
     rows of every edge and the block's fresh count words."""
     from graphmine_tpu.ops.triangles import _tail_table_class
 
-    def shape(dims, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
-
+    shape = _shape_on(one_chip)
     blocks, table_width = 2, 128
     compiled = _compile(
         _tail_table_class, shape((v,), jnp.uint32), shape((v,), jnp.uint32),
@@ -352,6 +354,35 @@ def test_lcc_tail_class_compiles_for_v5e_and_writes_no_runs_by_edges_operand(one
     )
     temporaries = compiled.memory_analysis().temp_size_in_bytes
     assert temporaries <= 2 * ne * table_width * 4 + 4 * v * 4, temporaries
+
+
+@pytest.mark.parametrize("w, nb, blocks", [
+    (6, 8192, 22),    # graph500-22's narrow classes: 1.15 M of its 1.74 M padded centres
+    (256, 512, 132),  # and one of its three widest by rows fetched
+])
+def test_lcc_core_class_compiles_for_v5e_with_no_loop_over_centres(one_chip, w, nb, blocks):
+    """LCC's core class program (ISSUE 48) reads a block's neighbour ranks as
+    one slice of the rows the plan laid out. Cut out of the CSR as ``nb``
+    windows, they were a gather the chip's compiler expands, from width 6
+    up, into a fourth ``while`` of ``nb`` trips (one ``s32[1,1]`` start and
+    one ``s32[1,w]`` window a trip, 1.16 us each: 1.32 s of a 6.6 s job at
+    graph500-22). The
+    program keeps the three loops of its source (blocks, ``mark``,
+    ``probe``), takes nothing of the CSR's size, and holds the ranks at four
+    bytes a slot: flat, not ``[n, w]`` padded to tiles of 128 lanes."""
+    from graphmine_tpu.ops.triangles import _core_class
+
+    shape = _shape_on(one_chip)
+    v, k = 1 << 22, 1 << 17
+    arguments = (
+        shape((v,), jnp.uint32), shape((v,), jnp.uint32), shape((k, k // 32), jnp.uint32),
+        shape(()), shape((blocks * nb * w,)), shape((blocks * nb,)), shape((blocks * nb,)),
+    )
+    compiled = _compile(_core_class, *arguments, w=w, nb=nb, core_start=v - k)
+    assert compiled.as_text().count(" while(") == 3
+    declared = sum(int(np.prod(a.shape)) * 4 for a in arguments)
+    held = compiled.memory_analysis().argument_size_in_bytes
+    assert declared <= held < declared + 4096, (held, declared)
 
 
 def test_query_engine_gather_compiles_for_v5e(one_chip):

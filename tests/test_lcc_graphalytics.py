@@ -137,8 +137,8 @@ def test_core_tail_and_the_wedges_that_straddle_them_add_up_to_the_whole():
     assert stats["core_vertices"] == 64 and plan.core_classes and plan.tail_classes
     assert stats["wedges_core"] > 0 and stats["wedges_tail"] > 0
     # centres outside the core with neighbours inside it: their rows straddle
-    outside = [c for c in plan.core_classes
-               if (np.asarray(c[5]) < plan.core_start).any()]
+    outside = [centres for *_, centres in plan.core_classes
+               if (np.asarray(centres) < plan.core_start).any()]
     assert outside
     np.testing.assert_array_equal(_counts(plan), tri)
     core_only = triangles._LccPlan(**{**plan.__dict__, "tail_classes": []})
@@ -146,6 +146,54 @@ def test_core_tail_and_the_wedges_that_straddle_them_add_up_to_the_whole():
     in_core, in_tail = _counts(core_only), _counts(tail_only)
     assert in_core.sum() > 0 and in_tail.sum() > 0
     np.testing.assert_array_equal(in_core + in_tail, tri)
+
+
+@pytest.mark.parametrize("core", [64, "all"])
+def test_the_plan_lays_out_every_centres_core_neighbours_as_its_csr_row_has_them(core):
+    """A core class reads a block's neighbour ranks as one slice of rows the
+    plan laid out (ISSUE 48), so those rows have to be what a window of the
+    CSR was: for every centre with two neighbours or more in the core, its
+    higher-ranked neighbours of rank ``core_start`` and up, ascending, in
+    the first ``length`` of its ``w`` slots; whole blocks, the padding rows
+    of length 0; and ``core_slots`` counts the slots, padding in."""
+    u, v, n = _rmat(11, 13)
+    graph = gm.build_graph(u, v, num_vertices=n)
+    plan = triangles._build_plan(graph, core_vertices=64 if core == 64 else n)
+    rank = np.asarray(plan.rank)
+    higher = [[] for _ in range(n)]  # by rank: the higher-ranked neighbours in the core
+    for a, b in set(zip(np.minimum(u, v).tolist(), np.maximum(u, v).tolist())):
+        if a != b:
+            low, high = sorted((int(rank[a]), int(rank[b])))
+            if high >= plan.core_start:
+                higher[low].append(high)
+    slots, seen = 0, []
+    for w, nb, blocks, rows, lens, centres in plan.core_classes:
+        assert rows.shape == (blocks * nb * w,) and rows.dtype == jnp.int32
+        assert lens.shape == centres.shape == (blocks * nb,)
+        rows, lens, centres = np.asarray(rows).reshape(-1, w), np.asarray(lens), np.asarray(centres)
+        held = int(np.count_nonzero(lens))
+        assert (lens[:held] >= 2).all() and not lens[held:].any() and (lens <= w).all()
+        assert (np.diff(lens) <= 0).all()  # a block's loops stop at its first row's length
+        for row, length, centre in zip(rows[:held], lens[:held], centres[:held]):
+            assert row[:length].tolist() == sorted(higher[centre]), (w, centre)
+        slots += blocks * nb * w
+        seen += centres[:held].tolist()
+    assert sorted(seen) == [c for c in range(n) if len(higher[c]) >= 2]
+    assert plan.stats["core_slots"] == slots > 0
+    assert plan.stats["core_rows"] == sum(len(higher[c]) for c in seen)
+
+
+def test_the_plan_holds_its_core_rows_at_four_bytes_a_slot_and_no_class_reads_the_csr():
+    u, v, n = _rmat(11, 13)
+    graph = gm.build_graph(u, v, num_vertices=n)
+    plan = triangles._build_plan(graph, core_vertices=64)
+    held = [plan.rank, plan.degree, plan.col, plan.bits, plan.tail_table]
+    held += [x for c in plan.core_classes + plan.tail_classes for x in c[3:]]
+    assert plan.stats["resident_bytes"] == sum(x.nbytes for x in held)
+    assert sum(c[3].nbytes for c in plan.core_classes) == 4 * plan.stats["core_slots"]
+    # the CSR stays for the tail's table alone: the core's programs count without it
+    core_only = triangles._LccPlan(**{**plan.__dict__, "tail_classes": [], "col": None})
+    assert _counts(core_only).sum() > 0
 
 
 @pytest.mark.parametrize("core", ["none", "all"])
@@ -305,7 +353,7 @@ def test_the_plan_is_built_once_per_graph_and_says_what_it_holds():
     assert found["seconds"] == 0.0
     for key in ("core_vertices", "core_edges", "classes", "wedges_core",
                 "wedges_tail", "resident_bytes", "padded_slots_per_edge",
-                "tail_middles", "tail_credit_slots"):
+                "tail_middles", "tail_credit_slots", "core_slots"):
         assert key in built, key
     above = np.asarray(triangles.oriented_wedge_count(graph))
     # oriented wedges sum d+^2; the plan counts the pairs sum d+ (d+ - 1) / 2
