@@ -9,6 +9,8 @@ program a process so that the peak RSS is that program's compile alone.
     python _proof/size_programs.py _proof/g500_22_shapes.json wcc            # WCC's while_loop
     python _proof/size_programs.py _proof/g500_24_shapes.json rewrite:0:marked  # the rewrite that says which rows it wrote to
     python _proof/size_programs.py _proof/g500_24_shapes.json dirty_modes:0     # the reduce over those rows (ISSUE 43)
+    python _proof/size_programs.py _proof/g500_24_shapes.json bfs_level         # the BFS job's row min (ISSUE 49);
+                                                # also bfs_gather, bfs_rewrite:<place>, bfs_start, bfs_full_level
 
 A shapes file of a MESH partition (``shards`` in it: _proof/mesh_shapes_and_k.py,
 ISSUE 39) compiles the mesh job's programs (``parallel/sharded.py``) for the
@@ -116,6 +118,22 @@ def one(said, name, text_out=None):
         total = sum(n for n, _ in said["classes"])
         dirty = jax.ShapeDtypeStruct((min(cap, total),), jnp.int32, sharding=chip)
         lowered = lpa._dirty_modes_program.lower(rows, labels, dirty, plan)
+    elif name.startswith("bfs_"):  # the BFS job's programs (ops/paths.py, ISSUE 49)
+        from graphmine_tpu.ops import paths
+
+        if name == "bfs_level":
+            lowered = paths._level_program.lower(rows, labels, plan)
+        elif name == "bfs_gather":
+            lowered = paths._gather_program.lower(rows, labels, plan)
+        elif name == "bfs_full_level":
+            lowered = paths._full_level_program.lower(labels, plan)
+        elif name == "bfs_start":
+            one_source = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=chip)
+            lowered = paths._start_program.lower(
+                one_source, plan.out_ptr, slots=s, num_vertices=v)
+        else:  # bfs_rewrite:<place>
+            cap = delta_rungs(said["num_messages"])[int(name.split(":")[1])]
+            lowered = paths._rewrite_program.lower(rows, labels, changed, plan, cap=cap)
     elif name in ("pagerank", "pagerank_step", "wcc"):
         import dataclasses
         import importlib

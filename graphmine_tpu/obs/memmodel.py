@@ -385,6 +385,10 @@ _REWRITE_SORT_WORDS = 8
 _REWRITE_CAP_WORDS = 5
 _MESH_CLASSES_AT_ONCE = 3  # carried_job_transients: the mesh `modes` program's classes overlap
 _DIRTY_V_WORDS = 10
+# the BFS level's V-vectors beside its largest class: the depths in two
+# memory spaces and out, the hubs' write back, and the count of K (the
+# changed mask, the out-degree's two slices and their difference, the select)
+_ROW_MIN_V_WORDS = 8
 _DIRTY_TRIP_ROWS = 8  # ops/bucketed_mode.py's _DIRTY_GROUP_ROWS: the rows a trip of the dirty reduce sorts
 _DIRTY_TRIP_FORMS = 8
 _DIRTY_PAIRWISE_SLOTS = 3 * 2048 * _PAIRWISE_MAX_W  # three forms of [2048 / 32, 32, 32]
@@ -402,11 +406,19 @@ def _tiled(n: int, w: int) -> tuple[int, int]:
     return min(col, row), row
 
 
-def carried_job_transients(plan, top_rung: int = 0, shards: int = 1) -> dict:
+def carried_job_transients(
+    plan, top_rung: int = 0, shards: int = 1, reduce: str = "mode"
+) -> dict:
     """Bytes of temporaries by program of the carried-rows job, from the
     plan's shapes (the note above): ``gather``, ``modes``, ``rewrite`` at
     ``top_rung`` messages (0 without a rung) and, where the one-chip job
-    has one, ``dirty_modes``. The hubs' histograms are
+    has one, ``dirty_modes``. ``reduce="min"`` is the BFS job
+    (``ops/paths.py``), whose reduce is ``row_min`` and which has no dirty
+    reduce: ``gather``, ``row_min``, ``rewrite``. A row's min reads the
+    class's rows in the row-major form the flat rows reshape to and writes
+    a vector; it is counted with the compiler's own form beside it, as the
+    gather is, and with the V-vectors of the level
+    (:data:`_ROW_MIN_V_WORDS`). The hubs' histograms are
     the inventory's own term. A weighted plan's reduce holds more (the
     weights ride through the sort) and no compile holds its count: at
     2^16 vertices the compiler kept one class's ``[n, w, w]`` pairwise
@@ -436,21 +448,28 @@ def carried_job_transients(plan, top_rung: int = 0, shards: int = 1) -> dict:
         if weighted:
             # labels, weights, scores and mask; the sort's key, weight and
             # iota, in and out, and the segmented sum's pair
-            reduce = (4 if w <= _PAIRWISE_MAX_W else 8) * kept
+            sorting = (4 if w <= _PAIRWISE_MAX_W else 8) * kept
         elif w <= _PAIRWISE_MAX_W:
-            reduce = 2 * kept
+            sorting = 2 * kept
         else:
-            reduce = (3 if sort_in_place else 4) * kept
-        modes.append(max(kept + row, reduce))
+            sorting = (3 if sort_in_place else 4) * kept
+        modes.append(max(kept + row, sorting))
     at_once = _MESH_CLASSES_AT_ONCE if shards > 1 else 1
     labels = 2 * _I32 * (v + 1)
+    rewrite = max(
+        _REWRITE_SORT_WORDS * _I32 * v,
+        _REWRITE_CAP_WORDS * _I32 * int(top_rung),
+    ) if top_rung else 0
+    if reduce == "min":
+        return {
+            "gather": max(gather, default=0) + labels,
+            "row_min": max(gather, default=0) + _ROW_MIN_V_WORDS * _I32 * (v + 1),
+            "rewrite": rewrite,
+        }
     by_program = {
         "gather": max(gather, default=0) + labels,
         "modes": sum(sorted(modes, reverse=True)[:at_once]) + labels,
-        "rewrite": max(
-            _REWRITE_SORT_WORDS * _I32 * v,
-            _REWRITE_CAP_WORDS * _I32 * int(top_rung),
-        ) if top_rung else 0,
+        "rewrite": rewrite,
     }
     if top_rung and shards == 1 and not weighted:
         # the one-chip job's second reduce, which runs after a marked
@@ -486,7 +505,9 @@ def row_sum_transients(plan) -> int:
     return carried_job_transients(plan)["gather"] + 5 * _I32 * int(plan.num_vertices)
 
 
-def carried_rows_inventory(plan, top_rung: int = 0, shards: int = 1) -> dict:
+def carried_rows_inventory(
+    plan, top_rung: int = 0, shards: int = 1, reduce: str = "mode"
+) -> dict:
     """What the carried-rows job of ``ops/lpa.py`` holds on the device
     beyond a fused ``plan``, known from the plan's shapes before the index
     is built. Exact: ``carried_rows``, the classes' rows end to end, ONCE
@@ -513,10 +534,16 @@ def carried_rows_inventory(plan, top_rung: int = 0, shards: int = 1) -> dict:
     shapes, of the mesh job: the terms are then ONE chip's (its rows, its
     index by global sender over the largest shard's messages, the padded
     label vector, replicated, twice), the programs the mesh job's
-    (:func:`carried_job_transients`)."""
+    (:func:`carried_job_transients`). ``reduce="min"`` is the BFS job's
+    (``ops/paths.py``): the same rows and index, depths for labels, its own
+    three programs and no histogram (its hubs take a ``segment_min`` over
+    their senders' depths)."""
     v = int(plan.num_vertices)
     classes = [_slots(x) for x in plan.send_idx or ()]
-    hubs = 0 if plan.hist_vertex_ids is None else int(plan.hist_vertex_ids.shape[0])
+    hubs = (
+        0 if reduce == "min" or plan.hist_vertex_ids is None
+        else int(plan.hist_vertex_ids.shape[0])
+    )
     return {
         "carried_rows": _I32 * sum(classes),
         "slot_index": _I32 * (int(plan.num_messages) + v + 1),
@@ -524,7 +551,7 @@ def carried_rows_inventory(plan, top_rung: int = 0, shards: int = 1) -> dict:
         "changed_mask": v,
         "hub_histograms": 2 * _I32 * hubs * v,
         "gather_transient": max(
-            carried_job_transients(plan, top_rung, shards).values()
+            carried_job_transients(plan, top_rung, shards, reduce).values()
         ),
     }
 

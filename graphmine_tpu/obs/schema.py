@@ -142,6 +142,10 @@ register("superstep_timing", "op", "family", "variant", "iteration",
 # carry; `changed` is cut to `supersteps` (to 64 entries on a longer run).
 # Every pass reads all M messages whatever moved: these counts size a
 # frontier. Benchmark metric `wcc_quiet_pass_share` reads it.
+# `bfs_distances(..., direction="both", sink=)` writes one too for every
+# job it steps from the host (`op: bfs_level`, PR 49: ops/paths.py): the
+# levels of the search, the last, which reaches nothing, among them, and
+# the vertices each reached, which ARE the frontier there.
 register("fixpoint", "op", "supersteps", "changed", "num_vertices", "family")
 
 # superstep_delta: one per `label_propagation(..., sink=)` call over a
@@ -181,6 +185,17 @@ register("fixpoint", "op", "supersteps", "changed", "num_vertices", "family")
 # gather, on the rungs above, in every superstep of the stateless scan, of
 # a weighted plan's job and of the mesh job) the two are the plan's rows
 # and S (a shard's, on a mesh).
+# The BFS job (`op: bfs_level`, PR 49: ops/paths.py, the same rows, index
+# and stepping loop with a row min for its reduce) writes the same record,
+# one a job, a level a superstep: `changed_vertices` the vertices the level
+# reached, `changed_messages` the messages they send, which picks the next
+# level's `branch`; the first level's is "fill" where it wrote the sources'
+# slots into rows that a fill, not a gather, had laid out (`source_messages`
+# is the K that picked its rung), the search stops at the first level that
+# reaches nothing, and every reduce is "full". Where the rows were not
+# admitted one compiled full-width level is stepped from the host: every
+# `branch` "full", no K, no rungs, and `seconds` all the same. Benchmark
+# metrics `bfs_sparse_level_share` and `bfs_full_level_ms` read it.
 register("superstep_delta", "op", "changed_vertices", "changed_messages",
          "branch", "rungs", "num_messages", "reduce", "dirty_rows",
          "dirty_slots")
@@ -416,7 +431,7 @@ RECOVERY_PHASES = frozenset((
 # ``tests/test_trace.py`` holds the package to this list both ways.
 DEVICE_SCOPES = frozenset((
     # outer: algorithm x family
-    "lpa_bucketed", "cc_bucketed", "pagerank_bucketed",
+    "lpa_bucketed", "cc_bucketed", "pagerank_bucketed", "bfs_level",
     "lpa_sort", "cc_sort", "pagerank_sort", "lpa_sharded", "masked_lpa", "superstep", "census",
     "modularity", "features", "triangles", "ivf", "knn_exact",
     "knn_cross", "lof",
@@ -426,6 +441,10 @@ DEVICE_SCOPES = frozenset((
     "hist", "write_back", "pointer_jump", "msg_gather", "segment_mode",
     "segment_min", "sort", "run_reduce", "mask", "exchange",
     "changed_count", "converged",
+    # under bfs_level (ops/bucketed_mode.py, PR 49): the hubs' segment_min
+    # over their senders' depths, and the rows' rewrite behind the vertices
+    # the last level reached (`delta` and its passes follow it)
+    "hubs", "rewrite",
     # carried rows (ops/bucketed_mode.rewrite_rows): outer, then its passes
     # (`mark`: the marked rewrite's list of the rows it wrote to, PR 43)
     "delta", "compact", "expand", "scatter", "mark",

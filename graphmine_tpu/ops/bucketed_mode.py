@@ -758,20 +758,25 @@ def with_slot_index(plan: BucketedModePlan) -> BucketedModePlan:
     )
 
 
+def _gather_classes(rows: jax.Array, values: jax.Array, plan: BucketedModePlan):
+    """:func:`gather_rows` under its caller's scope: every class's rows
+    gathered anew from ``values``, one class after the other, into the
+    flat buffer ``rows``."""
+    pad = _with_sentinel(values)
+    off = 0
+    for idx in plan.send_idx:
+        width = f"w{idx.shape[1]}"
+        with jax.named_scope("row_gather"), jax.named_scope(width):
+            rows = lax.dynamic_update_slice(rows, pad[idx].reshape(-1), (off,))
+        off += idx.shape[0] * idx.shape[1]
+    return rows
+
+
 def gather_rows(rows: jax.Array, labels: jax.Array, plan: BucketedModePlan):
     """Every class's rows gathered anew from ``labels`` into the flat
     buffer ``rows``: what :func:`_row_modes` gathers, kept."""
-    lbl_pad = _with_sentinel(labels)
-    off = 0
     with jax.named_scope("lpa_bucketed"):
-        for idx in plan.send_idx:
-            width = f"w{idx.shape[1]}"
-            with jax.named_scope("row_gather"), jax.named_scope(width):
-                rows = lax.dynamic_update_slice(
-                    rows, lbl_pad[idx].reshape(-1), (off,)
-                )
-            off += idx.shape[0] * idx.shape[1]
-    return rows
+        return _gather_classes(rows, labels, plan)
 
 
 def _rewrite_rows_and_slots(
@@ -1028,19 +1033,109 @@ def lpa_modes_from_rows(
     :func:`lpa_superstep_bucketed` would give from ``labels`` when ``rows``
     hold those labels' gathered rows."""
     wmats = plan.weight_mat or (None,) * len(plan.vertex_ids)
-    out, off, s = labels.astype(jnp.int32), 0, rows.shape[0]
+    out, off = labels.astype(jnp.int32), 0
     with jax.named_scope("lpa_bucketed"):
         for ids, idx, wmat in zip(plan.vertex_ids, plan.send_idx, wmats):
-            n, w = idx.shape[0] * idx.shape[1], idx.shape[1]
-            mat = rows[off:off + n]
-            if w > 1 and off % w == 0 and s % w == 0:
-                # Where the class starts at a multiple of its width in rows
-                # whose length is one too, the chip's compiler cuts the
-                # class out of a [S / w, w] view of ALL the rows, which it
-                # tiles to 128 lanes: 42 x the rows at w = 3 (92.6 GB for
-                # graph500-24's plan; PERF.md §6, PR 42). The barrier keeps
-                # the slice a slice; every other class's text is as it was.
-                mat = lax.optimization_barrier(mat)
-            out = _reduce_rows(out, ids, mat.reshape(idx.shape), wmat)
-            off += n
+            out = _reduce_rows(out, ids, _class_of_rows(rows, off, idx.shape), wmat)
+            off += idx.shape[0] * idx.shape[1]
         return _hist_modes(labels, out, plan)
+
+
+def _class_of_rows(rows: jax.Array, off: int, shape: tuple) -> jax.Array:
+    """The ``[n, w]`` rows of the class that starts at slot ``off`` of the
+    flat carried ``rows``."""
+    (n, w), s = shape, rows.shape[0]
+    mat = rows[off:off + n * w]
+    if w > 1 and off % w == 0 and s % w == 0:
+        # Where the class starts at a multiple of its width in rows whose
+        # length is one too, the chip's compiler cuts the class out of a
+        # [S / w, w] view of ALL the rows, which it tiles to 128 lanes:
+        # 42 x the rows at w = 3 (92.6 GB for graph500-24's plan; PERF.md
+        # §6, PR 42). The barrier keeps the slice a slice; every other
+        # class's text is as it was.
+        mat = lax.optimization_barrier(mat)
+    return mat.reshape(shape)
+
+
+# ---- BFS levels: the rows hold depths, and the reduce is a min (ISSUE 49) ----
+#
+# A depth is an int32 a slot as a label is, an unreached vertex's the
+# sentinel, which is also what a padding slot holds: a row's min is the
+# nearest neighbour's depth whatever the padding, and one more (saturating,
+# so that "unreached" stays unreached) is the vertex's own unless it already
+# has a smaller one. The rows are the carried-rows job's (:func:`gather_rows`
+# in full, :func:`rewrite_rows` behind the vertices a level reached); the
+# histogram hubs have no rows and take a ``segment_min`` over their senders'
+# depths, as ``ops/cc.py:cc_superstep_bucketed`` does.
+
+
+def _relax_class(out: jax.Array, ids: jax.Array, mat: jax.Array) -> jax.Array:
+    """``out`` with each of ``ids`` at most one past the least of its row."""
+    width = f"w{mat.shape[1]}"
+    with jax.named_scope("row_min"), jax.named_scope(width):
+        near = jnp.min(mat, axis=1)
+        reach = jnp.where(near == _SENTINEL, _SENTINEL, near + 1)
+    with jax.named_scope("write_back"):
+        return out.at[ids].min(reach, unique_indices=True, mode="drop")
+
+
+def _relax_hubs(depth: jax.Array, out: jax.Array, plan: BucketedModePlan):
+    """The histogram hubs' relaxation of ``depth``, written over ``out``."""
+    if plan.hist_vertex_ids is None:
+        return out
+    with jax.named_scope("hubs"):
+        near = jax.ops.segment_min(
+            depth[plan.hist_send],
+            plan.hist_row_offset // jnp.int32(plan.num_vertices),
+            num_segments=plan.hist_vertex_ids.shape[0], indices_are_sorted=True,
+        )
+        reach = jnp.where(near == _SENTINEL, _SENTINEL, near + 1)
+    with jax.named_scope("write_back"):
+        return out.at[plan.hist_vertex_ids].min(
+            reach, unique_indices=True, mode="drop"
+        )
+
+
+def gather_depth_rows(rows: jax.Array, depth: jax.Array, plan: BucketedModePlan):
+    """:func:`gather_rows` for the BFS job: the same gathers, named as its
+    level's."""
+    with jax.named_scope("bfs_level"):
+        return _gather_classes(rows, depth, plan)
+
+
+def rewrite_depth_rows(
+    rows: jax.Array, depth: jax.Array, reached: jax.Array,
+    plan: BucketedModePlan, cap: int,
+):
+    """:func:`rewrite_rows` for the BFS job: the slots behind the vertices
+    the last level ``reached`` take their depth."""
+    with jax.named_scope("bfs_level"), jax.named_scope("rewrite"):
+        return rewrite_rows(rows, depth, reached, plan, cap)
+
+
+def bfs_level_from_rows(
+    rows: jax.Array, depth: jax.Array, plan: BucketedModePlan
+) -> jax.Array:
+    """One BFS level over carried rows that hold every neighbour's
+    ``depth``: ``min(own, row min + 1)`` a vertex, the new depths."""
+    out, off = depth.astype(jnp.int32), 0
+    with jax.named_scope("bfs_level"):
+        for ids, idx in zip(plan.vertex_ids, plan.send_idx):
+            out = _relax_class(out, ids, _class_of_rows(rows, off, idx.shape))
+            off += idx.shape[0] * idx.shape[1]
+        return _relax_hubs(depth, out, plan)
+
+
+def bfs_level_bucketed(depth: jax.Array, plan: BucketedModePlan) -> jax.Array:
+    """One BFS level at full width and with nothing kept: every class's
+    rows gathered from ``depth`` and reduced as :func:`bfs_level_from_rows`
+    reduces them, the same depths bit for bit."""
+    pad = _with_sentinel(depth)
+    out = depth.astype(jnp.int32)
+    with jax.named_scope("bfs_level"):
+        for ids, idx in zip(plan.vertex_ids, plan.send_idx):
+            width = f"w{idx.shape[1]}"
+            with jax.named_scope("row_gather"), jax.named_scope(width):
+                mat = pad[idx]
+            out = _relax_class(out, ids, mat)
+        return _relax_hubs(depth, out, plan)

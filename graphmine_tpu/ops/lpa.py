@@ -343,14 +343,17 @@ def _cached_auto_plan(graph: Graph):
 _slot_index_cache: dict = {}
 
 
-def _cached_slot_index(plan):
+def _cached_slot_index(plan, reduce: str = "mode"):
     """``(plan, build seconds, (scan, reason))``: the fused ``plan`` as the
     job will run it. Once per plan (as the plan is paid once per graph)
     :func:`~graphmine_tpu.ops.superstep_policy.admit_carried_rows` says
     whether the carried rows and their index fit the device beside what
     it holds; only then is the index of the carried-rows job built
     (:func:`~graphmine_tpu.ops.bucketed_mode.with_slot_index`), and the
-    plan comes back with it. Under ``plain`` the plan comes back as it
+    plan comes back with it. The question is a job's own (``reduce``:
+    ``"mode"`` for LPA, ``"min"`` for the BFS job of ``ops/paths.py``,
+    whose programs hold other temporaries), asked once per plan and job;
+    the index is one, built for the first job admitted and shared. Under ``plain`` the plan comes back as it
     is, and runs the stateless bucketed scan; under a caller's trace too,
     with no question asked and nothing kept. The answer and the index
     are kept (0.0 seconds on a hit), keyed by the identity of the plan's
@@ -378,20 +381,29 @@ def _cached_slot_index(plan):
     hit = _slot_index_cache.get(key)
     seconds = 0.0
     if hit is None or hit[0]() is not anchor:
-        scan = admit_carried_rows(plan, device_memory_stats(plan))
-        out_ptr = out_slot = None
-        if scan[0] == "carried":
-            indexed, seconds = timed_plan_build(lambda: with_slot_index(plan))
-            out_ptr, out_slot = indexed.out_ptr, indexed.out_slot
         # the index alone: a cached plan would keep its own anchor alive
         hit = (
             weakref.ref(anchor, lambda _, k=key: _slot_index_cache.pop(k, None)),
-            out_ptr, out_slot, scan,
+            {"index": None, "scan": {}},
         )
         _slot_index_cache[key] = hit
-    if hit[2] is not None:
-        plan = dataclasses.replace(plan, out_ptr=hit[1], out_slot=hit[2])
-    return plan, seconds, hit[3]
+    kept = hit[1]
+    if reduce not in kept["scan"]:
+        stats = device_memory_stats(plan)
+        if stats and kept["index"] is not None:
+            # another job's index is in use already: it is not asked for twice
+            held = sum(int(x.nbytes) for x in kept["index"])
+            stats = dict(stats, bytes_in_use=int(stats.get("bytes_in_use", 0)) - held)
+        kept["scan"][reduce] = admit_carried_rows(plan, stats, reduce=reduce)
+    scan = kept["scan"][reduce]
+    if scan[0] == "carried":
+        if kept["index"] is None:
+            indexed, seconds = timed_plan_build(lambda: with_slot_index(plan))
+            kept["index"] = (indexed.out_ptr, indexed.out_slot)
+        if kept["index"][1] is not None:
+            out_ptr, out_slot = kept["index"]
+            plan = dataclasses.replace(plan, out_ptr=out_ptr, out_slot=out_slot)
+    return plan, seconds, scan
 
 
 _mesh_partition_cache: dict = {}

@@ -5,7 +5,10 @@ object built at ``Graphframes.py:78`` exposes both; the reference script
 never calls them). TPU design: distances are dense int32 vectors; one
 superstep relaxes every edge with a gather + ``segment_min`` — Bellman-Ford
 over unit weights, which for BFS converges in diameter supersteps inside a
-single ``lax.while_loop``.
+single ``lax.while_loop``. That loop is full width in every pass. On an
+undirected graph past the policy's crossover :func:`bfs_distances` follows
+the frontier instead (ISSUE 49): the carried-rows job of ``ops/lpa.py``
+with a min for its reduce, stepped from the host to the last level.
 
 Direction conventions:
 - ``direction="out"``: follow edge direction (src -> dst), GraphFrames'
@@ -16,6 +19,7 @@ Direction conventions:
 
 from __future__ import annotations
 
+import time
 from functools import partial
 
 import jax
@@ -42,15 +46,267 @@ def _edges(graph: Graph, direction: str):
     raise ValueError(f"direction must be 'out' or 'both', got {direction!r}")
 
 
-@partial(jax.jit, static_argnames=("direction", "max_depth"))
 def bfs_distances(
-    graph: Graph, sources: jax.Array, direction: str = "out", max_depth: int = 0
-) -> jax.Array:
+    graph: Graph, sources: jax.Array, direction: str = "out", max_depth: int = 0,
+    plan="auto", sink=None, return_levels: bool = False,
+):
     """Hop distance from the nearest of ``sources`` to every vertex.
 
     Returns int32 ``[V]``; unreachable vertices get ``UNREACHABLE``
-    (int32 max). ``sources`` is an int array of vertex ids.
+    (int32 max). ``sources`` is an int array of vertex ids. The search
+    runs until a level reaches nothing, or ``max_depth`` levels where that
+    is not 0. ``return_levels`` additionally returns the supersteps it
+    took, the last, which reaches nothing, among them.
+
+    ``plan``: which path runs; all give the same depths bit for bit.
+    ``"auto"`` on a symmetric graph with ``direction="both"`` resolves the
+    family through :func:`~graphmine_tpu.ops.superstep_policy.
+    select_superstep_family`, as ``connected_components`` does (the same
+    plan, cached per graph). On ``bucketed`` the search follows its
+    frontier over the carried rows (:func:`_frontier_job`): the plan's rows
+    hold every neighbour's depth, a level is ``min(own, row min + 1)``,
+    and only the vertices a level reached write their depth into their
+    neighbours' rows, through the slot index, at the rung the messages
+    they send fit under (:func:`~graphmine_tpu.ops.superstep_policy.
+    delta_rungs`; a level that sends more than the top rung gathers every
+    row anew). The host reads one pair of counts a level and stops at the
+    first that reached nothing. The rows and the index go on the device
+    only where :func:`~graphmine_tpu.ops.superstep_policy.
+    admit_carried_rows` finds room for this job's programs; otherwise one
+    compiled full-width level (gather every class, row min, write back) is
+    stepped from the host (:func:`_full_width_job`). On ``sort``, for
+    ``direction="out"``, on a plan that is not fused, with ``plan=None``
+    and under a caller's trace the ``lax.while_loop`` over the message
+    arrays runs (small graphs, ``bfs_parents``, ``shortest_paths``). A
+    fused :class:`~graphmine_tpu.ops.bucketed_mode.BucketedModePlan` may
+    be passed in ``"auto"``'s place.
+
+    ``sink``: optional MetricsSink. An auto resolution emits
+    ``impl_selected`` (with ``scan`` and ``scan_reason``), ``plan_build``
+    and ``device_residency`` as ``label_propagation`` does; a job over the
+    plan's rows one ``superstep_delta`` record (``op: bfs_level``; a level:
+    the branch taken, the vertices reached, the messages they send, its
+    seconds at the host's one wait) and every host-stepped job one
+    ``fixpoint`` record (the supersteps and the vertices each reached).
     """
+    from graphmine_tpu.ops.lpa import _under_a_trace
+
+    sources = jnp.atleast_1d(jnp.asarray(sources, jnp.int32))
+    traced = _under_a_trace() or isinstance(graph.msg_ptr, jax.core.Tracer)
+    scan = None
+    if direction != "both" or not graph.symmetric or traced:
+        plan = None
+    if isinstance(plan, str):
+        if plan != "auto":
+            raise ValueError(f"plan must be 'auto', None or a fused plan; got {plan!r}")
+        plan, scan = _auto_plan(graph, sink)
+    elif plan is not None and plan.send_idx:
+        from graphmine_tpu.ops.lpa import _cached_slot_index
+
+        plan, _, scan = _cached_slot_index(plan, reduce="min")
+    if plan is None or not plan.send_idx:
+        dist, levels = _bfs_loop(graph, sources, direction, max_depth)
+    else:
+        limit = max_depth if max_depth > 0 else graph.num_vertices + 1
+        clock = time.perf_counter if sink is not None else None
+        if plan.out_slot is not None:
+            dist, per_step = _frontier_job(graph, sources, limit, plan, clock)
+        else:
+            dist, per_step = _full_width_job(graph, sources, limit, plan, clock)
+        levels = len(per_step["changed_vertices"])
+        _emit_job_records(sink, graph, plan, per_step)
+    return (dist, levels) if return_levels else dist
+
+
+def _auto_plan(graph: Graph, sink):
+    """``(plan, scan)`` of an auto resolution, with its records: the graph's
+    cached bucketed plan with its slot index where the BFS job's rows are
+    admitted, or ``(None, None)`` on the ``sort`` family."""
+    from graphmine_tpu.ops.lpa import _cached_auto_plan, _cached_slot_index
+    from graphmine_tpu.ops.superstep_policy import (
+        emit_device_residency,
+        emit_plan_records,
+        select_superstep_family,
+    )
+
+    family, reason = select_superstep_family(graph.num_vertices, graph.num_messages)
+    plan, seconds, cached, scan = None, 0.0, False, None
+    if family == "bucketed":
+        plan, seconds, cached = _cached_auto_plan(graph)
+        plan, index_seconds, scan = _cached_slot_index(plan, reduce="min")
+        seconds += index_seconds
+    emit_plan_records(
+        sink, "bfs_level", plan, reason, seconds, cached, graph.num_edges,
+        graph.num_messages, num_vertices=graph.num_vertices, scan=scan,
+    )
+    if plan is not None:
+        emit_device_residency(sink, "bfs_level", graph, plan, scan)
+    return plan, scan
+
+
+def _emit_job_records(sink, graph: Graph, plan, per_step: dict) -> None:
+    """The ``superstep_delta`` and ``fixpoint`` records of one host-stepped
+    job (no-op without a sink): a level's ``branch`` is ``"fill"`` for a
+    first level that found the rows as the fill left them and wrote the
+    sources' slots alone, a rung, or ``"full"``; the full-width job has no
+    rows, no K and no rung, and every level of it is ``"full"``."""
+    if sink is None:
+        return
+    from graphmine_tpu.ops.lpa import _plan_rows_and_slots
+    from graphmine_tpu.ops.superstep_policy import delta_rungs
+
+    reached = per_step["changed_vertices"]
+    rows, slots = _plan_rows_and_slots(plan.send_idx)
+    if "branch" in per_step:
+        rungs = list(delta_rungs(plan.num_messages))
+        branch = [[*rungs, "full"][b] for b in per_step["branch"]]
+        if branch and branch[0] != "full":
+            branch[0] = "fill"
+        sent = per_step["changed_messages"]
+    else:
+        rungs, branch, sent = [], ["full"] * len(reached), []
+    sink.emit(
+        "superstep_delta", op="bfs_level", changed_vertices=reached,
+        changed_messages=sent, branch=branch, rungs=rungs,
+        num_messages=plan.num_messages, reduce=["full"] * len(reached),
+        dirty_rows=[rows] * len(reached), dirty_slots=[slots] * len(reached),
+        seconds=[round(s, 6) for s in per_step.get("seconds", ())],
+        **({"source_messages": per_step["source_messages"]}
+           if "source_messages" in per_step else {}),
+    )
+    sink.emit(
+        "fixpoint", op="bfs_level", supersteps=len(reached), changed=reached,
+        num_vertices=graph.num_vertices, family="bucketed",
+    )
+
+
+# The BFS job's programs. The rows are the donated argument of the three
+# that write them, as in ``ops/lpa.py``: filled, gathered and rewritten in
+# place (held by tests/test_chip_compile.py). The plan is an argument of
+# each: closed over, its arrays would be constants of the program.
+
+
+@partial(jax.jit, static_argnames=("slots", "num_vertices"))
+def _start_program(sources, out_ptr, slots: int, num_vertices: int):
+    """``(rows, depth, reached, K)`` before the first level: the rows as a
+    gather of all-unreached depths would leave them (a fill, and no gather
+    of S slots), the sources at depth 0, and the messages they send."""
+    rows = jnp.full((slots,), UNREACHABLE, jnp.int32)
+    depth = _unreached_but(sources, num_vertices)
+    reached = depth == 0
+    return rows, depth, reached, _k_and_count(reached, out_ptr)[0]
+
+
+def _unreached_but(sources, num_vertices: int):
+    """The depths before the first level: 0 at the sources."""
+    return jnp.full((num_vertices,), UNREACHABLE, jnp.int32).at[sources].set(0)
+
+
+def _k_and_count(reached, out_ptr):
+    """``(K, count)``: K the messages the ``reached`` vertices send, which
+    picks the next level's update, and their count."""
+    with jax.named_scope("bfs_level"), jax.named_scope("changed_count"):
+        out_deg = out_ptr[1:] - out_ptr[:-1]
+        k = jnp.sum(jnp.where(reached, out_deg, 0), dtype=jnp.int32)
+        return k, jnp.sum(reached, dtype=jnp.int32)
+
+
+@partial(jax.jit, donate_argnums=0)
+def _gather_program(rows, depth, plan):
+    from graphmine_tpu.ops.bucketed_mode import gather_depth_rows
+
+    return gather_depth_rows(rows, depth, plan)
+
+
+@partial(jax.jit, static_argnames=("cap",), donate_argnums=0)
+def _rewrite_program(rows, depth, reached, plan, cap: int):
+    from graphmine_tpu.ops.bucketed_mode import rewrite_depth_rows
+
+    return rewrite_depth_rows(rows, depth, reached, plan, cap)
+
+
+@jax.jit
+def _level_program(rows, depth, plan):
+    """``(new depths, reached, K, count)`` of one level over ``rows``."""
+    from graphmine_tpu.ops.bucketed_mode import bfs_level_from_rows
+
+    new = bfs_level_from_rows(rows, depth, plan)
+    reached = new != depth
+    return (new, reached, *_k_and_count(reached, plan.out_ptr))
+
+
+@jax.jit
+def _full_level_program(depth, plan):
+    """``(new depths, count)`` of one level at full width, nothing kept."""
+    from graphmine_tpu.ops.bucketed_mode import bfs_level_bucketed
+
+    new = bfs_level_bucketed(depth, plan)
+    with jax.named_scope("bfs_level"), jax.named_scope("changed_count"):
+        return new, jnp.sum(new != depth, dtype=jnp.int32)
+
+
+def _frontier_job(graph: Graph, sources, limit: int, plan, clock=None):
+    """``(depths, per_step)`` of a search that follows its frontier over a
+    fused plan with its slot index, stepped from the host by the one loop
+    of the carried-rows jobs (:func:`~graphmine_tpu.ops.superstep_policy.
+    step_carried_rows`): the rows start as a fill, the first level rewrites
+    the sources' slots, every later level brings the rows up to date by
+    what its predecessor reached (a rung's rewrite, or a full gather above
+    the top rung) and :func:`_level_program` takes the row min. It stops at
+    the first level that reaches nothing, or after ``limit``."""
+    from graphmine_tpu.ops.bucketed_mode import check_plan_fits, row_slots
+    from graphmine_tpu.ops.superstep_policy import delta_rungs, step_carried_rows
+
+    rows, depth, reached, k = _start_program(
+        sources, plan.out_ptr, slots=row_slots(plan), num_vertices=plan.num_vertices
+    )
+    check_plan_fits(depth, graph, plan)
+    k = int(k)  # a fetch a job: the sources' out-degree picks the first rung
+    depth, per_step = step_carried_rows(
+        limit, delta_rungs(plan.num_messages), k, rows, depth,
+        gather=lambda rows, depth: _gather_program(rows, depth, plan),
+        rewrite=lambda rows, depth, reached, cap: _rewrite_program(
+            rows, depth, reached, plan, cap=cap
+        ),
+        modes=lambda rows, depth: _level_program(rows, depth, plan),
+        clock=clock, changed=reached, until_quiet=True,
+    )
+    return depth, dict(per_step, source_messages=k)
+
+
+def _full_width_job(graph: Graph, sources, limit: int, plan, clock=None):
+    """``(depths, per_step)`` where the rows were not admitted: one compiled
+    full-width level stepped from the host until it reaches nothing (never
+    a ``while_loop`` over the plan's classes, whose temporaries the chip's
+    compiler holds all at once; PERF.md §7.5). The loop is still the one
+    stepping loop, with no rung to take and no rows to bring up to date;
+    ``per_step`` holds ``changed_vertices`` and, with a ``clock``,
+    ``seconds``."""
+    from graphmine_tpu.ops.bucketed_mode import check_plan_fits
+    from graphmine_tpu.ops.superstep_policy import step_carried_rows
+
+    depth = _unreached_but(sources, plan.num_vertices)
+    check_plan_fits(depth, graph, plan)
+
+    def level(rows, depth):
+        new, count = _full_level_program(depth, plan)
+        return new, None, 0, count  # no K: there is no update to pick
+
+    depth, per_step = step_carried_rows(
+        limit, (), 0, None, depth, gather=lambda rows, depth: rows, rewrite=None,
+        modes=level, clock=clock, until_quiet=True,
+    )
+    return depth, {
+        k: per_step[k] for k in ("changed_vertices", "seconds") if k in per_step
+    }
+
+
+@partial(jax.jit, static_argnames=("direction", "max_depth"))
+def _bfs_loop(
+    graph: Graph, sources: jax.Array, direction: str = "out", max_depth: int = 0
+):
+    """``(depths, supersteps)``: every level relaxes every edge, inside one
+    ``lax.while_loop``."""
     v = graph.num_vertices
     send, recv = _edges(graph, direction)
     limit = max_depth if max_depth > 0 else v + 1
@@ -69,8 +325,8 @@ def bfs_distances(
         _, changed, it = state
         return (changed > 0) & (it < limit)
 
-    dist, _, _ = lax.while_loop(cond, step, (dist0, jnp.int32(1), jnp.int32(0)))
-    return dist
+    dist, _, levels = lax.while_loop(cond, step, (dist0, jnp.int32(1), jnp.int32(0)))
+    return dist, levels
 
 
 def shortest_paths(graph: Graph, landmarks, direction: str = "out",

@@ -101,16 +101,26 @@ def delta_rungs(num_messages: int) -> tuple:
 def step_carried_rows(
     max_iter: int, rungs: tuple, over: int, rows, labels,
     gather, rewrite, modes, dirty_modes=None, clock=None,
+    changed=None, until_quiet: bool = False,
 ):
     """``(labels, per_step)`` of ``max_iter`` supersteps of a carried-rows
-    job, stepped from the host: the loop of ``ops/lpa.py:_carried_rows_job``
-    and of its mesh form (``parallel/sharded.py:carried_label_propagation``),
-    which hand it their programs. Each superstep first brings ``rows`` up
+    job, stepped from the host: the loop of ``ops/lpa.py:_carried_rows_job``,
+    of its mesh form (``parallel/sharded.py:carried_label_propagation``)
+    and of the BFS job (``ops/paths.py:_frontier_job``), which hand it their
+    programs. Each superstep first brings ``rows`` up
     to the labels it starts from by the update its predecessor's K picks
-    (K above every rung: ``gather(rows, labels)``, the first superstep
-    always, ``over`` being a K above them all; K <= a rung:
+    (K above every rung: ``gather(rows, labels)``; K <= a rung:
     ``rewrite(rows, labels, changed, rung)``), then ``modes(rows, labels)``
-    gives ``(new labels, changed, K, count)``. With ``dirty_modes`` (the
+    gives ``(new labels, changed, K, count)``. The start: ``over`` is the
+    K that picks the first superstep's update and ``changed`` the vertices
+    it is counted over. CDLP's rows start blank, so its ``over`` is a K
+    above every rung and its first superstep a full gather, with no
+    ``changed`` to read; the BFS job's rows start as the fill every vertex
+    but the sources would gather, so its ``changed`` are the sources,
+    ``over`` the messages they send, and its first superstep a rewrite.
+    The stop: ``max_iter`` supersteps, or with ``until_quiet`` the first
+    that moves nothing (its count is the one the host fetches anyway),
+    whichever comes first. With ``dirty_modes`` (the
     one-chip job's), a rewrite on a rung at or under
     :data:`DIRTY_REDUCE_TOP_PLACE` is asked for the rows it wrote to
     (``rewrite(..., marked=True)`` gives ``(rows, dirty)``) and
@@ -129,7 +139,7 @@ def step_carried_rows(
     a superstep's seconds on the host's clock, at the wait the job has."""
     import jax
 
-    changed, k = None, over
+    k = over
     count, sent, branch, dirty = [], [], [], []
     marks = [clock()] if clock else []
     for _ in range(max_iter):
@@ -151,6 +161,8 @@ def step_carried_rows(
         dirty.append(tuple(of_dirty) or None)
         if clock:
             marks.append(clock())
+        if until_quiet and moved == 0:
+            break
     per_step = {
         "changed_vertices": count, "changed_messages": sent, "branch": branch,
         "reduce": ["full" if d is None else "dirty" for d in dirty],
@@ -187,7 +199,7 @@ def mesh_memory_stats(mesh) -> dict | None:
 
 
 def admit_carried_rows(
-    plan, stats: dict | None, shards: int = 1
+    plan, stats: dict | None, shards: int = 1, reduce: str = "mode"
 ) -> tuple[str, str]:
     """``("carried" | "plain", reason)`` for the LPA job over the fused
     ``plan``: do the carried rows and the slot index go on the device
@@ -207,6 +219,10 @@ def admit_carried_rows(
     the graph and the plan being in use already. ``plain`` is the
     stateless bucketed scan, the same labels bit for bit at a full gather
     every superstep. A device that reports no limit admits ``carried``.
+    ``reduce="min"`` asks for the BFS job over the same rows and index
+    (``ops/paths.py``): its own programs (the gather, the row min, the top
+    rung's rewrite) and no histogram; ``plain`` is then one compiled
+    full-width level stepped from the host.
 
     The DEVICE's memory alone is sized. The host's is not: each program of
     the job compiles alone, and for graph500-24's plan (607.6 M slots, 58
@@ -215,8 +231,12 @@ def admit_carried_rows(
     whose compile does not fit the host still ends there, minutes later.
     Every ``reason`` says so."""
     top_rung = max(delta_rungs(int(plan.num_messages)), default=0)
-    need = carried_rows_inventory(plan, top_rung=top_rung, shards=shards)
-    by_program = carried_job_transients(plan, top_rung=top_rung, shards=shards)
+    need = carried_rows_inventory(
+        plan, top_rung=top_rung, shards=shards, reduce=reduce
+    )
+    by_program = carried_job_transients(
+        plan, top_rung=top_rung, shards=shards, reduce=reduce
+    )
     largest = max(by_program, key=by_program.get)
     slots = need["carried_rows"] // 4
     if slots == 0 or slots >= _INT32_MAX:
@@ -228,9 +248,9 @@ def admit_carried_rows(
     said = (
         (f"a shard of {shards}, on the fullest chip: " if shards > 1 else "")
         + f"rows, held once, {need['carried_rows']} B + slot index "
-        f"{need['slot_index']} B + labels and changed mask "
-        f"{need['labels'] + need['changed_mask']} B + hub histograms "
-        f"{need['hub_histograms']} B + the largest program's other "
+        f"{need['slot_index']} B + {'depths' if reduce == 'min' else 'labels'} "
+        f"and changed mask {need['labels'] + need['changed_mask']} B + hub "
+        f"histograms {need['hub_histograms']} B + the largest program's other "
         f"temporaries ({largest}; " + ", ".join(
             f"{name} {held} B" for name, held in by_program.items()
         ) + f") {need['gather_transient']} B = {total} B"

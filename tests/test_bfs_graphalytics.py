@@ -1,0 +1,371 @@
+"""LDBC Graphalytics BFS through ``gm.bfs_distances`` (ISSUE 49): the search
+that follows its frontier over the carried bucket rows, the full-width level
+stepped from the host and the ``while_loop`` over the message arrays give the
+depths of the benchmark's plain reference (``benchmark/algorithms/bfs.py``,
+SciPy on its own CSR) and each other's, bit for bit; the one stepping loop
+takes BFS's start and stop as arguments and steps CDLP as it did; the
+admission answers for this job's own programs."""
+
+import importlib.util
+import os
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+import graphmine_tpu as gm
+from graphmine_tpu.obs.schema import validate_records
+from graphmine_tpu.ops import lpa, superstep_policy
+from graphmine_tpu.ops.paths import UNREACHABLE, bfs_parents
+from graphmine_tpu.ops.superstep_policy import (
+    admit_carried_rows,
+    delta_rungs,
+    step_carried_rows,
+)
+from graphmine_tpu.pipeline.metrics import MetricsSink
+
+from test_lpa_delta import _fused, _rmat
+
+_BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                      "benchmark")
+
+
+def _algorithm():
+    spec = importlib.util.spec_from_file_location(
+        "bench_algorithms_bfs", os.path.join(_BENCH, "algorithms", "bfs.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+bfs = _algorithm()
+
+
+def _lowest_with_an_edge(u, v) -> int:
+    return int(min(u.min(), v.min()))
+
+
+def _case(name):
+    """``(u, v, num_vertices, sources, max_depth)`` of a named case."""
+    rng = np.random.default_rng(49)
+    if name == "rmat_with_a_histogram_hub":
+        u, v, n = _rmat(12, 16, seed=5)
+        return u, v, n, [_lowest_with_an_edge(u, v)], 0
+    if name == "flat":
+        u, v = rng.integers(0, 3000, 6000), rng.integers(0, 3000, 6000)
+        return u, v, 3000, [_lowest_with_an_edge(u, v)], 0
+    if name == "path":  # 150 levels either way from the middle
+        return np.arange(299), np.arange(1, 300), 300, [150], 0
+    if name == "grid":  # 30 x 30, from a corner: 59 levels
+        at = np.arange(900).reshape(30, 30)
+        u = np.concatenate([at[:, :-1].ravel(), at[:-1, :].ravel()])
+        v = np.concatenate([at[:, 1:].ravel(), at[1:, :].ravel()])
+        return u, v, 900, [0], 0
+    if name == "unreached_components":  # two halves, and 500 vertices alone
+        a, b = rng.integers(0, 1000, 5000), rng.integers(0, 1000, 5000)
+        c, d = rng.integers(1000, 1500, 1500), rng.integers(1000, 1500, 1500)
+        return np.concatenate([a, c]), np.concatenate([b, d]), 2000, [int(a[0])], 0
+    if name == "isolated_source":
+        u, v = rng.integers(0, 800, 4000), rng.integers(0, 800, 4000)
+        return u, v, 1000, [950], 0
+    if name == "several_sources":
+        u, v = rng.integers(0, 2500, 5000), rng.integers(0, 2500, 5000)
+        return u, v, 2500, [int(u[0]), int(v[7]), int(u[99]), int(u[0])], 0
+    if name == "max_depth":
+        u, v = rng.integers(0, 3000, 6000), rng.integers(0, 3000, 6000)
+        return u, v, 3000, [_lowest_with_an_edge(u, v)], 3
+    raise KeyError(name)
+
+
+CASES = ["rmat_with_a_histogram_hub", "flat", "path", "grid",
+         "unreached_components", "isolated_source", "several_sources", "max_depth"]
+
+
+def _want(u, v, n, sources, max_depth):
+    """The plain reference's depths, the program's way of writing them; the
+    levels past ``max_depth`` unreached."""
+    depths = bfs.reference(u, v, n, {"source": sources})[0]
+    if max_depth:
+        depths = np.where(depths > max_depth, bfs.UNREACHED, depths)
+    return np.where(depths == bfs.UNREACHED, int(UNREACHABLE), depths).astype(np.int32)
+
+
+def _squeeze(monkeypatch, limit):
+    """The device of every plan reports ``limit`` bytes, none in use."""
+    monkeypatch.setattr(superstep_policy, "device_memory_stats",
+                        lambda plan: {"bytes_limit": limit, "bytes_in_use": 0})
+
+
+def _records(sink) -> list:
+    """The job's own records: a sink also hears of every compile."""
+    return [r for r in sink.records if r["phase"] != "compile"]
+
+
+def _run(path, g, plan, sources, max_depth, monkeypatch, sink=None):
+    """``(depths, supersteps)`` of one of the three paths."""
+    if path == "while_loop":
+        plan = None
+    elif path == "full_width":
+        _squeeze(monkeypatch, 1)  # no room for the rows: nothing is carried
+    depths, levels = gm.bfs_distances(
+        g, np.asarray(sources), direction="both", max_depth=max_depth,
+        plan=plan, sink=sink, return_levels=True)
+    return np.asarray(depths), int(levels)
+
+
+@pytest.mark.parametrize("path", ["frontier", "full_width", "while_loop"])
+@pytest.mark.parametrize("name", CASES)
+def test_every_path_gives_the_plain_references_depths(name, path, monkeypatch):
+    u, v, n, sources, max_depth = _case(name)
+    g, plan = _fused(u, v, n)
+    sink = MetricsSink()
+    got, levels = _run(path, g, plan, sources, max_depth, monkeypatch, sink)
+    want = _want(u, v, n, sources, max_depth)
+    assert got.dtype == np.int32 and np.array_equal(got, want)
+    # the supersteps: one a level reached, and the one that reaches nothing
+    deepest = int(want[want != int(UNREACHABLE)].max())
+    assert levels == (max_depth if max_depth and deepest == max_depth else deepest + 1)
+    assert (plan.hist_vertex_ids is not None) == (name == "rmat_with_a_histogram_hub")
+    validate_records(sink.records)
+    by_phase = {r["phase"]: r for r in _records(sink)}
+    if path == "while_loop":
+        assert not by_phase
+        return
+    fix, delta = by_phase["fixpoint"], by_phase["superstep_delta"]
+    assert fix["op"] == delta["op"] == "bfs_level" and fix["supersteps"] == levels
+    # a level's count is the vertices at that depth
+    assert fix["changed"] == delta["changed_vertices"] == [
+        int((want == d).sum()) for d in range(1, levels + 1)]
+    assert len(delta["seconds"]) == len(delta["branch"]) == levels
+    if path == "full_width":
+        assert set(delta["branch"]) == {"full"} and delta["changed_messages"] == []
+    else:
+        deg = np.bincount(np.concatenate([u, v]), minlength=n)
+        assert delta["changed_messages"] == [
+            int(deg[want == d].sum()) for d in range(1, levels + 1)]
+        assert delta["source_messages"] == int(deg[np.unique(sources)].sum())
+
+
+def test_the_control_fails_the_comparison_and_a_sound_answer_passes():
+    u, v, n, sources, _ = _case("flat")
+    traffic = {"source": "lowest_id_with_an_edge"}
+    want = bfs.reference(u, v, n, traffic)
+    assert want.shape == (2, n) and want.dtype == np.int64
+    assert want[0, sources[0]] == 0 and want[1].sum() == len(np.union1d(u, v))
+    g = gm.build_graph(u, v, num_vertices=n)
+    got, levels = bfs.run(g, None, traffic)
+    checks = bfs.compare(np.asarray(got), want)
+    assert [c["check"] for c in checks] == ["bfs_depth_mismatches", "bfs_reached_share"]
+    assert all(c["ok"] for c in checks) and checks[0]["compared"] == n
+    assert int(levels) == checks[0]["deepest"] + 1
+    broken = bfs.compare(bfs.control(u, v, n, traffic), want)
+    assert not broken[0]["ok"] and broken[0]["value"] > 0
+    # a source in a component of two is a trivial job, and fails the second
+    lone = np.concatenate([u + 2, [0]]), np.concatenate([v + 2, [1]])
+    trivial = bfs.compare(bfs.reference(*lone, n + 2, traffic),
+                          bfs.reference(*lone, n + 2, traffic))
+    assert trivial[0]["ok"] and not trivial[1]["ok"]
+
+
+def _many_levels():
+    """A sparse uniform draw: a dozen levels whose frontiers send from a
+    handful of messages to a third of them all."""
+    rng = np.random.default_rng(7)
+    u, v = rng.integers(0, 4000, 7000), rng.integers(0, 4000, 7000)
+    return u, v, 4000, [_lowest_with_an_edge(u, v)]
+
+
+def test_every_branch_is_taken_and_the_record_says_so(monkeypatch):
+    u, v, n, sources = _many_levels()
+    g, plan = _fused(u, v, n)
+    sink = MetricsSink()
+    want, levels = _run("frontier", g, plan, sources, 0, monkeypatch, sink)
+    sent = [r for r in sink.records if r["phase"] == "superstep_delta"][0][
+        "changed_messages"]
+    ks = sorted(set(sent) - {0, max(sent)})
+    assert len(ks) >= 4
+    rungs = tuple(ks[i * (len(ks) - 1) // 3] for i in range(4))  # four of them, spread
+    monkeypatch.setattr(superstep_policy, "delta_rungs", lambda num_messages: rungs)
+    sink = MetricsSink()
+    got, again = _run("frontier", g, plan, sources, 0, monkeypatch, sink)
+    assert np.array_equal(got, want) and again == levels
+    (delta,) = [r for r in sink.records if r["phase"] == "superstep_delta"]
+    assert delta["rungs"] == list(rungs)
+    assert delta["branch"][0] == "fill"  # the sources' slots, into the fill
+    assert set(delta["branch"]) == {"fill", *rungs, "full"}
+    # a level's branch is the lowest rung its predecessor's K fits under
+    for k, taken in zip(delta["changed_messages"], delta["branch"][1:]):
+        assert taken == next((r for r in rungs if k <= r), "full")
+
+
+def test_many_sources_start_with_a_full_gather(monkeypatch):
+    u, v, n, _ = _many_levels()
+    g, plan = _fused(u, v, n)
+    monkeypatch.setattr(superstep_policy, "delta_rungs", lambda num_messages: (8, 64))
+    sink = MetricsSink()
+    sources = np.arange(0, n, 3)
+    got, _ = _run("frontier", g, plan, sources, 0, monkeypatch, sink)
+    assert np.array_equal(got, _want(u, v, n, sources, 0))
+    (delta,) = [r for r in sink.records if r["phase"] == "superstep_delta"]
+    assert delta["branch"][0] == "full" and delta["source_messages"] > 64
+
+
+def test_auto_takes_the_frontier_job_past_the_crossover_and_not_below_it():
+    u, v, n = _rmat(12, 16, seed=5)
+    g = gm.build_graph(u, v, num_vertices=n)
+    assert g.num_messages >= superstep_policy.BUCKETED_MIN_MESSAGES
+    sink = MetricsSink()
+    source = [_lowest_with_an_edge(u, v)]
+    got = np.asarray(gm.bfs_distances(g, source, direction="both", sink=sink))
+    assert np.array_equal(got, _want(u, v, n, source, 0))
+    validate_records(sink.records)
+    phases = [r["phase"] for r in _records(sink)]
+    assert phases == ["impl_selected", "plan_build", "device_residency",
+                      "superstep_delta", "fixpoint"]
+    picked, _, held = _records(sink)[:3]
+    assert picked["op"] == held["op"] == "bfs_level" and picked["impl"] == "bucketed"
+    assert picked["scan"] == held["scan"] == "carried" and "row_min" in held["reason"]
+    assert held["rows_bytes"] > 0 and held["slot_index_bytes"] > 0
+    # the plan is the one CDLP and WCC build for this graph, found again
+    sink = MetricsSink()
+    gm.bfs_distances(g, source, direction="both", sink=sink)
+    assert [r["cached"] for r in sink.records if r["phase"] == "plan_build"] == [True]
+    assert lpa._cached_auto_plan(g)[0].out_slot is None  # the index stays out of it
+    small = gm.build_graph(u[:500], v[:500], num_vertices=n)
+    sink = MetricsSink()
+    gm.bfs_distances(small, [int(u[0])], direction="both", sink=sink)
+    assert [(r["phase"], r["impl"]) for r in _records(sink)] == [("impl_selected", "sort")]
+
+
+def test_a_directed_search_a_trace_and_an_unfused_plan_keep_the_loop(monkeypatch):
+    u, v, n, sources, _ = _case("flat")
+    g, plan = _fused(u, v, n)
+    want = _want(u, v, n, sources, 0)
+
+    def no_job(*a, **k):
+        raise AssertionError("a host-stepped job ran")
+
+    from graphmine_tpu.ops import paths
+
+    monkeypatch.setattr(paths, "_frontier_job", no_job)
+    monkeypatch.setattr(paths, "_full_width_job", no_job)
+    src = np.asarray(sources)
+    # under a caller's trace no count is concrete: the loop, the same depths
+    traced = jax.jit(lambda g, s: gm.bfs_distances(g, s, direction="both", plan=plan))
+    assert np.array_equal(np.asarray(traced(g, src)), want)
+    dist, parent = bfs_parents(g, src, direction="both")
+    assert np.array_equal(np.asarray(dist), want) and int(parent[sources[0]]) == -1
+    # edges as drawn: the message arrays of the edge list
+    one_way = bfs.control(u, v, n, {"source": sources})[0]
+    out = np.asarray(gm.bfs_distances(g, src, direction="out", plan=plan))
+    assert np.array_equal(out.astype(np.int64)[one_way != bfs.UNREACHED],
+                          one_way[one_way != bfs.UNREACHED])
+    unfused = gm.ops.BucketedModePlan.from_graph(g)
+    assert unfused.send_idx is None
+    assert np.array_equal(
+        np.asarray(gm.bfs_distances(g, src, direction="both", plan=unfused)), want)
+    with pytest.raises(ValueError, match="plan must be"):
+        gm.bfs_distances(g, src, direction="both", plan="bucketed")
+
+
+# -- the one stepping loop -----------------------------------------------------
+
+
+def _stub_loop(ks, moved=None, **kw):
+    """``step_carried_rows`` over stub programs that report the K's of
+    ``ks`` (and the counts of ``moved``): the calls it makes, in order."""
+    calls = []
+    feed = iter(zip(ks, moved or [1] * len(ks)))
+
+    def modes(rows, labels):
+        calls.append(("modes",))
+        k, count = next(feed)
+        return labels, f"changed{len(calls)}", np.int32(k), np.int32(count)
+
+    _, per_step = step_carried_rows(
+        kw.pop("max_iter", len(ks)), (10, 100, 1000), kw.pop("over", 10**6),
+        "rows", "labels",
+        gather=lambda rows, labels: calls.append(("gather",)) or rows,
+        rewrite=lambda rows, labels, changed, cap: calls.append(
+            ("rewrite", cap, changed)) or rows,
+        modes=modes, **kw,
+    )
+    return calls, per_step
+
+
+def test_cdlps_arguments_step_as_they_did():
+    """No start and no stop stated: the first superstep gathers in full
+    (``over`` is a K above every rung), and a superstep that moves nothing
+    does not end the loop: ``max_iter`` does."""
+    calls, per_step = _stub_loop([7, 0, 500, 2000, 0], moved=[3, 0, 9, 4, 0])
+    assert calls == [
+        ("gather",), ("modes",),
+        ("rewrite", 10, "changed2"), ("modes",),
+        ("rewrite", 10, "changed4"), ("modes",),
+        ("rewrite", 1000, "changed6"), ("modes",),
+        ("gather",), ("modes",),
+    ]
+    assert per_step == {
+        "changed_vertices": [3, 0, 9, 4, 0], "changed_messages": [7, 0, 500, 2000, 0],
+        "branch": [3, 0, 0, 2, 3], "reduce": ["full"] * 5,
+        "dirty_rows": [None] * 5, "dirty_slots": [None] * 5,
+    }
+
+
+def test_the_start_and_the_stop_are_arguments_of_the_one_loop():
+    """BFS's: the first superstep rewrites the slots of the vertices handed
+    in, at the rung their K fits under, and the loop ends with the first
+    superstep that moves nothing, or at ``max_iter``."""
+    calls, per_step = _stub_loop(
+        [50, 5000, 3, 0, 99], moved=[2, 40, 1, 0, 7], max_iter=9,
+        over=4, changed="sources", until_quiet=True)
+    assert calls == [
+        ("rewrite", 10, "sources"), ("modes",),
+        ("rewrite", 100, "changed2"), ("modes",),
+        ("gather",), ("modes",),
+        ("rewrite", 10, "changed6"), ("modes",),
+    ]
+    assert per_step["branch"] == [0, 1, 3, 0]
+    assert per_step["changed_vertices"] == [2, 40, 1, 0]
+    cut, per_step = _stub_loop([50, 60, 70], max_iter=2, over=4, changed="sources",
+                               until_quiet=True)
+    assert len(cut) == 4 and per_step["changed_messages"] == [50, 60]
+    ticks = iter(range(100))
+    _, timed = _stub_loop([5, 0, 5], moved=[1, 0, 1], over=1, changed="sources",
+                          until_quiet=True, clock=lambda: next(ticks))
+    assert timed["seconds"] == [1, 1]
+
+
+# -- the admission answers for this job ----------------------------------------
+
+
+def test_the_admission_sizes_the_bfs_jobs_own_programs(monkeypatch):
+    from graphmine_tpu.obs import memmodel
+
+    u, v, n = _rmat(12, 16, seed=5)
+    g, plan = _fused(u, v, n)
+    top = max(delta_rungs(plan.num_messages))
+    cdlp = memmodel.carried_rows_inventory(plan, top_rung=top)
+    need = memmodel.carried_rows_inventory(plan, top_rung=top, reduce="min")
+    programs = memmodel.carried_job_transients(plan, top_rung=top, reduce="min")
+    assert sorted(programs) == ["gather", "rewrite", "row_min"]
+    assert cdlp["hub_histograms"] > 0 and need["hub_histograms"] == 0
+    assert need["gather_transient"] == max(programs.values())
+    for same in ("carried_rows", "slot_index", "labels", "changed_mask"):
+        assert need[same] == cdlp[same]
+    assert programs["gather"] == memmodel.carried_job_transients(plan, top)["gather"]
+    total = sum(need.values())
+    room = {"bytes_limit": total, "bytes_in_use": 0}
+    scan, reason = admit_carried_rows(plan, room, reduce="min")
+    assert scan == "carried" and "row_min" in reason and "modes" not in reason
+    assert admit_carried_rows(plan, room)[0] == "plain"  # CDLP's histograms do not fit
+    room["bytes_limit"] -= 1
+    assert admit_carried_rows(plan, room, reduce="min")[0] == "plain"
+    # one index a plan, one answer a job: CDLP is refused, BFS admitted
+    _squeeze(monkeypatch, total)
+    assert lpa._cached_slot_index(plan)[2][0] == "plain"
+    indexed, _, scan = lpa._cached_slot_index(plan, reduce="min")
+    assert scan[0] == "carried" and indexed.out_slot is not None
+    assert lpa._cached_slot_index(plan)[0].out_slot is None
+    assert lpa._cached_slot_index(plan, reduce="min")[0].out_slot is indexed.out_slot

@@ -276,6 +276,66 @@ def test_carried_rows_programs_compile_for_v5e(
             rows_bytes if program in ("modes", "dirty_modes") else rows_bytes // 4)
 
 
+@pytest.mark.parametrize("graph, program", [
+    *[("kronecker", p) for p in ("start", "gather", "rewrite", "level", "full_level")],
+    # the rewrite is CDLP's under another scope, a minute a compile: once is enough
+    *[("flat", p) for p in ("start", "gather", "level", "full_level")],
+])
+def test_bfs_job_programs_compile_for_v5e(
+    one_chip, fused_plan, flat_plan, planted, program, graph
+):
+    """The BFS job's programs (ISSUE 49: ``ops/paths.py``), each compiled
+    alone, beside CDLP's: the rows are the donated argument of the gather
+    and of the rewrite, so both update the whole ``s32[S]`` buffer IN PLACE
+    (aliased to the result, no copy and no temporary of its size), and the
+    start program lays them out by a fill, with no gather and no
+    temporary. The level reads the rows and writes V-sized results. Each
+    program's temporaries are at or under what the admission counts for
+    it (``carried_job_transients(..., reduce="min")``), on a skewed plan
+    with hubs, whose histograms this job never builds, and on a flat one."""
+    from graphmine_tpu.obs.memmodel import carried_job_transients
+    from graphmine_tpu.ops import paths
+    from graphmine_tpu.ops.bucketed_mode import row_slots, with_slot_index
+    from graphmine_tpu.ops.superstep_policy import delta_rungs
+
+    plan = fused_plan[1] if graph == "kronecker" else flat_plan
+    plan = _shapes(with_slot_index(plan), one_chip)
+    v, slots = planted[2], row_slots(plan)
+    top_rung = delta_rungs(plan.num_messages)[-1]
+    shape = _shape_on(one_chip)
+    rows, depth = shape((slots,)), shape((v,))
+    counted = carried_job_transients(plan, top_rung=top_rung, reduce="min")
+    if program == "start":
+        compiled = _compile(paths._start_program, shape((1,)), plan.out_ptr,
+                            slots=slots, num_vertices=v)
+        limit = 8 * v
+    elif program == "gather":
+        compiled = _compile(paths._gather_program, rows, depth, plan)
+        limit = counted["gather"]
+    elif program == "rewrite":
+        compiled = _compile(paths._rewrite_program, rows, depth,
+                            shape((v,), jnp.bool_), plan, cap=top_rung)
+        limit = counted["rewrite"]
+    elif program == "level":
+        compiled = _compile(paths._level_program, rows, depth, plan)
+        limit = counted["row_min"]
+    else:  # where the rows were not admitted: gathers, mins, keeps nothing
+        compiled = _compile(paths._full_level_program, depth, plan)
+        limit = counted["row_min"]
+    held = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert " conditional(" not in text and " while(" not in text
+    assert held.temp_size_in_bytes <= limit
+    if program in ("gather", "rewrite"):
+        assert held.alias_size_in_bytes >= 4 * slots
+    else:
+        assert held.alias_size_in_bytes == 0
+    if program == "start":
+        assert " gather(" not in text and held.output_size_in_bytes >= 4 * slots
+    if graph == "kronecker":  # wide classes, four hubs: well under the rows
+        assert held.temp_size_in_bytes < 4 * slots // (4 if program != "level" else 1)
+
+
 @pytest.mark.parametrize("graph", ["kronecker", "flat"])
 def test_pagerank_iteration_compiles_for_v5e_and_the_loop_holds_every_class(
     one_chip, fused_plan, flat_plan, planted, graph
