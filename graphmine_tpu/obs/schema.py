@@ -196,6 +196,16 @@ register("fixpoint", "op", "supersteps", "changed", "num_vertices", "family")
 # admitted one compiled full-width level is stepped from the host: every
 # `branch` "full", no K, no rungs, and `seconds` all the same. Benchmark
 # metrics `bfs_sparse_level_share` and `bfs_full_level_ms` read it.
+# Since PR 50 the job over carried rows also says `direction` and
+# `unreached_messages`, one a level: `"top_down"` (the rows brought up to
+# date behind the K messages of the vertices the level before reached, then
+# the row min) or `"bottom_up"` (the vertices still unreached look their
+# neighbours' depths up through the slot index; no row is read or written,
+# so `reduce` is "none" and `dirty_rows` / `dirty_slots` 0), and U, the
+# edges of the vertices the level left unreached. The next level is
+# bottom-up where U fits a rung strictly below K's and below the top one;
+# its `branch` is then the rung U fits under, never "full". After a
+# bottom-up level the rows are stale and a top-down level gathers in full.
 register("superstep_delta", "op", "changed_vertices", "changed_messages",
          "branch", "rungs", "num_messages", "reduce", "dirty_rows",
          "dirty_slots")
@@ -445,6 +455,10 @@ DEVICE_SCOPES = frozenset((
     # over their senders' depths, and the rows' rewrite behind the vertices
     # the last level reached (`delta` and its passes follow it)
     "hubs", "rewrite",
+    # the level that asks the unreached vertices for a reached neighbour
+    # (ops/bucketed_mode.bfs_level_bottom_up, PR 50): `compact`, `expand`,
+    # then `neighbours` (slot, row, vertex, depth), `write_back`, `hubs`
+    "bottom_up", "neighbours",
     # carried rows (ops/bucketed_mode.rewrite_rows): outer, then its passes
     # (`mark`: the marked rewrite's list of the rows it wrote to, PR 43)
     "delta", "compact", "expand", "scatter", "mark",

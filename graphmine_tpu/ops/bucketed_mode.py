@@ -779,6 +779,47 @@ def gather_rows(rows: jax.Array, labels: jax.Array, plan: BucketedModePlan):
         return _gather_classes(rows, labels, plan)
 
 
+def _compact_spans(send: jax.Array, out_ptr: jax.Array, out_deg: jax.Array, *carried):
+    """``(key, start, count, *carried)``, each ``[V]``: the vertices of the
+    mask ``send`` in front, ascending, by one sort that carries each one's
+    span of ``out_slot`` (where it starts, how many messages it sends) and
+    what else rides with the key; behind them the key is ``V`` and the
+    count 0."""
+    v = send.shape[0]
+    key = jnp.where(send, jnp.arange(v, dtype=jnp.int32), v)
+    return lax.sort(
+        (key, out_ptr[:-1], jnp.where(send, out_deg, 0), *carried), num_keys=1
+    )
+
+
+def _expand_spans(start: jax.Array, count: jax.Array, cap: int):
+    """``(place, source, spread, end)``: the compacted spans laid end to
+    end over ``cap`` places. Place ``p`` reads ``out_slot[source[p]]``;
+    ``spread(x)`` gives each place its span's entry of the per-sender
+    ``x``, by one scattered difference at the span's start and a
+    ``cumsum``; the places from ``end[-1]`` on belong to no span."""
+    end = jnp.cumsum(count)
+    first = end - count  # a span's first place; the last ends at K
+    at = jnp.where(count > 0, first, cap)  # past the senders: dropped
+
+    def spread(per_sender):
+        step = jnp.diff(per_sender, prepend=jnp.zeros((1,), jnp.int32))
+        return jnp.cumsum(
+            jnp.zeros((cap,), jnp.int32).at[at].add(step, mode="drop")
+        )
+
+    place = jnp.arange(cap, dtype=jnp.int32)
+    # place p of a span that starts at `first` reads out_slot[p + skip]
+    return place, place + spread(start - first), spread, end
+
+
+def _span_slots(place, source, end, out_slot: jax.Array, s: int):
+    """The flat slot behind each place; past the last span lie other
+    senders' slots: those places name ``s``, which is none."""
+    m = out_slot.shape[0]
+    return jnp.where(place < end[-1], out_slot[jnp.clip(source, 0, m - 1)], s)
+
+
 def _rewrite_rows_and_slots(
     rows: jax.Array, labels: jax.Array, changed: jax.Array,
     plan: BucketedModePlan, cap: int,
@@ -786,40 +827,22 @@ def _rewrite_rows_and_slots(
     """``(rows, slot)``: :func:`rewrite_rows`, and the ``cap`` flat slots
     it wrote to; a place past the last changed sender's span names slot
     ``S``, which is none."""
-    v, m, s = plan.num_vertices, plan.out_slot.shape[0], rows.shape[0]
+    v, s = plan.num_vertices, rows.shape[0]
     senders = min(cap, v)
     out_deg = plan.out_ptr[1:] - plan.out_ptr[:-1]
     with jax.named_scope("delta"):
         with jax.named_scope("compact"):
-            send = changed & (out_deg > 0)
-            key = jnp.where(send, jnp.arange(v, dtype=jnp.int32), v)
             _, start, count, label = (
-                x[:senders] for x in lax.sort(
-                    (key, plan.out_ptr[:-1], jnp.where(send, out_deg, 0),
-                     labels.astype(jnp.int32)),
-                    num_keys=1,
+                x[:senders] for x in _compact_spans(
+                    changed & (out_deg > 0), plan.out_ptr, out_deg,
+                    labels.astype(jnp.int32),
                 )
             )
         with jax.named_scope("expand"):
-            end = jnp.cumsum(count)
-            first = end - count  # a span's first place; the last ends at K
-            at = jnp.where(count > 0, first, cap)  # past the senders: dropped
-
-            def spread(per_sender):
-                step = jnp.diff(per_sender, prepend=jnp.zeros((1,), jnp.int32))
-                return jnp.cumsum(
-                    jnp.zeros((cap,), jnp.int32).at[at].add(step, mode="drop")
-                )
-
-            place = jnp.arange(cap, dtype=jnp.int32)
-            # place p of a span that starts at `first` reads out_slot[p + skip]
-            source = place + spread(start - first)
+            place, source, spread, end = _expand_spans(start, count, cap)
             value = spread(label)
         with jax.named_scope("scatter"):
-            # past the last span lie other senders' slots: name none
-            slot = jnp.where(
-                place < end[-1], plan.out_slot[jnp.clip(source, 0, m - 1)], s
-            )
+            slot = _span_slots(place, source, end, plan.out_slot, s)
             return rows.at[slot].set(value, mode="drop"), slot
 
 
@@ -886,6 +909,18 @@ def _pick(ge: jax.Array, table) -> jax.Array:
     )
 
 
+def _rows_of_slots(slot: jax.Array, plan: BucketedModePlan) -> jax.Array:
+    """The number of the row that holds each flat ``slot``, by arithmetic:
+    the slot's class by comparing it to the few dozen class offsets, then
+    ``(slot - class offset) // width``. No slot (``S``) is past every
+    class: offset ``S``, width 1, the plan's count of rows."""
+    offs, rowoffs, widths = _class_tables(plan)
+    ge = slot[:, None] >= jnp.asarray(offs[1:], jnp.int32)[None, :]
+    return _pick(ge, rowoffs) + lax.div(
+        slot - _pick(ge, offs), _pick(ge, np.append(widths, 1))
+    )
+
+
 def rewrite_rows_marked(
     rows: jax.Array, labels: jax.Array, changed: jax.Array,
     plan: BucketedModePlan, cap: int,
@@ -899,15 +934,9 @@ def rewrite_rows_marked(
     ``cap``-long sorts leave each row once; a message a histogram hub
     receives has no slot and marks no row."""
     rows, slot = _rewrite_rows_and_slots(rows, labels, changed, plan, cap)
-    offs, rowoffs, widths = _class_tables(plan)
-    total = int(rowoffs[-1])
+    total = sum(idx.shape[0] for idx in plan.send_idx)
     with jax.named_scope("delta"), jax.named_scope("mark"):
-        # no slot (s) is past every class: offset s, width 1, row `total`
-        ge = slot[:, None] >= jnp.asarray(offs[1:], jnp.int32)[None, :]
-        row = _pick(ge, rowoffs) + lax.div(
-            slot - _pick(ge, offs), _pick(ge, np.append(widths, 1))
-        )
-        row = lax.sort(row)
+        row = lax.sort(_rows_of_slots(slot, plan))
         again = jnp.concatenate([jnp.zeros((1,), jnp.bool_), row[1:] == row[:-1]])
         dirty = lax.sort(jnp.where(again, total, row))[: min(cap, total)]
     return rows, dirty
@@ -1069,12 +1098,17 @@ def _class_of_rows(rows: jax.Array, off: int, shape: tuple) -> jax.Array:
 # depths, as ``ops/cc.py:cc_superstep_bucketed`` does.
 
 
+def _one_past(near: jax.Array) -> jax.Array:
+    """The depth one past ``near``, saturating: past "unreached" lies
+    "unreached"."""
+    return jnp.where(near == _SENTINEL, _SENTINEL, near + 1)
+
+
 def _relax_class(out: jax.Array, ids: jax.Array, mat: jax.Array) -> jax.Array:
     """``out`` with each of ``ids`` at most one past the least of its row."""
     width = f"w{mat.shape[1]}"
     with jax.named_scope("row_min"), jax.named_scope(width):
-        near = jnp.min(mat, axis=1)
-        reach = jnp.where(near == _SENTINEL, _SENTINEL, near + 1)
+        reach = _one_past(jnp.min(mat, axis=1))
     with jax.named_scope("write_back"):
         return out.at[ids].min(reach, unique_indices=True, mode="drop")
 
@@ -1089,7 +1123,7 @@ def _relax_hubs(depth: jax.Array, out: jax.Array, plan: BucketedModePlan):
             plan.hist_row_offset // jnp.int32(plan.num_vertices),
             num_segments=plan.hist_vertex_ids.shape[0], indices_are_sorted=True,
         )
-        reach = jnp.where(near == _SENTINEL, _SENTINEL, near + 1)
+        reach = _one_past(near)
     with jax.named_scope("write_back"):
         return out.at[plan.hist_vertex_ids].min(
             reach, unique_indices=True, mode="drop"
@@ -1139,3 +1173,69 @@ def bfs_level_bucketed(depth: jax.Array, plan: BucketedModePlan) -> jax.Array:
                 mat = pad[idx]
             out = _relax_class(out, ids, mat)
         return _relax_hubs(depth, out, plan)
+
+
+# ---- the bottom-up level (ISSUE 50) ----
+#
+# Late in a search the frontier's messages land almost all in the rows of
+# vertices that already have their depth, and the question a level answers
+# is the other one: which vertex still unreached has a reached neighbour?
+# On a symmetric graph a vertex's in-neighbours are its out-neighbours, and
+# the slot index names them: the neighbours of u are the owners of the rows
+# that hold out_slot[out_ptr[u]:out_ptr[u + 1]]. So the unreached vertices'
+# spans are laid end to end as a rewrite lays the changed senders' (Beamer's
+# direction-optimising search, read off the index the job carries), each
+# place looks its neighbour's depth up, and the rows are neither read nor
+# written: after such a level they are stale.
+
+
+def compact_unreached(depth: jax.Array, plan: BucketedModePlan):
+    """``(owner, start, count)``, each ``int32 [V]``: the vertices without a
+    depth that have an edge, ascending, in front, each with its span of
+    ``out_slot``; behind them ``owner`` is ``V`` and ``count`` 0. The one
+    V-long sort of a bottom-up level, whatever its rung."""
+    out_deg = plan.out_ptr[1:] - plan.out_ptr[:-1]
+    with jax.named_scope("bfs_level"), jax.named_scope("bottom_up"):
+        with jax.named_scope("compact"):
+            return _compact_spans(
+                (depth == _SENTINEL) & (out_deg > 0), plan.out_ptr, out_deg
+            )
+
+
+def bfs_level_bottom_up(
+    depth: jax.Array, owner: jax.Array, start: jax.Array, count: jax.Array,
+    plan: BucketedModePlan, cap: int,
+) -> jax.Array:
+    """One BFS level that asks the unreached vertices
+    (:func:`compact_unreached`'s lists) for a reached neighbour:
+    ``min(own, least neighbour's depth + 1)`` for each of them, the depths
+    :func:`bfs_level_from_rows` gives bit for bit in a search whose reached
+    vertices keep their depths. ``cap`` (static) bounds the edges of the
+    unreached vertices, the caller's promise. A place reads its slot, the
+    slot's row by arithmetic, the row's vertex through the classes' ids
+    laid end to end, and that vertex's depth. A message a histogram hub
+    receives has no slot and names no row, so the hubs' neighbours are
+    relaxed from the hubs' own depths: by symmetry a hub's senders are its
+    neighbours."""
+    v = plan.num_vertices
+    senders = min(cap, v)
+    owner, start, count = (x[:senders] for x in (owner, start, count))
+    pad = _with_sentinel(depth)
+    with jax.named_scope("bfs_level"), jax.named_scope("bottom_up"):
+        with jax.named_scope("expand"):
+            place, source, spread, end = _expand_spans(start, count, cap)
+            vertex = spread(owner)
+        with jax.named_scope("neighbours"):
+            slot = _span_slots(place, source, end, plan.out_slot, row_slots(plan))
+            # no slot names the row past the last, whose vertex is V: unreached
+            ids = jnp.concatenate([*plan.vertex_ids, jnp.full((1,), v, jnp.int32)])
+            near = pad[ids[_rows_of_slots(slot, plan)]]
+        with jax.named_scope("write_back"):
+            out = depth.at[vertex].min(_one_past(near), mode="drop")
+        if plan.hist_vertex_ids is not None:
+            with jax.named_scope("hubs"):
+                hub = plan.hist_vertex_ids[
+                    plan.hist_row_offset // jnp.int32(v)
+                ]
+                out = out.at[plan.hist_send].min(_one_past(depth[hub]))
+    return out

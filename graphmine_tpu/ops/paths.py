@@ -8,7 +8,9 @@ over unit weights, which for BFS converges in diameter supersteps inside a
 single ``lax.while_loop``. That loop is full width in every pass. On an
 undirected graph past the policy's crossover :func:`bfs_distances` follows
 the frontier instead (ISSUE 49): the carried-rows job of ``ops/lpa.py``
-with a min for its reduce, stepped from the host to the last level.
+with a min for its reduce, stepped from the host to the last level, and
+turned bottom-up once the unreached vertices have fewer edges than the
+frontier sends messages (ISSUE 50).
 
 Direction conventions:
 - ``direction="out"``: follow edge direction (src -> dst), GraphFrames'
@@ -69,8 +71,17 @@ def bfs_distances(
     neighbours' rows, through the slot index, at the rung the messages
     they send fit under (:func:`~graphmine_tpu.ops.superstep_policy.
     delta_rungs`; a level that sends more than the top rung gathers every
-    row anew). The host reads one pair of counts a level and stops at the
-    first that reached nothing. The rows and the index go on the device
+    row anew). The search turns bottom-up (:func:`_next_update`) once the
+    edges of the vertices still unreached fit a rung strictly below the
+    one the frontier's messages fit, and below the top one: those vertices
+    then look their neighbours' depths up through the slot index
+    (:func:`~graphmine_tpu.ops.bucketed_mode.bfs_level_bottom_up`), a few
+    thousand places late in a search where the frontier would rewrite
+    millions of slots in rows that already have their depth. Such a level
+    reads and writes no row, so the rows are stale after it: from then on
+    the one top-down update is the full gather, and the search stays
+    turned unless that is the cheaper. The host reads three counts a level
+    and stops at the first that reached nothing. The rows and the index go on the device
     only where :func:`~graphmine_tpu.ops.superstep_policy.
     admit_carried_rows` finds room for this job's programs; otherwise one
     compiled full-width level (gather every class, row min, write back) is
@@ -85,8 +96,9 @@ def bfs_distances(
     ``impl_selected`` (with ``scan`` and ``scan_reason``), ``plan_build``
     and ``device_residency`` as ``label_propagation`` does; a job over the
     plan's rows one ``superstep_delta`` record (``op: bfs_level``; a level:
-    the branch taken, the vertices reached, the messages they send, its
-    seconds at the host's one wait) and every host-stepped job one
+    its direction, the branch taken, the vertices reached, the messages
+    they send, the edges of the vertices still unreached, its seconds at
+    the host's one wait) and every host-stepped job one
     ``fixpoint`` record (the supersteps and the vertices each reached).
     """
     from graphmine_tpu.ops.lpa import _under_a_trace
@@ -148,8 +160,10 @@ def _emit_job_records(sink, graph: Graph, plan, per_step: dict) -> None:
     """The ``superstep_delta`` and ``fixpoint`` records of one host-stepped
     job (no-op without a sink): a level's ``branch`` is ``"fill"`` for a
     first level that found the rows as the fill left them and wrote the
-    sources' slots alone, a rung, or ``"full"``; the full-width job has no
-    rows, no K and no rung, and every level of it is ``"full"``."""
+    sources' slots alone, a rung (a top-down level's the one K fits under,
+    a bottom-up level's the one U does), or ``"full"``; a bottom-up level
+    reduces no row (``reduce: "none"``); the full-width job has no rows, no
+    K, no U and no rung, and every level of it is ``"full"``."""
     if sink is None:
         return
     from graphmine_tpu.ops.lpa import _plan_rows_and_slots
@@ -157,22 +171,28 @@ def _emit_job_records(sink, graph: Graph, plan, per_step: dict) -> None:
 
     reached = per_step["changed_vertices"]
     rows, slots = _plan_rows_and_slots(plan.send_idx)
+    turned = [d == "bottom_up" for d in per_step.get("direction", reached)]
     if "branch" in per_step:
         rungs = list(delta_rungs(plan.num_messages))
         branch = [[*rungs, "full"][b] for b in per_step["branch"]]
-        if branch and branch[0] != "full":
+        if branch and branch[0] != "full" and not turned[0]:
             branch[0] = "fill"
+        more = {
+            "direction": per_step["direction"],
+            "unreached_messages": per_step["unreached_messages"],
+            "source_messages": per_step["source_messages"],
+        }
         sent = per_step["changed_messages"]
     else:
-        rungs, branch, sent = [], ["full"] * len(reached), []
+        rungs, branch, sent, more = [], ["full"] * len(reached), [], {}
     sink.emit(
         "superstep_delta", op="bfs_level", changed_vertices=reached,
         changed_messages=sent, branch=branch, rungs=rungs,
-        num_messages=plan.num_messages, reduce=["full"] * len(reached),
-        dirty_rows=[rows] * len(reached), dirty_slots=[slots] * len(reached),
-        seconds=[round(s, 6) for s in per_step.get("seconds", ())],
-        **({"source_messages": per_step["source_messages"]}
-           if "source_messages" in per_step else {}),
+        num_messages=plan.num_messages,
+        reduce=["none" if t else "full" for t in turned],
+        dirty_rows=[0 if t else rows for t in turned],
+        dirty_slots=[0 if t else slots for t in turned],
+        seconds=[round(s, 6) for s in per_step.get("seconds", ())], **more,
     )
     sink.emit(
         "fixpoint", op="bfs_level", supersteps=len(reached), changed=reached,
@@ -188,13 +208,15 @@ def _emit_job_records(sink, graph: Graph, plan, per_step: dict) -> None:
 
 @partial(jax.jit, static_argnames=("slots", "num_vertices"))
 def _start_program(sources, out_ptr, slots: int, num_vertices: int):
-    """``(rows, depth, reached, K)`` before the first level: the rows as a
-    gather of all-unreached depths would leave them (a fill, and no gather
-    of S slots), the sources at depth 0, and the messages they send."""
+    """``(rows, depth, reached, K, U)`` before the first level: the rows as
+    a gather of all-unreached depths would leave them (a fill, and no gather
+    of S slots), the sources at depth 0, the messages they send and the
+    edges of every other vertex."""
     rows = jnp.full((slots,), UNREACHABLE, jnp.int32)
     depth = _unreached_but(sources, num_vertices)
     reached = depth == 0
-    return rows, depth, reached, _k_and_count(reached, out_ptr)[0]
+    k, _, u = _level_counts(depth, reached, out_ptr)
+    return rows, depth, reached, k, u
 
 
 def _unreached_but(sources, num_vertices: int):
@@ -202,13 +224,16 @@ def _unreached_but(sources, num_vertices: int):
     return jnp.full((num_vertices,), UNREACHABLE, jnp.int32).at[sources].set(0)
 
 
-def _k_and_count(reached, out_ptr):
-    """``(K, count)``: K the messages the ``reached`` vertices send, which
-    picks the next level's update, and their count."""
+def _level_counts(depth, reached, out_ptr):
+    """``(K, count, U)``, what the host reads once a level: K the messages
+    the ``reached`` vertices send and U the edges of the vertices still
+    without a ``depth``, which between them pick the next level's update
+    (:func:`_next_update`), and the count of the reached."""
     with jax.named_scope("bfs_level"), jax.named_scope("changed_count"):
         out_deg = out_ptr[1:] - out_ptr[:-1]
         k = jnp.sum(jnp.where(reached, out_deg, 0), dtype=jnp.int32)
-        return k, jnp.sum(reached, dtype=jnp.int32)
+        u = jnp.sum(jnp.where(depth == UNREACHABLE, out_deg, 0), dtype=jnp.int32)
+        return k, jnp.sum(reached, dtype=jnp.int32), u
 
 
 @partial(jax.jit, donate_argnums=0)
@@ -227,12 +252,33 @@ def _rewrite_program(rows, depth, reached, plan, cap: int):
 
 @jax.jit
 def _level_program(rows, depth, plan):
-    """``(new depths, reached, K, count)`` of one level over ``rows``."""
+    """``(new depths, reached, K, count, U)`` of one level over ``rows``."""
     from graphmine_tpu.ops.bucketed_mode import bfs_level_from_rows
 
     new = bfs_level_from_rows(rows, depth, plan)
     reached = new != depth
-    return (new, reached, *_k_and_count(reached, plan.out_ptr))
+    return (new, reached, *_level_counts(new, reached, plan.out_ptr))
+
+
+@jax.jit
+def _unreached_program(depth, plan):
+    """The unreached vertices' spans, compacted: the one V-long sort of a
+    bottom-up level, in a program of its own so that no rung compiles it
+    again (a minute and more a compile at 2^24 vertices)."""
+    from graphmine_tpu.ops.bucketed_mode import compact_unreached
+
+    return compact_unreached(depth, plan)
+
+
+@partial(jax.jit, static_argnames=("cap",))
+def _bottom_up_program(depth, owner, start, count, plan, cap: int):
+    """``(new depths, reached, K, count, U)`` of one bottom-up level over
+    the unreached vertices' spans, whose edges fit ``cap`` places; no sort."""
+    from graphmine_tpu.ops.bucketed_mode import bfs_level_bottom_up
+
+    new = bfs_level_bottom_up(depth, owner, start, count, plan, cap)
+    reached = new != depth
+    return (new, reached, *_level_counts(new, reached, plan.out_ptr))
 
 
 @jax.jit
@@ -245,33 +291,89 @@ def _full_level_program(depth, plan):
         return new, jnp.sum(new != depth, dtype=jnp.int32)
 
 
+def _next_update(k: int, u: int, rungs: tuple, stale: bool) -> tuple:
+    """``(place, bottom_up)``: the cheaper update of the next level, by the
+    ladder alone. Top-down brings the rows up to date behind the K messages
+    the last level's vertices send, at the rung K fits under (``place`` in
+    ``rungs``, ``len(rungs)`` for the full gather; rows a bottom-up level
+    left ``stale`` can only be gathered anew), and takes the row min.
+    Bottom-up looks at the U edges of the vertices still unreached, at the
+    rung U fits under. A bottom-up place costs more than a rewrite's (two
+    more gathers: about 50 ns against 37-48 on a TPU v5e, PERF.md §6, PR 50),
+    so it is taken only where U's rung is strictly below K's, and never on
+    the top rung, which costs more than the full gather at that price."""
+    full = len(rungs)
+    place = full if stale else sum(k > rung for rung in rungs)
+    under = sum(u > rung for rung in rungs)
+    if under < min(place, full - 1):
+        return under, True
+    return place, False
+
+
 def _frontier_job(graph: Graph, sources, limit: int, plan, clock=None):
     """``(depths, per_step)`` of a search that follows its frontier over a
-    fused plan with its slot index, stepped from the host by the one loop
-    of the carried-rows jobs (:func:`~graphmine_tpu.ops.superstep_policy.
-    step_carried_rows`): the rows start as a fill, the first level rewrites
-    the sources' slots, every later level brings the rows up to date by
-    what its predecessor reached (a rung's rewrite, or a full gather above
-    the top rung) and :func:`_level_program` takes the row min. It stops at
-    the first level that reaches nothing, or after ``limit``."""
-    from graphmine_tpu.ops.bucketed_mode import check_plan_fits, row_slots
-    from graphmine_tpu.ops.superstep_policy import delta_rungs, step_carried_rows
+    fused plan with its slot index, stepped from the host: the rows start as
+    a fill, and every level takes the update :func:`_next_update` picks from
+    the two counts its predecessor left. Top-down: the rows are brought up
+    to date by what the predecessor reached (a rung's rewrite, the first
+    level's of the sources' slots; a full gather above the top rung) and
+    :func:`_level_program` takes the row min. Bottom-up (ISSUE 50), once
+    the unreached vertices' edges fit a lower rung than the frontier's
+    messages: :func:`_unreached_program` compacts those vertices and
+    :func:`_bottom_up_program` looks their neighbours' depths up through
+    the slot index; the rows are not touched and are stale from then on
+    (the job lets them go: a fifth of what it holds on the device), so the
+    search stays turned unless a full gather is the cheaper (U never
+    grows). The host waits once a level, for the three counts. It stops at
+    the first level that reaches nothing, or after ``limit``.
 
-    rows, depth, reached, k = _start_program(
+    ``per_step``, one entry a level: ``changed_vertices``,
+    ``changed_messages`` (K), ``unreached_messages`` (U), ``direction``,
+    ``branch`` (the place of the rung K fits under, or U on a bottom-up
+    level; ``len(rungs)`` for a full gather), with a ``clock`` ``seconds``;
+    and ``source_messages``, the K that picked the first level's rung."""
+    from graphmine_tpu.ops.bucketed_mode import check_plan_fits, row_slots
+    from graphmine_tpu.ops.superstep_policy import delta_rungs
+
+    rungs = delta_rungs(plan.num_messages)
+    rows, depth, reached, *counts = _start_program(
         sources, plan.out_ptr, slots=row_slots(plan), num_vertices=plan.num_vertices
     )
     check_plan_fits(depth, graph, plan)
-    k = int(k)  # a fetch a job: the sources' out-degree picks the first rung
-    depth, per_step = step_carried_rows(
-        limit, delta_rungs(plan.num_messages), k, rows, depth,
-        gather=lambda rows, depth: _gather_program(rows, depth, plan),
-        rewrite=lambda rows, depth, reached, cap: _rewrite_program(
-            rows, depth, reached, plan, cap=cap
-        ),
-        modes=lambda rows, depth: _level_program(rows, depth, plan),
-        clock=clock, changed=reached, until_quiet=True,
-    )
-    return depth, dict(per_step, source_messages=k)
+    # a fetch a job: the sources' out-degree picks the first rung
+    k, u = (int(x) for x in jax.device_get(counts))
+    source_messages = k
+    names = ("changed_vertices", "changed_messages", "unreached_messages",
+             "direction", "branch")
+    per_step = {name: [] for name in names}
+    marks = [clock()] if clock else []
+    for _ in range(limit):
+        place, bottom_up = _next_update(k, u, rungs, stale=rows is None)
+        if bottom_up:
+            rows = None  # stale from here on: the device has their room back
+            spans = _unreached_program(depth, plan)
+            depth, reached, *counts = _bottom_up_program(
+                depth, *spans, plan, cap=rungs[place]
+            )
+        else:
+            if rows is None:  # the gather writes every slot: any rows will do
+                rows = jnp.empty((row_slots(plan),), jnp.int32)
+            if place == len(rungs):
+                rows = _gather_program(rows, depth, plan)
+            else:
+                rows = _rewrite_program(rows, depth, reached, plan, cap=rungs[place])
+            depth, reached, *counts = _level_program(rows, depth, plan)
+        k, moved, u = (int(x) for x in jax.device_get(counts))  # the one wait
+        said = (moved, k, u, "bottom_up" if bottom_up else "top_down", place)
+        for name, value in zip(names, said):
+            per_step[name].append(value)
+        if clock:
+            marks.append(clock())
+        if moved == 0:
+            break
+    if clock:
+        per_step["seconds"] = [b - a for a, b in zip(marks, marks[1:])]
+    return depth, dict(per_step, source_messages=source_messages)
 
 
 def _full_width_job(graph: Graph, sources, limit: int, plan, clock=None):

@@ -101,23 +101,20 @@ def delta_rungs(num_messages: int) -> tuple:
 def step_carried_rows(
     max_iter: int, rungs: tuple, over: int, rows, labels,
     gather, rewrite, modes, dirty_modes=None, clock=None,
-    changed=None, until_quiet: bool = False,
+    until_quiet: bool = False,
 ):
     """``(labels, per_step)`` of ``max_iter`` supersteps of a carried-rows
     job, stepped from the host: the loop of ``ops/lpa.py:_carried_rows_job``,
     of its mesh form (``parallel/sharded.py:carried_label_propagation``)
-    and of the BFS job (``ops/paths.py:_frontier_job``), which hand it their
-    programs. Each superstep first brings ``rows`` up
+    and of the BFS job where its rows were not admitted
+    (``ops/paths.py:_full_width_job``: no rung, nothing carried), which hand
+    it their programs. Each superstep first brings ``rows`` up
     to the labels it starts from by the update its predecessor's K picks
     (K above every rung: ``gather(rows, labels)``; K <= a rung:
     ``rewrite(rows, labels, changed, rung)``), then ``modes(rows, labels)``
-    gives ``(new labels, changed, K, count)``. The start: ``over`` is the
-    K that picks the first superstep's update and ``changed`` the vertices
-    it is counted over. CDLP's rows start blank, so its ``over`` is a K
-    above every rung and its first superstep a full gather, with no
-    ``changed`` to read; the BFS job's rows start as the fill every vertex
-    but the sources would gather, so its ``changed`` are the sources,
-    ``over`` the messages they send, and its first superstep a rewrite.
+    gives ``(new labels, changed, K, count)``. ``over`` is the K that picks
+    the first superstep's update: the rows start blank, so it is a K above
+    every rung and the first superstep a full gather.
     The stop: ``max_iter`` supersteps, or with ``until_quiet`` the first
     that moves nothing (its count is the one the host fetches anyway),
     whichever comes first. With ``dirty_modes`` (the
@@ -136,10 +133,13 @@ def step_carried_rows(
     ``"dirty"``) and ``dirty_rows`` / ``dirty_slots`` (``None`` where the
     reduce was full), one a superstep; with a ``clock`` also ``seconds``,
     the clock's reading after each fetch of K less the reading before it:
-    a superstep's seconds on the host's clock, at the wait the job has."""
+    a superstep's seconds on the host's clock, at the wait the job has.
+    The BFS job over carried rows steps itself
+    (``ops/paths.py:_frontier_job``): its levels choose between this
+    loop's updates and one that reads no row."""
     import jax
 
-    k = over
+    k, changed = over, None
     count, sent, branch, dirty = [], [], [], []
     marks = [clock()] if clock else []
     for _ in range(max_iter):
@@ -221,8 +221,9 @@ def admit_carried_rows(
     every superstep. A device that reports no limit admits ``carried``.
     ``reduce="min"`` asks for the BFS job over the same rows and index
     (``ops/paths.py``): its own programs (the gather, the row min, the top
-    rung's rewrite) and no histogram; ``plain`` is then one compiled
-    full-width level stepped from the host.
+    rung's rewrite, the bottom-up level at the rung below it) and no
+    histogram; ``plain`` is then one compiled full-width level stepped from
+    the host.
 
     The DEVICE's memory alone is sized. The host's is not: each program of
     the job compiles alone, and for graph500-24's plan (607.6 M slots, 58
@@ -230,13 +231,14 @@ def admit_carried_rows(
     the stateless program's 20 GB (PERF.md §6, PR 36); a job admitted here
     whose compile does not fit the host still ends there, minutes later.
     Every ``reason`` says so."""
-    top_rung = max(delta_rungs(int(plan.num_messages)), default=0)
-    need = carried_rows_inventory(
-        plan, top_rung=top_rung, shards=shards, reduce=reduce
+    rungs = delta_rungs(int(plan.num_messages))
+    sized = dict(
+        top_rung=max(rungs, default=0), shards=shards, reduce=reduce,
+        # the BFS job's bottom-up level never takes the top rung
+        bottom_up_rung=rungs[-2] if reduce == "min" and len(rungs) > 1 else 0,
     )
-    by_program = carried_job_transients(
-        plan, top_rung=top_rung, shards=shards, reduce=reduce
-    )
+    need = carried_rows_inventory(plan, **sized)
+    by_program = carried_job_transients(plan, **sized)
     largest = max(by_program, key=by_program.get)
     slots = need["carried_rows"] // 4
     if slots == 0 or slots >= _INT32_MAX:

@@ -2,10 +2,13 @@
 that follows its frontier over the carried bucket rows, the full-width level
 stepped from the host and the ``while_loop`` over the message arrays give the
 depths of the benchmark's plain reference (``benchmark/algorithms/bfs.py``,
-SciPy on its own CSR) and each other's, bit for bit; the one stepping loop
-takes BFS's start and stop as arguments and steps CDLP as it did; the
+SciPy on its own CSR) and each other's, bit for bit; a level that asks the
+unreached vertices for a reached neighbour gives them too, and the search
+takes it where the ladder says it is the cheaper (ISSUE 50); the one
+stepping loop takes BFS's stop as an argument and steps CDLP as it did; the
 admission answers for this job's own programs."""
 
+import importlib
 import importlib.util
 import os
 import sys
@@ -194,9 +197,15 @@ def test_every_branch_is_taken_and_the_record_says_so(monkeypatch):
     assert delta["rungs"] == list(rungs)
     assert delta["branch"][0] == "fill"  # the sources' slots, into the fill
     assert set(delta["branch"]) == {"fill", *rungs, "full"}
-    # a level's branch is the lowest rung its predecessor's K fits under
-    for k, taken in zip(delta["changed_messages"], delta["branch"][1:]):
-        assert taken == next((r for r in rungs if k <= r), "full")
+    # a level's branch is the lowest rung its predecessor's count fits under:
+    # the messages of the vertices it reached (K) for a top-down level, the
+    # edges of the vertices still unreached (U) for a bottom-up one
+    for k, u, way, taken in zip(delta["changed_messages"], delta["unreached_messages"],
+                                delta["direction"][1:], delta["branch"][1:]):
+        read = u if way == "bottom_up" else k
+        assert taken == next((r for r in rungs if read <= r), "full")
+        assert way == "top_down" or taken not in ("full", rungs[-1])
+    assert delta["direction"][0] == "top_down"
 
 
 def test_many_sources_start_with_a_full_gather(monkeypatch):
@@ -209,6 +218,140 @@ def test_many_sources_start_with_a_full_gather(monkeypatch):
     assert np.array_equal(got, _want(u, v, n, sources, 0))
     (delta,) = [r for r in sink.records if r["phase"] == "superstep_delta"]
     assert delta["branch"][0] == "full" and delta["source_messages"] > 64
+
+
+# -- the bottom-up level (ISSUE 50) ---------------------------------------------
+
+
+def _only_bottom_up(monkeypatch, num_messages):
+    """Every level of a search asks the unreached vertices, at the lowest of
+    three rungs their edges fit under (the top one holds every message)."""
+    from graphmine_tpu.ops import paths
+
+    rungs = (16, 512, num_messages)
+    monkeypatch.setattr(superstep_policy, "delta_rungs", lambda num_messages: rungs)
+    monkeypatch.setattr(
+        paths, "_next_update",
+        lambda k, u, rungs, stale: (sum(u > rung for rung in rungs), True))
+    return rungs
+
+
+BOTTOM_UP_CASES = ["path", "grid", "several_sources", "unreached_components",
+                   "isolated_source", "rmat_with_a_histogram_hub", "many_hubs"]
+
+
+@pytest.mark.parametrize("name", BOTTOM_UP_CASES)
+def test_bottom_up_levels_give_the_plain_references_depths(name, monkeypatch):
+    """A search of bottom-up levels alone: the reference's depths, a level a
+    superstep, on many levels, many sources, a graph whose other component
+    and isolated vertices keep U above 0, and graphs with histogram hubs,
+    whose messages have no slot (R-MAT's four; and eighty, hubs that are
+    each other's neighbours among them, with the hub cut patched down)."""
+    if name == "many_hubs":
+        # the module: ops/__init__ exports a function under its name
+        monkeypatch.setattr(
+            importlib.import_module("graphmine_tpu.ops.bucketed_mode"), "_HIST_MIN_DEG", 24)
+        u, v, n = _rmat(11, 8, seed=50)
+        sources, max_depth = [_lowest_with_an_edge(u, v)], 0
+    else:
+        u, v, n, sources, max_depth = _case(name)
+    g, plan = _fused(u, v, n)
+    hubs = 0 if plan.hist_vertex_ids is None else plan.hist_vertex_ids.shape[0]
+    assert hubs >= {"many_hubs": 40, "rmat_with_a_histogram_hub": 1}.get(name, 0)
+    rungs = _only_bottom_up(monkeypatch, plan.num_messages)
+    sink = MetricsSink()
+    got, levels = _run("frontier", g, plan, sources, max_depth, monkeypatch, sink)
+    want = _want(u, v, n, sources, max_depth)
+    assert np.array_equal(got, want)
+    assert levels == int(want[want != int(UNREACHABLE)].max()) + 1
+    validate_records(sink.records)
+    (delta,) = [r for r in sink.records if r["phase"] == "superstep_delta"]
+    assert delta["direction"] == ["bottom_up"] * levels
+    assert delta["reduce"] == ["none"] * levels and set(delta["dirty_slots"]) == {0}
+    deg = np.bincount(np.concatenate([u, v]), minlength=n)
+    unreached = [int(deg[want > d].sum()) for d in range(1, levels + 1)]
+    assert delta["unreached_messages"] == unreached
+    if name in ("unreached_components", "isolated_source"):
+        assert unreached[-1] > 0  # the other component's edges, to the end
+    before = [plan.num_messages - delta["source_messages"], *unreached]
+    assert delta["branch"] == [next(r for r in rungs if x <= r) for x in before[:-1]]
+
+
+def test_a_search_turns_once_and_issues_no_rewrite_after(monkeypatch):
+    """Top-down on rungs, one full gather, then bottom-up to the end: the
+    record says so, and over the stale rows no rewrite is issued."""
+    from graphmine_tpu.ops import paths
+
+    u, v, n, sources = _many_levels()
+    g, plan = _fused(u, v, n)
+    rungs = (40, 400, 1500, 5000)
+    monkeypatch.setattr(superstep_policy, "delta_rungs", lambda num_messages: rungs)
+    issued = []
+    for program in ("_gather_program", "_rewrite_program", "_level_program",
+                    "_unreached_program", "_bottom_up_program"):
+        def told(*a, _run=getattr(paths, program), _name=program, **k):
+            issued.append(_name.strip("_").removesuffix("_program"))
+            return _run(*a, **k)
+        monkeypatch.setattr(paths, program, told)
+    sink = MetricsSink()
+    got, levels = _run("frontier", g, plan, sources, 0, monkeypatch, sink)
+    assert np.array_equal(got, _want(u, v, n, sources, 0))
+    (delta,) = [r for r in sink.records if r["phase"] == "superstep_delta"]
+    turn = delta["direction"].index("bottom_up")
+    assert delta["direction"] == ["top_down"] * turn + ["bottom_up"] * (levels - turn)
+    assert delta["branch"][turn - 1] == "full" and delta["branch"].count("full") == 1
+    assert delta["branch"][0] == "fill" and turn >= 3 and levels - turn >= 2
+    assert set(delta["branch"][turn:]) <= set(rungs[:-1])
+    assert delta["reduce"] == ["full"] * turn + ["none"] * (levels - turn)
+    assert issued == (
+        ["rewrite", "level"] * (turn - 1) + ["gather", "level"]
+        + ["unreached", "bottom_up"] * (levels - turn))
+    assert len(set(delta["branch"][turn:])) >= 2  # a bottom-up program a rung taken
+
+
+def test_stale_rows_are_gathered_anew_where_that_is_the_cheaper(monkeypatch):
+    """After a bottom-up level the alternative is a full gather, never a
+    rewrite: a search made to turn early comes back through one."""
+    from graphmine_tpu.ops import paths
+
+    u, v, n, sources = _many_levels()
+    g, plan = _fused(u, v, n)
+    rule, asked = paths._next_update, []
+
+    def turn_early(k, u, rungs, stale):
+        asked.append(stale)
+        if len(asked) == 2:  # the second level, whatever it would cost
+            return len(rungs) - 1, True
+        return rule(k, u, rungs, stale)
+
+    monkeypatch.setattr(paths, "_next_update", turn_early)
+    monkeypatch.setattr(superstep_policy, "delta_rungs",
+                        lambda num_messages: (40, 400, 1500, num_messages))
+    sink = MetricsSink()
+    got, _ = _run("frontier", g, plan, sources, 0, monkeypatch, sink)
+    assert np.array_equal(got, _want(u, v, n, sources, 0))
+    (delta,) = [r for r in sink.records if r["phase"] == "superstep_delta"]
+    assert delta["direction"][:3] == ["top_down", "bottom_up", "top_down"]
+    assert delta["branch"][2] == "full" and asked[:4] == [False, False, True, False]
+
+
+@pytest.mark.parametrize("k, u, stale, want", [
+    (50, 10**6, False, (1, False)),    # K's rung, U far above it
+    (50, 60, False, (1, False)),       # the same rung: a rewrite's place is the cheaper
+    (50, 5, False, (0, True)),         # U's rung strictly below K's
+    (10**6, 900, False, (2, True)),    # in a full gather's place
+    (10**6, 3000, False, (4, False)),  # never on the top rung: the gather
+    (5, 900, True, (2, True)),         # stale rows: no rewrite, whatever K
+    (5, 3000, True, (4, False)),       # stale rows and U on the top rung: gather
+    (5, 10**6, True, (4, False)),
+])
+def test_the_rule_reads_k_u_and_the_ladder(k, u, stale, want):
+    from graphmine_tpu.ops.paths import _next_update
+
+    assert _next_update(k, u, (10, 100, 1000, 5000), stale) == want
+    # a ladder of one rung, or of none, never turns
+    assert _next_update(k, u, (10**5,), stale)[1] is False
+    assert _next_update(k, u, (), stale) == (0, False)
 
 
 def test_auto_takes_the_frontier_job_past_the_crossover_and_not_below_it():
@@ -313,28 +456,28 @@ def test_cdlps_arguments_step_as_they_did():
     }
 
 
-def test_the_start_and_the_stop_are_arguments_of_the_one_loop():
-    """BFS's: the first superstep rewrites the slots of the vertices handed
-    in, at the rung their K fits under, and the loop ends with the first
-    superstep that moves nothing, or at ``max_iter``."""
+def test_the_stop_is_an_argument_of_the_one_loop():
+    """BFS's, where its rows were not admitted: the loop ends with the first
+    superstep that moves nothing, or at ``max_iter``. (The search over
+    carried rows steps itself, ISSUE 50: the loop has no start to take.)"""
     calls, per_step = _stub_loop(
-        [50, 5000, 3, 0, 99], moved=[2, 40, 1, 0, 7], max_iter=9,
-        over=4, changed="sources", until_quiet=True)
+        [50, 5000, 3, 0, 99], moved=[2, 40, 1, 0, 7], max_iter=9, until_quiet=True)
     assert calls == [
-        ("rewrite", 10, "sources"), ("modes",),
+        ("gather",), ("modes",),
         ("rewrite", 100, "changed2"), ("modes",),
         ("gather",), ("modes",),
         ("rewrite", 10, "changed6"), ("modes",),
     ]
-    assert per_step["branch"] == [0, 1, 3, 0]
+    assert per_step["branch"] == [3, 1, 3, 0]
     assert per_step["changed_vertices"] == [2, 40, 1, 0]
-    cut, per_step = _stub_loop([50, 60, 70], max_iter=2, over=4, changed="sources",
-                               until_quiet=True)
+    cut, per_step = _stub_loop([50, 60, 70], max_iter=2, until_quiet=True)
     assert len(cut) == 4 and per_step["changed_messages"] == [50, 60]
     ticks = iter(range(100))
-    _, timed = _stub_loop([5, 0, 5], moved=[1, 0, 1], over=1, changed="sources",
-                          until_quiet=True, clock=lambda: next(ticks))
+    _, timed = _stub_loop([5, 0, 5], moved=[1, 0, 1], until_quiet=True,
+                          clock=lambda: next(ticks))
     assert timed["seconds"] == [1, 1]
+    with pytest.raises(TypeError, match="changed"):
+        _stub_loop([5], changed="sources")
 
 
 # -- the admission answers for this job ----------------------------------------
@@ -348,8 +491,17 @@ def test_the_admission_sizes_the_bfs_jobs_own_programs(monkeypatch):
     top = max(delta_rungs(plan.num_messages))
     cdlp = memmodel.carried_rows_inventory(plan, top_rung=top)
     need = memmodel.carried_rows_inventory(plan, top_rung=top, reduce="min")
-    programs = memmodel.carried_job_transients(plan, top_rung=top, reduce="min")
-    assert sorted(programs) == ["gather", "rewrite", "row_min"]
+    rungs = delta_rungs(plan.num_messages)
+    sized = dict(top_rung=top, reduce="min", bottom_up_rung=rungs[-2])
+    need = memmodel.carried_rows_inventory(plan, **sized)
+    programs = memmodel.carried_job_transients(plan, **sized)
+    assert sorted(programs) == ["bottom_up", "gather", "rewrite", "row_min"]
+    # the level that reads no row: cap-long vectors at the rung below the top
+    # one, which it never takes, beside the level's V-vectors
+    assert programs["bottom_up"] > 4 * 5 * rungs[-2] + 4 * 8 * n
+    assert programs["bottom_up"] < memmodel.carried_job_transients(
+        plan, top_rung=top, reduce="min", bottom_up_rung=top)["bottom_up"]
+    assert memmodel.carried_job_transients(plan, top, reduce="min")["bottom_up"] == 0
     assert cdlp["hub_histograms"] > 0 and need["hub_histograms"] == 0
     assert need["gather_transient"] == max(programs.values())
     for same in ("carried_rows", "slot_index", "labels", "changed_mask"):
@@ -359,6 +511,7 @@ def test_the_admission_sizes_the_bfs_jobs_own_programs(monkeypatch):
     room = {"bytes_limit": total, "bytes_in_use": 0}
     scan, reason = admit_carried_rows(plan, room, reduce="min")
     assert scan == "carried" and "row_min" in reason and "modes" not in reason
+    assert f"bottom_up {programs['bottom_up']} B" in reason
     assert admit_carried_rows(plan, room)[0] == "plain"  # CDLP's histograms do not fit
     room["bytes_limit"] -= 1
     assert admit_carried_rows(plan, room, reduce="min")[0] == "plain"

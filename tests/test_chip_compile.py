@@ -277,7 +277,8 @@ def test_carried_rows_programs_compile_for_v5e(
 
 
 @pytest.mark.parametrize("graph, program", [
-    *[("kronecker", p) for p in ("start", "gather", "rewrite", "level", "full_level")],
+    *[("kronecker", p) for p in ("start", "gather", "rewrite", "level", "full_level",
+                                 "unreached", "bottom_up")],
     # the rewrite is CDLP's under another scope, a minute a compile: once is enough
     *[("flat", p) for p in ("start", "gather", "level", "full_level")],
 ])
@@ -292,7 +293,11 @@ def test_bfs_job_programs_compile_for_v5e(
     temporary. The level reads the rows and writes V-sized results. Each
     program's temporaries are at or under what the admission counts for
     it (``carried_job_transients(..., reduce="min")``), on a skewed plan
-    with hubs, whose histograms this job never builds, and on a flat one."""
+    with hubs, whose histograms this job never builds, and on a flat one.
+    ISSUE 50's two: the compaction of the unreached vertices holds the one
+    sort of a bottom-up level, and the level itself, at the highest rung
+    it may take (the one below the top), holds none, reads no row (the
+    rows are not its argument) and writes V-sized results."""
     from graphmine_tpu.obs.memmodel import carried_job_transients
     from graphmine_tpu.ops import paths
     from graphmine_tpu.ops.bucketed_mode import row_slots, with_slot_index
@@ -301,10 +306,11 @@ def test_bfs_job_programs_compile_for_v5e(
     plan = fused_plan[1] if graph == "kronecker" else flat_plan
     plan = _shapes(with_slot_index(plan), one_chip)
     v, slots = planted[2], row_slots(plan)
-    top_rung = delta_rungs(plan.num_messages)[-1]
+    *_, turn_rung, top_rung = delta_rungs(plan.num_messages)
     shape = _shape_on(one_chip)
     rows, depth = shape((slots,)), shape((v,))
-    counted = carried_job_transients(plan, top_rung=top_rung, reduce="min")
+    counted = carried_job_transients(
+        plan, top_rung=top_rung, reduce="min", bottom_up_rung=turn_rung)
     if program == "start":
         compiled = _compile(paths._start_program, shape((1,)), plan.out_ptr,
                             slots=slots, num_vertices=v)
@@ -319,6 +325,13 @@ def test_bfs_job_programs_compile_for_v5e(
     elif program == "level":
         compiled = _compile(paths._level_program, rows, depth, plan)
         limit = counted["row_min"]
+    elif program == "unreached":  # the rewrite's sort less an operand
+        compiled = _compile(paths._unreached_program, depth, plan)
+        limit = counted["rewrite"]
+    elif program == "bottom_up":
+        compiled = _compile(paths._bottom_up_program, depth, depth, depth, depth,
+                            plan, cap=turn_rung)
+        limit = counted["bottom_up"]
     else:  # where the rows were not admitted: gathers, mins, keeps nothing
         compiled = _compile(paths._full_level_program, depth, plan)
         limit = counted["row_min"]
@@ -332,8 +345,50 @@ def test_bfs_job_programs_compile_for_v5e(
         assert held.alias_size_in_bytes == 0
     if program == "start":
         assert " gather(" not in text and held.output_size_in_bytes >= 4 * slots
+    assert (" sort(" in text) == (program in ("rewrite", "unreached"))
     if graph == "kronecker":  # wide classes, four hubs: well under the rows
         assert held.temp_size_in_bytes < 4 * slots // (4 if program != "level" else 1)
+
+
+def test_the_bottom_up_level_fits_its_count_at_graph500_24s_shapes(one_chip):
+    """The BFS cell's own plan, by shapes (``_proof/g500_24_shapes.json``),
+    at the highest rung a bottom-up level may take there, M/16 = 32.5 M
+    places: the compiler's temporaries are at or under what the admission
+    counts for it (ISSUE 50: 721,833,472 B against 1,317,999,352), and under
+    the top rung's rewrite, so the job's largest program is what it was.
+    The compare of every place against the sixty class offsets is fused into
+    each lookup: were it written out it would be ``[cap, 60]``, 1.9 GB and
+    more."""
+    import json
+    import os
+
+    from graphmine_tpu.obs.memmodel import carried_job_transients
+    from graphmine_tpu.ops import paths
+    from graphmine_tpu.ops.bucketed_mode import BucketedModePlan
+    from graphmine_tpu.ops.superstep_policy import delta_rungs
+
+    said = json.load(open(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "_proof", "g500_24_shapes.json")))
+    shape = _shape_on(one_chip)
+    v, m = said["num_vertices"], said["num_messages"]
+    plan = BucketedModePlan(
+        vertex_ids=tuple(shape((n,)) for n, _ in said["classes"]), msg_idx=None,
+        num_vertices=v, num_messages=m,
+        send_idx=tuple(shape((n, w)) for n, w in said["classes"]),
+        hist_vertex_ids=shape((said["hubs"],)), hist_send=shape((said["hist_send"],)),
+        hist_row_offset=shape((said["hist_row_offset"],)),
+        out_ptr=shape((v + 1,)), out_slot=shape((m,)),
+    )
+    *_, turn_rung, top_rung = delta_rungs(m)
+    assert turn_rung == m // 16
+    depth = shape((v,))
+    held = _compile(paths._bottom_up_program, depth, depth, depth, depth, plan,
+                    cap=turn_rung).memory_analysis()
+    counted = carried_job_transients(
+        plan, top_rung=top_rung, reduce="min", bottom_up_rung=turn_rung)
+    assert held.temp_size_in_bytes <= counted["bottom_up"] < counted["rewrite"]
+    assert held.temp_size_in_bytes < 4 * turn_rung * 8  # a few words a place
+    assert held.alias_size_in_bytes == 0
 
 
 @pytest.mark.parametrize("graph", ["kronecker", "flat"])
