@@ -26,7 +26,9 @@ The superstep family is whatever ``auto`` resolves, the driver pins none.
 The plan is built by the warm-up job and cached by the program per graph,
 so the timed jobs hold processing only, as LDBC Graphalytics separates
 loading from processing time. The warm-up job alone carries a
-``MetricsSink``.
+``MetricsSink``; every record the program wrote into it is handed on under
+``scope: "warmup"``, beside set-up's stages and a ``job`` record a timed job
+(``benchmark/handover.py``).
 """
 
 from __future__ import annotations
@@ -37,6 +39,7 @@ import time
 import numpy as np
 
 import generators
+import handover
 
 # A superstep is quiet when it moves the label of under this share of the
 # vertices that have an edge: a frontier would not run it at full width.
@@ -75,10 +78,12 @@ def setup(ctx) -> dict:
     t0 = time.perf_counter()
     graph = gm.build_graph(u, v, num_vertices=num_vertices)
     build_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
     touched = np.zeros(num_vertices, bool)
     touched[u] = True
     touched[v] = True
     vertices_with_edge = int(touched.sum())
+    count_s = time.perf_counter() - t0
     sink = MetricsSink()
     _, supersteps, warm_s = _timed(algorithm, graph, traffic, sink)  # builds the plan too
     plan_s = sum(r.get("seconds", 0.0) for r in sink.records
@@ -93,10 +98,10 @@ def setup(ctx) -> dict:
         "num_vertices": num_vertices, "graph": graph,
         "iterations": supersteps, "answer": None, "fixpoint_facts": {},
         "edges_plus_vertices": vertices_with_edge + len(u),
-        "setup_records": [
-            {"phase": "build_graph", "seconds": build_s, "scope": "setup"},
-            {"phase": "plan_build", "seconds": plan_s, "scope": "setup"},
-        ],
+        "setup_records": handover.stages(
+            build_graph=build_s, plan_build=plan_s, generate=gen_s,
+            count_vertices=count_s, warmup_job=warm_s),
+        "warmup_records": handover.warmup(sink.records),  # the program's, whole
     }
     if changed is not None:
         state["fixpoint_facts"] = {
@@ -106,8 +111,8 @@ def setup(ctx) -> dict:
         }
     ctx["say"](vertices=num_vertices, vertices_with_edge=vertices_with_edge,
                edges=len(u), algorithm=name, family=family, generate_s=gen_s,
-               build_graph_s=build_s, plan_build_s=plan_s, warmup_job_s=warm_s,
-               supersteps=supersteps, changed=changed)
+               build_graph_s=build_s, count_vertices_s=count_s, plan_build_s=plan_s,
+               warmup_job_s=warm_s, supersteps=supersteps, changed=changed)
     return state
 
 
@@ -123,11 +128,7 @@ def end_to_end(state, jobs, window_s: float) -> dict:
     return {"evps": state["edges_plus_vertices"] * len(jobs) / window_s}
 
 
-def records(state, jobs) -> list:
-    return state["setup_records"] + [
-        {"phase": "job", "seconds": j["seconds"], "scope": "job", "job": i}
-        for i, j in enumerate(jobs)
-    ]
+records = handover.records
 
 
 def facts(state) -> dict:

@@ -22,7 +22,9 @@ reference's child would not fit after the window. And the warm-up job's
 program records are handed on: ``facts()`` states what the device keeps for
 this graph between jobs (``resident_bytes``, from ``device_residency``)
 and how the plan's rows are padded (``padded_slots_per_message``, from
-``plan_build``), each left out where the program writes no such record.
+``plan_build``), each left out where the program writes no such record;
+``records()`` hands the records themselves on, whole, under ``scope:
+"warmup"`` (``benchmark/handover.py``).
 The memory lines of the log, those facts and the trim are
 ``kernel_job_large``'s own functions, loaded by path.
 """
@@ -55,7 +57,7 @@ def _by_path(name: str, path: str):
 # records, and the trim of the heap the compiler freed
 _large = _by_path("bench_drivers_kernel_job_large",
                   os.path.join(_HERE, "kernel_job_large.py"))
-_mesh_driver = _large._mesh_driver
+_mesh_driver, handover = _large._mesh_driver, _large.handover
 _memory, _program_facts = _large._memory, _large._program_facts
 
 
@@ -104,14 +106,16 @@ def setup(ctx) -> dict:
     graph = gm.build_graph(u, v, num_vertices=num_vertices)
     jax.block_until_ready(graph)  # the transfer is build_graph's, not the plan's
     build_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
     touched = np.zeros(num_vertices, bool)
     touched[u] = True
     touched[v] = True
     num_edges, with_edge = len(u), int(touched.sum())
     del u, v, touched  # the reference reads its own copy, in its own process
+    count_s = time.perf_counter() - t0
     ctx["say"](vertices=num_vertices, vertices_with_edge=with_edge, edges=num_edges,
                algorithm=name, generate_s=gen_s, build_graph_s=build_s,
-               memory=_memory(device))
+               count_vertices_s=count_s, memory=_memory(device))
     sink = MetricsSink()
     # builds or finds the plan, compiles or loads the program
     _, supersteps, warm_s = _timed(algorithm, graph, traffic, sink)
@@ -127,6 +131,9 @@ def setup(ctx) -> dict:
                device_residency={k: v for k, v in
                                  by_phase.get("device_residency", {}).items()
                                  if k not in ("phase", "t")},
+               superstep_delta={k: v for k, v in
+                                by_phase.get("superstep_delta", {}).items()
+                                if k not in ("phase", "t")},
                superstep_timing={k: v for k, v in
                                  by_phase.get("superstep_timing", {}).items()
                                  if k in ("op", "family", "window", "seconds")})
@@ -136,10 +143,10 @@ def setup(ctx) -> dict:
         "iterations": supersteps, "answer": None, "reference": None,
         "edges_plus_vertices": with_edge + num_edges,
         "program_facts": _program_facts(sink.records),
-        "setup_records": [
-            {"phase": "build_graph", "seconds": build_s, "scope": "setup"},
-            {"phase": "plan_build", "seconds": plan_s, "scope": "setup"},
-        ],
+        "setup_records": handover.stages(
+            build_graph=build_s, plan_build=plan_s, generate=gen_s,
+            count_vertices=count_s, warmup_job=warm_s),
+        "warmup_records": handover.warmup(sink.records),  # the program's, whole
     }
 
 

@@ -11,7 +11,8 @@ path. This driver names no algorithm. It differs in four things:
 - every timed job carries a fresh ``MetricsSink`` with a tracer, and the
   stage spans the program closed in it are handed on as records of scope
   ``job`` beside the ``job`` record (``phase: span``, the span's ``name``
-  and ``seconds``), for the reader ``phase_seconds``. A stage span ends in a
+  and ``seconds``), for the reader ``phase_seconds``, before the warm-up
+  job's records, which ``graph_kernel_job`` hands on. A stage span ends in a
   sync on its own outputs: microseconds of a job of seconds;
 - a file that states ``facts(records)`` is handed the warm-up job's records,
   and what it returns joins ``facts()``;
@@ -112,7 +113,8 @@ def job(state, index: int) -> dict:
 
 
 def records(state, jobs) -> list:
-    return _base.records(state, jobs) + [r for spans in state["job_spans"] for r in spans]
+    return _base.records(state, jobs,
+                         more=[r for spans in state["job_spans"] for r in spans])
 
 
 def facts(state) -> dict:

@@ -6,6 +6,12 @@ plan="auto")``): the superstep family is whatever ``auto`` resolves, the
 driver pins none. The plan is built by the warm-up job and cached by the
 program per graph, so the timed jobs hold processing only, as LDBC
 Graphalytics separates loading from processing time.
+
+The warm-up job alone carries a ``MetricsSink``. ``records()`` hands on
+set-up's stages by the harness's clock, a ``job`` record a timed job, and
+every record the program wrote into that sink, as it wrote it, under ``scope:
+"warmup"`` (``benchmark/handover.py``, every driver's); ``facts()`` states the
+carried-rows job's facts from the same records (``handover.program_facts``).
 """
 
 from __future__ import annotations
@@ -15,6 +21,7 @@ import time
 import numpy as np
 
 import generators
+import handover
 import references
 
 
@@ -43,9 +50,11 @@ def setup(ctx) -> dict:
     t0 = time.perf_counter()
     graph = gm.build_graph(u, v, num_vertices=num_vertices)
     build_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
     touched = np.zeros(num_vertices, bool)
     touched[u] = True
     touched[v] = True
+    count_s = time.perf_counter() - t0
     sink = MetricsSink()
     _, warm_s = _run(graph, traffic["iterations"], sink)  # builds the plan too
     plan_s = sum(r.get("seconds", 0.0) for r in sink.records
@@ -56,14 +65,16 @@ def setup(ctx) -> dict:
         "ctx": ctx, "u": u, "v": v, "num_vertices": num_vertices, "graph": graph,
         "iterations": traffic["iterations"], "labels": None,
         "edges_plus_vertices": int(touched.sum()) + len(u),
-        "setup_records": [
-            {"phase": "build_graph", "seconds": build_s, "scope": "setup"},
-            {"phase": "plan_build", "seconds": plan_s, "scope": "setup"},
-        ],
+        "setup_records": handover.stages(
+            build_graph=build_s, plan_build=plan_s, generate=gen_s,
+            count_vertices=count_s, warmup_job=warm_s),
+        # the warm-up job's records whole, and the carried-rows job's facts
+        "warmup_records": handover.warmup(sink.records),
+        "program_facts": handover.program_facts(sink.records),
     }
     ctx["say"](vertices=num_vertices, vertices_with_edge=int(touched.sum()),
                edges=len(u), family=family, generate_s=gen_s, build_graph_s=build_s,
-               plan_build_s=plan_s, warmup_job_s=warm_s)
+               count_vertices_s=count_s, plan_build_s=plan_s, warmup_job_s=warm_s)
     return state
 
 
@@ -77,17 +88,13 @@ def end_to_end(state, jobs, window_s: float) -> dict:
     return {"evps": state["edges_plus_vertices"] * len(jobs) / window_s}
 
 
-def records(state, jobs) -> list:
-    return state["setup_records"] + [
-        {"phase": "job", "seconds": j["seconds"], "scope": "job", "job": i}
-        for i, j in enumerate(jobs)
-    ]
+records = handover.records
 
 
 def facts(state) -> dict:
-    return {"num_vertices": state["num_vertices"],
-            "num_messages": 2 * len(state["u"]),
-            "iterations": state["iterations"]}
+    return dict(state["program_facts"], num_vertices=state["num_vertices"],
+                num_messages=2 * len(state["u"]),
+                iterations=state["iterations"])
 
 
 # -- correctness --------------------------------------------------------------

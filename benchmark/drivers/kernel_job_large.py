@@ -26,8 +26,10 @@ program records are handed on: ``facts()`` states what the device keeps for
 this graph between jobs (``device_residency``), which scan was admitted,
 how many supersteps rewrote rows instead of gathering them anew and what
 one that gathers every row anew costs (``superstep_delta``), and how the
-plan's rows are padded (``plan_build``). A program that writes no such
-record states no such fact.
+plan's rows are padded (``plan_build``): ``handover.program_facts``, which
+every driver of the carried-rows job states. A program that writes no such
+record states no such fact. ``records()`` hands the records themselves on
+too, whole, under ``scope: "warmup"`` (``benchmark/handover.py``).
 
 A program that sizes nothing against the device (it registers no
 ``device_residency`` record) is turned away before any input is made: at
@@ -41,7 +43,6 @@ import ctypes
 import importlib.util
 import inspect
 import os
-import statistics
 import time
 
 import numpy as np
@@ -53,7 +54,8 @@ _spec = importlib.util.spec_from_file_location(
     os.path.join(os.path.dirname(os.path.abspath(__file__)), "kernel_job_mesh.py"))
 _mesh_driver = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(_mesh_driver)
-_on_one_heap = _mesh_driver._on_one_heap
+_on_one_heap, handover = _mesh_driver._on_one_heap, _mesh_driver.handover
+_program_facts = handover.program_facts  # graph_kernel_job_large takes it by this name
 
 # what this driver asks of the program, checked before any input is made
 _NEEDS = {"label_propagation": ("max_iter", "plan", "sink"),
@@ -82,34 +84,6 @@ def _memory(device) -> dict:
     except (OSError, KeyError, ValueError):
         pass
     return said
-
-
-def _program_facts(records: list) -> dict:
-    """What the warm-up job's records say of the device, of the scan and of
-    the plan. Each fact is left out where its record, or the key it reads,
-    is missing."""
-    by_phase = {r["phase"]: r for r in records}
-    facts = {}
-    slots = by_phase.get("plan_build", {}).get("padded_slots_per_message")
-    if slots is not None:
-        facts["padded_slots_per_message"] = slots
-    held = by_phase.get("device_residency")
-    if held is not None:
-        facts["scan"] = held["scan"]
-        # what stays on the chip for this graph between jobs: the graph's
-        # arrays, the plan and its slot index (the rows are a job's own)
-        facts["resident_bytes"] = (held["graph_bytes"] + held["plan_bytes"]
-                                   + held["slot_index_bytes"])
-    delta = by_phase.get("superstep_delta")
-    if delta is not None:
-        facts["sparse_supersteps"] = sum(b != "full" for b in delta["branch"])
-        # the median: the first full superstep of a warm-up job loads its
-        # programs; the stateless scan writes no seconds
-        full = [s for s, b in zip(delta.get("seconds", ()), delta["branch"])
-                if b == "full"]
-        if full:
-            facts["full_superstep_seconds"] = statistics.median(full)
-    return facts
 
 
 def _hand_back_the_freed_heap() -> None:
@@ -162,13 +136,16 @@ def setup(ctx) -> dict:
     graph = gm.build_graph(u, v, num_vertices=num_vertices)
     jax.block_until_ready(graph)  # the transfer is build_graph's, not the plan's
     build_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
     touched = np.zeros(num_vertices, bool)
     touched[u] = True
     touched[v] = True
     num_edges, with_edge = len(u), int(touched.sum())
     del u, v, touched  # the reference reads its own copy, in its own process
+    count_s = time.perf_counter() - t0
     ctx["say"](vertices=num_vertices, vertices_with_edge=with_edge, edges=num_edges,
-               generate_s=gen_s, build_graph_s=build_s, memory=_memory(device))
+               generate_s=gen_s, build_graph_s=build_s, count_vertices_s=count_s,
+               memory=_memory(device))
     state = {
         "ctx": ctx, "num_edges": num_edges, "num_vertices": num_vertices,
         "graph": graph, "devices": [device], "iterations": iterations,
@@ -183,10 +160,10 @@ def setup(ctx) -> dict:
     plan_s = sum(r.get("seconds", 0.0) for r in sink.records
                  if r["phase"] == "plan_build")
     state["program_facts"] = _program_facts(sink.records)
-    state["setup_records"] = [
-        {"phase": "build_graph", "seconds": build_s, "scope": "setup"},
-        {"phase": "plan_build", "seconds": plan_s, "scope": "setup"},
-    ]
+    state["warmup_records"] = handover.warmup(sink.records)  # the program's, whole
+    state["setup_records"] = handover.stages(
+        build_graph=build_s, plan_build=plan_s, generate=gen_s,
+        count_vertices=count_s, warmup_job=warm_s)
     selected = by_phase.get("impl_selected", {})
     ctx["say"](family=selected.get("impl"), scan=selected.get("scan"),
                scan_reason=selected.get("scan_reason"), plan_build_s=plan_s,

@@ -8,7 +8,10 @@ plan="auto", mesh=mesh)``): the superstep family and the exchange are
 whatever ``auto`` resolves on that mesh, the driver pins none. The
 partition, its plan and the placement are made by the warm-up job and
 cached by the program per (graph, mesh), so the timed jobs hold processing
-only, as LDBC Graphalytics separates loading from processing time.
+only, as LDBC Graphalytics separates loading from processing time. What
+``records()`` hands on is ``kernel_job``'s (``benchmark/handover.py``), with
+the ``partition`` seconds among set-up's; ``facts()`` states the ``exchange``
+record's counts and the carried-rows job's facts.
 """
 
 from __future__ import annotations
@@ -21,6 +24,11 @@ import sys
 import time
 
 import numpy as np
+
+_BENCHMARK = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _BENCHMARK not in sys.path:  # this file is also its children's __main__
+    sys.path.insert(0, _BENCHMARK)
+import handover  # noqa: E402
 
 # glibc reads these at start-up only. One arena that serves every size from
 # one growing heap and never gives it back: freed blocks are reused, not
@@ -122,13 +130,15 @@ def setup(ctx) -> dict:
     # copy onto the mesh
     graph = gm.build_graph(u, v, num_vertices=num_vertices, to_device=False)
     build_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
     touched = np.zeros(num_vertices, bool)
     touched[u] = True
     touched[v] = True
     num_edges, with_edge = len(u), int(touched.sum())
     del u, v, touched  # the reference reads its own copy, in its own process
+    count_s = time.perf_counter() - t0
     ctx["say"](vertices=num_vertices, vertices_with_edge=with_edge, edges=num_edges,
-               generate_s=gen_s, build_graph_s=build_s)
+               generate_s=gen_s, build_graph_s=build_s, count_vertices_s=count_s)
     state = {
         "ctx": ctx, "num_edges": num_edges, "num_vertices": num_vertices,
         "graph": graph, "mesh": mesh, "devices": devices,
@@ -145,11 +155,12 @@ def setup(ctx) -> dict:
     state["shards"] = exchange.get("shards", 1)
     plan_s = by_phase.get("plan_build", {}).get("seconds", 0.0)
     partition_s = by_phase.get("partition", {}).get("seconds", 0.0)
-    state["setup_records"] = [
-        {"phase": "build_graph", "seconds": build_s, "scope": "setup"},
-        {"phase": "plan_build", "seconds": plan_s, "scope": "setup"},
-        {"phase": "partition", "seconds": partition_s, "scope": "setup"},
-    ]
+    state["setup_records"] = handover.stages(
+        build_graph=build_s, plan_build=plan_s, partition=partition_s,
+        generate=gen_s, count_vertices=count_s, warmup_job=warm_s)
+    # the warm-up job's records whole, and the carried-rows job's facts
+    state["warmup_records"] = handover.warmup(sink.records)
+    state["program_facts"] = handover.program_facts(sink.records)
     selected = by_phase.get("impl_selected", {})
     ctx["say"](shards=state["shards"], family=selected.get("impl"),
                reason=selected.get("reason"), partition_s=partition_s,
@@ -168,15 +179,12 @@ def end_to_end(state, jobs, window_s: float) -> dict:
     return {"evps": state["edges_plus_vertices"] * len(jobs) / window_s}
 
 
-def records(state, jobs) -> list:
-    return state["setup_records"] + [
-        {"phase": "job", "seconds": j["seconds"], "scope": "job", "job": i}
-        for i, j in enumerate(jobs)
-    ]
+records = handover.records
 
 
 def facts(state) -> dict:
-    return dict(state["exchange"], num_vertices=state["num_vertices"],
+    return dict(state["program_facts"], **state["exchange"],
+                num_vertices=state["num_vertices"],
                 num_messages=2 * state["num_edges"],
                 iterations=state["iterations"], chips=state["shards"])
 
@@ -212,5 +220,4 @@ def check(state, jobs, control: bool) -> list:
 
 
 if __name__ == "__main__":  # a child of _on_one_heap: no program, no chip
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     _TASKS[sys.argv[1]](sys.argv[2], **json.loads(sys.argv[3]))

@@ -2,7 +2,10 @@
 the string-domain parquet on disk to the published snapshot, every chapter
 on, in a warm process. Set-up writes the parquet and runs one untimed job.
 No superstep family, kNN path or environment override is pinned here: the
-program's planner chooses.
+program's planner chooses. ``records()`` hands on every record of every timed
+job's JSONL (``scope: "job"``), set-up's three stages by the harness's clock
+(``generate``, ``write_parquet``, ``warmup_job``) and every record of the
+warm-up job's JSONL (``scope: "warmup"``; ``benchmark/handover.py``).
 """
 
 from __future__ import annotations
@@ -16,6 +19,7 @@ import time
 import numpy as np
 
 import generators
+import handover
 import references
 
 _FALLBACK_PHASES = ("degrade", "retry", "mesh_degrade", "ivf_fallback")
@@ -61,6 +65,9 @@ def setup(ctx) -> dict:
     state = {"ctx": ctx, "src": src, "dst": dst, "is_anomaly": is_anomaly,
              "parquet": parquet, "result": None, "store": None}
     warm = job(state, "warmup")
+    state["setup_records"] = handover.stages(
+        generate=t1 - t0, write_parquet=t2 - t1, warmup_job=warm["seconds"])
+    state["warmup_records"] = handover.warmup(warm["records"])  # the program's, whole
     ctx["say"](rows=len(src), anomalies=int(is_anomaly.sum()), generate_s=t1 - t0,
                write_parquet_s=t2 - t1, warmup_job_s=warm["seconds"])
     return state
@@ -72,8 +79,9 @@ def end_to_end(state, jobs, window_s: float) -> dict:
 
 
 def records(state, jobs) -> list:
-    return [dict(r, scope="job", job=i)
-            for i, j in enumerate(jobs) for r in j["records"]]
+    return ([dict(r, scope="job", job=i)
+             for i, j in enumerate(jobs) for r in j["records"]]
+            + state.get("setup_records", []) + state.get("warmup_records", []))
 
 
 def facts(state) -> dict:

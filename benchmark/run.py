@@ -191,6 +191,7 @@ def main() -> int:
         return 2
     import jax
 
+    t_imported = time.perf_counter()
     devices = jax.devices()
     if not args.rehearse and (
         devices[0].platform != "tpu" or len(devices) < cell["chips"]
@@ -205,8 +206,14 @@ def main() -> int:
     from graphmine_tpu.compile_cache import enable_compile_cache
 
     cache_dir = enable_compile_cache()
+    import handover  # benchmark/handover.py: the records' one shape
+
+    started = {"process_start": t_imported - _T0,
+               "backend_start": time.perf_counter() - t_imported}
+    own_stages = handover.stages(**started)
     _listen_for_compiles()
-    say(workload=args.workload, seed=args.seed, device=device, cache_dir=cache_dir)
+    say(workload=args.workload, seed=args.seed, device=device, cache_dir=cache_dir,
+        **{phase + "_s": s for phase, s in started.items()})
 
     scratch = tempfile.mkdtemp(prefix="bench_")
     try:
@@ -259,8 +266,9 @@ def main() -> int:
 
         run = {
             "jobs": jobs, "window_s": window_s, "trace": trace, "device": device,
-            "memory": memory,
-            "records": driver.records(state, jobs), "facts": driver.facts(state),
+            "memory": memory, "setup_s": setup_s,
+            "records": driver.records(state, jobs) + own_stages,
+            "facts": driver.facts(state),
         }
         if args.trace:
             metrics = {}

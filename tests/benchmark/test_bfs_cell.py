@@ -20,7 +20,7 @@ CELL, CONFIG, TRAFFIC = "bfs-g500-24", "graphalytics-g500-24-bfs", "bfs-batch-la
 SHARED = ("evps", "superstep_ms", "device_idle_share.kernel", "graph_build_s.setup",
           "peak_hbm_share.kernel", "plan_resident_gb", "plan_slots_per_message")
 OWN = ("bfs_levels", "bfs_sparse_level_share", "bfs_full_level_ms",
-       "bfs_roofline_share")
+       "bfs_roofline_share", "bfs_bottom_up_level_share")
 UNREACHED = 9223372036854775807
 
 

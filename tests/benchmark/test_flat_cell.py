@@ -14,7 +14,8 @@ import sys
 import numpy as np
 import pytest
 
-from _bench import BENCH_DIR, Bench, lines as _lines, load as _load, run as _run
+from _bench import (BENCH_DIR, SETUP_ACCOUNT, Bench, lines as _lines, load as _load,
+                    run as _run)
 from _bench import bench, grown_root  # noqa: F401  (fixtures)
 
 RUN = os.path.join(BENCH_DIR, "run.py")
@@ -198,8 +199,9 @@ def test_the_cell_rehearses_and_every_listed_metric_is_read(trace, request):
     if trace == "1":
         metrics = last["metrics"]
         # nine per layer, less the two a CPU cannot read (a trace of the
-        # device, the allocator's peak)
-        assert set(metrics) == {*SHARED, *WITH_THE_SIBLING, *NEW_COUNTERS} - {
+        # device, the allocator's peak), and set-up's account (PR 51)
+        assert set(metrics) == {*SHARED, *WITH_THE_SIBLING, *NEW_COUNTERS,
+                                *SETUP_ACCOUNT} - {
             "evps", "superstep_roofline_share", "device_idle_share.kernel",
             "peak_hbm_share.kernel"}
         assert metrics["full_superstep_ms"] == {

@@ -21,6 +21,8 @@ NEW_METRICS = ("peak_hbm_share.kernel", "plan_resident_gb",
                "cdlp_sparse_superstep_share")
 # two that PR 38 brought for the flat sibling and that read here too
 WIDER_FACTS = ("full_superstep_ms", "plan_slots_per_message")
+# two that read the handed-over `superstep_delta` record itself (PR 51)
+FROM_THE_RECORD = ("sparse_superstep_ms", "cdlp_dirty_slot_share")
 _json = Bench().data
 
 
@@ -68,10 +70,11 @@ def test_the_cell_is_one_chip_under_the_large_batch_traffic(bench):
     traffic = bench.data("traffic", TRAFFIC + ".json")
     small = bench.data("traffic", "cdlp-batch.json")
     assert traffic == dict(small, driver="kernel_job_large")  # the loop rule word for word
-    assert bench.reported_by(CELL) == {*SHARED, *NEW_METRICS, *WIDER_FACTS}
-    for name in (*SHARED, *NEW_METRICS, *WIDER_FACTS):
+    assert bench.reported_by(CELL) == {*SHARED, *NEW_METRICS, *WIDER_FACTS,
+                                       *FROM_THE_RECORD}
+    for name in (*SHARED, *NEW_METRICS, *WIDER_FACTS, *FROM_THE_RECORD):
         assert bench.lists(name, CELL), name
-    for name in (*NEW_METRICS, *WIDER_FACTS):
+    for name in (*NEW_METRICS, *WIDER_FACTS, *FROM_THE_RECORD):
         assert bench.metric(name)["moves"] == "evps"
     assert bench.reader_of("peak_hbm_share.kernel") == {"reader": "peak_memory_share"}
     assert bench.reader_of("plan_resident_gb") == {

@@ -27,6 +27,12 @@ OWN = {
         "fact": "bytes_per_superstep", "scale": 1e-06}},
     "shard_message_imbalance": {"reader": "fact_value", "args": {
         "fact": "messages_per_shard_max", "over": "messages_per_shard_mean"}},
+    # the carried-rows job's facts, which the one-chip CDLP cells brought and
+    # this driver states too since PR 51 (`handover.program_facts`)
+    "cdlp_sparse_superstep_share": {"reader": "fact_value", "args": {
+        "fact": "sparse_supersteps", "over": "iterations", "scale": 100.0}},
+    "full_superstep_ms": {"reader": "fact_value", "args": {
+        "fact": "full_superstep_seconds", "scale": 1000.0}},
 }
 
 sys.path.insert(0, BENCH_DIR)
