@@ -219,11 +219,13 @@ register("superstep_delta", "op", "changed_vertices", "changed_messages",
 # `nbytes` (0 for a host-resident graph; the plan without its slot index),
 # `slot_index_bytes` the index's, `rows_bytes` the carried rows' (one
 # buffer a job, updated in place; 0 under `plain`), `labels_bytes` labels
-# in and out, `code_bytes` the executable's size where one reports it
-# (None: a jitted call hands none back). Arrays only: what a compiled
+# in and out. Arrays only: what a compiled
 # program takes beside them while it runs (its temporaries, which the
 # allocator's `peak_bytes_in_use` leaves out too) is in no field; under
-# `carried` the `reason` holds the admission's count of it. `scan` is the admission's answer (`carried` |
+# `carried` the `reason` holds the admission's count of it, and the job's
+# `program_memory` records the compiler's, beside the programs' code (this
+# record is written before anything is compiled and has no field for it).
+# `scan` is the admission's answer (`carried` |
 # `plain`, ops/superstep_policy.admit_carried_rows) and `reason` its
 # arithmetic, of the device's memory alone. Benchmark metric
 # `plan_resident_gb` reads it. The mesh entry's record (PR 39:
@@ -241,7 +243,40 @@ register("superstep_delta", "op", "changed_vertices", "changed_messages",
 # host steps, beside the device's free bytes, and whether they fit.
 register("device_residency", "op", "scan", "reason", "bytes_limit",
          "graph_bytes", "plan_bytes", "rows_bytes", "slot_index_bytes",
-         "labels_bytes", "code_bytes")
+         "labels_bytes")
+
+# program_memory (PR 52): one for each compiled program a job under a sink
+# ran (ops/superstep_policy.emit_program_memory, the one writer; the
+# entries of ops/lpa.py, ops/paths.py, ops/pagerank.py, ops/cc.py and
+# ops/triangles.py call it when the job ends), in the order the job first
+# ran them: what the program's own executable says it takes of the chip
+# (`memory_analysis()`), integers: `code_bytes` (the generated code,
+# resident from the load on), `temp_bytes` (what it holds beside its
+# arguments and results while it runs: in no allocator statistic),
+# `argument_bytes`, `output_bytes`, `alias_bytes` (arguments it writes its
+# results into: a donated buffer). On a mesh they are ONE chip's and the
+# record says `shards`. `op` is `device_residency`'s; `program` the job's
+# own word (`gather`, `rewrite`, `modes`, `dirty_modes`, `blank_rows`; the
+# BFS job's `start`, `level`, `unreached`, `bottom_up`, `full_level`;
+# PageRank's `start` and `iteration`; `loop` for a fixpoint in one program,
+# `scan` for a stated count of supersteps in one; LCC's `core`, `tail`,
+# `by_id`) and beside it the program's static arguments (`cap`, `marked`;
+# `w`, `nb`, `ne`; `max_iter`), which tell two programs of one name apart.
+# `reckoned_temp_bytes`, where the admission's model counts this very
+# program (obs/memmodel.carried_job_transients by program, the rewrite and
+# the bottom-up level at the one rung they are reckoned at, the hubs'
+# histograms with the two programs that hold them; row_sum_transients for
+# PageRank's iteration), is that count: the
+# compiler's stands beside it in `temp_bytes`. The executable is asked once
+# a (plan, program), after the program's first call, of the lowering and
+# executable that call left in jit's caches (nothing compiles or loads
+# again); a later job on the same plan copies the answers and says
+# `cached: True`. The last record of a job says `asked_s`: the seconds the
+# job spent asking and writing. Benchmark metrics `program_code_gb`,
+# `program_temp_peak_gb` and `admission_temp_overcount_gb` read the warm-up
+# job's.
+register("program_memory", "op", "program", "code_bytes", "temp_bytes",
+         "argument_bytes", "output_bytes", "alias_bytes", "cached")
 
 # memory_watermark (ISSUE 14): predicted-vs-measured HBM/RSS for one
 # operating point, emitted by obs/memmodel.emit_memory_watermark (the

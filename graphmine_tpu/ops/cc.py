@@ -129,7 +129,8 @@ def connected_components(
     records (see ``label_propagation``), and every call one ``fixpoint``
     record: the supersteps it took and how many labels each one moved
     (the last entry is the confirming pass's 0 unless ``max_iter`` cut
-    the run short).
+    the run short), and one ``program_memory`` record: what the loop's
+    executable takes of the chip (``label_propagation``'s).
     """
     if isinstance(plan, str) and plan == "auto":
         from graphmine_tpu.ops.lpa import _cached_auto_plan
@@ -164,8 +165,17 @@ def connected_components(
             timed_fixpoint,
         )
 
+        from graphmine_tpu.ops.superstep_policy import (
+            emit_program_memory,
+            noting,
+            plan_anchor,
+            program_log,
+        )
+
+        programs = program_log(sink, plan_anchor(graph, plan))
+        loop = noting(programs, "loop", _connected_components, max_iter=max_iter)
         (labels, iters, changed), secs, cold = timed_fixpoint(
-            lambda: _connected_components(graph, max_iter, True, plan),
+            lambda: loop(graph, max_iter, True, plan),
         )
         iters = int(iters)
         # weighted=False explicitly: CC's min ignores the weight payload
@@ -184,6 +194,7 @@ def connected_components(
             changed=np.asarray(changed)[:iters].tolist(),
             num_vertices=graph.num_vertices, family=cost.family,
         )
+        emit_program_memory(sink, "cc_superstep", programs)
         if return_iterations:
             return labels, iters
         return labels

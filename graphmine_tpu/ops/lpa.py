@@ -110,7 +110,12 @@ def label_propagation(
     a fused plan's rows one ``superstep_delta`` record (per superstep: the
     branch taken, the labels moved, the messages their vertices send, and
     its seconds on the host's clock, read where the host fetches the
-    count; all ``full`` and no seconds where the rows were not admitted).
+    count; all ``full`` and no seconds where the rows were not admitted),
+    and every job one ``program_memory`` record for each compiled program
+    it ran: what the executable takes of the chip (code, temporaries,
+    arguments), beside the admission's count of its temporaries where it
+    has one (:func:`~graphmine_tpu.ops.superstep_policy.
+    emit_program_memory`; asked once a plan, copied after).
 
     On one device the graph may be host-resident too
     (``build_graph(..., to_device=False)``): the fused plan is built from
@@ -162,6 +167,11 @@ def label_propagation(
     from graphmine_tpu.ops.superstep_policy import (
         emit_device_residency,
         emit_plan_records,
+        emit_program_memory,
+        noting,
+        plan_anchor,
+        program_log,
+        reckoned_temp_bytes,
         select_superstep_family,
     )
 
@@ -226,7 +236,14 @@ def label_propagation(
             timed_fixpoint,
         )
 
-        timed = {"clock": time.perf_counter} if carried else {}
+        programs = program_log(
+            sink, plan_anchor(graph, plan),
+            partial(reckoned_temp_bytes, plan) if carried else None,
+        )
+        if carried:
+            timed = {"clock": time.perf_counter, "programs": programs}
+        else:
+            job, timed = noting(programs, "scan", job, max_iter=max_iter), {}
         (labels, per_step), secs, cold = timed_fixpoint(
             lambda: job(graph, max_iter, init_labels, plan, **timed),
         )
@@ -245,6 +262,7 @@ def label_propagation(
                 sink, per_step, plan.num_messages,
                 _plan_rows_and_slots(plan.send_idx),
             )
+        emit_program_memory(sink, "lpa_superstep", programs)
     else:
         labels, per_step = job(graph, max_iter, init_labels, plan)
     if return_history:
@@ -419,13 +437,18 @@ def _mesh_label_propagation(graph, mesh, max_iter, init_labels, plan, sink):
     run the one compiled program."""
     from graphmine_tpu.ops.superstep_policy import (
         crossover_thresholds,
+        emit_program_memory,
         emit_shard_residency,
+        noting,
+        program_log,
+        reckoned_temp_bytes,
         select_superstep_family,
     )
     from graphmine_tpu.parallel.sharded import (
+        _sharded_lpa_jit,
         carried_label_propagation,
         shard_messages,
-        sharded_label_propagation,
+        shard_plan_shapes,
     )
 
     if not isinstance(plan, str):
@@ -470,22 +493,39 @@ def _mesh_label_propagation(graph, mesh, max_iter, init_labels, plan, sink):
             )
             emit_shard_residency(sink, "lpa_superstep", sg, mesh, scan)
         sink.emit("exchange", op="lpa_superstep", family=family, **stats["exchange"])
+    # what the partition's cache keys on stays with the graph; the placed
+    # shards are this (graph, mesh, family)'s own
+    anchor = jax.tree.leaves(sg)[0]
     if scan[0] == "carried":
+        programs = program_log(
+            sink, anchor, shards=sg.num_shards,
+            reckoned=lambda: reckoned_temp_bytes(
+                shard_plan_shapes(sg, shard_messages(sg)), shards=sg.num_shards
+            ),
+        )
         labels, per_step = carried_label_propagation(
             sg, mesh, max_iter, init_labels,
             clock=time.perf_counter if sink is not None else None,
+            programs=programs,
         )
         if sink is not None:
             _emit_superstep_delta(
                 sink, per_step, shard_messages(sg),
                 _plan_rows_and_slots(sg.bucket_send), shards=sg.num_shards,
             )
+            emit_program_memory(sink, "lpa_superstep", programs)
         return labels
     if sg.out_slot is not None:  # the one program is handed what it reads
         import dataclasses
 
         sg = dataclasses.replace(sg, out_ptr=None, out_slot=None)
-    return sharded_label_propagation(sg, mesh, max_iter, init_labels)
+    # sharded_label_propagation's one program, no tripwire and no telemetry
+    programs = program_log(sink, anchor, shards=sg.num_shards)
+    labels = noting(programs, "scan", _sharded_lpa_jit, max_iter=max_iter)(
+        sg, mesh, max_iter, init_labels, 0, False
+    )
+    emit_program_memory(sink, "lpa_superstep", programs)
+    return labels
 
 
 def _cached_mesh_partition(graph: Graph, mesh, family: str):
@@ -711,7 +751,7 @@ def _dirty_modes_program(rows, labels, dirty, plan):
 
 
 def _carried_rows_job(
-    graph: Graph, max_iter: int, init_labels, plan, clock=None
+    graph: Graph, max_iter: int, init_labels, plan, clock=None, programs=None
 ):
     """``(labels, per_step)`` of ``max_iter`` supersteps over a fused plan
     with its slot index, stepped from the host
@@ -733,9 +773,15 @@ def _carried_rows_job(
     argument. ``per_step`` holds ``changed_vertices``,
     ``changed_messages`` and ``branch`` (the rung's place, or
     ``len(rungs)`` for a full gather), one a superstep; with a ``clock``
-    (the caller's, where a sink wants them) also ``seconds``."""
+    (the caller's, where a sink wants them) also ``seconds``. ``programs``
+    (the caller's too, :class:`~graphmine_tpu.ops.superstep_policy.
+    ProgramLog`) notes each program the job runs."""
     from graphmine_tpu.ops.bucketed_mode import check_plan_fits, row_slots
-    from graphmine_tpu.ops.superstep_policy import delta_rungs, step_carried_rows
+    from graphmine_tpu.ops.superstep_policy import (
+        delta_rungs,
+        noting,
+        step_carried_rows,
+    )
 
     labels = (
         jnp.arange(graph.num_vertices, dtype=jnp.int32)
@@ -743,18 +789,24 @@ def _carried_rows_job(
         else jnp.asarray(init_labels).astype(jnp.int32)
     )
     check_plan_fits(labels, graph, plan)
+    slots = row_slots(plan)
+    blank = noting(programs, "blank_rows", _blank_rows, slots=slots)
+    gather = noting(programs, "gather", _gather_program)
+    rewrite = noting(programs, "rewrite", _rewrite_program)
+    modes = noting(programs, "modes", _modes_program)
+    dirty_modes = noting(programs, "dirty_modes", _dirty_modes_program)
     return step_carried_rows(
         max_iter, delta_rungs(plan.num_messages), plan.num_messages + 1,
-        _blank_rows(row_slots(plan)), labels,
-        gather=lambda rows, labels: _gather_program(rows, labels, plan),
-        rewrite=lambda rows, labels, changed, cap, marked=False: _rewrite_program(
+        blank(slots), labels,
+        gather=lambda rows, labels: gather(rows, labels, plan),
+        rewrite=lambda rows, labels, changed, cap, marked=False: rewrite(
             rows, labels, changed, plan, cap=cap, marked=marked
         ),
-        modes=lambda rows, labels: _modes_program(rows, labels, plan),
+        modes=lambda rows, labels: modes(rows, labels, plan),
         # a weighted plan's weights are a matrix a class: its job keeps the
         # full reduce (bucketed_mode.lpa_modes_from_dirty_rows)
         dirty_modes=None if plan.weight_mat is not None else (
-            lambda rows, labels, dirty: _dirty_modes_program(rows, labels, dirty, plan)
+            lambda rows, labels, dirty: dirty_modes(rows, labels, dirty, plan)
         ),
         clock=clock,
     )

@@ -112,10 +112,21 @@ def pagerank(
         raise ValueError(
             f"plan must be 'auto', None or a BucketedModePlan; got {plan!r}"
         )
+    from graphmine_tpu.obs.memmodel import row_sum_transients
+    from graphmine_tpu.ops.superstep_policy import (
+        emit_program_memory,
+        noting,
+        plan_anchor,
+        program_log,
+    )
+
     traced = isinstance(graph.msg_ptr, jax.core.Tracer)
     if directed:
         plan = None
-        run = lambda: _pagerank(graph, alpha, max_iter, tol, reset, weights)
+        programs = program_log(sink, graph.msg_ptr)
+        run = lambda: noting(programs, "loop", _pagerank, max_iter=max_iter)(
+            graph, alpha, max_iter, tol, reset, weights
+        )
     else:
         if weights is not None:
             raise ValueError(
@@ -126,8 +137,13 @@ def pagerank(
             plan = None if traced else _auto_inflow_plan(graph, sink)
         if plan is not None and plan.send_idx is None:
             plan = None  # non-fused plan: no sender indices to sum over
+        # the one program the admission's model counts: the stepped iteration
+        reckoned = None if plan is None else (
+            lambda: {("iteration", None): row_sum_transients(plan)}
+        )
+        programs = program_log(sink, plan_anchor(graph, plan), reckoned)
         run = lambda: _pagerank_messages(
-            graph, plan, alpha, max_iter, tol, reset
+            graph, plan, alpha, max_iter, tol, reset, programs
         )
     if sink is None or traced:
         return run()[0]
@@ -151,6 +167,7 @@ def pagerank(
         sink, "pagerank_inflow", cost, iters, iters, secs,
         graph.num_edges, variant="fused", cold_compile=cold,
     )
+    emit_program_memory(sink, "pagerank_inflow", programs)
     return pr
 
 
@@ -326,7 +343,7 @@ def _stepped_start(graph, reset):
     )
 
 
-def _stepped_pagerank(graph, plan, alpha, max_iter, tol, reset):
+def _stepped_pagerank(graph, plan, alpha, max_iter, tol, reset, programs=None):
     """``(ranks, iterations)`` over a fused plan, one compiled iteration
     (:func:`_bucketed_iteration`) stepped from the host, as
     ``ops/lpa.py:_carried_rows_job`` steps its supersteps, and for the
@@ -337,11 +354,15 @@ def _stepped_pagerank(graph, plan, alpha, max_iter, tol, reset):
     its largest class (PERF.md §6, PR 41). With a stated count the host
     reads nothing between iterations: ten dispatches, one wait. With a
     ``tol`` it reads the delta once an iteration. ``max_iter`` is the
-    length of this loop and no program's argument."""
-    pr, inv_out, reset_v = _stepped_start(graph, reset)
+    length of this loop and no program's argument. ``programs`` (the
+    caller's ``ProgramLog``) notes the two programs."""
+    from graphmine_tpu.ops.superstep_policy import noting
+
+    iteration = noting(programs, "iteration", _bucketed_iteration)
+    pr, inv_out, reset_v = noting(programs, "start", _stepped_start)(graph, reset)
     iterations = 0
     while iterations < max_iter:
-        pr, delta = _bucketed_iteration(
+        pr, delta = iteration(
             pr, inv_out, reset_v, plan, alpha, with_delta=tol is not None
         )
         iterations += 1
@@ -350,11 +371,15 @@ def _stepped_pagerank(graph, plan, alpha, max_iter, tol, reset):
     return pr, iterations
 
 
-def _pagerank_messages(graph, plan, alpha, max_iter, tol, reset):
+def _pagerank_messages(graph, plan, alpha, max_iter, tol, reset, programs=None):
     """The message reading: ``(ranks, iterations)`` on the family ``plan``
-    names (``None``: ``sort``)."""
+    names (``None``: ``sort``); ``programs`` notes the programs it runs."""
+    from graphmine_tpu.ops.superstep_policy import noting
+
     if plan is None:
-        return _pagerank_messages_jit(graph, None, alpha, max_iter, tol, reset)
+        return noting(programs, "loop", _pagerank_messages_jit)(
+            graph, None, alpha, max_iter, tol, reset
+        )
     if (
         plan.num_vertices != graph.num_vertices
         or plan.num_messages != graph.num_messages
@@ -366,7 +391,7 @@ def _pagerank_messages(graph, plan, alpha, max_iter, tol, reset):
         )
     if not jax.core.trace_ctx.is_top_level():  # a caller's jit: no host steps
         return _pagerank_messages_jit(graph, plan, alpha, max_iter, tol, reset)
-    return _stepped_pagerank(graph, plan, alpha, max_iter, tol, reset)
+    return _stepped_pagerank(graph, plan, alpha, max_iter, tol, reset, programs)
 
 
 def _validate_sources(sources, v: int) -> np.ndarray:

@@ -99,7 +99,9 @@ def bfs_distances(
     its direction, the branch taken, the vertices reached, the messages
     they send, the edges of the vertices still unreached, its seconds at
     the host's one wait) and every host-stepped job one
-    ``fixpoint`` record (the supersteps and the vertices each reached).
+    ``fixpoint`` record (the supersteps and the vertices each reached);
+    every job one ``program_memory`` record for each compiled program it ran
+    (what the executable takes of the chip; ``label_propagation``'s).
     """
     from graphmine_tpu.ops.lpa import _under_a_trace
 
@@ -116,17 +118,38 @@ def bfs_distances(
         from graphmine_tpu.ops.lpa import _cached_slot_index
 
         plan, _, scan = _cached_slot_index(plan, reduce="min")
+    from graphmine_tpu.ops.superstep_policy import (
+        emit_program_memory,
+        noting,
+        plan_anchor,
+        program_log,
+        reckoned_temp_bytes,
+    )
+
     if plan is None or not plan.send_idx:
-        dist, levels = _bfs_loop(graph, sources, direction, max_depth)
+        programs = program_log(sink, graph.msg_ptr)
+        dist, levels = noting(
+            programs, "loop", _bfs_loop, direction=direction, max_depth=max_depth
+        )(graph, sources, direction, max_depth)
     else:
         limit = max_depth if max_depth > 0 else graph.num_vertices + 1
         clock = time.perf_counter if sink is not None else None
         if plan.out_slot is not None:
-            dist, per_step = _frontier_job(graph, sources, limit, plan, clock)
+            programs = program_log(
+                sink, plan_anchor(graph, plan),
+                partial(reckoned_temp_bytes, plan, reduce="min"),
+            )
+            dist, per_step = _frontier_job(
+                graph, sources, limit, plan, clock, programs
+            )
         else:
-            dist, per_step = _full_width_job(graph, sources, limit, plan, clock)
+            programs = program_log(sink, plan_anchor(graph, plan))
+            dist, per_step = _full_width_job(
+                graph, sources, limit, plan, clock, programs
+            )
         levels = len(per_step["changed_vertices"])
         _emit_job_records(sink, graph, plan, per_step)
+    emit_program_memory(sink, "bfs_level", programs)
     return (dist, levels) if return_levels else dist
 
 
@@ -310,7 +333,7 @@ def _next_update(k: int, u: int, rungs: tuple, stale: bool) -> tuple:
     return place, False
 
 
-def _frontier_job(graph: Graph, sources, limit: int, plan, clock=None):
+def _frontier_job(graph: Graph, sources, limit: int, plan, clock=None, programs=None):
     """``(depths, per_step)`` of a search that follows its frontier over a
     fused plan with its slot index, stepped from the host: the rows start as
     a fill, and every level takes the update :func:`_next_update` picks from
@@ -331,12 +354,19 @@ def _frontier_job(graph: Graph, sources, limit: int, plan, clock=None):
     ``changed_messages`` (K), ``unreached_messages`` (U), ``direction``,
     ``branch`` (the place of the rung K fits under, or U on a bottom-up
     level; ``len(rungs)`` for a full gather), with a ``clock`` ``seconds``;
-    and ``source_messages``, the K that picked the first level's rung."""
+    and ``source_messages``, the K that picked the first level's rung.
+    ``programs`` (the caller's ``ProgramLog``) notes each program run."""
     from graphmine_tpu.ops.bucketed_mode import check_plan_fits, row_slots
-    from graphmine_tpu.ops.superstep_policy import delta_rungs
+    from graphmine_tpu.ops.superstep_policy import delta_rungs, noting
 
+    start = noting(programs, "start", _start_program)
+    unreached = noting(programs, "unreached", _unreached_program)
+    bottom_up_level = noting(programs, "bottom_up", _bottom_up_program)
+    gather = noting(programs, "gather", _gather_program)
+    rewrite = noting(programs, "rewrite", _rewrite_program)
+    level = noting(programs, "level", _level_program)
     rungs = delta_rungs(plan.num_messages)
-    rows, depth, reached, *counts = _start_program(
+    rows, depth, reached, *counts = start(
         sources, plan.out_ptr, slots=row_slots(plan), num_vertices=plan.num_vertices
     )
     check_plan_fits(depth, graph, plan)
@@ -351,18 +381,18 @@ def _frontier_job(graph: Graph, sources, limit: int, plan, clock=None):
         place, bottom_up = _next_update(k, u, rungs, stale=rows is None)
         if bottom_up:
             rows = None  # stale from here on: the device has their room back
-            spans = _unreached_program(depth, plan)
-            depth, reached, *counts = _bottom_up_program(
+            spans = unreached(depth, plan)
+            depth, reached, *counts = bottom_up_level(
                 depth, *spans, plan, cap=rungs[place]
             )
         else:
             if rows is None:  # the gather writes every slot: any rows will do
                 rows = jnp.empty((row_slots(plan),), jnp.int32)
             if place == len(rungs):
-                rows = _gather_program(rows, depth, plan)
+                rows = gather(rows, depth, plan)
             else:
-                rows = _rewrite_program(rows, depth, reached, plan, cap=rungs[place])
-            depth, reached, *counts = _level_program(rows, depth, plan)
+                rows = rewrite(rows, depth, reached, plan, cap=rungs[place])
+            depth, reached, *counts = level(rows, depth, plan)
         k, moved, u = (int(x) for x in jax.device_get(counts))  # the one wait
         said = (moved, k, u, "bottom_up" if bottom_up else "top_down", place)
         for name, value in zip(names, said):
@@ -376,7 +406,7 @@ def _frontier_job(graph: Graph, sources, limit: int, plan, clock=None):
     return depth, dict(per_step, source_messages=source_messages)
 
 
-def _full_width_job(graph: Graph, sources, limit: int, plan, clock=None):
+def _full_width_job(graph: Graph, sources, limit: int, plan, clock=None, programs=None):
     """``(depths, per_step)`` where the rows were not admitted: one compiled
     full-width level stepped from the host until it reaches nothing (never
     a ``while_loop`` over the plan's classes, whose temporaries the chip's
@@ -385,13 +415,14 @@ def _full_width_job(graph: Graph, sources, limit: int, plan, clock=None):
     ``per_step`` holds ``changed_vertices`` and, with a ``clock``,
     ``seconds``."""
     from graphmine_tpu.ops.bucketed_mode import check_plan_fits
-    from graphmine_tpu.ops.superstep_policy import step_carried_rows
+    from graphmine_tpu.ops.superstep_policy import noting, step_carried_rows
 
     depth = _unreached_but(sources, plan.num_vertices)
     check_plan_fits(depth, graph, plan)
+    full_level = noting(programs, "full_level", _full_level_program)
 
     def level(rows, depth):
-        new, count = _full_level_program(depth, plan)
+        new, count = full_level(depth, plan)
         return new, None, 0, count  # no K: there is no update to pick
 
     depth, per_step = step_carried_rows(

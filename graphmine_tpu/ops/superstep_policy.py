@@ -18,11 +18,15 @@ plan: whether the gathered rows and their slot index go on the device
 says what the device then holds (:func:`emit_device_residency`); for the
 PageRank job over the same plan, which carries nothing, what its one
 iteration's program takes beside the plan (:func:`stepped_residency`).
+For every job that steps or runs compiled programs under a sink: what each
+program takes of the chip by its own executable's account
+(:class:`ProgramLog`, :func:`emit_program_memory`).
 """
 
 from __future__ import annotations
 
 import time
+import weakref
 
 from graphmine_tpu.obs.costmodel import _bucketed_padded_slots, superstep_cost
 from graphmine_tpu.obs.memmodel import (
@@ -198,6 +202,38 @@ def mesh_memory_stats(mesh) -> dict | None:
     )
 
 
+def _admission_sizes(plan, shards: int, reduce: str) -> dict:
+    """The rungs the admission sizes the job's programs at: the rewrite at
+    the top one; the BFS job's bottom-up level, which never takes the top
+    rung, at the one below it."""
+    rungs = delta_rungs(int(plan.num_messages))
+    return dict(
+        top_rung=max(rungs, default=0), shards=shards, reduce=reduce,
+        bottom_up_rung=rungs[-2] if reduce == "min" and len(rungs) > 1 else 0,
+    )
+
+
+def reckoned_temp_bytes(plan, shards: int = 1, reduce: str = "mode") -> dict:
+    """``{(program, cap): bytes}``: what :func:`admit_carried_rows` counted
+    for each program's temporaries, under the names the job gives its
+    programs (the BFS job's row min is its ``level``) and, for the two
+    programs that are reckoned at one rung alone, that rung's ``cap``;
+    ``None`` for a program that has no rung. The hubs' histograms, which
+    the admission's sum holds as a term of its own and the compiler as
+    temporaries of the two programs that reduce the hubs, are counted with
+    those two, so that both sides count the same bytes. A program the
+    admission counts nothing for has no entry. The ``reckoned_temp_bytes``
+    of the ``program_memory`` records (:class:`ProgramLog`)."""
+    sized = _admission_sizes(plan, shards, reduce)
+    caps = {"rewrite": sized["top_rung"], "bottom_up": sized["bottom_up_rung"]}
+    hubs = carried_rows_inventory(plan, **sized)["hub_histograms"]
+    return {
+        ("level" if name == "row_min" else name, caps.get(name)):
+            held + (hubs if name in ("modes", "dirty_modes") else 0)
+        for name, held in carried_job_transients(plan, **sized).items() if held
+    }
+
+
 def admit_carried_rows(
     plan, stats: dict | None, shards: int = 1, reduce: str = "mode"
 ) -> tuple[str, str]:
@@ -231,12 +267,7 @@ def admit_carried_rows(
     the stateless program's 20 GB (PERF.md §6, PR 36); a job admitted here
     whose compile does not fit the host still ends there, minutes later.
     Every ``reason`` says so."""
-    rungs = delta_rungs(int(plan.num_messages))
-    sized = dict(
-        top_rung=max(rungs, default=0), shards=shards, reduce=reduce,
-        # the BFS job's bottom-up level never takes the top rung
-        bottom_up_rung=rungs[-2] if reduce == "min" and len(rungs) > 1 else 0,
-    )
+    sized = _admission_sizes(plan, shards, reduce)
     need = carried_rows_inventory(plan, **sized)
     by_program = carried_job_transients(plan, **sized)
     largest = max(by_program, key=by_program.get)
@@ -456,8 +487,6 @@ def emit_device_residency(
         rows_bytes=inv.get("carried_rows", 0),
         slot_index_bytes=on_device(plan, index),
         labels_bytes=inv["labels"],
-        # the jitted call hands back no executable to ask for its size
-        code_bytes=None,
     )
 
 
@@ -496,8 +525,138 @@ def emit_shard_residency(
         rows_bytes=per_chip(sg.bucket_send) if carried else 0,
         slot_index_bytes=per_chip(sg.out_ptr, sg.out_slot),
         labels_bytes=2 * 4 * sg.padded_vertices,
-        code_bytes=None,
     )
+
+
+# ---- what a job's programs take of the chip ---------------------------------
+
+# What the executables said, once a (plan, program): kept under the identity
+# of the array that anchors the plan (as `ops/lpa.py:_cached_auto_plan` and
+# its kin key theirs) and let go with it.
+_program_memory: dict = {}
+
+_EXECUTABLE_SIZES = {
+    "code_bytes": "generated_code_size_in_bytes",
+    "temp_bytes": "temp_size_in_bytes",
+    "argument_bytes": "argument_size_in_bytes",
+    "output_bytes": "output_size_in_bytes",
+    "alias_bytes": "alias_size_in_bytes",
+}
+
+
+def _ask_executable(fn, args, statics) -> dict:
+    """The sizes the executable of the call ``fn(*args, **statics)`` states
+    of itself (``memory_analysis()``), on a mesh ONE chip's. Asked after
+    the call, of its very arguments (a donated one is gone by then and
+    serves: only its shape and placement are read), the lowering and the
+    executable are the ones the call left in jit's caches: nothing is
+    compiled or loaded again (held by ``tests/test_program_memory.py``)."""
+    said = fn.lower(*args, **statics).compile().memory_analysis()
+    return {
+        field: None if said is None else int(getattr(said, name))
+        for field, name in _EXECUTABLE_SIZES.items()
+    }
+
+
+class ProgramLog:
+    """The compiled programs one job under a sink ran, each with what its
+    executable takes of the chip: the ``program_memory`` records'
+    material. A job wraps the programs it hands out with :func:`noting`;
+    the first call of a program in the job looks its sizes up in what is
+    kept with ``anchor`` (the array whose identity keys the plan's cache
+    entry; the graph's ``msg_ptr`` where there is no plan) and asks the
+    executable only where nothing is kept yet, so a later job on the same
+    plan copies its records (``cached: True``) and asks nothing.
+    ``reckoned``, called here, gives the admission's count by program
+    (:func:`reckoned_temp_bytes`'s dict) where it counted this job's;
+    ``shards`` is the mesh's size."""
+
+    def __init__(self, anchor, reckoned=None, shards: int | None = None):
+        key = id(anchor)
+        hit = _program_memory.get(key)
+        if hit is None or hit[0]() is not anchor:
+            hit = (
+                weakref.ref(anchor, lambda _, k=key: _program_memory.pop(k, None)),
+                {},
+            )
+            _program_memory[key] = hit
+        self.kept = hit[1]
+        self.reckoned = reckoned() if reckoned else {}
+        self.shards = shards
+        self.ran: dict = {}
+        self.seconds = 0.0
+
+    def note(self, name: str, fn, args, statics: dict, said: dict) -> None:
+        t0 = time.perf_counter()
+        fields = {**said, **statics}
+        key = (fn, name, *sorted(fields.items()))
+        if key not in self.ran:
+            sizes = self.kept.get(key)
+            cached = sizes is not None
+            if not cached:
+                sizes = self.kept[key] = _ask_executable(fn, args, statics)
+            counted = self.reckoned.get((name, fields.get("cap")))
+            self.ran[key] = dict(
+                program=name, **fields, **sizes, cached=cached,
+                **({} if counted is None else {"reckoned_temp_bytes": counted}),
+            )
+        self.seconds += time.perf_counter() - t0
+
+
+def plan_anchor(graph, plan):
+    """The array a :class:`ProgramLog` keeps its answers with: the plan's
+    first (every plan of one graph's cache entry shares it, with or
+    without its slot index), the graph's ``msg_ptr`` on the ``sort``
+    family, which has no plan."""
+    return plan.vertex_ids[0] if plan is not None and plan.vertex_ids else graph.msg_ptr
+
+
+def program_log(sink, anchor, reckoned=None, shards=None) -> ProgramLog | None:
+    """A job's :class:`ProgramLog`, or ``None`` without a sink and under a
+    caller's trace (no executable to ask): the job then calls its programs
+    bare and nothing is asked or kept."""
+    import jax
+
+    if sink is None or not jax.core.trace_ctx.is_top_level():
+        return None
+    return ProgramLog(anchor, reckoned, shards)
+
+
+def noting(programs: ProgramLog | None, name: str, fn, **said):
+    """``fn`` itself without a log; under one, ``fn`` followed by the
+    log's note of the call under the job's word for the program
+    (``name``). The call's keyword arguments (a program's static ones:
+    ``cap``, ``w``) and ``said`` (a static argument the call passes by
+    position) tell one program of that name from another and go into its
+    record; arrays are passed by position. A stand-in that is no jitted
+    function (a test's, in a program's place) has no executable to ask and
+    is not noted."""
+    if programs is None or not hasattr(fn, "lower"):
+        return fn
+
+    def call(*args, **statics):
+        out = fn(*args, **statics)
+        programs.note(name, fn, args, statics, said)
+        return out
+
+    return call
+
+
+def emit_program_memory(sink, op: str, programs: ProgramLog | None) -> None:
+    """One ``program_memory`` record (see ``obs/schema.py``) for each
+    program the job behind ``programs`` ran, in the order it first ran
+    them; the last one says ``asked_s``, the seconds the job spent in the
+    log and here by this module's own clock. No-op without a sink."""
+    if sink is None or programs is None:
+        return
+    t0 = time.perf_counter()
+    mesh = {} if programs.shards is None else {"shards": programs.shards}
+    records = list(programs.ran.values())
+    for i, fields in enumerate(records, 1):
+        last = {} if i < len(records) else {
+            "asked_s": round(programs.seconds + time.perf_counter() - t0, 6)
+        }
+        sink.emit("program_memory", op=op, **fields, **mesh, **last)
 
 
 def timed_plan_build(build) -> tuple:

@@ -553,7 +553,19 @@ def _triangles(graph: Graph, simple_edges=None, sink=None):
 
 
 def _count(plan: _LccPlan, sink=None):
-    """The device side on a built plan."""
+    """The device side on a built plan; under a sink also one
+    ``program_memory`` record a width class and stage (asked of the
+    executables by the plan's first job, copied by the later ones)."""
+    from graphmine_tpu.ops.superstep_policy import (
+        emit_program_memory,
+        noting,
+        program_log,
+    )
+
+    programs = program_log(sink, plan.col)
+    core = noting(programs, "core", _core_class)
+    tail_table = noting(programs, "tail", _tail_table_class)
+    tail = noting(programs, "tail", _tail_class)
     v, stats = plan.num_vertices, plan.stats
     with stage_span(sink, "triangles_device",
                     wedges=stats["wedges_core"] + stats["wedges_tail"]) as device:
@@ -562,8 +574,8 @@ def _count(plan: _LccPlan, sink=None):
                         rows=stats["core_rows"],
                         bit_products=stats["core_rows"] * stats["core_vertices"]) as stage:
             for w, nb, blocks, *arrays in plan.core_classes:
-                lo, hi = _core_class(lo, hi, plan.bits, blocks, *arrays,
-                                     w=w, nb=nb, core_start=plan.core_start)
+                lo, hi = core(lo, hi, plan.bits, blocks, *arrays,
+                              w=w, nb=nb, core_start=plan.core_start)
             stage.sync((lo, hi))
         with stage_span(sink, "lcc_tail", blocks=sum(c[2] for c in plan.tail_classes),
                         wedges=stats["wedges_tail"], edges=stats["tail_edges"],
@@ -571,12 +583,13 @@ def _count(plan: _LccPlan, sink=None):
                         credit_slots=stats["tail_credit_slots"]) as stage:
             for w, ne, blocks, *arrays in plan.tail_classes:
                 if plan.tail_table is not None:
-                    lo, hi = _tail_table_class(lo, hi, plan.tail_table, blocks, *arrays,
-                                               w=w, ne=ne)
+                    lo, hi = tail_table(lo, hi, plan.tail_table, blocks, *arrays,
+                                        w=w, ne=ne)
                 else:
-                    lo, hi = _tail_class(lo, hi, plan.col, blocks, *arrays, w=w, ne=ne)
+                    lo, hi = tail(lo, hi, plan.col, blocks, *arrays, w=w, ne=ne)
             stage.sync((lo, hi))
-        lo, hi = device.sync(_by_id(lo, hi, plan.rank))
+        lo, hi = device.sync(noting(programs, "by_id", _by_id)(lo, hi, plan.rank))
+    emit_program_memory(sink, "lcc", programs)
     return lo, hi, plan.degree
 
 

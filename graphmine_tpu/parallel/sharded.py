@@ -1130,7 +1130,7 @@ def _mesh_modes_program(rows, labels, sg: ShardedGraph, mesh):
 
 def carried_label_propagation(
     sg: ShardedGraph, mesh, max_iter: int = 5,
-    init_labels: jax.Array | None = None, clock=None,
+    init_labels: jax.Array | None = None, clock=None, programs=None,
 ):
     """``(labels [V], per_step)`` of ``max_iter`` LPA supersteps over a
     placed partition with its slot index (:func:`with_shard_slot_index`),
@@ -1153,24 +1153,34 @@ def carried_label_propagation(
     is the length of that loop and no program's argument. ``per_step`` is
     ``_carried_rows_job``'s: ``changed_vertices``, ``changed_messages``
     (the largest shard's K), ``branch``, and with a ``clock`` ``seconds``.
+    ``programs`` (``ops/superstep_policy.ProgramLog``, the caller's where a
+    sink wants the ``program_memory`` records) notes each program run.
 
     One process only: the host steps the chips it addresses."""
-    from graphmine_tpu.ops.superstep_policy import delta_rungs, step_carried_rows
+    from graphmine_tpu.ops.superstep_policy import (
+        delta_rungs,
+        noting,
+        step_carried_rows,
+    )
 
     _check_mesh(sg, mesh)
     if sg.out_slot is None:
         raise ValueError("the partition has no slot index (with_shard_slot_index)")
-    labels, rows = _mesh_job_start(
+    start = noting(programs, "start", _mesh_job_start)
+    gather = noting(programs, "gather", _mesh_gather_program)
+    rewrite = noting(programs, "rewrite", _mesh_rewrite_program)
+    modes = noting(programs, "modes", _mesh_modes_program)
+    labels, rows = start(
         init_labels, mesh, sg.num_vertices, sg.chunk_size, shard_row_slots(sg)
     )
     labels, per_step = step_carried_rows(
         max_iter, delta_rungs(shard_messages(sg)), shard_messages(sg) + 1,
         rows, labels,
-        gather=lambda rows, labels: _mesh_gather_program(rows, labels, sg, mesh),
-        rewrite=lambda rows, labels, changed, cap: _mesh_rewrite_program(
+        gather=lambda rows, labels: gather(rows, labels, sg, mesh),
+        rewrite=lambda rows, labels, changed, cap: rewrite(
             rows, labels, changed, sg, mesh, cap=cap
         ),
-        modes=lambda rows, labels: _mesh_modes_program(rows, labels, sg, mesh),
+        modes=lambda rows, labels: modes(rows, labels, sg, mesh),
         clock=clock,
     )
     if sg.num_vertices != sg.padded_vertices:

@@ -132,8 +132,9 @@ def test_every_path_gives_the_plain_references_depths(name, path, monkeypatch):
     assert (plan.hist_vertex_ids is not None) == (name == "rmat_with_a_histogram_hub")
     validate_records(sink.records)
     by_phase = {r["phase"]: r for r in _records(sink)}
-    if path == "while_loop":
-        assert not by_phase
+    if path == "while_loop":  # one program, and what it takes of the chip
+        assert set(by_phase) == {"program_memory"}
+        assert by_phase["program_memory"]["program"] == "loop"
         return
     fix, delta = by_phase["fixpoint"], by_phase["superstep_delta"]
     assert fix["op"] == delta["op"] == "bfs_level" and fix["supersteps"] == levels
@@ -364,8 +365,10 @@ def test_auto_takes_the_frontier_job_past_the_crossover_and_not_below_it():
     assert np.array_equal(got, _want(u, v, n, source, 0))
     validate_records(sink.records)
     phases = [r["phase"] for r in _records(sink)]
-    assert phases == ["impl_selected", "plan_build", "device_residency",
-                      "superstep_delta", "fixpoint"]
+    ran = phases.count("program_memory")  # a record a program, when the job ends
+    assert ran >= 3 and phases == [
+        "impl_selected", "plan_build", "device_residency", "superstep_delta",
+        "fixpoint", *["program_memory"] * ran]
     picked, _, held = _records(sink)[:3]
     assert picked["op"] == held["op"] == "bfs_level" and picked["impl"] == "bucketed"
     assert picked["scan"] == held["scan"] == "carried" and "row_min" in held["reason"]
@@ -378,7 +381,8 @@ def test_auto_takes_the_frontier_job_past_the_crossover_and_not_below_it():
     small = gm.build_graph(u[:500], v[:500], num_vertices=n)
     sink = MetricsSink()
     gm.bfs_distances(small, [int(u[0])], direction="both", sink=sink)
-    assert [(r["phase"], r["impl"]) for r in _records(sink)] == [("impl_selected", "sort")]
+    assert [(r["phase"], r.get("impl", r.get("program"))) for r in _records(sink)] == [
+        ("impl_selected", "sort"), ("program_memory", "loop")]
 
 
 def test_a_directed_search_a_trace_and_an_unfused_plan_keep_the_loop(monkeypatch):

@@ -354,7 +354,7 @@ def test_auto_says_which_scan_it_admitted_and_what_the_device_holds(monkeypatch)
         selected, held = by_phase["impl_selected"], by_phase["device_residency"]
         assert selected["impl"] == "bucketed" and selected["scan"] == scan
         assert selected["scan_reason"] == held["reason"] and held["scan"] == scan
-        assert held["bytes_limit"] == limit and held["code_bytes"] is None
+        assert held["bytes_limit"] == limit and "code_bytes" not in held
         assert held["graph_bytes"] == _nbytes(g) and held["plan_bytes"] == _nbytes(plan)
         assert held["labels_bytes"] == 8 * n
         indexed = lpa._cached_slot_index(plan)[0]
