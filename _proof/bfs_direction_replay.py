@@ -1,8 +1,9 @@
 """ISSUE 50, before a chip-minute is spent: K and U by level of the BFS cell's
 search at a smaller scale, and the update the job's own rule picks for each
 (host only: the benchmark's generator, its plain reference's depths, and
-``ops/paths.py:_next_update`` over ``delta_rungs``). K is the messages the
-vertices a level reached send, U the edges of the vertices still unreached.
+``ops/paths.py:_next_update`` over ``delta_rungs``, the plan's slots taken as
+M: the padding is 4 %). K is the messages the vertices a level reached send,
+U the edges of the vertices still unreached.
 
     python _proof/bfs_direction_replay.py 20 22      # one JSON line a scale
 
@@ -17,7 +18,7 @@ import numpy as np
 
 import bfs, generators
 from graphmine_tpu.ops.paths import _next_update
-from graphmine_tpu.ops.superstep_policy import delta_rungs
+from graphmine_tpu.ops.superstep_policy import bottom_up_chunk, delta_rungs
 
 cfg = json.load(open(os.path.join(ROOT, "benchmark", "configs", "graphalytics-g500-24-bfs.json")))
 for scale in (int(a) for a in sys.argv[1:]):
@@ -33,11 +34,13 @@ for scale in (int(a) for a in sys.argv[1:]):
     k, unreached, stale, levels = int(deg[depth == 0].sum()), m, False, []
     unreached -= k
     for level in range(1, deepest + 2):  # the last reaches nothing
-        place, bottom_up = _next_update(k, unreached, rungs, stale)
+        chunk = bottom_up_chunk(m)
+        places = -(-unreached // chunk) * chunk
+        place, bottom_up = _next_update(k, places, rungs, m, stale)
         stale = bottom_up
         at = depth == level
         levels.append({"level": level, "picked_by_K": k, "picked_by_U": unreached,
-                       "branch": names[place], "direction": "bottom_up" if bottom_up else "top_down",
+                       "branch": places if bottom_up else names[place], "direction": "bottom_up" if bottom_up else "top_down",
                        "reached": int(at.sum())})
         k = int(deg[at].sum())
         unreached -= k

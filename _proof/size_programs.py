@@ -11,8 +11,8 @@ program a process so that the peak RSS is that program's compile alone.
     python _proof/size_programs.py _proof/g500_24_shapes.json dirty_modes:0     # the reduce over those rows (ISSUE 43)
     python _proof/size_programs.py _proof/g500_24_shapes.json bfs_level         # the BFS job's row min (ISSUE 49);
                                                 # also bfs_gather, bfs_rewrite:<place>, bfs_start, bfs_full_level
-    python _proof/size_programs.py _proof/g500_24_shapes.json bfs_bottom_up:2   # the bottom-up level at a rung (ISSUE 50);
-                                                # bfs_unreached is its compaction, one program whatever the rung
+    python _proof/size_programs.py _proof/g500_24_shapes.json bfs_bottom_up   # the bottom-up level's one program (ISSUE 53);
+                                                # bfs_unreached is its compaction, one program whatever the level
 
 A shapes file of a MESH partition (``shards`` in it: _proof/mesh_shapes_and_k.py,
 ISSUE 39) compiles the mesh job's programs (``parallel/sharded.py``) for the
@@ -134,11 +134,15 @@ def one(said, name, text_out=None):
             lowered = paths._start_program.lower(
                 one_source, plan.out_ptr, slots=s, num_vertices=v)
         elif name == "bfs_unreached":
-            lowered = paths._unreached_program.lower(labels, plan)
-        elif name.startswith("bfs_bottom_up:"):
-            cap = delta_rungs(said["num_messages"])[int(name.split(":")[1])]
+            lowered = paths._unreached_program.lower(labels, plan.out_ptr)
+        elif name == "bfs_bottom_up":  # ISSUE 53: one program whatever the level's size
+            from graphmine_tpu.ops.superstep_policy import bottom_up_chunk
+
+            m = said["num_messages"]
+            msg_send = jax.ShapeDtypeStruct((m,), jnp.int32, sharding=chip)
             lowered = paths._bottom_up_program.lower(
-                labels, labels, labels, labels, plan, cap=cap)
+                labels, labels, labels, labels, msg_send, plan.out_ptr,
+                chunk=bottom_up_chunk(m))
         else:  # bfs_rewrite:<place>
             cap = delta_rungs(said["num_messages"])[int(name.split(":")[1])]
             lowered = paths._rewrite_program.lower(rows, labels, changed, plan, cap=cap)

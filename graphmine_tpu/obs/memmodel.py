@@ -389,14 +389,16 @@ _DIRTY_V_WORDS = 10
 # memory spaces and out, the hubs' write back, and the count of K (the
 # changed mask, the out-degree's two slices and their difference, the select)
 _ROW_MIN_V_WORDS = 8
-# the bottom-up level's cap-long vectors (ISSUE 50): the expansion's, as the
-# rewrite's (the scattered differences and their running sums for source and
-# vertex, the slot), the row, its vertex and that vertex's depth following
-# them as they die; its V-vectors are the row min's, the classes' ids laid
-# end to end among them (graph500-24's shapes at M / 16, compiled for a
-# described v5e: 721,833,472 B, 5.5 words a place; the compare against the
-# class offsets is fused into each lookup and never written)
-_BOTTOM_UP_CAP_WORDS = 6
+# the bottom-up level (ISSUE 53: a loop over chunks of places, which reads the
+# graph's message CSR). V-long: the spans' running end, first place and offset
+# into `msg_send`, the depths out, and the count of K as the row min's. A trip's
+# chunk-long vectors: the scattered differences and their running sums for
+# source and vertex, the neighbour, its depth, and the spans cut out of the
+# lists (a quarter of a chunk of each). graph500-24's shapes compiled for a described
+# v5e at a chunk of 2^19: 202,003,968 B, 2.9 words a vertex beside six a
+# place of the chunk (6,347,776 B at 2^16 vertices, where the lists are short).
+_BOTTOM_UP_V_WORDS = 4
+_BOTTOM_UP_CHUNK_WORDS = 8
 _DIRTY_TRIP_ROWS = 8  # ops/bucketed_mode.py's _DIRTY_GROUP_ROWS: the rows a trip of the dirty reduce sorts
 _DIRTY_TRIP_FORMS = 8
 _DIRTY_PAIRWISE_SLOTS = 3 * 2048 * _PAIRWISE_MAX_W  # three forms of [2048 / 32, 32, 32]
@@ -416,7 +418,7 @@ def _tiled(n: int, w: int) -> tuple[int, int]:
 
 def carried_job_transients(
     plan, top_rung: int = 0, shards: int = 1, reduce: str = "mode",
-    bottom_up_rung: int = 0,
+    bottom_up_chunk: int = 0,
 ) -> dict:
     """Bytes of temporaries by program of the carried-rows job, from the
     plan's shapes (the note above): ``gather``, ``modes``, ``rewrite`` at
@@ -424,11 +426,11 @@ def carried_job_transients(
     has one, ``dirty_modes``. ``reduce="min"`` is the BFS job
     (``ops/paths.py``), whose reduce is ``row_min`` and which has no dirty
     reduce: ``gather``, ``row_min``, ``rewrite`` and ``bottom_up``, the
-    level that reads no row (ISSUE 50), at ``bottom_up_rung`` places, the
-    highest rung it may take (0 without one): its cap-long vectors
-    (:data:`_BOTTOM_UP_CAP_WORDS`) beside the level's V-vectors; its
-    compaction is the rewrite's sort less an operand and never the
-    largest. A row's min reads the
+    level that reads no row (ISSUE 53), whose loop takes
+    ``bottom_up_chunk`` places a trip whatever the level's size: a trip's
+    chunk-long vectors (:data:`_BOTTOM_UP_CHUNK_WORDS`) beside the level's
+    V-vectors (:data:`_BOTTOM_UP_V_WORDS`); its compaction is the rewrite's
+    sort less an operand and never the largest. A row's min reads the
     class's rows in the row-major form the flat rows reshape to and writes
     a vector; it is counted with the compiler's own form beside it, as the
     gather is, and with the V-vectors of the level
@@ -480,9 +482,10 @@ def carried_job_transients(
             "gather": max(gather, default=0) + labels,
             "row_min": max(gather, default=0) + level,
             "rewrite": rewrite,
-            "bottom_up": (
-                _BOTTOM_UP_CAP_WORDS * _I32 * int(bottom_up_rung) + level
-            ) if bottom_up_rung else 0,
+            "bottom_up": _I32 * (
+                _BOTTOM_UP_CHUNK_WORDS * int(bottom_up_chunk)
+                + _BOTTOM_UP_V_WORDS * (v + 1)
+            ) if bottom_up_chunk else 0,
         }
     by_program = {
         "gather": max(gather, default=0) + labels,
@@ -525,7 +528,7 @@ def row_sum_transients(plan) -> int:
 
 def carried_rows_inventory(
     plan, top_rung: int = 0, shards: int = 1, reduce: str = "mode",
-    bottom_up_rung: int = 0,
+    bottom_up_chunk: int = 0,
 ) -> dict:
     """What the carried-rows job of ``ops/lpa.py`` holds on the device
     beyond a fused ``plan``, known from the plan's shapes before the index
@@ -555,7 +558,7 @@ def carried_rows_inventory(
     label vector, replicated, twice), the programs the mesh job's
     (:func:`carried_job_transients`). ``reduce="min"`` is the BFS job's
     (``ops/paths.py``): the same rows and index, depths for labels, its own
-    programs (the bottom-up level at ``bottom_up_rung`` places among them)
+    programs (the bottom-up level at ``bottom_up_chunk`` places a trip among them)
     and no histogram (its hubs take a ``segment_min`` over their senders'
     depths)."""
     v = int(plan.num_vertices)
@@ -572,7 +575,7 @@ def carried_rows_inventory(
         "hub_histograms": 2 * _I32 * hubs * v,
         "gather_transient": max(
             carried_job_transients(
-                plan, top_rung, shards, reduce, bottom_up_rung
+                plan, top_rung, shards, reduce, bottom_up_chunk
             ).values()
         ),
     }

@@ -200,12 +200,17 @@ register("fixpoint", "op", "supersteps", "changed", "num_vertices", "family")
 # `unreached_messages`, one a level: `"top_down"` (the rows brought up to
 # date behind the K messages of the vertices the level before reached, then
 # the row min) or `"bottom_up"` (the vertices still unreached look their
-# neighbours' depths up through the slot index; no row is read or written,
-# so `reduce` is "none" and `dirty_rows` / `dirty_slots` 0), and U, the
-# edges of the vertices the level left unreached. The next level is
-# bottom-up where U fits a rung strictly below K's and below the top one;
-# its `branch` is then the rung U fits under, never "full". After a
-# bottom-up level the rows are stale and a top-down level gathers in full.
+# neighbours' depths up; no row is read or written, so `reduce` is "none"
+# and `dirty_rows` / `dirty_slots` 0), and U, the edges of the vertices the
+# level left unreached. Since PR 53 a bottom-up level reads its neighbours
+# from the graph's message CSR in one loop over chunks of places, and the
+# record says `places`, one a level: what that loop ran over, the U before
+# the level rounded up to its trips (0 on a top-down level), so that
+# `seconds` over `places` is a bottom-up place's cost. The next level is
+# bottom-up where its places cost less than the top-down update (the rung
+# K fits under, or the full gather: ops/paths._next_update); its `branch`
+# is then its `places`, never "full". After a bottom-up level the rows are
+# stale and a top-down level gathers in full.
 register("superstep_delta", "op", "changed_vertices", "changed_messages",
          "branch", "rungs", "num_messages", "reduce", "dirty_rows",
          "dirty_slots")
@@ -261,11 +266,12 @@ register("device_residency", "op", "scan", "reason", "bytes_limit",
 # PageRank's `start` and `iteration`; `loop` for a fixpoint in one program,
 # `scan` for a stated count of supersteps in one; LCC's `core`, `tail`,
 # `by_id`) and beside it the program's static arguments (`cap`, `marked`;
-# `w`, `nb`, `ne`; `max_iter`), which tell two programs of one name apart.
+# `chunk`; `w`, `nb`, `ne`; `max_iter`), which tell two programs of one name apart.
 # `reckoned_temp_bytes`, where the admission's model counts this very
-# program (obs/memmodel.carried_job_transients by program, the rewrite and
-# the bottom-up level at the one rung they are reckoned at, the hubs'
-# histograms with the two programs that hold them; row_sum_transients for
+# program (obs/memmodel.carried_job_transients by program, the rewrite at
+# the one rung it is reckoned at, the bottom-up level at its loop's chunk,
+# the hubs' histograms with the two programs that hold them;
+# row_sum_transients for
 # PageRank's iteration), is that count: the
 # compiler's stands beside it in `temp_bytes`. The executable is asked once
 # a (plan, program), after the program's first call, of the lowering and
@@ -491,8 +497,9 @@ DEVICE_SCOPES = frozenset((
     # the last level reached (`delta` and its passes follow it)
     "hubs", "rewrite",
     # the level that asks the unreached vertices for a reached neighbour
-    # (ops/bucketed_mode.bfs_level_bottom_up, PR 50): `compact`, `expand`,
-    # then `neighbours` (slot, row, vertex, depth), `write_back`, `hubs`
+    # (ops/bucketed_mode.bfs_level_bottom_up, PR 50; PR 53: through the
+    # graph's message CSR): `compact`, `expand`, then a trip's `expand`,
+    # `neighbours` (the neighbour, its depth) and `write_back`
     "bottom_up", "neighbours",
     # carried rows (ops/bucketed_mode.rewrite_rows): outer, then its passes
     # (`mark`: the marked rewrite's list of the rows it wrote to, PR 43)

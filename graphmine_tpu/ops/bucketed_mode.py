@@ -39,6 +39,7 @@ itself gets.
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass
 from functools import partial
 
@@ -1175,67 +1176,124 @@ def bfs_level_bucketed(depth: jax.Array, plan: BucketedModePlan) -> jax.Array:
         return _relax_hubs(depth, out, plan)
 
 
-# ---- the bottom-up level (ISSUE 50) ----
+# ---- the bottom-up level (ISSUE 50, ISSUE 53) ----
 #
 # Late in a search the frontier's messages land almost all in the rows of
 # vertices that already have their depth, and the question a level answers
 # is the other one: which vertex still unreached has a reached neighbour?
-# On a symmetric graph a vertex's in-neighbours are its out-neighbours, and
-# the slot index names them: the neighbours of u are the owners of the rows
-# that hold out_slot[out_ptr[u]:out_ptr[u + 1]]. So the unreached vertices'
-# spans are laid end to end as a rewrite lays the changed senders' (Beamer's
-# direction-optimising search, read off the index the job carries), each
-# place looks its neighbour's depth up, and the rows are neither read nor
-# written: after such a level they are stale.
+# The graph's message CSR is sorted by receiver, so the neighbours of u are
+# msg_send[msg_ptr[u]:msg_ptr[u + 1]] with no slot, row or class between
+# (Beamer's direction-optimising search, read off the graph itself). The
+# unreached vertices' spans are laid end to end as a rewrite lays the
+# changed senders', each place reads its neighbour and that neighbour's
+# depth, and the rows are neither read nor written: after such a level they
+# are stale.
 
 
-def compact_unreached(depth: jax.Array, plan: BucketedModePlan):
+def compact_unreached(depth: jax.Array, msg_ptr: jax.Array):
     """``(owner, start, count)``, each ``int32 [V]``: the vertices without a
-    depth that have an edge, ascending, in front, each with its span of
-    ``out_slot``; behind them ``owner`` is ``V`` and ``count`` 0. The one
-    V-long sort of a bottom-up level, whatever its rung."""
-    out_deg = plan.out_ptr[1:] - plan.out_ptr[:-1]
+    depth that receive a message, ascending, in front, each with its span
+    of the message CSR (``msg_send[start:start + count]``, its neighbours);
+    behind them ``owner`` is ``V`` and ``count`` 0. The one V-long sort of a
+    bottom-up level."""
+    in_deg = msg_ptr[1:] - msg_ptr[:-1]
     with jax.named_scope("bfs_level"), jax.named_scope("bottom_up"):
         with jax.named_scope("compact"):
-            return _compact_spans(
-                (depth == _SENTINEL) & (out_deg > 0), plan.out_ptr, out_deg
-            )
+            return _compact_spans((depth == _SENTINEL) & (in_deg > 0), msg_ptr, in_deg)
+
+
+# The spans a trip of the bottom-up loop cuts out of the compacted lists, as a
+# share of its places. A span's offset and vertex reach the span's places by a
+# scatter at the span's start, and a scatter costs an issue an index, taken or
+# dropped: cutting as many spans as a trip has places, the two spreads were 14 ns
+# a place of graph500-24's level 4, at a quarter 3.8 (PERF.md §6, PR 53). A chunk
+# holds chunk / (mean degree of the unreached) spans; where those are more than
+# a quarter of its places (a hundred thousand vertices of degree under 4 in a
+# row) the trip stops at its last span's end and the next starts there.
+_BOTTOM_UP_SPAN_SHARE = 4
+
+# The places between neighbouring issues of a trip's read of `msg_send`: read
+# in the order of the places, neighbouring issues of the gather fall in one span,
+# a few bytes apart, and the chip serves them one after the other: 26.3 ns a
+# place on graph500-24 against 14.8 with the issues far apart (PERF.md §6, PR 53).
+_BOTTOM_UP_ISSUE_STRIDE = 1024
 
 
 def bfs_level_bottom_up(
     depth: jax.Array, owner: jax.Array, start: jax.Array, count: jax.Array,
-    plan: BucketedModePlan, cap: int,
-) -> jax.Array:
-    """One BFS level that asks the unreached vertices
-    (:func:`compact_unreached`'s lists) for a reached neighbour:
+    msg_send: jax.Array, chunk: int,
+):
+    """``(new depths, trips)`` of one BFS level that asks the unreached
+    vertices (:func:`compact_unreached`'s lists) for a reached neighbour:
     ``min(own, least neighbour's depth + 1)`` for each of them, the depths
     :func:`bfs_level_from_rows` gives bit for bit in a search whose reached
-    vertices keep their depths. ``cap`` (static) bounds the edges of the
-    unreached vertices, the caller's promise. A place reads its slot, the
-    slot's row by arithmetic, the row's vertex through the classes' ids
-    laid end to end, and that vertex's depth. A message a histogram hub
-    receives has no slot and names no row, so the hubs' neighbours are
-    relaxed from the hubs' own depths: by symmetry a hub's senders are its
-    neighbours."""
-    v = plan.num_vertices
-    senders = min(cap, v)
-    owner, start, count = (x[:senders] for x in (owner, start, count))
-    pad = _with_sentinel(depth)
+    vertices keep their depths.
+
+    The spans lie end to end over U places, U the edges of the unreached
+    vertices, read on the device: one loop, ``chunk`` places a trip
+    (static), about ``ceil(U / chunk)`` trips (``trips`` says how many), so
+    a level costs what U costs and holds ``chunk``-long temporaries
+    whatever U. A trip starts at the first place not yet looked at, in the
+    span its predecessor stopped in, and cuts the next ``chunk //
+    _BOTTOM_UP_SPAN_SHARE`` spans out of the lists; it takes a chunk of
+    places, or fewer where those spans end sooner. It spreads each span's
+    offset into ``msg_send`` and its vertex over the span's places by a
+    scattered difference and a ``cumsum`` (:func:`_expand_spans`'s way, a
+    chunk at a time), reads the neighbour and the neighbour's depth as the
+    level found it, and takes the least into the vertex by a scatter-min.
+    Places and vertices ascend together, and the scatters say so: neither
+    sorts its indices. A histogram hub's id stands in ``msg_send`` like any
+    other."""
+    v, m = depth.shape[0], msg_send.shape[0]
+    spans = max(1, min(chunk // _BOTTOM_UP_SPAN_SHARE, v))
+    stride = math.gcd(chunk, _BOTTOM_UP_ISSUE_STRIDE)  # 1: in the order of the places
     with jax.named_scope("bfs_level"), jax.named_scope("bottom_up"):
         with jax.named_scope("expand"):
-            place, source, spread, end = _expand_spans(start, count, cap)
-            vertex = spread(owner)
-        with jax.named_scope("neighbours"):
-            slot = _span_slots(place, source, end, plan.out_slot, row_slots(plan))
-            # no slot names the row past the last, whose vertex is V: unreached
-            ids = jnp.concatenate([*plan.vertex_ids, jnp.full((1,), v, jnp.int32)])
-            near = pad[ids[_rows_of_slots(slot, plan)]]
-        with jax.named_scope("write_back"):
-            out = depth.at[vertex].min(_one_past(near), mode="drop")
-        if plan.hist_vertex_ids is not None:
-            with jax.named_scope("hubs"):
-                hub = plan.hist_vertex_ids[
-                    plan.hist_row_offset // jnp.int32(v)
-                ]
-                out = out.at[plan.hist_send].min(_one_past(depth[hub]))
-    return out
+            end = jnp.cumsum(count)
+            first = end - count
+            # place p of a span reads msg_send[p + start - first]
+            lists = (first, end, start - first, owner)
+            u = end[-1]
+
+        def trip(state):
+            base, lo, trips, out = state  # `lo` is the span that holds place `base`
+            with jax.named_scope("expand"):
+                cut = jnp.minimum(lo, v - spans)  # the lists' last spans: `lo` among them
+                a, b, skip, vertex = (
+                    lax.dynamic_slice(x, (cut,), (spans,)) for x in lists
+                )
+                stop = jnp.minimum(base + chunk, b[-1])
+                # the spans with a place here are neighbours in the cut; those
+                # in front of them add nothing at place 0, so `at` ascends
+                here = (b > jnp.maximum(a, base)) & (a < stop)
+                at = jnp.where(b <= base, 0, jnp.where(here, jnp.maximum(a - base, 0), chunk))
+                after = jnp.concatenate([jnp.zeros((1,), jnp.bool_), here[:-1]])
+
+                def spread(per_span):
+                    last = jnp.concatenate([jnp.zeros((1,), jnp.int32), per_span[:-1]])
+                    step = jnp.where(here, per_span - jnp.where(after, last, 0), 0)
+                    return jnp.cumsum(
+                        jnp.zeros((chunk,), jnp.int32).at[at].add(
+                            step, indices_are_sorted=True, mode="drop"
+                        )
+                    )
+
+                place = base + jnp.arange(chunk, dtype=jnp.int32)
+                source = jnp.clip(place + spread(skip), 0, m - 1)
+                whose = jnp.where(place < stop, spread(vertex), v)  # past the stop: nobody's
+            with jax.named_scope("neighbours"):
+                # issued `stride` places apart, and put back in place
+                apart = source.reshape(-1, stride).T.reshape(-1)
+                near = depth[msg_send[apart]].reshape(stride, -1).T.reshape(-1)
+            with jax.named_scope("write_back"):
+                out = out.at[whose].min(
+                    _one_past(near), indices_are_sorted=True, mode="drop"
+                )
+            # the spans looked at to their end lie behind the next trip
+            return stop, cut + jnp.sum(b <= stop, dtype=jnp.int32), trips + 1, out
+
+        zero = jnp.int32(0)
+        _, _, trips, out = lax.while_loop(
+            lambda state: state[0] < u, trip, (zero, zero, zero, depth)
+        )
+        return out, trips

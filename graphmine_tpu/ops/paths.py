@@ -9,8 +9,9 @@ single ``lax.while_loop``. That loop is full width in every pass. On an
 undirected graph past the policy's crossover :func:`bfs_distances` follows
 the frontier instead (ISSUE 49): the carried-rows job of ``ops/lpa.py``
 with a min for its reduce, stepped from the host to the last level, and
-turned bottom-up once the unreached vertices have fewer edges than the
-frontier sends messages (ISSUE 50).
+turned bottom-up once the unreached vertices' edges cost less to look at,
+in the graph's own message CSR, than the frontier's messages cost to write
+(ISSUE 50, ISSUE 53).
 
 Direction conventions:
 - ``direction="out"``: follow edge direction (src -> dst), GraphFrames'
@@ -72,12 +73,16 @@ def bfs_distances(
     they send fit under (:func:`~graphmine_tpu.ops.superstep_policy.
     delta_rungs`; a level that sends more than the top rung gathers every
     row anew). The search turns bottom-up (:func:`_next_update`) once the
-    edges of the vertices still unreached fit a rung strictly below the
-    one the frontier's messages fit, and below the top one: those vertices
-    then look their neighbours' depths up through the slot index
-    (:func:`~graphmine_tpu.ops.bucketed_mode.bfs_level_bottom_up`), a few
-    thousand places late in a search where the frontier would rewrite
-    millions of slots in rows that already have their depth. Such a level
+    edges of the vertices still unreached cost less to look at than the
+    top-down update does, by what a place of each costs on the chip: those
+    vertices then read their neighbours out of the graph's message CSR,
+    which is sorted by receiver (``depth[msg_send[msg_ptr[u]:msg_ptr[u +
+    1]]]``, no slot, row or class between;
+    :func:`~graphmine_tpu.ops.bucketed_mode.bfs_level_bottom_up`), in one
+    program that loops over fixed chunks of places as many times as those
+    edges take: 68 M places at the loud level of a Kronecker graph where
+    the frontier would have every slot gathered anew, a few thousand late
+    in a search. Such a level
     reads and writes no row, so the rows are stale after it: from then on
     the one top-down update is the full gather, and the search stays
     turned unless that is the cheaper. The host reads three counts a level
@@ -96,10 +101,10 @@ def bfs_distances(
     ``impl_selected`` (with ``scan`` and ``scan_reason``), ``plan_build``
     and ``device_residency`` as ``label_propagation`` does; a job over the
     plan's rows one ``superstep_delta`` record (``op: bfs_level``; a level:
-    its direction, the branch taken, the vertices reached, the messages
-    they send, the edges of the vertices still unreached, its seconds at
-    the host's one wait) and every host-stepped job one
-    ``fixpoint`` record (the supersteps and the vertices each reached);
+    its direction, the branch taken, the places a bottom-up level looked
+    at, the vertices reached, the messages they send, the edges of the
+    vertices still unreached, its seconds at the host's one wait) and
+    every host-stepped job one ``fixpoint`` record (the supersteps and the vertices each reached);
     every job one ``program_memory`` record for each compiled program it ran
     (what the executable takes of the chip; ``label_propagation``'s).
     """
@@ -183,8 +188,8 @@ def _emit_job_records(sink, graph: Graph, plan, per_step: dict) -> None:
     """The ``superstep_delta`` and ``fixpoint`` records of one host-stepped
     job (no-op without a sink): a level's ``branch`` is ``"fill"`` for a
     first level that found the rows as the fill left them and wrote the
-    sources' slots alone, a rung (a top-down level's the one K fits under,
-    a bottom-up level's the one U does), or ``"full"``; a bottom-up level
+    sources' slots alone, the rung K fits under, ``"full"``, or on a
+    bottom-up level the ``places`` its loop ran over; a bottom-up level
     reduces no row (``reduce: "none"``); the full-width job has no rows, no
     K, no U and no rung, and every level of it is ``"full"``."""
     if sink is None:
@@ -197,13 +202,12 @@ def _emit_job_records(sink, graph: Graph, plan, per_step: dict) -> None:
     turned = [d == "bottom_up" for d in per_step.get("direction", reached)]
     if "branch" in per_step:
         rungs = list(delta_rungs(plan.num_messages))
-        branch = [[*rungs, "full"][b] for b in per_step["branch"]]
+        branch = list(per_step["branch"])
         if branch and branch[0] != "full" and not turned[0]:
             branch[0] = "fill"
         more = {
-            "direction": per_step["direction"],
-            "unreached_messages": per_step["unreached_messages"],
-            "source_messages": per_step["source_messages"],
+            name: per_step[name] for name in
+            ("direction", "unreached_messages", "places", "source_messages")
         }
         sent = per_step["changed_messages"]
     else:
@@ -284,24 +288,25 @@ def _level_program(rows, depth, plan):
 
 
 @jax.jit
-def _unreached_program(depth, plan):
-    """The unreached vertices' spans, compacted: the one V-long sort of a
-    bottom-up level, in a program of its own so that no rung compiles it
-    again (a minute and more a compile at 2^24 vertices)."""
+def _unreached_program(depth, msg_ptr):
+    """The unreached vertices' spans of the message CSR, compacted: the one
+    V-long sort of a bottom-up level, in a program of its own (a minute and
+    more a compile at 2^24 vertices; the level's loop compiles in seconds)."""
     from graphmine_tpu.ops.bucketed_mode import compact_unreached
 
-    return compact_unreached(depth, plan)
+    return compact_unreached(depth, msg_ptr)
 
 
-@partial(jax.jit, static_argnames=("cap",))
-def _bottom_up_program(depth, owner, start, count, plan, cap: int):
-    """``(new depths, reached, K, count, U)`` of one bottom-up level over
-    the unreached vertices' spans, whose edges fit ``cap`` places; no sort."""
+@partial(jax.jit, static_argnames=("chunk",))
+def _bottom_up_program(depth, owner, start, count, msg_send, out_ptr, chunk: int):
+    """``(new depths, reached, K, count, U, trips)`` of one bottom-up level
+    over the unreached vertices' spans, ``chunk`` places a trip of its loop
+    and as many trips as their edges take; no sort, no row."""
     from graphmine_tpu.ops.bucketed_mode import bfs_level_bottom_up
 
-    new = bfs_level_bottom_up(depth, owner, start, count, plan, cap)
+    new, trips = bfs_level_bottom_up(depth, owner, start, count, msg_send, chunk)
     reached = new != depth
-    return (new, reached, *_level_counts(new, reached, plan.out_ptr))
+    return (new, reached, *_level_counts(new, reached, out_ptr), trips)
 
 
 @jax.jit
@@ -314,23 +319,32 @@ def _full_level_program(depth, plan):
         return new, jnp.sum(new != depth, dtype=jnp.int32)
 
 
-def _next_update(k: int, u: int, rungs: tuple, stale: bool) -> tuple:
-    """``(place, bottom_up)``: the cheaper update of the next level, by the
-    ladder alone. Top-down brings the rows up to date behind the K messages
-    the last level's vertices send, at the rung K fits under (``place`` in
-    ``rungs``, ``len(rungs)`` for the full gather; rows a bottom-up level
-    left ``stale`` can only be gathered anew), and takes the row min.
-    Bottom-up looks at the U edges of the vertices still unreached, at the
-    rung U fits under. A bottom-up place costs more than a rewrite's (two
-    more gathers: about 50 ns against 37-48 on a TPU v5e, PERF.md §6, PR 50),
-    so it is taken only where U's rung is strictly below K's, and never on
-    the top rung, which costs more than the full gather at that price."""
+# What a place of each update costs on a TPU v5e, in nanoseconds: what the
+# direction rule weighs. Measured on graph500-24 (V = 2^24, M = 520.8 M), each
+# by the PERF.md section named; the ratios decide, and they hold from scale 22
+# up (a gather and a rewrite's place are bound by issue per index, §7.4).
+_GATHERED_SLOT_NS = 7.4     # the full gather: 542.5 M slots in 4.03 s (§5, PR 50)
+_REWRITTEN_PLACE_NS = 43.0  # a rewrite, a place of its RUNG: 37-48 (§6, PR 49)
+_BOTTOM_UP_PLACE_NS = 39.4  # a bottom-up place of the loop's trips: 39.3-39.5 (§6, PR 53)
+
+
+def _next_update(k: int, places: int, rungs: tuple, slots: int, stale: bool) -> tuple:
+    """``(place, bottom_up)``: the cheaper update of the next level, by what
+    a place of each costs. Top-down brings the rows up to date behind the K
+    messages the last level's vertices send and takes the row min: a rewrite
+    of the rung K fits under (``place`` in ``rungs``), which costs the
+    RUNG's places, or above the top rung the gather of all ``slots``
+    (``place == len(rungs)``); rows a bottom-up level left ``stale`` can
+    only be gathered anew. Bottom-up looks at ``places``, the edges of the
+    vertices still unreached as the level's loop runs over them (U rounded
+    up to its trips). It is taken where it is the cheaper."""
     full = len(rungs)
     place = full if stale else sum(k > rung for rung in rungs)
-    under = sum(u > rung for rung in rungs)
-    if under < min(place, full - 1):
-        return under, True
-    return place, False
+    top_down = (
+        slots * _GATHERED_SLOT_NS if place == full
+        else rungs[place] * _REWRITTEN_PLACE_NS
+    )
+    return place, places * _BOTTOM_UP_PLACE_NS < top_down
 
 
 def _frontier_job(graph: Graph, sources, limit: int, plan, clock=None, programs=None):
@@ -340,24 +354,27 @@ def _frontier_job(graph: Graph, sources, limit: int, plan, clock=None, programs=
     the two counts its predecessor left. Top-down: the rows are brought up
     to date by what the predecessor reached (a rung's rewrite, the first
     level's of the sources' slots; a full gather above the top rung) and
-    :func:`_level_program` takes the row min. Bottom-up (ISSUE 50), once
-    the unreached vertices' edges fit a lower rung than the frontier's
-    messages: :func:`_unreached_program` compacts those vertices and
-    :func:`_bottom_up_program` looks their neighbours' depths up through
-    the slot index; the rows are not touched and are stale from then on
-    (the job lets them go: a fifth of what it holds on the device), so the
-    search stays turned unless a full gather is the cheaper (U never
-    grows). The host waits once a level, for the three counts. It stops at
-    the first level that reaches nothing, or after ``limit``.
+    :func:`_level_program` takes the row min. Bottom-up (ISSUE 50, 53),
+    once the unreached vertices' edges cost less to look at than the
+    frontier's messages to write: :func:`_unreached_program` compacts those
+    vertices' spans of the graph's message CSR and
+    :func:`_bottom_up_program` reads their neighbours' depths there; the
+    rows are not touched and are stale from then on (the job lets them go:
+    a fifth of what it holds on the device), so the search stays turned
+    unless a full gather is the cheaper (U never grows). The host waits
+    once a level, for the three counts. It stops at the first level that
+    reaches nothing, or after ``limit``.
 
     ``per_step``, one entry a level: ``changed_vertices``,
     ``changed_messages`` (K), ``unreached_messages`` (U), ``direction``,
-    ``branch`` (the place of the rung K fits under, or U on a bottom-up
-    level; ``len(rungs)`` for a full gather), with a ``clock`` ``seconds``;
-    and ``source_messages``, the K that picked the first level's rung.
-    ``programs`` (the caller's ``ProgramLog``) notes each program run."""
+    ``branch`` (the rung K fits under or ``"full"``; on a bottom-up level
+    its ``places``), ``places`` (what a bottom-up level's loop ran over, its
+    trips' chunks: the U before it rounded up; 0 on a top-down level), with
+    a ``clock`` ``seconds``; and ``source_messages``, the K that picked the
+    first level's rung. ``programs`` (the caller's ``ProgramLog``) notes
+    each program run."""
     from graphmine_tpu.ops.bucketed_mode import check_plan_fits, row_slots
-    from graphmine_tpu.ops.superstep_policy import delta_rungs, noting
+    from graphmine_tpu.ops.superstep_policy import bottom_up_chunk, delta_rungs, noting
 
     start = noting(programs, "start", _start_program)
     unreached = noting(programs, "unreached", _unreached_program)
@@ -366,35 +383,44 @@ def _frontier_job(graph: Graph, sources, limit: int, plan, clock=None, programs=
     rewrite = noting(programs, "rewrite", _rewrite_program)
     level = noting(programs, "level", _level_program)
     rungs = delta_rungs(plan.num_messages)
+    slots = row_slots(plan)
+    chunk = bottom_up_chunk(plan.num_messages)
     rows, depth, reached, *counts = start(
-        sources, plan.out_ptr, slots=row_slots(plan), num_vertices=plan.num_vertices
+        sources, plan.out_ptr, slots=slots, num_vertices=plan.num_vertices
     )
     check_plan_fits(depth, graph, plan)
     # a fetch a job: the sources' out-degree picks the first rung
     k, u = (int(x) for x in jax.device_get(counts))
     source_messages = k
     names = ("changed_vertices", "changed_messages", "unreached_messages",
-             "direction", "branch")
+             "direction", "branch", "places")
     per_step = {name: [] for name in names}
     marks = [clock()] if clock else []
     for _ in range(limit):
-        place, bottom_up = _next_update(k, u, rungs, stale=rows is None)
+        place, bottom_up = _next_update(
+            k, -(-u // chunk) * chunk, rungs, slots, stale=rows is None
+        )
         if bottom_up:
             rows = None  # stale from here on: the device has their room back
-            spans = unreached(depth, plan)
+            spans = unreached(depth, graph.msg_ptr)
             depth, reached, *counts = bottom_up_level(
-                depth, *spans, plan, cap=rungs[place]
+                depth, *spans, graph.msg_send, plan.out_ptr, chunk=chunk
             )
         else:
             if rows is None:  # the gather writes every slot: any rows will do
-                rows = jnp.empty((row_slots(plan),), jnp.int32)
+                rows = jnp.empty((slots,), jnp.int32)
             if place == len(rungs):
                 rows = gather(rows, depth, plan)
             else:
                 rows = rewrite(rows, depth, reached, plan, cap=rungs[place])
             depth, reached, *counts = level(rows, depth, plan)
-        k, moved, u = (int(x) for x in jax.device_get(counts))  # the one wait
-        said = (moved, k, u, "bottom_up" if bottom_up else "top_down", place)
+        # the one wait; a bottom-up level also says the trips its loop took
+        k, moved, u, *trips = (int(x) for x in jax.device_get(counts))
+        places = sum(trips) * chunk
+        said = (
+            moved, k, u, "bottom_up" if bottom_up else "top_down",
+            places if bottom_up else [*rungs, "full"][place], places,
+        )
         for name, value in zip(names, said):
             per_step[name].append(value)
         if clock:

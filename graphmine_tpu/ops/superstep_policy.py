@@ -93,6 +93,20 @@ DELTA_RUNG_DIVISORS = (4096, 256, 16, 6)
 DIRTY_REDUCE_TOP_PLACE = 0
 
 
+# The places a trip of the BFS job's bottom-up loop takes
+# (ops/bucketed_mode.bfs_level_bottom_up). A level costs its trips, so U rounded
+# up to this. Measured on graph500-24 (PERF.md §6, PR 53): 39.3-39.5 ns a place
+# at 2^19 and at 2^20, 35.1 at 2^22; one trip, which is what each of a search's
+# last levels pays for a few thousand edges, 0.034 s at 2^19, 0.064 at 2^20,
+# 0.17 at 2^22. At 2^19 the level of 68.2 M edges takes 131 trips.
+BOTTOM_UP_CHUNK = 1 << 19
+
+
+def bottom_up_chunk(num_messages: int) -> int:
+    """A trip's places on a graph of ``num_messages``: no more than it has."""
+    return max(1, min(BOTTOM_UP_CHUNK, int(num_messages)))
+
+
 def delta_rungs(num_messages: int) -> tuple:
     """The rungs of the carried-rows job, ascending: the static caps on
     the messages a sparse superstep rewrites (none on a graph too small
@@ -203,21 +217,20 @@ def mesh_memory_stats(mesh) -> dict | None:
 
 
 def _admission_sizes(plan, shards: int, reduce: str) -> dict:
-    """The rungs the admission sizes the job's programs at: the rewrite at
-    the top one; the BFS job's bottom-up level, which never takes the top
-    rung, at the one below it."""
+    """What the admission sizes the job's programs at: the rewrite at the
+    top rung; the BFS job's bottom-up level at its loop's chunk."""
     rungs = delta_rungs(int(plan.num_messages))
     return dict(
         top_rung=max(rungs, default=0), shards=shards, reduce=reduce,
-        bottom_up_rung=rungs[-2] if reduce == "min" and len(rungs) > 1 else 0,
+        bottom_up_chunk=bottom_up_chunk(plan.num_messages) if reduce == "min" else 0,
     )
 
 
 def reckoned_temp_bytes(plan, shards: int = 1, reduce: str = "mode") -> dict:
     """``{(program, cap): bytes}``: what :func:`admit_carried_rows` counted
     for each program's temporaries, under the names the job gives its
-    programs (the BFS job's row min is its ``level``) and, for the two
-    programs that are reckoned at one rung alone, that rung's ``cap``;
+    programs (the BFS job's row min is its ``level``) and, for the
+    rewrite, which is reckoned at the top rung alone, that rung's ``cap``;
     ``None`` for a program that has no rung. The hubs' histograms, which
     the admission's sum holds as a term of its own and the compiler as
     temporaries of the two programs that reduce the hubs, are counted with
@@ -225,7 +238,7 @@ def reckoned_temp_bytes(plan, shards: int = 1, reduce: str = "mode") -> dict:
     admission counts nothing for has no entry. The ``reckoned_temp_bytes``
     of the ``program_memory`` records (:class:`ProgramLog`)."""
     sized = _admission_sizes(plan, shards, reduce)
-    caps = {"rewrite": sized["top_rung"], "bottom_up": sized["bottom_up_rung"]}
+    caps = {"rewrite": sized["top_rung"]}
     hubs = carried_rows_inventory(plan, **sized)["hub_histograms"]
     return {
         ("level" if name == "row_min" else name, caps.get(name)):
@@ -257,7 +270,7 @@ def admit_carried_rows(
     every superstep. A device that reports no limit admits ``carried``.
     ``reduce="min"`` asks for the BFS job over the same rows and index
     (``ops/paths.py``): its own programs (the gather, the row min, the top
-    rung's rewrite, the bottom-up level at the rung below it) and no
+    rung's rewrite, the bottom-up level at its loop's chunk) and no
     histogram; ``plain`` is then one compiled full-width level stepped from
     the host.
 

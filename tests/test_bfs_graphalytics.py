@@ -3,8 +3,9 @@ that follows its frontier over the carried bucket rows, the full-width level
 stepped from the host and the ``while_loop`` over the message arrays give the
 depths of the benchmark's plain reference (``benchmark/algorithms/bfs.py``,
 SciPy on its own CSR) and each other's, bit for bit; a level that asks the
-unreached vertices for a reached neighbour gives them too, and the search
-takes it where the ladder says it is the cheaper (ISSUE 50); the one
+unreached vertices for a reached neighbour through the graph's message CSR
+gives them too, and the search takes it where a place's cost says it is the
+cheaper (ISSUE 50, ISSUE 53); the one
 stepping loop takes BFS's stop as an argument and steps CDLP as it did; the
 admission answers for this job's own programs."""
 
@@ -23,6 +24,7 @@ from graphmine_tpu.ops import lpa, superstep_policy
 from graphmine_tpu.ops.paths import UNREACHABLE, bfs_parents
 from graphmine_tpu.ops.superstep_policy import (
     admit_carried_rows,
+    bottom_up_chunk,
     delta_rungs,
     step_carried_rows,
 )
@@ -198,15 +200,12 @@ def test_every_branch_is_taken_and_the_record_says_so(monkeypatch):
     assert delta["rungs"] == list(rungs)
     assert delta["branch"][0] == "fill"  # the sources' slots, into the fill
     assert set(delta["branch"]) == {"fill", *rungs, "full"}
-    # a level's branch is the lowest rung its predecessor's count fits under:
-    # the messages of the vertices it reached (K) for a top-down level, the
-    # edges of the vertices still unreached (U) for a bottom-up one
-    for k, u, way, taken in zip(delta["changed_messages"], delta["unreached_messages"],
-                                delta["direction"][1:], delta["branch"][1:]):
-        read = u if way == "bottom_up" else k
-        assert taken == next((r for r in rungs if read <= r), "full")
-        assert way == "top_down" or taken not in ("full", rungs[-1])
-    assert delta["direction"][0] == "top_down"
+    # a level's branch is the lowest rung its predecessor's K fits under; on
+    # a graph this small one trip of the bottom-up loop looks at as many
+    # places as the graph has messages, so no level turns
+    for k, taken in zip(delta["changed_messages"], delta["branch"][1:]):
+        assert taken == next((r for r in rungs if k <= r), "full")
+    assert set(delta["direction"]) == {"top_down"} and set(delta["places"]) == {0}
 
 
 def test_many_sources_start_with_a_full_gather(monkeypatch):
@@ -221,33 +220,37 @@ def test_many_sources_start_with_a_full_gather(monkeypatch):
     assert delta["branch"][0] == "full" and delta["source_messages"] > 64
 
 
-# -- the bottom-up level (ISSUE 50) ---------------------------------------------
+# -- the bottom-up level (ISSUE 50, ISSUE 53) -----------------------------------
+
+_CHUNK = 64  # a trip's places in these tests: spans straddle its end, trips are many
 
 
-def _only_bottom_up(monkeypatch, num_messages):
-    """Every level of a search asks the unreached vertices, at the lowest of
-    three rungs their edges fit under (the top one holds every message)."""
+def _small_chunks(monkeypatch):
+    monkeypatch.setattr(superstep_policy, "BOTTOM_UP_CHUNK", _CHUNK)
+
+
+def _whole_trips(places, edges):
+    """What a bottom-up level's loop ran over: whole trips of ``_CHUNK``
+    places, as many as the edges before it take, more only where a trip ends
+    with the spans it may cut (a quarter of its places) and not with its
+    places; none where nothing is left to look at."""
+    for ran, u in zip(places, edges, strict=True):
+        trips, least = ran // _CHUNK, -(-u // _CHUNK)
+        assert ran % _CHUNK == 0 and least <= trips <= max(4 * least, least + 1)
+
+
+def _only_bottom_up(monkeypatch):
+    """Every level of a search asks the unreached vertices, ``_CHUNK`` places
+    a trip."""
     from graphmine_tpu.ops import paths
 
-    rungs = (16, 512, num_messages)
-    monkeypatch.setattr(superstep_policy, "delta_rungs", lambda num_messages: rungs)
+    _small_chunks(monkeypatch)
     monkeypatch.setattr(
-        paths, "_next_update",
-        lambda k, u, rungs, stale: (sum(u > rung for rung in rungs), True))
-    return rungs
+        paths, "_next_update", lambda k, places, rungs, slots, stale: (len(rungs), True))
 
 
-BOTTOM_UP_CASES = ["path", "grid", "several_sources", "unreached_components",
-                   "isolated_source", "rmat_with_a_histogram_hub", "many_hubs"]
-
-
-@pytest.mark.parametrize("name", BOTTOM_UP_CASES)
-def test_bottom_up_levels_give_the_plain_references_depths(name, monkeypatch):
-    """A search of bottom-up levels alone: the reference's depths, a level a
-    superstep, on many levels, many sources, a graph whose other component
-    and isolated vertices keep U above 0, and graphs with histogram hubs,
-    whose messages have no slot (R-MAT's four; and eighty, hubs that are
-    each other's neighbours among them, with the hub cut patched down)."""
+def _bottom_up_case(name, monkeypatch):
+    """``(u, v, n, sources, max_depth, graph, plan)`` of a bottom-up case."""
     if name == "many_hubs":
         # the module: ops/__init__ exports a function under its name
         monkeypatch.setattr(
@@ -259,7 +262,24 @@ def test_bottom_up_levels_give_the_plain_references_depths(name, monkeypatch):
     g, plan = _fused(u, v, n)
     hubs = 0 if plan.hist_vertex_ids is None else plan.hist_vertex_ids.shape[0]
     assert hubs >= {"many_hubs": 40, "rmat_with_a_histogram_hub": 1}.get(name, 0)
-    rungs = _only_bottom_up(monkeypatch, plan.num_messages)
+    return u, v, n, sources, max_depth, g, plan
+
+
+BOTTOM_UP_CASES = ["path", "grid", "several_sources", "unreached_components",
+                   "isolated_source", "rmat_with_a_histogram_hub", "many_hubs"]
+
+
+@pytest.mark.parametrize("name", BOTTOM_UP_CASES)
+def test_bottom_up_levels_give_the_plain_references_depths(name, monkeypatch):
+    """A search of bottom-up levels alone: the reference's depths, a level a
+    superstep, on many levels, many sources, a graph whose other component
+    and isolated vertices keep U above 0, and graphs with histogram hubs,
+    whose ids stand in the message CSR like any other (R-MAT's four; and
+    eighty, hubs that are each other's neighbours among them, with the hub
+    cut patched down). The record's ``places`` are the U before the level
+    rounded up to the loop's trips, and its ``branch`` says the same."""
+    u, v, n, sources, max_depth, g, plan = _bottom_up_case(name, monkeypatch)
+    _only_bottom_up(monkeypatch)
     sink = MetricsSink()
     got, levels = _run("frontier", g, plan, sources, max_depth, monkeypatch, sink)
     want = _want(u, v, n, sources, max_depth)
@@ -275,18 +295,69 @@ def test_bottom_up_levels_give_the_plain_references_depths(name, monkeypatch):
     if name in ("unreached_components", "isolated_source"):
         assert unreached[-1] > 0  # the other component's edges, to the end
     before = [plan.num_messages - delta["source_messages"], *unreached]
-    assert delta["branch"] == [next(r for r in rungs if x <= r) for x in before[:-1]]
+    assert delta["places"] == delta["branch"]
+    _whole_trips(delta["places"], before[:-1])
+    assert max(delta["places"]) > 8 * _CHUNK  # many trips
+
+
+@pytest.mark.parametrize("name", BOTTOM_UP_CASES)
+def test_the_bottom_up_level_equals_the_row_min_bit_for_bit(name, monkeypatch):
+    """Level by level down a search, beside ``bfs_level_from_rows`` over rows
+    gathered anew: the same depths from the level that reads the message
+    CSR, at a chunk of a few places (every longer span straddles a trip's
+    end, the last trip is part empty), at the chunk U passes by one place
+    and at the one it fills, and past the last level (U = 0, or the edges
+    no path reaches)."""
+    bm = importlib.import_module("graphmine_tpu.ops.bucketed_mode")
+    u, v, n, sources, max_depth, g, plan = _bottom_up_case(name, monkeypatch)
+    rows_level = jax.jit(lambda depth: bm.bfs_level_from_rows(
+        bm.gather_depth_rows(np.zeros(bm.row_slots(plan), np.int32), depth, plan),
+        depth, plan))
+    bottom_up = jax.jit(
+        lambda depth, chunk: bm.bfs_level_bottom_up(
+            depth, *bm.compact_unreached(depth, g.msg_ptr), g.msg_send, chunk)[0],
+        static_argnames="chunk")
+    deg = np.diff(np.asarray(g.msg_ptr))
+    depth = np.full(n, int(UNREACHABLE), np.int32)
+    depth[np.asarray(sources)] = 0
+    edges, fitted = [], False
+    for _ in range(n):
+        want = np.asarray(rows_level(depth))
+        left = int(deg[depth == int(UNREACHABLE)].sum())
+        edges.append(left)
+        chunks = [7]
+        if not fitted and left > 2:
+            chunks += [left - 1, left]  # one place into a second trip; a full trip
+            fitted = True
+        for chunk in chunks:
+            assert np.array_equal(np.asarray(bottom_up(depth, chunk=chunk)), want), chunk
+        if np.array_equal(want, depth):
+            break
+        depth = want
+    assert fitted  # and the last level looked and found nothing
+    if name in ("grid", "path"):
+        assert edges[-1] == 0  # every vertex with an edge reached: no place, no trip
+    elif name in ("unreached_components", "isolated_source"):
+        assert edges[-1] > 0
+    assert np.array_equal(depth, _want(u, v, n, sources, 0))
+
+
+def _turning_search(monkeypatch):
+    _small_chunks(monkeypatch)
+    rungs = (40, 400, 1500, 5000)
+    monkeypatch.setattr(superstep_policy, "delta_rungs", lambda num_messages: rungs)
+    return rungs
 
 
 def test_a_search_turns_once_and_issues_no_rewrite_after(monkeypatch):
-    """Top-down on rungs, one full gather, then bottom-up to the end: the
+    """Top-down on rungs, a full gather, then bottom-up to the end: the
     record says so, and over the stale rows no rewrite is issued."""
     from graphmine_tpu.ops import paths
+    from graphmine_tpu.ops.bucketed_mode import row_slots
 
     u, v, n, sources = _many_levels()
     g, plan = _fused(u, v, n)
-    rungs = (40, 400, 1500, 5000)
-    monkeypatch.setattr(superstep_policy, "delta_rungs", lambda num_messages: rungs)
+    rungs = _turning_search(monkeypatch)
     issued = []
     for program in ("_gather_program", "_rewrite_program", "_level_program",
                     "_unreached_program", "_bottom_up_program"):
@@ -300,14 +371,26 @@ def test_a_search_turns_once_and_issues_no_rewrite_after(monkeypatch):
     (delta,) = [r for r in sink.records if r["phase"] == "superstep_delta"]
     turn = delta["direction"].index("bottom_up")
     assert delta["direction"] == ["top_down"] * turn + ["bottom_up"] * (levels - turn)
-    assert delta["branch"][turn - 1] == "full" and delta["branch"].count("full") == 1
     assert delta["branch"][0] == "fill" and turn >= 3 and levels - turn >= 2
-    assert set(delta["branch"][turn:]) <= set(rungs[:-1])
+    assert "full" in delta["branch"][:turn] and set(delta["branch"][1:turn]) <= {*rungs, "full"}
     assert delta["reduce"] == ["full"] * turn + ["none"] * (levels - turn)
+    gathers = delta["branch"].count("full")
     assert issued == (
-        ["rewrite", "level"] * (turn - 1) + ["gather", "level"]
+        ["rewrite", "level"] * (turn - gathers) + ["gather", "level"] * gathers
         + ["unreached", "bottom_up"] * (levels - turn))
-    assert len(set(delta["branch"][turn:])) >= 2  # a bottom-up program a rung taken
+    # the places a bottom-up level looked at: the U its predecessor left, in
+    # whole trips; one program whatever their number
+    assert delta["places"][:turn] == [0] * turn
+    _whole_trips(delta["places"][turn:], delta["unreached_messages"][turn - 1:-1])
+    assert delta["branch"][turn:] == delta["places"][turn:]
+    assert len(set(delta["places"][turn:])) >= 2
+    # the rule, level for level, from the counts the record holds
+    ks = [delta["source_messages"], *delta["changed_messages"]]
+    us = [plan.num_messages - ks[0], *delta["unreached_messages"]]
+    for i, way in enumerate(delta["direction"]):
+        places = -(-us[i] // _CHUNK) * _CHUNK
+        assert paths._next_update(
+            ks[i], places, rungs, row_slots(plan), stale=i > turn)[1] == (way == "bottom_up")
 
 
 def test_stale_rows_are_gathered_anew_where_that_is_the_cheaper(monkeypatch):
@@ -317,42 +400,73 @@ def test_stale_rows_are_gathered_anew_where_that_is_the_cheaper(monkeypatch):
 
     u, v, n, sources = _many_levels()
     g, plan = _fused(u, v, n)
+    _turning_search(monkeypatch)
     rule, asked = paths._next_update, []
 
-    def turn_early(k, u, rungs, stale):
+    def turn_early(k, places, rungs, slots, stale):
         asked.append(stale)
         if len(asked) == 2:  # the second level, whatever it would cost
-            return len(rungs) - 1, True
-        return rule(k, u, rungs, stale)
+            return len(rungs), True
+        return rule(k, places, rungs, slots, stale)
 
     monkeypatch.setattr(paths, "_next_update", turn_early)
-    monkeypatch.setattr(superstep_policy, "delta_rungs",
-                        lambda num_messages: (40, 400, 1500, num_messages))
     sink = MetricsSink()
     got, _ = _run("frontier", g, plan, sources, 0, monkeypatch, sink)
     assert np.array_equal(got, _want(u, v, n, sources, 0))
     (delta,) = [r for r in sink.records if r["phase"] == "superstep_delta"]
     assert delta["direction"][:3] == ["top_down", "bottom_up", "top_down"]
     assert delta["branch"][2] == "full" and asked[:4] == [False, False, True, False]
+    assert delta["places"][0] == delta["places"][2] == 0
+    _whole_trips(delta["places"][1:2], delta["unreached_messages"][:1])
 
 
-@pytest.mark.parametrize("k, u, stale, want", [
+_G500_24 = dict(rungs=delta_rungs(520_752_272), slots=542_524_857)
+
+
+@pytest.mark.parametrize("k, places, stale, want", [
     (50, 10**6, False, (1, False)),    # K's rung, U far above it
-    (50, 60, False, (1, False)),       # the same rung: a rewrite's place is the cheaper
-    (50, 5, False, (0, True)),         # U's rung strictly below K's
-    (10**6, 900, False, (2, True)),    # in a full gather's place
-    (10**6, 3000, False, (4, False)),  # never on the top rung: the gather
-    (5, 900, True, (2, True)),         # stale rows: no rewrite, whatever K
-    (5, 3000, True, (4, False)),       # stale rows and U on the top rung: gather
-    (5, 10**6, True, (4, False)),
+    (50, 60, False, (1, True)),        # U under K's rung: a place costs about the same
+    (50, 110, False, (1, False)),      # U over K's rung by more than the costs differ
+    (5, 9, False, (0, True)),          # on the lowest rung, which costs all its places
+    (10**6, 900, False, (4, True)),    # in a full gather's place
+    (10**6, 3000, False, (4, False)),  # 3,000 places at 39 ns against 6,000 slots at 7.4
+    (10**6, 1100, False, (4, True)),   # ... and under a fifth of the slots
+    (5, 900, True, (4, True)),         # stale rows: no rewrite, whatever K
+    (5, 3000, True, (4, False)),       # stale rows and too many places: gather
+    (5, 0, True, (4, True)),           # nothing left to look at costs nothing
 ])
-def test_the_rule_reads_k_u_and_the_ladder(k, u, stale, want):
+def test_the_rule_weighs_what_a_place_of_each_update_costs(k, places, stale, want):
+    from graphmine_tpu.ops import paths
     from graphmine_tpu.ops.paths import _next_update
 
-    assert _next_update(k, u, (10, 100, 1000, 5000), stale) == want
-    # a ladder of one rung, or of none, never turns
-    assert _next_update(k, u, (10**5,), stale)[1] is False
-    assert _next_update(k, u, (), stale) == (0, False)
+    assert _next_update(k, places, (10, 100, 1000, 5000), 6000, stale) == want
+    # with no rung the one top-down update is the gather
+    assert _next_update(k, places, (), 6000, stale) == (
+        0, places * paths._BOTTOM_UP_PLACE_NS < 6000 * paths._GATHERED_SLOT_NS)
+
+
+@pytest.mark.parametrize("level, k, u, want", [
+    # graph500-24 from the benchmark's source (PERF.md §5, PR 50): what each
+    # level's predecessor left, and the update the level takes
+    (1, 1, 520_752_271, ("top_down", 127_136)),
+    (2, 718, 520_751_553, ("top_down", 127_136)),
+    (3, 5_252_974, 515_498_579, ("top_down", 32_547_017)),
+    (4, 447_287_276, 68_211_303, ("bottom_up", 68_681_728)),  # K fits no rung: 131 trips
+    (5, 67_957_465, 253_838, ("bottom_up", 1 << 19)),
+    (6, 246_930, 6_908, ("bottom_up", 1 << 19)),
+    (7, 719, 6_189, ("bottom_up", 1 << 19)),
+    (8, 1, 6_188, ("bottom_up", 1 << 19)),
+])
+def test_the_rule_turns_graph500_24_at_its_fourth_level(level, k, u, want):
+    from graphmine_tpu.ops.paths import _next_update
+
+    chunk = bottom_up_chunk(520_752_272)
+    assert chunk == 1 << 19
+    places = -(-u // chunk) * chunk
+    place, bottom_up = _next_update(k, places, stale=level > 4, **_G500_24)
+    took = places if bottom_up else [*_G500_24["rungs"], "full"][place]
+    assert ("bottom_up" if bottom_up else "top_down", took) == want
+    assert abs(places - u) < chunk
 
 
 def test_auto_takes_the_frontier_job_past_the_crossover_and_not_below_it():
@@ -495,34 +609,40 @@ def test_the_admission_sizes_the_bfs_jobs_own_programs(monkeypatch):
     top = max(delta_rungs(plan.num_messages))
     cdlp = memmodel.carried_rows_inventory(plan, top_rung=top)
     need = memmodel.carried_rows_inventory(plan, top_rung=top, reduce="min")
-    rungs = delta_rungs(plan.num_messages)
-    sized = dict(top_rung=top, reduce="min", bottom_up_rung=rungs[-2])
+    chunk = bottom_up_chunk(plan.num_messages)
+    # a trip takes no more places than the graph has messages
+    assert chunk == plan.num_messages < superstep_policy.BOTTOM_UP_CHUNK == 1 << 19
+    sized = dict(top_rung=top, reduce="min", bottom_up_chunk=chunk)
     need = memmodel.carried_rows_inventory(plan, **sized)
     programs = memmodel.carried_job_transients(plan, **sized)
     assert sorted(programs) == ["bottom_up", "gather", "rewrite", "row_min"]
-    # the level that reads no row: cap-long vectors at the rung below the top
-    # one, which it never takes, beside the level's V-vectors
-    assert programs["bottom_up"] > 4 * 5 * rungs[-2] + 4 * 8 * n
-    assert programs["bottom_up"] < memmodel.carried_job_transients(
-        plan, top_rung=top, reduce="min", bottom_up_rung=top)["bottom_up"]
+    # the level that reads no row: a trip's chunk-long vectors beside the
+    # level's V-vectors, whatever the level's size
+    assert 4 * (6 * chunk + 3 * n) < programs["bottom_up"] <= 4 * 8 * (chunk + n + 1)
+    assert programs["bottom_up"] > memmodel.carried_job_transients(
+        plan, top_rung=top, reduce="min", bottom_up_chunk=chunk // 2)["bottom_up"]
     assert memmodel.carried_job_transients(plan, top, reduce="min")["bottom_up"] == 0
+    assert bottom_up_chunk(5) == 5 and bottom_up_chunk(0) == 1  # no more than M places
     assert cdlp["hub_histograms"] > 0 and need["hub_histograms"] == 0
     assert need["gather_transient"] == max(programs.values())
     for same in ("carried_rows", "slot_index", "labels", "changed_mask"):
         assert need[same] == cdlp[same]
     assert programs["gather"] == memmodel.carried_job_transients(plan, top)["gather"]
-    total = sum(need.values())
+    # at this size a trip of the bottom-up loop (as many places as the graph has
+    # messages) is the job's largest program, and BFS asks for more than CDLP
+    total, less = sum(need.values()), sum(cdlp.values())
+    assert need["gather_transient"] == programs["bottom_up"] and total > less
     room = {"bytes_limit": total, "bytes_in_use": 0}
     scan, reason = admit_carried_rows(plan, room, reduce="min")
     assert scan == "carried" and "row_min" in reason and "modes" not in reason
     assert f"bottom_up {programs['bottom_up']} B" in reason
-    assert admit_carried_rows(plan, room)[0] == "plain"  # CDLP's histograms do not fit
     room["bytes_limit"] -= 1
     assert admit_carried_rows(plan, room, reduce="min")[0] == "plain"
-    # one index a plan, one answer a job: CDLP is refused, BFS admitted
-    _squeeze(monkeypatch, total)
-    assert lpa._cached_slot_index(plan)[2][0] == "plain"
-    indexed, _, scan = lpa._cached_slot_index(plan, reduce="min")
+    assert admit_carried_rows(plan, room)[0] == "carried"  # CDLP's own programs fit
+    # one index a plan, one answer a job: CDLP is admitted, BFS refused
+    _squeeze(monkeypatch, less)
+    assert lpa._cached_slot_index(plan, reduce="min")[2][0] == "plain"
+    indexed, _, scan = lpa._cached_slot_index(plan)
     assert scan[0] == "carried" and indexed.out_slot is not None
-    assert lpa._cached_slot_index(plan)[0].out_slot is None
-    assert lpa._cached_slot_index(plan, reduce="min")[0].out_slot is indexed.out_slot
+    assert lpa._cached_slot_index(plan, reduce="min")[0].out_slot is None
+    assert lpa._cached_slot_index(plan)[0].out_slot is indexed.out_slot

@@ -6,9 +6,11 @@ interpreter never show. Nothing runs, so nothing here is a result or a
 time. The full-size (2^18 vertices / 25 M edges) compiles take minutes
 and belong to the no-chip rehearsal before a chip call, not to tier-1.
 
-Everything built from the topology is built inside fixtures or tests of
-THIS file: only one process may hold libtpu, and under pytest-xdist every
-worker imports every test module.
+Everything built from the topology is built inside fixtures
+(``tests/chip_compile_fixtures.py``) or tests: only one process may hold
+libtpu, and under pytest-xdist every worker imports every test module. The
+BFS job's programs are compiled by ``tests/test_chip_compile_bfs.py``: under
+``--dist loadfile`` a file is one worker's, and this one is the run's wall.
 """
 
 import os
@@ -17,69 +19,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-
-@pytest.fixture(scope="module")
-def topo():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        described = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        )
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # an executable compiled for a described chip can be written to the
-    # persistent cache but not read back without one: keep it off here
-    was_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield described
-    jax.config.update("jax_enable_compilation_cache", was_on)
-    compilation_cache.reset_cache()
-
-
-@pytest.fixture(scope="module")
-def one_chip(topo):
-    return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture(scope="module")
-def planted():
-    from graphmine_tpu.datasets import planted_anomaly_graph
-
-    v = 1 << 16
-    src, dst, _, _ = planted_anomaly_graph(v, 1_000_000, seed=0)
-    return src, dst, v
-
-
-@pytest.fixture(scope="module")
-def fused_plan(planted):
-    from graphmine_tpu.ops.bucketed_mode import build_graph_and_plan
-
-    src, dst, v = planted
-    return build_graph_and_plan(src, dst, num_vertices=v)
-
-
-def _shapes(tree, sharding):
-    return jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=sharding),
-        tree,
-    )
-
-
-def _shape_on(sharding):
-    return lambda dims, dtype=jnp.int32: jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
-
-
-def _compile(fn, *args, **kwargs):
-    compiled = fn.lower(*args, **kwargs).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 15 << 30
-    return compiled
+from chip_compile_fixtures import (  # noqa: F401  (fixtures, by name)
+    _compile,
+    _shape_on,
+    _shapes,
+    flat_plan,
+    fused_plan,
+    one_chip,
+    planted,
+    topo,
+)
 
 
 @pytest.mark.parametrize("impl, k", [("pallas", 8), ("xla", 128)])
@@ -182,25 +134,6 @@ def test_lpa_superstep_bucketed_compiles_for_v5e(one_chip, fused_plan, planted):
     )
 
 
-@pytest.fixture(scope="module")
-def flat_plan():
-    """GAP Urand's plan at scale 16 (the benchmark's own generator at
-    a = b = c = 0.25, as ``gap-urand-24``): two dozen narrow classes of
-    like size, no hub."""
-    import sys
-
-    from graphmine_tpu.ops.bucketed_mode import BucketedModePlan
-
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark"))
-    import generators
-
-    u, v = generators.rmat_undirected(16, 16, 0.25, 0.25, 0.25, seed=2147483659)
-    plan = BucketedModePlan.from_edges(u, v, 1 << 16)
-    assert plan.hist_vertex_ids is None and len(plan.send_idx) > 20
-    return plan
-
-
 @pytest.mark.parametrize(
     "program", ["gather", "rewrite", "modes", "rewrite:marked", "dirty_modes"]
 )
@@ -274,121 +207,6 @@ def test_carried_rows_programs_compile_for_v5e(
     if graph == "kronecker":  # wide classes: well under the rows
         assert held.temp_size_in_bytes < (
             rows_bytes if program in ("modes", "dirty_modes") else rows_bytes // 4)
-
-
-@pytest.mark.parametrize("graph, program", [
-    *[("kronecker", p) for p in ("start", "gather", "rewrite", "level", "full_level",
-                                 "unreached", "bottom_up")],
-    # the rewrite is CDLP's under another scope, a minute a compile: once is enough
-    *[("flat", p) for p in ("start", "gather", "level", "full_level")],
-])
-def test_bfs_job_programs_compile_for_v5e(
-    one_chip, fused_plan, flat_plan, planted, program, graph
-):
-    """The BFS job's programs (ISSUE 49: ``ops/paths.py``), each compiled
-    alone, beside CDLP's: the rows are the donated argument of the gather
-    and of the rewrite, so both update the whole ``s32[S]`` buffer IN PLACE
-    (aliased to the result, no copy and no temporary of its size), and the
-    start program lays them out by a fill, with no gather and no
-    temporary. The level reads the rows and writes V-sized results. Each
-    program's temporaries are at or under what the admission counts for
-    it (``carried_job_transients(..., reduce="min")``), on a skewed plan
-    with hubs, whose histograms this job never builds, and on a flat one.
-    ISSUE 50's two: the compaction of the unreached vertices holds the one
-    sort of a bottom-up level, and the level itself, at the highest rung
-    it may take (the one below the top), holds none, reads no row (the
-    rows are not its argument) and writes V-sized results."""
-    from graphmine_tpu.obs.memmodel import carried_job_transients
-    from graphmine_tpu.ops import paths
-    from graphmine_tpu.ops.bucketed_mode import row_slots, with_slot_index
-    from graphmine_tpu.ops.superstep_policy import delta_rungs
-
-    plan = fused_plan[1] if graph == "kronecker" else flat_plan
-    plan = _shapes(with_slot_index(plan), one_chip)
-    v, slots = planted[2], row_slots(plan)
-    *_, turn_rung, top_rung = delta_rungs(plan.num_messages)
-    shape = _shape_on(one_chip)
-    rows, depth = shape((slots,)), shape((v,))
-    counted = carried_job_transients(
-        plan, top_rung=top_rung, reduce="min", bottom_up_rung=turn_rung)
-    if program == "start":
-        compiled = _compile(paths._start_program, shape((1,)), plan.out_ptr,
-                            slots=slots, num_vertices=v)
-        limit = 8 * v
-    elif program == "gather":
-        compiled = _compile(paths._gather_program, rows, depth, plan)
-        limit = counted["gather"]
-    elif program == "rewrite":
-        compiled = _compile(paths._rewrite_program, rows, depth,
-                            shape((v,), jnp.bool_), plan, cap=top_rung)
-        limit = counted["rewrite"]
-    elif program == "level":
-        compiled = _compile(paths._level_program, rows, depth, plan)
-        limit = counted["row_min"]
-    elif program == "unreached":  # the rewrite's sort less an operand
-        compiled = _compile(paths._unreached_program, depth, plan)
-        limit = counted["rewrite"]
-    elif program == "bottom_up":
-        compiled = _compile(paths._bottom_up_program, depth, depth, depth, depth,
-                            plan, cap=turn_rung)
-        limit = counted["bottom_up"]
-    else:  # where the rows were not admitted: gathers, mins, keeps nothing
-        compiled = _compile(paths._full_level_program, depth, plan)
-        limit = counted["row_min"]
-    held = compiled.memory_analysis()
-    text = compiled.as_text()
-    assert " conditional(" not in text and " while(" not in text
-    assert held.temp_size_in_bytes <= limit
-    if program in ("gather", "rewrite"):
-        assert held.alias_size_in_bytes >= 4 * slots
-    else:
-        assert held.alias_size_in_bytes == 0
-    if program == "start":
-        assert " gather(" not in text and held.output_size_in_bytes >= 4 * slots
-    assert (" sort(" in text) == (program in ("rewrite", "unreached"))
-    if graph == "kronecker":  # wide classes, four hubs: well under the rows
-        assert held.temp_size_in_bytes < 4 * slots // (4 if program != "level" else 1)
-
-
-def test_the_bottom_up_level_fits_its_count_at_graph500_24s_shapes(one_chip):
-    """The BFS cell's own plan, by shapes (``_proof/g500_24_shapes.json``),
-    at the highest rung a bottom-up level may take there, M/16 = 32.5 M
-    places: the compiler's temporaries are at or under what the admission
-    counts for it (ISSUE 50: 721,833,472 B against 1,317,999,352), and under
-    the top rung's rewrite, so the job's largest program is what it was.
-    The compare of every place against the sixty class offsets is fused into
-    each lookup: were it written out it would be ``[cap, 60]``, 1.9 GB and
-    more."""
-    import json
-    import os
-
-    from graphmine_tpu.obs.memmodel import carried_job_transients
-    from graphmine_tpu.ops import paths
-    from graphmine_tpu.ops.bucketed_mode import BucketedModePlan
-    from graphmine_tpu.ops.superstep_policy import delta_rungs
-
-    said = json.load(open(os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "_proof", "g500_24_shapes.json")))
-    shape = _shape_on(one_chip)
-    v, m = said["num_vertices"], said["num_messages"]
-    plan = BucketedModePlan(
-        vertex_ids=tuple(shape((n,)) for n, _ in said["classes"]), msg_idx=None,
-        num_vertices=v, num_messages=m,
-        send_idx=tuple(shape((n, w)) for n, w in said["classes"]),
-        hist_vertex_ids=shape((said["hubs"],)), hist_send=shape((said["hist_send"],)),
-        hist_row_offset=shape((said["hist_row_offset"],)),
-        out_ptr=shape((v + 1,)), out_slot=shape((m,)),
-    )
-    *_, turn_rung, top_rung = delta_rungs(m)
-    assert turn_rung == m // 16
-    depth = shape((v,))
-    held = _compile(paths._bottom_up_program, depth, depth, depth, depth, plan,
-                    cap=turn_rung).memory_analysis()
-    counted = carried_job_transients(
-        plan, top_rung=top_rung, reduce="min", bottom_up_rung=turn_rung)
-    assert held.temp_size_in_bytes <= counted["bottom_up"] < counted["rewrite"]
-    assert held.temp_size_in_bytes < 4 * turn_rung * 8  # a few words a place
-    assert held.alias_size_in_bytes == 0
 
 
 @pytest.mark.parametrize("graph", ["kronecker", "flat"])
